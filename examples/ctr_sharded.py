@@ -131,6 +131,10 @@ def main():
 
 if __name__ == "__main__":
     import jax
-    if jax.default_backend() != "cpu" and jax.device_count() < 4:
-        jax.config.update("jax_platforms", "cpu")
+    if jax.device_count() < 4:
+        raise SystemExit(
+            f"ctr_sharded.py shards over a dp2 x mp2 mesh and needs 4 "
+            f"devices; JAX found {jax.device_count()} "
+            f"({jax.default_backend()}). On a CPU host: JAX_PLATFORMS=cpu "
+            f"XLA_FLAGS=--xla_force_host_platform_device_count=4")
     main()

@@ -22,13 +22,14 @@ import jax
 import jax.numpy as jnp
 
 from .tensor import Tensor
+from .monitor import profile as _profile
 
 float0 = jax.dtypes.float0
 
 
 class TapeNode:
     """One recorded op: inputs, a vjp closure, and weak links to outputs."""
-    __slots__ = ("inputs", "vjp", "outputs", "seq", "name")
+    __slots__ = ("inputs", "vjp", "outputs", "seq", "name", "scope")
 
     _counter = [0]
 
@@ -40,6 +41,9 @@ class TapeNode:
         TapeNode._counter[0] += 1
         self.seq = TapeNode._counter[0]
         self.name = name
+        # monitor.profile scope path of the forward op (() when
+        # profiling is off): backward() re-enters it around the vjp
+        self.scope = ()
 
 
 class _State(threading.local):
@@ -159,7 +163,12 @@ def backward(root: Tensor, grad_tensor=None, retain_graph=False, _only=None):
                 "Trying to backward through a graph that has been freed "
                 f"(op '{node.name}'). Call backward(retain_graph=True) on "
                 "the first backward if you need to backward twice.")
-        in_grads = node.vjp(tuple(outs_ct) if len(outs_ct) > 1 else outs_ct[0])
+        cts = tuple(outs_ct) if len(outs_ct) > 1 else outs_ct[0]
+        if node.scope:
+            with _profile.reenter(node.scope):
+                in_grads = node.vjp(cts)
+        else:
+            in_grads = node.vjp(cts)
         for t, g in zip(node.inputs, in_grads):
             if g is None or (hasattr(g, "dtype") and g.dtype == float0):
                 continue

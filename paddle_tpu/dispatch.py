@@ -23,6 +23,7 @@ import jax
 from .tensor import Tensor, as_tensor
 from . import autograd
 from .autograd import TapeNode
+from .monitor import profile as _profile
 
 # Static-graph hook, installed by paddle_tpu.static to avoid a circular
 # import. When non-None and static mode is on, apply() records graph nodes.
@@ -97,6 +98,8 @@ def apply(impl, tensors, attrs=None, nondiff=False, n_out=1, name=""):
 
     if need_grad:
         node = TapeNode(ts, vjp, list(out_tensors), name=name)
+        if _profile.scopes_on:
+            node.scope = _profile.current_path()
         for ot in out_tensors:
             ot._tape_node = node
 

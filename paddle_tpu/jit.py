@@ -19,6 +19,7 @@ to_static scan the function's closure for Layers and Optimizers.
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 import inspect
 
@@ -258,14 +259,20 @@ class StaticFunction:
                 _monitor.counter("jit.compile").inc()
                 if base in self._seen_base:
                     _monitor.counter("jit.recompile").inc()
+        state_vals = [holders[n].data for n in state_names]
         if is_new:
             self._seen_base.add(base)
+            # how many devices the step's state and inputs span, seen
+            # once, when the entry is made: a GSPMD program (> 1) cannot
+            # hold a Mosaic kernel, see ops.pallas.gspmd_trace
+            span = max((len(sh.device_set) for sh in (
+                getattr(a, "sharding", None) for a in state_vals + arrays)
+                if sh is not None), default=1)
             with _monitor.trace.span(f"jit.compile.{fn_label}"):
-                self._cache[key] = self._make_entry(treedef, arr_idx,
-                                                    statics, state_names)
+                self._cache[key] = self._make_entry(
+                    treedef, arr_idx, statics, state_names, span)
         entry = self._cache[key]
 
-        state_vals = [holders[n].data for n in state_names]
         if is_new and _monitor.enabled():
             # AOT the fresh entry (the compile the first call pays
             # anyway) so monitor.xla records its measured flops/bytes;
@@ -326,7 +333,7 @@ class StaticFunction:
                 out_leaves.append(payload)
         return jax.tree_util.tree_unflatten(meta["treedef"], out_leaves)
 
-    def _make_entry(self, treedef, arr_idx, statics, state_names):
+    def _make_entry(self, treedef, arr_idx, statics, state_names, span=1):
         fn = self._fn
         fn_scope = getattr(self, "__name__", None) or "to_static"
         # a "root" scope is recognized by monitor.profile but never
@@ -360,14 +367,15 @@ class StaticFunction:
                     saved_views.append(a.bind_views())
                 # tag the whole step's HLO with the function name (shows
                 # up in XLA profiles / the flight recorder's HLO dump)
-                if self._remat is not None:
-                    from . import memory_plan as _mp
-                    with _mp.remat_scope(self._remat):
-                        with jax.named_scope(fn_scope):
-                            out = fn(*args, **kwargs)
-                else:
-                    with jax.named_scope(fn_scope):
-                        out = fn(*args, **kwargs)
+                with contextlib.ExitStack() as scopes:
+                    if span > 1:
+                        from .ops import pallas as _pallas
+                        scopes.enter_context(_pallas.gspmd_trace(span))
+                    if self._remat is not None:
+                        from . import memory_plan as _mp
+                        scopes.enter_context(_mp.remat_scope(self._remat))
+                    scopes.enter_context(jax.named_scope(fn_scope))
+                    out = fn(*args, **kwargs)
                 new_state = [hs[n].data for n in state_names]
                 # flatten outputs treating Tensors as leaves (don't let the
                 # pytree registration split them — we need to tag them)

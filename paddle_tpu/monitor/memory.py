@@ -67,7 +67,7 @@ CLASSES = ("param", "activation", "opt_state", "temp", "remat")
 # rematted_computation sub-scope. Buffers born under either are
 # recomputed activations, not stored ones.
 _REMAT_NAME_RE = re.compile(
-    r"rematted_computation|remat2|jvp\(checkpoint\)")
+    r"rematted_computation|remat2|jvp\(checkpoint\)|(?:^|/)checkpoint/")
 
 # view opcodes: they alias operand storage, never allocate
 _TUPLE_OPS = frozenset(("tuple",))
@@ -248,6 +248,37 @@ def liveness(text, scope_map=None):
                     if row["consumer_region"] is None and \
                             region != _profile.UNATTRIBUTED:
                         row["consumer_region"] = (region, leaf)
+
+    # a compiler-inserted copy carries no op_name: what it feeds names it
+    # (above), and it passes that name on to what it copies — the XLA of
+    # jaxlib 0.9 copies donated parameters before their first use, so a
+    # parameter's only direct consumer can be such a copy
+    for ins in reversed(instrs):
+        if ins["opcode"] != "copy" or ins["op_name"]:
+            continue
+        own = buffers.get(ins["name"])
+        if own is None or own["consumer_region"] is None:
+            continue
+        for opnd in ins["operands"]:
+            for b in _resolve(_operand_name(opnd)):
+                row = buffers[b]
+                row["consumer_kinds"] |= own["consumer_kinds"]
+                if row["consumer_region"] is None:
+                    row["consumer_region"] = own["consumer_region"]
+    # ... and a copy that feeds only the ROOT tuple (an updated state
+    # leaf on its way out) is named by the buffer it copies
+    for ins in instrs:
+        own = buffers.get(ins["name"])
+        if ins["opcode"] != "copy" or ins["op_name"] or own is None \
+                or own["consumer_region"] is not None:
+            continue
+        for opnd in ins["operands"]:
+            for b in _resolve(_operand_name(opnd)):
+                region, leaf = _profile._region_of(buffers[b]["op_name"],
+                                                   scope_map)
+                if region != _profile.UNATTRIBUTED:
+                    own["consumer_region"] = (region, leaf)
+                    break
 
     # outputs: ROOT tuple components live to the end of the schedule;
     # a component aliased to a donated parameter is written *in place*

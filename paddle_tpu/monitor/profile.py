@@ -39,21 +39,25 @@ Usage::
 """
 from __future__ import annotations
 
+import contextlib
 import os
 import re
 import threading
 import time
 
+import jax
+
 __all__ = [
     "enable", "disable", "enabled", "scopes_on", "register_scope",
-    "scopes", "layer_scope", "optimizer_scope", "fscope", "reset",
+    "scopes", "layer_scope", "optimizer_scope", "fscope", "scope",
+    "current_path", "reenter", "reset",
     "roofline_ceilings", "parse_hlo", "attribute", "report",
     "format_table", "last_report", "last_summary",
 ]
 
 UNATTRIBUTED = "<unattributed>"
 
-# scope kind taxonomy: "root" scopes (the to_static function name) exist
+# scope kinds: "root" scopes (the to_static function name) exist
 # so whole-step labels are recognized WITHOUT counting as attribution —
 # everything lives under the root, so crediting it would make the ≥90%
 # attribution bar trivially true.
@@ -140,6 +144,43 @@ def fscope(name):
     return name
 
 
+# The scope path of the calling thread, kept beside jax's own name stack
+# because that one cannot be read back: jax names the ops of a vjp by
+# the stack at the time the vjp is CALLED, not where it was built, so
+# the tape (dispatch.apply -> autograd.backward) records the forward
+# op's path here and re-enters it around the op's backward. Without
+# that, every backward op of an eager-tape step is "<unattributed>".
+_tls = threading.local()
+
+
+@contextlib.contextmanager
+def scope(name):
+    """``jax.named_scope(name)``, with ``name`` also pushed on this
+    thread's scope path (see :func:`current_path`)."""
+    path = _tls.__dict__.setdefault("path", [])
+    path.append(name)
+    try:
+        with jax.named_scope(name):
+            yield
+    finally:
+        path.pop()
+
+
+def current_path():
+    """The scopes entered through :func:`scope` on this thread, outermost
+    first — what a tape node keeps to label its backward."""
+    return tuple(_tls.__dict__.get("path", ()))
+
+
+@contextlib.contextmanager
+def reenter(path):
+    """Re-enter a recorded scope path (around a tape node's vjp call)."""
+    with contextlib.ExitStack() as stack:
+        for name in path:
+            stack.enter_context(jax.named_scope(name))
+        yield
+
+
 def reset():
     """Clear registered scopes, per-class counters and the cached
     report (labeling flag is left as-is)."""
@@ -153,27 +194,32 @@ def reset():
 # ---------------------------------------------------------------------------
 # roofline ceilings
 
-# unknown silicon (the CPU test mesh) still needs a roofline to rank
-# fusion candidates against — assume a v5e and say so in the report
+# CPU-side HLO ranking only (the test mesh has no roofline of its own):
+# rank fusion candidates against an *assumed* v5e and say so in the
+# report. On a tpu backend there is no assumption — a device kind the
+# peaks table (monitor/step.py) does not know is an error.
 ASSUMED_KIND = "TPU v5e"
 
 
 def roofline_ceilings(device_kind=None):
-    """Flops + HBM-bandwidth ceilings for ``device_kind`` (default: the
-    local device, then $PADDLE_TPU_ROOFLINE_DEVICE, then an *assumed*
-    v5e so CPU-side profiling still ranks). $PADDLE_TPU_FLOPS_CEILING
-    (flops/s) and $PADDLE_TPU_HBM_GBPS override the tables."""
+    """Flops + HBM-bandwidth ceilings for ``device_kind`` (default:
+    $PADDLE_TPU_ROOFLINE_DEVICE, then the local device). Off-TPU an
+    unknown kind ranks against an *assumed*, labelled v5e; on a tpu
+    backend it raises. $PADDLE_TPU_FLOPS_CEILING (flops/s) and
+    $PADDLE_TPU_HBM_GBPS override the tables."""
     from . import step as _step
+    from ..device import is_tpu_backend
     kind = device_kind or os.environ.get("PADDLE_TPU_ROOFLINE_DEVICE")
     if not kind:
-        try:
-            import jax
-            kind = str(getattr(jax.local_devices()[0], "device_kind", ""))
-        except Exception:
-            kind = ""
+        kind = jax.local_devices()[0].device_kind
     kind = str(kind)
     flops, bw = _step.ceilings_for_kind(kind)
     assumed = False
+    if (flops is None or bw is None) and is_tpu_backend():
+        raise ValueError(
+            f"device kind {kind!r} is not in the peaks table "
+            f"(paddle_tpu/monitor/step.py); add it with its source "
+            f"instead of assuming another chip's roofline")
     if flops is None or bw is None:
         a_flops, a_bw = _step.ceilings_for_kind(ASSUMED_KIND)
         if flops is None:
@@ -344,6 +390,25 @@ def _parse_instr(line):
     }
 
 
+_BARE_OPERAND_RE = re.compile(r"^%?([\w.\-]+)$")
+
+
+def _type_bare_operands(instrs):
+    """The XLA of jaxlib 0.9 prints an operand as a bare ``%name``; older
+    text carried its type (``f32[8,16]{1,0} %name``), which the flop and
+    byte models read. Put the defining instruction's type back in front
+    of every bare operand, so one form reaches the rest of the parser."""
+    types = {i["name"]: i["out_type"] for i in instrs}
+    for instr in instrs:
+        if instr["opcode"] == "parameter":
+            continue        # its "operand" is the parameter number
+        ops = instr["operands"]
+        for k, op in enumerate(ops):
+            m = _BARE_OPERAND_RE.match(op.strip())
+            if m and m.group(1) in types:
+                ops[k] = f"{types[m.group(1)]} %{m.group(1)}"
+
+
 def parse_hlo(text):
     """Parse optimized HLO text into ``{computation_name: {"entry": bool,
     "instrs": [...]}}`` plus reference sets. Returns (comps, entry_name,
@@ -372,6 +437,8 @@ def parse_hlo(text):
         instr = _parse_instr(line)
         if instr is not None:
             comps[cur]["instrs"].append(instr)
+    for comp in comps.values():
+        _type_bare_operands(comp["instrs"])
     refs = {"to_apply": set(), "calls": set(), "inline": set()}
     for comp in comps.values():
         for instr in comp["instrs"]:

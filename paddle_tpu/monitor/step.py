@@ -5,8 +5,7 @@ step time, items/sec (tokens or images), device memory stats
 (``jax.local_devices()[i].memory_stats()``), and MFU against a
 configurable flops ceiling. Each step emits a JSONL ``step`` record
 through the monitor sink, and ``report()`` prints a summary table plus a
-final ``counters`` snapshot event — the round's perf ledger rows
-(docs/PERF_LEDGER.md) are built from exactly these records.
+final ``counters`` snapshot event.
 
 MFU here is the standard model-flops utilization: model flops per step
 (NOT hardware flops — rematerialization and padding don't count) divided

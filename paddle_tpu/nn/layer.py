@@ -320,21 +320,20 @@ class Layer:
             if out is not NotImplemented:
                 return out
         if _profile.scopes_on:
-            with jax.named_scope(_profile.layer_scope(self)):
+            with _profile.scope(_profile.layer_scope(self)):
                 return self._run_forward(args, kwargs)
         return self._run_forward(args, kwargs)
 
     def _run_forward(self, args, kwargs):
         from .. import tensor as _ptensor
-        if _ptensor._arena_hook is not None and \
-                jax.core.trace_state_clean():
+        if _ptensor._arena_hook is not None:
             # an EAGER forward is a read boundary for flat-arena params:
             # compiled steps leave leaf views stale on purpose (the flat
             # buffer is the carried state), so settle them before eager
             # math reads the payloads. Inside a trace the views are
             # bound by jit.py and must not be touched.
-            from ..optimizer.arena import flush
-            flush()
+            from ..optimizer.arena import flush_eager
+            flush_eager(self, args, kwargs)
         for hook in self._forward_pre_hooks.values():
             res = hook(self, args)
             if res is not None:

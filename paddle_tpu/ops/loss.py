@@ -30,7 +30,7 @@ def _pscope(name):
     """named_scope(F.<name>) when profiling is armed, else a no-op —
     one flag check, so the disabled path stays free."""
     if _profile.scopes_on:
-        return jax.named_scope(_profile.fscope(name))
+        return _profile.scope(_profile.fscope(name))
     return nullcontext()
 
 

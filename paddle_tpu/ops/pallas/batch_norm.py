@@ -22,7 +22,7 @@ registers instead of round-tripping f32 copies through HBM (the
 `convert_reduce_fusion` cost the ResNet-50 trace showed at ~8 ms/step).
 
 Default-OFF (`pallas.configure(batch_norm=True)` opts in): the fused_adam
-lesson (13.6% LOSS vs XLA's own fusion, docs/perf_r04.md) is that
+lesson (13.6% LOSS vs XLA's own fusion, docs/performance.md) is that
 hand-written kernels must beat the compiler on the chip before they ride
 the default path; scripts/bench_pallas_bn.py measures exactly that when
 a chip window is available.

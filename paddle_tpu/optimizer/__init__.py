@@ -283,9 +283,8 @@ class Optimizer:
             arena = self._ensure_arena()
             # offload is an EAGER-path mechanism (the split step runs
             # the apply outside jit); inside a trace the transfers would
-            # clobber tracers, so the hooks are gated on a clean trace
-            offload = (self._offloader is not None
-                       and jax.core.trace_state_clean())
+            # clobber tracers, so the hooks are gated on concrete buffers
+            offload = self._offloader is not None and not arena.traced
             if offload:
                 # wait for the H2D prefetch and rebind the moments
                 # before the fused apply reads them
@@ -298,7 +297,7 @@ class Optimizer:
                 self._post_step()
                 return
             if _monitor.profile.scopes_on:
-                with jax.named_scope(
+                with _monitor.profile.scope(
                         _monitor.profile.optimizer_scope(self)):
                     self._arena_apply(arena, packed, lr)
             else:
@@ -311,7 +310,8 @@ class Optimizer:
             self._post_step()
             return
         if _monitor.profile.scopes_on:
-            with jax.named_scope(_monitor.profile.optimizer_scope(self)):
+            with _monitor.profile.scope(
+                    _monitor.profile.optimizer_scope(self)):
                 return self._apply_update_body(params_grads, lr)
         return self._apply_update_body(params_grads, lr)
 

@@ -95,6 +95,23 @@ def _maybe_uninstall():
         _ptensor._arena_hook = None
 
 
+def flush_eager(layer, args, kwargs):
+    """:func:`flush` at an eager ``Layer`` call — and nothing inside a
+    trace, where the views are bound by jit.py and must not be touched.
+    A trace shows in the payloads the forward is about to read: a
+    carried arena's flat buffer, the layer's own parameters or its
+    array arguments are ``jax.core.Tracer``s there."""
+    if not (_STALE or _DIRTY):
+        return
+    if any(a.traced for a in _ALL):
+        return
+    payloads = [p.data for p in layer._parameters.values() if p is not None]
+    payloads += jax.tree_util.tree_leaves((args, kwargs))
+    if any(_is_tracer(x) for x in payloads):
+        return
+    flush()
+
+
 def flush(exclude=()):
     """Settle all pending coherence work: repack leaf-dirty arenas
     (restored checkpoints) and sync stale leaves, except arenas in
@@ -281,6 +298,11 @@ class ParamArena:
                 p.data = v
         return saved
 
+    @property
+    def traced(self):
+        """True while a trace has bound the flat buffers (jit.py)."""
+        return any(_is_tracer(grp.flat.data) for grp in self.groups)
+
     def unbind_views(self, saved):
         for p, data in saved.values():
             p.data = data
@@ -288,7 +310,7 @@ class ParamArena:
     def sync_leaves(self):
         """Materialise every leaf view from the flat buffer (the lazy
         re-scatter paid only at read boundaries, never per step)."""
-        if any(_is_tracer(grp.flat.data) for grp in self.groups):
+        if self.traced:
             self.bind_views(resave=False)
             return
         for grp in self.groups:
@@ -330,7 +352,7 @@ class ParamArena:
         consistent); eagerly, refresh the leaves now — eager mode has no
         write-back boundary to defer to."""
         self._pow_restore_seen.clear()
-        if any(_is_tracer(grp.flat.data) for grp in self.groups):
+        if self.traced:
             self.bind_views(resave=False)
         else:
             self.sync_leaves()

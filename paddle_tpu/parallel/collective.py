@@ -83,29 +83,7 @@ def shard(x, spec, mesh=None):
 # SPMD-region detection: collectives need an axis name bound by
 # shard_map/pmap; in plain eager (or plain jit) they act as identity.
 
-def axis_size(axis_name):
-    """lax.axis_size(axis_name) across jax versions. Older jax has no
-    lax.axis_size; psum of the literal 1 folds statically to the axis
-    size inside any SPMD region and raises NameError outside — exactly
-    the contract callers (and in_spmd_context) need."""
-    fn = getattr(lax, "axis_size", None)
-    if fn is not None:
-        return fn(axis_name)
-    return lax.psum(1, axis_name)
-
-
-def shard_map_compat(f, mesh, in_specs, out_specs, check_vma=None):
-    """jax.shard_map across jax versions: older jax only ships
-    jax.experimental.shard_map.shard_map, whose replication-check kwarg
-    is spelled check_rep rather than check_vma."""
-    fn = getattr(jax, "shard_map", None)
-    if fn is not None:
-        kw = {} if check_vma is None else {"check_vma": check_vma}
-        return fn(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  **kw)
-    from jax.experimental.shard_map import shard_map as _sm
-    kw = {} if check_vma is None else {"check_rep": check_vma}
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
+axis_size = lax.axis_size   # static inside an SPMD region, NameError out
 
 
 def in_spmd_context(axis_name=None):

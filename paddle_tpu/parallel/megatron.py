@@ -536,12 +536,11 @@ def _build_flat_train_step(cfg: MegatronConfig, mesh: Mesh, params):
                  "t": t}, loss)
 
     token_spec = P(None, "dp", "sp")
-    from .collective import shard_map_compat
     jstep = jax.jit(
-        shard_map_compat(device_fn, mesh=mesh,
-                         in_specs=(state_spec, token_spec),
-                         out_specs=(state_spec, P()),
-                         check_vma=False),
+        jax.shard_map(device_fn, mesh=mesh,
+                      in_specs=(state_spec, token_spec),
+                      out_specs=(state_spec, P()),
+                      check_vma=False),
         donate_argnums=(0,))
 
     def step(state, tokens):
@@ -688,9 +687,8 @@ def build_train_step(cfg: MegatronConfig, mesh: Mesh):
     # tokens: [n_micro, batch, seq]: batch over dp, seq over sp
     token_spec = P(None, "dp", "sp")
 
-    from .collective import shard_map_compat
     step = jax.jit(
-        shard_map_compat(
+        jax.shard_map(
             device_fn, mesh=mesh,
             in_specs=(state_spec, token_spec),
             out_specs=(state_spec, P()),

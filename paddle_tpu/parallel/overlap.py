@@ -60,8 +60,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from .collective import (all_reduce_quantized, axis_size, get_mesh,
-                         shard_map_compat)
+from .collective import all_reduce_quantized, axis_size, get_mesh
 from ..io.bucketing import next_bucket
 from .. import monitor as _monitor
 from ..monitor import trace as _trace
@@ -240,8 +239,8 @@ def local_value_and_grad(loss_fn, mesh=None, axis_name="dp"):
 
     if mesh is None:
         return _local
-    sm = shard_map_compat(
-        _local, mesh,
+    sm = jax.shard_map(
+        _local, mesh=mesh,
         in_specs=(P(), P(axis_name)),
         out_specs=(P(axis_name), P(axis_name)),
         check_vma=False)
@@ -371,8 +370,8 @@ class GradSyncScheduler:
                 flat = jnp.pad(flat, (0, padded - total))
                 return _unpack(_reduce_flat(flat, axis, wire, bits, op))
 
-            fn = jax.jit(shard_map_compat(
-                device_fn, mesh,
+            fn = jax.jit(jax.shard_map(
+                device_fn, mesh=mesh,
                 in_specs=P(self.axis_name),
                 out_specs=P(),
                 check_vma=False))

@@ -53,7 +53,9 @@ class PipelineStack(Layer):
         # remat: jax.checkpoint each stage inside the scan (recompute
         # activations during backward — the fleet recompute strategy
         # applied to the stacked trunk)
-        self._remat = bool(remat)
+        # not `_remat`: that name is nn.Layer's memory-plan policy slot,
+        # which memory_plan's process-wide layer hook reads
+        self._stage_remat = bool(remat)
         self._template = blocks[0]
         # template params are NOT trainable on their own — exclude the
         # template from registration (its holders get swapped per step)
@@ -119,7 +121,7 @@ class PipelineStack(Layer):
                         holders[name].data = v
                 return out, auxs
 
-            if self._remat:
+            if self._stage_remat:
                 stage_call = jax.checkpoint(stage_call)
 
             def body(carry, slices):

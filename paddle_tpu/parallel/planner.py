@@ -61,7 +61,7 @@ __all__ = [
     "MeshPlan", "MEGATRON_RULES", "TRANSFORMER_RULES", "resolve",
     "candidate_sizes", "megatron_candidate_stats", "stats_from_profile",
     "score", "advise", "plan", "measure_link_bandwidth",
-    "link_bandwidth", "last_decision",
+    "link_bandwidth", "link_is_assumed", "last_decision",
 ]
 
 
@@ -373,20 +373,25 @@ def candidate_sizes(n_devices, axes=("dp", "tp")):
 
 def link_bandwidth(link_gbps=None, ceilings=None):
     """Interconnect bandwidth (bytes/s) for the comm model. Priority:
-    explicit arg → PADDLE_TPU_LINK_GBPS env → device-kind default
-    (TPU ICI ~90 GB/s; CPU 'links' are host memcpys, ~8 GB/s)."""
+    explicit arg → PADDLE_TPU_LINK_GBPS env → an ASSUMED per-platform
+    constant (90 GB/s for anything called ``tpu``, 8 GB/s for the CPU
+    mesh's host memcpys) that no run has measured —
+    :func:`link_is_assumed` says which, and every report of a number
+    priced with it carries that flag. :func:`measure_link_bandwidth`
+    gives the measured figure."""
     import os
     if link_gbps is not None:
         return float(link_gbps) * 1e9
     env = os.environ.get("PADDLE_TPU_LINK_GBPS")
     if env:
         return float(env) * 1e9
-    plat = None
-    try:
-        plat = jax.devices()[0].platform
-    except Exception:
-        pass
-    return 90e9 if plat == "tpu" else 8e9
+    return 90e9 if jax.devices()[0].platform == "tpu" else 8e9
+
+
+def link_is_assumed(link_gbps=None):
+    """True when :func:`link_bandwidth` falls to its assumed constant."""
+    import os
+    return link_gbps is None and not os.environ.get("PADDLE_TPU_LINK_GBPS")
 
 
 def measure_link_bandwidth(mesh, axis, n_elems=1 << 22, repeats=3):
@@ -397,7 +402,6 @@ def measure_link_bandwidth(mesh, axis, n_elems=1 << 22, repeats=3):
     n = sizes.get(axis, 1)
     if n <= 1:
         return None
-    from .collective import shard_map_compat
     spec = P(axis)
     x = jax.device_put(np.ones((n_elems,), "f4"),
                        NamedSharding(mesh, spec))
@@ -406,8 +410,8 @@ def measure_link_bandwidth(mesh, axis, n_elems=1 << 22, repeats=3):
         from jax import lax
         return lax.psum(v, axis)
 
-    f = jax.jit(shard_map_compat(dev, mesh=mesh, in_specs=(spec,),
-                                 out_specs=spec, check_vma=False))
+    f = jax.jit(jax.shard_map(dev, mesh=mesh, in_specs=(spec,),
+                              out_specs=spec, check_vma=False))
     f(x).block_until_ready()  # compile
     best = float("inf")
     for _ in range(max(1, repeats)):
@@ -446,6 +450,8 @@ def score(stats, ceilings=None, link_gbps=None):
         "pred_step_s": roof + comm_s,
         "bound": ("comm" if comm_s > roof else
                   "compute" if compute_s >= hbm_s else "memory"),
+        "link_bytes_per_s": link,
+        "link_assumed": link_is_assumed(link_gbps),
     }
 
 

@@ -1,13 +1,13 @@
 """paddle_tpu.resilience.watchdog — hung-step detection.
 
 A deadlocked collective, a stuck host callback, or an input pipeline
-wedge all look the same from outside: the step just never ends. The
+hang all look the same from outside: the step just never ends. The
 watchdog is a daemon thread that knows when each step started and flags
 any step exceeding a rolling deadline — ``factor`` × the p99 of recent
 step times once enough history exists, never below ``min_deadline``.
 On a stall it emits ``resilience.watchdog_stall`` plus a one-shot
 monitor state dump (every counter/gauge, so the post-mortem shows what
-the run was doing when it wedged) and calls the optional ``on_stall``
+the run was doing when it stuck) and calls the optional ``on_stall``
 hook. It never kills the step itself — detection and evidence, not
 preemption.
 

@@ -218,7 +218,7 @@ class DynamicBatcher:
         ``drain=True`` (default) queued requests are flushed first;
         anything still queued afterwards (``drain=False``, or no thread
         ever started) fails with RuntimeError. If the drain thread is
-        wedged inside ``process`` (a hung replica) the join times out
+        stuck inside ``process`` (a hung replica) the join times out
         and the *dispatched* group's unresolved futures fail too — a
         future is never silently lost, even when its executor never
         comes back. Disowned in-flight requests (failover took them)

@@ -353,7 +353,7 @@ class PrefillEngine:
 
     def probe(self, timeout_s=1.0):
         """Half-open test traffic: one smallest-bucket prefill on a
-        side thread (the worker may be the wedged thing)."""
+        side thread (the worker may be the stuck thing)."""
         import jax.numpy as jnp
         lb = self.prompt_buckets[0]
         if ("prefill", lb) not in self._exec:
@@ -391,7 +391,7 @@ class PrefillEngine:
         """Failover: hand the in-flight request (if any) to the caller.
         Prefill is a pure function of the prompt — the adopting replica
         re-runs it and produces an identical segment; if this replica's
-        wedged dispatch ever completes, the ownership check in
+        stuck dispatch ever completes, the ownership check in
         :meth:`_process` discards its result. ``export_kv`` is accepted
         for surface parity (nothing is resident here to export)."""
         with self._lock:
@@ -698,7 +698,7 @@ class DisaggServer:
                  tokens_floor=None, prefill_initial_active=None,
                  decode_initial_active=None):
         import jax
-        from ..parallel.planner import link_bandwidth
+        from ..parallel.planner import link_bandwidth, link_is_assumed
         devs = jax.local_devices()
         if prefill_devices is None:
             prefill_devices = [devs[i % len(devs)]
@@ -714,6 +714,7 @@ class DisaggServer:
         self.spec = model.kv_spec()
         self._kv_per_token = bytes_per_token(self.spec)
         self._link_bw = link_bandwidth(link_gbps)   # bytes/s
+        self._link_assumed = link_is_assumed(link_gbps)
         self.prefix = (PrefixCache(self.spec,
                                    budget_bytes=prefix_budget_bytes)
                        if prefix_cache else None)
@@ -888,6 +889,7 @@ class DisaggServer:
             "handoff_bytes": handoff_bytes,
             "kv_bytes_per_token": self._kv_per_token,
             "link_bandwidth_bps": self._link_bw,
+            "link_bandwidth_assumed": self._link_assumed,
         }
         if self.prefix is not None:
             out["prefix"] = self.prefix.stats()

@@ -7,7 +7,10 @@ inputs along the batch axis, pad to the next ``io.bucketing`` bucket
 (repeat-mode, so pad rows stay in-distribution), run the wrapped
 ``Predictor`` on a pre-compiled bucket shape, slice every request's
 rows back out, and resolve its future with host numpy outputs
-(bit-identical to what ``Predictor.run`` on the lone request returns).
+(bit-identical to ``Predictor.run`` on the lone request at the same
+padded shape — which requests share the flush never shows; against
+an UNPADDED run, XLA may pick another kernel for the other shape and
+the last ulp can differ).
 
 :meth:`warmup` AOT-compiles every (bucket, signature) pair up front via
 ``Predictor.warmup`` — ``lower().compile()`` over ShapeDtypeStructs,
@@ -327,7 +330,7 @@ class ServingEngine:
     def probe(self, timeout_s=1.0):
         """Half-open test traffic: replay a 1-row copy of real input
         through the full assemble→execute path on a side thread (the
-        drain thread may be wedged — that's exactly what we're probing)
+        drain thread may be stuck — that's exactly what we're probing)
         and report whether it finished in time. No future, no queue:
         the probe must not compete with, or be blocked by, real work."""
         template = self._probe_template
@@ -445,7 +448,7 @@ class ServingEngine:
         before = len(self.predictor._compiled)
         if _faults.enabled():
             # the chaos gate's injection site: replica_error raises,
-            # replica_hang/replica_slow stall right where a wedged
+            # replica_hang/replica_slow stall right where a stuck
             # device runtime would
             _faults.maybe_serving_fault(self.replica_id)
         with _monitor.trace.span("serving.execute",

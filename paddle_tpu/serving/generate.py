@@ -874,7 +874,7 @@ class GenerateEngine:
         """Half-open test traffic: run the decode executable (or, on a
         speculative engine, the verify executable) on an all-inactive
         batch on a side thread (the tick thread may be the thing that's
-        wedged) and report whether it finished in time."""
+        stuck) and report whether it finished in time."""
         import jax.numpy as jnp
         cap = self.pool.capacity
         kind = ("decode" if ("decode", cap) in self._exec
@@ -1169,17 +1169,20 @@ class GenerateEngine:
                 jnp.asarray([sp.seed or 0], jnp.uint32),
                 jnp.zeros((1,), jnp.int32))
             first = int(first[0])
-            self.pool.buffers = self._get_insert(bucket,
-                                                 self.pool.capacity)(
-                self.pool.buffers, kv, jnp.int32(s))
+            insert = self._get_insert(bucket, self.pool.capacity)
+            with self.pool.arena_lock:
+                self.pool.buffers = insert(self.pool.buffers, kv,
+                                           jnp.int32(s))
             self.pool.note_length(s, p)
             if self.draft_pool is not None:
                 dkv = self._get_draft_prefill(bucket)(
                     self._draft_state, jnp.asarray(tokens),
                     jnp.asarray([p], jnp.int32))
-                self.draft_pool.buffers = self._get_insert(
-                    bucket, self.draft_pool.capacity, kind="dinsert")(
-                    self.draft_pool.buffers, dkv, jnp.int32(s))
+                dinsert = self._get_insert(
+                    bucket, self.draft_pool.capacity, kind="dinsert")
+                with self.draft_pool.arena_lock:
+                    self.draft_pool.buffers = dinsert(
+                        self.draft_pool.buffers, dkv, jnp.int32(s))
                 self.draft_pool.note_length(s, p)
             ms = (time.monotonic() - t0) * 1e3
             metrics.record_prefill(p, ms, bucket)
@@ -1330,10 +1333,14 @@ class GenerateEngine:
                 _faults.maybe_serving_fault(self.replica_id)
             t0 = time.monotonic()
             fn = self._get_decode(self.pool.capacity)
-            nxt, new_bufs = fn(self.model.state, self.pool.buffers,
-                               jnp.asarray(tokens), jnp.asarray(lengths),
-                               jnp.asarray(active),
-                               *(jnp.asarray(a) for a in samp))
+            # the step DONATES the arena: dispatch and re-point
+            # pool.buffers under the arena lock, so a drain thread's
+            # export_slot never reads the consumed buffers
+            args = [jnp.asarray(a)
+                    for a in (tokens, lengths, active, *samp)]
+            with self.pool.arena_lock:
+                nxt, self.pool.buffers = fn(
+                    self.model.state, self.pool.buffers, *args)
             nxt = np.asarray(nxt)
             step_ms = (time.monotonic() - t0) * 1e3
         except BaseException as e:   # noqa: BLE001 - fail the wave
@@ -1341,7 +1348,6 @@ class GenerateEngine:
             self._fail_active(assigned, e)
             return True
         self._note_outcome(True)
-        self.pool.buffers = new_bufs
         finished = []
         with self._lock:
             n_active = 0
@@ -1421,17 +1427,21 @@ class GenerateEngine:
             tok_dev = jnp.asarray(tokens)
             len_dev = jnp.asarray(lengths)
             act_dev = jnp.asarray(active)
-            ds, qs, dbufs = self._get_spec_draft(cap)(
-                self._draft_state, self.draft_pool.buffers,
-                tok_dev, len_dev, act_dev, *samp_dev)
-            # settle the draft arena BEFORE verify can raise: the scan
-            # donated (consumed) the old buffers, so the pool must point
-            # at the new ones even if this tick's wave fails
-            self.draft_pool.buffers = dbufs
+            # both executables DONATE their arena: each dispatch and the
+            # re-pointing of pool.buffers happen under that pool's arena
+            # lock (see _decode_once), so the pools always point at live
+            # buffers — also when a later call of this tick raises
+            draft, verify = self._get_spec_draft(cap), \
+                self._get_verify(cap)
+            with self.draft_pool.arena_lock:
+                ds, qs, self.draft_pool.buffers = draft(
+                    self._draft_state, self.draft_pool.buffers,
+                    tok_dev, len_dev, act_dev, *samp_dev)
             chunk = jnp.concatenate([tok_dev[:, None], ds], axis=1)
-            a, resampled, new_bufs = self._get_verify(cap)(
-                self.model.state, self.pool.buffers, chunk, len_dev,
-                act_dev, *samp_dev, ds, qs)
+            with self.pool.arena_lock:
+                a, resampled, self.pool.buffers = verify(
+                    self.model.state, self.pool.buffers, chunk, len_dev,
+                    act_dev, *samp_dev, ds, qs)
             a = np.asarray(a)
             resampled = np.asarray(resampled)
             ds_host = np.asarray(ds)
@@ -1441,7 +1451,6 @@ class GenerateEngine:
             self._fail_active(assigned, e)
             return True
         self._note_outcome(True)
-        self.pool.buffers = new_bufs
         finished = []
         emitted_total = 0
         accepted_total = 0

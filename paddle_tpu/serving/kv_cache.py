@@ -96,6 +96,12 @@ class KVCachePool:
             name: jnp.zeros((self.slots, self.capacity) + tail, dtype=dt)
             for name, tail, dt in self._leaf_list}
         self._lock = threading.Lock()
+        # owns ``buffers``: the engine's executables DONATE the arena,
+        # so whoever dispatches one holds this lock from the call until
+        # ``buffers`` points at the result, and every other thread that
+        # reads the arena (export_slot from a drain thread) takes it —
+        # a reader never sees a donated (deleted) array
+        self.arena_lock = threading.RLock()
         self._free = list(range(self.slots))[::-1]   # pop() -> slot 0 first
         # per-slot live length: how many leading arena positions hold
         # *accepted* history. Readers mask by it; rollback() shrinks it.
@@ -205,8 +211,12 @@ class KVCachePool:
             raise ValueError(
                 f"export pad {pad} exceeds arena capacity "
                 f"{self.capacity}")
-        leaves = {name: np.asarray(self.buffers[name][slot, :pad])
-                  for name, _tail, _dt in self._leaf_list}
+        # only the slicing needs the arena: the slices are new arrays no
+        # executable donates, so the device wait happens off the lock
+        with self.arena_lock:
+            leaves = {name: self.buffers[name][slot, :pad]
+                      for name, _tail, _dt in self._leaf_list}
+        leaves = {name: np.asarray(a) for name, a in leaves.items()}
         seg_bytes = sum(int(a.nbytes) for a in leaves.values())
         expected = bytes_per_token(self.spec) * pad
         if seg_bytes != expected:
@@ -252,19 +262,20 @@ class KVCachePool:
             raise AssertionError(
                 f"import_slot byte accounting drifted: segment holds "
                 f"{seg_bytes} bytes, spec arithmetic says {expected}")
-        before = self.allocated_bytes()
-        if insert_fn is not None:
-            chunk = {name: jnp.asarray(np.asarray(leaves[name])[None])
-                     for name, _t, _d in self._leaf_list}
-            self.buffers = insert_fn(self.buffers, chunk,
-                                     jnp.int32(slot))
-        else:
-            for name, tail, _dt in self._leaf_list:
-                start = (slot, 0) + (0,) * len(tail)
-                self.buffers[name] = jax.lax.dynamic_update_slice(
-                    self.buffers[name], jnp.asarray(leaves[name])[None],
-                    start)
-        after = self.allocated_bytes()
+        with self.arena_lock:
+            before = self.allocated_bytes()
+            if insert_fn is not None:
+                chunk = {name: jnp.asarray(np.asarray(leaves[name])[None])
+                         for name, _t, _d in self._leaf_list}
+                self.buffers = insert_fn(self.buffers, chunk,
+                                         jnp.int32(slot))
+            else:
+                for name, tail, _dt in self._leaf_list:
+                    start = (slot, 0) + (0,) * len(tail)
+                    self.buffers[name] = jax.lax.dynamic_update_slice(
+                        self.buffers[name],
+                        jnp.asarray(leaves[name])[None], start)
+            after = self.allocated_bytes()
         if after != before:
             raise AssertionError(
                 f"import_slot changed the arena footprint: "
@@ -302,8 +313,10 @@ class KVCachePool:
                 f"{self.seq_buckets}")
         if new_capacity <= self.capacity:
             return
-        self.buffers = grow_fn(self.buffers, self.capacity, new_capacity)
-        self.capacity = new_capacity
+        with self.arena_lock:
+            self.buffers = grow_fn(self.buffers, self.capacity,
+                                   new_capacity)
+            self.capacity = new_capacity
         self._grows += 1
         metrics.record_cache_grow(new_capacity)
         self._publish()

@@ -710,7 +710,7 @@ class MultiDeviceEngine:
         """Re-``replicate()`` state onto the replica's device, swap in a
         fresh engine (warmed with the remembered signatures), and close
         the old one in the background with a bounded join — its drain
-        thread may be wedged forever."""
+        thread may be stuck forever."""
         old_engine = replica.engine
         fresh_pred = self._replicate(self.predictor, [replica.device])[0]
         fresh = self._make_replica(replica.index, fresh_pred)

@@ -14,9 +14,9 @@ and resumes. Serving needs the same loop with different verbs, running
   per tick (a 1-row replay of real input on a side thread, see
   ``ServingEngine.probe``); success closes the breaker and the replica
   rejoins the rotation.
-* **restart** — a replica still wedged ``restart_after_s`` after its
+* **restart** — a replica still stuck ``restart_after_s`` after its
   hang verdict gets rebuilt: state re-``replicate()``d onto the device,
-  a fresh engine warmed and swapped in, the wedged one reaped in the
+  a fresh engine warmed and swapped in, the stuck one reaped in the
   background.
 * **scaling** — when the live ``slo.goodput`` window sags below the
   floor — or, for decode fleets, when the rolling ``slo.tokens_per_s``
@@ -209,7 +209,7 @@ class ServingSupervisor:
             self._decide("failover", replica=replica.index,
                          inflight_age_s=round(age, 3), moved=moved)
 
-        # restart: the same dispatch still wedged well past the verdict
+        # restart: the same dispatch still stuck well past the verdict
         if age is not None and age > self.restart_after_s \
                 and token != replica.restart_token:
             replica.restart_token = token

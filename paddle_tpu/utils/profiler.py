@@ -87,7 +87,7 @@ def summarize_trace(trace_dir, top=20, steps=1):
 
     This is the tool the round-4 ResNet diagnosis used to find batch
     norm's reduce chains at ~70% of step time while convs ran at peak
-    (docs/perf_r04.md)."""
+    (docs/performance.md)."""
     import collections
     import glob
     import gzip

@@ -1,6 +1,6 @@
 """ResNet-50 perf triage on the real chip: where does the step time go?
 
-Measurement rules for this environment (docs/perf_r04.md): repeated
+Measurement rules: repeated
 identical dispatches are served from cache and `block_until_ready` is
 not a real sync, so (a) the conv/matmul ceilings use a fori_loop
 dependency CHAIN with a scalar D2H at the end, and (b) the model rows
@@ -111,9 +111,8 @@ def train_step_rate(batch, data_format="NCHW", inner=8, trace_dir=None):
 
 
 def main():
-    jax.config.update("jax_compilation_cache_dir",
-                      "/tmp/paddle_tpu_xla_cache")
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+    from paddle_tpu.device import enable_compilation_cache
+    enable_compilation_cache()
     from paddle_tpu import monitor
     monitor.enable()          # in-memory counters + xla capture
     monitor.profile.enable()  # named scopes -> attributable step HLO

@@ -4,7 +4,7 @@ Closed-loop seeded Poisson decode load against a 3-replica
 :class:`MultiDecodeEngine` while a seeded chaos schedule mixes every
 lifecycle disturbance the stack claims to survive:
 
-* ``replica_hang``    — a replica wedges mid-step long enough to trip
+* ``replica_hang``    — a replica hangs mid-step long enough to trip
   the supervisor's hang failover
 * ``replica_slow``    — straggler injections
 * ``preempt_replica`` — the supervisor drains + migrates the replica,

@@ -358,3 +358,52 @@ def test_arena_layout_properties():
         assert bounds[0][0] == 0 and bounds[-1][1] == grp.total
         for (_, a1), (b0, _) in zip(bounds, bounds[1:]):
             assert a1 == b0  # contiguous, no gaps or overlap
+
+
+# -- the eager / trace boundary (the `trace_state_clean` class of break) ---
+
+def test_unrelated_layer_call_while_an_arena_is_alive():
+    """While ANY flat arena exists every Layer.__call__ in the process
+    asks "eager or tracing?" (tensor._arena_hook is armed). That
+    question must be answered with what the installed JAX offers — for
+    a layer that has nothing to do with the arena, eagerly and inside a
+    trace — and the owning file, not whichever test file shares a
+    worker with this one, is where a break shows."""
+    from paddle_tpu import tensor as ptensor
+    model, _ = _pair()
+    o = opt.Adam(learning_rate=1e-2, parameters=model.parameters(),
+                 flat_arena=True)
+    xs, ys = _data(2)
+    _train(model, o, xs, ys, compiled=True)     # arena built, leaves stale
+    assert ptensor._arena_hook is not None
+
+    pt.seed(3)
+    other = nn.Sequential(nn.Linear(16, 8), nn.ReLU(), nn.Linear(8, 2))
+    x = pt.to_tensor(xs[0])
+    eager = np.asarray(other(x).numpy())                     # eager call
+    traced = jit.to_static(lambda t: other(t), models=[other],
+                           optimizers=[])(x)                 # in a trace
+    np.testing.assert_allclose(np.asarray(traced.numpy()), eager,
+                               rtol=1e-6, atol=1e-6)
+
+
+def test_eager_forward_after_compiled_steps_reads_fresh_params():
+    """An eager forward is a read boundary: after compiled steps left
+    the leaf views stale it must see the UPDATED parameters; inside the
+    next compiled step the same call must leave the bound views alone
+    (losses keep matching the per-leaf optimizer bit for bit)."""
+    flat_m, leaf_m = _pair()
+    xs, ys = _data(6)
+    of = opt.Adam(learning_rate=1e-2, parameters=flat_m.parameters(),
+                  flat_arena=True)
+    ol = opt.Adam(learning_rate=1e-2, parameters=leaf_m.parameters())
+    first = slice(0, 3)
+    assert _train(flat_m, of, xs[first], ys[first], compiled=True) == \
+        _train(leaf_m, ol, xs[first], ys[first], compiled=True)
+    x = pt.to_tensor(xs[0])
+    np.testing.assert_array_equal(np.asarray(flat_m(x).numpy()),
+                                  np.asarray(leaf_m(x).numpy()))
+    rest = slice(3, 6)
+    assert _train(flat_m, of, xs[rest], ys[rest], compiled=True) == \
+        _train(leaf_m, ol, xs[rest], ys[rest], compiled=True)
+    _assert_params_equal(flat_m, leaf_m)

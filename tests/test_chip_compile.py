@@ -1,0 +1,242 @@
+"""The kernels and serving steps of the main path, compiled for a TPU
+v5e that is described, not attached (the TPU compiler is installed on
+CPU-only machines). Nothing runs; what the chip's compiler would refuse
+— a misaligned slice, too much VMEM — fails here at no chip time.
+
+The topology is described inside a module-scoped fixture and every
+compile happens in the test's own process: only one process may load
+the TPU library, so nothing here may run at import or collection time.
+Code that asks ``jax.default_backend()`` sees the CPU in this process;
+the ``compiled_kernels`` fixture steers ``pallas.interpret_mode`` to the
+TPU answer for the duration of one test.
+"""
+import os
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+from paddle_tpu.ops import pallas as P
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:   # noqa: BLE001 - any failure to describe
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    assert topo.devices[0].device_kind == "TPU v5 lite"
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture()
+def compiled_kernels(monkeypatch):
+    monkeypatch.setattr(P, "interpret_mode", lambda: False)
+
+
+def _is_shape_dtype(x):
+    return (isinstance(x, tuple) and len(x) == 2
+            and isinstance(x[0], tuple)
+            and all(isinstance(n, int) for n in x[0])
+            and not isinstance(x[1], tuple))
+
+
+def _compile(fn, one_chip, *shapes):
+    """Lower ``fn`` at ``(shape, dtype)`` arguments placed on the
+    described chip and compile it; returns the tpu_custom_call count."""
+    args = jax.tree_util.tree_map(
+        lambda sd: jax.ShapeDtypeStruct(sd[0], sd[1], sharding=one_chip),
+        shapes, is_leaf=_is_shape_dtype)
+    compiled = jax.jit(fn).lower(*args).compile()
+    return compiled.as_text().count("tpu_custom_call")
+
+
+def _grad_sum(f, argnums=0):
+    return jax.grad(lambda *a: f(*a).astype(jnp.float32).sum(),
+                    argnums=argnums)
+
+
+# -- layer norm, the shapes bench.py's long-sequence stage reaches ---------
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_layer_norm_fwd_bwd(one_chip, compiled_kernels, dtype):
+    from paddle_tpu.ops.pallas.layer_norm import _layer_norm2
+    f = _grad_sum(lambda x, w, b: _layer_norm2(x, w, b, 1e-12),
+                  argnums=(0, 1, 2))
+    n = _compile(f, one_chip, ((8192, 768), dtype),
+                 ((768,), jnp.float32), ((768,), jnp.float32))
+    assert n == 2       # forward and backward kernels
+
+
+# -- flash attention at BERT-base head geometry ----------------------------
+
+def _flash_grad(seq, mask_shape=None, causal=False, dropout=0.0):
+    from paddle_tpu.ops.pallas.flash_attention import (_canon_mask, _flash,
+                                                       _mask_mode)
+    mode = _mask_mode(mask_shape, 1, 12, seq, seq)
+    assert mode != "fallback"
+
+    def f(q, k, v, seed, *mask):
+        m = _canon_mask(mask[0]) if mask else None
+        return _flash(q, k, v, m, mode, seed, causal, None, 512, 1024,
+                      dropout)
+
+    return _grad_sum(f, argnums=(0, 1, 2))
+
+
+@pytest.mark.parametrize("seq", [512, 2048])
+@pytest.mark.parametrize("dropout", [0.0, 0.1], ids=["nodrop", "drop"])
+def test_flash_fwd_bwd(one_chip, compiled_kernels, seq, dropout):
+    qkv = ((1, 12, seq, 64), jnp.bfloat16)
+    n = _compile(_flash_grad(seq, dropout=dropout), one_chip,
+                 qkv, qkv, qkv, ((2,), jnp.int32))
+    assert n == 3       # forward, dq, dk/dv
+
+
+def test_flash_bert_padding_mask(one_chip, compiled_kernels):
+    """BERT's additive [B, 1, 1, S] mask tiles as a 'key' mask — it must
+    reach the kernel, not the sdpa fallback."""
+    qkv = ((1, 12, 512, 64), jnp.bfloat16)
+    mask = (1, 1, 1, 512)
+    n = _compile(_flash_grad(512, mask_shape=mask, dropout=0.1), one_chip,
+                 qkv, qkv, qkv, ((2,), jnp.int32), (mask, jnp.float32))
+    assert n == 3
+
+
+def test_flash_causal(one_chip, compiled_kernels):
+    qkv = ((1, 12, 512, 64), jnp.bfloat16)
+    n = _compile(_flash_grad(512, causal=True), one_chip,
+                 qkv, qkv, qkv, ((2,), jnp.int32))
+    assert n == 3
+
+
+# -- the four kernels that ship off ----------------------------------------
+
+def test_fused_adam(one_chip, compiled_kernels):
+    from paddle_tpu.ops.pallas.fused_adam import fused_adam_update
+
+    def f(p, g, m, v):
+        return fused_adam_update(p, g, m, v, 1e-3, 0.9, 0.999)
+
+    t = ((2048, 768), jnp.float32)
+    assert _compile(f, one_chip, t, t, t, t) >= 1
+
+
+def test_fused_adam_multi(one_chip, compiled_kernels):
+    from paddle_tpu.ops.pallas.fused_adam import fused_adam_update_multi
+
+    def f(ps, gs, ms, vs):
+        return fused_adam_update_multi(ps, gs, ms, vs, 1e-3, 0.9, 0.999)
+
+    ts = [((512, 768), jnp.float32), ((768,), jnp.float32)]
+    assert _compile(f, one_chip, ts, ts, ts, ts) >= 1
+
+
+def test_batch_norm_fwd_bwd(one_chip, compiled_kernels):
+    """ResNet-50 stage-1 NHWC activations, flattened channels-last."""
+    from paddle_tpu.ops.pallas.batch_norm import _batch_norm2
+    f = _grad_sum(lambda x, w, b: _batch_norm2(x, w, b, 1e-5)[0])
+    n = _compile(f, one_chip, ((128 * 112 * 112, 64), jnp.bfloat16),
+                 ((64,), jnp.float32), ((64,), jnp.float32))
+    assert n >= 2
+
+
+def test_softmax_xent_fwd_bwd(one_chip, compiled_kernels):
+    """batch 64 x seq 128 rows over BERT's vocabulary."""
+    from paddle_tpu.ops.pallas.softmax_xent import _softmax_xent2
+    f = _grad_sum(_softmax_xent2)
+    n = _compile(f, one_chip, ((8192, 30522), jnp.float32),
+                 ((8192, 1), jnp.int32))
+    assert n >= 2
+
+
+# -- the decode server's two steps at the widest demo model ----------------
+
+@pytest.fixture(scope="module")
+def engine():
+    from paddle_tpu import serving
+    model = serving.demo_model(dim=256, heads=4, layers=2, max_len=512,
+                               seed=1)
+    eng = serving.GenerateEngine(model, slots=8, page=64, factor=2.0,
+                                 max_len=512, start=False)
+    yield eng
+    eng.close()
+
+
+def _shapes_of(tree):
+    return jax.tree_util.tree_map(lambda a: (tuple(a.shape), a.dtype), tree)
+
+
+def test_engine_decode_step(one_chip, engine):
+    cap = engine.pool.seq_buckets[-1]
+    s = engine.slots
+    arena = {name: ((s, cap) + tail, dt)
+             for name, tail, dt in engine.pool._leaf_list}
+    vec = lambda dt: ((s,), dt)     # noqa: E731
+    _compile(engine._get_decode(cap), one_chip,
+             _shapes_of(engine.model.state), arena, vec(jnp.int32),
+             vec(jnp.int32), vec(jnp.bool_), vec(jnp.float32),
+             vec(jnp.int32), vec(jnp.float32), vec(jnp.uint32),
+             vec(jnp.int32))
+
+
+def test_engine_prefill_holds_flash(one_chip, engine, compiled_kernels):
+    """The 512-token prompt bucket sits at flash's crossover: its
+    prefill is the causal kernel."""
+    bucket = engine.prompt_buckets[-1]
+    assert bucket == 512
+    one = lambda dt: ((1,), dt)     # noqa: E731
+    n = _compile(engine._get_prefill(bucket), one_chip,
+                 _shapes_of(engine.model.state), ((1, bucket), jnp.int32),
+                 one(jnp.int32), one(jnp.float32), one(jnp.int32),
+                 one(jnp.float32), one(jnp.uint32), one(jnp.int32))
+    assert n == engine.model.layers
+
+
+# -- GSPMD cannot partition a Mosaic kernel --------------------------------
+
+def test_auto_kernels_are_off_inside_a_gspmd_trace(compiled_kernels):
+    assert P.enabled("layer_norm")
+    with pytest.warns(UserWarning, match="cannot partition a Mosaic"):
+        with P.gspmd_trace(4):
+            assert not P.enabled("layer_norm")
+            assert not P.enabled("flash_attention", seq_len=2048)
+            P.configure(layer_norm=True)        # forcing still forces
+            try:
+                assert P.enabled("layer_norm")
+            finally:
+                P.configure(layer_norm=None)
+    assert P.enabled("layer_norm")
+
+
+def test_to_static_step_on_a_mesh_traces_without_kernels(compiled_kernels):
+    """A step whose state spans several devices (the Fleet path) must
+    not hold a kernel: with the TPU answer steered in, a traced
+    pallas_call would fail to lower on this CPU mesh — the step runs
+    because jit.to_static saw the span and traced the XLA path."""
+    import paddle_tpu as pt
+    from paddle_tpu import jit, nn
+    from paddle_tpu.parallel.fleet import DistributedStrategy, Fleet
+    pt.seed(0)
+    model = nn.Sequential(nn.Linear(16, 32), nn.LayerNorm(32),
+                          nn.Linear(32, 4))
+    fleet = Fleet()
+    st = DistributedStrategy()
+    st.mesh_shape = {"dp": 2, "tp": 2}
+    fleet.init(strategy=st, devices=jax.devices()[:4])
+    model = fleet.distributed_model(model)
+    x = fleet.shard_batch(pt.to_tensor(jnp.ones((8, 16), jnp.float32)))
+    with pytest.warns(UserWarning, match="spans 4 devices"):
+        out = jit.to_static(lambda t: model(t), models=[model],
+                            optimizers=[])(x)
+    assert out.shape == [8, 4] or tuple(out.shape) == (8, 4)

@@ -291,3 +291,55 @@ def test_disagg_reqtrace_stages(model):
         assert any(h["hop"] == "handoff" for h in rec["hops"])
     assert "prefill_ms" in miss
     assert "prefill_ms" not in hit              # a hit never prefills
+
+
+# -- use-after-donate: export racing a ticking engine -----------------------
+
+def test_export_races_ticking_engine_no_use_after_donate(model):
+    """The decode step DONATES the KV arena, and a drain thread exports
+    slots off that arena while the tick thread is mid-dispatch. Every
+    export must read live buffers: on a backend that honours donation
+    (jax 0.9 does, also on CPU — and every TPU always did) a reader that
+    catches ``pool.buffers`` between the donating call and its
+    re-pointing dies with "Array has been deleted". Many iterations,
+    because the window is a few microseconds per tick."""
+    import threading
+    import time
+    # one capacity bucket: the arena never grows, so a fixed export pad
+    # stays valid for the whole race
+    eng = GenerateEngine(model, slots=4, page=64, factor=2.0, max_len=64,
+                         prompt_buckets=(8, 32), kv_import=True)
+    eng.warmup()
+    stop = threading.Event()
+    errors = []
+
+    def feed():
+        # keep every slot busy for the whole race
+        k = 0
+        while not stop.is_set():
+            futs = [eng.submit([1 + (k + i) % 7, 2, 3], max_new_tokens=48)
+                    for i in range(4)]
+            k += 4
+            for f in futs:
+                try:
+                    f.result(timeout=60)
+                except Exception as e:  # noqa: BLE001
+                    errors.append(e)
+                    return
+
+    feeder = threading.Thread(target=feed, daemon=True)
+    feeder.start()
+    exports = 0
+    try:
+        deadline = time.monotonic() + 60
+        while exports < 1500 and time.monotonic() < deadline:
+            for s in range(eng.slots):
+                seg = eng.pool.export_slot(s, pad_to=64)
+                assert seg["pad"] == 64
+                exports += 1
+    finally:
+        stop.set()
+        feeder.join(timeout=60)
+        eng.close()
+    assert not errors, errors[:1]
+    assert exports >= 1500 and eng.stats()["ticks"] > 50

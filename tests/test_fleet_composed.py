@@ -107,7 +107,7 @@ def test_composed_bert_base_dp_pp_tp_adamw_recompute():
     st.recompute = True  # per-stage jax.checkpoint inside the pp scan
     fleet.init(strategy=st)
     model.bert.encoder = fleet.pipeline_stack(list(model.bert.encoder))
-    assert model.bert.encoder._remat
+    assert model.bert.encoder._stage_remat
     model = fleet.distributed_model(model)
 
     # trunk params stacked over pp AND column/row split over tp

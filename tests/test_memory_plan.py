@@ -173,7 +173,9 @@ def test_to_static_remat_marks_hlo(tmp_path):
     rep0 = memory.report(label="jit.step", emit_records=False)
     assert (rep["by_class"]["activation"]
             < rep0["by_class"]["activation"])
-    assert rep["attributed_frac"] >= rep0["attributed_frac"] - 1e-6
+    # (to a hundredth: which few unscoped bytes — the RNG key split —
+    # are live at the peak instruction is the compiler's schedule)
+    assert rep["attributed_frac"] >= rep0["attributed_frac"] - 0.01
 
 
 # -- fit(memory=): toggle + auto ----------------------------------------------

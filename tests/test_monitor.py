@@ -8,7 +8,7 @@ import numpy as np
 import pytest
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 import paddle_tpu as pt

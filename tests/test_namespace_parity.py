@@ -2,6 +2,8 @@
 optimizer/initializer/metrics/clip/dygraph.nn/backward resolves, and the
 newly added classes compute (reference: the corresponding fluid
 modules)."""
+import os
+
 import numpy as np
 import pytest
 
@@ -73,6 +75,12 @@ def _resolve(dotted):
     return None
 
 
+_needs_reference = pytest.mark.skipif(
+    not os.path.isdir("/root/reference/python/paddle"),
+    reason="the reference tree (/root/reference) is not mounted here")
+
+
+@_needs_reference
 def test_every_reference_fluid_all_name_resolves():
     """Mechanical sweep (VERDICT r4 task 5): for EVERY module under
     reference fluid/, fluid/dygraph/, and fluid/layers/, each __all__
@@ -144,6 +152,7 @@ def _reference_root_exports():
     return names
 
 
+@_needs_reference
 def test_every_reference_toplevel_all_name_resolves():
     """Same mechanical sweep over the NON-fluid reference tree
     (python/paddle/**: tensor/, nn/, dataset/, reader/, distributed/,

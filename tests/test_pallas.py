@@ -404,7 +404,7 @@ def test_pallas_configure_overrides():
         P.configure(layer_norm=None, fused_adam=None)
         # None restores the measured auto defaults: layer_norm is
         # auto-on on TPU, fused_adam auto-off everywhere (it loses to
-        # XLA's own update fusion — docs/perf_r04.md)
+        # XLA's own update fusion — docs/performance.md)
         assert P.enabled("layer_norm") == P.on_tpu()
         assert P.enabled("fused_adam") is False
 

@@ -410,13 +410,36 @@ def test_hapi_fit_bucket_and_prefetch(mon):
 # ---------------------------------------------------------------------------
 # persistent compilation cache
 
-def test_enable_compilation_cache(tmp_path):
+@pytest.mark.parametrize("env_set", [False, True],
+                         ids=["env-unset", "env-set"])
+def test_enable_compilation_cache(tmp_path, monkeypatch, env_set):
+    """One rule: JAX_COMPILATION_CACHE_DIR wins and no other directory
+    is set in code; unset, the cache is one fixed directory inside the
+    checkout."""
+    import os
+    from paddle_tpu import device
     old = jax.config.jax_compilation_cache_dir
+    old_min = jax.config.jax_persistent_cache_min_compile_time_secs
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert device._CACHE_DIR_DEFAULT == os.path.join(repo, ".jax_cache")
     try:
-        p = pt.enable_compilation_cache(str(tmp_path / "xla"))
-        assert p == str(tmp_path / "xla")
-        import os
-        assert os.path.isdir(p)
-        assert jax.config.jax_compilation_cache_dir == p
+        if env_set:
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR",
+                               str(tmp_path / "outside"))
+            jax.config.update("jax_compilation_cache_dir", "sentinel")
+            assert pt.enable_compilation_cache() == \
+                str(tmp_path / "outside")
+            # JAX reads the variable itself; the code set nothing
+            assert jax.config.jax_compilation_cache_dir == "sentinel"
+            assert not os.path.exists(tmp_path / "outside")
+        else:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+            monkeypatch.setattr(device, "_CACHE_DIR_DEFAULT",
+                                str(tmp_path / ".jax_cache"))
+            p = pt.enable_compilation_cache()
+            assert p == str(tmp_path / ".jax_cache") and os.path.isdir(p)
+            assert jax.config.jax_compilation_cache_dir == p
     finally:
         jax.config.update("jax_compilation_cache_dir", old)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                          old_min)

@@ -177,11 +177,11 @@ def test_close_nodrain_resolves_dispatched_future():
             break
         time.sleep(0.005)
     assert b.inflight_token() is not None
-    b.close(drain=False, timeout=0.2)   # bounded join, thread is wedged
+    b.close(drain=False, timeout=0.2)   # bounded join, thread is stuck
     assert r.future.done()
     with pytest.raises(RuntimeError, match="still dispatched"):
         r.future.result()
-    release.set()                       # let the wedged thread exit
+    release.set()                       # let the stuck thread exit
 
 
 def test_close_nodrain_leaves_disowned_inflight_alone():

@@ -450,13 +450,13 @@ def test_sentinel_lower_is_better_latency(sentinel):
 def test_sentinel_outage_skipped_not_failed(sentinel):
     rows = sentinel.compare(
         {"value": 0.0, "resnet50_images_per_sec": 0.0,
-         "error": "backend init failed: tunnel wedged"}, BASE)
+         "error": "backend init failed: no device"}, BASE)
     assert all(r["verdict"] == "outage" for r in rows
                if r["candidate"] is not None)
 
 
 def test_sentinel_silent_zero_is_regression(sentinel):
-    # zero WITHOUT an error field is slow code, not a dead tunnel
+    # zero WITHOUT an error field is slow code, not a dead run
     rows = sentinel.compare({"value": 0.0}, BASE)
     v = {r["metric"]: r["verdict"] for r in rows}
     assert v["bert_tokens_per_sec"] == "regression"
@@ -479,7 +479,7 @@ def test_sentinel_end_to_end_repo_layout(sentinel, tmp_path):
                        "resnet50_images_per_sec": 1500.0}})
     _write(os.path.join(root, "BENCH_r02.json"),
            {"n": 2, "cmd": "python bench.py", "rc": 1, "tail": "",
-            "parsed": {"value": 0.0, "error": "tunnel wedged",
+            "parsed": {"value": 0.0, "error": "no device",
                        "last_committed_measurement": BASE,
                        "last_committed_measurement_file":
                            "docs/bench_r04_measured.json"}})
@@ -517,5 +517,10 @@ def test_sentinel_baseline_discovery_prefers_banked(sentinel, tmp_path):
     assert "BENCH_r01.json" in src
 
 
-def test_sentinel_no_data_is_clean(sentinel, tmp_path):
-    assert sentinel.main(["--repo-root", str(tmp_path)]) == 0
+def test_sentinel_no_records_is_a_failure(sentinel, tmp_path, capsys):
+    """Nothing to compare is not a pass — and that is what the
+    repository root holds until the benchmark PR banks a record."""
+    assert sentinel.main(["--repo-root", str(tmp_path)]) == 1
+    out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert out["ok"] is False and "no records" in out["note"]
+    assert sentinel.main(["--repo-root", _ROOT]) == 1
