@@ -1,0 +1,8 @@
+"""python -m pytest benchmark/tests -q   (not part of the repo's tier-1 run)"""
+import os
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(
+    __file__))))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
