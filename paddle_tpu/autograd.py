@@ -143,49 +143,53 @@ def backward(root: Tensor, grad_tensor=None, retain_graph=False, _only=None):
             return
         t._grad = ct if t._grad is None else t._grad + ct
 
-    for node in nodes:
-        outs_ct = []
-        any_ct = False
-        for o in node.outputs:
-            ct = cotangents.pop(id(o), None)
-            holders.pop(id(o), None)
-            if ct is None:
-                ct = _zero_cotangent(o.data)
-            else:
-                ct = _clip_err(o, ct)
-                any_ct = True
-                _accumulate_grad(o, ct)
-            outs_ct.append(ct)
-        if not any_ct:
-            continue
-        if node.vjp is None:
-            raise RuntimeError(
-                "Trying to backward through a graph that has been freed "
-                f"(op '{node.name}'). Call backward(retain_graph=True) on "
-                "the first backward if you need to backward twice.")
-        cts = tuple(outs_ct) if len(outs_ct) > 1 else outs_ct[0]
-        if node.scope:
-            with _profile.reenter(node.scope):
-                in_grads = node.vjp(cts)
-        else:
-            in_grads = node.vjp(cts)
-        for t, g in zip(node.inputs, in_grads):
-            if g is None or (hasattr(g, "dtype") and g.dtype == float0):
+    # the sweep is the step's backward phase: under tracing (or an armed
+    # profile) its ops carry the scope "bwd" in front of their layer's
+    with (_profile.backward_scope() if _profile.live and _profile.armed()
+          else contextlib.nullcontext()):
+        for node in nodes:
+            outs_ct = []
+            any_ct = False
+            for o in node.outputs:
+                ct = cotangents.pop(id(o), None)
+                holders.pop(id(o), None)
+                if ct is None:
+                    ct = _zero_cotangent(o.data)
+                else:
+                    ct = _clip_err(o, ct)
+                    any_ct = True
+                    _accumulate_grad(o, ct)
+                outs_ct.append(ct)
+            if not any_ct:
                 continue
-            if t.stop_gradient and t._tape_node is None:
-                continue  # dead end: nothing downstream wants this grad
-            if t._tape_node is None and t._graph_freed:
+            if node.vjp is None:
                 raise RuntimeError(
-                    "Trying to backward through a sub-graph that has "
-                    "already been freed (shared intermediate "
-                    f"feeding op '{node.name}'). Use retain_graph=True.")
-            prev = cotangents.get(id(t))
-            cotangents[id(t)] = g if prev is None else prev + g
-            holders[id(t)] = t
+                    "Trying to backward through a graph that has been freed "
+                    f"(op '{node.name}'). Call backward(retain_graph=True) on "
+                    "the first backward if you need to backward twice.")
+            cts = tuple(outs_ct) if len(outs_ct) > 1 else outs_ct[0]
+            if node.scope:
+                with _profile.reenter(node.scope):
+                    in_grads = node.vjp(cts)
+            else:
+                in_grads = node.vjp(cts)
+            for t, g in zip(node.inputs, in_grads):
+                if g is None or (hasattr(g, "dtype") and g.dtype == float0):
+                    continue
+                if t.stop_gradient and t._tape_node is None:
+                    continue  # dead end: nothing downstream wants this grad
+                if t._tape_node is None and t._graph_freed:
+                    raise RuntimeError(
+                        "Trying to backward through a sub-graph that has "
+                        "already been freed (shared intermediate "
+                        f"feeding op '{node.name}'). Use retain_graph=True.")
+                prev = cotangents.get(id(t))
+                cotangents[id(t)] = g if prev is None else prev + g
+                holders[id(t)] = t
 
-    # Whatever is left in the accumulator belongs to leaf tensors.
-    for key, ct in cotangents.items():
-        _accumulate_grad(holders[key], _clip_err(holders[key], ct))
+        # Whatever is left in the accumulator belongs to leaf tensors.
+        for key, ct in cotangents.items():
+            _accumulate_grad(holders[key], _clip_err(holders[key], ct))
 
     if not retain_graph:
         for node in nodes:

@@ -98,7 +98,7 @@ def apply(impl, tensors, attrs=None, nondiff=False, n_out=1, name=""):
 
     if need_grad:
         node = TapeNode(ts, vjp, list(out_tensors), name=name)
-        if _profile.scopes_on:
+        if _profile.live and _profile.armed():
             node.scope = _profile.current_path()
         for ot in out_tensors:
             ot._tape_node = node

@@ -191,75 +191,84 @@ class StaticFunction:
         return holders, state_names, all_params
 
     def __call__(self, *args, **kwargs):
-        from .dygraph_to_static import ProgramTranslator, convert_function
-        ast_on = ProgramTranslator.is_enabled()
-        if ast_on:
-            if self._converted_fn is None:
-                self._converted_fn = convert_function(self._orig_fn)
-            self._fn = self._converted_fn
-        else:
-            self._fn = self._orig_fn
-        models, optimizers, scalers = self._resolve_objects()
-        from . import tensor as _ptensor
-        own_arenas = []
-        if _ptensor._arena_hook is not None:
-            from .optimizer import arena as _arena_mod
-            own_arenas = [a for a in (getattr(o, "_arena", None)
-                                      for o in optimizers) if a is not None]
-            # external writes to arena leaves (set_value/checkpoint
-            # restore) must land in the flat buffers before we trace
-            # from them; foreign arenas also sync so the step reads
-            # fresh leaf data
-            _arena_mod.flush(exclude=own_arenas)
-        holders, state_names, all_params = self._cached_state(
-            models, optimizers, scalers)
+        # jit.<fn> is the parent of the step path's three spans: collect
+        # (everything before the executable is called), execute (the
+        # call), writeback (state and outputs); a compile sits between
+        # the first two under its own spans
+        with _monitor.trace.span(f"jit.{getattr(self, '__name__', 'fn')}"):
+            return self._call(args, kwargs)
 
-        # Tensor is a pytree node, so leaves here are raw arrays / scalars.
-        flat_args, treedef = jax.tree_util.tree_flatten((args, kwargs))
-        arr_idx, arrays, statics = [], [], []
-        for i, a in enumerate(flat_args):
-            if isinstance(a, (jax.Array, np.ndarray)):
-                arrays.append(jnp.asarray(a))
-                arr_idx.append(i)
+    def _call(self, args, kwargs):
+        with _monitor.trace.span("jit.collect"):
+            from .dygraph_to_static import ProgramTranslator, convert_function
+            ast_on = ProgramTranslator.is_enabled()
+            if ast_on:
+                if self._converted_fn is None:
+                    self._converted_fn = convert_function(self._orig_fn)
+                self._fn = self._converted_fn
             else:
-                statics.append((i, a))
+                self._fn = self._orig_fn
+            models, optimizers, scalers = self._resolve_objects()
+            from . import tensor as _ptensor
+            own_arenas = []
+            if _ptensor._arena_hook is not None:
+                from .optimizer import arena as _arena_mod
+                own_arenas = [a for a in (getattr(o, "_arena", None)
+                                          for o in optimizers) if a is not None]
+                # external writes to arena leaves (set_value/checkpoint
+                # restore) must land in the flat buffers before we trace
+                # from them; foreign arenas also sync so the step reads
+                # fresh leaf data
+                _arena_mod.flush(exclude=own_arenas)
+            holders, state_names, all_params = self._cached_state(
+                models, optimizers, scalers)
 
-        pad_info = None
-        if self._bucket and arrays and arrays[0].ndim >= 1:
-            # bucket the common leading (batch) dim: every array sharing
-            # it pads up to the bucket; outputs slice back after the call
-            from .io.bucketing import next_bucket, pad_to_bucket
-            lead = arrays[0].shape[0]
-            target = next_bucket(lead, self._buckets)
-            if target != lead:
-                arrays = [pad_to_bucket(a, target, mode=self._pad_mode)
-                          if a.ndim >= 1 and a.shape[0] == lead else a
-                          for a in arrays]
-                pad_info = (lead, target)
-                if _monitor.enabled():
-                    _monitor.counter("jit.bucket_pad").inc()
+            # Tensor is a pytree node, so leaves here are raw arrays / scalars.
+            flat_args, treedef = jax.tree_util.tree_flatten((args, kwargs))
+            arr_idx, arrays, statics = [], [], []
+            for i, a in enumerate(flat_args):
+                if isinstance(a, (jax.Array, np.ndarray)):
+                    arrays.append(jnp.asarray(a))
+                    arr_idx.append(i)
+                else:
+                    statics.append((i, a))
 
-        if self._plan is not None:
-            arrays = [self._plan.shard_input(a) for a in arrays]
+            pad_info = None
+            if self._bucket and arrays and arrays[0].ndim >= 1:
+                # bucket the common leading (batch) dim: every array sharing
+                # it pads up to the bucket; outputs slice back after the call
+                from .io.bucketing import next_bucket, pad_to_bucket
+                lead = arrays[0].shape[0]
+                target = next_bucket(lead, self._buckets)
+                if target != lead:
+                    arrays = [pad_to_bucket(a, target, mode=self._pad_mode)
+                              if a.ndim >= 1 and a.shape[0] == lead else a
+                              for a in arrays]
+                    pad_info = (lead, target)
+                    if _monitor.enabled():
+                        _monitor.counter("jit.bucket_pad").inc()
 
-        train_flags = tuple(m.training for m in models)
-        base = (treedef, tuple(arr_idx),
-                tuple((i, repr(s)) for i, s in statics), train_flags,
-                tuple(state_names), ast_on,
-                self._plan.plan_key() if self._plan is not None else None,
-                self._remat)
-        key = base + (tuple((a.shape, str(a.dtype)) for a in arrays),)
+            if self._plan is not None:
+                arrays = [self._plan.shard_input(a) for a in arrays]
 
-        fn_label = getattr(self, "__name__", "fn")
-        is_new = key not in self._cache
-        if _monitor.enabled():
-            if not is_new:
-                _monitor.counter("jit.cache_hit").inc()
-            else:
-                _monitor.counter("jit.compile").inc()
-                if base in self._seen_base:
-                    _monitor.counter("jit.recompile").inc()
-        state_vals = [holders[n].data for n in state_names]
+            train_flags = tuple(m.training for m in models)
+            base = (treedef, tuple(arr_idx),
+                    tuple((i, repr(s)) for i, s in statics), train_flags,
+                    tuple(state_names), ast_on,
+                    self._plan.plan_key() if self._plan is not None else None,
+                    self._remat)
+            key = base + (tuple((a.shape, str(a.dtype)) for a in arrays),)
+
+            fn_label = getattr(self, "__name__", "fn")
+            is_new = key not in self._cache
+            if _monitor.enabled():
+                if not is_new:
+                    _monitor.counter("jit.cache_hit").inc()
+                else:
+                    _monitor.counter("jit.compile").inc()
+                    if base in self._seen_base:
+                        _monitor.counter("jit.recompile").inc()
+            state_vals = [holders[n].data for n in state_names]
         if is_new:
             self._seen_base.add(base)
             # how many devices the step's state and inputs span, seen
@@ -288,7 +297,7 @@ class StaticFunction:
             # compile category (monitor/step.py)
             _monitor.counter("jit.compile_s").inc(
                 _time.perf_counter() - _t0_compile)
-        with _monitor.trace.span(f"jit.{fn_label}"):
+        with _monitor.trace.span("jit.execute"):
             try:
                 out_arrays, new_state = entry["jitted"](state_vals, arrays)
             except ValueError:
@@ -306,32 +315,33 @@ class StaticFunction:
                     _monitor.counter("jit.aot_sharding_fallback").inc()
                 out_arrays, new_state = entry["jitted"](state_vals, arrays)
 
-        for name, new in zip(state_names, new_state):
-            holders[name].data = new
-        # the flat buffers just advanced; per-leaf views now lag until a
-        # read syncs them (lazily — zero per-step scatter)
-        for a in own_arenas:
-            a.mark_stale()
-        for p in all_params:
-            p._grad = None
+        with _monitor.trace.span("jit.writeback"):
+            for name, new in zip(state_names, new_state):
+                holders[name].data = new
+            # the flat buffers just advanced; per-leaf views now lag until a
+            # read syncs them (lazily — zero per-step scatter)
+            for a in own_arenas:
+                a.mark_stale()
+            for p in all_params:
+                p._grad = None
 
-        if pad_info is not None:
-            lead, target = pad_info
-            out_arrays = [o[:lead] if getattr(o, "ndim", 0) >= 1 and
-                          o.shape[0] == target else o
-                          for o in out_arrays]
+            if pad_info is not None:
+                lead, target = pad_info
+                out_arrays = [o[:lead] if getattr(o, "ndim", 0) >= 1 and
+                              o.shape[0] == target else o
+                              for o in out_arrays]
 
-        # rebuild outputs: arrays -> Tensors at recorded positions
-        meta = entry["meta"]
-        out_leaves = []
-        ai = 0
-        for kind, payload in meta["slots"]:
-            if kind == "arr":
-                out_leaves.append(Tensor(out_arrays[ai]))
-                ai += 1
-            else:
-                out_leaves.append(payload)
-        return jax.tree_util.tree_unflatten(meta["treedef"], out_leaves)
+            # rebuild outputs: arrays -> Tensors at recorded positions
+            meta = entry["meta"]
+            out_leaves = []
+            ai = 0
+            for kind, payload in meta["slots"]:
+                if kind == "arr":
+                    out_leaves.append(Tensor(out_arrays[ai]))
+                    ai += 1
+                else:
+                    out_leaves.append(payload)
+            return jax.tree_util.tree_unflatten(meta["treedef"], out_leaves)
 
     def _make_entry(self, treedef, arr_idx, statics, state_names, span=1):
         fn = self._fn
@@ -374,6 +384,11 @@ class StaticFunction:
                     if self._remat is not None:
                         from . import memory_plan as _mp
                         scopes.enter_context(_mp.remat_scope(self._remat))
+                    # every compiled step carries its scopes: the
+                    # labelling sites are armed on this thread while it
+                    # traces, and only then (HLO metadata: Python time
+                    # here, once, and nothing per step)
+                    scopes.enter_context(_monitor.profile.tracing_step())
                     scopes.enter_context(jax.named_scope(fn_scope))
                     out = fn(*args, **kwargs)
                 new_state = [hs[n].data for n in state_names]
@@ -403,6 +418,12 @@ class StaticFunction:
                 for name, v in saved.items():
                     hs[name].data = v
 
+        # the HLO module is named after the step (``jit_<fn>``), which is
+        # how a device trace tells two compiled steps apart. The name is
+        # also part of the compile-cache key, where op_name metadata is
+        # not: an executable cached by a build that labelled nothing is
+        # not mistaken for this one
+        traced.__name__ = traced.__qualname__ = fn_scope
         donate = (0,) if self._donate else ()
         jitted = jax.jit(traced, donate_argnums=donate, **self._jit_kwargs)
         return {"jitted": jitted, "meta": meta}

@@ -261,6 +261,7 @@ def enable(path=None, time_dispatch=None, max_bytes=None,
                 old.close()
             _sink = JsonlSink(fp, max_bytes=max_bytes)
     _enabled = True
+    trace.note_monitor(True)
 
     telemetry_target = telemetry_dir or os.environ.get(
         "PADDLE_TPU_TELEMETRY_DIR")
@@ -300,6 +301,7 @@ def disable(flush_counters=True):
     fleet.stop_publisher()
     fleet.stop_server()
     _enabled = False
+    trace.note_monitor(False)
     if _sink is not None:
         _sink.close()
         _sink = None

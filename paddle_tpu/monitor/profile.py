@@ -12,12 +12,18 @@ Attribution rides on ``jax.named_scope``: XLA preserves the scope stack
 of every traced eqn in instruction ``metadata={op_name=...}`` — through
 fusion (inner instructions keep their own op_name), through the
 backward pass (scopes resurface inside ``transpose(...)``/``jvp(...)``
-wrappers), and through ``while``/``cond`` bodies. When profiling is
-enabled (``profile.enable()`` or ``PADDLE_TPU_PROFILE=1`` next to the
-monitor), every ``nn.Layer`` call, optimizer update body, and the fused
-functional ops (softmax/xent/norm) run under a stable registered scope
-name (``Linear_0``, ``opt.Adam``, ``F.softmax``, ...), so the ledger
-rows name real model parts, not HLO serial numbers.
+wrappers), and through ``while``/``cond`` bodies. While ``jit.to_static``
+traces a step (always, on the tracing thread only: :func:`tracing_step`),
+or everywhere once profiling is enabled (``profile.enable()`` or
+``PADDLE_TPU_PROFILE=1`` next to the monitor), every ``nn.Layer`` call,
+optimizer update body, and the fused functional ops (softmax/xent/norm)
+run under a stable registered scope name (``Linear_0``, ``opt.Adam``,
+``F.softmax``, ...), so the ledger rows name real model parts, not HLO
+serial numbers. The tape's backward runs under the phase scope ``bwd``;
+:func:`phase_and_region` reads phase (``fwd``/``bwd``/``opt``/``none``)
+and region off an ``op_name``, and :func:`instruction_ledger` says what
+every instruction that runs holds of each — the side a device trace is
+joined with (``benchmark/program_trace.py``).
 
 The flop/byte model mirrors XLA's ``HloCostAnalysis`` conventions
 (dot = 2·out·K, elementwise = 1/elem, reduce = in−out with the
@@ -26,8 +32,10 @@ shape ops free), verified against ``Compiled.cost_analysis()`` — the
 reconciliation is asserted to 1% in tests/test_profile.py.
 
 Cost discipline: when disabled (the default) the labeling sites check
-one module flag (``profile.scopes_on``) and nothing else happens — no
-scope objects, no HLO parse. ``report()`` is always explicit.
+one module flag (``profile.live``) and nothing else happens — no scope
+objects, no HLO parse. Scopes are HLO metadata: in a compiled step they
+cost Python time once, while it is traced, and nothing per step.
+``report()`` and ``instruction_ledger()`` are always explicit.
 
 Usage::
 
@@ -48,11 +56,13 @@ import time
 import jax
 
 __all__ = [
-    "enable", "disable", "enabled", "scopes_on", "register_scope",
+    "enable", "disable", "enabled", "scopes_on", "live", "armed",
+    "tracing_step", "register_scope",
     "scopes", "layer_scope", "optimizer_scope", "fscope", "scope",
     "current_path", "reenter", "reset",
     "roofline_ceilings", "parse_hlo", "attribute", "report",
     "format_table", "last_report", "last_summary",
+    "phase_and_region", "instruction_ledger", "PHASES", "BWD_SCOPE",
 ]
 
 UNATTRIBUTED = "<unattributed>"
@@ -63,9 +73,18 @@ UNATTRIBUTED = "<unattributed>"
 # attribution bar trivially true.
 _ATTRIBUTING_KINDS = ("layer", "optimizer", "functional", "op")
 
+# kinds that exist to be recognized and never count as attribution:
+# "root" (see above) and "phase" (the tape's backward, BWD_SCOPE)
+PHASES = ("fwd", "bwd", "opt", "none")
+BWD_SCOPE = "bwd"
+
 _lock = threading.Lock()
-scopes_on = False           # read by nn.Layer/__call__, ops, optimizer
+scopes_on = False           # profile.enable(): label eager code too
+live = False                # what nn.Layer/__call__, ops, optimizer read:
+#                             scopes_on, or some thread is tracing a step
+_tracing = 0                # threads inside tracing_step()
 _scopes = {}                # scope name -> kind
+_scope_phase = {}           # scope name -> phase, for the few that set one
 _layer_counters = {}        # class name -> next per-instance index
 _last = None                # cached last report() result
 
@@ -75,24 +94,58 @@ _last = None                # cached last report() result
 
 def enable():
     """Arm scope labeling (one module-flag check at each site when off)."""
-    global scopes_on
-    scopes_on = True
+    global scopes_on, live
+    with _lock:
+        scopes_on = live = True
 
 
 def disable():
-    global scopes_on
-    scopes_on = False
+    global scopes_on, live
+    with _lock:
+        scopes_on = False
+        live = _tracing > 0
 
 
 def enabled():
     return scopes_on
 
 
-def register_scope(name, kind="layer"):
-    """Register ``name`` as an attributable scope (kind: layer /
-    optimizer / functional / op / root)."""
+def armed():
+    """Whether the calling thread labels: after ``enable()``, or while it
+    traces a compiled step. The sites ask this only once ``live`` is set,
+    so a trace on one thread costs eager code on another this one call
+    per site, and labels nothing there."""
+    return scopes_on or _tls.__dict__.get("tracing", 0) > 0
+
+
+@contextlib.contextmanager
+def tracing_step():
+    """Arm the labelling sites on the calling thread for the duration of
+    one trace of a compiled step (``jit.StaticFunction``), and put things
+    back after: ``scopes_on`` is never touched."""
+    global live, _tracing
+    depth = _tls.__dict__.get("tracing", 0)
+    with _lock:
+        _tracing += 1
+        live = True
+    _tls.tracing = depth + 1
+    try:
+        yield
+    finally:
+        _tls.tracing = depth
+        with _lock:
+            _tracing -= 1
+            live = scopes_on or _tracing > 0
+
+
+def register_scope(name, kind="layer", phase=None):
+    """Register ``name`` as a scope the ledger recognizes (kind: layer /
+    optimizer / functional / op, which attribute; root / phase, which do
+    not). ``phase`` puts everything under it into that phase."""
     with _lock:
         _scopes[str(name)] = kind
+        if phase is not None:
+            _scope_phase[str(name)] = phase
     return name
 
 
@@ -131,8 +184,7 @@ def optimizer_scope(opt):
         except Exception:
             pass
     if name not in _scopes:
-        with _lock:
-            _scopes[name] = "optimizer"
+        register_scope(name, "optimizer", phase="opt")
     return name
 
 
@@ -181,12 +233,21 @@ def reenter(path):
         yield
 
 
+def backward_scope():
+    """The phase scope the tape's backward sweep runs under
+    (``autograd.backward``). Not pushed on the scope path: a node taped
+    during the sweep keeps its layer's path and no ``bwd`` in it."""
+    register_scope(BWD_SCOPE, "phase", phase="bwd")
+    return jax.named_scope(BWD_SCOPE)
+
+
 def reset():
     """Clear registered scopes, per-class counters and the cached
     report (labeling flag is left as-is)."""
     global _last
     with _lock:
         _scopes.clear()
+        _scope_phase.clear()
         _layer_counters.clear()
     _last = None
 
@@ -269,11 +330,11 @@ _REF_RE = {
 }
 _CONTRACT_RE = re.compile(r"lhs_contracting_dims=\{([0-9,]*)\}")
 _WINDOW_RE = re.compile(r"window=\{[^}]*\bsize=([0-9x]+)")
-_FGC_RE = re.compile(r"feature_group_count=(\d+)")
-_DIMLABEL_RE = re.compile(r"dim_labels=\w+_\w+->(\w+)")
-_WRAPPER_RE = re.compile(
-    r"^(jit|jvp|vjp|transpose|vmap|pmap|xmap|remat|checkpoint|"
-    r"custom_jvp|custom_vjp|shard_map)\((.*)\)$")
+_DIMLABELS_RE = re.compile(r"dim_labels=(\w+)_(\w+)->(\w+)")
+_WRAPPERS = (r"(jit|jvp|vjp|transpose|vmap|pmap|xmap|remat|checkpoint|"
+             r"custom_jvp|custom_vjp|shard_map)")
+_WRAPPER_RE = re.compile(r"^" + _WRAPPERS + r"\((.*)\)$")
+_WRAPPER_OPEN_RE = re.compile(r"^" + _WRAPPERS + r"\(")
 
 # 1 flop per output element (HloCostAnalysis default for elementwise)
 _ELEMENTWISE = frozenset((
@@ -443,8 +504,10 @@ def parse_hlo(text):
     for comp in comps.values():
         for instr in comp["instrs"]:
             attrs = instr["attrs"]
+            # a reduce folds its to_apply in; a `call` runs it
+            applied = "inline" if instr["opcode"] == "call" else "to_apply"
             for n in _REF_RE["to_apply"].findall(attrs):
-                refs["to_apply"].add(n)
+                refs[applied].add(n)
             for n in _REF_RE["calls"].findall(attrs):
                 refs["calls"].add(n)
             for n in _REF_RE["inline"].findall(attrs):
@@ -455,6 +518,61 @@ def parse_hlo(text):
                     if tok:
                         refs["inline"].add(tok)
     return comps, entry, refs
+
+
+def _window_field(window, key, n, default):
+    """One ``key=AxBx..`` field of a ``window={...}`` attribute as n
+    strings, or n defaults."""
+    m = re.search(r"\b" + key + r"=([\w\-]+)", window)
+    return m.group(1).split("x") if m else [default] * n
+
+
+def _conv_flops(instr, out_elems):
+    """2 x multiply-adds of a convolution, HloCostAnalysis's way: per
+    spatial dimension the (output position, kernel position) pairs that
+    land on a real input element — not in the padding, not in a hole of
+    a dilated input — times batch, output features and input features
+    per group. The count matters on TPU, where a batched matmul is a
+    convolution whose batch dimensions sit in a dilated window with one
+    live position per output (``size=64x12 stride=63x11
+    lhs_dilate=64x12``): kernel size times output size would count it
+    64 x 12 times over."""
+    dm = _DIMLABELS_RE.search(instr["attrs"])
+    if dm is None or len(instr["operands"]) < 2:
+        return 2 * out_elems
+    lhs_spec, rhs_spec, out_spec = dm.groups()
+    lhs = _first_shape(instr["operands"][0])
+    rhs = _first_shape(instr["operands"][1])
+    out = _first_shape(instr["out_type"])
+    if (len(lhs), len(rhs), len(out)) != (len(lhs_spec), len(rhs_spec),
+                                          len(out_spec)):
+        return 2 * out_elems
+    n = len(out_spec) - 2
+    wm = re.search(r"window=\{([^}]*)\}", instr["attrs"])
+    window = wm.group(1) if wm else ""
+    stride = [int(x) for x in _window_field(window, "stride", n, "1")]
+    pad_low = [int(x.split("_")[0])
+               for x in _window_field(window, "pad", n, "0_0")]
+    lhs_dil = [int(x) for x in _window_field(window, "lhs_dilate", n, "1")]
+    rhs_dil = [int(x) for x in _window_field(window, "rhs_dilate", n, "1")]
+    pairs = 1
+    for d in range(n):
+        c = str(d)
+        size_in = lhs[lhs_spec.index(c)]
+        size_k = rhs[rhs_spec.index(c)]
+        size_out = out[out_spec.index(c)]
+        live = 0
+        for k in range(size_k):
+            for o in range(size_out):
+                at = o * stride[d] - pad_low[d] + k * rhs_dil[d]
+                if at >= 0 and at % lhs_dil[d] == 0 \
+                        and at // lhs_dil[d] < size_in:
+                    live += 1
+        pairs *= live
+    # the result's batch is per batch group and the kernel's input
+    # features are per feature group already
+    return 2 * out[out_spec.index("b")] * out[out_spec.index("f")] \
+        * rhs[rhs_spec.index("i")] * pairs
 
 
 def _instr_flops(instr, comps):
@@ -483,21 +601,7 @@ def _instr_flops(instr, comps):
                     contracted *= lhs_dims[int(idx)]
         return 2 * out_elems * contracted, 0
     if opcode == "convolution":
-        # 2 × out_elems × kernel_spatial × in_features/groups: the rhs
-        # holds exactly (spatial × i × o) elements, so rhs_elems /
-        # out_features is the per-output-element MAC count
-        rhs_elems = (_type_elems(instr["operands"][1])
-                     if len(instr["operands"]) > 1 else 0)
-        out_features = 1
-        dm = _DIMLABEL_RE.search(instr["attrs"])
-        if dm:
-            out_spec = dm.group(1)
-            fpos = out_spec.find("f")
-            out_dims = _first_shape(instr["out_type"])
-            if 0 <= fpos < len(out_dims):
-                out_features = max(1, out_dims[fpos])
-        macs_per_out = rhs_elems // max(1, out_features)
-        return 2 * out_elems * max(1, macs_per_out), 0
+        return _conv_flops(instr, out_elems), 0
     if opcode == "reduce":
         ops = instr["operands"]
         arrays = ops[:max(1, len(ops) // 2)]
@@ -530,32 +634,72 @@ def _instr_bytes(instr):
 def _scope_tokens(op_name):
     """named_scope path segments of an op_name, with jit()/jvp()/
     transpose()/... wrappers peeled recursively — backward-pass ops
-    carry their forward scope inside transpose(jvp(scope))."""
+    carry their forward scope inside transpose(jvp(scope)). A wrapper
+    may span segments (``transpose(jvp(A/B/kernel))``: a custom_vjp
+    keeps the path it was defined under): its opening is peeled off the
+    first segment and its closing off the last."""
     toks = []
     for raw in op_name.split("/"):
         t = raw.strip()
         while True:
             m = _WRAPPER_RE.match(t)
-            if m is None:
+            if m is not None:
+                t = m.group(2)
+                continue
+            m = _WRAPPER_OPEN_RE.match(t)
+            if m is None or t.count("(") <= t.count(")"):
                 break
-            t = m.group(2)
+            t = t[m.end():]
+        while t.endswith(")") and t.count(")") > t.count("("):
+            t = t[:-1]
         if t:
             toks.append(t)
     return toks
 
 
-def _region_of(op_name, scope_map):
+def _region_of(op_name, scope_map, tokens=None):
     """(region_path, leaf_scope) from an op_name given the registry —
     the joined chain of registered attributable scopes, or
     (UNATTRIBUTED, None) when no registered scope appears."""
     hits = []
-    for t in _scope_tokens(op_name):
-        if scope_map.get(t) in _ATTRIBUTING_KINDS:
-            if not hits or hits[-1] != t:
-                hits.append(t)
+    for t in _scope_tokens(op_name) if tokens is None else tokens:
+        # once each: a path re-entered for a backward op (or kept inside
+        # a custom_vjp's wrapper) names the same scopes again
+        if scope_map.get(t) in _ATTRIBUTING_KINDS and t not in hits:
+            hits.append(t)
     if not hits:
         return UNATTRIBUTED, None
     return "/".join(hits), hits[-1]
+
+
+def phase_and_region(op_name, scope_map=None, phase_map=None):
+    """(phase, region) of an op_name. The region is :func:`_region_of`'s.
+    The phase is ``opt`` under a scope registered with that phase
+    (``opt.<Cls>``, ``arena.pack``), else ``bwd`` under the tape's
+    backward scope, else ``fwd`` under a root scope (a compiled step's),
+    else ``none``: an instruction the compiler made, or eager code."""
+    scope_map = _scopes if scope_map is None else scope_map
+    phase_map = _scope_phase if phase_map is None else phase_map
+    tokens = _scope_tokens(op_name)
+    phase = "none"
+    for t in tokens:
+        marked = phase_map.get(t)
+        if marked == "opt":
+            phase = "opt"
+            break
+        if marked == "bwd":
+            phase = "bwd"
+        elif phase == "none" and scope_map.get(t) == "root":
+            phase = "fwd"
+    return phase, _region_of(op_name, scope_map, tokens)[0]
+
+
+def _running_computations(comps, entry, refs):
+    """Names of the computations whose instructions run as instructions
+    of their own: ENTRY and control-flow bodies, not the folded
+    (to_apply) and fused (calls) ones."""
+    inline = refs["inline"] - refs["calls"] - refs["to_apply"]
+    return [n for n in [entry] + sorted(inline - {entry}) if n in comps]
 
 
 def attribute(text, scope_map=None):
@@ -575,20 +719,9 @@ def attribute(text, scope_map=None):
         return {"ops": [], "total_flops": 0.0, "attributed_flops": 0.0,
                 "attributed_frac": 0.0, "transcendentals": 0.0}
 
-    # top-level stream: ENTRY + control-flow bodies (transitively),
-    # skipping folded (to_apply) and fused (calls) computations
-    top_names, work = [], [entry]
-    seen = set(work)
-    inline = refs["inline"] - refs["calls"] - refs["to_apply"]
-    for name in sorted(inline):
-        if name not in seen:
-            seen.add(name)
-            work.append(name)
-    top_names = [n for n in work if n in comps]
-
     ops = []
     total_f = attr_f = total_t = 0.0
-    for cname in top_names:
+    for cname in _running_computations(comps, entry, refs):
         for instr in comps[cname]["instrs"]:
             if instr["opcode"] in _SKIP_OPS:
                 continue
@@ -642,6 +775,245 @@ def attribute(text, scope_map=None):
         "attributed_frac": (attr_f / total_f) if total_f else 0.0,
         "transcendentals": float(total_t),
     }
+
+
+# ---------------------------------------------------------------------------
+# what each instruction of a captured executable holds, by phase and region
+
+_MODULE_RE = re.compile(r"^HloModule\s+([^\s,]+)", re.M)
+_OPERAND_NAME_RE = re.compile(r"%?([\w.\-]+)$")
+MOSAIC_TARGET = 'custom_call_target="tpu_custom_call"'
+# they read or write a window of their big operand, not all of it
+_WINDOWED = frozenset(("slice", "dynamic-slice", "gather"))
+# they change a value's type or layout and compute nothing on it
+_FORMATTING = frozenset(("bitcast", "get-tuple-element", "convert", "copy",
+                         "transpose", "reshape", "broadcast"))
+
+
+def _kernel_name(instr):
+    """The ``name=`` of the ``pl.pallas_call`` an instruction is, or None:
+    the op_name segment in front of ``pallas_call``, wrappers peeled."""
+    if instr["opcode"] != "custom-call" or MOSAIC_TARGET not in instr["attrs"]:
+        return None
+    tokens = _scope_tokens(instr["op_name"])
+    names = [prev for prev, tok in zip(tokens, tokens[1:])
+             if tok == "pallas_call"]
+    return names[-1] if names else None
+
+
+def _operand_name(operand):
+    m = _OPERAND_NAME_RE.search(operand.strip())
+    return m.group(1) if m else None
+
+
+def _by_name_and_users(instrs):
+    """({name: instruction}, {name: the instructions that read it, in
+    schedule order}) of one computation."""
+    by_name = {i["name"]: i for i in instrs}
+    users = {}
+    for instr in instrs:
+        for operand in instr["operands"]:
+            users.setdefault(_operand_name(operand), []).append(instr)
+    return by_name, users
+
+
+def _boundary_bytes(instrs):
+    """Bytes each instruction of a FUSED computation moves across the
+    fusion's boundary, by name: a fusion parameter's bytes go to the
+    first instruction that computes on it, the result's to the
+    instruction that computes it; conversions and changes of layout on
+    the way pass them through (where nothing else is there, the first of
+    them takes them). What passes between the inner instructions never
+    reaches memory and is not counted, so the parts add up to the
+    fusion's own operands and result."""
+    by_name, users = _by_name_and_users(instrs)
+    moved = {i["name"]: 0 for i in instrs if i["opcode"] not in _SKIP_OPS}
+
+    def reader(param):
+        first, front, seen = None, [param], set()
+        while front:
+            nxt = []
+            for instr in front:
+                for user in users.get(instr["name"], ()):
+                    if user["name"] in seen:
+                        continue
+                    seen.add(user["name"])
+                    if user["opcode"] not in _FORMATTING:
+                        return user
+                    first = first or (user if user["name"] in moved
+                                      else None)
+                    nxt.append(user)
+            front = nxt
+        return first
+
+    def writer(name):
+        first, instr = None, by_name.get(name)
+        while instr is not None and instr["opcode"] in _FORMATTING \
+                and instr["operands"]:
+            first = first or (instr if instr["name"] in moved else None)
+            instr = by_name.get(_operand_name(instr["operands"][0]))
+        if instr is not None and instr["name"] in moved:
+            return instr
+        return first
+
+    for param in instrs:
+        if param["opcode"] != "parameter":
+            continue
+        instr = reader(param)
+        if instr is not None:
+            moved[instr["name"]] += _type_bytes(
+                instr["out_type"] if instr["opcode"] in _WINDOWED
+                else param["out_type"])
+    root = next((i for i in instrs if i["root"]), None)
+    results = []
+    if root is not None:
+        results = [_operand_name(o) for o in root["operands"]] \
+            if root["opcode"] == "tuple" else [root["name"]]
+    for name in results:
+        instr = writer(name)
+        if instr is None:
+            continue
+        written = by_name[name]["out_type"]
+        if instr["opcode"] == "dynamic-update-slice" \
+                and len(instr["operands"]) > 1:
+            written = instr["operands"][1]      # the update, in place
+        moved[instr["name"]] += _type_bytes(written)
+    return moved
+
+
+def _labelled(op_name):
+    """Whether an op_name holds a scope path. The compiler's own
+    instructions have none at all, and a copy of an argument is named
+    after the argument alone (``state_vals[397]``)."""
+    return "/" in op_name
+
+
+def _serves(instrs):
+    """{name: (op_name, name of the instruction it was taken from)} for
+    the instructions of one computation that carry no scope path: what
+    the compiler made to move data (``copy-start``/``copy-done`` and
+    ``slice-start``/``slice-done`` prefetches, ``ConcatBitcast``, layout
+    copies of arguments) belongs to the labelled instruction it serves —
+    the first one downstream in the schedule, else the nearest one
+    upstream."""
+    by_name, users = _by_name_and_users(instrs)
+
+    def nearest(start, step):
+        seen, front = {start["name"]}, [start]
+        for _ in range(8):
+            nxt = []
+            for instr in front:
+                for other in step(instr):
+                    if other is None or other["name"] in seen:
+                        continue
+                    if _labelled(other["op_name"]):
+                        return other
+                    seen.add(other["name"])
+                    nxt.append(other)
+            front = nxt
+        return None
+
+    out = {}
+    for instr in instrs:
+        if _labelled(instr["op_name"]) or instr["opcode"] in _SKIP_OPS:
+            continue
+        found = nearest(instr, lambda i: users.get(i["name"], ())) or \
+            nearest(instr, lambda i: [by_name.get(_operand_name(o))
+                                      for o in i["operands"]])
+        if found is not None:
+            out[instr["name"]] = (found["op_name"], found["name"])
+    return out
+
+
+def _instruction_parts(instr, comps, scope_map, phase_map, op_name=None):
+    """{(phase, region): [flops, bytes]} of one top-level instruction. A
+    fusion is split over its inner instructions' own op_names; the inner
+    instructions the compiler made (no op_name) follow the rest.
+    ``op_name`` stands in where the instruction has none of its own."""
+    parts = {}
+
+    def add(op_name, flops, nbytes):
+        key = phase_and_region(op_name, scope_map, phase_map)
+        part = parts.setdefault(key, [0.0, 0.0])
+        part[0] += flops
+        part[1] += nbytes
+
+    if instr["opcode"] == "fusion":
+        for target in _REF_RE["calls"].findall(instr["attrs"]):
+            inner_instrs = comps.get(target, {"instrs": ()})["instrs"]
+            moved = _boundary_bytes(inner_instrs)
+            for inner in inner_instrs:
+                if inner["opcode"] in _SKIP_OPS \
+                        or not _labelled(inner["op_name"]):
+                    continue
+                flops, trans = _instr_flops(inner, comps)
+                add(inner["op_name"], flops + trans, moved[inner["name"]])
+    if not parts:
+        flops, trans = _instr_flops(instr, comps)
+        add(op_name or instr["op_name"], flops + trans, _instr_bytes(instr))
+    return parts
+
+
+def instruction_ledger(label=None, hlo=None, scope_map=None,
+                       phase_map=None):
+    """What every instruction that runs holds, by phase and region: the
+    side of the join that a device trace's own times are credited
+    through. One row per top-level instruction of ENTRY and of every
+    control-flow body, of the executable ``monitor.xla`` keeps under
+    ``label`` (default: of every executable it keeps), or of ``hlo=``
+    text::
+
+        {"module": "jit_bert_step",   # as the trace's "XLA Modules" line
+         "label": "jit.bert_step",    # monitor.xla's
+         "name": "fusion.123",        # as the trace's "XLA Ops" line
+         "opcode": "fusion",
+         "kernel": None,              # a pl.pallas_call's name=
+         "serves": None,              # unlabelled: whose labels it took
+         "parts": [{"phase": "bwd", "region": "BertLayer_3/Linear_0",
+                    "flops": ..., "bytes": ...}, ...]}
+
+    ``flops`` and ``bytes`` are the cost MODEL's (:func:`attribute`'s
+    conventions; a fusion's bytes are what crosses its boundary), there to
+    split an instruction that holds several phases; what an instruction
+    took is the trace's to say. Parses when called and never before:
+    nothing of this may run in a step, in set-up or in a timed window."""
+    from . import xla as _xla
+    scope_map = dict(_scopes) if scope_map is None else dict(scope_map)
+    phase_map = dict(_scope_phase) if phase_map is None else dict(phase_map)
+    if hlo is not None:
+        texts = [(label, hlo)]
+    else:
+        texts = []
+        for lb in ([str(label)] if label is not None else _xla.labels()):
+            exe = _xla.executable(lb)
+            try:
+                texts.append((lb, exe.as_text()))
+            except Exception:
+                continue
+    rows = []
+    for lb, text in texts:
+        comps, entry, refs = parse_hlo(text)
+        if entry is None:
+            continue
+        m = _MODULE_RE.search(text)
+        module = m.group(1) if m else None
+        for cname in _running_computations(comps, entry, refs):
+            serves = _serves(comps[cname]["instrs"])
+            for instr in comps[cname]["instrs"]:
+                if instr["opcode"] in _SKIP_OPS:
+                    continue
+                op_name, served = serves.get(instr["name"], (None, None))
+                parts = _instruction_parts(instr, comps, scope_map,
+                                           phase_map, op_name)
+                rows.append({
+                    "module": module, "label": lb, "name": instr["name"],
+                    "opcode": instr["opcode"],
+                    "kernel": _kernel_name(instr), "serves": served,
+                    "parts": [{"phase": ph, "region": reg,
+                               "flops": float(f), "bytes": float(b)}
+                              for (ph, reg), (f, b) in sorted(parts.items())],
+                })
+    return rows
 
 
 # ---------------------------------------------------------------------------

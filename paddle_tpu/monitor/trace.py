@@ -10,12 +10,19 @@ that Perfetto / chrome://tracing loads directly — the prefetch producer
 thread, the host step loop and the watchdog each get their own track,
 so pipeline overlap is *observed*, not inferred from counters.
 
-Cost discipline (same contract as the dispatch hook): when tracing is
-disabled — the default — ``span()`` does ONE module-flag check and
-returns a shared null context manager; no event tuple, no clock read,
-no dict. Enabling costs one ``perf_counter()`` + one deque append per
-span edge (appends on ``collections.deque`` are atomic in CPython, so
+Cost discipline (same contract as the dispatch hook): with the tracer
+and the monitor both off — the default — ``span()`` does ONE module-flag
+check and returns a shared null context manager; no event tuple, no clock
+read, no dict. The tracer costs one ``perf_counter()`` + one deque append
+per span edge (appends on ``collections.deque`` are atomic in CPython, so
 producer threads never contend on a lock).
+
+One clock with the device: whenever the tracer OR the monitor is on,
+``span()`` also enters a ``jax.profiler.TraceAnnotation`` of the same
+name, so the program's spans lie in a captured profiler trace beside the
+device's operations (``/host:CPU``, the calling thread's line) with no
+second switch. With no profiler session that is one small object per
+span. The ring records only while the tracer is on.
 
 Usage::
 
@@ -32,10 +39,9 @@ Span sites wired by this package: ``Executor.run`` phases
 ``optimizer.step``, ``checkpoint.save``/``restore``,
 ``resilience.backoff`` waits, ``fit.step``; ``dispatch.<op>`` complete
 events ride the existing ``time_dispatch`` opt-in, and collectives
-appear as instant events. With ``bridge=True`` (or
-``PADDLE_TPU_TRACE_BRIDGE=1``) each span additionally enters a
-``jax.profiler.TraceAnnotation`` so the same names show up inside a
-captured XLA device trace.
+appear as instant events. A compiled step's call is ``jit.<fn>`` with
+``jit.collect``, ``jit.execute`` and ``jit.writeback`` inside it;
+``Tensor.numpy()`` reads the device under ``tensor.to_host``.
 
 The flight recorder (:func:`flight_record`) turns "it hung at step
 4017" into an artifact: on a watchdog stall, a NaN-guard rollback or an
@@ -66,8 +72,9 @@ DEFAULT_BUFFER = 65536
 
 _CLOCK = time.perf_counter
 
-_active = False
-_bridge = False
+_active = False             # the ring records
+_monitor_on = False         # monitor.enable() told us (note_monitor)
+_live = False               # what span() reads: _active or _monitor_on
 _events = collections.deque(maxlen=DEFAULT_BUFFER)
 _thread_names = {}          # thread ident -> name (first event wins)
 _t0 = 0.0                   # perf_counter origin for export timestamps
@@ -99,30 +106,35 @@ def enabled():
     return _active
 
 
-def enable(buffer_size=None, bridge=None):
+def enable(buffer_size=None):
     """Turn span recording on. ``buffer_size`` resizes the ring buffer
-    (default 65536 events ≈ 32k spans — old events fall off the front);
-    ``bridge=True`` additionally enters a jax.profiler.TraceAnnotation
-    per span (``PADDLE_TPU_TRACE_BRIDGE=1``). Idempotent."""
-    global _active, _bridge, _events, _t0, _wall0
+    (default 65536 events ≈ 32k spans — old events fall off the front).
+    Idempotent."""
+    global _active, _live, _events, _t0, _wall0
     if buffer_size:
         _events = collections.deque(_events, maxlen=int(buffer_size))
-    if bridge is None:
-        bridge = os.environ.get(
-            "PADDLE_TPU_TRACE_BRIDGE", "") not in ("", "0")
-    _bridge = bool(bridge)
     if not _active:
         _t0 = _CLOCK()
         _wall0 = time.time()
-        _active = True
+        _active = _live = True
     _note_thread(threading.get_ident())
 
 
 def disable():
     """Stop recording. The buffer is KEPT so a post-run
     export_chrome_trace() still works; clear() empties it."""
-    global _active
+    global _active, _live
     _active = False
+    _live = _monitor_on
+
+
+def note_monitor(on):
+    """``monitor.enable()`` / ``monitor.disable()`` say so here: with the
+    monitor on, spans are profiler annotations even while the ring is
+    off."""
+    global _monitor_on, _live
+    _monitor_on = bool(on)
+    _live = _active or _monitor_on
 
 
 def clear():
@@ -159,30 +171,38 @@ class _NullSpan:
 _NULL = _NullSpan()
 
 
+_annotation_cls = None
+
+
 def _annotation(name):
-    import jax.profiler
-    return jax.profiler.TraceAnnotation(name)
+    global _annotation_cls
+    if _annotation_cls is None:
+        import jax.profiler
+        _annotation_cls = jax.profiler.TraceAnnotation
+    return _annotation_cls(name)
 
 
 class _Span:
-    __slots__ = ("name", "args", "_ann")
+    __slots__ = ("name", "args", "_ann", "_recorded")
 
     def __init__(self, name, args):
         self.name = name
         self.args = args
         self._ann = None
+        self._recorded = False
 
     def __enter__(self):
-        tid = threading.get_ident()
-        if tid not in _thread_names:
-            _note_thread(tid)
-        _events.append(("B", self.name, tid, _CLOCK(), self.args))
-        if _bridge:
-            try:
-                self._ann = _annotation(self.name)
-                self._ann.__enter__()
-            except Exception:
-                self._ann = None
+        if _active:
+            tid = threading.get_ident()
+            if tid not in _thread_names:
+                _note_thread(tid)
+            _events.append(("B", self.name, tid, _CLOCK(), self.args))
+            self._recorded = True
+        try:
+            self._ann = _annotation(self.name)
+            self._ann.__enter__()
+        except Exception:
+            self._ann = None
         return self
 
     def __exit__(self, *exc):
@@ -192,15 +212,19 @@ class _Span:
             except Exception:
                 pass
             self._ann = None
-        _events.append(("E", self.name, threading.get_ident(), _CLOCK()))
+        if self._recorded:
+            _events.append(("E", self.name, threading.get_ident(),
+                            _CLOCK()))
         return False
 
 
 def span(name, **args):
-    """``with trace.span("executor.execute", step=i): ...`` — records a
-    begin/end event pair on the calling thread's track. Disabled mode
-    returns the shared null context manager after one flag check."""
-    if not _active:
+    """``with trace.span("executor.execute", step=i): ...`` — a profiler
+    annotation of that name on the calling thread whenever the tracer or
+    the monitor is on, and a begin/end event pair in the ring while the
+    tracer is. With both off it returns the shared null context manager
+    after one flag check."""
+    if not _live:
         return _NULL
     return _Span(name, args or None)
 
@@ -323,7 +347,7 @@ def traced(name=None):
 
         @functools.wraps(fn)
         def wrapped(*a, **k):
-            if not _active:
+            if not _live:
                 return fn(*a, **k)
             with _Span(label, None):
                 return fn(*a, **k)

@@ -319,7 +319,7 @@ class Layer:
             out = _remat_hook(self, args, kwargs)
             if out is not NotImplemented:
                 return out
-        if _profile.scopes_on:
+        if _profile.live and _profile.armed():
             with _profile.scope(_profile.layer_scope(self)):
                 return self._run_forward(args, kwargs)
         return self._run_forward(args, kwargs)

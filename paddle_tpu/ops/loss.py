@@ -29,7 +29,7 @@ from . import nn_ops as _nn
 def _pscope(name):
     """named_scope(F.<name>) when profiling is armed, else a no-op —
     one flag check, so the disabled path stays free."""
-    if _profile.scopes_on:
+    if _profile.live and _profile.armed():
         return _profile.scope(_profile.fscope(name))
     return nullcontext()
 

@@ -123,6 +123,7 @@ def _stats(x2):
         out_shape=(jax.ShapeDtypeStruct((1, c), jnp.float32),
                    jax.ShapeDtypeStruct((1, c), jnp.float32)),
         interpret=interpret_mode(),
+        name="batch_norm_stats",
     )(x2, shift)
     m_s = s / m
     mean = m_s + shift
@@ -142,6 +143,7 @@ def _normalize(x2, scale, shift):
         out_specs=wide,
         out_shape=jax.ShapeDtypeStruct((m, c), x2.dtype),
         interpret=interpret_mode(),
+        name="batch_norm_apply",
     )(x2, scale, shift)
 
 
@@ -186,6 +188,7 @@ def _bn_bwd(eps, res, gs):
         out_shape=(jax.ShapeDtypeStruct((1, c), jnp.float32),
                    jax.ShapeDtypeStruct((1, c), jnp.float32)),
         interpret=interpret_mode(),
+        name="batch_norm_bwd_reduce",
     )(x2, g, mean, rstd)
     wr = (w.astype(jnp.float32).reshape(1, -1) * rstd)
     # cotangents of the direct mean/var outputs, pre-scaled and stacked
@@ -203,6 +206,7 @@ def _bn_bwd(eps, res, gs):
         out_specs=wide,
         out_shape=jax.ShapeDtypeStruct((m, c), x2.dtype),
         interpret=interpret_mode(),
+        name="batch_norm_bwd_dx",
     )(x2, g, mean, rstd, wr, dg / m, db / m, gmv)
     return dx, dg[0].astype(w.dtype), db[0].astype(w.dtype)
 

@@ -372,6 +372,7 @@ def _flash_fwd_res(q, k, v, mask, mask_mode, seed, causal, scale, block_q,
             jax.ShapeDtypeStruct((b * h, sq, _LANES), jnp.float32),
         ],
         interpret=interp,
+        name="flash_fwd",
     )(seed2, q3, k3, v3, m3, keep3)
     # keep only one lane as residuals (128× smaller across the fwd→bwd gap)
     return out.reshape(b, h, sq, d), mrow[..., :1], lrow[..., :1]
@@ -460,6 +461,7 @@ def _flash_bwd(q, k, v, mask, mask_mode, seed, out, mrow, lrow, g, causal,
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
         interpret=interp,
+        name="flash_bwd_dq",
     )(seed2, q3, k3, v3, m3, keep3, mb_l, linv_l, delta_l, do3)
 
     # dK/dV pass needs whole-Q operands padded to the block multiple
@@ -503,6 +505,7 @@ def _flash_bwd(q, k, v, mask, mask_mode, seed, out, mrow, lrow, g, causal,
             jax.ShapeDtypeStruct((b * h, sk_pad, d), v.dtype),
         ],
         interpret=interp,
+        name="flash_bwd_dkv",
     )(seed2, q3p, k3, v3, m3, keep3, mb_l, linv_l, delta_l, do3p)
     dk = dk[:, :sk].reshape(b, h, sk, d)
     dv = dv[:, :sk].reshape(b, h, sk, d)

@@ -88,6 +88,7 @@ def fused_adam_update(p, g, m, v, lr, beta1_pow, beta2_pow, beta1=0.9,
         ],
         input_output_aliases={1: 0, 3: 1, 4: 2},
         interpret=interpret_mode(),
+        name="fused_adam",
     )(scal, flat(p, p.dtype), flat(g), flat(m), flat(v))
 
     def unflat(x, dtype):
@@ -170,6 +171,7 @@ def fused_adam_update_multi(ps, gs, ms, vs, lr, beta1_pow, beta2_pow,
         out_shape=[jax.ShapeDtypeStruct((rows, cols), jnp.float32)] * 3,
         input_output_aliases={1: 0, 3: 1, 4: 2},
         interpret=interpret_mode(),
+        name="fused_adam_multi",
     )(scal, flat_cat(ps), flat_cat(gs), flat_cat(ms), flat_cat(vs))
 
     def split(buf, refs, dtype_from=None):
@@ -241,6 +243,7 @@ def fused_adam_update_flat(p, g, m, v, lr, beta1_pow, beta2_pow,
         out_shape=[jax.ShapeDtypeStruct((rows, cols), jnp.float32)] * 3,
         input_output_aliases={1: 0, 3: 1, 4: 2},
         interpret=interpret_mode(),
+        name="fused_adam_flat",
     )(scal, tile(p), tile(g), tile(m), tile(v))
     return (new_p.reshape(-1).astype(p.dtype),
             new_m.reshape(-1).astype(m.dtype),

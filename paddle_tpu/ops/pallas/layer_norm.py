@@ -98,6 +98,7 @@ def _run_fwd(x2, w, b, eps):
             jax.ShapeDtypeStruct((n, 1), jnp.float32),
         ],
         interpret=interpret_mode(),
+        name="layer_norm_fwd",
     )(x2, w.reshape(1, d), b.reshape(1, d))
     return out, mu, rstd
 
@@ -140,6 +141,7 @@ def _ln_bwd(eps, res, g):
             jax.ShapeDtypeStruct((1, d), jnp.float32),
         ],
         interpret=interpret_mode(),
+        name="layer_norm_bwd",
     )(x2, w.reshape(1, d), mu, rstd, g)
     dw = dw_part[0].astype(w.dtype)
     db = db_part[0].astype(w.dtype)

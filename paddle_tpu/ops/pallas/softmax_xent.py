@@ -88,6 +88,7 @@ def _run_fwd(logits2, labels2, eps):
         out_shape=(jax.ShapeDtypeStruct((n, 1), jnp.float32),
                    jax.ShapeDtypeStruct((n, 1), jnp.float32)),
         interpret=interpret_mode(),
+        name="softmax_xent_fwd",
     )(logits2, labels2)
 
 
@@ -114,6 +115,7 @@ def _run_bwd(logits2, labels2, lse, g, eps):
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((n, v), logits2.dtype),
         interpret=interpret_mode(),
+        name="softmax_xent_bwd",
     )(logits2, labels2, lse, g)
 
 

@@ -296,7 +296,7 @@ class Optimizer:
             if packed is None:
                 self._post_step()
                 return
-            if _monitor.profile.scopes_on:
+            if _monitor.profile.live and _monitor.profile.armed():
                 with _monitor.profile.scope(
                         _monitor.profile.optimizer_scope(self)):
                     self._arena_apply(arena, packed, lr)
@@ -309,7 +309,7 @@ class Optimizer:
                 self._offloader.page_out(arena)
             self._post_step()
             return
-        if _monitor.profile.scopes_on:
+        if _monitor.profile.live and _monitor.profile.armed():
             with _monitor.profile.scope(
                     _monitor.profile.optimizer_scope(self)):
                 return self._apply_update_body(params_grads, lr)

@@ -369,7 +369,7 @@ class ParamArena:
         by_pid = {id(p): g for p, g in params_grads if g is not None}
         if not by_pid:
             return None
-        _monitor.profile.register_scope("arena.pack", "op")
+        _monitor.profile.register_scope("arena.pack", "op", phase="opt")
         packed = []
         with jax.named_scope("arena.pack"):
             for grp in self.groups:

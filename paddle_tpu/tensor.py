@@ -16,6 +16,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from .monitor import trace as _trace
+
 # ---------------------------------------------------------------------------
 # dtype utilities
 
@@ -129,7 +131,10 @@ class Tensor:
     def numpy(self):
         if _arena_hook is not None:
             _arena_hook(self, "read")
-        return np.asarray(jax.device_get(self.data))
+        # the wait for the device and the copy to the host, as a span of
+        # the program on the profiler's clock (one flag check when off)
+        with _trace.span("tensor.to_host"):
+            return np.asarray(jax.device_get(self.data))
 
     def item(self):
         return self.numpy().item()

@@ -11,6 +11,7 @@ the ``compiled_kernels`` fixture steers ``pallas.interpret_mode`` to the
 TPU answer for the duration of one test.
 """
 import os
+import re
 
 import pytest
 
@@ -50,14 +51,21 @@ def _is_shape_dtype(x):
             and not isinstance(x[1], tuple))
 
 
-def _compile(fn, one_chip, *shapes):
+def _compile(fn, one_chip, *shapes, names=()):
     """Lower ``fn`` at ``(shape, dtype)`` arguments placed on the
-    described chip and compile it; returns the tpu_custom_call count."""
+    described chip and compile it; returns the tpu_custom_call count.
+    ``names`` are the ``name=`` of the ``pl.pallas_call``s the program
+    holds: each has to stand in front of a kernel's ``pallas_call`` in
+    the compiled text (the op_name of its custom call), where
+    ``monitor.profile.instruction_ledger`` and a device trace find it."""
     args = jax.tree_util.tree_map(
         lambda sd: jax.ShapeDtypeStruct(sd[0], sd[1], sharding=one_chip),
         shapes, is_leaf=_is_shape_dtype)
-    compiled = jax.jit(fn).lower(*args).compile()
-    return compiled.as_text().count("tpu_custom_call")
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    for name in names:
+        assert re.search(r"[/(]%s\)*/pallas_call" % re.escape(name), text), \
+            f"no kernel named {name} in the compiled text"
+    return text.count("tpu_custom_call")
 
 
 def _grad_sum(f, argnums=0):
@@ -74,7 +82,8 @@ def test_layer_norm_fwd_bwd(one_chip, compiled_kernels, dtype):
     f = _grad_sum(lambda x, w, b: _layer_norm2(x, w, b, 1e-12),
                   argnums=(0, 1, 2))
     n = _compile(f, one_chip, ((8192, 768), dtype),
-                 ((768,), jnp.float32), ((768,), jnp.float32))
+                 ((768,), jnp.float32), ((768,), jnp.float32),
+                 names=("layer_norm_fwd", "layer_norm_bwd"))
     assert n == 2       # forward and backward kernels
 
 
@@ -99,7 +108,8 @@ def _flash_grad(seq, mask_shape=None, causal=False, dropout=0.0):
 def test_flash_fwd_bwd(one_chip, compiled_kernels, seq, dropout):
     qkv = ((1, 12, seq, 64), jnp.bfloat16)
     n = _compile(_flash_grad(seq, dropout=dropout), one_chip,
-                 qkv, qkv, qkv, ((2,), jnp.int32))
+                 qkv, qkv, qkv, ((2,), jnp.int32),
+                 names=("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"))
     assert n == 3       # forward, dq, dk/dv
 
 
@@ -129,7 +139,19 @@ def test_fused_adam(one_chip, compiled_kernels):
         return fused_adam_update(p, g, m, v, 1e-3, 0.9, 0.999)
 
     t = ((2048, 768), jnp.float32)
-    assert _compile(f, one_chip, t, t, t, t) >= 1
+    assert _compile(f, one_chip, t, t, t, t, names=("fused_adam",)) >= 1
+
+
+def test_fused_adam_flat(one_chip, compiled_kernels):
+    """The arena's flat buffer: a 128-lane multiple, as the arena pads."""
+    from paddle_tpu.ops.pallas.fused_adam import fused_adam_update_flat
+
+    def f(p, g, m, v):
+        return fused_adam_update_flat(p, g, m, v, 1e-3, 0.9, 0.999)
+
+    t = ((2048 * 768,), jnp.float32)
+    assert _compile(f, one_chip, t, t, t, t,
+                    names=("fused_adam_flat",)) >= 1
 
 
 def test_fused_adam_multi(one_chip, compiled_kernels):
@@ -139,7 +161,8 @@ def test_fused_adam_multi(one_chip, compiled_kernels):
         return fused_adam_update_multi(ps, gs, ms, vs, 1e-3, 0.9, 0.999)
 
     ts = [((512, 768), jnp.float32), ((768,), jnp.float32)]
-    assert _compile(f, one_chip, ts, ts, ts, ts) >= 1
+    assert _compile(f, one_chip, ts, ts, ts, ts,
+                    names=("fused_adam_multi",)) >= 1
 
 
 def test_batch_norm_fwd_bwd(one_chip, compiled_kernels):
@@ -147,8 +170,16 @@ def test_batch_norm_fwd_bwd(one_chip, compiled_kernels):
     from paddle_tpu.ops.pallas.batch_norm import _batch_norm2
     f = _grad_sum(lambda x, w, b: _batch_norm2(x, w, b, 1e-5)[0])
     n = _compile(f, one_chip, ((128 * 112 * 112, 64), jnp.bfloat16),
-                 ((64,), jnp.float32), ((64,), jnp.float32))
+                 ((64,), jnp.float32), ((64,), jnp.float32),
+                 names=("batch_norm_stats", "batch_norm_bwd_reduce",
+                        "batch_norm_bwd_dx"))
     assert n >= 2
+    # the gradient of a sum needs no normalised output: the forward alone
+    shapes = (((128 * 112 * 112, 64), jnp.bfloat16), ((64,), jnp.float32),
+              ((64,), jnp.float32))
+    assert _compile(lambda x, w, b: _batch_norm2(x, w, b, 1e-5)[0], one_chip,
+                    *shapes, names=("batch_norm_stats",
+                                    "batch_norm_apply")) == 2
 
 
 def test_softmax_xent_fwd_bwd(one_chip, compiled_kernels):
@@ -156,7 +187,8 @@ def test_softmax_xent_fwd_bwd(one_chip, compiled_kernels):
     from paddle_tpu.ops.pallas.softmax_xent import _softmax_xent2
     f = _grad_sum(_softmax_xent2)
     n = _compile(f, one_chip, ((8192, 30522), jnp.float32),
-                 ((8192, 1), jnp.int32))
+                 ((8192, 1), jnp.int32),
+                 names=("softmax_xent_fwd", "softmax_xent_bwd"))
     assert n >= 2
 
 
@@ -240,3 +272,52 @@ def test_to_static_step_on_a_mesh_traces_without_kernels(compiled_kernels):
         out = jit.to_static(lambda t: model(t), models=[model],
                             optimizers=[])(x)
     assert out.shape == [8, 4] or tuple(out.shape) == (8, 4)
+
+
+# -- every kernel has a name of its own -------------------------------------
+
+KERNEL_NAMES = {
+    "batch_norm.py": ["batch_norm_stats", "batch_norm_apply",
+                      "batch_norm_bwd_reduce", "batch_norm_bwd_dx"],
+    "flash_attention.py": ["flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"],
+    "fused_adam.py": ["fused_adam", "fused_adam_multi", "fused_adam_flat"],
+    "layer_norm.py": ["layer_norm_fwd", "layer_norm_bwd"],
+    "softmax_xent.py": ["softmax_xent_fwd", "softmax_xent_bwd"],
+}
+
+
+def _pallas_call_names(path):
+    """The ``name=`` of every ``pl.pallas_call(...)`` in a source file, in
+    order; None where a call site has none or computes it."""
+    import ast
+    with open(path) as f:
+        tree = ast.parse(f.read())
+    out = []
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Call) \
+                and isinstance(node.func, ast.Attribute) \
+                and node.func.attr == "pallas_call":
+            kw = {k.arg: k.value for k in node.keywords}
+            name = kw.get("name")
+            out.append((node.lineno, name.value
+                        if isinstance(name, ast.Constant) else None))
+    return [n for _, n in sorted(out)]
+
+
+@pytest.mark.parametrize("filename", sorted(KERNEL_NAMES))
+def test_every_pallas_call_has_a_stable_name_of_its_own(filename):
+    """A trace tells kernels apart by these names (PERF.md section 3), so
+    a call site without one, or two with the same, is a blind spot."""
+    here = os.path.dirname(os.path.abspath(P.__file__))
+    assert _pallas_call_names(os.path.join(here, filename)) == \
+        KERNEL_NAMES[filename]
+
+
+def test_no_pallas_call_site_is_left_out_and_no_name_is_used_twice():
+    here = os.path.dirname(os.path.abspath(P.__file__))
+    found = {f: _pallas_call_names(os.path.join(here, f))
+             for f in sorted(os.listdir(here)) if f.endswith(".py")}
+    found = {f: names for f, names in found.items() if names}
+    assert found == KERNEL_NAMES
+    every = [n for names in found.values() for n in names]
+    assert len(every) == len(set(every)) == 14

@@ -2,6 +2,7 @@
 classification, fusion-menu ranking, ceilings, and the disabled-mode
 zero-cost contract."""
 import json
+import threading
 
 import numpy as np
 import pytest
@@ -315,28 +316,229 @@ def test_flight_record_bundles_op_ledger(tmp_path):
 
 # -- disabled mode: one flag check, nothing else ------------------------------
 
-def test_disabled_mode_no_scope_no_parse(monkeypatch):
-    assert profile.scopes_on is False
+def _bombs(monkeypatch):
     bomb = lambda *a, **k: (_ for _ in ()).throw(
         AssertionError("profiling touched while disabled"))
-    monkeypatch.setattr(profile, "layer_scope", bomb)
-    monkeypatch.setattr(profile, "fscope", bomb)
-    monkeypatch.setattr(profile, "optimizer_scope", bomb)
-    monkeypatch.setattr(profile, "parse_hlo", bomb)
-    model = nn.Sequential(nn.Linear(4, 4), nn.ReLU())
+    for name in ("layer_scope", "fscope", "optimizer_scope",
+                 "backward_scope", "armed", "parse_hlo"):
+        monkeypatch.setattr(profile, name, bomb)
+
+
+def test_disabled_mode_eager_enters_no_scope_and_parses_nothing(monkeypatch):
+    """Eager dispatch with the profile off: every labelling site is the
+    one check of ``profile.live`` and nothing behind it runs."""
+    assert profile.scopes_on is False and profile.live is False
+    _bombs(monkeypatch)
+    model = nn.Sequential(nn.Linear(4, 4), nn.ReLU(), nn.LayerNorm(4))
     adam = opt.Adam(learning_rate=1e-3, parameters=model.parameters())
-
-    @jit.to_static(models=[model], optimizers=[adam])
-    def step(x, y):
-        loss = F.cross_entropy(model(x), y)
-        loss.backward()
-        adam.step()
-        return loss
-
     x = pt.to_tensor(np.ones((2, 4), dtype="float32"))
     y = pt.to_tensor(np.zeros((2,), dtype="int64"))
-    step(x, y)       # labels, forward, backward, update: no bomb trips
+    for _ in range(2):   # labels, forward, backward, update: no bomb trips
+        loss = F.cross_entropy(F.softmax(model(x)), y)
+        loss.backward()
+        adam.step()
+        adam.clear_grad()
     assert profile.last_report() is None
+    assert profile.scopes() == {}
+
+
+def _bert_tiny_step(seen=None):
+    """A BERT-tiny pre-training step through jit.to_static, as the
+    benchmark builds it, with NOTHING armed by the user."""
+    from paddle_tpu import amp
+    from paddle_tpu.models.bert import BertConfig, BertForPretraining
+    cfg = BertConfig(vocab_size=512, hidden_size=64, num_hidden_layers=2,
+                     num_attention_heads=2, intermediate_size=128,
+                     max_position_embeddings=64, hidden_dropout_prob=0.0,
+                     attention_probs_dropout_prob=0.0)
+    model = BertForPretraining(cfg)
+    adamw = opt.AdamW(learning_rate=1e-4, parameters=model.parameters())
+
+    def bert_step(ids, types, mlm, nsp):
+        if seen is not None:
+            # at trace time, what ANOTHER thread sees of the labelling
+            t = threading.Thread(target=lambda: seen.append(
+                (profile.scopes_on, profile.armed())))
+            t.start()
+            t.join()
+            seen.append((profile.scopes_on, profile.armed()))
+        with amp.auto_cast(dtype="bfloat16"):
+            logits, nsp_logits = model(ids, types)
+        loss = model.loss(logits.astype("float32"),
+                          nsp_logits.astype("float32"), mlm, nsp)
+        loss.backward()
+        adamw.step()
+        adamw.clear_grad()
+        return loss
+
+    step = jit.to_static(bert_step, models=[model], optimizers=[adamw])
+    rng = np.random.default_rng(0)
+    ids = rng.integers(0, 512, (4, 16), dtype=np.int32)
+    mlm = np.where(rng.random((4, 16)) < 0.15,
+                   rng.integers(0, 512, (4, 16)), -1).astype(np.int32)
+    feed = [pt.to_tensor(a) for a in (
+        ids, np.zeros((4, 16), np.int32), mlm,
+        rng.integers(0, 2, (4,), dtype=np.int32))]
+    return step, feed
+
+
+def test_compiled_step_carries_its_scopes_with_nothing_armed(tmp_path):
+    monitor.enable(str(tmp_path))        # keeps the executable; no profile
+    seen = []
+    step, feed = _bert_tiny_step(seen)
+    assert np.isfinite(float(step(*feed).numpy()))
+    # the trace armed its own thread and no other, and put things back
+    assert seen == [(False, False), (False, True)]
+    assert profile.scopes_on is False and profile.live is False
+    assert profile.armed() is False
+
+    rows = profile.instruction_ledger()
+    assert rows and {r["module"] for r in rows} == {"jit_bert_step"}
+    assert {r["label"] for r in rows} == {"jit.bert_step"}
+    flops = {ph: 0.0 for ph in profile.PHASES}
+    named = total = 0.0
+    regions = {ph: set() for ph in profile.PHASES}
+    for r in rows:
+        for part in r["parts"]:
+            flops[part["phase"]] += part["flops"]
+            regions[part["phase"]].add(part["region"])
+            total += part["flops"]
+            if part["phase"] != "none" \
+                    and part["region"] != profile.UNATTRIBUTED:
+                named += part["flops"]
+    assert named / total >= 0.90
+    assert flops["none"] / total < 0.05
+    # backward ops under bwd with their layer's region, the update under opt
+    assert flops["bwd"] > flops["fwd"] > 0 and flops["opt"] > 0
+    assert regions["opt"] == {"opt.AdamW"}
+    assert any(r.endswith("/Linear_0") for r in regions["bwd"])
+    assert any("LayerNorm" in r and r.endswith("F.layer_norm")
+               for r in regions["bwd"])
+    assert not any(r.startswith("opt.") for r in regions["bwd"] | regions["fwd"])
+    # eager code after the step is dark again
+    scopes_before = profile.scopes()
+    nn.Linear(4, 4)(pt.to_tensor(np.ones((2, 4), dtype="float32")))
+    assert profile.scopes() == scopes_before
+
+
+def test_phase_and_region_reads_an_op_name():
+    scope_map = {"step": "root", "bwd": "phase", "Linear_0": "layer",
+                 "F.softmax": "functional", "opt.Adam": "optimizer",
+                 "arena.pack": "op"}
+    phase_map = {"bwd": "bwd", "opt.Adam": "opt", "arena.pack": "opt"}
+    read = lambda name: profile.phase_and_region(name, scope_map, phase_map)
+    assert read("jit(step)/step/Linear_0/dot_general") == ("fwd", "Linear_0")
+    assert read("jit(step)/step/bwd/Linear_0/F.softmax/transpose(jvp())/mul") \
+        == ("bwd", "Linear_0/F.softmax")
+    assert read("jit(step)/step/opt.Adam/sub") == ("opt", "opt.Adam")
+    assert read("jit(step)/step/arena.pack/concatenate") == \
+        ("opt", "arena.pack")
+    # loss scaling and gradient clipping sit under the root and nothing
+    # else: forward by the rule, unattributed by region
+    assert read("jit(step)/step/mul") == ("fwd", profile.UNATTRIBUTED)
+    # no root scope: the compiler's own, an argument's copy, eager code
+    assert read("") == ("none", profile.UNATTRIBUTED)
+    assert read("state_vals[397]") == ("none", profile.UNATTRIBUTED)
+    assert read("jit(other)/Linear_0/add") == ("none", "Linear_0")
+
+
+TWO_PHASE_HLO = """\
+HloModule jit_step, is_scheduled=true
+
+%fused_computation.1 (p0: bf16[512,256], p1: bf16[512,128], p2: f32[256,128], p3: f32[256,128]) -> (f32[256,128], f32[256,128]) {
+  %p0 = bf16[512,256]{1,0} parameter(0)
+  %p1 = bf16[512,128]{1,0} parameter(1)
+  %p2 = f32[256,128]{1,0} parameter(2)
+  %p3 = f32[256,128]{1,0} parameter(3)
+  %dot.1 = f32[256,128]{1,0} dot(bf16[512,256]{1,0} %p0, bf16[512,128]{1,0} %p1), lhs_contracting_dims={0}, rhs_contracting_dims={0}, metadata={op_name="jit(step)/step/bwd/Linear_0/transpose(jvp())/dot_general"}
+  %c = f32[] constant(0.1)
+  %b = f32[256,128]{1,0} broadcast(f32[] %c), dimensions={}
+  %mul.1 = f32[256,128]{1,0} multiply(f32[256,128]{1,0} %dot.1, f32[256,128]{1,0} %b), metadata={op_name="jit(step)/step/opt.SGD/mul"}
+  %add.1 = f32[256,128]{1,0} add(f32[256,128]{1,0} %p3, f32[256,128]{1,0} %mul.1), metadata={op_name="jit(step)/step/opt.SGD/add"}
+  %sub.1 = f32[256,128]{1,0} subtract(f32[256,128]{1,0} %p2, f32[256,128]{1,0} %add.1), metadata={op_name="jit(step)/step/opt.SGD/sub"}
+  ROOT %t = (f32[256,128]{1,0}, f32[256,128]{1,0}) tuple(f32[256,128]{1,0} %sub.1, f32[256,128]{1,0} %add.1)
+}
+
+ENTRY %main.9 (w: f32[256,128], v: f32[256,128], x: bf16[512,256], g: bf16[512,128]) -> (f32[256,128], f32[256,128]) {
+  %w = f32[256,128]{1,0} parameter(0), metadata={op_name="state_vals[0]"}
+  %v = f32[256,128]{1,0} parameter(1)
+  %x = bf16[512,256]{1,0} parameter(2)
+  %g = bf16[512,128]{1,0} parameter(3)
+  %copy-start.1 = (f32[256,128]{1,0}, f32[256,128]{1,0}, u32[]) copy-start(f32[256,128]{1,0} %w)
+  %copy-done.1 = f32[256,128]{1,0} copy-done((f32[256,128]{1,0}, f32[256,128]{1,0}, u32[]) %copy-start.1)
+  %ln = (f32[512,128]{1,0}, f32[512,1]{1,0}) custom-call(bf16[512,128]{1,0} %g), custom_call_target="tpu_custom_call", metadata={op_name="jit(step)/step/bwd/LayerNorm_0/F.layer_norm/transpose(jvp(layer_norm_bwd))/pallas_call"}
+  ROOT %multiply_subtract_fusion.3 = (f32[256,128]{1,0}, f32[256,128]{1,0}) fusion(bf16[512,256]{1,0} %x, bf16[512,128]{1,0} %g, f32[256,128]{1,0} %copy-done.1, f32[256,128]{1,0} %v), kind=kOutput, calls=%fused_computation.1, metadata={op_name="jit(step)/step/opt.SGD/sub"}
+}
+"""
+_TWO_PHASE_SCOPES = {"step": "root", "bwd": "phase", "Linear_0": "layer",
+                     "LayerNorm_0": "layer", "F.layer_norm": "functional",
+                     "opt.SGD": "optimizer"}
+_TWO_PHASE_PHASES = {"bwd": "bwd", "opt.SGD": "opt"}
+
+
+def test_instruction_ledger_splits_a_fusion_that_holds_two_phases():
+    rows = profile.instruction_ledger(
+        label="fixture", hlo=TWO_PHASE_HLO, scope_map=_TWO_PHASE_SCOPES,
+        phase_map=_TWO_PHASE_PHASES)
+    by_name = {r["name"]: r for r in rows}
+    assert set(by_name) == {"copy-start.1", "copy-done.1", "ln",
+                            "multiply_subtract_fusion.3"}
+    assert {r["module"] for r in rows} == {"jit_step"}
+    fusion = by_name["multiply_subtract_fusion.3"]
+    assert fusion["opcode"] == "fusion" and fusion["kernel"] is None
+    parts = {(p["phase"], p["region"]): p for p in fusion["parts"]}
+    assert set(parts) == {("bwd", "Linear_0"), ("opt", "opt.SGD")}
+    # the weight-gradient matmul and the three elementwise ops of the update
+    assert parts[("bwd", "Linear_0")]["flops"] == 2 * 256 * 128 * 512
+    assert parts[("opt", "opt.SGD")]["flops"] == 3 * 256 * 128
+    # bytes are what crosses the fusion's boundary, charged to the
+    # instruction that reads or makes them: both bf16 operands to the
+    # matmul; w, v and the two results to the update
+    assert parts[("bwd", "Linear_0")]["bytes"] == 2 * (512 * 256 + 512 * 128)
+    assert parts[("opt", "opt.SGD")]["bytes"] == 4 * 4 * 256 * 128
+    # a Pallas kernel is known by its name=
+    assert by_name["ln"]["kernel"] == "layer_norm_bwd"
+    assert [(p["phase"], p["region"]) for p in by_name["ln"]["parts"]] == \
+        [("bwd", "LayerNorm_0/F.layer_norm")]
+    # what the compiler made to move data takes the labels of what it serves
+    for name in ("copy-start.1", "copy-done.1"):
+        assert by_name[name]["serves"] == "multiply_subtract_fusion.3"
+        assert [(p["phase"], p["region"]) for p in by_name[name]["parts"]] \
+            == [("opt", "opt.SGD")]
+    assert fusion["serves"] is None
+
+
+def test_instruction_ledger_is_empty_without_a_captured_executable():
+    assert profile.instruction_ledger() == []
+
+
+def test_conv_flops_count_live_window_positions_only():
+    """On TPU a batched matmul is a convolution whose batch dimensions sit
+    in a dilated window with one live position per output."""
+    batched = """\
+HloModule m
+
+ENTRY %main (a: bf16[64,12,128,64], b: bf16[64,12,128,64]) -> f32[64,12,128,128] {
+  %a = bf16[64,12,128,64]{3,2,1,0} parameter(0)
+  %b = bf16[64,12,128,64]{3,2,1,0} parameter(1)
+  ROOT %c = f32[64,12,128,128]{3,2,1,0} convolution(bf16[64,12,128,64]{3,2,1,0} %a, bf16[64,12,128,64]{3,2,1,0} %b), window={size=64x12 stride=63x11 lhs_dilate=64x12}, dim_labels=01bf_01oi->01bf, metadata={op_name="jit(f)/root/L0/dot_general"}
+}
+"""
+    rep = profile.attribute(batched, scope_map={"root": "root", "L0": "layer"})
+    assert rep["total_flops"] == 2 * 64 * 12 * 128 * 128 * 64
+    plain = """\
+HloModule m
+
+ENTRY %main (x: f32[8,3,32,32], k: f32[16,3,3,3]) -> f32[8,16,16,16] {
+  %x = f32[8,3,32,32]{3,2,1,0} parameter(0)
+  %k = f32[16,3,3,3]{3,2,1,0} parameter(1)
+  ROOT %c = f32[8,16,16,16]{3,2,1,0} convolution(f32[8,3,32,32]{3,2,1,0} %x, f32[16,3,3,3]{3,2,1,0} %k), window={size=3x3 stride=2x2 pad=1_1x1_1}, dim_labels=bf01_oi01->bf01, metadata={op_name="jit(f)/root/L0/conv"}
+}
+"""
+    rep = profile.attribute(plain, scope_map={"root": "root", "L0": "layer"})
+    # 16 outputs a side: the first hangs one tap into the low padding
+    live = 16 * 3 - 1
+    assert rep["total_flops"] == 2 * 8 * 16 * 3 * live * live
 
 
 def test_enable_env_var(tmp_path, monkeypatch):

@@ -117,12 +117,101 @@ def test_disable_keeps_buffer_clear_empties_it():
     assert trace.events() == []
 
 
-def test_bridge_annotation_smoke():
-    # TraceAnnotation bridging must never break span recording
-    trace.enable(bridge=True)
+def _count_annotations(monkeypatch):
+    """Stand in for jax.profiler.TraceAnnotation: every span that enters
+    one is listed by name."""
+    entered = []
+
+    class Annotation:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            entered.append(self.name)
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(trace, "_annotation", Annotation)
+    return entered
+
+
+def test_bridge_annotation_smoke(monkeypatch):
+    # the tracer on: a span is an annotation on the profiler's clock AND
+    # an event pair in the ring, with no second switch; an annotation
+    # that fails never breaks span recording
+    entered = _count_annotations(monkeypatch)
+    trace.enable()
     with trace.span("bridged"):
         pass
+    assert entered == ["bridged"]
     assert [e[0] for e in trace.events()] == ["B", "E"]
+    assert not hasattr(trace, "_bridge")
+    with pytest.raises(TypeError):
+        trace.enable(bridge=True)
+
+    def broken(name):
+        raise RuntimeError("no profiler here")
+    monkeypatch.setattr(trace, "_annotation", broken)
+    with trace.span("still_recorded"):
+        pass
+    assert [e[1] for e in trace.events()][-2:] == ["still_recorded"] * 2
+
+
+def test_monitor_on_tracer_off_annotates_and_records_nothing(monkeypatch):
+    entered = _count_annotations(monkeypatch)
+    assert trace.span("dark") is trace._NULL      # both off: one flag check
+    monitor.enable()
+    assert not trace.enabled()
+    with trace.span("jit.collect"):
+        pass
+
+    @trace.traced("decorated")
+    def f():
+        return 7
+
+    assert f() == 7
+    assert entered == ["jit.collect", "decorated"]
+    assert trace.events() == []
+    # a span that was entered while the ring was off stays out of it
+    sp = trace.span("straddles")
+    with sp:
+        trace.enable()
+    assert [e[1] for e in trace.events()] == []
+    trace.disable()
+    monitor.disable(flush_counters=False)
+    assert trace.span("dark_again") is trace._NULL
+
+
+def test_the_real_annotation_is_entered_without_a_profiler_session():
+    monitor.enable()
+    with trace.span("jit.execute"):      # jax.profiler.TraceAnnotation
+        pass
+    assert trace.events() == []
+
+
+def test_compiled_step_call_and_host_read_are_nested_spans(monkeypatch):
+    entered = _count_annotations(monkeypatch)
+    from paddle_tpu import jit, nn
+    monitor.enable()
+    trace.enable()
+    layer = nn.Linear(4, 4)
+    step = jit.to_static(lambda x: layer(x), models=[layer], optimizers=[])
+    x = pt.to_tensor(np.ones((2, 4), "float32"))
+    step(x)                     # compiles
+    trace.clear()
+    del entered[:]
+    step(x).numpy()
+    assert entered == ["jit.<lambda>", "jit.collect", "jit.execute",
+                       "jit.writeback", "tensor.to_host"]
+    # each of the three closes before the next opens, all inside jit.<fn>
+    assert [(e[0], e[1]) for e in trace.events()] == [
+        ("B", "jit.<lambda>"), ("B", "jit.collect"), ("E", "jit.collect"),
+        ("B", "jit.execute"), ("E", "jit.execute"),
+        ("B", "jit.writeback"), ("E", "jit.writeback"),
+        ("E", "jit.<lambda>"),
+        ("B", "tensor.to_host"), ("E", "tensor.to_host")]
 
 
 # -- export -------------------------------------------------------------------
