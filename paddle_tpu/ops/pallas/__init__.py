@@ -55,11 +55,15 @@ _AUTO_ON = {"layer_norm": True, "flash_attention": True,
 
 
 # flash is an O(S^2)-score win: below some sequence length the XLA sdpa
-# (one fused attention) beats the blocked kernel's overheads. Measured
-# on v5e (scripts/tune_flash.py + ablate_bert.py, same record):
-# seq 128 flash loses 11% full-model; seq 512 is a wash (flash ahead
-# ~5% kernel-only, and O(S) memory tiebreaks); seq 2048 flash wins
-# 1.53x kernel-only. Crossover set at 512; 0 = flash whenever enabled.
+# (one fused attention) beats the blocked kernel's overheads. The
+# crossover (512; 0 = flash whenever enabled) was set on a superseded
+# toolchain and has not been re-derived: no cell runs both paths at one
+# length. What the benchmark measures on the v5e today (PERF.md §5-6,
+# PR 26): at seq 512 (bert_base.pretrain_seq512, 16 x 12 heads x 512 x 64)
+# the three flash kernels take 8.98 ms of a 66.3 ms step, 30.6 % of their
+# roofline; at seq 128 the gate sends attention to sdpa (attention core
+# 4.3 ms of 56.3). Whether sdpa would beat 8.98 ms at 512, or flash 4.3 ms
+# at 128, is unmeasured.
 _FLASH_MIN_SEQ_DEFAULT = 512
 _flash_min_seq = _FLASH_MIN_SEQ_DEFAULT
 _UNSET = object()

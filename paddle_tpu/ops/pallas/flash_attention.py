@@ -13,18 +13,32 @@ TPU PRNG, seeded per (bh, q-block, k-block) tile so the backward
 regenerates the identical keep-mask without ever storing it.
 
 Backward: two kernels. dQ: grid (bh, q-blocks) loops k-blocks; dK/dV:
-grid (bh, k-blocks) loops q-blocks, accumulating dv = pd^T @ dO and
-dk = ds^T @ Q. Both recompute p = exp(s - m) / l from the saved PER-ROW
+grid (bh, k-blocks) loops q-blocks over the TRANSPOSED score tile
+s^T = K Q^T, accumulating dv = pd^T @ dO and dk = ds^T @ Q with no
+transpose of a tile. Both recompute p = exp(s - m) / l from the saved PER-ROW
 (max m, normalizer l) — deliberately NOT the folded lse = m + log l: with
 a finite large-negative additive mask (the -1e9 convention) s and m are
 ~1e9-scale where f32 ulp is 64, so s − m reproduces the forward's (and
 sdpa's) rounding exactly while s − (m + log l) would silently lose the
 entire log-normalizer. delta = rowsum(dO∘O) is one cheap XLA reduction
 outside the kernels (the identity Σ_k p_k·dp_k = rowsum(dO∘O) holds under
-dropout too). Row stats are stored (…, 1) between passes and broadcast to
-(…, 128) lanes only transiently around each kernel call (Mosaic-trivial
-layouts without holding 128× residual HBM — same lane-replication scheme
-as the upstream pallas TPU attention kernel).
+dropout too).
+
+Row statistics (m, l, and the backward's 1/l and delta) cross HBM as ONE
+f32 a (batch·head, row): (BH, 1, S) arrays with the sequence on the lane
+axis — as a forward result, as the residual saved for the backward, and as
+an operand of both backward kernels. Inside a kernel a statistic is a
+(BQ, 1) column (it broadcasts along a score tile's keys); the forward
+relays its two carries to (1, BQ) rows once a q-block before the store
+(_stat_row) and the dQ kernel relays the three rows it reads back to
+columns (_stat_col): in-VMEM relayouts, no HBM traffic. The dK/dV kernel
+needs none: a (1, BQ) row broadcasts down the sublanes of its transposed
+tile as it is. There is no lane-replicated
+(…, 128) copy and no (…, 1) custom-call operand (whose tiled layout pads
+the 1 to 128 lanes in HBM) on any path: at BERT-base's seq-512 shapes
+those cost 50 MB an array — 650 MB a layer against 190 MB of q/k/v/o/dO
+traffic, and 5.4 ms of a 74.8 ms step in the XLA ops that made and sliced
+them (PERF.md §6, PR 26).
 """
 from __future__ import annotations
 
@@ -37,7 +51,28 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30
-_LANES = 128
+
+
+# A row statistic changes between column (BQ, 1) and row (1, BQ) through
+# one lane tile in VMEM: replicate, transpose, keep one. Of the forms Mosaic
+# lowers (reshape, expand_dims, .T, this), this one leaves the column in the
+# layout the score tiles broadcast from cheaply: a reshape is cheaper to
+# make but costs the dQ kernel 11-14 % at seq 2048, where a program walks
+# several k-blocks (PERF.md §6, PR 26).
+_TILE = 128
+
+
+def _stat_row(col):
+    """(BQ, 1) carry of a row statistic -> the (1, BQ) lane-major row that
+    crosses HBM."""
+    return jnp.broadcast_to(col, (col.shape[0], _TILE)).T[:1, :]
+
+
+def _stat_col(row):
+    """(1, BQ) lane-major row, as read from HBM -> the (BQ, 1) column that
+    broadcasts along a score tile's keys (also the key bias of the dK/dV
+    kernel's transposed tile)."""
+    return jnp.broadcast_to(row, (_TILE, row.shape[1])).T[:, :1]
 
 
 def _dropout_keep(seed_ref, bh, qi, j, shape, threshold):
@@ -137,8 +172,8 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, mask_ref, keep_ref, o_ref,
     m, l, acc = jax.lax.fori_loop(0, nk_needed, body, (m0, l0, acc0))
     o_ref[0] = (acc / jnp.maximum(l, 1e-20)).astype(o_ref.dtype)
     m_fin = jnp.where(m <= _NEG_INF, 0.0, m)
-    m_ref[0] = jax.lax.broadcast_in_dim(m_fin, m_ref.shape[1:], (0, 1))
-    l_ref[0] = jax.lax.broadcast_in_dim(l, l_ref.shape[1:], (0, 1))
+    m_ref[0] = _stat_row(m_fin)
+    l_ref[0] = _stat_row(l)
 
 
 def _bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, mask_ref, keep_ref,
@@ -147,9 +182,9 @@ def _bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, mask_ref, keep_ref,
                    threshold, drop_mode):
     q = q_ref[0].astype(jnp.float32) * scale
     do = do_ref[0].astype(jnp.float32)
-    mrow = m_ref[0][:, :1]       # (BQ, 1)
-    linv = linv_ref[0][:, :1]    # (BQ, 1)
-    delta = delta_ref[0][:, :1]  # (BQ, 1)
+    mrow = _stat_col(m_ref[0])       # (1, BQ) -> (BQ, 1)
+    linv = _stat_col(linv_ref[0])
+    delta = _stat_col(delta_ref[0])
     bh = pl.program_id(0)
     qi = pl.program_id(1)
     dq0 = jnp.zeros((q.shape[0], q_ref.shape[2]), jnp.float32)
@@ -187,47 +222,55 @@ def _bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, mask_ref, keep_ref,
                     dropout_p, threshold, drop_mode):
     # this program owns ONE k-block (grid (bh, k-blocks)) and loops
     # q-blocks. q_ref/do_ref: (1, SQp, D); k_ref/v_ref: (1, BK, D);
-    # mask_ref: (1, {1, SQp}, BK); m/linv/delta: (1, SQp, LANES)
+    # mask_ref: (1, {1, SQp}, BK); m/linv/delta: (1, 1, SQp).
+    # The score tile is computed TRANSPOSED, s^T = K Q^T (BK, BQ): the
+    # (1, BQ) row statistics broadcast down its sublanes as they are read
+    # (no relayout), and p^T, ds^T are already the left operands of
+    # dv = p^T dO and dk = ds^T Q (no transpose of a score tile).
     bh = pl.program_id(0)
     j = pl.program_id(1)
     k = k_ref[0].astype(jnp.float32)
     v = v_ref[0].astype(jnp.float32)
+    if mask_mode == "key":      # (1, BK) key bias -> (BK, 1), once
+        kbias = _stat_col(mask_ref[0].astype(jnp.float32))
 
     def body(qi, carry):
         dk, dv = carry
-        q = q_ref[0, pl.ds(qi * block_q, block_q), :].astype(
-            jnp.float32) * scale
-        do = do_ref[0, pl.ds(qi * block_q, block_q), :].astype(jnp.float32)
-        mrow = m_ref[0, pl.ds(qi * block_q, block_q), :][:, :1]
-        linv = linv_ref[0, pl.ds(qi * block_q, block_q), :][:, :1]
-        delta = delta_ref[0, pl.ds(qi * block_q, block_q), :][:, :1]
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)
+        rows = pl.ds(qi * block_q, block_q)
+        q = q_ref[0, rows, :].astype(jnp.float32) * scale
+        do = do_ref[0, rows, :].astype(jnp.float32)
+        mrow = m_ref[0, :, rows]            # (1, BQ)
+        linv = linv_ref[0, :, rows]
+        delta = delta_ref[0, :, rows]
+        st = jnp.dot(k, q.T, preferred_element_type=jnp.float32)
         if mask_mode == "key":
-            s = s + mask_ref[0, :, :].astype(jnp.float32)  # (1, BK)
+            st = st + kbias
         elif mask_mode == "full":
-            s = s + mask_ref[0, pl.ds(qi * block_q, block_q), :].astype(
-                jnp.float32)
-        q_pos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        k_pos = j * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            st = st + mask_ref[0, rows, :].astype(jnp.float32).T
+        k_pos = j * block_k + jax.lax.broadcasted_iota(jnp.int32, st.shape, 0)
+        q_pos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, st.shape, 1)
         valid = (q_pos < sq) & (k_pos < sk)
         if causal:
             valid = valid & (q_pos >= k_pos)
-        p = jnp.where(valid, jnp.exp(jnp.where(valid, s, _NEG_INF) - mrow)
-                      * linv, 0.0)
-        dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
-        pd = p
+        pt = jnp.where(valid, jnp.exp(jnp.where(valid, st, _NEG_INF) - mrow)
+                       * linv, 0.0)
+        dpt = jnp.dot(v, do.T, preferred_element_type=jnp.float32)
+        pdt = pt
         if dropout_p > 0.0:
+            # the forward's (BQ, BK) keep tile, transposed
             if drop_mode == "prng":
-                keep = _dropout_keep(seed_ref, bh, qi, j, p.shape,
-                                     threshold)
+                keep = jnp.where(_dropout_keep(seed_ref, bh, qi, j,
+                                               (block_q, block_k),
+                                               threshold), 1.0, 0.0)
             else:
-                keep = keep_ref[0, pl.ds(qi * block_q, block_q), :] > 0.5
-            pd = jnp.where(keep, p / (1.0 - dropout_p), 0.0)
-            dp = jnp.where(keep, dp / (1.0 - dropout_p), 0.0)
-        dv = dv + jnp.dot(pd.T, do, preferred_element_type=jnp.float32)
-        ds = p * (dp - delta)
+                keep = keep_ref[0, rows, :]
+            keep = keep.T > 0.5
+            pdt = jnp.where(keep, pt / (1.0 - dropout_p), 0.0)
+            dpt = jnp.where(keep, dpt / (1.0 - dropout_p), 0.0)
+        dv = dv + jnp.dot(pdt, do, preferred_element_type=jnp.float32)
+        dst = pt * (dpt - delta)
         # q above is pre-scaled, so ds^T @ (q·scale) is already dk
-        dk = dk + jnp.dot(ds.T, q, preferred_element_type=jnp.float32)
+        dk = dk + jnp.dot(dst, q, preferred_element_type=jnp.float32)
         return dk, dv
 
     nq = pl.cdiv(sq, block_q)
@@ -291,12 +334,6 @@ def _pad_axis(x, axis, new):
     return jnp.pad(x, pads)
 
 
-def _lanes(stat, sq_pad):
-    """(BH, SQ, 1) row stat → transient lane-replicated (BH, SQp, LANES)."""
-    stat = _pad_axis(stat, 1, sq_pad)
-    return jnp.broadcast_to(stat, stat.shape[:2] + (_LANES,))
-
-
 def _flash_fwd_res(q, k, v, mask, mask_mode, seed, causal, scale, block_q,
                    block_k, dropout_p):
     from . import interpret_mode
@@ -341,6 +378,9 @@ def _flash_fwd_res(q, k, v, mask, mask_mode, seed, causal, scale, block_q,
         kspec = pl.BlockSpec((1, 1, 1), lambda i, j: (0, 0, 0),
                              memory_space=pltpu.VMEM)
 
+    # row statistics leave as (BH, 1, SQ): one value a row, seq on lanes
+    stat_spec = pl.BlockSpec((1, 1, bq), lambda i, j: (i, 0, j),
+                             memory_space=pltpu.VMEM)
     out, mrow, lrow = pl.pallas_call(
         functools.partial(
             _fwd_kernel, block_q=bq, block_k=bk, sq=sq, sk=sk,
@@ -361,21 +401,18 @@ def _flash_fwd_res(q, k, v, mask, mask_mode, seed, causal, scale, block_q,
         out_specs=[
             pl.BlockSpec((1, bq, d), lambda i, j: (i, j, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bq, _LANES), lambda i, j: (i, j, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bq, _LANES), lambda i, j: (i, j, 0),
-                         memory_space=pltpu.VMEM),
+            stat_spec,
+            stat_spec,
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
-            jax.ShapeDtypeStruct((b * h, sq, _LANES), jnp.float32),
-            jax.ShapeDtypeStruct((b * h, sq, _LANES), jnp.float32),
+            jax.ShapeDtypeStruct((b * h, 1, sq), jnp.float32),
+            jax.ShapeDtypeStruct((b * h, 1, sq), jnp.float32),
         ],
         interpret=interp,
         name="flash_fwd",
     )(seed2, q3, k3, v3, m3, keep3)
-    # keep only one lane as residuals (128× smaller across the fwd→bwd gap)
-    return out.reshape(b, h, sq, d), mrow[..., :1], lrow[..., :1]
+    return out.reshape(b, h, sq, d), mrow, lrow
 
 
 def _flash_bwd(q, k, v, mask, mask_mode, seed, out, mrow, lrow, g, causal,
@@ -396,12 +433,15 @@ def _flash_bwd(q, k, v, mask, mask_mode, seed, out, mrow, lrow, g, causal,
     do3 = g.reshape(b * h, sq, d)
     # delta_i = Σ_d dO_id·O_id (= Σ_k p_ik·dp_ik — valid under dropout too)
     delta = jnp.sum(do3.astype(jnp.float32) *
-                    out.reshape(b * h, sq, d).astype(jnp.float32), axis=-1,
-                    keepdims=True)
+                    out.reshape(b * h, sq, d).astype(jnp.float32),
+                    axis=-1)[:, None, :]
     linv = 1.0 / jnp.maximum(lrow, 1e-20)
-    mb_l = _lanes(mrow, sq_pad)
-    linv_l = _lanes(linv, sq_pad)
-    delta_l = _lanes(delta, sq_pad)
+    # (BH, 1, SQp), as the forward wrote them: no lane replication
+    stats = [_pad_axis(x, 2, sq_pad) for x in (mrow, linv, delta)]
+    stat_q = pl.BlockSpec((1, 1, bq), lambda i, j: (i, 0, j),
+                          memory_space=pltpu.VMEM)
+    stat_all = pl.BlockSpec((1, 1, sq_pad), lambda i, j: (i, 0, 0),
+                            memory_space=pltpu.VMEM)
 
     if mask_mode in ("key", "full"):
         m3, bh_to_g = _mask_operand(mask, mask_mode, h, sq_pad, sk_pad)
@@ -448,12 +488,9 @@ def _flash_bwd(q, k, v, mask, mask_mode, seed, out, mrow, lrow, g, causal,
                          memory_space=pltpu.VMEM),
             mspec_q,
             kspec_q,
-            pl.BlockSpec((1, bq, _LANES), lambda i, j: (i, j, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bq, _LANES), lambda i, j: (i, j, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bq, _LANES), lambda i, j: (i, j, 0),
-                         memory_space=pltpu.VMEM),
+            stat_q,
+            stat_q,
+            stat_q,
             pl.BlockSpec((1, bq, d), lambda i, j: (i, j, 0),
                          memory_space=pltpu.VMEM),
         ],
@@ -462,7 +499,7 @@ def _flash_bwd(q, k, v, mask, mask_mode, seed, out, mrow, lrow, g, causal,
         out_shape=jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
         interpret=interp,
         name="flash_bwd_dq",
-    )(seed2, q3, k3, v3, m3, keep3, mb_l, linv_l, delta_l, do3)
+    )(seed2, q3, k3, v3, m3, keep3, *stats, do3)
 
     # dK/dV pass needs whole-Q operands padded to the block multiple
     q3p = _pad_axis(q3, 1, sq_pad)
@@ -485,12 +522,9 @@ def _flash_bwd(q, k, v, mask, mask_mode, seed, out, mrow, lrow, g, causal,
             pl.BlockSpec((1, msq_blk, bk), lambda i, j: (bh_to_g(i), 0, j),
                          memory_space=pltpu.VMEM),
             kspec_kv,
-            pl.BlockSpec((1, sq_pad, _LANES), lambda i, j: (i, 0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, sq_pad, _LANES), lambda i, j: (i, 0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, sq_pad, _LANES), lambda i, j: (i, 0, 0),
-                         memory_space=pltpu.VMEM),
+            stat_all,
+            stat_all,
+            stat_all,
             pl.BlockSpec((1, sq_pad, d), lambda i, j: (i, 0, 0),
                          memory_space=pltpu.VMEM),
         ],
@@ -506,7 +540,7 @@ def _flash_bwd(q, k, v, mask, mask_mode, seed, out, mrow, lrow, g, causal,
         ],
         interpret=interp,
         name="flash_bwd_dkv",
-    )(seed2, q3p, k3, v3, m3, keep3, mb_l, linv_l, delta_l, do3p)
+    )(seed2, q3p, k3, v3, m3, keep3, *stats, do3p)
     dk = dk[:, :sk].reshape(b, h, sk, d)
     dv = dv[:, :sk].reshape(b, h, sk, d)
     return dq.reshape(b, h, sq, d), dk, dv
