@@ -25,7 +25,8 @@ SECTIONS = [
               "paddle_tpu.device", "paddle_tpu.param_attr"]),
     ("Ops", ["paddle_tpu.ops.math", "paddle_tpu.ops.manip",
              "paddle_tpu.ops.creation", "paddle_tpu.ops.nn_ops",
-             "paddle_tpu.ops.loss", "paddle_tpu.ops.sequence",
+             "paddle_tpu.ops.loss", "paddle_tpu.ops.ssm",
+             "paddle_tpu.ops.moe", "paddle_tpu.ops.sequence",
              "paddle_tpu.ops.crf", "paddle_tpu.ops.ctc",
              "paddle_tpu.ops.detection", "paddle_tpu.ops.control_flow",
              "paddle_tpu.ops.imperative_flow"]),

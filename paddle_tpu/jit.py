@@ -508,6 +508,13 @@ def recompute(layer_or_fn, *args, policy=None, **kwargs):
         # checkpoint outputs and re-stash them afterwards
         moe_subs = [l for l in layer.sublayers(include_self=True)
                     if isinstance(l, MoEFFN)]
+        # buffers the subtree WRITES (running statistics, device
+        # counters) leave the checkpoint the same way, as explicit
+        # outputs, and are put back into their holders afterwards:
+        # bind_state restores every holder when the body ends. Which
+        # ones were written is seen while the body traces.
+        buffer_names = [n for n in names if n.startswith("buffer:")]
+        written = []
 
         def impl(rng_key, *vals):
             # the RNG key is threaded EXPLICITLY: stochastic ops inside
@@ -531,21 +538,27 @@ def recompute(layer_or_fn, *args, policy=None, **kwargs):
                     with bind_state(layer, state):
                         with _ag.no_grad():
                             out = layer(*full, **kwargs)
+                        written[:] = [n for n in buffer_names
+                                      if holder_map[n].data is not state[n]]
+                        new_buffers = tuple(holder_map[n].data
+                                            for n in written)
             finally:
                 prandom._global_key.data = saved
             out = out.data if isinstance(out, Tensor) else out
             auxs = tuple(l.aux_loss.data for l in moe_subs)
-            return (out,) + auxs if moe_subs else out
+            extra = auxs + new_buffers
+            return (out,) + extra if extra else out
 
         ckpt = jax.checkpoint(impl, policy=ckpt_policy)
         tensors = (prandom.next_key_graph(),) + live_args + tuple(
             holder_map[n] for n in names)
-        if not moe_subs:
-            return apply(ckpt, tensors, name="recompute")
-        res = apply(ckpt, tensors, name="recompute",
-                    n_out=1 + len(moe_subs))
+        res = apply(ckpt, tensors, name="recompute")
+        if not isinstance(res, tuple):
+            return res
         for l, a in zip(moe_subs, res[1:]):
             l.aux_loss = a
+        for n, t in zip(written, res[1 + len(moe_subs):]):
+            holder_map[n].data = t.data
         return res[0]
 
     fn = layer_or_fn

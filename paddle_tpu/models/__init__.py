@@ -24,6 +24,9 @@ def __getattr__(name):
     if name in ("Bert", "BertConfig", "BertForPretraining"):
         from . import bert
         return getattr(bert, name)
+    if name in ("NemotronHConfig", "NemotronHForCausalLM"):
+        from . import nemotron_h
+        return getattr(nemotron_h, name)
     if name in ("Transformer",):
         from . import transformer
         return getattr(transformer, name)

@@ -190,7 +190,7 @@ __all__ = [
     "transformer_train_flops_per_token", "device_memory_stats",
     "GoodputLedger", "GOODPUT_CATEGORIES",
     "read_jsonl", "trace", "xla", "serve", "export", "sampler",
-    "profile", "memory", "fleet", "alerts",
+    "profile", "memory", "fleet", "alerts", "device_counters",
 ]
 
 _registry = Registry()
@@ -385,4 +385,4 @@ def record_collective(op, axis_name, nbytes):
 # imported last: the submodules reach back into this namespace
 # (gauge/emit/snapshot), which is fully populated by this point
 from . import (trace, xla, export, sampler, profile,  # noqa: E402,F401
-               memory, fleet, alerts)
+               memory, fleet, alerts, device_counters)

@@ -925,6 +925,40 @@ def _serves(instrs):
     return out
 
 
+def _callers(comps):
+    """{computation: the ``while`` / ``conditional`` / ``call`` instruction
+    that runs it as a body, a condition or a branch}."""
+    out = {}
+    for comp in comps.values():
+        for instr in comp["instrs"]:
+            attrs = instr["attrs"]
+            ran = _REF_RE["inline"].findall(attrs)
+            for group in _REF_RE["inline_set"].findall(attrs):
+                ran += [t.strip().lstrip("%") for t in group.split(",")]
+            if instr["opcode"] == "call":
+                ran += _REF_RE["to_apply"].findall(attrs)
+            for target in ran:
+                if target:
+                    out[target] = instr
+    return out
+
+
+def _loop_it_runs_in(cname, callers, comps):
+    """(op_name, name) of the nearest labelled instruction that runs the
+    computation ``cname``, or (None, None): what a loop body's own data
+    movement (a prefetch that only the body's ROOT tuple reads) serves
+    is the loop."""
+    seen = set()
+    while cname in callers and cname not in seen:
+        seen.add(cname)
+        caller = callers[cname]
+        if _labelled(caller["op_name"]):
+            return caller["op_name"], caller["name"]
+        cname = next((c for c, comp in comps.items()
+                      if caller in comp["instrs"]), None)
+    return None, None
+
+
 def _instruction_parts(instr, comps, scope_map, phase_map, op_name=None):
     """{(phase, region): [flops, bytes]} of one top-level instruction. A
     fusion is split over its inner instructions' own op_names; the inner
@@ -997,12 +1031,16 @@ def instruction_ledger(label=None, hlo=None, scope_map=None,
             continue
         m = _MODULE_RE.search(text)
         module = m.group(1) if m else None
+        callers = _callers(comps)
         for cname in _running_computations(comps, entry, refs):
             serves = _serves(comps[cname]["instrs"])
+            loop = _loop_it_runs_in(cname, callers, comps)
             for instr in comps[cname]["instrs"]:
                 if instr["opcode"] in _SKIP_OPS:
                     continue
-                op_name, served = serves.get(instr["name"], (None, None))
+                op_name, served = serves.get(
+                    instr["name"],
+                    (None, None) if _labelled(instr["op_name"]) else loop)
                 parts = _instruction_parts(instr, comps, scope_map,
                                            phase_map, op_name)
                 rows.append({

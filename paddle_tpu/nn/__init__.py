@@ -9,7 +9,7 @@ from .layers import (
     Linear, Conv2D, Conv2DTranspose, Conv3D, MaxPool2D, AvgPool2D,
     AdaptiveAvgPool2D, Pool2D, BatchNorm, BatchNorm1D, BatchNorm2D,
     BatchNorm3D, SyncBatchNorm, LayerNorm, GroupNorm, InstanceNorm2D,
-    SpectralNorm, Embedding, Dropout, PRelu, BilinearTensorProduct, GRUUnit,
+    RMSNorm, SpectralNorm, Embedding, Dropout, PRelu, BilinearTensorProduct, GRUUnit,
     Flatten, Upsample, Pad2D,
     ReLU, ReLU6, LeakyReLU, GELU, Sigmoid, Tanh, Softmax, LogSoftmax,
     Softplus, Hardswish, Hardsigmoid, Swish, Silu, Mish, ELU, SELU, Hardtanh,
@@ -36,7 +36,8 @@ from ..static import data  # noqa: F401
 from ..ops import nn_ops as conv  # reference exports its conv module
 from .layers import Upsample as UpSample  # noqa: F401 (2.0-alpha name)
 from .layers import HSigmoid  # noqa: F401
-from .moe import MoEFFN, moe_aux_loss  # noqa: F401
+from .moe import MoEFFN, RoutedMoE, moe_aux_loss  # noqa: F401
+from .hybrid import Mamba2Mixer, GroupedQueryAttention  # noqa: F401
 from ..fluid.dygraph import RowConv  # noqa: F401
 
 # paddle.nn 1.x functional tails (reference: python/paddle/nn/

@@ -1,0 +1,136 @@
+"""Sequence mixers of hybrid language models: a Mamba-2 state-space mixer
+and grouped-query attention.
+
+No reference counterpart in Paddle Fluid 1.7. Both map ``[B, S, hidden]``
+to ``[B, S, hidden]`` with no bias, no dropout and no cache: the training
+path. (A state cache for serving is future work, PERF.md section 7.)
+"""
+from __future__ import annotations
+
+import math
+
+import numpy as np
+
+from .layer import Layer
+from .layers import Linear, RMSNorm
+from .. import initializer as I
+from ..ops import ssm as S
+
+__all__ = ["Mamba2Mixer", "GroupedQueryAttention"]
+
+
+class Mamba2Mixer(Layer):
+    """Mamba-2 mixer (Dao & Gu, arXiv:2405.21060) as the ``nemotron_h``
+    and ``mamba2`` model codes lay it out: ``[z | xBC | dt] = u W_in``; a
+    causal depthwise convolution of ``conv_kernel`` taps and SiLU over
+    ``xBC = [x | B | C]``; the selective recurrence (``F.ssd_scan``) with
+    ``num_heads`` heads of ``head_dim``, ``n_groups`` groups of ``B`` and
+    ``C`` of ``state_size`` (head h reads group ``h // (num_heads /
+    n_groups)``); the gate ``silu(z)`` goes on before a grouped RMS norm;
+    then ``W_out``. The inner width is ``num_heads * head_dim``."""
+
+    def __init__(self, hidden_size, num_heads, head_dim, state_size,
+                 n_groups=1, conv_kernel=4, chunk_size=128, epsilon=1e-5,
+                 time_step_min=0.001, time_step_max=0.1,
+                 time_step_floor=1e-4):
+        super().__init__()
+        if num_heads % n_groups:
+            raise ValueError(f"Mamba2Mixer: {num_heads} heads do not split "
+                             f"into {n_groups} groups")
+        self.num_heads, self.head_dim = num_heads, head_dim
+        self.state_size, self.n_groups = state_size, n_groups
+        self.chunk_size = chunk_size
+        self.inner = num_heads * head_dim
+        self.conv_dim = self.inner + 2 * n_groups * state_size
+        self.in_proj = Linear(hidden_size,
+                              self.inner + self.conv_dim + num_heads,
+                              bias_attr=False)
+        tap = 1.0 / math.sqrt(conv_kernel)
+        self.conv_weight = self.create_parameter(
+            (self.conv_dim, conv_kernel),
+            default_initializer=I.Uniform(-tap, tap))
+        self.conv_bias = self.create_parameter(
+            (self.conv_dim,), default_initializer=I.Uniform(-tap, tap))
+        # the step sizes start log-uniform in [min, max] (softplus^-1 of
+        # them), A = -exp(A_log) uniform in -[1, 16], D = 1
+        rng = np.random.default_rng(0)
+        dt = np.maximum(np.exp(rng.uniform(math.log(time_step_min),
+                                           math.log(time_step_max),
+                                           num_heads)), time_step_floor)
+        self.dt_bias = self.create_parameter(
+            (num_heads,), default_initializer=I.Assign(
+                (dt + np.log(-np.expm1(-dt))).astype("float32")))
+        self.A_log = self.create_parameter(
+            (num_heads,), default_initializer=I.Assign(
+                np.log(rng.uniform(1.0, 16.0, num_heads)).astype("float32")))
+        self.D = self.create_parameter(
+            (num_heads,), default_initializer=I.Constant(1.0))
+        self.norm = RMSNorm(self.inner, epsilon, num_groups=n_groups)
+        self.out_proj = Linear(self.inner, hidden_size, bias_attr=False)
+
+    def forward(self, u):
+        b, s = u.shape[0], u.shape[1]
+        h, p, g, n = (self.num_heads, self.head_dim, self.n_groups,
+                      self.state_size)
+        zxbcdt = self.in_proj(u)
+        z = zxbcdt[:, :, :self.inner]
+        xbc = zxbcdt[:, :, self.inner:self.inner + self.conv_dim]
+        dt = zxbcdt[:, :, self.inner + self.conv_dim:]
+        xbc = S.causal_conv1d(xbc, self.conv_weight, self.conv_bias,
+                              activation="silu")
+        x = xbc[:, :, :self.inner].reshape([b, s, h, p])
+        bb = xbc[:, :, self.inner:self.inner + g * n].reshape([b, s, g, n])
+        cc = xbc[:, :, self.inner + g * n:].reshape([b, s, g, n])
+        y = S.ssd_scan(x, dt, self.A_log, bb, cc, self.D, self.dt_bias,
+                       chunk_size=self.chunk_size)
+        y = self.norm(y.reshape([b, s, self.inner]), gate=z)
+        return self.out_proj(y)
+
+
+class GroupedQueryAttention(Layer):
+    """Self-attention with fewer key/value heads than query heads
+    (Ainslie et al., arXiv:2305.13245): KV head j serves the query heads
+    ``[j r, (j + 1) r)``, ``r = num_heads / num_kv_heads``. No bias, no
+    position embedding of its own. The K/V heads are repeated to
+    ``num_heads`` in front of the attention op, which is the flash
+    dispatch (``ops.pallas.flash_attention``: the Pallas kernels on a TPU
+    from ``flash_min_seq`` on, else ``F.scaled_dot_product_attention``);
+    a kernel that reads each K/V head once is future work (PERF.md
+    section 7)."""
+
+    def __init__(self, hidden_size, num_heads, num_kv_heads, head_dim,
+                 causal=True):
+        super().__init__()
+        if num_heads % num_kv_heads:
+            raise ValueError(f"GroupedQueryAttention: {num_heads} query "
+                             f"heads over {num_kv_heads} key/value heads")
+        self.num_heads, self.num_kv_heads = num_heads, num_kv_heads
+        self.head_dim, self.causal = head_dim, causal
+        self.q_proj = Linear(hidden_size, num_heads * head_dim,
+                             bias_attr=False)
+        self.k_proj = Linear(hidden_size, num_kv_heads * head_dim,
+                             bias_attr=False)
+        self.v_proj = Linear(hidden_size, num_kv_heads * head_dim,
+                             bias_attr=False)
+        self.o_proj = Linear(num_heads * head_dim, hidden_size,
+                             bias_attr=False)
+
+    def _heads(self, t, b, s, count):
+        """[B, S, count * D] -> [B, num_heads, S, D]."""
+        t = t.reshape([b, s, count, self.head_dim]).transpose([0, 2, 1, 3])
+        r = self.num_heads // count
+        if r == 1:
+            return t
+        t = t.unsqueeze(2).expand([b, count, r, s, self.head_dim])
+        return t.reshape([b, self.num_heads, s, self.head_dim])
+
+    def forward(self, x, force_flash=False):
+        b, s = x.shape[0], x.shape[1]
+        q = self._heads(self.q_proj(x), b, s, self.num_heads)
+        k = self._heads(self.k_proj(x), b, s, self.num_kv_heads)
+        v = self._heads(self.v_proj(x), b, s, self.num_kv_heads)
+        from ..ops.pallas import flash_attention
+        ctx = flash_attention(q, k, v, causal=self.causal, force=force_flash)
+        ctx = ctx.transpose([0, 2, 1, 3]).reshape(
+            [b, s, self.num_heads * self.head_dim])
+        return self.o_proj(ctx)

@@ -310,6 +310,29 @@ class LayerNorm(Layer):
                             self.bias, self._epsilon)
 
 
+class RMSNorm(Layer):
+    """Root-mean-square norm over the last axis with a learned scale
+    (``F.rms_norm``): no mean, no shift. ``num_groups`` > 1 norms each
+    group of the last axis by itself; ``forward(x, gate)`` multiplies by
+    ``silu(gate)`` before the norm (Mamba-2's gated norm)."""
+
+    def __init__(self, hidden_size, epsilon=1e-5, num_groups=1,
+                 weight_attr=None):
+        super().__init__()
+        if hidden_size % num_groups:
+            raise ValueError(f"RMSNorm: {hidden_size} does not split into "
+                             f"{num_groups} groups")
+        self._epsilon = epsilon
+        self._num_groups = num_groups
+        self.weight = self.create_parameter(
+            (hidden_size,), attr=weight_attr,
+            default_initializer=I.Constant(1.0))
+
+    def forward(self, x, gate=None):
+        return F.rms_norm(x, self.weight, self._epsilon, self._num_groups,
+                          gate=gate)
+
+
 class GroupNorm(Layer):
     def __init__(self, num_groups, num_channels, epsilon=1e-5,
                  weight_attr=None, bias_attr=None, data_format="NCHW"):

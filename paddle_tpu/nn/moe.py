@@ -25,7 +25,7 @@ from ..tensor import Tensor
 from ..dispatch import apply
 from .. import initializer as I
 
-__all__ = ["MoEFFN", "moe_aux_loss"]
+__all__ = ["MoEFFN", "RoutedMoE", "moe_aux_loss"]
 
 
 class MoEFFN(Layer):
@@ -107,6 +107,87 @@ class MoEFFN(Layer):
                               self.experts_b1, self.experts_w2,
                               self.experts_b2), name="moe_ffn", n_out=2)
         self.aux_loss = aux
+        return y
+
+
+class RoutedMoE(Layer):
+    """The local half of an expert-parallel mixture-of-experts layer, as
+    DeepSeek-V3-style models route (sigmoid scores, a selection bias,
+    top-k weights renormalised and scaled, one always-on shared expert,
+    relu-squared experts without a gate): the layer is TOLD which experts
+    it holds (``experts_held``, a range over the model's ``num_experts``),
+    routes over all of them, and returns what its own experts give for
+    the tokens routed to them, plus the shared expert:
+
+        y = sum_{i in chosen & held} w_i Expert_i(u) + Expert_shared(u)
+
+    What the experts on other chips would add is left out, and nothing
+    here stands in for them or for their exchange. Dropless under any
+    imbalance (``F.moe_experts``). With ``experts_held=None`` the layer
+    holds every expert and is the whole layer.
+
+    ``stats`` (a buffer, int32[5], ``ops.moe.MOE_STATS``) adds up what
+    each call routed, on the device; ``monitor.device_counters.read()``
+    gives it as ``moe.slots_routed_here``, ``moe.slots_dropped``,
+    ``moe.expert_load_max`` (the fullest expert's rows, summed over
+    calls), ``moe.steps`` (calls, every layer counted) and
+    ``moe.rows_computed`` (the rows the experts' products ran over,
+    padding included: what the layer's time follows).
+
+    Beside :class:`MoEFFN` (top-1, capacity-bounded, dense dispatch),
+    which stays as it is."""
+
+    COUNTERS = ("moe.slots_routed_here", "moe.slots_dropped",
+                "moe.expert_load_max", "moe.steps", "moe.rows_computed")
+
+    def __init__(self, d_model, d_expert, num_experts, top_k,
+                 d_shared=None, experts_held=None, routed_scaling_factor=1.0):
+        super().__init__()
+        import jax.numpy as jnp
+        from .layers import Linear
+        from .. import monitor
+        held = range(num_experts) if experts_held is None else experts_held
+        if held.step != 1 or held.start < 0 or held.stop > num_experts \
+                or not len(held):
+            raise ValueError(f"RoutedMoE: experts_held {held!r} is not a "
+                             f"range of the {num_experts} experts")
+        self.num_experts, self.top_k = num_experts, top_k
+        self.experts_held = held
+        self.routed_scaling_factor = float(routed_scaling_factor)
+        self.router = Linear(d_model, num_experts, bias_attr=False,
+                             weight_attr=I.Normal(0.0, 0.02))
+        # the selection bias: moved by a balancing rule outside the
+        # gradient (none here), so a buffer and not a parameter
+        self.register_buffer("e_score_correction_bias", Tensor(
+            jnp.zeros((num_experts,), jnp.float32)))
+        self.experts_up = self.create_parameter(
+            (len(held), d_model, d_expert),
+            default_initializer=I.Normal(0.0, 0.02))
+        self.experts_down = self.create_parameter(
+            (len(held), d_expert, d_model),
+            default_initializer=I.Normal(0.0, 0.02))
+        self.shared_up = self.shared_down = None
+        if d_shared:
+            self.shared_up = Linear(d_model, d_shared, bias_attr=False)
+            self.shared_down = Linear(d_shared, d_model, bias_attr=False)
+        self.register_buffer("stats", monitor.device_counters.register(
+            self.COUNTERS, Tensor(jnp.zeros((len(self.COUNTERS),),
+                                            jnp.int32)), owner=self),
+            persistable=False)
+
+    def forward(self, u):
+        from ..ops import moe as M
+        from ..ops import nn_ops as F
+        weights, experts = M.moe_route(
+            u, self.router.weight, self.e_score_correction_bias,
+            top_k=self.top_k, scale=self.routed_scaling_factor)
+        y, seen = M.moe_experts(u, experts, weights, self.experts_up,
+                                self.experts_down,
+                                first_expert=self.experts_held.start)
+        self.stats.data = self.stats.data + seen.data
+        if self.shared_up is not None:
+            h = F.relu(self.shared_up(u))
+            y = y + self.shared_down(h * h)
         return y
 
 

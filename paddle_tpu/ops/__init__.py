@@ -19,6 +19,8 @@ from .math import *  # noqa: F401,F403
 from .manip import *  # noqa: F401,F403
 from .creation import *  # noqa: F401,F403
 from .nn_ops import *  # noqa: F401,F403
+from .ssm import *  # noqa: F401,F403
+from .moe import *  # noqa: F401,F403
 from .control_flow import cond, while_loop, case, switch_case  # noqa: F401
 from .imperative_flow import (IfElse, Switch, DynamicRNN,  # noqa: F401
                               TensorArray, create_array, array_write,

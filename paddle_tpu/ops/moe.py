@@ -1,0 +1,213 @@
+"""paddle_tpu.ops.moe — routed mixture-of-experts ops.
+
+No reference counterpart in Paddle Fluid 1.7. Two ops, each one pure-jax
+impl through ``dispatch.apply``:
+
+* ``moe_route`` — a sigmoid router with a selection bias: top-k of ``s +
+  b`` over ALL experts the model has, weights from ``s`` alone,
+  renormalised over the chosen and scaled; float32 throughout.
+* ``moe_experts`` — the part of the layer's result that the experts HELD
+  HERE give (expert parallelism's local half: the layer is told which
+  experts it holds, the router still ranges over all of them). Grouped
+  products whose cost follows the rows routed: the tokens of each held
+  expert are brought to the front of a list (a stable sort), the expert
+  picks, inside the step, the smallest capacity of a ladder that holds
+  its rows (``_ladder``: from ``MIN_ROWS`` up by doubling to the number
+  of tokens, which is the most one expert can draw), gathers that
+  many rows, runs its two products on them and adds the result back to
+  its tokens. Dropless by construction: the ladder's last rung holds
+  every token, so no imbalance can overflow it. The backward pass is
+  written by hand (``jax.custom_vjp``) over the same rows and makes the
+  hidden activations again, so nothing of a rung's size is kept between
+  the passes. What the absent experts would add is left out; no code
+  stands in for their chips or for the exchange.
+"""
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from ..dispatch import apply
+from .nn_ops import _pscope
+
+__all__ = ["moe_route", "moe_experts", "MOE_STATS"]
+
+# what moe_experts counts each call, in this order (int32[5])
+MOE_STATS = ("slots_routed_here", "slots_dropped", "expert_load_max", "calls",
+             "rows_computed")
+
+# Under about 500 rows an expert's products on a v5e wait for its two
+# weight matrices, not for its rows (2 flops a weight byte and row against
+# the chip's 240 flops a byte), so smaller rungs would cost the same.
+MIN_ROWS = 512
+
+
+def moe_route(x, router_weight, bias=None, top_k=1, scale=1.0, name=None):
+    """``(weights [..., k] float32, experts [..., k] int32)`` of a
+    sigmoid router: ``s = sigmoid(x W_r)`` in float32 whatever ``x`` is;
+    experts = top-k of ``s + bias`` (``bias`` a buffer: no gradient
+    reaches it); ``weights = scale * s_i / (sum over the chosen of s +
+    1e-20)``."""
+    def impl(x, w, *b, top_k, scale):
+        s = jax.nn.sigmoid(jnp.einsum(
+            "...d,de->...e", x.astype(jnp.float32), w.astype(jnp.float32),
+            precision="highest"))
+        ranked = s if not b else s + jax.lax.stop_gradient(
+            b[0].astype(jnp.float32))
+        _, experts = jax.lax.top_k(ranked, top_k)
+        picked = jnp.take_along_axis(s, experts, -1)
+        weights = scale * picked / (jnp.sum(picked, -1, keepdims=True)
+                                    + 1e-20)
+        return weights, experts.astype(jnp.int32)
+
+    args = (x, router_weight) if bias is None else (x, router_weight, bias)
+    with _pscope("F.moe_route"):
+        return apply(impl, args, dict(top_k=int(top_k), scale=float(scale)),
+                     n_out=2, name="moe_route")
+
+
+def _ladder(tokens, min_rows):
+    """The capacities an expert's rows are padded to: ``min_rows``, x2,
+    x4, ... and last ``tokens`` (a token chooses an expert once, so no
+    expert draws more): under half of a rung is padding."""
+    rungs, c = [], min_rows
+    while c < tokens:
+        rungs.append(c)
+        c *= 2
+    return tuple(rungs) + (tokens,)
+
+
+def _dot(a, b, contract):
+    return lax.dot_general(a, b, ((contract, ((), ()))),
+                           preferred_element_type=jnp.float32)
+
+
+def _on_rung(rung, ladder, run, *carry):
+    """``run(capacity, *carry)`` at ``ladder[rung]``, chosen on the
+    device."""
+    if len(ladder) == 1:
+        return run(ladder[0], *carry)
+    return lax.switch(rung, [functools.partial(run, cap) for cap in ladder],
+                      *carry)
+
+
+def _grouped_rows(rows, gate, w_up, w_down, order, rung, ladder, dot_dtype):
+    """``y[t] = sum_e gate[e, t] W_down[e] relu(W_up[e] rows[t])^2`` over
+    the first ``ladder[rung[e]]`` tokens of ``order[e]``; ``gate`` is 0
+    for every token after an expert's own. float32 [tokens, d]."""
+    def expert(y, xs):
+        up, down, ids, g, r = xs
+
+        def run(cap, y):
+            at = ids[:cap]
+            h = _dot(rows[at], up.astype(dot_dtype), ((1,), (0,)))
+            h = jnp.square(jax.nn.relu(h)) * g[at][:, None]
+            out = _dot(h.astype(dot_dtype), down.astype(dot_dtype),
+                       ((1,), (0,)))
+            return y.at[at].add(out, unique_indices=True)
+
+        return _on_rung(r, ladder, run, y), None
+
+    y = jnp.zeros(rows.shape, jnp.float32)
+    return lax.scan(expert, y, (w_up, w_down, order, gate, rung))[0]
+
+
+_grouped = jax.custom_vjp(_grouped_rows, nondiff_argnums=(6, 7))
+
+
+def _grouped_fwd(rows, gate, w_up, w_down, order, rung, ladder, dot_dtype):
+    y = _grouped_rows(rows, gate, w_up, w_down, order, rung, ladder,
+                      dot_dtype)
+    return y, (rows, gate, w_up, w_down, order, rung)
+
+
+def _grouped_bwd(ladder, dot_dtype, saved, dy):
+    rows, gate, w_up, w_down, order, rung = saved
+    f32 = jnp.float32
+
+    def expert(dx, xs):
+        up, down, ids, g, r = xs
+
+        def run(cap, dx):
+            at = ids[:cap]
+            x, ga, dyr = rows[at], g[at][:, None], dy[at].astype(dot_dtype)
+            upc, downc = up.astype(dot_dtype), down.astype(dot_dtype)
+            act = jax.nn.relu(_dot(x, upc, ((1,), (0,))))
+            h = jnp.square(act)
+            d_h = _dot(dyr, downc, ((1,), (1,)))            # [cap, f]
+            d_down = _dot((h * ga).astype(dot_dtype), dyr, ((0,), (0,)))
+            d_gate = jnp.zeros(g.shape, f32).at[at].set(
+                jnp.sum(d_h * h, -1), unique_indices=True)
+            d_pre = (d_h * (2.0 * ga) * act).astype(dot_dtype)
+            d_up = _dot(x, d_pre, ((0,), (0,)))
+            dx = dx.at[at].add(_dot(d_pre, upc, ((1,), (1,))),
+                               unique_indices=True)
+            return dx, d_up, d_down, d_gate
+
+        dx, d_up, d_down, d_gate = _on_rung(r, ladder, run, dx)
+        return dx, (d_up, d_down, d_gate)
+
+    dx, (d_up, d_down, d_gate) = lax.scan(
+        expert, jnp.zeros(rows.shape, f32), (w_up, w_down, order, gate, rung))
+    return (dx.astype(rows.dtype), d_gate, d_up.astype(w_up.dtype),
+            d_down.astype(w_down.dtype), None, None)
+
+
+_grouped.defvjp(_grouped_fwd, _grouped_bwd)
+
+
+def _routed(x, experts, weights, w_up, w_down, *, first, dot_dtype):
+    f32 = jnp.float32
+    lead, d = x.shape[:-1], x.shape[-1]
+    rows = x.reshape(-1, d)
+    tokens, k = rows.shape[0], experts.shape[-1]
+    held = w_up.shape[0]
+    local = experts.reshape(tokens, k) - first
+    chose = local[None] == jnp.arange(held)[:, None, None]    # [held, T, k]
+    # gate[e, t]: token t's weight for held expert e, 0 where not chosen
+    gate = jnp.sum(jnp.where(chose, weights.reshape(1, tokens, k)
+                             .astype(f32), 0.0), -1)
+    chose = jnp.any(chose, -1)
+    sizes = jnp.sum(chose, 1, dtype=jnp.int32)        # rows of each expert
+    # each expert's own tokens first, in their order
+    order = jnp.argsort(~chose, axis=1, stable=True).astype(jnp.int32)
+    ladder = _ladder(tokens, MIN_ROWS)
+    rung = jnp.searchsorted(jnp.asarray(ladder, jnp.int32), sizes)
+    rung = jnp.minimum(rung, len(ladder) - 1).astype(jnp.int32)
+    y = _grouped(rows.astype(dot_dtype), gate, w_up, w_down, order, rung,
+                 ladder, dot_dtype)
+    computed = jnp.asarray(ladder, jnp.int32)[rung]
+    stats = jnp.stack([jnp.sum(sizes),
+                       jnp.sum(jnp.maximum(sizes - computed, 0)),
+                       jnp.max(sizes), jnp.ones((), jnp.int32),
+                       jnp.sum(computed)])
+    return y.reshape(*lead, d).astype(x.dtype), stats
+
+
+def moe_experts(x, experts, weights, w_up, w_down, first_expert=0,
+                name=None):
+    """``(y, stats)``: ``y[t] = sum over t's chosen experts i that are
+    held here of weights[t, i] * W_down[i] relu(W_up[i] x[t])^2``.
+
+    ``experts`` / ``weights`` [..., k] from :func:`moe_route`, over all the
+    model's experts; ``w_up`` [held, d, f] and ``w_down`` [held, f, d]
+    are the experts ``first_expert .. first_expert + held``. ``stats`` is
+    int32[5], :data:`MOE_STATS`: slots routed here; slots a rung did not
+    hold (0: the last rung holds every token); the fullest expert's rows;
+    1; and the rows the products ran over, padding included (the ladder
+    starts at :data:`MIN_ROWS`). Under ``amp.auto_cast`` the products
+    take the compute dtype's operands and accumulate in float32."""
+    from .. import amp
+    dot_dtype = amp.compute_dtype() if amp.is_enabled() else None
+
+    def impl(x, experts, weights, w_up, w_down, *, first):
+        return _routed(x, experts, weights, w_up, w_down, first=first,
+                       dot_dtype=dot_dtype or jnp.result_type(x))
+
+    with _pscope("F.moe_experts"):
+        return apply(impl, (x, experts, weights, w_up, w_down),
+                     dict(first=int(first_expert)), n_out=2,
+                     name="moe_experts")

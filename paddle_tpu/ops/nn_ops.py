@@ -577,6 +577,38 @@ def layer_norm(x, normalized_shape, weight=None, bias=None, epsilon=1e-5,
                      name="layer_norm")
 
 
+def rms_norm(x, weight=None, epsilon=1e-5, num_groups=1, gate=None,
+             name=None):
+    """Root-mean-square norm over the last axis (Zhang & Sennrich,
+    arXiv:1910.07467): ``x * rsqrt(mean(x^2) + epsilon) * weight``, no mean
+    taken off and no shift. ``num_groups`` > 1 splits the last axis into
+    that many groups, each with a mean square of its own; ``gate``
+    multiplies ``x`` by ``silu(gate)`` BEFORE the norm (Mamba-2's gated
+    norm). Statistics in float32 whatever ``x`` is; the result has ``x``'s
+    dtype."""
+    def impl(x, *rest, epsilon, num_groups, gated, scaled):
+        rest = list(rest)
+        h = x.astype(jnp.float32)
+        if gated:
+            h = h * jax.nn.silu(rest.pop(0).astype(jnp.float32))
+        shape = h.shape
+        h = h.reshape(shape[:-1] + (num_groups, shape[-1] // num_groups))
+        h = h * lax.rsqrt(jnp.mean(jnp.square(h), -1, keepdims=True)
+                          + epsilon)
+        h = h.reshape(shape)
+        if scaled:
+            h = h * rest.pop(0).astype(jnp.float32)
+        return h.astype(x.dtype)
+
+    args = (x,) + (() if gate is None else (gate,)) \
+        + (() if weight is None else (weight,))
+    with _pscope("F.rms_norm"):
+        return apply(impl, args,
+                     dict(epsilon=float(epsilon), num_groups=int(num_groups),
+                          gated=gate is not None, scaled=weight is not None),
+                     name="rms_norm")
+
+
 def group_norm(x, num_groups, weight=None, bias=None, epsilon=1e-5,
                data_format="NCHW", name=None):
     def impl(x, *wb, num_groups, epsilon, data_format):

@@ -575,6 +575,21 @@ def _bwd(mask_mode, causal, scale, block_q, block_k, dropout_p, res, g):
 _flash.defvjp(_fwd, _bwd)
 
 
+# Each kernel keeps the whole other side of one (batch, head) in VMEM,
+# double-buffered (the forward and dQ kernels K and V, the dK/dV kernel Q
+# and dO), beside four float32 (BQ, BK) score tiles. Compiled for a v5e at
+# 8,192 positions x 128 in bfloat16 with BK = 1024 the dK/dV kernel asks
+# for 18.4 MiB of the 16 MiB a kernel may use; with BK = 512 it fits. Up to
+# 4,096 x 128 (and at every BERT shape) the block stays as asked.
+_WHOLE_SIDE_BYTES = 2 * 1024 * 1024
+
+
+def _block_k_that_fits(seq, d, itemsize, block_k):
+    if seq * d * itemsize >= _WHOLE_SIDE_BYTES:
+        return min(block_k, 512)
+    return block_k
+
+
 def flash_attention(q, k, v, attn_mask=None, causal=False, scale=None,
                     block_q=512, block_k=1024, dropout_p=0.0,
                     training=False, force=False, name=None):
@@ -590,6 +605,7 @@ def flash_attention(q, k, v, attn_mask=None, causal=False, scale=None,
 
     b, h, sq, d = q.shape
     sk = k.shape[2]
+    block_k = _block_k_that_fits(max(sq, sk), d, q.dtype.itemsize, block_k)
     p_drop = float(dropout_p) if training else 0.0
     has_mask = attn_mask is not None
     mode = _mask_mode(attn_mask.shape if has_mask else None, b, h, sq, sk)

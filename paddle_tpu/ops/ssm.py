@@ -1,0 +1,145 @@
+"""paddle_tpu.ops.ssm — state-space sequence ops (Mamba-2).
+
+No reference counterpart in Paddle Fluid 1.7 (its recurrences are the
+LSTM/GRU ops of ops/sequence.py and nn/rnn.py); these are the ops of a
+Mamba-2 mixer (Dao & Gu, arXiv:2405.21060): a causal depthwise
+convolution, and the selective state-space recurrence in its chunked
+"state-space dual" form — all matrix products over chunks of the
+sequence, no step-by-step loop, so the MXU does the work and the backward
+pass is the products' own.
+
+Both are one pure-jax impl through ``dispatch.apply`` (tape autograd,
+``jit.to_static``, ``jit.recompute``), plain XLA; a Pallas kernel for the
+scan is future work (PERF.md section 7).
+"""
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+from ..dispatch import apply
+from .nn_ops import _pscope
+
+__all__ = ["causal_conv1d", "ssd_scan"]
+
+
+def causal_conv1d(x, weight, bias=None, activation=None, name=None):
+    """Depthwise causal convolution along the sequence: ``y[t, c] =
+    sum_j weight[c, j] * x[t - (K - 1) + j, c] (+ bias[c])``, positions
+    before the start read zero. ``x`` [B, S, C]; ``weight`` [C, K] (tap
+    K - 1 multiplies the current position); ``activation`` None or
+    "silu"."""
+    if activation not in (None, "silu"):
+        raise ValueError(f"causal_conv1d: activation {activation!r} is "
+                         f"not one of None, 'silu'")
+
+    def impl(x, w, *b, activation):
+        s, taps = x.shape[1], w.shape[1]
+        xf = jnp.pad(x.astype(jnp.float32), [(0, 0), (taps - 1, 0), (0, 0)])
+        wf = w.astype(jnp.float32)
+        y = sum(xf[:, j:j + s] * wf[:, j] for j in range(taps))
+        if b:
+            y = y + b[0].astype(jnp.float32)
+        if activation == "silu":
+            y = jax.nn.silu(y)
+        return y.astype(x.dtype)
+
+    args = (x, weight) if bias is None else (x, weight, bias)
+    with _pscope("F.causal_conv1d"):
+        return apply(impl, args, dict(activation=activation),
+                     name="causal_conv1d")
+
+
+def _chunk_heads(t, k, chunk, g, r):
+    """[B, S, G * R, ...] -> [B, K, G, R, L, ...]: heads in front of the
+    chunk's positions, so that the two minor dimensions of everything
+    below are a chunk's positions or a head's width, never a count of
+    heads."""
+    t = t.reshape(t.shape[0], k, chunk, g, r, *t.shape[3:])
+    return jnp.moveaxis(t, 2, 4)
+
+
+def _ssd(x, dt, a_log, b, c, d_skip, dt_bias, *, chunk, dot_dtype):
+    """The recurrence ``H_t = exp(dt_t A) H_{t-1} + dt_t x_t B_t^T``,
+    ``y_t = H_t C_t + D x_t`` per head, evaluated by chunks: inside a
+    chunk the masked decay matrix ``L[t, s] = exp(sum_{s < r <= t} dt_r
+    A)`` on ``(C_t . B_s) dt_s x_s``; between chunks the carried state.
+    Decays and their cumulative sums in float32; the products take
+    ``dot_dtype`` operands and accumulate in float32."""
+    f32 = jnp.float32
+    bsz, s, h, p = x.shape
+    g, n = b.shape[2:]
+    r = h // g
+    dt = jax.nn.softplus(dt.astype(f32) + dt_bias.astype(f32))     # [B,S,H]
+    a = -jnp.exp(a_log.astype(f32))
+    xdt = x.astype(f32) * dt[..., None]
+    pad = -s % chunk
+    if pad:     # dt = 0 there: the state stands still, nothing reads them
+        xdt, dt, b, c = (jnp.pad(t, [(0, 0), (0, pad)]
+                                 + [(0, 0)] * (t.ndim - 2))
+                         for t in (xdt, dt, b, c))
+    k = (s + pad) // chunk
+    xc = _chunk_heads(xdt.astype(dot_dtype), k, chunk, g, r)   # [B,K,G,R,L,P]
+    bc = jnp.moveaxis(b.astype(dot_dtype).reshape(bsz, k, chunk, g, n), 2, 3)
+    cc = jnp.moveaxis(c.astype(dot_dtype).reshape(bsz, k, chunk, g, n), 2, 3)
+    cum = jnp.cumsum(_chunk_heads(dt * a, k, chunk, g, r), -1)  # [B,K,G,R,L]
+
+    # inside a chunk. A chunk's sums stay small (|dt A| <= ~2 a step), so
+    # the difference of two cumulative sums loses nothing that matters
+    seg = cum[..., :, None] - cum[..., None, :]                 # [.., t, s]
+    lower = jnp.tril(jnp.ones((chunk, chunk), bool))
+    decay = jnp.where(lower, jnp.exp(jnp.where(lower, seg, 0.0)), 0.0)
+    scores = jnp.einsum("bkgtn,bkgsn->bkgts", cc, bc,
+                        preferred_element_type=f32)
+    y = jnp.einsum("bkgrts,bkgrsp->bkgrtp",
+                   (scores[:, :, :, None] * decay).astype(dot_dtype), xc,
+                   preferred_element_type=f32)
+
+    # what each chunk leaves behind, decayed to the chunk's end
+    left = jnp.exp(cum[..., -1:] - cum)                          # [B,K,G,R,L]
+    states = jnp.einsum(
+        "bkgsn,bkgrsp->bkgrpn", bc,
+        (xc.astype(f32) * left[..., None]).astype(dot_dtype),
+        preferred_element_type=f32)                              # [B,K,G,R,P,N]
+
+    # between chunks: the state that enters chunk k is the sum over j < k
+    # of exp(sum_{j < i < k} total_i) states_j. The K x K sums are taken
+    # term by term (masked cumulative sum), not as differences of sums
+    # that grow with the sequence
+    total = jnp.moveaxis(cum[..., -1], 1, -1)                    # [B,G,R,K]
+    rows = jnp.arange(k)[:, None]
+    cols = jnp.arange(k)[None, :]
+    seg_k = jnp.cumsum(jnp.where(rows > cols, total[..., :, None], 0.0), -2)
+    carry = jnp.where(rows >= cols, jnp.exp(jnp.where(rows >= cols, seg_k,
+                                                       0.0)), 0.0)
+    after = jnp.einsum("bgrkj,bjgrpn->bkgrpn", carry, states,
+                       precision="highest")          # state after chunk k
+    entering = jnp.pad(after[:, :-1], [(0, 0), (1, 0)] + [(0, 0)] * 4)
+    y = y + jnp.einsum("bkgtn,bkgrpn->bkgrtp", cc, entering.astype(dot_dtype),
+                       preferred_element_type=f32) * jnp.exp(cum)[..., None]
+
+    y = jnp.moveaxis(y, 4, 2).reshape(bsz, k * chunk, h, p)[:, :s]
+    y = y + d_skip.astype(f32)[:, None] * x.astype(f32)
+    return y.astype(x.dtype)
+
+
+def ssd_scan(x, dt, A_log, B, C, D, dt_bias, chunk_size=128, name=None):
+    """Mamba-2's selective state-space recurrence, by chunks.
+
+    ``x`` [B, S, H, P] (heads x head width); ``dt`` [B, S, H], the raw
+    step sizes (``softplus(dt + dt_bias)`` is taken here, in float32);
+    ``A_log``, ``D``, ``dt_bias`` [H] (``A = -exp(A_log)``); ``B``, ``C``
+    [B, S, G, N] (head h reads group ``h // (H / G)``). Returns ``y`` [B,
+    S, H, P] in ``x``'s dtype. Any sequence length; under
+    ``amp.auto_cast`` the matrix products take the compute dtype's
+    operands, everything else stays float32."""
+    from .. import amp
+    dot_dtype = amp.compute_dtype() if amp.is_enabled() else None
+
+    def impl(x, dt, a_log, b, c, d_skip, dt_bias, *, chunk):
+        return _ssd(x, dt, a_log, b, c, d_skip, dt_bias, chunk=chunk,
+                    dot_dtype=dot_dtype or jnp.result_type(x))
+
+    with _pscope("F.ssd_scan"):
+        return apply(impl, (x, dt, A_log, B, C, D, dt_bias),
+                     dict(chunk=int(chunk_size)), name="ssd_scan")

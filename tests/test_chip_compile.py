@@ -194,6 +194,55 @@ def test_flash_causal(one_chip, compiled_kernels):
     assert n == 3
 
 
+# 8,192 x 128 causal, 32 heads: the nemotron cell's attention after its K/V
+# heads are repeated. The op picks the key block (``_block_k_that_fits``): a
+# kernel keeps the whole other side of a (batch, head) in VMEM, and inside
+# the compiled step the dK/dV kernel with BK = 1024 asked for 18.4 MiB of
+# the 16 a kernel may use
+@pytest.mark.parametrize("seq", [8192, 4096])
+def test_flash_long_causal_at_head_size_128(one_chip, compiled_kernels, seq):
+    from paddle_tpu.ops.pallas.flash_attention import (_block_k_that_fits,
+                                                       _flash)
+    block_k = _block_k_that_fits(seq, 128, 2, 1024)
+    assert block_k == (512 if seq == 8192 else 1024)
+    assert _block_k_that_fits(512, 64, 2, 1024) == 1024     # BERT's, as asked
+
+    def f(q, k, v, seed):
+        return _flash(q, k, v, None, None, seed, True, None, 512, block_k,
+                      0.0)
+
+    qkv = ((1, 32, seq, 128), jnp.bfloat16)
+    n = _compile(_grad_sum(f, argnums=(0, 1, 2)), one_chip, qkv, qkv, qkv,
+                 ((2,), jnp.int32),
+                 names=("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"))
+    assert n == 3
+
+
+# -- the nemotron cell's routed experts: plain XLA, chosen on the device ----
+
+def test_grouped_experts_fwd_bwd_at_the_cells_size(one_chip):
+    """8,192 tokens x 2,688 in bfloat16, 8 held experts 1,856 wide, top-6:
+    each expert's gather, two products and scatter-add sit in a
+    conditional per rung of the ladder (512 ... 8,192 rows), forward and
+    hand-written backward; the chip's compiler takes both."""
+    from paddle_tpu.ops import moe
+
+    def loss(x, weights, w_up, w_down, experts):
+        y, _ = moe._routed(x, experts, weights, w_up, w_down, first=0,
+                           dot_dtype=jnp.bfloat16)
+        return jnp.sum(jnp.square(y.astype(jnp.float32)))
+
+    text = _compiled_text(
+        jax.grad(loss, argnums=(0, 1, 2, 3)), one_chip,
+        ((1, 8192, 2688), jnp.bfloat16), ((1, 8192, 6), jnp.float32),
+        ((8, 2688, 1856), jnp.float32), ((8, 1856, 2688), jnp.float32),
+        ((1, 8192, 6), jnp.int32))
+    assert text.count(" conditional(") == 2          # forward, backward
+    for rows in moe._ladder(8192, moe.MIN_ROWS):
+        assert f"bf16[{rows},2688]" in text          # a rung's gathered rows
+    assert "tpu_custom_call" not in text
+
+
 # -- the four kernels that ship off ----------------------------------------
 
 def test_fused_adam(one_chip, compiled_kernels):

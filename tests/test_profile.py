@@ -508,6 +508,51 @@ def test_instruction_ledger_splits_a_fusion_that_holds_two_phases():
     assert fusion["serves"] is None
 
 
+LOOP_PREFETCH_HLO = """\
+HloModule jit_step, is_scheduled=true
+
+%body (p: (s32[], f32[64,8])) -> (s32[], f32[64,8]) {
+  %p = (s32[], f32[64,8]{1,0}) parameter(0)
+  %i = s32[] get-tuple-element((s32[], f32[64,8]{1,0}) %p), index=0
+  %x = f32[64,8]{1,0} get-tuple-element((s32[], f32[64,8]{1,0}) %p), index=1
+  %slice-start.1 = ((f32[64,8]{1,0}), f32[64,8]{1,0:S(1)}, s32[]) slice-start(f32[64,8]{1,0} %x), slice={[0:64], [0:8]}
+  %slice-done.1 = f32[64,8]{1,0:S(1)} slice-done(((f32[64,8]{1,0}), f32[64,8]{1,0:S(1)}, s32[]) %slice-start.1)
+  %one = s32[] constant(1)
+  %next = s32[] add(s32[] %i, s32[] %one), metadata={op_name="jit(step)/step/bwd/Linear_0/while/body/add"}
+  ROOT %t = (s32[], f32[64,8]{1,0}) tuple(s32[] %next, f32[64,8]{1,0:S(1)} %slice-done.1)
+}
+
+%cond (p: (s32[], f32[64,8])) -> pred[] {
+  %p = (s32[], f32[64,8]{1,0}) parameter(0)
+  %i = s32[] get-tuple-element((s32[], f32[64,8]{1,0}) %p), index=0
+  %n = s32[] constant(8)
+  ROOT %lt = pred[] compare(s32[] %i, s32[] %n), direction=LT, metadata={op_name="jit(step)/step/bwd/Linear_0/while/cond/lt"}
+}
+
+ENTRY %main.3 (x: f32[64,8]) -> (s32[], f32[64,8]) {
+  %x = f32[64,8]{1,0} parameter(0)
+  %zero = s32[] constant(0)
+  %init = (s32[], f32[64,8]{1,0}) tuple(s32[] %zero, f32[64,8]{1,0} %x)
+  ROOT %while.1 = (s32[], f32[64,8]{1,0}) while((s32[], f32[64,8]{1,0}) %init), condition=%cond, body=%body, metadata={op_name="jit(step)/step/bwd/Linear_0/while"}
+}
+"""
+
+
+def test_a_loop_bodys_own_prefetch_takes_the_labels_of_its_loop():
+    """A prefetch that the compiler carries from one iteration to the
+    next is read by the body's ROOT tuple alone: no labelled neighbour in
+    its computation. It serves the loop, and is not phase ``none``."""
+    rows = profile.instruction_ledger(
+        label="fixture", hlo=LOOP_PREFETCH_HLO, scope_map=_TWO_PHASE_SCOPES,
+        phase_map=_TWO_PHASE_PHASES)
+    by_name = {r["name"]: r for r in rows}
+    for name in ("slice-start.1", "slice-done.1"):
+        assert by_name[name]["serves"] == "while.1"
+        assert [(p["phase"], p["region"]) for p in by_name[name]["parts"]] \
+            == [("bwd", "Linear_0")]
+    assert by_name["next"]["serves"] is None
+
+
 def test_instruction_ledger_is_empty_without_a_captured_executable():
     assert profile.instruction_ledger() == []
 
