@@ -100,9 +100,9 @@ def phase_kernels(rows, hidden, batch, heads, seq, head_dim,
     from paddle_tpu.ops.pallas.layer_norm import layer_norm as pallas_ln
 
     shipped = sorted(k for k, on in P._AUTO_ON.items() if on)
-    if shipped != ["flash_attention", "layer_norm"]:
-        raise AssertionError(f"_AUTO_ON ships {shipped}; this phase "
-                             f"covers flash_attention and layer_norm")
+    if shipped != ["flash_attention", "layer_norm", "ssd_scan"]:
+        raise AssertionError(f"_AUTO_ON ships {shipped}; this phase covers "
+                             f"flash_attention, layer_norm and ssd_scan")
     on_chip = pt.device.is_tpu_backend()
     if on_chip and P.interpret_mode():
         raise AssertionError("interpret mode reachable on a TPU backend")
@@ -171,6 +171,33 @@ def phase_kernels(rows, hidden, batch, heads, seq, head_dim,
 
         run(f"flash_attention[{batch}x{heads}x{seq}x{head_dim},bf16,"
             f"{name}]", k_fa, r_fa, (q, k, v, ct), 3, tol_bf16, 3)
+
+    # the Mamba-2 scan over (batch, seq, 2 * heads heads of 64 in `heads`
+    # groups, state 128), bf16 products, all seven gradients
+    from paddle_tpu.ops.pallas import ssd_scan as ssd
+    from paddle_tpu.ops.ssm import _ssd
+    h, p, n, chunk = 2 * heads, 64, 128, 128
+    if not ssd.supported((batch, seq, h, p), (batch, seq, heads, n), chunk):
+        raise AssertionError("the scan's kernels would not take this shape")
+    scan_args = (
+        jnp.asarray(rng.randn(batch, seq, h, p), jnp.bfloat16),
+        jnp.asarray(rng.randn(batch, seq, h), jnp.float32),
+        jnp.asarray(np.log(rng.uniform(1.0, 16.0, h)), jnp.float32),
+        jnp.asarray(rng.randn(batch, seq, heads, n), jnp.bfloat16),
+        jnp.asarray(rng.randn(batch, seq, heads, n), jnp.bfloat16),
+        jnp.asarray(rng.randn(h), jnp.float32),
+        jnp.asarray(rng.randn(h) - 2.0, jnp.float32),
+        jnp.asarray(rng.randn(batch, seq, h, p), jnp.float32))
+
+    def k_scan(*a):
+        y = ssd.ssd_scan(*a[:7], chunk=chunk, dot_dtype=jnp.bfloat16)
+        return (y.astype(jnp.float32) * a[7]).sum()
+
+    def r_scan(*a):
+        return (_ssd(*a[:7], chunk=chunk, dot_dtype=jnp.float32) * a[7]).sum()
+
+    run(f"ssd_scan[{batch}x{seq}x{h}x{p},state{n},bf16]", k_scan, r_scan,
+        scan_args, 7, tol_bf16, 2)
 
 
 # ---------------------------------------------------------------------------

@@ -32,7 +32,7 @@ def on_tpu():
 # Per-kernel default overrides: None = auto.
 _overrides = {}
 _KERNELS = ("layer_norm", "fused_adam", "fused_adam_multi",
-            "flash_attention", "softmax_xent", "batch_norm")
+            "flash_attention", "softmax_xent", "batch_norm", "ssd_scan")
 
 # Auto defaults from one builder-run v5e ablation (2026-07-31, superseded
 # toolchain, not reproduced — docs/performance.md carries the table):
@@ -49,9 +49,18 @@ _KERNELS = ("layer_norm", "fused_adam", "fused_adam_multi",
 # fused_adam_multi: ONE dispatch over concatenated buffers (r5; the
 # r4-measured -13.6% was the per-tensor dispatch) — auto-off until
 # scripts/bench_adam_multi.py proves it beats XLA's own update fusion.
+# ssd_scan: on, measured on the v5e of this toolchain (PERF.md section 6,
+# PR 28). At the nemotron cell's size (1 x 8,192 positions, 64 heads x 64
+# in 8 groups, state 128, chunk 128, bfloat16) the kernel pair takes 0.57
+# ms a layer forward and 1.68 backward where ops/ssm.py: _ssd took 3.9 and
+# 11.1; in nemotron3_nano_30b_a3b.causal_pretrain F.ssd_scan fell from
+# 59.9 to 12.2 ms of a step and the step from 337.9 to 301.4 ms
+# (medians of five shared-seed pairs). Beside
+# this switch the op looks at the call: shapes the tiles do not fit keep
+# _ssd (ssd_scan.supported), as does everything off-TPU or under a mesh.
 _AUTO_ON = {"layer_norm": True, "flash_attention": True,
             "fused_adam": False, "fused_adam_multi": False,
-            "softmax_xent": False, "batch_norm": False}
+            "softmax_xent": False, "batch_norm": False, "ssd_scan": True}
 
 
 # flash is an O(S^2)-score win: below some sequence length the XLA sdpa
@@ -99,8 +108,8 @@ def gspmd_trace(n_devices):
 def configure(flash_min_seq=_UNSET, **kernels):
     """configure(layer_norm=False, fused_adam=None, ...) — override the
     auto default for named kernels ('layer_norm', 'fused_adam',
-    'flash_attention', 'softmax_xent', 'batch_norm'). None restores
-    auto.
+    'flash_attention', 'softmax_xent', 'batch_norm', 'ssd_scan'). None
+    restores auto.
     flash_min_seq=N routes sequences shorter than N to XLA sdpa even
     with the flash kernel enabled (N=0 disables the gate);
     flash_min_seq=None restores the measured default crossover,
@@ -143,6 +152,7 @@ from . import softmax_xent as softmax_xent_mod
 from . import flash_attention as flash_attention_mod
 from . import fused_adam as fused_adam_mod
 from . import batch_norm as batch_norm_mod
+from . import ssd_scan as ssd_scan_mod
 
 from .layer_norm import layer_norm
 from .softmax_xent import softmax_cross_entropy

@@ -8,9 +8,15 @@ convolution, and the selective state-space recurrence in its chunked
 sequence, no step-by-step loop, so the MXU does the work and the backward
 pass is the products' own.
 
-Both are one pure-jax impl through ``dispatch.apply`` (tape autograd,
-``jit.to_static``, ``jit.recompute``), plain XLA; a Pallas kernel for the
-scan is future work (PERF.md section 7).
+Both go through ``dispatch.apply`` (tape autograd, ``jit.to_static``,
+``jit.recompute``). The convolution is plain XLA. The scan has two forms
+of one algorithm: ``_ssd`` below, plain XLA, runs anywhere (a CPU, a step
+that spans devices, any chunk and width) and is the kernels' oracle; on
+one TPU, where the shapes fit their tiles, ``ops/pallas/ssd_scan.py``'s
+kernel pair runs it with every chunk x chunk and heads x P x N array in
+VMEM (PERF.md section 6, PR 28). Which one a call takes is read off the
+call (``ssd_scan`` below), and the counters ``ssd_scan.kernel_traced`` /
+``ssd_scan.xla_traced`` say which it was.
 """
 from __future__ import annotations
 
@@ -133,11 +139,20 @@ def ssd_scan(x, dt, A_log, B, C, D, dt_bias, chunk_size=128, name=None):
     S, H, P] in ``x``'s dtype. Any sequence length; under
     ``amp.auto_cast`` the matrix products take the compute dtype's
     operands, everything else stays float32."""
-    from .. import amp
+    from .. import amp, monitor
+    from . import pallas
     dot_dtype = amp.compute_dtype() if amp.is_enabled() else None
+    # read off the call, not set by a user: the kernels where their tiles
+    # fit the shapes and the registry has them on (a TPU, the step on one
+    # device), else the portable path
+    kernel = (pallas.enabled("ssd_scan") and pallas.ssd_scan_mod.supported(
+        tuple(x.shape), tuple(B.shape), int(chunk_size)))
+    monitor.counter("ssd_scan.kernel_traced" if kernel
+                    else "ssd_scan.xla_traced").inc()
+    scan = pallas.ssd_scan_mod.ssd_scan if kernel else _ssd
 
     def impl(x, dt, a_log, b, c, d_skip, dt_bias, *, chunk):
-        return _ssd(x, dt, a_log, b, c, d_skip, dt_bias, chunk=chunk,
+        return scan(x, dt, a_log, b, c, d_skip, dt_bias, chunk=chunk,
                     dot_dtype=dot_dtype or jnp.result_type(x))
 
     with _pscope("F.ssd_scan"):

@@ -243,6 +243,67 @@ def test_grouped_experts_fwd_bwd_at_the_cells_size(one_chip):
     assert "tpu_custom_call" not in text
 
 
+# -- the nemotron cell's Mamba-2 scan: a kernel pair ------------------------
+
+def test_ssd_scan_kernels_keep_chunk_sized_arrays_in_vmem(one_chip,
+                                                          compiled_kernels):
+    """``nemotron3_nano_30b_a3b.causal_pretrain``'s scan, forward + backward
+    (1 x 8,192 positions, 64 heads x 64 in 8 groups, state 128, chunk 128,
+    bfloat16): both kernels fit the chip's VMEM, and the compiled program
+    around them holds no decay matrix, no chunk states and no window
+    reduction (a count over the compiled text, no time)."""
+    from paddle_tpu.ops.pallas import ssd_scan as K
+    b, s, h, p, g, n, chunk = 1, 8192, 64, 64, 8, 128, 128
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    assert K.supported((b, s, h, p), (b, s, g, n), chunk)
+
+    def scan(*a):
+        return K.ssd_scan(*a, chunk=chunk, dot_dtype=bf16)
+
+    text = _compiled_text(
+        _grad_sum(scan, argnums=tuple(range(7))), one_chip,
+        ((b, s, h, p), bf16), ((b, s, h), bf16), ((h,), f32),
+        ((b, s, g, n), bf16), ((b, s, g, n), bf16), ((h,), f32), ((h,), f32),
+        names=("ssd_fwd", "ssd_bwd"))
+    assert text.count("tpu_custom_call") == 2
+    assert "reduce-window" not in text
+    # chunk x chunk: only the triangle of ones of the in-chunk sums, once,
+    # never an array of them (a decay matrix a head and chunk, its mask)
+    for dtype in ("f32", "bf16"):
+        for shape in _shapes(text, dtype):
+            assert shape[-2:] != (chunk, chunk) or len(shape) == 2, shape
+    # heads x P x N: only the one residual, [B, K, N, H * P] float32, as
+    # the forward kernel's result and the backward kernel's operand
+    state = b * (s // chunk) * h * p * n
+    big = {shape for shape in _shapes(text, "f32") if np.prod(shape) >= state}
+    assert big == {(b, s // chunk, n, h * p)}, big
+    # x, y and their gradients cross HBM in the mixer's layout and dtype:
+    # no float32 copy of them anywhere (the residual happens to be as large)
+    assert not [shape for shape in _shapes(text, "f32")
+                if np.prod(shape) == b * s * h * p and shape not in big]
+
+
+# what ``supported`` says yes to, the chip's compiler has to take: a chunk
+# of 256, heads 128 and 256 wide (one head a lane tile), a state of 256
+@pytest.mark.parametrize("b,s,h,p,g,n,chunk", [
+    (2, 2048, 64, 64, 8, 128, 256),
+    (1, 1024, 8, 128, 2, 256, 128),
+    (1, 1024, 4, 256, 1, 128, 128),
+], ids=["chunk256", "head128-state256", "head256"])
+def test_ssd_scan_kernels_compile_where_supported(one_chip, compiled_kernels,
+                                                  b, s, h, p, g, n, chunk):
+    from paddle_tpu.ops.pallas import ssd_scan as K
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    assert K.supported((b, s, h, p), (b, s, g, n), chunk)
+    count = _compile(
+        _grad_sum(lambda *a: K.ssd_scan(*a, chunk=chunk, dot_dtype=bf16),
+                  argnums=tuple(range(7))), one_chip,
+        ((b, s, h, p), bf16), ((b, s, h), bf16), ((h,), f32),
+        ((b, s, g, n), bf16), ((b, s, g, n), bf16), ((h,), f32), ((h,), f32),
+        names=("ssd_fwd", "ssd_bwd"))
+    assert count == 2
+
+
 # -- the four kernels that ship off ----------------------------------------
 
 def test_fused_adam(one_chip, compiled_kernels):
@@ -396,6 +457,7 @@ KERNEL_NAMES = {
     "fused_adam.py": ["fused_adam", "fused_adam_multi", "fused_adam_flat"],
     "layer_norm.py": ["layer_norm_fwd", "layer_norm_bwd"],
     "softmax_xent.py": ["softmax_xent_fwd", "softmax_xent_bwd"],
+    "ssd_scan.py": ["ssd_fwd", "ssd_bwd"],
 }
 
 
@@ -433,4 +495,4 @@ def test_no_pallas_call_site_is_left_out_and_no_name_is_used_twice():
     found = {f: names for f, names in found.items() if names}
     assert found == KERNEL_NAMES
     every = [n for names in found.values() for n in names]
-    assert len(every) == len(set(every)) == 14
+    assert len(every) == len(set(every)) == 16
