@@ -215,8 +215,9 @@ def _bert_batch(cfg, batch, seq, seed=0):
 
 
 def _bert_trainer(cfg, wrap=None):
-    """(model, optimizer, compiled step) — the path of bench.py's
-    bench_bert, one optimizer step per call. ``wrap(model, opt)`` lets
+    """(model, optimizer, compiled step) — the path of the benchmark's
+    BERT cells (benchmark/families/bert_pretrain.py), one optimizer step
+    per call. ``wrap(model, opt)`` lets
     the fleet phase place both on its mesh."""
     import paddle_tpu as pt
     from paddle_tpu import amp, jit, optimizer as opt

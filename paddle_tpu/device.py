@@ -112,8 +112,8 @@ def enable_compilation_cache(min_compile_time_secs=1.0):
     """Turn on XLA's persistent compilation cache so executables survive
     process restarts, and return the directory in use.
 
-    One rule for the whole repo (bench.py, chip_smoke.py and every
-    script come through here): where ``JAX_COMPILATION_CACHE_DIR`` is
+    One rule for the whole repo (benchmark/run.py, chip_smoke.py and
+    every script come through here): where ``JAX_COMPILATION_CACHE_DIR`` is
     set, JAX already keeps its cache there and no other directory is
     set in code; where it is not, the cache is ``.jax_cache`` at the
     root of the checkout. Programs that compile faster than

@@ -105,6 +105,11 @@ def _collect_state(models, optimizers, scalers=()):
     return holders
 
 
+def _arenas_of(optimizers):
+    return [a for a in (getattr(o, "_arena", None) for o in optimizers)
+            if a is not None]
+
+
 class StaticFunction:
     """The compiled callable returned by to_static."""
 
@@ -210,18 +215,20 @@ class StaticFunction:
                 self._fn = self._orig_fn
             models, optimizers, scalers = self._resolve_objects()
             from . import tensor as _ptensor
-            own_arenas = []
             if _ptensor._arena_hook is not None:
                 from .optimizer import arena as _arena_mod
-                own_arenas = [a for a in (getattr(o, "_arena", None)
-                                          for o in optimizers) if a is not None]
                 # external writes to arena leaves (set_value/checkpoint
                 # restore) must land in the flat buffers before we trace
                 # from them; foreign arenas also sync so the step reads
                 # fresh leaf data
-                _arena_mod.flush(exclude=own_arenas)
+                _arena_mod.flush(exclude=_arenas_of(optimizers))
             holders, state_names, all_params = self._cached_state(
                 models, optimizers, scalers)
+            # read after _cached_state: the first call builds a flat-arena
+            # optimizer's arena (and installs the hook) there, and its
+            # leaves go stale with this very step
+            own_arenas = _arenas_of(optimizers) \
+                if _ptensor._arena_hook is not None else []
 
             # Tensor is a pytree node, so leaves here are raw arrays / scalars.
             flat_args, treedef = jax.tree_util.tree_flatten((args, kwargs))

@@ -99,8 +99,8 @@ overlap & quantized sync"; ``paddle_tpu.parallel.overlap``):
   reduces
 * ``comm.exposed_wait_s`` (histogram) / ``comm.exposed_wait_s_total``
   — seconds the step loop spent *blocked* on unfinished reduces: the
-  exposed wire time overlap mode is built to remove (bench.py's
-  ``collective_overlap`` stage gates on it)
+  exposed wire time overlap mode is built to remove
+  (``scripts/comm_smoke.py`` gates on it)
 * ``comm.sync.<mode>`` / ``comm.lag_warmup`` — sync calls per mode and
   lag-1 warm-up steps that had no previous grads to apply
 * ``comm.bucket_reduce`` / ``comm.wait`` trace spans — bucket reduces
@@ -136,7 +136,7 @@ Span tracing & XLA-measured cost (PR 4's additions):
   compiled executables as ``xla.flops.<label>`` /
   ``xla.bytes_accessed.<label>`` / ``xla.peak_memory.<label>`` gauges
   plus ``xla_cost`` JSONL records; feeds the measured-MFU columns in
-  StepMonitor and bench.py.
+  StepMonitor.
 
 Memory-observability series (docs/observability.md "Memory
 attribution & budget"; ``paddle_tpu.monitor.memory``):

@@ -116,7 +116,7 @@ DEFAULT_RULES = default_rules
 class Alert:
     """Lifecycle record for one rule/finding: pending → firing →
     resolved, with timestamps for each edge (the detection-latency
-    evidence bench.py banks)."""
+    evidence ``scripts/telemetry_smoke.py`` reports)."""
 
     def __init__(self, name, series=None, source=None, context=None):
         self.name = name

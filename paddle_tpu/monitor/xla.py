@@ -9,7 +9,7 @@ accessed) and ``memory_analysis()`` (argument/output/temp/alias bytes)
 ``xla.peak_memory.<label>``) plus one ``xla_cost`` JSONL record, and
 keeps the executables around so the flight recorder can dump HLO text.
 
-``StepMonitor`` and bench.py report **measured MFU** (XLA-counted
+``StepMonitor`` reports **measured MFU** (XLA-counted
 flops ÷ step time ÷ peak) next to the analytic number, flagging >20%
 divergence between the two flop counts — the cross-check the fusion
 cost-model literature insists on (hand-rolled ceilings drift; the

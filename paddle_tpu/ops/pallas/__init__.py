@@ -2,10 +2,9 @@
 
 TPU-native rebuild of the reference's fused CUDA kernels
 (reference: paddle/fluid/operators/fused/fused_elemwise_activation_op.cu,
-layer_norm_op.cu, softmax_with_cross_entropy_op.cu, optimizers/adam_op.cu
-multi-tensor path). Each kernel runs compiled on TPU and in interpret mode
-on CPU (tests), and exposes a custom VJP so the tape/jit path differentiates
-through it.
+layer_norm_op.cu, softmax_with_cross_entropy_op.cu). Each kernel runs
+compiled on TPU and in interpret mode on CPU (tests), and exposes a custom
+VJP so the tape/jit path differentiates through it.
 """
 import contextlib
 import threading
@@ -31,24 +30,21 @@ def on_tpu():
 
 # Per-kernel default overrides: None = auto.
 _overrides = {}
-_KERNELS = ("layer_norm", "fused_adam", "fused_adam_multi",
-            "flash_attention", "softmax_xent", "batch_norm", "ssd_scan")
+_KERNELS = ("layer_norm", "flash_attention", "softmax_xent", "batch_norm",
+            "ssd_scan")
 
 # Auto defaults from one builder-run v5e ablation (2026-07-31, superseded
 # toolchain, not reproduced — docs/performance.md carries the table):
-# layer_norm is the only unconditional win (+0.4%); fused_adam loses
-# 13.6% to XLA's own update fusion (a separate pallas dispatch per param
-# tensor vs one fused backward+update program); softmax_xent loses 1.7%
-# at seq-128 shapes (its value is the O(N·V) HBM saving, opt-in);
+# layer_norm is the only unconditional win (+0.4%); softmax_xent loses
+# 1.7% at seq-128 shapes (its value is the O(N·V) HBM saving, opt-in);
 # flash_attention wins only once S^2 scores dominate — seq-gated via
 # _flash_min_seq below. configure(kernel=True/False) still forces any
 # of them either way.
 # batch_norm: built to attack the ResNet trace's BN-bound 70% (same
 # record), auto-off until scripts/bench_pallas_bn.py proves it
 # beats the (already once-fixed) XLA schedule on the chip.
-# fused_adam_multi: ONE dispatch over concatenated buffers (r5; the
-# r4-measured -13.6% was the per-tensor dispatch) — auto-off until
-# scripts/bench_adam_multi.py proves it beats XLA's own update fusion.
+# There is no Adam kernel: XLA carries the update in the weight-gradient
+# matmul's epilogue, which a Mosaic call cannot be (PERF.md section 6, PR 29).
 # ssd_scan: on, measured on the v5e of this toolchain (PERF.md section 6,
 # PR 28). At the nemotron cell's size (1 x 8,192 positions, 64 heads x 64
 # in 8 groups, state 128, chunk 128, bfloat16) the kernel pair takes 0.57
@@ -59,7 +55,6 @@ _KERNELS = ("layer_norm", "fused_adam", "fused_adam_multi",
 # this switch the op looks at the call: shapes the tiles do not fit keep
 # _ssd (ssd_scan.supported), as does everything off-TPU or under a mesh.
 _AUTO_ON = {"layer_norm": True, "flash_attention": True,
-            "fused_adam": False, "fused_adam_multi": False,
             "softmax_xent": False, "batch_norm": False, "ssd_scan": True}
 
 
@@ -106,10 +101,10 @@ def gspmd_trace(n_devices):
 
 
 def configure(flash_min_seq=_UNSET, **kernels):
-    """configure(layer_norm=False, fused_adam=None, ...) — override the
-    auto default for named kernels ('layer_norm', 'fused_adam',
-    'flash_attention', 'softmax_xent', 'batch_norm', 'ssd_scan'). None
-    restores auto.
+    """configure(layer_norm=False, softmax_xent=None, ...) — override the
+    auto default for named kernels ('layer_norm', 'flash_attention',
+    'softmax_xent', 'batch_norm', 'ssd_scan'); any other name raises
+    ValueError. None restores auto.
     flash_min_seq=N routes sequences shorter than N to XLA sdpa even
     with the flash kernel enabled (N=0 disables the gate);
     flash_min_seq=None restores the measured default crossover,
@@ -150,12 +145,10 @@ def enabled(kernel, seq_len=None):
 from . import layer_norm as layer_norm_mod
 from . import softmax_xent as softmax_xent_mod
 from . import flash_attention as flash_attention_mod
-from . import fused_adam as fused_adam_mod
 from . import batch_norm as batch_norm_mod
 from . import ssd_scan as ssd_scan_mod
 
 from .layer_norm import layer_norm
 from .softmax_xent import softmax_cross_entropy
 from .flash_attention import flash_attention
-from .fused_adam import fused_adam_update
 from .batch_norm import fused_batch_norm_train

@@ -21,11 +21,11 @@ needs, but with the f32 converts, squares and x̂ recomputation kept in
 registers instead of round-tripping f32 copies through HBM (the
 `convert_reduce_fusion` cost the ResNet-50 trace showed at ~8 ms/step).
 
-Default-OFF (`pallas.configure(batch_norm=True)` opts in): the fused_adam
-lesson (13.6% LOSS vs XLA's own fusion, docs/performance.md) is that
-hand-written kernels must beat the compiler on the chip before they ride
-the default path; scripts/bench_pallas_bn.py measures exactly that when
-a chip window is available.
+Default-OFF (`pallas.configure(batch_norm=True)` opts in): the lesson of
+the Adam kernels (they lost to XLA's own fusion and were removed, PERF.md
+section 6, PR 29) is that hand-written kernels must beat the compiler on
+the chip before they ride the default path; scripts/bench_pallas_bn.py
+measures exactly that when a chip window is available.
 """
 from __future__ import annotations
 

@@ -11,9 +11,10 @@ Design: each optimizer implements one pure `_rule(param, grad, slots, lr)`
 over jnp arrays. In dygraph the rule runs eagerly per parameter; under
 ``jit.to_static`` the whole loop is traced into the train step, so XLA fuses
 all parameter updates with the backward pass (the reference needs a fused
-multi-tensor adam CUDA kernel for this; XLA fusion + optional Pallas fused
-adam in ops/pallas give it for free). Slot state lives in Tensors, so it is
-carried state for to_static and checkpointable.
+multi-tensor adam CUDA kernel for this; XLA's fusion gives it for free —
+the Adam update rides the weight-gradient matmul's epilogue, see
+adam_rule.py). Slot state lives in Tensors, so it is carried state for
+to_static and checkpointable.
 """
 from __future__ import annotations
 
@@ -29,6 +30,7 @@ from ..clip import ClipGradBase
 from .. import monitor as _monitor
 from ..resilience import guard as _rguard
 from . import lr as lr_sched
+from .adam_rule import adam_rule
 from .lr import LRScheduler
 
 
@@ -273,7 +275,7 @@ class Optimizer:
         self._apply_update(params_grads, lr)
 
     def _apply_update(self, params_grads, lr):
-        """The raw update: batched multi-tensor path or the per-param
+        """The raw update: the flat arena's apply or the per-param
         _rule loop (split from step() so the resilience guard can
         bracket it with its snapshot/select machinery). Under an armed
         profiler the whole body runs inside a stable ``opt.<Cls>``
@@ -316,9 +318,6 @@ class Optimizer:
         return self._apply_update_body(params_grads, lr)
 
     def _apply_update_body(self, params_grads, lr):
-        if self._batched_update(params_grads, lr):
-            self._post_step()
-            return
         for p, g in params_grads:
             if g is None:
                 continue
@@ -330,12 +329,6 @@ class Optimizer:
             for n, v in new_slots.items():
                 self._slot(p, n).data = v
         self._post_step()
-
-    def _batched_update(self, params_grads, lr):
-        """Hook: apply ALL updates in one dispatch (multi-tensor
-        kernels). Return True if handled; False falls through to the
-        per-param _rule loop. Base: no batched path."""
-        return False
 
     def _ensure_all_slots(self):
         """Create every accumulator eagerly (used by jit.to_static so slot
@@ -591,112 +584,42 @@ class Adadelta(Optimizer):
 
 class Adam(Optimizer):
     """reference: AdamOptimizer / adam_op.cc (incl. beta-pow accumulators).
-    use_fused=True routes the update through the Pallas fused-adam kernel
-    (reference: the fused multi-tensor adam CUDA path)."""
+    The update itself is :func:`adam_rule.adam_rule`, per leaf here and
+    over the flat buffers in arena mode."""
 
     _arena_slots = ("moment1", "moment2")
     _arena_pows = ("beta1_pow", "beta2_pow")
+    _wd = 0.0  # AdamW's decoupled decay
 
     def __init__(self, learning_rate=0.001, beta1=0.9, beta2=0.999,
-                 epsilon=1e-8, parameters=None, lazy_mode=False,
-                 use_fused=None, use_multi_tensor=None, **kw):
+                 epsilon=1e-8, parameters=None, lazy_mode=False, **kw):
         super().__init__(learning_rate, parameters, **kw)
         self._beta1, self._beta2, self._eps = beta1, beta2, epsilon
-        # None = auto, resolved via pallas.enabled() when the step traces
-        # (configure() before the first jitted step; traced steps keep
-        # the choice they were compiled with)
-        self._use_fused = use_fused
-        self._use_multi_tensor = use_multi_tensor
 
     def _pre_param(self, p):
         self._slot(p, "moment1")
         self._slot(p, "moment2")
-        self._slot(p, "beta1_pow", init=1.0, shape=())
-        self._slot(p, "beta2_pow", init=1.0, shape=())
+        # float32 whatever the parameter's dtype: 0.999 is 1.0 in
+        # bfloat16, and 1 - beta2_pow == 0 stops every update
+        self._slot(p, "beta1_pow", init=1.0, shape=(), dtype=jnp.float32)
+        self._slot(p, "beta2_pow", init=1.0, shape=(), dtype=jnp.float32)
 
     def _rule(self, p, g, slots, lr):
-        b1, b2, eps = self._beta1, self._beta2, self._eps
-        b1p = slots["beta1_pow"] * b1
-        b2p = slots["beta2_pow"] * b2
-        from ..ops.pallas.fused_adam import adam_step
-        new_p, m, v = adam_step(p, g, slots["moment1"], slots["moment2"],
-                                lr, b1p, b2p, beta1=b1, beta2=b2, eps=eps,
-                                use_fused=self._use_fused)
+        b1p = slots["beta1_pow"] * self._beta1
+        b2p = slots["beta2_pow"] * self._beta2
+        new_p, m, v = adam_rule(
+            p, g, slots["moment1"], slots["moment2"], lr, b1p, b2p,
+            beta1=self._beta1, beta2=self._beta2, eps=self._eps,
+            weight_decay=self._wd)
         return new_p, {"moment1": m, "moment2": v, "beta1_pow": b1p,
                        "beta2_pow": b2p}
 
-    _warned_unequal_beta_pow = False
-
-    def _batched_update(self, params_grads, lr):
-        """Multi-tensor path (reference adam_op.cu FusedAdamKernel):
-        one Pallas dispatch updates every param. Shared beta-pow bias
-        correction — see fused_adam_update_multi's semantics note. The
-        shared correction is only valid when every live param has
-        stepped in lockstep; unequal beta-pow slots (a param added
-        mid-training, a partial checkpoint restore) warn once and fall
-        back to the exact per-tensor loop."""
-        use = self._use_multi_tensor
-        if use is None:
-            from ..ops import pallas as P
-            use = P.enabled("fused_adam_multi")
-        live = [(p, g) for p, g in params_grads if g is not None]
-        if not use or len(live) < 2:
-            return False
-        from ..ops.pallas.fused_adam import fused_adam_update_multi
-        for p, _ in live:
-            self._pre_param(p)
-        slots = [self._accumulators[id(p)] for p, _ in live]
-        if not self._beta_pows_aligned(slots):
-            if not Adam._warned_unequal_beta_pow:
-                import warnings
-                warnings.warn(
-                    "multi-tensor Adam: live params' beta1_pow/beta2_pow "
-                    "slots are not all equal (params stepped out of "
-                    "lockstep); falling back to the exact per-tensor "
-                    "update loop", RuntimeWarning)
-                Adam._warned_unequal_beta_pow = True
-            if _monitor.enabled():
-                _monitor.counter(
-                    "optimizer.adam_multi_tensor_fallback").inc()
-            return False
-        b1p = slots[0]["beta1_pow"].data * self._beta1
-        b2p = slots[0]["beta2_pow"].data * self._beta2
-        new_ps, new_ms, new_vs = fused_adam_update_multi(
-            [p.data for p, _ in live], [g for _, g in live],
-            [s["moment1"].data for s in slots],
-            [s["moment2"].data for s in slots],
-            lr, b1p, b2p, beta1=self._beta1, beta2=self._beta2,
-            eps=self._eps, weight_decay=getattr(self, "_wd", 0.0))
-        for (p, _), s, np_, nm, nv in zip(live, slots, new_ps, new_ms,
-                                          new_vs):
-            p.data = np_
-            s["moment1"].data = nm
-            s["moment2"].data = nv
-            s["beta1_pow"].data = b1p
-            s["beta2_pow"].data = b2p
-        return True
-
-    @staticmethod
-    def _beta_pows_aligned(slots):
-        """True when every live param's beta-pow pair matches slot 0's.
-        Tracers (a step being traced by jit.to_static) can't be compared
-        host-side — the traced loop keeps whatever layout it was traced
-        with, so treat them as aligned."""
-        vals = []
-        for s in slots:
-            pair = (s["beta1_pow"].data, s["beta2_pow"].data)
-            if any(isinstance(v, jax.core.Tracer) for v in pair):
-                return True
-            vals.append((float(pair[0]), float(pair[1])))
-        return all(v == vals[0] for v in vals[1:])
-
     def _arena_apply(self, arena, packed, lr):
-        """Flat-arena update: one adam_step_flat call per dtype group,
+        """Flat-arena update: one adam_rule call per dtype group,
         reading/writing the arena buffers in place — no per-step
         gather/scatter over the param set. Beta-pow bias correction is
-        shared per group (multi-tensor semantics; arena packing already
-        warned if adopted pows disagreed)."""
-        from ..ops.pallas.fused_adam import adam_step_flat
+        shared per group (arena packing already warned if adopted pows
+        disagreed)."""
         for grp, flat_g, mask in packed:
             m = grp.slots["moment1"]
             v = grp.slots["moment2"]
@@ -704,11 +627,10 @@ class Adam(Optimizer):
                 self._beta1, grp.pows["beta1_pow"].data.dtype)
             b2p = grp.pows["beta2_pow"].data * jnp.asarray(
                 self._beta2, grp.pows["beta2_pow"].data.dtype)
-            new_p, new_m, new_v = adam_step_flat(
+            new_p, new_m, new_v = adam_rule(
                 grp.flat.data, flat_g, m.data, v.data, lr, b1p, b2p,
                 beta1=self._beta1, beta2=self._beta2, eps=self._eps,
-                weight_decay=getattr(self, "_wd", 0.0), mask=mask,
-                use_fused=self._use_fused)
+                weight_decay=self._wd, mask=mask)
             grp.flat.data = new_p
             m.data = new_m
             v.data = new_v
@@ -727,14 +649,6 @@ class AdamW(Adam):
         self._wd = float(weight_decay) if not isinstance(
             weight_decay, WeightDecayRegularizer) else weight_decay.coeff
         self._regularization = None  # decoupled — not added to grad
-
-    def _rule(self, p, g, slots, lr):
-        new_p, new_slots = super()._rule(p, g, slots, lr)
-        # cast back per term: a weak-typed f32 lr*wd*p would otherwise
-        # promote bf16 params (and diverge from adam_step_flat's
-        # cast-per-term sequence)
-        new_p = (new_p - lr * self._wd * p).astype(p.dtype)
-        return new_p, new_slots
 
 
 class Adamax(Optimizer):

@@ -12,9 +12,8 @@ path is pure elementwise math over the flat buffers:
 * gradients are packed with one ordered concat per dtype group under a
   dedicated ``arena.pack`` profile scope (the unavoidable cost of fresh
   per-leaf cotangents — NOT attributed to ``opt.*``);
-* the update is one flat ``adam_step_flat`` call per group
-  (ops/pallas/fused_adam.py) instead of the multi-tensor path's
-  4-gather + 3-scatter rebuild every step;
+* the update is one ``adam_rule`` call per group (adam_rule.py) over the
+  flat buffers, with no per-step gather or scatter over the param set;
 * grad-sync buckets (parallel.overlap) are CONTIGUOUS SLICES of the
   same layout (``bucket_bounds``), so exact/quantized/overlap reduce
   operates in place on the training buffers.
@@ -37,8 +36,9 @@ flat layout.
 Scope: the arena keeps EXACT per-leaf bit-identity only while every
 member steps in lockstep (the jit/SPMD training reality). Members with
 *no* grad in a step are masked out (param, moments, pows untouched per
-element) — the shared per-group beta pows then follow the multi-tensor
-kernel's semantics note in ops/pallas/fused_adam.py.
+element) — the per-group beta pows are SHARED, so a member that skips
+steps takes the group's bias correction, not its own (the per-leaf path
+keeps one pair of pows a parameter).
 """
 from __future__ import annotations
 
@@ -52,11 +52,12 @@ import jax.numpy as jnp
 from ..tensor import Tensor
 from .. import tensor as _ptensor
 from .. import monitor as _monitor
+from .adam_rule import adam_rule
 
 __all__ = ["ParamArena", "flush", "sync_all"]
 
-# pad each dtype group to a full (8, 128) f32 tile multiple so the
-# Pallas flat kernel's (rows, 128) view is a free reshape, never a pad
+# pad each dtype group to a full (8, 128) f32 tile multiple, so that
+# bucket bounds and slices of the flat buffers fall on tile boundaries
 ALIGN = 1024
 
 _ALL = weakref.WeakSet()    # every live arena
@@ -204,14 +205,14 @@ class ParamArena:
                     if seed is not None:
                         val = float(jax.device_get(seed.data))
                         # keyed per (group, pow): each group carries its
-                        # own pow scalar, and dtype rounding makes pows
-                        # differ ACROSS groups even in lockstep
+                        # own pow scalar
                         pow_seed.setdefault((grp.tag, pname),
                                             set()).add(val)
                         if pow_src is None:
                             pow_src = p
+                # float32 in every group: 0.999 is 1.0 in bfloat16
                 grp.pows[pname] = Tensor(
-                    jnp.asarray(val, grp.dtype),
+                    jnp.asarray(val, jnp.float32),
                     name=f"arena.{grp.tag}.{pname}")
         if any(len(v) > 1 for v in pow_seed.values()):
             warnings.warn(
@@ -503,7 +504,7 @@ class ParamArena:
                         RuntimeWarning)
                     ParamArena._warned_pow_restore = True
                 self._pow_restore_seen[(grp.tag, sname)] = new
-                t.data = jnp.asarray(new, grp.dtype)
+                t.data = jnp.asarray(new, t.data.dtype)
 
     def leaf_slot_tensors(self, p):
         """Fresh per-leaf slot Tensors for one member (used when the
@@ -528,19 +529,16 @@ class ParamArena:
 def static_apply(opt, params_grads, param_vals, slot_vals, lr):
     """Arena update for the static Executor's functional ``run_fn``:
     params stay per-leaf (the Program's carried-state contract) but the
-    m/v/pow slots live FLAT, so the per-step repack drops from the
-    multi-tensor path's 4 gathers + 3 scatters to 2 gathers (p, g) + 1
+    m/v/pow slots live FLAT: the per-step repack is 2 gathers (p, g) + 1
     split (new p) — the slot buffers never leave the arena layout.
 
     ``params_grads``: the Executor's (param, grad) pairs after clip/reg;
     ``param_vals``: {id(param): current traced value};
     ``slot_vals``: {arena holder name: traced value}.
     Returns (new_param_by_pid, new_slot_vals)."""
-    from ..ops.pallas.fused_adam import adam_step_flat
     arena = opt._arena
     new_params, new_slots = {}, dict(slot_vals)
     by_pid = {id(p): g for p, g in params_grads if g is not None}
-    wd = getattr(opt, "_wd", 0.0)
     for grp in arena.groups:
         segs, pparts, flags, any_live = [], [], [], False
         for p, off, n, shape in grp.entries:
@@ -571,13 +569,12 @@ def static_apply(opt, params_grads, param_vals, slot_vals, lr):
             mask = jnp.asarray(m)
         b1p = slot_vals[f"{grp.tag}.beta1_pow"] * opt._beta1
         b2p = slot_vals[f"{grp.tag}.beta2_pow"] * opt._beta2
-        new_p, new_m, new_v = adam_step_flat(
+        new_p, new_m, new_v = adam_rule(
             flat_p, flat_g,
             slot_vals[f"{grp.tag}.moment1"],
             slot_vals[f"{grp.tag}.moment2"],
             lr, b1p, b2p, beta1=opt._beta1, beta2=opt._beta2,
-            eps=opt._eps, weight_decay=wd, mask=mask,
-            use_fused=opt._use_fused)
+            eps=opt._eps, weight_decay=opt._wd, mask=mask)
         new_slots[f"{grp.tag}.moment1"] = new_m
         new_slots[f"{grp.tag}.moment2"] = new_v
         new_slots[f"{grp.tag}.beta1_pow"] = b1p

@@ -27,6 +27,7 @@ from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P, NamedSharding
 
 from .collective import axis_size as _axis_size
+from ..optimizer.adam_rule import adam_rule
 
 
 # ---------------------------------------------------------------------------
@@ -44,7 +45,7 @@ class MegatronConfig(NamedTuple):
     n_micro: int = 2           # microbatches per step (pipeline depth)
     lr: float = 1e-3
     use_moe: bool = True
-    optimizer: str = "adam"    # "adam" (fused-kernel rule) | "sgd"
+    optimizer: str = "adam"    # "adam" (optimizer.adam_rule) | "sgd"
     beta1: float = 0.9
     beta2: float = 0.999
     adam_eps: float = 1e-8
@@ -67,7 +68,7 @@ class MegatronConfig(NamedTuple):
     # flat parameter arena (optimizer/arena.py layout, dp/sp-only meshes):
     # the whole f32 param tree lives in ONE contiguous buffer; the loss fn
     # differentiates the buffer itself so the gradient materializes flat —
-    # no per-leaf concat before the dp sync and one fused adam dispatch.
+    # no per-leaf concat before the dp sync and one adam update over it.
     # Requires tp == pp == ep == 1 (sharded params can't share a
     # replicated buffer); ignored with a warning otherwise.
     flat_arena: bool = False
@@ -487,7 +488,7 @@ def _build_flat_train_step(cfg: MegatronConfig, mesh: Mesh, params):
         layout.append((k, off, n, tuple(params[k].shape)))
         off += n
     total = off
-    pad = (-total) % 128  # lane-align so the fused flat kernel is eligible
+    pad = (-total) % 128  # lane-align the buffer and its bucket bounds
     flat0 = jnp.concatenate(
         [jnp.ravel(params[k]).astype(jnp.float32) for k in keys]
         + ([jnp.zeros((pad,), jnp.float32)] if pad else []))
@@ -527,8 +528,7 @@ def _build_flat_train_step(cfg: MegatronConfig, mesh: Mesh, params):
         tf = t.astype(jnp.float32)
         b1p = jnp.power(cfg.beta1, tf)
         b2p = jnp.power(cfg.beta2, tf)
-        from ..ops.pallas.fused_adam import adam_step_flat
-        new_flat, new_m, new_v = adam_step_flat(
+        new_flat, new_m, new_v = adam_rule(
             state["flat"], flat_g, state["opt"]["m"], state["opt"]["v"],
             cfg.lr, b1p, b2p, beta1=cfg.beta1, beta2=cfg.beta2,
             eps=cfg.adam_eps)
@@ -580,9 +580,9 @@ def build_train_step(cfg: MegatronConfig, mesh: Mesh):
     seq_len] int32.
 
     The update rule is the REAL optimizer compute path (reference: fleet
-    distributed_optimizer wrapping Adam/SGD): "adam" runs the same fused
-    Pallas adam kernel Optimizer.Adam uses (ops/pallas/fused_adam.py) on
-    each param's local shard, slot state sharded exactly like its param.
+    distributed_optimizer wrapping Adam/SGD): "adam" runs the rule
+    optimizer.Adam runs (optimizer/adam_rule.py) on each param's local
+    shard, slot state sharded exactly like its param.
 
     cfg.flat_arena=True switches dp/sp-only meshes to the flat parameter
     arena layout (see _build_flat_train_step); state then carries "flat"
@@ -615,13 +615,6 @@ def build_train_step(cfg: MegatronConfig, mesh: Mesh):
              "t": jnp.zeros((), jnp.int32)}
     state_spec = {"params": pspec_tree, "opt": opt_spec, "t": P()}
 
-    def _adam_update(p, g, slots, b1p, b2p):
-        from ..ops.pallas.fused_adam import adam_step
-        new_p, m, v = adam_step(p, g, slots["m"], slots["v"], cfg.lr,
-                                b1p, b2p, beta1=cfg.beta1, beta2=cfg.beta2,
-                                eps=cfg.adam_eps)
-        return new_p, {"m": m, "v": v}
-
     def device_fn(state, tokens_local):
         params_local = state["params"]
 
@@ -653,28 +646,13 @@ def build_train_step(cfg: MegatronConfig, mesh: Mesh):
             tf = t.astype(jnp.float32)
             b1p = jnp.power(cfg.beta1, tf)
             b2p = jnp.power(cfg.beta2, tf)
-            from ..ops import pallas as _P
-            if _P.enabled("fused_adam_multi"):
-                # same multi-tensor rule as Optimizer.Adam: one dispatch
-                # over every LOCAL shard (slot state sharded like params)
-                from ..ops.pallas.fused_adam import fused_adam_update_multi
-                keys = list(params_local)
-                nps, nms, nvs = fused_adam_update_multi(
-                    [params_local[k] for k in keys],
-                    [grads[k] for k in keys],
-                    [state["opt"][k]["m"] for k in keys],
-                    [state["opt"][k]["v"] for k in keys],
+            new_params, new_opt = {}, {}
+            for k, slots in state["opt"].items():
+                new_params[k], m, v = adam_rule(
+                    params_local[k], grads[k], slots["m"], slots["v"],
                     cfg.lr, b1p, b2p, beta1=cfg.beta1, beta2=cfg.beta2,
                     eps=cfg.adam_eps)
-                new_params = dict(zip(keys, nps))
-                new_opt = {k: {"m": m, "v": v}
-                           for k, m, v in zip(keys, nms, nvs)}
-            else:
-                new_params, new_opt = {}, {}
-                for k in params_local:
-                    new_params[k], new_opt[k] = _adam_update(
-                        params_local[k], grads[k], state["opt"][k], b1p,
-                        b2p)
+                new_opt[k] = {"m": m, "v": v}
         else:
             new_params = jax.tree_util.tree_map(
                 lambda p, g: p - cfg.lr * g, params_local, grads)

@@ -27,9 +27,8 @@ arxiv 2506.17615, and fused computation-collectives, arxiv 2305.06942):
 Exposed wire time is *measured*: every second the caller spends blocked
 on an unfinished reduce lands in ``scheduler.exposed_wait_s`` and the
 ``comm.exposed_wait_s`` histogram; ``comm.bytes_wire`` vs
-``comm.bytes_logical`` records what quantization saved. bench.py's
-``collective_overlap`` stage and ``scripts/comm_smoke.py`` gate on
-both.
+``comm.bytes_logical`` records what quantization saved.
+``scripts/comm_smoke.py`` gates on both.
 
 Mode knob (one string everywhere — DataParallel, MegatronConfig,
 Optimizer, hapi/static entry points):

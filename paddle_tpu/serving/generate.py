@@ -1897,7 +1897,7 @@ class DemoLM:
 
 def demo_model(vocab=64, dim=32, heads=2, layers=2, max_len=512, seed=0):
     """The reference decode model for docs, tests, the loadgen, and the
-    smoke/bench stages."""
+    smoke scripts."""
     return DemoLM(vocab=vocab, dim=dim, heads=heads, layers=layers,
                   max_len=max_len, seed=seed)
 

@@ -1,8 +1,6 @@
 #!/usr/bin/env bash
 # CI gate for the zero-copy flat parameter arena: the same MLP + Adam
 # to_static step, per-leaf vs flat_arena=True, must be bit-identical,
-# cut opt.* bytes_accessed >= 40% against the multi-tensor baseline
-# (whose per-step concat traffic must be VISIBLE in the baseline HLO),
 # leave zero concat/gather/scatter attributed to the optimizer scope,
 # and compile exactly once with zero recompiles over the run.
 # Tier-1-safe: small MLP, CPU, seconds.

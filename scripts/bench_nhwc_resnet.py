@@ -7,7 +7,7 @@ step and named "fused stats+normalize Pallas BN, NHWC-native layout" as
 the fix — this script decides whether to flip the headline layout and
 _AUTO_ON['batch_norm'].
 
-Methodology: same as bench.py — `inner` real optimizer steps chained in
+Methodology: `inner` real optimizer steps chained in
 one compiled call over distinct resident uint8 batches (normalize on
 device), so host dispatch amortizes.
 
@@ -99,9 +99,9 @@ def main():
             if base else ""), flush=True)
         if best == "NHWC pallas-bn":
             print("-> flip _AUTO_ON['batch_norm']=True (channels-last) "
-                  "and headline NHWC in bench.py", flush=True)
+                  "and make NHWC the resnet cell's layout", flush=True)
         elif best == "NHWC xla-bn":
-            print("-> headline NHWC in bench.py; keep pallas BN off",
+            print("-> NHWC for the resnet cell; keep pallas BN off",
                   flush=True)
         else:
             print("-> keep NCHW headline; record table in "

@@ -23,8 +23,7 @@ asserts the ISSUE's acceptance criteria directly against measurements
   finishes BIT-IDENTICAL to the uninterrupted run
 
 Writes trace.json + the monitor JSONL to --out-dir as CI artifacts and
-prints one JSON result line (bench.py's ``collective_overlap`` stage
-re-reads it). Exit code 0 iff every gate passes.
+prints one JSON result line. Exit code 0 iff every gate passes.
 """
 import argparse
 import json

@@ -345,7 +345,7 @@ def main():
         smetrics.reset_windows()
         result[name] = fn(args)
     result["wall_s"] = round(time.perf_counter() - t0, 3)
-    # the bench harness banks these
+    # flat copies of the headline numbers
     result["prefix_hit_rate"] = result["prefix"]["hit_rate"]
     result["ttft_hit_p50_ms"] = result["prefix"]["ttft_hit_p50_ms"]
     result["ttft_miss_p50_ms"] = result["prefix"]["ttft_miss_p50_ms"]

@@ -13,9 +13,6 @@ literally "curl /metrics during fit and get live series back":
 * ``/snapshot`` answers with the counter snapshot
 * ``monitor.disable()`` tears everything down: no paddle_tpu
   threads survive, the port stops answering
-* ``scripts/perf_sentinel.py`` passes on the repo's own banked
-  artifacts (module-level invocation — the gate proves the sentinel
-  runs clean at head, not just in its unit tests)
 
 Prints one JSON result line; exit code 0 iff every gate passes.
 """
@@ -108,18 +105,6 @@ def main():
     except Exception:
         pass
 
-    sentinel_rc = None
-    try:
-        import importlib.util
-        spec = importlib.util.spec_from_file_location(
-            "perf_sentinel", os.path.join(_ROOT, "scripts",
-                                          "perf_sentinel.py"))
-        sentinel = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(sentinel)
-        sentinel_rc = sentinel.main(["--repo-root", _ROOT])
-    except Exception as e:  # noqa: BLE001 - gate reports, not raises
-        sentinel_rc = f"crashed: {e!r}"
-
     gates = {
         "metrics_200_openmetrics": (status == 200
                                     and "openmetrics-text" in ctype
@@ -138,7 +123,6 @@ def main():
                               and "nan_guard" in health),
         "snapshot_answers": s_status == 200 and "counters" in snap,
         "teardown_clean": port_dead and not leaked,
-        "sentinel_clean_at_head": sentinel_rc == 0,
     }
     result = {
         "port": port,
@@ -146,7 +130,6 @@ def main():
         "n_series": len(metric_names),
         "watchdogs": health.get("watchdogs"),
         "leaked_threads": leaked,
-        "sentinel_rc": sentinel_rc,
         "gates": gates,
         "jsonl": jsonl,
         "ok": all(gates.values()),

@@ -2,9 +2,8 @@
 # CI gate for the live telemetry plane: train a tiny hapi.Model with
 # fit(metrics_port=0), scrape /metrics + /healthz + /snapshot MID-RUN
 # (must parse as OpenMetrics with executor counters, at least one
-# sampled mem_* gauge, and live watchdog/NaN-guard health), prove
-# monitor.disable() frees the port and every thread, then run the perf
-# regression sentinel over the repo's banked bench artifacts.
+# sampled mem_* gauge, and live watchdog/NaN-guard health), and prove
+# monitor.disable() frees the port and every thread.
 # Tier-1-safe: tiny MLP, CPU, seconds.
 #
 # Usage: scripts/export_smoke.sh [out_dir]

@@ -18,9 +18,8 @@ The PR 11 acceptance run, end to end:
   chosen so the gap is structural (tp replicates the vocab logits
   matmul per rank), not a timing coin-flip.
 
-Writes the monitor JSONL to --out-dir and prints one JSON result line
-(the bench `planner` stage parses it). Exit code 0 iff every gate
-passes.
+Writes the monitor JSONL to --out-dir and prints one JSON result line.
+Exit code 0 iff every gate passes.
 """
 import argparse
 import json
@@ -164,7 +163,7 @@ def main():
     measured_best = json.loads(min(measured, key=measured.get))
     prediction_ok = predicted_best == measured_best
 
-    # -- ledger: record the decision the bench stage banks ------------
+    # -- ledger: record the decision ----------------------------------
     chosen = planner.plan(auto=True, cfg=acfg, n_devices=8,
                           candidates=cand, global_batch=8,
                           name="plan_smoke")

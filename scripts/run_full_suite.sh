@@ -203,15 +203,6 @@ bash scripts/disagg_smoke.sh "$MONITOR_DIR/disagg_smoke"
 dsg=$?
 [ $dsg -ne 0 ] && rc=$((rc == 0 ? dsg : rc))
 
-# final gate: the perf regression sentinel over the repo's banked bench
-# artifacts — nonzero iff a real measurement fell out of its tolerance
-# band (outage-shaped zero/error lines are skipped, not failed)
-echo ""
-echo "-- perf sentinel gate --"
-python scripts/perf_sentinel.py
-sen=$?
-[ $sen -ne 0 ] && rc=$((rc == 0 ? sen : rc))
-
 latest=$(ls -t "$MONITOR_DIR"/events-*.jsonl 2>/dev/null | head -1)
 echo ""
 echo "monitor JSONL: ${latest:-<none written>} (dir: $MONITOR_DIR)"

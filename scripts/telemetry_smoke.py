@@ -22,11 +22,11 @@ the ISSUE's acceptance bar end to end:
 * the goodput ledger reconciles to wall time within 5% around a loop
   with a real checkpoint save and a measured input stall;
 * snapshot publishing costs <= 1% of a worker's wall time
-  (``fleet_agg_overhead_pct``, banked for the perf sentinel along with
+  (``fleet_agg_overhead_pct``, reported along with
   ``alert_detection_latency_s``);
 * with the monitor disabled nothing publishes: zero files, no thread.
 
-Prints one JSON result line (last stdout line) for bench.py.
+Prints one JSON result line (last stdout line).
 
 Usage::
 

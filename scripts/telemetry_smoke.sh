@@ -13,7 +13,7 @@
 # monitor disabled. CPU-only, ~1 min.
 #
 # Usage: scripts/telemetry_smoke.sh [out_dir]
-# The last stdout line is one JSON result record (bench.py parses it).
+# The last stdout line is one JSON result record.
 set -euo pipefail
 cd "$(dirname "$0")/.."
 OUT_DIR="${1:-/tmp/paddle_tpu_telemetry_smoke}"

@@ -4,7 +4,6 @@ chapter's model in static mode (or dygraph where the book does), trains a
 few steps on synthetic data, and asserts the loss drops — the ported-user
 experience check."""
 import numpy as np
-import pytest
 
 import paddle_tpu as pt
 import paddle_tpu.fluid as fluid
@@ -143,7 +142,6 @@ def test_understand_sentiment_conv():
     assert losses[-1] < losses[0]
 
 
-@pytest.mark.slow
 def test_label_semantic_roles_crf():
     """reference book/test_label_semantic_roles.py — BiLSTM + linear
     chain CRF (dygraph form: the static CRF path is the same op)."""
@@ -218,7 +216,6 @@ def test_rnn_encoder_decoder():
     assert losses[-1] < losses[0] * 0.9
 
 
-@pytest.mark.slow
 def test_machine_translation_beam_decode():
     """reference book/test_machine_translation.py — train briefly, then
     beam-search decode with the Transformer zoo model (the modern path the

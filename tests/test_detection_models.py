@@ -3,9 +3,6 @@
 reference: the PaddleDetection-era YOLOv3/SSD configs over
 fluid/layers/detection.py)."""
 import numpy as np
-import pytest
-
-pytestmark = pytest.mark.slow
 
 import paddle_tpu as pt
 from paddle_tpu import jit, optimizer as opt

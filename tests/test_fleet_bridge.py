@@ -6,7 +6,6 @@ the losses match a single-device run of the same model step for step —
 i.e. GSPMD partitioning with Megatron param placement is semantically
 invisible. (reference: fluid/incubate/fleet/collective/__init__.py)"""
 import numpy as np
-import pytest
 import jax
 from jax.sharding import PartitionSpec as P
 
@@ -59,7 +58,6 @@ def test_megatron_param_spec_patterns():
     assert megatron_param_spec("encoder.0.attn_norm.weight", (64,)) == P()
 
 
-@pytest.mark.slow
 def test_fleet_bert_dp_tp_matches_single_device():
     # ---- single-device reference run -------------------------------
     cfg, model_ref, ids, mlm, nsp = _bert_and_data()
@@ -95,7 +93,6 @@ def test_fleet_bert_dp_tp_matches_single_device():
     assert qkv.data.sharding.spec == P(None, "tp")
 
 
-@pytest.mark.slow
 def test_fleet_dp_only_matches_single_device():
     cfg, model_ref, ids, mlm, nsp = _bert_and_data(batch=8)
     o_ref = optimizer.Momentum(learning_rate=0.05, momentum=0.9,

@@ -8,7 +8,6 @@ training losses must match the single-device run step for step.
 sp: the token batch is sharded over (dp, sp) and GSPMD inserts the
 sequence-parallel collectives; losses again match single-device."""
 import numpy as np
-import pytest
 import jax
 from jax.sharding import PartitionSpec as P
 
@@ -53,7 +52,6 @@ def _reference_losses(steps=3):
             for _ in range(steps)], (ids, mlm, nsp)
 
 
-@pytest.mark.slow
 def test_fleet_bert_dp_pp_tp_matches_single_device():
     ref_losses, (ids, mlm, nsp) = _reference_losses()
 
@@ -79,7 +77,6 @@ def test_fleet_bert_dp_pp_tp_matches_single_device():
     np.testing.assert_allclose(losses, ref_losses, rtol=2e-4, atol=2e-4)
 
 
-@pytest.mark.slow
 def test_fleet_bert_sp_sharded_tokens_matches_single_device():
     ref_losses, (ids, mlm, nsp) = _reference_losses()
 

@@ -201,7 +201,6 @@ def test_preload_into_memory_matches_serial_load(tmp_path):
     b.wait_preload_done()
 
 
-@pytest.mark.slow
 def test_preload_into_memory_thread_scaling(tmp_path):
     """4 preload threads must cut wall-clock >= 2x over 1 thread. The
     per-file cost is pinned in the pipe command (a GIL-releasing

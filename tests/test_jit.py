@@ -1,6 +1,5 @@
 """jit.to_static: compiled train step parity with eager (SURVEY §3)."""
 import numpy as np
-import pytest
 
 import paddle_tpu as pt
 from paddle_tpu import nn, optimizer as opt, jit
@@ -131,8 +130,8 @@ def test_recompute_matches_plain():
 
 
 def test_to_static_multi_step_unrolled_matches_sequential():
-    """bench.py runs `inner` REAL optimizer steps inside ONE compiled
-    call (dispatch amortization); the unrolled trace must produce
+    """A step function may run `inner` REAL optimizer steps inside ONE
+    compiled call (dispatch amortization); the unrolled trace must produce
     bit-comparable params to running the steps one compiled call each."""
     import numpy as np
     import paddle_tpu as pt
@@ -180,7 +179,6 @@ def test_to_static_multi_step_unrolled_matches_sequential():
         np.testing.assert_allclose(p1.numpy(), p2.numpy(), atol=1e-6)
 
 
-@pytest.mark.slow
 def test_bert_recompute_matches_plain():
     """use_recompute=True (per-layer jax.checkpoint, RNG threaded
     explicitly through the checkpointed region) must be bit-comparable to

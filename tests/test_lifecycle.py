@@ -386,10 +386,9 @@ def test_healthz_draining_distinct_from_open_and_snapshot(mon):
 
 
 # ---------------------------------------------------------------------------
-# the short chaos soak, end to end (slow: ~40s wall)
+# the short chaos soak, end to end (~40s wall)
 
 
-@pytest.mark.slow
 def test_soak_chaos_short_mode_holds_invariants(tmp_path):
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ, JAX_PLATFORMS="cpu",

@@ -2,7 +2,6 @@
 recompute through the encoder, ring attention on the sp mesh (SURVEY §2
 row 30)."""
 import numpy as np
-import pytest
 
 import paddle_tpu as pt
 from paddle_tpu.ops.pallas import flash_attention
@@ -23,7 +22,6 @@ def test_flash_longer_seq_causal_matches_sdpa():
     np.testing.assert_allclose(out.numpy(), ref.numpy(), atol=2e-3)
 
 
-@pytest.mark.slow
 def test_bert_long_seq_recompute_flash_trains():
     """Tiny-width BERT at seq 512 with recompute on: the long-context
     configuration (flash stays off on CPU via the auto gate — it runs on

@@ -46,7 +46,6 @@ def test_lenet_converges():
     assert acc > 0.9, f"LeNet failed to fit synthetic MNIST: acc={acc}"
 
 
-@pytest.mark.slow
 def test_resnet50_forward_backward():
     from paddle_tpu.models.resnet import resnet50, resnet18
     m = resnet18(num_classes=10)
@@ -64,7 +63,6 @@ def test_resnet50_forward_backward():
     assert 25_000_000 < n < 26_000_000
 
 
-@pytest.mark.slow
 def test_bert_tiny_forward_backward():
     from paddle_tpu.models.bert import BertConfig, BertForPretraining
     cfg = BertConfig.tiny()
@@ -84,7 +82,6 @@ def test_bert_tiny_forward_backward():
     assert m.bert.embeddings.word_embeddings.weight.grad is not None
 
 
-@pytest.mark.slow
 def test_transformer_seq2seq():
     from paddle_tpu.models.transformer import Transformer
     m = Transformer(src_vocab_size=100, tgt_vocab_size=100, d_model=32,
@@ -124,7 +121,7 @@ def test_word2vec():
     assert m.emb_in.weight.grad is not None
 
 
-@pytest.mark.slow
+@pytest.mark.slow  # 52-54 s alone, 67 s inside the whole tier-1 run (PR 29)
 def test_vgg_mobilenet_smoke():
     from paddle_tpu.models.vgg import vgg16
     from paddle_tpu.models.mobilenet import MobileNetV1, MobileNetV2
@@ -134,7 +131,6 @@ def test_vgg_mobilenet_smoke():
     assert MobileNetV2(num_classes=5)(x).shape == [1, 5]
 
 
-@pytest.mark.slow
 def test_resnet_nhwc_matches_nchw():
     """data_format='NHWC' plumbs through stem/blocks/pools and matches
     the NCHW model in eval mode (weights stay OIHW — layout-independent
@@ -161,7 +157,6 @@ def test_resnet_nhwc_matches_nchw():
         assert k1 == k2 and v1.shape == v2.shape
 
 
-@pytest.mark.slow
 def test_se_resnext50_forward_and_grads():
     """SE-ResNeXt (grouped convs + SE gates) trains a step; the SE gate
     actually modulates (zeroing excite bias shifts outputs)."""
@@ -188,7 +183,6 @@ def test_se_resnext50_forward_and_grads():
     assert blk.conv1._attrs["groups"] == 32
 
 
-@pytest.mark.slow
 def test_resnet_nhwc_pallas_bn_matches_nchw():
     """NHWC resnet == NCHW resnet on transposed input (same seed, same
     params): the layout knob changes memory order only. Also asserts

@@ -276,29 +276,6 @@ def test_optimizer_step_counter(mon):
     assert monitor.snapshot("optimizer.")["optimizer.step.SGD"] == 1
 
 
-def test_adam_multi_tensor_fallback_on_unequal_beta_pows(mon):
-    model = nn.Linear(4, 4)
-    o = opt.Adam(learning_rate=1e-3, parameters=model.parameters(),
-                 use_multi_tensor=True)
-    loss = model(pt.to_tensor(np.ones((2, 4), np.float32))).sum()
-    loss.backward()
-    params = [p for p in model.parameters() if p._grad is not None]
-    assert len(params) >= 2
-    for p in params:
-        o._pre_param(p)
-    # knock one param out of lockstep (as a partial restore would)
-    o._accumulators[id(params[0])]["beta1_pow"].data = \
-        jnp.asarray(0.9, jnp.float32)
-    opt.Adam._warned_unequal_beta_pow = False
-    try:
-        with pytest.warns(RuntimeWarning, match="multi-tensor Adam"):
-            o.step()
-    finally:
-        opt.Adam._warned_unequal_beta_pow = False
-    assert monitor.snapshot(
-        "optimizer.")["optimizer.adam_multi_tensor_fallback"] == 1
-
-
 def test_linear_lr_warmup_init_peek_leaves_inner_untouched():
     from paddle_tpu.fluid.dygraph_lr import (LinearLrWarmup,
                                              NaturalExpDecay)

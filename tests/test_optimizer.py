@@ -52,6 +52,102 @@ def test_adamw_decoupled_decay():
                                atol=1e-4)
 
 
+def _adam_reference(p, g, m, v, t, lr, wd, b1=0.9, b2=0.999, eps=1e-8):
+    """Adam / AdamW in NumPy float64; ``t`` counts this leaf's own steps."""
+    m = b1 * m + (1 - b1) * g
+    v = b2 * v + (1 - b2) * g * g
+    step = lr * (m / (1 - b1 ** t)) / (np.sqrt(v / (1 - b2 ** t)) + eps)
+    return p - step - lr * wd * p, m, v
+
+
+@pytest.mark.parametrize("layout", ["per_leaf", "flat_arena"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("cls,wd", [(opt.Adam, 0.0), (opt.AdamW, 0.1)],
+                         ids=["Adam", "AdamW"])
+def test_adam_rule_follows_a_float64_reference(cls, wd, dtype, layout):
+    """Five compiled steps on seeded gradients: the parameters follow the
+    float64 rule to their dtype's rounding, keep their dtype (the float32
+    lr must not promote a bfloat16 leaf), and the step compiles once."""
+    import jax.numpy as jnp
+    from paddle_tpu import jit, monitor, nn
+    rng = np.random.RandomState(0)
+    shapes = [(5, 3), (7,)]
+
+    def rounded(x):  # to values the dtype holds exactly
+        return np.asarray(jnp.asarray(x, dtype).astype(jnp.float32))
+
+    p0 = [rounded(rng.randn(*s)) for s in shapes]
+    grads = [[rounded(rng.randn(*s)) for s in shapes] for _ in range(5)]
+    ws = [pt.Parameter(jnp.asarray(p, dtype)) for p in p0]
+    holder = nn.Layer()
+    holder.w0, holder.w1 = ws
+    kw = {"weight_decay": wd} if cls is opt.AdamW else {}
+    o = cls(learning_rate=0.1, parameters=ws,
+            flat_arena=(layout == "flat_arena"), **kw)
+
+    def step(g0, g1):
+        loss = (ws[0] * g0).sum() + (ws[1] * g1).sum()
+        loss.backward()
+        o.step()
+        o.clear_grad()
+        return loss
+
+    monitor.enable(None)
+    try:
+        compiles0 = monitor.counter("jit.compile")._value
+        recompiles0 = monitor.counter("jit.recompile")._value
+        fn = jit.to_static(step, models=[holder], optimizers=[o])
+        ref = [(p.astype("f8"), np.zeros_like(p, "f8"),
+                np.zeros_like(p, "f8")) for p in p0]
+        # a bfloat16 leaf rounds to 2^-8 of its size at every step
+        tol = {"float32": 2e-5, "bfloat16": 5 * 2.0 ** -8}[dtype]
+        for t, gs in enumerate(grads, 1):
+            fn(*[pt.to_tensor(jnp.asarray(g, dtype)) for g in gs])
+            ref = [_adam_reference(p, g.astype("f8"), m, v, t, 0.1, wd)
+                   for (p, m, v), g in zip(ref, gs)]
+            for w, (p, _, _) in zip(ws, ref):
+                assert str(w.numpy().dtype) == dtype
+                np.testing.assert_allclose(
+                    np.asarray(w.numpy(), "f8"), p, rtol=tol, atol=tol)
+        assert monitor.counter("jit.compile")._value == compiles0 + 1
+        assert monitor.counter("jit.recompile")._value == recompiles0
+    finally:
+        monitor.disable(flush_counters=False)
+    assert (o._arena is not None) == (layout == "flat_arena")
+
+
+def test_a_parameter_that_skips_steps_keeps_its_own_bias_correction():
+    """Per leaf, each parameter carries its own beta-pows: a leaf whose
+    gradient is None on alternate steps is corrected by ITS step count,
+    not by its neighbour's (what a shared correction would get wrong)."""
+    rng = np.random.RandomState(1)
+    a = pt.Parameter(rng.randn(4).astype("f4"))
+    b = pt.Parameter(rng.randn(4).astype("f4"))
+    o = opt.Adam(learning_rate=0.05, parameters=[a, b])
+    ref = {k: (w.numpy().astype("f8"), np.zeros(4), np.zeros(4), 0)
+           for k, w in (("a", a), ("b", b))}
+    for i in range(6):
+        ga, gb = rng.randn(4).astype("f4"), rng.randn(4).astype("f4")
+        loss = (a * pt.to_tensor(ga)).sum()
+        live = {"a": ga}
+        if i % 2 == 0:
+            loss = loss + (b * pt.to_tensor(gb)).sum()
+            live["b"] = gb
+        loss.backward()
+        o.step()
+        o.clear_grad()
+        for k, g in live.items():
+            p, m, v, t = ref[k]
+            ref[k] = _adam_reference(p, g.astype("f8"), m, v, t + 1,
+                                     0.05, 0.0) + (t + 1,)
+    assert ref["a"][3] == 6 and ref["b"][3] == 3
+    np.testing.assert_allclose(a.numpy(), ref["a"][0], rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(b.numpy(), ref["b"][0], rtol=1e-5, atol=1e-6)
+    slots = o._accumulators[id(b)]
+    np.testing.assert_allclose(float(slots["beta1_pow"].numpy()), 0.9 ** 3,
+                               rtol=1e-6)
+
+
 def test_adagrad_rmsprop_adadelta_run():
     for cls in [opt.Adagrad, opt.RMSProp, opt.Adadelta, opt.Adamax,
                 opt.Lamb, opt.Ftrl, opt.DecayedAdagrad, opt.LarsMomentum]:

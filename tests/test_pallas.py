@@ -5,7 +5,7 @@ import pytest
 
 import paddle_tpu as pt
 from paddle_tpu.ops.pallas import (layer_norm, softmax_cross_entropy,
-                                   flash_attention, fused_adam_update)
+                                   flash_attention)
 
 
 def test_layer_norm_forward_matches():
@@ -103,40 +103,6 @@ def test_flash_attention_backward():
                                atol=3e-3)
     np.testing.assert_allclose(np.asarray(v.grad), np.asarray(v2.grad),
                                atol=3e-3)
-
-
-def test_fused_adam_matches_rule():
-    import jax.numpy as jnp
-    rng = np.random.RandomState(0)
-    p = rng.randn(37, 5).astype("f4")  # deliberately unaligned size
-    g = rng.randn(37, 5).astype("f4")
-    m = np.zeros_like(p)
-    v = np.zeros_like(p)
-    lr, b1, b2, eps = 0.01, 0.9, 0.999, 1e-8
-    b1p, b2p = b1, b2
-    new_p, new_m, new_v = fused_adam_update(
-        jnp.asarray(p), jnp.asarray(g), jnp.asarray(m), jnp.asarray(v),
-        lr, b1p, b2p, beta1=b1, beta2=b2, eps=eps)
-    m_ref = (1 - b1) * g
-    v_ref = (1 - b2) * g * g
-    p_ref = p - lr * (m_ref / (1 - b1p)) / (
-        np.sqrt(v_ref / (1 - b2p)) + eps)
-    np.testing.assert_allclose(np.asarray(new_p), p_ref, atol=1e-5)
-    np.testing.assert_allclose(np.asarray(new_m), m_ref, atol=1e-6)
-    np.testing.assert_allclose(np.asarray(new_v), v_ref, atol=1e-6)
-
-
-def test_fused_adam_in_optimizer():
-    from paddle_tpu import optimizer as opt
-    w1 = pt.Parameter(np.ones((8, 4), "f4"))
-    w2 = pt.Parameter(np.ones((8, 4), "f4"))
-    o1 = opt.Adam(learning_rate=0.1, parameters=[w1], use_fused=True)
-    o2 = opt.Adam(learning_rate=0.1, parameters=[w2])
-    for o, w in ((o1, w1), (o2, w2)):
-        (w * w).sum().backward()
-        o.step()
-        o.clear_grad()
-    np.testing.assert_allclose(w1.numpy(), w2.numpy(), atol=1e-5)
 
 
 def test_pallas_layer_norm_layer_flag():
@@ -399,29 +365,6 @@ def test_flash_wrapper_dropout_no_fallback_shape():
     assert out.shape == [b, h, s, d]
 
 
-def test_fused_adam_multiblock_grid():
-    """Tensors bigger than one (1024, 128) block must grid-stride
-    correctly (the single-block VMEM-OOM regression at BERT-embedding
-    scale: 7 refs x 4096 rows blew the 16MB scoped-VMEM limit)."""
-    import jax.numpy as jnp
-    rng = np.random.RandomState(1)
-    n = 1024 * 128 * 2 + 77  # 2 full row-blocks + ragged tail
-    p = rng.randn(n).astype("f4")
-    g = rng.randn(n).astype("f4")
-    m = rng.rand(n).astype("f4") * 0.1
-    v = rng.rand(n).astype("f4") * 0.01
-    lr, b1, b2, eps = 1e-3, 0.9, 0.999, 1e-8
-    new_p, new_m, new_v = fused_adam_update(
-        jnp.asarray(p), jnp.asarray(g), jnp.asarray(m), jnp.asarray(v),
-        lr, b1, b2, beta1=b1, beta2=b2, eps=eps)
-    m_ref = b1 * m + (1 - b1) * g
-    v_ref = b2 * v + (1 - b2) * g * g
-    p_ref = p - lr * (m_ref / (1 - b1)) / (np.sqrt(v_ref / (1 - b2)) + eps)
-    np.testing.assert_allclose(np.asarray(new_p), p_ref, atol=1e-5)
-    np.testing.assert_allclose(np.asarray(new_m), m_ref, atol=1e-6)
-    np.testing.assert_allclose(np.asarray(new_v), v_ref, atol=1e-6)
-
-
 def test_layer_norm_multiblock_rows():
     """Row count spanning several blocks incl. a partial final block; the
     bwd dw/db accumulation must not double-count or include padding."""
@@ -464,9 +407,9 @@ def test_pallas_configure_overrides():
     from paddle_tpu.ops import pallas as P
     try:
         assert P.enabled("layer_norm") == P.on_tpu()
-        P.configure(layer_norm=True, fused_adam=False)
+        P.configure(layer_norm=True, softmax_xent=True)
         assert P.enabled("layer_norm") is True
-        assert P.enabled("fused_adam") is False
+        assert P.enabled("softmax_xent") is True
         # a LayerNorm built BEFORE the configure() call still honors it
         from paddle_tpu import nn
         ln = nn.LayerNorm(16)
@@ -476,12 +419,11 @@ def test_pallas_configure_overrides():
         out_xla = ln(x).numpy()
         np.testing.assert_allclose(out_forced, out_xla, atol=1e-5)
     finally:
-        P.configure(layer_norm=None, fused_adam=None)
-        # None restores the measured auto defaults: layer_norm is
-        # auto-on on TPU, fused_adam auto-off everywhere (it loses to
-        # XLA's own update fusion — docs/performance.md)
+        P.configure(layer_norm=None, softmax_xent=None)
+        # None restores the auto defaults: layer_norm is auto-on on
+        # TPU, softmax_xent auto-off everywhere (docs/performance.md)
         assert P.enabled("layer_norm") == P.on_tpu()
-        assert P.enabled("fused_adam") is False
+        assert P.enabled("softmax_xent") is False
 
 
 def test_softmax_xent_gated_in_loss_op():
@@ -519,11 +461,17 @@ def test_softmax_xent_gated_in_loss_op():
     np.testing.assert_allclose(g_k, g_x, atol=1e-4)
 
 
-def test_pallas_configure_rejects_unknown():
+@pytest.mark.parametrize("name", [
+    "flash_atention",    # a typo must not pass silently
+    # kernels that are gone (PR 29): a script that still asks for one
+    # is told so, not left believing it measured a kernel
+    "fused_adam", "fused_adam_multi"])
+def test_pallas_configure_rejects_unknown(name):
     from paddle_tpu.ops import pallas as P
-    import pytest
     with pytest.raises(ValueError):
-        P.configure(flash_atention=False)  # typo must not pass silently
+        P.configure(**{name: False})
+    with pytest.raises(ValueError):
+        P.enabled(name)
 
 
 def test_softmax_xent_gated_in_cross_entropy():
@@ -737,76 +685,3 @@ def test_fused_batch_norm_gated_in_layer():
         np.testing.assert_allclose(a, b_, atol=3e-4)
 
 
-def test_fused_adam_multi_matches_per_tensor():
-    """Multi-tensor kernel == the plain-XLA per-tensor math (shared
-    beta pows, mixed shapes incl. scalar-ish and non-128-aligned)."""
-    import jax.numpy as jnp
-    from paddle_tpu.ops.pallas.fused_adam import (
-        adam_step, fused_adam_update_multi)
-
-    rng = np.random.RandomState(0)
-    shapes = [(3, 5), (17,), (2, 2, 2), (1,)]
-    ps = [jnp.asarray(rng.randn(*s).astype("f4")) for s in shapes]
-    gs = [jnp.asarray(rng.randn(*s).astype("f4")) for s in shapes]
-    ms = [jnp.asarray(rng.rand(*s).astype("f4")) for s in shapes]
-    vs = [jnp.asarray(rng.rand(*s).astype("f4")) for s in shapes]
-    lr, b1p, b2p = 0.01, 0.9, 0.999
-
-    nps, nms, nvs = fused_adam_update_multi(ps, gs, ms, vs, lr, b1p, b2p)
-    for i in range(len(shapes)):
-        ep, em, ev = adam_step(ps[i], gs[i], ms[i], vs[i], lr, b1p, b2p,
-                               use_fused=False)
-        np.testing.assert_allclose(np.asarray(nps[i]), np.asarray(ep),
-                                   rtol=2e-5, atol=1e-6)
-        np.testing.assert_allclose(np.asarray(nms[i]), np.asarray(em),
-                                   rtol=2e-5, atol=1e-6)
-        np.testing.assert_allclose(np.asarray(nvs[i]), np.asarray(ev),
-                                   rtol=2e-5, atol=1e-6)
-
-
-def test_fused_adam_multi_weight_decay():
-    """Decoupled wd inside the kernel == AdamW's p - lr*wd*p term."""
-    import jax.numpy as jnp
-    from paddle_tpu.ops.pallas.fused_adam import (
-        adam_step, fused_adam_update_multi)
-    rng = np.random.RandomState(1)
-    p = jnp.asarray(rng.randn(4, 4).astype("f4"))
-    g = jnp.asarray(rng.randn(4, 4).astype("f4"))
-    m = jnp.zeros((4, 4), jnp.float32)
-    v = jnp.zeros((4, 4), jnp.float32)
-    lr, wd = 0.01, 0.1
-    nps, _, _ = fused_adam_update_multi([p], [g], [m], [v], lr, 0.9,
-                                        0.999, weight_decay=wd)
-    ep, _, _ = adam_step(p, g, m, v, lr, 0.9, 0.999, use_fused=False)
-    expect = np.asarray(ep) - lr * wd * np.asarray(p)
-    np.testing.assert_allclose(np.asarray(nps[0]), expect, rtol=2e-5,
-                               atol=1e-6)
-
-
-def test_adam_optimizer_multi_tensor_path():
-    """optimizer.AdamW(use_multi_tensor=True) trains identically to the
-    per-tensor path (all params stepping together)."""
-    from paddle_tpu import nn, optimizer
-
-    def build():
-        pt.seed(3)
-        m = nn.Sequential(nn.Linear(6, 8), nn.ReLU(), nn.Linear(8, 2))
-        return m
-
-    x = pt.to_tensor(np.random.RandomState(2).randn(4, 6).astype("f4"))
-    y = pt.to_tensor(np.random.RandomState(3).randn(4, 2).astype("f4"))
-
-    results = []
-    for multi in (False, True):
-        m = build()
-        o = optimizer.AdamW(learning_rate=1e-2,
-                            parameters=m.parameters(),
-                            weight_decay=0.01, use_multi_tensor=multi)
-        for _ in range(4):
-            loss = pt.nn.functional.mse_loss(m(x), y)
-            loss.backward()
-            o.step()
-            o.clear_grad()
-        results.append([p.numpy().copy() for p in m.parameters()])
-    for a, b in zip(*results):
-        np.testing.assert_allclose(a, b, rtol=3e-5, atol=1e-6)

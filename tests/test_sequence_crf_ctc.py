@@ -155,7 +155,6 @@ def test_crf_decoding_matches_bruteforce():
         assert (path[bi, L:] == 0).all()
 
 
-@pytest.mark.slow
 def test_crf_trains_down():
     """CRF NLL decreases under SGD on the transition + emission params."""
     rs = np.random.RandomState(3)

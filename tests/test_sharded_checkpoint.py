@@ -5,7 +5,6 @@ the 8-device mesh — placement preserved, training resumes bit-exact."""
 import os
 
 import numpy as np
-import pytest
 import jax
 from jax.sharding import PartitionSpec as P
 
@@ -58,7 +57,6 @@ def _sharded_param(model):
     raise AssertionError("no ffn1.weight found")
 
 
-@pytest.mark.slow
 def test_orbax_roundtrip_placement_and_bitexact_resume(tmp_path):
     fleet, model, ids, mlm, nsp = _make_fleet_model()
     o = optimizer.Adam(learning_rate=1e-3, parameters=model.parameters())
@@ -109,7 +107,6 @@ def test_checkpoint_manager_sharded_model(tmp_path):
     assert p.data.sharding.spec == P(None, "tp")
 
 
-@pytest.mark.slow
 def test_save_inference_model_from_fleet(tmp_path):
     fleet, model, ids, mlm, nsp = _make_fleet_model()
     model.eval()

@@ -1,10 +1,7 @@
 """Live telemetry plane: OpenMetrics exporter endpoints, the periodic
 sampler, /healthz stall semantics, teardown hygiene, serving SLO
-rollups + qps decay, device-memory hardening, and the perf regression
-sentinel's verdicts."""
-import importlib.util
+rollups + qps decay, and device-memory hardening."""
 import json
-import os
 import threading
 import time
 import urllib.error
@@ -20,8 +17,6 @@ from paddle_tpu.monitor.registry import Registry
 from paddle_tpu.resilience import faults
 from paddle_tpu.resilience.watchdog import Watchdog
 from paddle_tpu.serving import metrics as smetrics
-
-_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 @pytest.fixture(autouse=True)
@@ -399,128 +394,3 @@ def test_step_monitor_omits_empty_device_memory():
     rec = sm.step()
     rec = sm.step()
     assert rec is not None and "device_memory" not in rec
-
-
-# ---------------------------------------------------------------------------
-# perf sentinel
-
-def _sentinel():
-    spec = importlib.util.spec_from_file_location(
-        "perf_sentinel", os.path.join(_ROOT, "scripts",
-                                      "perf_sentinel.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-@pytest.fixture(scope="module")
-def sentinel():
-    return _sentinel()
-
-
-BASE = {"bert_base_seq128_tokens_per_sec": 100000.0,
-        "resnet50_images_per_sec": 2000.0, "serving_p99_ms": 10.0}
-
-
-def test_sentinel_flags_regression(sentinel):
-    rows = sentinel.compare({"value": 80000.0,
-                             "resnet50_images_per_sec": 1990.0}, BASE)
-    v = {r["metric"]: r["verdict"] for r in rows}
-    assert v["bert_tokens_per_sec"] == "regression"
-    assert v["resnet50_images_per_sec"] == "ok"
-
-
-def test_sentinel_within_band_and_improved(sentinel):
-    rows = sentinel.compare({"value": 95000.0,
-                             "resnet50_images_per_sec": 2400.0,
-                             "serving_p99_ms": 11.0}, BASE)
-    v = {r["metric"]: r["verdict"] for r in rows}
-    assert v["bert_tokens_per_sec"] == "ok"          # -5% < 10% band
-    assert v["resnet50_images_per_sec"] == "improved"
-    assert v["serving_p99_ms"] == "ok"               # +10% < 50% band
-
-
-def test_sentinel_lower_is_better_latency(sentinel):
-    rows = sentinel.compare({"value": 100000.0, "serving_p99_ms": 16.0},
-                            BASE)
-    v = {r["metric"]: r["verdict"] for r in rows}
-    assert v["serving_p99_ms"] == "regression"       # +60% > 50% band
-
-
-def test_sentinel_outage_skipped_not_failed(sentinel):
-    rows = sentinel.compare(
-        {"value": 0.0, "resnet50_images_per_sec": 0.0,
-         "error": "backend init failed: no device"}, BASE)
-    assert all(r["verdict"] == "outage" for r in rows
-               if r["candidate"] is not None)
-
-
-def test_sentinel_silent_zero_is_regression(sentinel):
-    # zero WITHOUT an error field is slow code, not a dead run
-    rows = sentinel.compare({"value": 0.0}, BASE)
-    v = {r["metric"]: r["verdict"] for r in rows}
-    assert v["bert_tokens_per_sec"] == "regression"
-
-
-def _write(path, blob):
-    with open(path, "w") as fh:
-        json.dump(blob, fh)
-
-
-def test_sentinel_end_to_end_repo_layout(sentinel, tmp_path):
-    """Driver-format rounds: old slow round is NOT judged (history,
-    not candidate); the newest outage round exits 0; a regressed
-    newest JSONL artifact exits 1."""
-    root = str(tmp_path)
-    os.makedirs(os.path.join(root, "docs"))
-    _write(os.path.join(root, "BENCH_r01.json"),
-           {"n": 1, "cmd": "python bench.py", "rc": 0, "tail": "",
-            "parsed": {"value": 60000.0,
-                       "resnet50_images_per_sec": 1500.0}})
-    _write(os.path.join(root, "BENCH_r02.json"),
-           {"n": 2, "cmd": "python bench.py", "rc": 1, "tail": "",
-            "parsed": {"value": 0.0, "error": "no device",
-                       "last_committed_measurement": BASE,
-                       "last_committed_measurement_file":
-                           "docs/bench_r04_measured.json"}})
-    _write(os.path.join(root, "docs", "bench_r04_measured.json"), BASE)
-
-    assert sentinel.main(["--repo-root", root]) == 0  # outage round
-
-    # a driver round with parsed=None (raw-traceback round) also skips
-    _write(os.path.join(root, "BENCH_r03.json"),
-           {"n": 3, "cmd": "python bench.py", "rc": 1,
-            "tail": "Traceback ...", "parsed": None})
-    assert sentinel.main(["--repo-root", root]) == 0
-
-    jsonl = os.path.join(root, "bench.jsonl")
-    with open(jsonl, "w") as fh:
-        fh.write(json.dumps({"value": 99000.0}) + "\n")   # old line
-        fh.write(json.dumps({"value": 70000.0}) + "\n")   # newest: bad
-    assert sentinel.main(["--repo-root", root,
-                          "--jsonl", jsonl]) == 1
-
-    with open(jsonl, "a") as fh:
-        fh.write(json.dumps({"value": 101000.0}) + "\n")  # recovered
-    assert sentinel.main(["--repo-root", root,
-                          "--jsonl", jsonl]) == 0
-
-
-def test_sentinel_baseline_discovery_prefers_banked(sentinel, tmp_path):
-    root = str(tmp_path)
-    _write(os.path.join(root, "BENCH_r01.json"),
-           {"n": 1, "cmd": "c", "rc": 0, "tail": "",
-            "parsed": {"value": 50000.0,
-                       "last_committed_measurement": BASE}})
-    blob, src = sentinel.discover_baseline(root)
-    assert blob["bert_base_seq128_tokens_per_sec"] == 100000.0
-    assert "BENCH_r01.json" in src
-
-
-def test_sentinel_no_records_is_a_failure(sentinel, tmp_path, capsys):
-    """Nothing to compare is not a pass — and that is what the
-    repository root holds until the benchmark PR banks a record."""
-    assert sentinel.main(["--repo-root", str(tmp_path)]) == 1
-    out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert out["ok"] is False and "no records" in out["note"]
-    assert sentinel.main(["--repo-root", _ROOT]) == 1
