@@ -100,9 +100,11 @@ def phase_kernels(rows, hidden, batch, heads, seq, head_dim,
     from paddle_tpu.ops.pallas.layer_norm import layer_norm as pallas_ln
 
     shipped = sorted(k for k, on in P._AUTO_ON.items() if on)
-    if shipped != ["flash_attention", "layer_norm", "ssd_scan"]:
+    if shipped != ["causal_conv1d", "flash_attention", "gated_rms_norm",
+                   "layer_norm", "ssd_scan"]:
         raise AssertionError(f"_AUTO_ON ships {shipped}; this phase covers "
-                             f"flash_attention, layer_norm and ssd_scan")
+                             f"causal_conv1d, flash_attention, "
+                             f"gated_rms_norm, layer_norm and ssd_scan")
     on_chip = pt.device.is_tpu_backend()
     if on_chip and P.interpret_mode():
         raise AssertionError("interpret mode reachable on a TPU backend")
@@ -198,6 +200,42 @@ def phase_kernels(rows, hidden, batch, heads, seq, head_dim,
 
     run(f"ssd_scan[{batch}x{seq}x{h}x{p},state{n},bf16]", k_scan, r_scan,
         scan_args, 7, tol_bf16, 2)
+
+    # the same mixer's convolution (x | B | C: h * p + 2 * heads * n
+    # channels, 4 taps, SiLU) and its gated norm (h * p lanes in `heads`
+    # groups), bf16 in and out
+    from paddle_tpu.ops.nn_ops import _rms_norm
+    from paddle_tpu.ops.pallas import causal_conv1d as conv
+    from paddle_tpu.ops.pallas import gated_rms_norm as gnorm
+    from paddle_tpu.ops.ssm import _conv1d
+    inner, taps = h * p, 4
+    channels = inner + 2 * heads * n
+    if not (conv.supported((batch, seq, channels), taps)
+            and gnorm.supported((batch, seq, inner), heads)):
+        raise AssertionError("the mixer's stage kernels would not take "
+                             "this shape")
+    conv_args = (
+        jnp.asarray(rng.randn(batch, seq, channels), jnp.bfloat16),
+        jnp.asarray(rng.uniform(-0.5, 0.5, (channels, taps)), jnp.float32),
+        jnp.asarray(rng.uniform(-0.5, 0.5, channels), jnp.float32),
+        jnp.asarray(rng.randn(batch, seq, channels), jnp.float32))
+    run(f"causal_conv1d[{batch}x{seq}x{channels},{taps}taps,bf16]",
+        lambda *a: (conv.causal_conv1d(*a[:3], activation="silu")
+                    .astype(jnp.float32) * a[3]).sum(),
+        lambda *a: (_conv1d(*a[:3], activation="silu") * a[3]).sum(),
+        conv_args, 3, tol_bf16, 2)
+    norm_args = (
+        jnp.asarray(rng.randn(batch, seq, inner), jnp.bfloat16),
+        jnp.asarray(rng.randn(batch, seq, inner), jnp.bfloat16),
+        jnp.asarray(1.0 + 0.1 * rng.randn(inner), jnp.float32),
+        jnp.asarray(rng.randn(batch, seq, inner), jnp.float32))
+    run(f"gated_rms_norm[{batch}x{seq}x{inner},{heads}groups,bf16]",
+        lambda *a: (gnorm.gated_rms_norm(*a[:3], epsilon=1e-5,
+                                         num_groups=heads)
+                    .astype(jnp.float32) * a[3]).sum(),
+        lambda *a: (_rms_norm(*a[:3], epsilon=1e-5, num_groups=heads,
+                              gated=True, scaled=True) * a[3]).sum(),
+        norm_args, 3, tol_bf16, 2)
 
 
 # ---------------------------------------------------------------------------

@@ -16,6 +16,8 @@ TPU-first choices:
 """
 from __future__ import annotations
 
+import functools
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -577,6 +579,24 @@ def layer_norm(x, normalized_shape, weight=None, bias=None, epsilon=1e-5,
                      name="layer_norm")
 
 
+def _rms_norm(x, *rest, epsilon, num_groups, gated, scaled):
+    """The portable path of ``rms_norm`` (``rest``: the gate where
+    ``gated``, then the weight where ``scaled``) and the oracle of the
+    gated kernels."""
+    rest = list(rest)
+    h = x.astype(jnp.float32)
+    if gated:
+        h = h * jax.nn.silu(rest.pop(0).astype(jnp.float32))
+    shape = h.shape
+    h = h.reshape(shape[:-1] + (num_groups, shape[-1] // num_groups))
+    h = h * lax.rsqrt(jnp.mean(jnp.square(h), -1, keepdims=True)
+                      + epsilon)
+    h = h.reshape(shape)
+    if scaled:
+        h = h * rest.pop(0).astype(jnp.float32)
+    return h.astype(x.dtype)
+
+
 def rms_norm(x, weight=None, epsilon=1e-5, num_groups=1, gate=None,
              name=None):
     """Root-mean-square norm over the last axis (Zhang & Sennrich,
@@ -585,28 +605,33 @@ def rms_norm(x, weight=None, epsilon=1e-5, num_groups=1, gate=None,
     that many groups, each with a mean square of its own; ``gate``
     multiplies ``x`` by ``silu(gate)`` BEFORE the norm (Mamba-2's gated
     norm). Statistics in float32 whatever ``x`` is; the result has ``x``'s
-    dtype."""
-    def impl(x, *rest, epsilon, num_groups, gated, scaled):
-        rest = list(rest)
-        h = x.astype(jnp.float32)
-        if gated:
-            h = h * jax.nn.silu(rest.pop(0).astype(jnp.float32))
-        shape = h.shape
-        h = h.reshape(shape[:-1] + (num_groups, shape[-1] // num_groups))
-        h = h * lax.rsqrt(jnp.mean(jnp.square(h), -1, keepdims=True)
-                          + epsilon)
-        h = h.reshape(shape)
-        if scaled:
-            h = h * rest.pop(0).astype(jnp.float32)
-        return h.astype(x.dtype)
-
+    dtype. The gated norm of a ``[B, S, D]`` array runs, on one TPU and
+    where its tiles fit, as the kernel pair of
+    ``ops/pallas/gated_rms_norm.py`` (a group is a window of lanes, no
+    reshape; PERF.md section 6, PR 30); counters
+    ``rms_norm.gated_kernel_traced`` / ``rms_norm.gated_xla_traced``."""
     args = (x,) + (() if gate is None else (gate,)) \
         + (() if weight is None else (weight,))
+    attrs = dict(epsilon=float(epsilon), num_groups=int(num_groups))
+    fn = functools.partial(_rms_norm, gated=gate is not None,
+                           scaled=weight is not None)
+    if gate is not None:
+        from .. import monitor
+        from . import pallas
+        # read off the call: the kernel pair where its tiles fit and the
+        # registry has it on, else _rms_norm
+        kernel = (pallas.enabled("gated_rms_norm")
+                  and pallas.gated_rms_norm_mod.supported(
+                      tuple(x.shape), int(num_groups))
+                  and tuple(gate.shape) == tuple(x.shape)
+                  and (weight is None
+                       or tuple(weight.shape) == tuple(x.shape[-1:])))
+        monitor.counter("rms_norm.gated_kernel_traced" if kernel
+                        else "rms_norm.gated_xla_traced").inc()
+        if kernel:
+            fn = pallas.gated_rms_norm_mod.gated_rms_norm
     with _pscope("F.rms_norm"):
-        return apply(impl, args,
-                     dict(epsilon=float(epsilon), num_groups=int(num_groups),
-                          gated=gate is not None, scaled=weight is not None),
-                     name="rms_norm")
+        return apply(fn, args, attrs, name="rms_norm")
 
 
 def group_norm(x, num_groups, weight=None, bias=None, epsilon=1e-5,

@@ -31,7 +31,7 @@ def on_tpu():
 # Per-kernel default overrides: None = auto.
 _overrides = {}
 _KERNELS = ("layer_norm", "flash_attention", "softmax_xent", "batch_norm",
-            "ssd_scan")
+            "ssd_scan", "causal_conv1d", "gated_rms_norm")
 
 # Auto defaults from one builder-run v5e ablation (2026-07-31, superseded
 # toolchain, not reproduced — docs/performance.md carries the table):
@@ -54,8 +54,20 @@ _KERNELS = ("layer_norm", "flash_attention", "softmax_xent", "batch_norm",
 # (medians of five shared-seed pairs). Beside
 # this switch the op looks at the call: shapes the tiles do not fit keep
 # _ssd (ssd_scan.supported), as does everything off-TPU or under a mesh.
+# causal_conv1d, gated_rms_norm: on, measured on the same v5e (PERF.md
+# section 6, PR 30). Alone at the nemotron cell's size, bfloat16: the
+# convolution (1 x 8,192 x 6,144, 4 taps, SiLU) 0.59 ms forward and 1.21
+# backward where ops/ssm.py's _conv1d took 1.78 and 5.81; the gated norm
+# (1 x 8,192 x 4,096 in 8 groups) 0.35 and 0.55 where ops/nn_ops.py's
+# _rms_norm took 2.13 and 3.42. In nemotron3_nano_30b_a3b.causal_pretrain
+# F.causal_conv1d fell from 26.05 to 9.88 ms of a step, the gated
+# F.rms_norm from 21.58 to 4.17, the step from 301.4 to 262.2 ms (means
+# of two shared-seed pairs). Each op looks at the call as ssd_scan does
+# (causal_conv1d.supported, gated_rms_norm.supported; an F.rms_norm
+# without a gate never asks).
 _AUTO_ON = {"layer_norm": True, "flash_attention": True,
-            "softmax_xent": False, "batch_norm": False, "ssd_scan": True}
+            "softmax_xent": False, "batch_norm": False, "ssd_scan": True,
+            "causal_conv1d": True, "gated_rms_norm": True}
 
 
 # flash is an O(S^2)-score win: below some sequence length the XLA sdpa
@@ -103,8 +115,8 @@ def gspmd_trace(n_devices):
 def configure(flash_min_seq=_UNSET, **kernels):
     """configure(layer_norm=False, softmax_xent=None, ...) — override the
     auto default for named kernels ('layer_norm', 'flash_attention',
-    'softmax_xent', 'batch_norm', 'ssd_scan'); any other name raises
-    ValueError. None restores auto.
+    'softmax_xent', 'batch_norm', 'ssd_scan', 'causal_conv1d',
+    'gated_rms_norm'); any other name raises ValueError. None restores auto.
     flash_min_seq=N routes sequences shorter than N to XLA sdpa even
     with the flash kernel enabled (N=0 disables the gate);
     flash_min_seq=None restores the measured default crossover,
@@ -147,6 +159,8 @@ from . import softmax_xent as softmax_xent_mod
 from . import flash_attention as flash_attention_mod
 from . import batch_norm as batch_norm_mod
 from . import ssd_scan as ssd_scan_mod
+from . import causal_conv1d as causal_conv1d_mod
+from . import gated_rms_norm as gated_rms_norm_mod
 
 from .layer_norm import layer_norm
 from .softmax_xent import softmax_cross_entropy

@@ -9,8 +9,13 @@ sequence, no step-by-step loop, so the MXU does the work and the backward
 pass is the products' own.
 
 Both go through ``dispatch.apply`` (tape autograd, ``jit.to_static``,
-``jit.recompute``). The convolution is plain XLA. The scan has two forms
-of one algorithm: ``_ssd`` below, plain XLA, runs anywhere (a CPU, a step
+``jit.recompute``), and each has two forms of one algorithm, chosen by
+what the call shows. The convolution: ``_conv1d`` below, plain XLA, and
+on one TPU, where rows and channels fit their tiles, the kernel pair of
+``ops/pallas/causal_conv1d.py``, which reads and writes the call's own
+dtype in the mixer's ``[B, S, C]`` layout (PERF.md section 6, PR 30);
+counters ``causal_conv1d.kernel_traced`` / ``causal_conv1d.xla_traced``.
+The scan: ``_ssd`` below, plain XLA, runs anywhere (a CPU, a step
 that spans devices, any chunk and width) and is the kernels' oracle; on
 one TPU, where the shapes fit their tiles, ``ops/pallas/ssd_scan.py``'s
 kernel pair runs it with every chunk x chunk and heads x P x N array in
@@ -29,6 +34,20 @@ from .nn_ops import _pscope
 __all__ = ["causal_conv1d", "ssd_scan"]
 
 
+def _conv1d(x, w, *b, activation):
+    """The portable path of ``causal_conv1d`` and the kernels' oracle:
+    float32 taps over a padded float32 copy, rounded once."""
+    s, taps = x.shape[1], w.shape[1]
+    xf = jnp.pad(x.astype(jnp.float32), [(0, 0), (taps - 1, 0), (0, 0)])
+    wf = w.astype(jnp.float32)
+    y = sum(xf[:, j:j + s] * wf[:, j] for j in range(taps))
+    if b:
+        y = y + b[0].astype(jnp.float32)
+    if activation == "silu":
+        y = jax.nn.silu(y)
+    return y.astype(x.dtype)
+
+
 def causal_conv1d(x, weight, bias=None, activation=None, name=None):
     """Depthwise causal convolution along the sequence: ``y[t, c] =
     sum_j weight[c, j] * x[t - (K - 1) + j, c] (+ bias[c])``, positions
@@ -38,21 +57,21 @@ def causal_conv1d(x, weight, bias=None, activation=None, name=None):
     if activation not in (None, "silu"):
         raise ValueError(f"causal_conv1d: activation {activation!r} is "
                          f"not one of None, 'silu'")
-
-    def impl(x, w, *b, activation):
-        s, taps = x.shape[1], w.shape[1]
-        xf = jnp.pad(x.astype(jnp.float32), [(0, 0), (taps - 1, 0), (0, 0)])
-        wf = w.astype(jnp.float32)
-        y = sum(xf[:, j:j + s] * wf[:, j] for j in range(taps))
-        if b:
-            y = y + b[0].astype(jnp.float32)
-        if activation == "silu":
-            y = jax.nn.silu(y)
-        return y.astype(x.dtype)
-
+    from .. import monitor
+    from . import pallas
+    # read off the call, as ssd_scan below: the kernel pair where its
+    # tiles fit and the registry has it on, else _conv1d
+    kernel = (pallas.enabled("causal_conv1d")
+              and pallas.causal_conv1d_mod.supported(
+                  tuple(x.shape), int(weight.shape[1]))
+              and (bias is None
+                   or tuple(bias.shape) == tuple(x.shape[-1:])))
+    monitor.counter("causal_conv1d.kernel_traced" if kernel
+                    else "causal_conv1d.xla_traced").inc()
     args = (x, weight) if bias is None else (x, weight, bias)
     with _pscope("F.causal_conv1d"):
-        return apply(impl, args, dict(activation=activation),
+        return apply(pallas.causal_conv1d_mod.causal_conv1d if kernel
+                     else _conv1d, args, dict(activation=activation),
                      name="causal_conv1d")
 
 

@@ -304,6 +304,85 @@ def test_ssd_scan_kernels_compile_where_supported(one_chip, compiled_kernels,
     assert count == 2
 
 
+# -- the nemotron cell's mixer: its convolution and gated norm as kernels ----
+
+def test_mixer_stages_cross_hbm_in_bfloat16_and_the_mixers_layout(
+        one_chip, compiled_kernels):
+    """One ``Mamba2Mixer`` of ``nemotron3_nano_30b_a3b.causal_pretrain``
+    (hidden 2,688; 64 heads x 64 in 8 groups, state 128, 4 taps), forward
+    + backward at 1 x 8,192 under bfloat16 autocast: the convolution and
+    the gated norm are the kernel pairs, and the compiled text holds no
+    float32 array of rows x channels of either stage and no group count
+    on the sublanes (a count over the compiled text, no time)."""
+    import paddle_tpu as pt
+    from paddle_tpu import amp, nn
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    mixer = nn.Mamba2Mixer(2688, 64, 64, 128, n_groups=8)
+    params = dict(mixer.named_parameters())
+    held = {n: p.data for n, p in params.items()}
+
+    def loss(u, values):
+        for n, p in params.items():
+            p.data = values[n]
+        with pt.no_grad(), amp.auto_cast(dtype="bfloat16"):
+            return jnp.sum(mixer(pt.Tensor(u)).data.astype(f32))
+
+    try:
+        text = _compiled_text(
+            jax.grad(loss, argnums=(0, 1)), one_chip, ((1, 8192, 2688), bf16),
+            {n: (tuple(v.shape), v.dtype) for n, v in held.items()},
+            names=("conv1d_fwd", "conv1d_bwd", "gated_norm_fwd",
+                   "gated_norm_bwd", "ssd_fwd", "ssd_bwd"))
+    finally:
+        for n, p in params.items():
+            p.data = held[n]
+    assert text.count("tpu_custom_call") == 6
+    # the entry computation's instructions are what reaches HBM (a fused
+    # computation's body upcasts in registers)
+    entry = text[text.index("\nENTRY "):]
+    wide = {shape for shape in _shapes(entry, "f32")
+            if shape[-2:] in ((8192, 6144), (8192, 4096))
+            or shape == (1024, 8, 8, 512)}
+    assert not wide, wide
+    # what the mixer holds between its stages is bfloat16, rows x channels
+    assert (1, 8192, 6144) in _shapes(entry, "bf16")
+    assert (1, 8192, 4096) in _shapes(entry, "bf16")
+
+
+@pytest.mark.parametrize("b,s,c,taps", [(2, 1024, 768, 4), (1, 384, 128, 2),
+                                        (1, 256, 256, 9)],
+                         ids=["2x1024x768-K4", "384x128-K2", "256x256-K9"])
+def test_conv1d_kernels_compile_where_supported(one_chip, compiled_kernels,
+                                                b, s, c, taps):
+    from paddle_tpu.ops.pallas import causal_conv1d as K
+    assert K.supported((b, s, c), taps)
+    for dtype in (jnp.bfloat16, jnp.float32):
+        count = _compile(
+            _grad_sum(lambda *a: K.causal_conv1d(*a, activation="silu"),
+                      argnums=(0, 1, 2)), one_chip,
+            ((b, s, c), dtype), ((c, taps), jnp.float32),
+            ((c,), jnp.float32), names=("conv1d_bwd",))
+        assert count == 1            # the forward's result is not needed
+
+
+@pytest.mark.parametrize("b,s,d,groups", [(2, 1024, 1024, 8), (1, 384, 2048, 1),
+                                          (1, 128, 256, 1)],
+                         ids=["2x1024x1024-G8", "384x2048-G1", "128x256-G1"])
+def test_gated_norm_kernels_compile_where_supported(one_chip,
+                                                    compiled_kernels, b, s, d,
+                                                    groups):
+    from paddle_tpu.ops.pallas import gated_rms_norm as K
+    assert K.supported((b, s, d), groups)
+    for dtype in (jnp.bfloat16, jnp.float32):
+        count = _compile(
+            _grad_sum(lambda *a: K.gated_rms_norm(*a, epsilon=1e-5,
+                                                  num_groups=groups),
+                      argnums=(0, 1, 2)), one_chip,
+            ((b, s, d), dtype), ((b, s, d), dtype), ((d,), jnp.float32),
+            names=("gated_norm_bwd",))
+        assert count == 1
+
+
 # -- the two kernels that ship off -----------------------------------------
 
 def test_batch_norm_fwd_bwd(one_chip, compiled_kernels):
@@ -420,7 +499,9 @@ def test_to_static_step_on_a_mesh_traces_without_kernels(compiled_kernels):
 KERNEL_NAMES = {
     "batch_norm.py": ["batch_norm_stats", "batch_norm_apply",
                       "batch_norm_bwd_reduce", "batch_norm_bwd_dx"],
+    "causal_conv1d.py": ["conv1d_fwd", "conv1d_bwd"],
     "flash_attention.py": ["flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"],
+    "gated_rms_norm.py": ["gated_norm_fwd", "gated_norm_bwd"],
     "layer_norm.py": ["layer_norm_fwd", "layer_norm_bwd"],
     "softmax_xent.py": ["softmax_xent_fwd", "softmax_xent_bwd"],
     "ssd_scan.py": ["ssd_fwd", "ssd_bwd"],
@@ -461,7 +542,7 @@ def test_no_pallas_call_site_is_left_out_and_no_name_is_used_twice():
     found = {f: names for f, names in found.items() if names}
     assert found == KERNEL_NAMES
     every = [n for names in found.values() for n in names]
-    assert len(every) == len(set(every)) == 13
+    assert len(every) == len(set(every)) == 17
 
 
 def test_every_registered_kernel_has_a_module_with_a_call_site():

@@ -37,7 +37,8 @@ def test_phase_kernels_tiny_interpret(cs, capsys):
     cs.phase_kernels(rows=64, hidden=128, batch=2, heads=2, seq=128,
                      head_dim=32)
     out = _lines(capsys, "kernels")
-    assert len(out) == 6        # layer norm f32+bf16, flash x3, the scan
+    # layer norm f32+bf16, flash x3, the scan, the convolution, the gated norm
+    assert len(out) == 8
     assert any("padmask" in l for l in out)
     assert all("tpu_custom_calls=0" in l for l in out)   # interpreted
 

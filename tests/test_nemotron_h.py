@@ -209,6 +209,103 @@ def test_rms_norm_matches_the_reference(groups, gated):
         nn.RMSNorm(30, num_groups=4)
 
 
+# -- the mixer's convolution and gated norm as kernels -----------------------
+
+# the tiny widths padded to what the kernels' tiles take: 128 positions,
+# heads of 64 in two groups (a group of the norm is 128 lanes), state 32
+# (the convolution runs over 256 + 2 * 2 * 32 = 384 channels)
+_KERNEL_WIDTHS = dict(mamba_head_dim=64, ssm_state_size=32, n_groups=2)
+
+
+def _with_stage_kernels(forced, run):
+    """``run()`` with the two kernel pairs forced (interpret mode) or
+    left to the registry (off on a CPU), and how many call sites traced
+    each: ((conv kernel, conv XLA), (norm kernel, norm XLA))."""
+    from paddle_tpu.ops import pallas as P
+
+    def traced():
+        conv, norm = (monitor.snapshot(k) for k in ("causal_conv1d",
+                                                     "rms_norm"))
+        return np.array([
+            [conv.get("causal_conv1d.kernel_traced", 0),
+             conv.get("causal_conv1d.xla_traced", 0)],
+            [norm.get("rms_norm.gated_kernel_traced", 0),
+             norm.get("rms_norm.gated_xla_traced", 0)]], int)
+
+    if forced:
+        P.configure(causal_conv1d=True, gated_rms_norm=True)
+    try:
+        before = traced()
+        out = run()
+        return out, (traced() - before).tolist()
+    finally:
+        P.configure(causal_conv1d=None, gated_rms_norm=None)
+
+
+def test_mixer_gives_the_same_result_and_gradients_through_the_kernels():
+    pt.seed(3)
+    mixer = nn.Mamba2Mixer(64, 4, 64, 32, n_groups=2, chunk_size=32)
+    u = np.random.default_rng(1).normal(size=(2, 128, 64)).astype(np.float32)
+    probe = np.random.default_rng(2).normal(size=(2, 128, 64)).astype(
+        np.float32)
+
+    def run():
+        x = pt.to_tensor(u)
+        x.stop_gradient = False
+        for p in mixer.parameters():
+            p._grad = None
+        y = mixer(x)
+        (y * pt.to_tensor(probe)).sum().backward()
+        return [y.numpy(), np.asarray(x._grad)] + [
+            np.asarray(p._grad) for _, p in mixer.named_parameters()]
+
+    plain, took = _with_stage_kernels(False, run)
+    assert took == [[0, 1], [0, 1]]
+    kernels, took = _with_stage_kernels(True, run)
+    assert took == [[1, 0], [1, 0]]
+    names = ["y", "u"] + [n for n, _ in mixer.named_parameters()]
+    assert len(plain) == len(names) == 10
+    for name, a, b in zip(names, plain, kernels):
+        assert np.abs(a - b).max() <= 2e-5 * np.abs(a).max(), name
+
+
+@pytest.mark.parametrize("recompute", [False, True],
+                         ids=["plain", "recompute"])
+def test_model_gives_the_same_loss_and_gradients_through_the_kernels(
+        recompute):
+    """The whole model (``ME`` at the padded widths), every block
+    recomputed or not: the loss and every parameter's gradient with the
+    convolution and the gated norm through their kernels are the portable
+    paths', and both are the reference's."""
+    model, cfg, weights = _model(recompute=recompute, num_hidden_layers=2,
+                                 hybrid_override_pattern="ME",
+                                 **_KERNEL_WIDTHS)
+    ids = _ids(rows=2, seq=128)
+
+    def run():
+        for p in model.parameters():
+            p._grad = None
+        loss = model.loss(model(pt.to_tensor(ids)), pt.to_tensor(ids))
+        loss.backward()
+        return float(loss.numpy()), {n: np.asarray(p._grad)
+                                     for n, p in model.named_parameters()}
+
+    (plain_loss, plain), took = _with_stage_kernels(False, run)
+    assert took == [[0, 1], [0, 1]]         # one mixer, traced once
+    (loss, grads), took = _with_stage_kernels(True, run)
+    assert took == [[1, 0], [1, 0]]
+    assert abs(loss - plain_loss) < 1e-6
+    want_loss, want = jax.value_and_grad(
+        lambda q: R.loss_fn(cfg, q, (jnp.asarray(ids),)))(weights)
+    assert abs(loss - float(want_loss)) < 1e-5
+    for name, g in grads.items():
+        scale = np.abs(plain[name]).max() + 1e-12
+        assert np.abs(g - plain[name]).max() / scale < 2e-5, name
+        ref = np.asarray(want[name])
+        assert np.abs(g - ref).max() / (np.abs(ref).max() + 1e-12) < 5e-5, \
+            name
+
+
 # -- routed experts ---------------------------------------------------------
 
 def _moe_cfg(**kw):
