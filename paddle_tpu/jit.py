@@ -84,9 +84,12 @@ def _collect_state(models, optimizers, scalers=()):
     for oi, o in enumerate(optimizers):
         o._ensure_all_slots()
         holders[f"o{oi}.lr"] = o._lr_tensor
-        for pid, slots in o._accumulators.items():
+        # named by position, not by id(param): the sorted names are the
+        # step's argument order, and an order that follows addresses
+        # gives every process its own module and compile-cache key
+        for k, slots in enumerate(o._accumulators.values()):
             for sname, t in slots.items():
-                holders[f"o{oi}.{pid}.{sname}"] = t
+                holders[f"o{oi}.{k:06d}.{sname}"] = t
         arena = getattr(o, "_arena", None)
         if arena is not None:
             covered |= arena.param_ids

@@ -27,6 +27,9 @@ def __getattr__(name):
     if name in ("NemotronHConfig", "NemotronHForCausalLM"):
         from . import nemotron_h
         return getattr(nemotron_h, name)
+    if name in ("JoyAIFlashConfig", "JoyAIFlashForCausalLM"):
+        from . import joyai_llm_flash
+        return getattr(joyai_llm_flash, name)
     if name in ("Transformer",):
         from . import transformer
         return getattr(transformer, name)

@@ -1,7 +1,8 @@
-"""Sequence mixers of hybrid language models: a Mamba-2 state-space mixer
-and grouped-query attention.
+"""Sequence mixers of hybrid and latent-attention language models: a
+Mamba-2 state-space mixer, grouped-query attention and multi-head latent
+attention.
 
-No reference counterpart in Paddle Fluid 1.7. Both map ``[B, S, hidden]``
+No reference counterpart in Paddle Fluid 1.7. All map ``[B, S, hidden]``
 to ``[B, S, hidden]`` with no bias, no dropout and no cache: the training
 path. (A state cache for serving is future work, PERF.md section 7.)
 """
@@ -14,9 +15,12 @@ import numpy as np
 from .layer import Layer
 from .layers import Linear, RMSNorm
 from .. import initializer as I
+from ..ops import manip
+from ..ops import nn_ops as F
 from ..ops import ssm as S
 
-__all__ = ["Mamba2Mixer", "GroupedQueryAttention"]
+__all__ = ["Mamba2Mixer", "GroupedQueryAttention",
+           "MultiHeadLatentAttention"]
 
 
 class Mamba2Mixer(Layer):
@@ -95,8 +99,9 @@ class GroupedQueryAttention(Layer):
     ``num_heads`` in front of the attention op, which is the flash
     dispatch (``ops.pallas.flash_attention``: the Pallas kernels on a TPU
     from ``flash_min_seq`` on, else ``F.scaled_dot_product_attention``);
-    a kernel that reads each K/V head once is future work (PERF.md
-    section 7)."""
+    the kernels take any ``head_dim`` (a size that is no multiple of 128
+    lanes is a block's whole last dimension); a kernel that reads each
+    K/V head once is future work (PERF.md section 7)."""
 
     def __init__(self, hidden_size, num_heads, num_kv_heads, head_dim,
                  causal=True):
@@ -133,4 +138,79 @@ class GroupedQueryAttention(Layer):
         ctx = flash_attention(q, k, v, causal=self.causal, force=force_flash)
         ctx = ctx.transpose([0, 2, 1, 3]).reshape(
             [b, s, self.num_heads * self.head_dim])
+        return self.o_proj(ctx)
+
+
+class MultiHeadLatentAttention(Layer):
+    """Multi-head latent attention (DeepSeek-V2, arXiv:2405.04434 section
+    2.1; the ``deepseek_v3`` / ``joyai_llm_flash`` model codes), training
+    form: queries and keys/values go through low-rank latents with an RMS
+    norm each, and positions enter through a decoupled rotary part —
+    per query head ``qk_rope_head_dim`` wide, and ONE rotary key head that
+    all heads share:
+
+        c_q = RMSNorm(u W_qa);  [q_nope | q_rope]_h = c_q W_qb
+        [c_kv | k_r] = u W_kva;  [k_nope | v]_h = RMSNorm(c_kv) W_kvb
+        q_h = [q_nope_h | R(q_rope_h)],  k_h = [k_nope_h | R(k_r)]
+        o_h = softmax(q_h k_h^T / sqrt(d_qk) + causal) v_h;  out = [o_h] W_o
+
+    with ``R`` = ``F.rotary_embedding`` (interleaved pairs) and ``d_qk =
+    qk_nope_head_dim + qk_rope_head_dim``. Queries and keys are ``d_qk``
+    wide and values ``v_head_dim``: the flash dispatch
+    (``ops.pallas.flash_attention``) takes the two sizes as they are. The
+    rotary key head is repeated to ``num_heads`` in front of it (a kernel
+    that reads it once is future work, PERF.md section 7). No bias.
+    Parameter names are the source's (``q_a_proj``, ``q_a_layernorm``,
+    ``q_b_proj``, ``kv_a_proj_with_mqa``, ``kv_a_layernorm``,
+    ``kv_b_proj``, ``o_proj``). The compressed cache and the absorbed
+    decode path of serving are not here."""
+
+    def __init__(self, hidden_size, num_heads, q_lora_rank, kv_lora_rank,
+                 qk_nope_head_dim, qk_rope_head_dim, v_head_dim,
+                 rope_theta=10000.0, epsilon=1e-6):
+        super().__init__()
+        self.num_heads, self.kv_lora_rank = num_heads, kv_lora_rank
+        self.qk_nope_head_dim = qk_nope_head_dim
+        self.qk_rope_head_dim = qk_rope_head_dim
+        self.v_head_dim, self.rope_theta = v_head_dim, float(rope_theta)
+        qk = qk_nope_head_dim + qk_rope_head_dim
+        self.q_a_proj = Linear(hidden_size, q_lora_rank, bias_attr=False)
+        self.q_a_layernorm = RMSNorm(q_lora_rank, epsilon)
+        self.q_b_proj = Linear(q_lora_rank, num_heads * qk, bias_attr=False)
+        self.kv_a_proj_with_mqa = Linear(
+            hidden_size, kv_lora_rank + qk_rope_head_dim, bias_attr=False)
+        self.kv_a_layernorm = RMSNorm(kv_lora_rank, epsilon)
+        self.kv_b_proj = Linear(
+            kv_lora_rank, num_heads * (qk_nope_head_dim + v_head_dim),
+            bias_attr=False)
+        self.o_proj = Linear(num_heads * v_head_dim, hidden_size,
+                             bias_attr=False)
+
+    def qkv(self, x):
+        """``(q, k, v)`` as the attention op takes them: ``[B, heads, S,
+        d_qk]`` twice and ``[B, heads, S, v_head_dim]``."""
+        b, s, h = x.shape[0], x.shape[1], self.num_heads
+        nope, rope = self.qk_nope_head_dim, self.qk_rope_head_dim
+        q = self.q_b_proj(self.q_a_layernorm(self.q_a_proj(x)))
+        q = q.reshape([b, s, h, nope + rope]).transpose([0, 2, 1, 3])
+        ckv = self.kv_a_proj_with_mqa(x)
+        kv = self.kv_b_proj(self.kv_a_layernorm(
+            ckv[:, :, :self.kv_lora_rank]))
+        kv = kv.reshape([b, s, h, nope + self.v_head_dim]).transpose(
+            [0, 2, 1, 3])
+        q_rope = F.rotary_embedding(q[:, :, :, nope:], theta=self.rope_theta)
+        k_rope = F.rotary_embedding(ckv[:, :, self.kv_lora_rank:],
+                                    theta=self.rope_theta)
+        k_rope = k_rope.unsqueeze(1).expand([b, h, s, rope])
+        q = manip.concat([q[:, :, :, :nope], q_rope], axis=-1)
+        k = manip.concat([kv[:, :, :, :nope], k_rope], axis=-1)
+        return q, k, kv[:, :, :, nope:]
+
+    def forward(self, x, force_flash=False):
+        b, s = x.shape[0], x.shape[1]
+        q, k, v = self.qkv(x)
+        from ..ops.pallas import flash_attention
+        ctx = flash_attention(q, k, v, causal=True, force=force_flash)
+        ctx = ctx.transpose([0, 2, 1, 3]).reshape(
+            [b, s, self.num_heads * self.v_head_dim])
         return self.o_proj(ctx)

@@ -25,7 +25,7 @@ from ..tensor import Tensor
 from ..dispatch import apply
 from .. import initializer as I
 
-__all__ = ["MoEFFN", "RoutedMoE", "moe_aux_loss"]
+__all__ = ["MoEFFN", "RoutedMoE", "GatedMLP", "moe_aux_loss"]
 
 
 class MoEFFN(Layer):
@@ -110,11 +110,33 @@ class MoEFFN(Layer):
         return y
 
 
+class GatedMLP(Layer):
+    """The gated feed-forward block of the Llama / DeepSeek families
+    (SwiGLU; Shazeer, arXiv:2002.05202): ``W_down(silu(W_gate x) * W_up
+    x)``, no bias. Parameter names are the families' (``gate_proj``,
+    ``up_proj``, ``down_proj``)."""
+
+    def __init__(self, d_model, d_ffn):
+        super().__init__()
+        from .layers import Linear
+        self.gate_proj = Linear(d_model, d_ffn, bias_attr=False)
+        self.up_proj = Linear(d_model, d_ffn, bias_attr=False)
+        self.down_proj = Linear(d_ffn, d_model, bias_attr=False)
+
+    def forward(self, x):
+        from ..ops import nn_ops as F
+        return self.down_proj(F.silu(self.gate_proj(x)) * self.up_proj(x))
+
+
 class RoutedMoE(Layer):
     """The local half of an expert-parallel mixture-of-experts layer, as
     DeepSeek-V3-style models route (sigmoid scores, a selection bias,
-    top-k weights renormalised and scaled, one always-on shared expert,
-    relu-squared experts without a gate): the layer is TOLD which experts
+    top-k weights renormalised and scaled, one always-on shared expert).
+    The experts are ``W_down relu(W_up x)^2`` (``nemotron_h``), or with
+    ``gated=True`` ``W_down(silu(W_gate x) * W_up x)`` (DeepSeek-V3,
+    ``joyai_llm_flash``): ``experts_gate`` beside ``experts_up``, and the
+    shared expert a :class:`GatedMLP` named ``shared_experts`` in place of
+    ``shared_up`` / ``shared_down``. The layer is TOLD which experts
     it holds (``experts_held``, a range over the model's ``num_experts``),
     routes over all of them, and returns what its own experts give for
     the tokens routed to them, plus the shared expert:
@@ -141,7 +163,8 @@ class RoutedMoE(Layer):
                 "moe.expert_load_max", "moe.steps", "moe.rows_computed")
 
     def __init__(self, d_model, d_expert, num_experts, top_k,
-                 d_shared=None, experts_held=None, routed_scaling_factor=1.0):
+                 d_shared=None, experts_held=None, routed_scaling_factor=1.0,
+                 gated=False):
         super().__init__()
         import jax.numpy as jnp
         from .layers import Linear
@@ -160,14 +183,21 @@ class RoutedMoE(Layer):
         # gradient (none here), so a buffer and not a parameter
         self.register_buffer("e_score_correction_bias", Tensor(
             jnp.zeros((num_experts,), jnp.float32)))
+        self.experts_gate = None
+        if gated:
+            self.experts_gate = self.create_parameter(
+                (len(held), d_model, d_expert),
+                default_initializer=I.Normal(0.0, 0.02))
         self.experts_up = self.create_parameter(
             (len(held), d_model, d_expert),
             default_initializer=I.Normal(0.0, 0.02))
         self.experts_down = self.create_parameter(
             (len(held), d_expert, d_model),
             default_initializer=I.Normal(0.0, 0.02))
-        self.shared_up = self.shared_down = None
-        if d_shared:
+        self.shared_up = self.shared_down = self.shared_experts = None
+        if d_shared and gated:
+            self.shared_experts = GatedMLP(d_model, d_shared)
+        elif d_shared:
             self.shared_up = Linear(d_model, d_shared, bias_attr=False)
             self.shared_down = Linear(d_shared, d_model, bias_attr=False)
         self.register_buffer("stats", monitor.device_counters.register(
@@ -183,9 +213,12 @@ class RoutedMoE(Layer):
             top_k=self.top_k, scale=self.routed_scaling_factor)
         y, seen = M.moe_experts(u, experts, weights, self.experts_up,
                                 self.experts_down,
-                                first_expert=self.experts_held.start)
+                                first_expert=self.experts_held.start,
+                                w_gate=self.experts_gate)
         self.stats.data = self.stats.data + seen.data
-        if self.shared_up is not None:
+        if self.shared_experts is not None:
+            y = y + self.shared_experts(u)
+        elif self.shared_up is not None:
             h = F.relu(self.shared_up(u))
             y = y + self.shared_down(h * h)
         return y

@@ -14,8 +14,9 @@ impl through ``dispatch.apply``:
   picks, inside the step, the smallest capacity of a ladder that holds
   its rows (``_ladder``: from ``MIN_ROWS`` up by doubling to the number
   of tokens, which is the most one expert can draw), gathers that
-  many rows, runs its two products on them and adds the result back to
-  its tokens. Dropless by construction: the ladder's last rung holds
+  many rows, runs its products on them (two, ``W_down relu(W_up x)^2``;
+  three with a gate, ``W_down (silu(W_gate x) * W_up x)``) and adds the
+  result back to its tokens. Dropless by construction: the ladder's last rung holds
   every token, so no imbalance can overflow it. The backward pass is
   written by hand (``jax.custom_vjp``) over the same rows and makes the
   hidden activations again, so nothing of a rung's size is kept between
@@ -94,17 +95,25 @@ def _on_rung(rung, ladder, run, *carry):
                       *carry)
 
 
-def _grouped_rows(rows, gate, w_up, w_down, order, rung, ladder, dot_dtype):
-    """``y[t] = sum_e gate[e, t] W_down[e] relu(W_up[e] rows[t])^2`` over
-    the first ``ladder[rung[e]]`` tokens of ``order[e]``; ``gate`` is 0
-    for every token after an expert's own. float32 [tokens, d]."""
+def _grouped_rows(rows, gate, ups, w_down, order, rung, ladder, dot_dtype):
+    """``y[t] = sum_e gate[e, t] W_down[e] h_e(rows[t])`` over the first
+    ``ladder[rung[e]]`` tokens of ``order[e]``; ``gate`` is 0 for every
+    token after an expert's own. ``ups`` is ``(w_up,)`` for ``h = relu(W_up
+    x)^2`` and ``(w_gate, w_up)`` for the gated ``h = silu(W_gate x) *
+    (W_up x)``. float32 [tokens, d]."""
     def expert(y, xs):
-        up, down, ids, g, r = xs
+        ups, down, ids, g, r = xs
 
         def run(cap, y):
             at = ids[:cap]
-            h = _dot(rows[at], up.astype(dot_dtype), ((1,), (0,)))
-            h = jnp.square(jax.nn.relu(h)) * g[at][:, None]
+            if len(ups) == 1:
+                h = _dot(rows[at], ups[0].astype(dot_dtype), ((1,), (0,)))
+                h = jnp.square(jax.nn.relu(h)) * g[at][:, None]
+            else:
+                x = rows[at]
+                a, u = (_dot(x, w.astype(dot_dtype), ((1,), (0,)))
+                        for w in ups)
+                h = jax.nn.silu(a) * u * g[at][:, None]
             out = _dot(h.astype(dot_dtype), down.astype(dot_dtype),
                        ((1,), (0,)))
             return y.at[at].add(out, unique_indices=True)
@@ -112,54 +121,76 @@ def _grouped_rows(rows, gate, w_up, w_down, order, rung, ladder, dot_dtype):
         return _on_rung(r, ladder, run, y), None
 
     y = jnp.zeros(rows.shape, jnp.float32)
-    return lax.scan(expert, y, (w_up, w_down, order, gate, rung))[0]
+    return lax.scan(expert, y, (ups, w_down, order, gate, rung))[0]
 
 
 _grouped = jax.custom_vjp(_grouped_rows, nondiff_argnums=(6, 7))
 
 
-def _grouped_fwd(rows, gate, w_up, w_down, order, rung, ladder, dot_dtype):
-    y = _grouped_rows(rows, gate, w_up, w_down, order, rung, ladder,
+def _grouped_fwd(rows, gate, ups, w_down, order, rung, ladder, dot_dtype):
+    y = _grouped_rows(rows, gate, ups, w_down, order, rung, ladder,
                       dot_dtype)
-    return y, (rows, gate, w_up, w_down, order, rung)
+    return y, (rows, gate, ups, w_down, order, rung)
 
 
 def _grouped_bwd(ladder, dot_dtype, saved, dy):
-    rows, gate, w_up, w_down, order, rung = saved
+    rows, gate, ups, w_down, order, rung = saved
     f32 = jnp.float32
 
     def expert(dx, xs):
-        up, down, ids, g, r = xs
+        ups, down, ids, g, r = xs
 
         def run(cap, dx):
             at = ids[:cap]
             x, ga, dyr = rows[at], g[at][:, None], dy[at].astype(dot_dtype)
-            upc, downc = up.astype(dot_dtype), down.astype(dot_dtype)
-            act = jax.nn.relu(_dot(x, upc, ((1,), (0,))))
-            h = jnp.square(act)
-            d_h = _dot(dyr, downc, ((1,), (1,)))            # [cap, f]
+            if len(ups) == 1:
+                upc, downc = ups[0].astype(dot_dtype), down.astype(dot_dtype)
+                act = jax.nn.relu(_dot(x, upc, ((1,), (0,))))
+                h = jnp.square(act)
+                d_h = _dot(dyr, downc, ((1,), (1,)))            # [cap, f]
+                d_down = _dot((h * ga).astype(dot_dtype), dyr, ((0,), (0,)))
+                d_gate = jnp.zeros(g.shape, f32).at[at].set(
+                    jnp.sum(d_h * h, -1), unique_indices=True)
+                d_pre = (d_h * (2.0 * ga) * act).astype(dot_dtype)
+                d_up = _dot(x, d_pre, ((0,), (0,)))
+                dx = dx.at[at].add(_dot(d_pre, upc, ((1,), (1,))),
+                                   unique_indices=True)
+                return dx, (d_up,), d_down, d_gate
+            gatec, upc = (w.astype(dot_dtype) for w in ups)
+            downc = down.astype(dot_dtype)
+            a = _dot(x, gatec, ((1,), (0,)))
+            u = _dot(x, upc, ((1,), (0,)))
+            sig = jax.nn.sigmoid(a)
+            act = a * sig                                       # silu(a)
+            h = act * u
+            d_h = _dot(dyr, downc, ((1,), (1,)))                # [cap, f]
             d_down = _dot((h * ga).astype(dot_dtype), dyr, ((0,), (0,)))
             d_gate = jnp.zeros(g.shape, f32).at[at].set(
                 jnp.sum(d_h * h, -1), unique_indices=True)
-            d_pre = (d_h * (2.0 * ga) * act).astype(dot_dtype)
-            d_up = _dot(x, d_pre, ((0,), (0,)))
-            dx = dx.at[at].add(_dot(d_pre, upc, ((1,), (1,))),
+            d_h = d_h * ga
+            d_a = (d_h * u * (sig + act * (1.0 - sig))).astype(dot_dtype)
+            d_u = (d_h * act).astype(dot_dtype)
+            dx = dx.at[at].add(_dot(d_a, gatec, ((1,), (1,)))
+                               + _dot(d_u, upc, ((1,), (1,))),
                                unique_indices=True)
-            return dx, d_up, d_down, d_gate
+            return (dx, (_dot(x, d_a, ((0,), (0,))),
+                         _dot(x, d_u, ((0,), (0,)))), d_down, d_gate)
 
-        dx, d_up, d_down, d_gate = _on_rung(r, ladder, run, dx)
-        return dx, (d_up, d_down, d_gate)
+        dx, d_ups, d_down, d_gate = _on_rung(r, ladder, run, dx)
+        return dx, (d_ups, d_down, d_gate)
 
-    dx, (d_up, d_down, d_gate) = lax.scan(
-        expert, jnp.zeros(rows.shape, f32), (w_up, w_down, order, gate, rung))
-    return (dx.astype(rows.dtype), d_gate, d_up.astype(w_up.dtype),
+    dx, (d_ups, d_down, d_gate) = lax.scan(
+        expert, jnp.zeros(rows.shape, f32), (ups, w_down, order, gate, rung))
+    return (dx.astype(rows.dtype), d_gate,
+            tuple(d.astype(w.dtype) for d, w in zip(d_ups, ups)),
             d_down.astype(w_down.dtype), None, None)
 
 
 _grouped.defvjp(_grouped_fwd, _grouped_bwd)
 
 
-def _routed(x, experts, weights, w_up, w_down, *, first, dot_dtype):
+def _routed(x, experts, weights, w_up, w_down, w_gate=None, *, first,
+            dot_dtype):
     f32 = jnp.float32
     lead, d = x.shape[:-1], x.shape[-1]
     rows = x.reshape(-1, d)
@@ -177,7 +208,8 @@ def _routed(x, experts, weights, w_up, w_down, *, first, dot_dtype):
     ladder = _ladder(tokens, MIN_ROWS)
     rung = jnp.searchsorted(jnp.asarray(ladder, jnp.int32), sizes)
     rung = jnp.minimum(rung, len(ladder) - 1).astype(jnp.int32)
-    y = _grouped(rows.astype(dot_dtype), gate, w_up, w_down, order, rung,
+    ups = (w_up,) if w_gate is None else (w_gate, w_up)
+    y = _grouped(rows.astype(dot_dtype), gate, ups, w_down, order, rung,
                  ladder, dot_dtype)
     computed = jnp.asarray(ladder, jnp.int32)[rung]
     stats = jnp.stack([jnp.sum(sizes),
@@ -188,13 +220,16 @@ def _routed(x, experts, weights, w_up, w_down, *, first, dot_dtype):
 
 
 def moe_experts(x, experts, weights, w_up, w_down, first_expert=0,
-                name=None):
+                w_gate=None, name=None):
     """``(y, stats)``: ``y[t] = sum over t's chosen experts i that are
-    held here of weights[t, i] * W_down[i] relu(W_up[i] x[t])^2``.
+    held here of weights[t, i] * W_down[i] h_i(x[t])``, with ``h_i(x) =
+    relu(W_up[i] x)^2``, or, given ``w_gate``, the gated ``silu(W_gate[i]
+    x) * (W_up[i] x)``.
 
     ``experts`` / ``weights`` [..., k] from :func:`moe_route`, over all the
-    model's experts; ``w_up`` [held, d, f] and ``w_down`` [held, f, d]
-    are the experts ``first_expert .. first_expert + held``. ``stats`` is
+    model's experts; ``w_up`` (and ``w_gate``) [held, d, f] and ``w_down``
+    [held, f, d] are the experts ``first_expert .. first_expert + held``.
+    ``stats`` is
     int32[5], :data:`MOE_STATS`: slots routed here; slots a rung did not
     hold (0: the last rung holds every token); the fullest expert's rows;
     1; and the rows the products ran over, padding included (the ladder
@@ -203,11 +238,12 @@ def moe_experts(x, experts, weights, w_up, w_down, first_expert=0,
     from .. import amp
     dot_dtype = amp.compute_dtype() if amp.is_enabled() else None
 
-    def impl(x, experts, weights, w_up, w_down, *, first):
-        return _routed(x, experts, weights, w_up, w_down, first=first,
+    def impl(x, experts, weights, w_up, w_down, *gate, first):
+        return _routed(x, experts, weights, w_up, w_down, *gate, first=first,
                        dot_dtype=dot_dtype or jnp.result_type(x))
 
+    args = (x, experts, weights, w_up, w_down)
     with _pscope("F.moe_experts"):
-        return apply(impl, (x, experts, weights, w_up, w_down),
+        return apply(impl, args if w_gate is None else args + (w_gate,),
                      dict(first=int(first_expert)), n_out=2,
                      name="moe_experts")

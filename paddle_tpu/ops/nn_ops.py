@@ -764,6 +764,47 @@ def scaled_dot_product_attention(q, k, v, attn_mask=None, dropout_p=0.0,
     return apply(impl, args, attrs, name="sdpa")
 
 
+def rotary_embedding(x, positions=None, theta=10000.0, interleaved=True,
+                     name=None):
+    """Rotary position embedding (Su et al., arXiv:2104.09864) of ``x``
+    ``[..., S, D]``, the sequence on the axis before the last and ``D``
+    even: pair ``j < D / 2`` at position ``p`` turns by ``p * theta ** (-2 j
+    / D)``. ``positions`` ``[S]`` (or anything that broadcasts against
+    ``x``'s leading axes and ``S``); None counts from 0.
+
+    ``interleaved=True`` pairs the neighbours ``(x[2j], x[2j+1])`` and
+    returns the halves apart, as the DeepSeek-V3 family's code
+    de-interleaves and then rotates halves: ``out[j] = x[2j] cos - x[2j+1]
+    sin``, ``out[j + D/2] = x[2j+1] cos + x[2j] sin``. ``False`` pairs
+    ``(x[j], x[j + D/2])`` and keeps that layout. A score ``q . k`` is the
+    same for any layout q and k share, and depends on the two positions'
+    difference only. Angles and the rotation in float32 whatever ``x`` is
+    (the frequencies are made in float64 on the host); the result has
+    ``x``'s dtype."""
+    d = int(x.shape[-1])
+    if d % 2:
+        raise ValueError(f"rotary_embedding: the last axis has to be even, "
+                         f"got {d}")
+    freq = np.asarray(float(theta) ** (-np.arange(0, d, 2, dtype=np.float64)
+                                       / d), np.float32)
+
+    def impl(x, *pos, interleaved):
+        p = pos[0].astype(jnp.float32) if pos \
+            else jnp.arange(x.shape[-2], dtype=jnp.float32)
+        angle = p[..., None] * freq
+        cos, sin = jnp.cos(angle), jnp.sin(angle)
+        xf = x.astype(jnp.float32)
+        a, b = (xf[..., 0::2], xf[..., 1::2]) if interleaved \
+            else (xf[..., :d // 2], xf[..., d // 2:])
+        return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
+                               -1).astype(x.dtype)
+
+    args = (x,) if positions is None else (x, positions)
+    with _pscope("F.rotary_embedding"):
+        return apply(impl, args, dict(interleaved=bool(interleaved)),
+                     name="rotary_embedding")
+
+
 def interpolate(x, size=None, scale_factor=None, mode="nearest",
                 align_corners=False, data_format="NCHW", name=None):
     """reference: interpolate_op.cc (nearest/bilinear)."""

@@ -127,14 +127,15 @@ def _masked_scores(q, k, mask_ref, qi, j, *, block_q, block_k, sq, sk,
 def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, mask_ref, keep_ref, o_ref,
                 m_ref, l_ref, *, block_q, block_k, sq, sk, causal, scale,
                 mask_mode, dropout_p, threshold, drop_mode):
-    # q_ref: (1, BQ, D); k_ref/v_ref: (1, SKp, D); mask_ref: (1,{1,BQ},SKp)
+    # q_ref: (1, BQ, D); k_ref: (1, SKp, D); v_ref: (1, SKp, DV);
+    # mask_ref: (1, {1, BQ}, SKp); o_ref: (1, BQ, DV)
     q = q_ref[0].astype(jnp.float32) * scale
     bh = pl.program_id(0)
     qi = pl.program_id(1)
 
     m0 = jnp.full((q.shape[0], 1), -jnp.inf, jnp.float32)
     l0 = jnp.zeros((q.shape[0], 1), jnp.float32)
-    acc0 = jnp.zeros((q.shape[0], q_ref.shape[2]), jnp.float32)
+    acc0 = jnp.zeros((q.shape[0], v_ref.shape[2]), jnp.float32)
 
     def body(j, carry):
         m, l, acc = carry
@@ -221,8 +222,9 @@ def _bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, mask_ref, keep_ref,
                     block_q, block_k, sq, sk, causal, scale, mask_mode,
                     dropout_p, threshold, drop_mode):
     # this program owns ONE k-block (grid (bh, k-blocks)) and loops
-    # q-blocks. q_ref/do_ref: (1, SQp, D); k_ref/v_ref: (1, BK, D);
-    # mask_ref: (1, {1, SQp}, BK); m/linv/delta: (1, 1, SQp).
+    # q-blocks. q_ref: (1, SQp, D); do_ref: (1, SQp, DV); k_ref: (1, BK, D);
+    # v_ref: (1, BK, DV); mask_ref: (1, {1, SQp}, BK); m/linv/delta:
+    # (1, 1, SQp).
     # The score tile is computed TRANSPOSED, s^T = K Q^T (BK, BQ): the
     # (1, BQ) row statistics broadcast down its sublanes as they are read
     # (no relayout), and p^T, ds^T are already the left operands of
@@ -338,7 +340,7 @@ def _flash_fwd_res(q, k, v, mask, mask_mode, seed, causal, scale, block_q,
                    block_k, dropout_p):
     from . import interpret_mode
     b, h, sq, d = q.shape
-    sk = k.shape[2]
+    sk, dvh = k.shape[2], v.shape[3]
     bq = min(block_q, max(sq, 8))
     bk = min(block_k, sk)
     # pad K/V up to a block multiple: a manual pl.ds read past the end
@@ -348,7 +350,7 @@ def _flash_fwd_res(q, k, v, mask, mask_mode, seed, causal, scale, block_q,
     sq_pad = -(-sq // bq) * bq
     q3 = q.reshape(b * h, sq, d)
     k3 = _pad_axis(k.reshape(b * h, sk, d), 1, sk_pad)
-    v3 = _pad_axis(v.reshape(b * h, sk, d), 1, sk_pad)
+    v3 = _pad_axis(v.reshape(b * h, sk, dvh), 1, sk_pad)
     s = scale if scale is not None else 1.0 / np.sqrt(d)
     threshold = min(int(dropout_p * 4294967296.0), 4294967295)
 
@@ -393,33 +395,33 @@ def _flash_fwd_res(q, k, v, mask, mask_mode, seed, causal, scale, block_q,
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((1, sk_pad, d), lambda i, j: (i, 0, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, sk_pad, d), lambda i, j: (i, 0, 0),
+            pl.BlockSpec((1, sk_pad, dvh), lambda i, j: (i, 0, 0),
                          memory_space=pltpu.VMEM),
             mspec,
             kspec,
         ],
         out_specs=[
-            pl.BlockSpec((1, bq, d), lambda i, j: (i, j, 0),
+            pl.BlockSpec((1, bq, dvh), lambda i, j: (i, j, 0),
                          memory_space=pltpu.VMEM),
             stat_spec,
             stat_spec,
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
+            jax.ShapeDtypeStruct((b * h, sq, dvh), q.dtype),
             jax.ShapeDtypeStruct((b * h, 1, sq), jnp.float32),
             jax.ShapeDtypeStruct((b * h, 1, sq), jnp.float32),
         ],
         interpret=interp,
         name="flash_fwd",
     )(seed2, q3, k3, v3, m3, keep3)
-    return out.reshape(b, h, sq, d), mrow, lrow
+    return out.reshape(b, h, sq, dvh), mrow, lrow
 
 
 def _flash_bwd(q, k, v, mask, mask_mode, seed, out, mrow, lrow, g, causal,
                scale, block_q, block_k, dropout_p):
     from . import interpret_mode
     b, h, sq, d = q.shape
-    sk = k.shape[2]
+    sk, dvh = k.shape[2], v.shape[3]
     bq = min(block_q, max(sq, 8))
     bk = min(block_k, sk)
     sk_pad = -(-sk // bk) * bk
@@ -429,11 +431,11 @@ def _flash_bwd(q, k, v, mask, mask_mode, seed, out, mrow, lrow, g, causal,
 
     q3 = q.reshape(b * h, sq, d)
     k3 = _pad_axis(k.reshape(b * h, sk, d), 1, sk_pad)
-    v3 = _pad_axis(v.reshape(b * h, sk, d), 1, sk_pad)
-    do3 = g.reshape(b * h, sq, d)
+    v3 = _pad_axis(v.reshape(b * h, sk, dvh), 1, sk_pad)
+    do3 = g.reshape(b * h, sq, dvh)
     # delta_i = Σ_d dO_id·O_id (= Σ_k p_ik·dp_ik — valid under dropout too)
     delta = jnp.sum(do3.astype(jnp.float32) *
-                    out.reshape(b * h, sq, d).astype(jnp.float32),
+                    out.reshape(b * h, sq, dvh).astype(jnp.float32),
                     axis=-1)[:, None, :]
     linv = 1.0 / jnp.maximum(lrow, 1e-20)
     # (BH, 1, SQp), as the forward wrote them: no lane replication
@@ -484,14 +486,14 @@ def _flash_bwd(q, k, v, mask, mask_mode, seed, out, mrow, lrow, g, causal,
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((1, sk_pad, d), lambda i, j: (i, 0, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, sk_pad, d), lambda i, j: (i, 0, 0),
+            pl.BlockSpec((1, sk_pad, dvh), lambda i, j: (i, 0, 0),
                          memory_space=pltpu.VMEM),
             mspec_q,
             kspec_q,
             stat_q,
             stat_q,
             stat_q,
-            pl.BlockSpec((1, bq, d), lambda i, j: (i, j, 0),
+            pl.BlockSpec((1, bq, dvh), lambda i, j: (i, j, 0),
                          memory_space=pltpu.VMEM),
         ],
         out_specs=pl.BlockSpec((1, bq, d), lambda i, j: (i, j, 0),
@@ -517,7 +519,7 @@ def _flash_bwd(q, k, v, mask, mask_mode, seed, out, mrow, lrow, g, causal,
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((1, bk, d), lambda i, j: (i, j, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bk, d), lambda i, j: (i, j, 0),
+            pl.BlockSpec((1, bk, dvh), lambda i, j: (i, j, 0),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((1, msq_blk, bk), lambda i, j: (bh_to_g(i), 0, j),
                          memory_space=pltpu.VMEM),
@@ -525,24 +527,24 @@ def _flash_bwd(q, k, v, mask, mask_mode, seed, out, mrow, lrow, g, causal,
             stat_all,
             stat_all,
             stat_all,
-            pl.BlockSpec((1, sq_pad, d), lambda i, j: (i, 0, 0),
+            pl.BlockSpec((1, sq_pad, dvh), lambda i, j: (i, 0, 0),
                          memory_space=pltpu.VMEM),
         ],
         out_specs=[
             pl.BlockSpec((1, bk, d), lambda i, j: (i, j, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bk, d), lambda i, j: (i, j, 0),
+            pl.BlockSpec((1, bk, dvh), lambda i, j: (i, j, 0),
                          memory_space=pltpu.VMEM),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b * h, sk_pad, d), k.dtype),
-            jax.ShapeDtypeStruct((b * h, sk_pad, d), v.dtype),
+            jax.ShapeDtypeStruct((b * h, sk_pad, dvh), v.dtype),
         ],
         interpret=interp,
         name="flash_bwd_dkv",
     )(seed2, q3p, k3, v3, m3, keep3, *stats, do3p)
     dk = dk[:, :sk].reshape(b, h, sk, d)
-    dv = dv[:, :sk].reshape(b, h, sk, d)
+    dv = dv[:, :sk].reshape(b, h, sk, dvh)
     return dq.reshape(b, h, sq, d), dk, dv
 
 
@@ -577,41 +579,68 @@ _flash.defvjp(_fwd, _bwd)
 
 # Each kernel keeps the whole other side of one (batch, head) in VMEM,
 # double-buffered (the forward and dQ kernels K and V, the dK/dV kernel Q
-# and dO), beside four float32 (BQ, BK) score tiles. Compiled for a v5e at
-# 8,192 positions x 128 in bfloat16 with BK = 1024 the dK/dV kernel asks
-# for 18.4 MiB of the 16 MiB a kernel may use; with BK = 512 it fits. Up to
-# 4,096 x 128 (and at every BERT shape) the block stays as asked.
-_WHOLE_SIDE_BYTES = 2 * 1024 * 1024
+# and dO), beside four float32 (BQ, BK) score tiles, in the 16 MiB a kernel
+# may use. Compiled for a v5e at 8,192 positions in bfloat16:
+# * head size 128 for q/k and v (4 MiB a side): with BK = 1024 the dK/dV
+#   kernel asks for 18.4 MiB; with BK = 512 it fits. Up to 4,096 x 128 (and
+#   at every BERT shape) the blocks stay as asked.
+# * head size 192 for q/k, 128 for v (multi-head latent attention; 5 MiB a
+#   side as counted here, 6 in VMEM, where 192 lanes take two tiles of
+#   128): with BQ = 512 the forward asks for 16.04 MiB at BK = 512; BQ =
+#   256, BK = 512 fits all three kernels compiled alone and is the fastest
+#   pair on the chip (8.2 ms forward, 30.8 forward + backward, 32 heads),
+#   but inside the joyai_llm_flash step the dK/dV kernel then asks for
+#   16.15 MiB; 256 x 256 (11.6, 34.9) and 128 x 512 (9.9, 43.6) fit there
+#   (PERF.md section 6, PR 31). Up to 4,096 positions the blocks stay as
+#   asked.
+_WHOLE_SIDE_BYTES = 4 * 1024 * 1024
 
 
-def _block_k_that_fits(seq, d, itemsize, block_k):
-    if seq * d * itemsize >= _WHOLE_SIDE_BYTES:
-        return min(block_k, 512)
-    return block_k
+def _blocks_that_fit(seq, d, dv, itemsize, block_q, block_k):
+    """(block_q, block_k) as asked, or as large as scoped VMEM holds beside
+    the whole other side of ``seq`` positions at head sizes ``d`` (q, k)
+    and ``dv`` (v, o)."""
+    side = seq * (d + dv) * itemsize
+    if side >= _WHOLE_SIDE_BYTES:
+        block_k = min(block_k, 512)
+    if side > _WHOLE_SIDE_BYTES:
+        block_q, block_k = min(block_q, 256), min(block_k, 256)
+    return block_q, block_k
 
 
 def flash_attention(q, k, v, attn_mask=None, causal=False, scale=None,
                     block_q=512, block_k=1024, dropout_p=0.0,
                     training=False, force=False, name=None):
-    """Framework op: flash attention over (B, H, S, D). The additive (or
-    bool) attn_mask and attention-probability dropout are fused into the
-    kernels; mask shapes the kernel can't tile (non-broadcastable to
-    (B,H,Sq,Sk)) fall back to plain sdpa with identical semantics.
-    Off-TPU the op also falls back to sdpa (the interpret-mode kernel is
-    emulator-speed) unless force=True (kernel correctness tests)."""
+    """Framework op: flash attention over q, k (B, H, S, D) and v (B, H,
+    S, DV); the result is (B, H, Sq, DV). The kernels take any head sizes:
+    ``DV`` may differ from ``D`` (multi-head latent attention: 192 and
+    128), and a size that is no multiple of 128 lanes is a block's whole
+    last dimension (BERT's 64; 192), nothing is padded in HBM by this op.
+    The additive (or bool) attn_mask and attention-probability dropout are
+    fused into the kernels; mask shapes the kernel can't tile
+    (non-broadcastable to (B,H,Sq,Sk)) fall back to plain sdpa with
+    identical semantics. Off-TPU the op also falls back to sdpa (the
+    interpret-mode kernel is emulator-speed) unless force=True (kernel
+    correctness tests). ``monitor`` counters ``flash_attention.
+    kernel_traced`` / ``flash_attention.xla_traced`` count the call sites
+    that traced each path."""
     from ...dispatch import apply
+    from ... import monitor
     from ... import random as prandom
     from . import enabled
 
     b, h, sq, d = q.shape
     sk = k.shape[2]
-    block_k = _block_k_that_fits(max(sq, sk), d, q.dtype.itemsize, block_k)
+    block_q, block_k = _blocks_that_fit(max(sq, sk), d, v.shape[3],
+                                        q.dtype.itemsize, block_q, block_k)
     p_drop = float(dropout_p) if training else 0.0
     has_mask = attn_mask is not None
     mode = _mask_mode(attn_mask.shape if has_mask else None, b, h, sq, sk)
-    if mode == "fallback" or (not force and
-                              not enabled("flash_attention",
-                                          seq_len=max(sq, sk))):
+    kernel = mode != "fallback" and (force or enabled(
+        "flash_attention", seq_len=max(sq, sk)))
+    monitor.counter("flash_attention.kernel_traced" if kernel
+                    else "flash_attention.xla_traced").inc()
+    if not kernel:
         from ..nn_ops import scaled_dot_product_attention as sdpa
         return sdpa(q, k, v, attn_mask=attn_mask, is_causal=causal,
                     scale=scale, dropout_p=p_drop, training=training)

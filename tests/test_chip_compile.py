@@ -195,27 +195,64 @@ def test_flash_causal(one_chip, compiled_kernels):
 
 
 # 8,192 x 128 causal, 32 heads: the nemotron cell's attention after its K/V
-# heads are repeated. The op picks the key block (``_block_k_that_fits``): a
+# heads are repeated. The op picks the blocks (``_blocks_that_fit``): a
 # kernel keeps the whole other side of a (batch, head) in VMEM, and inside
 # the compiled step the dK/dV kernel with BK = 1024 asked for 18.4 MiB of
 # the 16 a kernel may use
 @pytest.mark.parametrize("seq", [8192, 4096])
 def test_flash_long_causal_at_head_size_128(one_chip, compiled_kernels, seq):
-    from paddle_tpu.ops.pallas.flash_attention import (_block_k_that_fits,
+    from paddle_tpu.ops.pallas.flash_attention import (_blocks_that_fit,
                                                        _flash)
-    block_k = _block_k_that_fits(seq, 128, 2, 1024)
-    assert block_k == (512 if seq == 8192 else 1024)
-    assert _block_k_that_fits(512, 64, 2, 1024) == 1024     # BERT's, as asked
+    block_q, block_k = _blocks_that_fit(seq, 128, 128, 2, 512, 1024)
+    assert (block_q, block_k) == (512, 512 if seq == 8192 else 1024)
+    # BERT's, as asked
+    assert _blocks_that_fit(512, 64, 64, 2, 512, 1024) == (512, 1024)
 
     def f(q, k, v, seed):
-        return _flash(q, k, v, None, None, seed, True, None, 512, block_k,
-                      0.0)
+        return _flash(q, k, v, None, None, seed, True, None, block_q,
+                      block_k, 0.0)
 
     qkv = ((1, 32, seq, 128), jnp.bfloat16)
     n = _compile(_grad_sum(f, argnums=(0, 1, 2)), one_chip, qkv, qkv, qkv,
                  ((2,), jnp.int32),
                  names=("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"))
     assert n == 3
+
+
+# 8,192 x (192 | 128) causal, 32 heads: the joyai_llm_flash cell's latent
+# attention — q and k 128 position-free + 64 rotary wide, v and the output
+# 128. 192 lanes are a block's whole last dimension (one and a half lane
+# tiles, two in VMEM); v is not padded to 192 anywhere. The blocks that fit
+# scoped VMEM beside 6 MiB of whole side are the rule's
+@pytest.mark.parametrize("seq", [8192, 4096])
+def test_flash_long_causal_at_head_sizes_192_and_128(one_chip,
+                                                     compiled_kernels, seq):
+    from paddle_tpu.ops.pallas.flash_attention import (_blocks_that_fit,
+                                                       _flash)
+    block_q, block_k = _blocks_that_fit(seq, 192, 128, 2, 512, 1024)
+    assert (block_q, block_k) == ((256, 256) if seq == 8192
+                                  else (512, 1024))
+
+    def f(q, k, v, seed):
+        return _flash(q, k, v, None, None, seed, True, None, block_q,
+                      block_k, 0.0)
+
+    qk = ((1, 32, seq, 192), jnp.bfloat16)
+    v = ((1, 32, seq, 128), jnp.bfloat16)
+    text = _compiled_text(_grad_sum(f, argnums=(0, 1, 2)), one_chip, qk, qk,
+                          v, ((2,), jnp.int32),
+                          names=("flash_fwd", "flash_bwd_dq",
+                                 "flash_bwd_dkv"))
+    assert text.count("tpu_custom_call") == 3
+    # v, o, dO and dV cross HBM 128 wide, q, k, dq and dk 192 wide: per
+    # kernel (192-wide, 128-wide) operands and results — forward q k | v o,
+    # dQ q k dq | v dO, dK/dV q k dk | v dO dv
+    calls = [line.split("backend_config=")[0] for line in text.splitlines()
+             if "tpu_custom_call" in line and " custom-call(" in line]
+    widths = sorted(
+        tuple(_shapes(head, "bf16").count((32, seq, w)) for w in (192, 128))
+        for head in calls)
+    assert widths == [(2, 2), (3, 2), (3, 3)], widths
 
 
 # -- the nemotron cell's routed experts: plain XLA, chosen on the device ----

@@ -262,3 +262,28 @@ def test_state_cache_sees_unfreeze():
     fn(x)
     assert not np.allclose(frozen, m.weight.numpy()), \
         "unfrozen weight never trained: stale jit state cache"
+
+
+def test_the_steps_argument_order_does_not_follow_addresses():
+    """The sorted state names are the compiled step's argument order: two
+    builds of one model (other objects at other addresses, as in another
+    process) have to name their optimizer slots alike, parameter by
+    parameter, or each process lowers a module of its own and the
+    persistent compile cache misses (PERF.md section 7 row 33)."""
+    from paddle_tpu.jit import _collect_state
+
+    def build(keep):
+        m = make_model()
+        keep.append([np.zeros(n) for n in range(1, 40)])    # move the heap
+        o = opt.AdamW(learning_rate=0.01, parameters=m.parameters())
+        holders = _collect_state([m], [o])
+        slot_of = {id(t): name for name, t in holders.items()}
+        params = {id(p): n for n, p in m.named_parameters()}
+        # which parameter each optimizer slot name stands for
+        return {slot_of[id(t)]: (params[pid], sname)
+                for pid, slots in o._accumulators.items()
+                for sname, t in slots.items()}
+
+    keep = []
+    a, b = build(keep), build(keep)
+    assert len(a) >= 8 and a == b
