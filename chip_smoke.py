@@ -174,6 +174,26 @@ def phase_kernels(rows, hidden, batch, heads, seq, head_dim,
         run(f"flash_attention[{batch}x{heads}x{seq}x{head_dim},bf16,"
             f"{name}]", k_fa, r_fa, (q, k, v, ct), 3, tol_bf16, 3)
 
+    # latent attention's head sizes, causal: q and k 3/2 as wide as v (192
+    # and 128 where head_dim is 64), against float32 sdpa — the number the
+    # next change to the kernels' arithmetic has to hold
+    dq, dv = 3 * head_dim, 2 * head_dim
+    q, k = (jnp.asarray(rng.randn(batch, heads, seq, dq), jnp.bfloat16)
+            for _ in range(2))
+    v = jnp.asarray(rng.randn(batch, heads, seq, dv), jnp.bfloat16)
+    ct = jnp.asarray(rng.randn(batch, heads, seq, dv), jnp.float32)
+
+    def k_mla(q, k, v, ct):
+        out = flash_attention(q, k, v, causal=True, force=True)
+        return (_raw(out).astype(jnp.float32) * ct).sum()
+
+    def r_mla(q, k, v, ct):
+        out = F.scaled_dot_product_attention(q, k, v, is_causal=True)
+        return (_raw(out) * ct).sum()
+
+    run(f"flash_attention[{batch}x{heads}x{seq}x{dq}|{dv},bf16,causal]",
+        k_mla, r_mla, (q, k, v, ct), 3, tol_bf16, 3)
+
     # the Mamba-2 scan over (batch, seq, 2 * heads heads of 64 in `heads`
     # groups, state 128), bf16 products, all seven gradients
     from paddle_tpu.ops.pallas import ssd_scan as ssd

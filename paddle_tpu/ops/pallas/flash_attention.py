@@ -24,6 +24,24 @@ entire log-normalizer. delta = rowsum(dO∘O) is one cheap XLA reduction
 outside the kernels (the identity Σ_k p_k·dp_k = rowsum(dO∘O) holds under
 dropout too).
 
+Inner loops (PR 32). A tile loaded from q, k, v or dO enters the MXU in
+the dtype it was read in (bfloat16 as bfloat16, float32 as float32: read
+off the call, no option) through products that contract both operands'
+own last dimension, so no float32 copy of a K or V tile is made or
+transposed; what a kernel makes itself (p, ds, p^T, ds^T) is rounded to
+that dtype in front of its product, q is scaled in float32 and rounded
+once; scores, soft-max, statistics and accumulators stay float32. On the
+chip this is the parent's arithmetic bit for bit: the MXU took a float32
+operand in one bfloat16 pass all along. Only the tiles the diagonal or a
+true length crosses make positions and select: the others run a plain
+body (the additive bias is still added, dropout still drawn), and where
+the shapes say how many crossed tiles a program has (a square causal call
+of whole blocks) they are straight-line code behind the plain loop, not a
+second loop. An inner iteration costs about half a microsecond whatever
+its tile holds, so the block rule at the end of this file takes the
+largest tiles that fit, and holds a whole side too large to keep twice
+(8,192 x 192 | 128) in one VMEM buffer to make them fit.
+
 Row statistics (m, l, and the backward's 1/l and delta) cross HBM as ONE
 f32 a (batch·head, row): (BH, 1, S) arrays with the sequence on the lane
 axis — as a forward result, as the residual saved for the backward, and as
@@ -106,22 +124,118 @@ def _host_keep_mask(seed, bh, sq_pad, sk_pad, dropout_p):
     return (u >= dropout_p).astype(jnp.float32)
 
 
-def _masked_scores(q, k, mask_ref, qi, j, *, block_q, block_k, sq, sk,
-                   causal, mask_mode):
-    """Scaled scores + additive bias with invalid positions at _NEG_INF.
-    q is pre-scaled f32 (BQ, D); k is f32 (BK, D). mask_ref rows are
-    already positioned by the BlockSpec ((1,BK) key bias broadcasts down,
-    (BQ,BK) full bias adds elementwise)."""
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)
-    if mask_mode in ("key", "full"):
-        s = s + mask_ref[0, :, pl.ds(j * block_k, block_k)].astype(
-            jnp.float32)
-    q_pos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-    k_pos = j * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    valid = (q_pos < sq) & (k_pos < sk)
+def _operand_dtype(*refs):
+    """What tiles read from ``refs`` enter the MXU as: bfloat16 as they were
+    read where both sides of a product are, float32 for everything else (a
+    float32 caller keeps float32 products)."""
+    return (jnp.bfloat16 if all(r.dtype == jnp.bfloat16 for r in refs)
+            else jnp.float32)
+
+
+def _dot(a, b):
+    """(M, K) @ (K, N) -> float32."""
+    return jax.lax.dot_general(a, b, (((1,), (0,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _dot_nt(a, b):
+    """(M, K) @ (N, K)^T -> float32, contracted over both operands' own
+    last dimension: no transposed copy of a tile is made."""
+    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _k_tile_ranges(xp, qi, *, block_q, block_k, sq, sk, causal):
+    """The k-blocks q-block ``qi`` of the forward and dQ kernels walks, as
+    ``(n_plain, n_needed)``: every position of tiles ``[0, n_plain)`` is
+    valid, tiles ``[n_plain, n_needed)`` are crossed by the diagonal or by
+    a true length, the rest lies wholly above the diagonal. Integer
+    arithmetic; ``xp`` is ``jnp`` inside a kernel (``qi`` a program id) and
+    ``np`` on the host (``qi`` every q-block at once, ``_tile_counts``)."""
+    n_needed, n_plain = -(-sk // block_k), sk // block_k
     if causal:
-        valid = valid & (q_pos >= k_pos)
-    return jnp.where(valid, s, _NEG_INF), valid
+        n_needed = xp.minimum(
+            n_needed, ((qi + 1) * block_q + block_k - 1) // block_k)
+        n_plain = xp.minimum(n_plain, (qi * block_q + 1) // block_k)
+    if sq % block_q:        # the last q-block holds rows past sq
+        n_plain = xp.where((qi + 1) * block_q <= sq, n_plain, 0)
+    return n_plain, n_needed
+
+
+def _q_tile_ranges(xp, j, *, block_q, block_k, sq, sk, causal):
+    """The q-blocks k-block ``j`` of the dK/dV kernel walks, as ``(q_start,
+    plain_lo, plain_hi)``: tiles ``[q_start, plain_lo)`` are crossed by the
+    diagonal (two of them where ``block_q < block_k``), every position of
+    ``[plain_lo, plain_hi)`` is valid, and ``[plain_hi, cdiv(sq, block_q))``
+    is the one tile with rows past ``sq``, if there is one."""
+    q_start, plain_lo, plain_hi = 0, 0, sq // block_q
+    if causal:
+        q_start = (j * block_k) // block_q
+        plain_lo = xp.minimum(
+            plain_hi, ((j + 1) * block_k + block_q - 2) // block_q)
+    if sk % block_k:        # the last k-block holds keys past sk
+        plain_lo = xp.where((j + 1) * block_k <= sk, plain_lo, plain_hi)
+    return q_start, plain_lo, plain_hi
+
+
+def _tile_counts(bh, *, block_q, block_k, sq, sk, causal):
+    """(score tiles, tiles that run the masked body) of one forward call:
+    the kernel's own bounds, added up over its grid on the host."""
+    qi = np.arange(-(-sq // block_q))
+    n_plain, n_needed = _k_tile_ranges(np, qi, block_q=block_q,
+                                       block_k=block_k, sq=sq, sk=sk,
+                                       causal=causal)
+    tiles = int(np.sum(np.broadcast_to(n_needed, qi.shape)))
+    plain = int(np.sum(np.broadcast_to(n_plain, qi.shape)))
+    return bh * tiles, bh * (tiles - plain)
+
+
+def _walk(lo, hi, body, carry):
+    """``fori_loop`` over tiles ``[lo, hi)``; no loop where the bounds are
+    the same number at trace time."""
+    if isinstance(lo, int) and isinstance(hi, int) and lo >= hi:
+        return carry
+    return jax.lax.fori_loop(lo, hi, body, carry)
+
+
+def _crossed_tiles(own, other, *, block_q, block_k, sq, sk, causal):
+    """How many tiles the diagonal crosses in every program of a kernel
+    whose programs own blocks of ``own`` positions and walk blocks of
+    ``other``, where the shapes alone say it: a square causal call of
+    whole blocks, one block size a multiple of the other. None where the
+    count differs from program to program (a true length inside a block)."""
+    if (causal and sq == sk and sq % block_q == 0 and sk % block_k == 0
+            and (own % other == 0 or other % own == 0)):
+        return max(1, own // other)
+    return None
+
+
+def _walk_crossed(lo, hi, body, carry, count):
+    """The masked tiles ``[lo, hi)``. Where their number is a fact of the
+    shapes (``count``) they are straight-line code behind the plain loop,
+    which the scheduler overlaps with the program's epilogue; a second loop
+    in their place costs more than the masks it saves (PERF.md section 6,
+    PR 32)."""
+    if count is None:
+        return _walk(lo, hi, body, carry)
+    for t in range(count):
+        carry = body(lo + t, carry)
+    return carry
+
+
+def _valid(shape, q0, k0, q_axis, *, sq, sk, block_q, block_k, causal):
+    """Which positions of a score tile that starts at query ``q0`` and key
+    ``k0`` (queries along ``q_axis``) may attend. A length that is a
+    multiple of its block has no position past it: a fact of the shapes."""
+    q_pos = q0 + jax.lax.broadcasted_iota(jnp.int32, shape, q_axis)
+    k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis)
+    valid = None
+    for term in ((q_pos < sq) if sq % block_q else None,
+                 (k_pos < sk) if sk % block_k else None,
+                 (q_pos >= k_pos) if causal else None):
+        if term is not None:
+            valid = term if valid is None else valid & term
+    return valid
 
 
 def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, mask_ref, keep_ref, o_ref,
@@ -129,48 +243,62 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, mask_ref, keep_ref, o_ref,
                 mask_mode, dropout_p, threshold, drop_mode):
     # q_ref: (1, BQ, D); k_ref: (1, SKp, D); v_ref: (1, SKp, DV);
     # mask_ref: (1, {1, BQ}, SKp); o_ref: (1, BQ, DV)
-    q = q_ref[0].astype(jnp.float32) * scale
+    geom = dict(block_q=block_q, block_k=block_k, sq=sq, sk=sk,
+                causal=causal)
     bh = pl.program_id(0)
     qi = pl.program_id(1)
+    # scaled in float32 once a q-block, then rounded to what the MXU takes
+    q = (q_ref[0].astype(jnp.float32) * scale).astype(
+        _operand_dtype(q_ref, k_ref))
+    v_dtype = _operand_dtype(v_ref)
 
     m0 = jnp.full((q.shape[0], 1), -jnp.inf, jnp.float32)
     l0 = jnp.zeros((q.shape[0], 1), jnp.float32)
     acc0 = jnp.zeros((q.shape[0], v_ref.shape[2]), jnp.float32)
 
-    def body(j, carry):
+    def tile(j, carry, masked):
         m, l, acc = carry
-        k = k_ref[0, pl.ds(j * block_k, block_k), :].astype(jnp.float32)
-        v = v_ref[0, pl.ds(j * block_k, block_k), :].astype(jnp.float32)
-        # zero padded v rows: p is 0 there, but 0 * NaN-padding would
-        # still poison the accumulator
-        row_pos = j * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_k, 1), 0)
-        v = jnp.where(row_pos < sk, v, 0.0)
-        s, _ = _masked_scores(q, k, mask_ref, qi, j, block_q=block_q,
-                              block_k=block_k, sq=sq, sk=sk, causal=causal,
-                              mask_mode=mask_mode)
+        cols = pl.ds(j * block_k, block_k)
+        k = k_ref[0, cols, :].astype(q.dtype)
+        v = v_ref[0, cols, :].astype(v_dtype)
+        s = _dot_nt(q, k)
+        if mask_mode in ("key", "full"):
+            # rows are already positioned by the BlockSpec: a (1, BK) key
+            # bias broadcasts down, a (BQ, BK) full bias adds elementwise
+            s = s + mask_ref[0, :, cols].astype(jnp.float32)
+        if masked:
+            valid = _valid(s.shape, qi * block_q, j * block_k, 0, **geom)
+            s = jnp.where(valid, s, _NEG_INF)
+            if sk % block_k:
+                # zero padded v rows: p is 0 there, but 0 * NaN-padding
+                # would still poison the accumulator
+                row_pos = j * block_k + jax.lax.broadcasted_iota(
+                    jnp.int32, (block_k, 1), 0)
+                v = jnp.where(row_pos < sk, v, jnp.zeros_like(v))
         m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
-        # rows with every key masked: keep the exp argument finite
-        m_safe = jnp.where(m_new <= _NEG_INF, 0.0, m_new)
+        m_safe = m_new
+        if masked or mask_mode is not None:
+            # rows with every key masked so far: keep the exp argument
+            # finite. exp(_NEG_INF - m_safe) is 0 without a second select
+            m_safe = jnp.where(m_new <= _NEG_INF, 0.0, m_new)
         p = jnp.exp(s - m_safe)
-        p = jnp.where(s <= _NEG_INF, 0.0, p)
-        corr = jnp.where(m <= _NEG_INF, 0.0, jnp.exp(m - m_safe))
+        corr = jnp.exp(m - m_safe)
         l_new = l * corr + jnp.sum(p, axis=1, keepdims=True)
         if dropout_p > 0.0:
             if drop_mode == "prng":
                 keep = _dropout_keep(seed_ref, bh, qi, j, p.shape,
                                      threshold)
             else:
-                keep = keep_ref[0, :, pl.ds(j * block_k, block_k)] > 0.5
+                keep = keep_ref[0, :, cols] > 0.5
             p = jnp.where(keep, p / (1.0 - dropout_p), 0.0)
-        acc_new = acc * corr + jnp.dot(p, v,
-                                       preferred_element_type=jnp.float32)
-        return m_new, l_new, acc_new
+        return m_new, l_new, acc * corr + _dot(p.astype(v.dtype), v)
 
-    nk = pl.cdiv(sk, block_k)
-    nk_needed = nk if not causal else jnp.minimum(
-        nk, pl.cdiv((qi + 1) * block_q, block_k))
-    m, l, acc = jax.lax.fori_loop(0, nk_needed, body, (m0, l0, acc0))
+    n_plain, n_needed = _k_tile_ranges(jnp, qi, **geom)
+    carry = _walk(0, n_plain, functools.partial(tile, masked=False),
+                  (m0, l0, acc0))
+    m, l, acc = _walk_crossed(
+        n_plain, n_needed, functools.partial(tile, masked=True), carry,
+        _crossed_tiles(block_q, block_k, **geom))
     o_ref[0] = (acc / jnp.maximum(l, 1e-20)).astype(o_ref.dtype)
     m_fin = jnp.where(m <= _NEG_INF, 0.0, m)
     m_ref[0] = _stat_row(m_fin)
@@ -181,8 +309,11 @@ def _bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, mask_ref, keep_ref,
                    m_ref, linv_ref, delta_ref, do_ref, dq_ref, *, block_q,
                    block_k, sq, sk, causal, scale, mask_mode, dropout_p,
                    threshold, drop_mode):
-    q = q_ref[0].astype(jnp.float32) * scale
-    do = do_ref[0].astype(jnp.float32)
+    geom = dict(block_q=block_q, block_k=block_k, sq=sq, sk=sk,
+                causal=causal)
+    q = (q_ref[0].astype(jnp.float32) * scale).astype(
+        _operand_dtype(q_ref, k_ref))
+    do = do_ref[0].astype(_operand_dtype(v_ref, do_ref))
     mrow = _stat_col(m_ref[0])       # (1, BQ) -> (BQ, 1)
     linv = _stat_col(linv_ref[0])
     delta = _stat_col(delta_ref[0])
@@ -190,30 +321,36 @@ def _bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, mask_ref, keep_ref,
     qi = pl.program_id(1)
     dq0 = jnp.zeros((q.shape[0], q_ref.shape[2]), jnp.float32)
 
-    def body(j, dq):
-        k = k_ref[0, pl.ds(j * block_k, block_k), :].astype(jnp.float32)
-        v = v_ref[0, pl.ds(j * block_k, block_k), :].astype(jnp.float32)
-        s, valid = _masked_scores(q, k, mask_ref, qi, j, block_q=block_q,
-                                  block_k=block_k, sq=sq, sk=sk,
-                                  causal=causal, mask_mode=mask_mode)
+    def tile(j, dq, masked):
+        cols = pl.ds(j * block_k, block_k)
+        k = k_ref[0, cols, :].astype(q.dtype)
+        v = v_ref[0, cols, :].astype(do.dtype)
+        s = _dot_nt(q, k)
+        if mask_mode in ("key", "full"):
+            s = s + mask_ref[0, :, cols].astype(jnp.float32)
+        if masked:
+            valid = _valid(s.shape, qi * block_q, j * block_k, 0, **geom)
+            s = jnp.where(valid, s, _NEG_INF)
         # p = exp(s − m)/l: same rounding as the forward recurrence even
-        # for ~1e9-scale masked scores (see module docstring)
-        p = jnp.where(valid, jnp.exp(s - mrow) * linv, 0.0)
-        dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
+        # for ~1e9-scale masked scores (see module docstring); 0 at
+        # _NEG_INF, whose row's m is finite
+        p = jnp.exp(s - mrow) * linv
+        dp = _dot_nt(do, v)
         if dropout_p > 0.0:
             if drop_mode == "prng":
                 keep = _dropout_keep(seed_ref, bh, qi, j, p.shape,
                                      threshold)
             else:
-                keep = keep_ref[0, :, pl.ds(j * block_k, block_k)] > 0.5
+                keep = keep_ref[0, :, cols] > 0.5
             dp = jnp.where(keep, dp / (1.0 - dropout_p), 0.0)
         ds = p * (dp - delta)
-        return dq + jnp.dot(ds, k, preferred_element_type=jnp.float32)
+        return dq + _dot(ds.astype(k.dtype), k)
 
-    nk = pl.cdiv(sk, block_k)
-    nk_needed = nk if not causal else jnp.minimum(
-        nk, pl.cdiv((qi + 1) * block_q, block_k))
-    dq = jax.lax.fori_loop(0, nk_needed, body, dq0)
+    n_plain, n_needed = _k_tile_ranges(jnp, qi, **geom)
+    dq = _walk(0, n_plain, functools.partial(tile, masked=False), dq0)
+    dq = _walk_crossed(n_plain, n_needed,
+                       functools.partial(tile, masked=True), dq,
+                       _crossed_tiles(block_q, block_k, **geom))
     dq_ref[0] = (dq * scale).astype(dq_ref.dtype)
 
 
@@ -229,34 +366,36 @@ def _bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, mask_ref, keep_ref,
     # (1, BQ) row statistics broadcast down its sublanes as they are read
     # (no relayout), and p^T, ds^T are already the left operands of
     # dv = p^T dO and dk = ds^T Q (no transpose of a score tile).
+    geom = dict(block_q=block_q, block_k=block_k, sq=sq, sk=sk,
+                causal=causal)
     bh = pl.program_id(0)
     j = pl.program_id(1)
-    k = k_ref[0].astype(jnp.float32)
-    v = v_ref[0].astype(jnp.float32)
+    k = k_ref[0].astype(_operand_dtype(q_ref, k_ref))
+    v = v_ref[0].astype(_operand_dtype(v_ref, do_ref))
     if mask_mode == "key":      # (1, BK) key bias -> (BK, 1), once
         kbias = _stat_col(mask_ref[0].astype(jnp.float32))
 
-    def body(qi, carry):
+    def tile(qi, carry, masked):
         dk, dv = carry
         rows = pl.ds(qi * block_q, block_q)
-        q = q_ref[0, rows, :].astype(jnp.float32) * scale
-        do = do_ref[0, rows, :].astype(jnp.float32)
+        # the forward's rounding of the scaled q, so that s^T is its s
+        # (k scaled once a program, or the float32 tile scaled, is no
+        # faster and another rounding: PERF.md section 6, PR 32)
+        q = (q_ref[0, rows, :].astype(jnp.float32) * scale).astype(k.dtype)
+        do = do_ref[0, rows, :].astype(v.dtype)
         mrow = m_ref[0, :, rows]            # (1, BQ)
         linv = linv_ref[0, :, rows]
         delta = delta_ref[0, :, rows]
-        st = jnp.dot(k, q.T, preferred_element_type=jnp.float32)
+        st = _dot_nt(k, q)
         if mask_mode == "key":
             st = st + kbias
         elif mask_mode == "full":
             st = st + mask_ref[0, rows, :].astype(jnp.float32).T
-        k_pos = j * block_k + jax.lax.broadcasted_iota(jnp.int32, st.shape, 0)
-        q_pos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, st.shape, 1)
-        valid = (q_pos < sq) & (k_pos < sk)
-        if causal:
-            valid = valid & (q_pos >= k_pos)
-        pt = jnp.where(valid, jnp.exp(jnp.where(valid, st, _NEG_INF) - mrow)
-                       * linv, 0.0)
-        dpt = jnp.dot(v, do.T, preferred_element_type=jnp.float32)
+        if masked:
+            valid = _valid(st.shape, qi * block_q, j * block_k, 1, **geom)
+            st = jnp.where(valid, st, _NEG_INF)
+        pt = jnp.exp(st - mrow) * linv
+        dpt = _dot_nt(v, do)
         pdt = pt
         if dropout_p > 0.0:
             # the forward's (BQ, BK) keep tile, transposed
@@ -269,16 +408,23 @@ def _bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, mask_ref, keep_ref,
             keep = keep.T > 0.5
             pdt = jnp.where(keep, pt / (1.0 - dropout_p), 0.0)
             dpt = jnp.where(keep, dpt / (1.0 - dropout_p), 0.0)
-        dv = dv + jnp.dot(pdt, do, preferred_element_type=jnp.float32)
+        dv = dv + _dot(pdt.astype(do.dtype), do)
         dst = pt * (dpt - delta)
         # q above is pre-scaled, so ds^T @ (q·scale) is already dk
-        dk = dk + jnp.dot(dst, q, preferred_element_type=jnp.float32)
+        dk = dk + _dot(dst.astype(q.dtype), q)
         return dk, dv
 
-    nq = pl.cdiv(sq, block_q)
-    q_start = 0 if not causal else (j * block_k) // block_q
-    dk, dv = jax.lax.fori_loop(q_start, nq, body,
-                               (jnp.zeros_like(k), jnp.zeros_like(v)))
+    q_start, plain_lo, plain_hi = _q_tile_ranges(jnp, j, **geom)
+    plain, crossed = (functools.partial(tile, masked=m)
+                      for m in (False, True))
+    carry = (jnp.zeros(k.shape, jnp.float32), jnp.zeros(v.shape, jnp.float32))
+    carry = _walk_crossed(q_start, plain_lo, crossed, carry,
+                          _crossed_tiles(block_k, block_q, **geom))
+    carry = _walk(plain_lo, plain_hi, plain, carry)
+    if sq % block_q:        # the one q-block with rows past sq
+        carry = _walk(jnp.maximum(plain_hi, q_start), plain_hi + 1, crossed,
+                      carry)
+    dk, dv = carry
     dk_ref[0] = dk.astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
@@ -328,6 +474,21 @@ def _mask_operand(mask, mode, h, sq_pad, sk_pad):
     return m3, bh_to_g
 
 
+def _clamped_blocks(block_q, block_k, sq, sk):
+    """The blocks a call runs with: no larger than its lengths."""
+    return min(block_q, max(sq, 8)), min(block_k, sk)
+
+
+def _whole_side(shape, single):
+    """BlockSpec of an operand a program keeps whole: one (batch, head)'s
+    other side, which changes only with the grid's first axis. ``single``:
+    one buffer in VMEM and no prefetch of the next head's while this one's
+    last block runs (``_single_buffered``)."""
+    mode = {"pipeline_mode": pl.Buffered(1)} if single else {}
+    return pl.BlockSpec(shape, lambda i, j: (i, 0, 0),
+                        memory_space=pltpu.VMEM, **mode)
+
+
 def _pad_axis(x, axis, new):
     if x.shape[axis] == new:
         return x
@@ -341,8 +502,8 @@ def _flash_fwd_res(q, k, v, mask, mask_mode, seed, causal, scale, block_q,
     from . import interpret_mode
     b, h, sq, d = q.shape
     sk, dvh = k.shape[2], v.shape[3]
-    bq = min(block_q, max(sq, 8))
-    bk = min(block_k, sk)
+    bq, bk = _clamped_blocks(block_q, block_k, sq, sk)
+    single = _single_buffered(max(sq, sk), d, dvh, q.dtype.itemsize)
     # pad K/V up to a block multiple: a manual pl.ds read past the end
     # CLAMPS its start (dynamic-slice semantics) and would silently re-read
     # earlier rows; the kernels mask positions >= the true sk
@@ -393,10 +554,8 @@ def _flash_fwd_res(q, k, v, mask, mask_mode, seed, causal, scale, block_q,
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, bq, d), lambda i, j: (i, j, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, sk_pad, d), lambda i, j: (i, 0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, sk_pad, dvh), lambda i, j: (i, 0, 0),
-                         memory_space=pltpu.VMEM),
+            _whole_side((1, sk_pad, d), single),
+            _whole_side((1, sk_pad, dvh), single),
             mspec,
             kspec,
         ],
@@ -422,8 +581,8 @@ def _flash_bwd(q, k, v, mask, mask_mode, seed, out, mrow, lrow, g, causal,
     from . import interpret_mode
     b, h, sq, d = q.shape
     sk, dvh = k.shape[2], v.shape[3]
-    bq = min(block_q, max(sq, 8))
-    bk = min(block_k, sk)
+    bq, bk = _clamped_blocks(block_q, block_k, sq, sk)
+    single = _single_buffered(max(sq, sk), d, dvh, q.dtype.itemsize)
     sk_pad = -(-sk // bk) * bk
     sq_pad = -(-sq // bq) * bq
     s = scale if scale is not None else 1.0 / np.sqrt(d)
@@ -442,8 +601,7 @@ def _flash_bwd(q, k, v, mask, mask_mode, seed, out, mrow, lrow, g, causal,
     stats = [_pad_axis(x, 2, sq_pad) for x in (mrow, linv, delta)]
     stat_q = pl.BlockSpec((1, 1, bq), lambda i, j: (i, 0, j),
                           memory_space=pltpu.VMEM)
-    stat_all = pl.BlockSpec((1, 1, sq_pad), lambda i, j: (i, 0, 0),
-                            memory_space=pltpu.VMEM)
+    stat_all = _whole_side((1, 1, sq_pad), single)
 
     if mask_mode in ("key", "full"):
         m3, bh_to_g = _mask_operand(mask, mask_mode, h, sq_pad, sk_pad)
@@ -484,10 +642,8 @@ def _flash_bwd(q, k, v, mask, mask_mode, seed, out, mrow, lrow, g, causal,
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, bq, d), lambda i, j: (i, j, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, sk_pad, d), lambda i, j: (i, 0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, sk_pad, dvh), lambda i, j: (i, 0, 0),
-                         memory_space=pltpu.VMEM),
+            _whole_side((1, sk_pad, d), single),
+            _whole_side((1, sk_pad, dvh), single),
             mspec_q,
             kspec_q,
             stat_q,
@@ -515,8 +671,7 @@ def _flash_bwd(q, k, v, mask, mask_mode, seed, out, mrow, lrow, g, causal,
         grid=(b * h, pl.cdiv(sk, bk)),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, sq_pad, d), lambda i, j: (i, 0, 0),
-                         memory_space=pltpu.VMEM),
+            _whole_side((1, sq_pad, d), single),
             pl.BlockSpec((1, bk, d), lambda i, j: (i, j, 0),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((1, bk, dvh), lambda i, j: (i, j, 0),
@@ -527,8 +682,7 @@ def _flash_bwd(q, k, v, mask, mask_mode, seed, out, mrow, lrow, g, causal,
             stat_all,
             stat_all,
             stat_all,
-            pl.BlockSpec((1, sq_pad, dvh), lambda i, j: (i, 0, 0),
-                         memory_space=pltpu.VMEM),
+            _whole_side((1, sq_pad, dvh), single),
         ],
         out_specs=[
             pl.BlockSpec((1, bk, d), lambda i, j: (i, j, 0),
@@ -577,35 +731,51 @@ def _bwd(mask_mode, causal, scale, block_q, block_k, dropout_p, res, g):
 _flash.defvjp(_fwd, _bwd)
 
 
-# Each kernel keeps the whole other side of one (batch, head) in VMEM,
-# double-buffered (the forward and dQ kernels K and V, the dK/dV kernel Q
-# and dO), beside four float32 (BQ, BK) score tiles, in the 16 MiB a kernel
-# may use. Compiled for a v5e at 8,192 positions in bfloat16:
-# * head size 128 for q/k and v (4 MiB a side): with BK = 1024 the dK/dV
-#   kernel asks for 18.4 MiB; with BK = 512 it fits. Up to 4,096 x 128 (and
-#   at every BERT shape) the blocks stay as asked.
+# Each kernel keeps the whole other side of one (batch, head) in VMEM (the
+# forward and dQ kernels K and V, the dK/dV kernel Q, dO and three rows of
+# statistics), beside its own blocks and a few float32 (BQ, BK) score
+# tiles, in the 16 MiB a kernel may use. What a tile costs beyond its
+# products is paid once an inner iteration (the fill and drain of the MXU
+# and of the cross-lane reductions; about ten operations on (BQ, 1)
+# columns), so the largest tiles that fit are the fastest: alone on a v5e,
+# 32 heads x 8,192 causal, forward + (forward + backward) ms at 192 / 128:
+# 256 x 256 47.1, 256 x 512 37.3, 512 x 512 35.1; at 128 / 128: 256 x 256
+# 36.0, 256 x 512 24.3, 512 x 512 22.4 (PERF.md section 7, row 29).
+# Compiled for a v5e at 8,192 positions in bfloat16:
+# * up to 4,096 positions at these head sizes (under 4 MiB a side) the
+#   blocks stay as asked (512 x 1,024), the whole side double-buffered.
+# * head size 128 for q/k and v (4 MiB a side): with BK = 1,024 the dK/dV
+#   kernel asks for 18.4 MiB; with BK = 512 it fits, double-buffered.
 # * head size 192 for q/k, 128 for v (multi-head latent attention; 5 MiB a
 #   side as counted here, 6 in VMEM, where 192 lanes take two tiles of
-#   128): with BQ = 512 the forward asks for 16.04 MiB at BK = 512; BQ =
-#   256, BK = 512 fits all three kernels compiled alone and is the fastest
-#   pair on the chip (8.2 ms forward, 30.8 forward + backward, 32 heads),
-#   but inside the joyai_llm_flash step the dK/dV kernel then asks for
-#   16.15 MiB; 256 x 256 (11.6, 34.9) and 128 x 512 (9.9, 43.6) fit there
-#   (PERF.md section 6, PR 31). Up to 4,096 positions the blocks stay as
-#   asked.
+#   128): double-buffered, nothing above 256 x 256 fits inside the
+#   joyai_llm_flash step (256 x 512: the dK/dV kernel asks 16.02 MiB).
+#   The whole side changes once in 16 to 32 programs, so it is held in ONE
+#   buffer there: the next head's 5 MiB are fetched when the last block of
+#   this one is done (0.3 ms a forward call of 7.2, measured), and 512 x
+#   512 fits all three kernels, alone and inside the step. At 4 MiB and
+#   under one buffer only costs (23.5 -> 24.2 ms at 128 / 128; +12 % on
+#   the backward at BERT's seq 512, where every program has a new side).
 _WHOLE_SIDE_BYTES = 4 * 1024 * 1024
+
+
+def _side_bytes(seq, d, dv, itemsize):
+    return seq * (d + dv) * itemsize
 
 
 def _blocks_that_fit(seq, d, dv, itemsize, block_q, block_k):
     """(block_q, block_k) as asked, or as large as scoped VMEM holds beside
     the whole other side of ``seq`` positions at head sizes ``d`` (q, k)
     and ``dv`` (v, o)."""
-    side = seq * (d + dv) * itemsize
-    if side >= _WHOLE_SIDE_BYTES:
+    if _side_bytes(seq, d, dv, itemsize) >= _WHOLE_SIDE_BYTES:
         block_k = min(block_k, 512)
-    if side > _WHOLE_SIDE_BYTES:
-        block_q, block_k = min(block_q, 256), min(block_k, 256)
     return block_q, block_k
+
+
+def _single_buffered(seq, d, dv, itemsize):
+    """Whether the kernels hold the whole other side in one VMEM buffer:
+    where two of them would leave no room for 512 x 512 tiles."""
+    return _side_bytes(seq, d, dv, itemsize) > _WHOLE_SIDE_BYTES
 
 
 def flash_attention(q, k, v, attn_mask=None, causal=False, scale=None,
@@ -623,7 +793,11 @@ def flash_attention(q, k, v, attn_mask=None, causal=False, scale=None,
     interpret-mode kernel is emulator-speed) unless force=True (kernel
     correctness tests). ``monitor`` counters ``flash_attention.
     kernel_traced`` / ``flash_attention.xla_traced`` count the call sites
-    that traced each path."""
+    that traced each path; per kernel call site ``flash_attention.tiles``
+    and ``flash_attention.tiles_masked`` add the forward kernel's score
+    tiles and those of them that run the masked body, and
+    ``flash_attention.native_operands_traced`` counts the call sites whose
+    products take bfloat16 operands."""
     from ...dispatch import apply
     from ... import monitor
     from ... import random as prandom
@@ -644,6 +818,16 @@ def flash_attention(q, k, v, attn_mask=None, causal=False, scale=None,
         from ..nn_ops import scaled_dot_product_attention as sdpa
         return sdpa(q, k, v, attn_mask=attn_mask, is_causal=causal,
                     scale=scale, dropout_p=p_drop, training=training)
+    # how often the kernels' two mechanisms engage, per call site: the
+    # forward kernel's score tiles and those of them that run the masked
+    # body; call sites whose products take bfloat16 operands
+    bq, bk = _clamped_blocks(block_q, block_k, sq, sk)
+    tiles, masked = _tile_counts(b * h, block_q=bq, block_k=bk, sq=sq, sk=sk,
+                                 causal=causal)
+    monitor.counter("flash_attention.tiles").inc(tiles)
+    monitor.counter("flash_attention.tiles_masked").inc(masked)
+    if q.dtype == k.dtype == v.dtype == jnp.bfloat16:
+        monitor.counter("flash_attention.native_operands_traced").inc()
 
     def impl(q, k, v, *rest):
         m = _canon_mask(rest[0]) if has_mask else None

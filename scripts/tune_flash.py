@@ -1,10 +1,25 @@
-"""Flash-attention block-size sweep on the real chip: block_q x block_k
-over BERT-base-shaped attention at seq 128 / 512 / 2048, fwd+bwd.
-Prints one line per config; the best (block_q, block_k) per seq length
-feeds flash_attention's defaults (and the flash_min_seq crossover comes
-from comparing against the sdpa row). Run:
-    python -u scripts/tune_flash.py
+"""Flash-attention block sweep on the chip, at the geometries of the three
+benchmark cells that run the kernels (q/k and v head sizes apart):
+
+    joyai     1 x 32 x 8192 x 192 | 128, causal
+    nemotron  1 x 32 x 8192 x 128 | 128, causal
+    seq512    16 x 12 x 512 x 64 | 64, key bias, no causal mask
+
+For each (block_q, block_k): forward ms and forward + backward ms (host
+clock around ``block_until_ready``, the kernels alone: no projections), the
+forward's tiles and how many of them run the masked body. With
+``--parent DIR`` (a checkout of the parent commit, e.g. ``git archive`` into
+the git-ignored ``_archive_check/``) every row is timed on the parent's
+kernels too, in the same process, and at the blocks the rule gives the
+parent's and the change's o, dq, dk, dv are compared bit for bit.
+
+    chiprun -- python -u scripts/tune_flash.py --parent _archive_check/parent
+
+The table goes into PERF.md section 7, row 29.
 """
+import argparse
+import importlib
+import importlib.util
 import os
 import sys
 import time
@@ -14,58 +29,141 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 
 import numpy as np
 
+GEOMETRIES = {
+    # name: (batch, heads, seq, d, dv, causal, key_bias, block pairs)
+    "joyai": (1, 32, 8192, 192, 128, True, False,
+              [(256, 256), (128, 512), (256, 512), (512, 512), (256, 1024),
+               (512, 1024)]),
+    "nemotron": (1, 32, 8192, 128, 128, True, False,
+                 [(256, 256), (256, 512), (512, 512), (512, 1024),
+                  (1024, 512), (1024, 1024)]),
+    "seq512": (16, 12, 512, 64, 64, False, True,
+               [(512, 512), (256, 512), (512, 256), (256, 256)]),
+}
 
-def bench_attention(seq, block_q, block_k, use_flash, batch=8, heads=12,
-                    head_dim=64, steps=10):
+
+def load_kernels(parent):
+    """The flash module of this checkout, or of the checkout at ``parent``
+    loaded beside it (its relative imports resolve in this package)."""
+    name = "paddle_tpu.ops.pallas.flash_attention"
+    if parent is None:
+        return importlib.import_module(name)
+    path = os.path.join(parent, "paddle_tpu", "ops", "pallas",
+                        "flash_attention.py")
+    spec = importlib.util.spec_from_file_location(name + "_parent", path)
+    module = importlib.util.module_from_spec(spec)
+    module.__package__ = "paddle_tpu.ops.pallas"
+    spec.loader.exec_module(module)
+    return module
+
+
+def make_inputs(geometry, seed=0):
+    import jax.numpy as jnp
+    b, h, s, d, dv, causal, key_bias, _ = geometry
+    rng = np.random.RandomState(seed)
+    q, k = (jnp.asarray(rng.randn(b, h, s, d), jnp.bfloat16)
+            for _ in range(2))
+    v, ct = (jnp.asarray(rng.randn(b, h, s, dv), jnp.bfloat16)
+             for _ in range(2))
+    bias = None
+    if key_bias:
+        bias = jnp.asarray(np.where(rng.rand(b, 1, 1, s) < 0.1, -1e9, 0.0),
+                           jnp.float32)
+    return q, k, v, ct, bias
+
+
+def functions(module, geometry, bias, block_q, block_k):
+    """(forward, forward + backward) of ``module``'s kernels, jitted."""
     import jax
     import jax.numpy as jnp
-    from paddle_tpu.ops.pallas.flash_attention import _flash
-
-    rng = np.random.RandomState(0)
-    q = jnp.asarray(rng.randn(batch, heads, seq, head_dim),
-                    jnp.bfloat16)
+    b, h, s, d, dv, causal, key_bias, _ = geometry
+    mode = "key" if key_bias else None
     seed = jnp.zeros((2,), jnp.int32)
 
-    if use_flash:
-        def f(q):
-            out = _flash(q, q, q, None, None, seed, False, None,
-                         block_q, block_k, 0.0)
-            return out.astype(jnp.float32).sum()
-    else:
-        def f(q):
-            s = jnp.einsum("bhqd,bhkd->bhqk", q, q) / np.sqrt(head_dim)
-            p = jax.nn.softmax(s.astype(jnp.float32), axis=-1)
-            out = jnp.einsum("bhqk,bhkd->bhqd", p.astype(q.dtype), q)
-            return out.astype(jnp.float32).sum()
+    def fwd(q, k, v):
+        return module._flash(q, k, v, bias, mode, seed, causal, None,
+                             block_q, block_k, 0.0)
 
-    g = jax.jit(jax.grad(f))
-    g(q).block_until_ready()
+    def both(q, k, v, ct):
+        out, vjp = jax.vjp(fwd, q, k, v)
+        return (out,) + vjp(ct)
+
+    return jax.jit(fwd), jax.jit(both)
+
+
+def timed(fn, args, steps):
+    import jax
+    jax.block_until_ready(fn(*args))
+    jax.block_until_ready(fn(*args))
     t0 = time.perf_counter()
     for _ in range(steps):
-        out = g(q)
-    out.block_until_ready()
-    dt = (time.perf_counter() - t0) / steps
-    # attention fwd+bwd ~ 4x the 2*B*H*S^2*D fwd matmul FLOPs
-    flops = 4 * 2 * batch * heads * seq * seq * head_dim
-    return dt * 1e3, flops / dt / 1e12
+        out = fn(*args)
+    jax.block_until_ready(out)
+    return (time.perf_counter() - t0) / steps * 1e3
+
+
+def ulps(a, b):
+    """(share of elements that differ, the largest difference in units in
+    the last bfloat16 place of the larger of the two elements, the largest
+    difference over the largest element of ``a``)."""
+    a, b = (np.asarray(x, np.float32) for x in (a, b))
+    differ = a != b
+    if not differ.any():
+        return 0.0, 0.0, 0.0
+    size = np.maximum(np.abs(a), np.abs(b))[differ]
+    spacing = 2.0 ** (np.floor(np.log2(size)) - 7)
+    gap = np.abs(a - b)[differ]
+    return (float(differ.mean()), float((gap / spacing).max()),
+            float(gap.max() / np.abs(a).max()))
 
 
 def main():
-    for seq in (128, 512, 2048):
-        ms, tf = bench_attention(seq, 0, 0, use_flash=False)
-        print(f"seq={seq:5d} sdpa:              {ms:8.2f} ms  "
-              f"{tf:6.2f} TF/s", flush=True)
-        for bq in (256, 512, 1024):
-            for bk in (256, 512, 1024):
-                if bq > seq * 2 or bk > seq * 2:
-                    continue
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--parent", default=None)
+    ap.add_argument("--cells", default="joyai,nemotron,seq512")
+    ap.add_argument("--steps", type=int, default=20)
+    args = ap.parse_args()
+
+    import jax
+    print("device", jax.devices()[0].device_kind, flush=True)
+    change = load_kernels(None)
+    sides = [("change", change)]
+    if args.parent:
+        sides.insert(0, ("parent", load_kernels(args.parent)))
+
+    for cell in args.cells.split(","):
+        geometry = GEOMETRIES[cell]
+        b, h, s, d, dv, causal, key_bias, pairs = geometry
+        q, k, v, ct, bias = make_inputs(geometry)
+        rule = {name: m._blocks_that_fit(s, d, dv, 2, 512, 1024)
+                for name, m in sides}
+        print(f"{cell}: {b} x {h} x {s} x {d} | {dv} "
+              f"causal={causal} key_bias={key_bias} rule={rule}", flush=True)
+        for bq, bk in pairs:
+            bq_, bk_ = change._clamped_blocks(bq, bk, s, s)
+            tiles, masked = change._tile_counts(
+                b * h, block_q=bq_, block_k=bk_, sq=s, sk=s, causal=causal)
+            row = f"  {bq:4d} x {bk:4d}  tiles {tiles:6d} masked {masked:5d}"
+            for name, module in sides:
                 try:
-                    ms, tf = bench_attention(seq, bq, bk, use_flash=True)
-                    print(f"seq={seq:5d} flash bq={bq:4d} bk={bk:4d}: "
-                          f"{ms:8.2f} ms  {tf:6.2f} TF/s", flush=True)
-                except Exception as e:
-                    print(f"seq={seq:5d} flash bq={bq:4d} bk={bk:4d}: "
-                          f"FAIL {type(e).__name__}: {e}", flush=True)
+                    f, fb = functions(module, geometry, bias, bq, bk)
+                    ms = timed(f, (q, k, v), args.steps)
+                    ms_both = timed(fb, (q, k, v, ct), args.steps)
+                    row += f"  {name} fwd {ms:7.3f} fwd+bwd {ms_both:7.3f}"
+                except Exception as e:     # noqa: BLE001 - VMEM, mostly
+                    row += f"  {name} FAIL {type(e).__name__}: " \
+                           f"{str(e).splitlines()[0][:120]}"
+            print(row, flush=True)
+        if args.parent:
+            # the same inputs through both sides, each at its rule's blocks
+            outs = {name: functions(m, geometry, bias, *rule[name])[1](
+                q, k, v, ct) for name, m in sides}
+            for label, a, c in zip(("o", "dq", "dk", "dv"), outs["parent"],
+                                   outs["change"]):
+                share, worst, rel = ulps(a, c)
+                print(f"  parent vs change {label}: {share:.6f} of elements "
+                      f"differ, at most {worst:.2f} bf16 ulp of the element, "
+                      f"{rel:.2e} of the largest element", flush=True)
 
 
 if __name__ == "__main__":

@@ -222,16 +222,19 @@ def test_flash_long_causal_at_head_size_128(one_chip, compiled_kernels, seq):
 # 8,192 x (192 | 128) causal, 32 heads: the joyai_llm_flash cell's latent
 # attention — q and k 128 position-free + 64 rotary wide, v and the output
 # 128. 192 lanes are a block's whole last dimension (one and a half lane
-# tiles, two in VMEM); v is not padded to 192 anywhere. The blocks that fit
-# scoped VMEM beside 6 MiB of whole side are the rule's
+# tiles, two in VMEM); v is not padded to 192 anywhere. Two buffers of 6 MiB
+# of whole side leave room for 256 x 256 tiles; the kernels hold it in one
+# buffer there (PR 32) and the rule's 512 x 512 fit
 @pytest.mark.parametrize("seq", [8192, 4096])
 def test_flash_long_causal_at_head_sizes_192_and_128(one_chip,
                                                      compiled_kernels, seq):
     from paddle_tpu.ops.pallas.flash_attention import (_blocks_that_fit,
-                                                       _flash)
+                                                       _flash,
+                                                       _single_buffered)
     block_q, block_k = _blocks_that_fit(seq, 192, 128, 2, 512, 1024)
-    assert (block_q, block_k) == ((256, 256) if seq == 8192
-                                  else (512, 1024))
+    assert (block_q, block_k) == (512, 512 if seq == 8192 else 1024)
+    assert _single_buffered(seq, 192, 128, 2) == (seq == 8192)
+    assert not _single_buffered(8192, 128, 128, 2)
 
     def f(q, k, v, seed):
         return _flash(q, k, v, None, None, seed, True, None, block_q,
@@ -253,6 +256,51 @@ def test_flash_long_causal_at_head_sizes_192_and_128(one_chip,
         tuple(_shapes(head, "bf16").count((32, seq, w)) for w in (192, 128))
         for head in calls)
     assert widths == [(2, 2), (3, 2), (3, 3)], widths
+
+
+def _kernel_eqns(jaxpr):
+    for e in jaxpr.eqns:
+        yield e
+        for v in e.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else (v,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _kernel_eqns(sub)
+
+
+def test_flash_kernels_widen_no_tile_of_k_or_v(compiled_kernels):
+    """What Mosaic is handed at the joyai cell's call (8,192 x 192 | 128,
+    512 x 512 tiles): every product takes bfloat16 operands with a float32
+    result, and the only tile extended to float32 is q's (512, 192), to be
+    scaled and rounded back — once a q-block in the forward and dQ kernels,
+    once a tile body in the dK/dV kernel (its loop's and the crossed
+    tile's). No float32 copy of a K, V or dO tile is made; the MXU would
+    round it back."""
+    from paddle_tpu.ops.pallas.flash_attention import _flash
+
+    def f(q, k, v, seed):
+        return _flash(q, k, v, None, None, seed, True, None, 512, 512, 0.0)
+
+    qk = jax.ShapeDtypeStruct((1, 32, 8192, 192), jnp.bfloat16)
+    v = jax.ShapeDtypeStruct((1, 32, 8192, 128), jnp.bfloat16)
+    outer = jax.make_jaxpr(_grad_sum(f, argnums=(0, 1, 2)))(
+        qk, qk, v, jax.ShapeDtypeStruct((2,), jnp.int32))
+    kernels = {e.params["name"]: e.params["jaxpr"]
+               for e in _kernel_eqns(outer.jaxpr)
+               if e.primitive.name == "pallas_call"}
+    assert sorted(kernels) == ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
+    for name, kernel in kernels.items():
+        eqns = list(_kernel_eqns(kernel))
+        for e in eqns:
+            if e.primitive.name == "dot_general":
+                assert [x.aval.dtype for x in e.invars] == [jnp.bfloat16] * 2
+                assert e.outvars[0].aval.dtype == jnp.float32
+        widened = [e.outvars[0].aval.shape for e in eqns
+                   if e.primitive.name == "convert_element_type"
+                   and e.invars[0].aval.dtype == jnp.bfloat16
+                   and e.outvars[0].aval.dtype == jnp.float32]
+        assert widened == [(512, 192)] * (2 if name == "flash_bwd_dkv"
+                                          else 1), (name, widened)
 
 
 # -- the nemotron cell's routed experts: plain XLA, chosen on the device ----

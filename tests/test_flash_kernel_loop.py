@@ -1,0 +1,340 @@
+"""The flash kernels' inner loops (ops/pallas/flash_attention.py, PR 32):
+loaded tiles reach the MXU in the dtype they were read in; only the score
+tiles the diagonal or a true length crosses run the masked body, as
+straight-line code behind the plain loop where the shapes say how many
+they are; a whole side too large to hold twice is held in one buffer.
+Read off the kernels' jaxprs, checked against float32 attention in
+interpret mode, and counted at the dispatch. CPU only: counts and values,
+no time."""
+import itertools
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+import paddle_tpu as pt
+from paddle_tpu import monitor
+from paddle_tpu.ops.pallas import flash_attention
+from paddle_tpu.ops.pallas.flash_attention import (_blocks_that_fit,
+                                                   _canon_mask,
+                                                   _crossed_tiles, _flash,
+                                                   _host_keep_mask,
+                                                   _mask_mode,
+                                                   _single_buffered,
+                                                   _tile_counts)
+
+KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
+B, H, BQ, BK, D, DV = 1, 2, 16, 32, 24, 16
+
+
+def _eqns(jaxpr):
+    """Every equation of ``jaxpr``, those of nested jaxprs included."""
+    for e in jaxpr.eqns:
+        yield e
+        for v in e.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else (v,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _eqns(sub)
+
+
+def _kernel_jaxprs(dtype, causal=True, seq=64, mask_shape=None):
+    """name -> jaxpr of each of the three kernels at (1, 2, seq, 24 | 16),
+    16 x 32 blocks."""
+    mode = _mask_mode(mask_shape, B, H, seq, seq)
+
+    def loss(q, k, v, *mask):
+        m = _canon_mask(mask[0]) if mask else None
+        return _flash(q, k, v, m, mode, jnp.zeros((2,), jnp.int32), causal,
+                      None, BQ, BK, 0.0).astype(jnp.float32).sum()
+
+    qk = jnp.zeros((B, H, seq, D), dtype)
+    v = jnp.zeros((B, H, seq, DV), dtype)
+    mask = () if mask_shape is None else (jnp.zeros(mask_shape, jnp.float32),)
+    outer = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(qk, qk, v,
+                                                              *mask)
+    found = {e.params["name"]: e.params["jaxpr"]
+             for e in _eqns(outer.jaxpr) if e.primitive.name == "pallas_call"}
+    assert sorted(found) == sorted(KERNELS)
+    return found
+
+
+def _loops(kernel):
+    """The bodies of a kernel's tile loops (``fori_loop`` is a ``while``
+    under traced bounds and a ``scan`` under static ones)."""
+    return [e.params["body_jaxpr" if e.primitive.name == "while"
+                     else "jaxpr"].jaxpr for e in kernel.eqns
+            if e.primitive.name in ("while", "scan")]
+
+
+def _names(jaxpr):
+    return [e.primitive.name for e in _eqns(jaxpr)]
+
+
+# -- (a) what the products take ---------------------------------------------
+
+# tile bodies a causal 64 x 64 call at 16 x 32 holds: the plain loop's, and
+# the crossed tiles behind it (one a q-block; two q-blocks a k-block)
+BODIES = {"flash_fwd": 2, "flash_bwd_dq": 2, "flash_bwd_dkv": 3}
+# forward: q k^T, p v; dQ: q k^T, dO v^T, ds k; dK/dV: k q^T, v dO^T,
+# p^T dO, ds^T q
+PRODUCTS = {"flash_fwd": 2, "flash_bwd_dq": 3, "flash_bwd_dkv": 4}
+
+
+@pytest.mark.parametrize("name", KERNELS)
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+def test_products_take_their_operands_as_they_were_read(dtype, name):
+    kernel = _kernel_jaxprs(dtype)[name]
+    dots = [e for e in _eqns(kernel) if e.primitive.name == "dot_general"]
+    assert len(dots) == BODIES[name] * PRODUCTS[name]
+    for e in dots:
+        assert [v.aval.dtype for v in e.invars] == [dtype, dtype], e
+        assert e.outvars[0].aval.dtype == jnp.float32
+        assert e.params["preferred_element_type"] == jnp.float32
+    widened = [e.outvars[0].aval.shape for e in _eqns(kernel)
+               if e.primitive.name == "convert_element_type"
+               and e.invars[0].aval.dtype == jnp.bfloat16
+               and e.outvars[0].aval.dtype == jnp.float32]
+    # bfloat16 in: no K or V tile is widened anywhere; q is, to be scaled in
+    # float32 and rounded back: once a q-block in the forward and dQ
+    # kernels, once a tile in the dK/dV kernel, which walks q-blocks
+    assert widened == ([] if dtype == jnp.float32 else [(BQ, D)] * (
+        BODIES[name] if name == "flash_bwd_dkv" else 1)), widened
+    (body,) = _loops(kernel)
+    # no copy of a tile is transposed: the products contract over the
+    # operands' own last dimensions
+    assert "transpose" not in _names(body)
+    # statistics and accumulators are carried in float32
+    carried = {v.aval.dtype for v in body.outvars if v.aval.shape}
+    assert carried == {jnp.dtype(jnp.float32)}, carried
+    # what a kernel makes itself (p, ds, p^T, ds^T) is rounded to the
+    # inputs' dtype in front of its product
+    narrowed = [e for e in _eqns(body)
+                if e.primitive.name == "convert_element_type"
+                and e.outvars[0].aval.dtype == jnp.bfloat16
+                and e.invars[0].aval.shape in ((BQ, BK), (BK, BQ))]
+    assert len(narrowed) == (0 if dtype == jnp.float32 else
+                             2 if name == "flash_bwd_dkv" else 1)
+
+
+# -- (b) a plain loop, and the crossed tiles behind it -----------------------
+
+_POSITIONS = {"iota", "select_n", "ge", "lt", "and"}
+
+
+@pytest.mark.parametrize("name", KERNELS)
+def test_a_causal_kernel_masks_its_crossed_tiles_outside_the_loop(name):
+    """A square causal call of whole blocks: how many tiles the diagonal
+    crosses in a program is a fact of the shapes, so they are straight-line
+    code and the one loop makes no position."""
+    kernel = _kernel_jaxprs(jnp.bfloat16)[name]
+    (plain,) = _loops(kernel)
+    assert not _POSITIONS & set(_names(plain))
+    outside = [e.primitive.name for e in kernel.eqns]
+    # two iotas a crossed tile
+    assert outside.count("iota") == 2 * (BODIES[name] - 1)
+
+
+@pytest.mark.parametrize("own,other,want", [
+    (512, 512, 1), (256, 512, 1), (512, 256, 2), (1024, 256, 4),
+    (384, 256, None)])
+def test_crossed_tiles_are_counted_from_the_shapes(own, other, want):
+    geom = dict(block_q=own, block_k=other, sq=3072, sk=3072)
+    assert _crossed_tiles(own, other, causal=True, **geom) == want
+    assert _crossed_tiles(own, other, causal=False, **geom) is None
+    for odd in (dict(geom, sq=3000), dict(geom, sk=3000)):
+        assert _crossed_tiles(own, other, causal=True, **odd) is None
+
+
+@pytest.mark.parametrize("name", KERNELS)
+def test_a_true_length_inside_a_block_keeps_the_masked_loop(name):
+    """Causal at 80 = 2.5 k-blocks: the crossed tiles differ from program
+    to program, so they are a loop of their own."""
+    loops = _loops(_kernel_jaxprs(jnp.bfloat16, seq=80)[name])
+    with_positions = ["iota" in _names(body) for body in loops]
+    assert with_positions == ([True, False] if name == "flash_bwd_dkv"
+                              else [False, True])
+    plain = loops[with_positions.index(False)]
+    assert not _POSITIONS & set(_names(plain))
+
+
+@pytest.mark.parametrize("name", KERNELS)
+def test_aligned_lengths_without_a_diagonal_run_the_plain_loop_alone(name):
+    """BERT's seq-512 call: a key bias, no causal mask, whole blocks. The
+    bias is one add; no position is made anywhere in the kernel."""
+    kernel = _kernel_jaxprs(jnp.bfloat16, causal=False,
+                            mask_shape=(B, 1, 1, 64))[name]
+    assert len(_loops(kernel)) == 1
+    assert "iota" not in _names(kernel)
+
+
+@pytest.mark.parametrize("name", KERNELS)
+def test_a_partial_block_is_masked_and_the_whole_ones_are_not(name):
+    """40 = 2.5 q-blocks, no causal mask: the last k-block of every q-block
+    and every tile of the last q-block run the masked body."""
+    loops = _loops(_kernel_jaxprs(jnp.float32, causal=False, seq=40)[name])
+    with_positions = ["iota" in _names(body) for body in loops]
+    # dK/dV: the k-blocks' partial one, the plain tiles, the q tail
+    assert with_positions == ([True, False, True] if name == "flash_bwd_dkv"
+                              else [False, True])
+
+
+# -- (c) values: output and gradients against float32 attention ---------------
+
+def _reference(q, k, v, ct, bias, causal, keep, p_drop):
+    """Plain float32 attention and its gradients; ``keep`` is the
+    dropout's (B*H, Sq, Sk) keep-mask or None."""
+    b, h, sq, d = q.shape
+    sk = k.shape[2]
+
+    def f(q, k, v):
+        s = jnp.einsum("bhqd,bhkd->bhqk", q, k) / np.float32(np.sqrt(d))
+        if bias is not None:
+            s = s + bias
+        if causal:
+            s = jnp.where(np.tril(np.ones((sq, sk), bool)), s, -jnp.inf)
+        p = jax.nn.softmax(s, axis=-1)
+        if keep is not None:
+            p = p * keep.reshape(b, h, sq, sk) / (1.0 - p_drop)
+        return jnp.einsum("bhqk,bhkd->bhqd", p, v)
+
+    out, vjp = jax.vjp(f, q, k, v)
+    return (out,) + vjp(ct)
+
+
+def _bias(kind, seq, rng):
+    if kind == "none":
+        return None
+    if kind == "key":
+        return np.where(rng.rand(B, 1, 1, seq) < 0.3, -1e9, 0.0).astype("f4")
+    m = (rng.randn(1, 1, seq, seq) * 2).astype("f4")
+    if kind == "masked_rows":
+        m[0, 0, 3, :] = -1e9            # a row with every key masked
+        m[0, 0, seq - 1, :seq - 2] = -1e9   # and the last row, nearly
+        m[0, 0, 19, :] = -1e9
+    return m
+
+
+CASES = list(itertools.product(
+    (False, True),                          # causal
+    ("aligned", "2.5 blocks"),
+    ((16, 16), (16, 32)),                   # BQ = BK | BQ < BK
+    ((16, 16), (24, 16)),                   # head sizes d, dv
+    ("none", "key", "full", "masked_rows"),
+    (0.0, 0.1)))                            # dropout
+# BQ > BK: two crossed tiles a q-block in the forward and dQ kernels
+CASES += list(itertools.product((True,), ("aligned", "2.5 blocks"),
+                                ((32, 16),), ((24, 16),), ("none", "key"),
+                                (0.0,)))
+
+
+@pytest.mark.parametrize(
+    "causal,lengths,blocks,heads,mask,p_drop", CASES,
+    ids=["-".join(("causal" if c[0] else "bidir", c[1].replace(" ", ""),
+                   "%dx%d" % c[2], "d%d.%d" % c[3], c[4], "drop%g" % c[5]))
+         for c in CASES])
+def test_bf16_kernels_against_float32_attention(causal, lengths, blocks,
+                                                heads, mask, p_drop):
+    (bq, bk), (d, dv) = blocks, heads
+    seq = 2 * bk if lengths == "aligned" else 5 * bk // 2
+    rng = np.random.RandomState(len(str((causal, lengths, blocks, heads,
+                                         mask, p_drop))) + seq + d)
+    # bfloat16 inputs; the reference reads the same rounded numbers
+    q, k = (jnp.asarray(rng.randn(B, H, seq, d), jnp.bfloat16)
+            for _ in range(2))
+    v, ct = (jnp.asarray(rng.randn(B, H, seq, dv), jnp.bfloat16)
+             for _ in range(2))
+    bias = _bias(mask, seq, rng)
+    mode = _mask_mode(None if bias is None else bias.shape, B, H, seq, seq)
+    assert mode == {"none": None, "key": "key"}.get(mask, "full")
+    seed = jnp.asarray([7, 11], jnp.int32)
+    canon = None if bias is None else _canon_mask(jnp.asarray(bias))
+
+    def f(q, k, v):
+        return _flash(q, k, v, canon, mode, seed, causal, None, bq, bk,
+                      p_drop)
+
+    out, vjp = jax.vjp(f, q, k, v)
+    got = (out,) + vjp(ct)
+    keep = None
+    if p_drop:
+        pad = lambda n, blk: -(-n // blk) * blk
+        keep = _host_keep_mask(seed, B * H, pad(seq, bq), pad(seq, bk),
+                               p_drop)[:, :seq, :seq]
+    f32 = lambda a: jnp.asarray(a, jnp.float32)
+    want = _reference(f32(q), f32(k), f32(v), f32(ct), bias, causal, keep,
+                      p_drop)
+    for name, a, w in zip(("o", "dq", "dk", "dv"), got, want):
+        assert a.dtype == jnp.bfloat16
+        a, w = np.asarray(f32(a)), np.asarray(w)
+        assert np.isfinite(a).all(), name
+        # bfloat16 products and a bfloat16 result: 2^-8 of the array's size
+        np.testing.assert_allclose(a, w, atol=0.03 * max(1.0, np.abs(w).max()),
+                                   err_msg=name)
+        assert np.linalg.norm(a - w) <= 0.012 * np.linalg.norm(w), name
+
+
+# -- (d) the counters at the three flash cells' shapes ------------------------
+
+def _traced_counts(shape_qk, shape_v, dtype, causal, mask_shape=None):
+    """What one call site adds to the dispatch's counters (traced, not
+    run: the shapes are the cells')."""
+    args = [jax.ShapeDtypeStruct(shape_qk, dtype)] * 2 + [
+        jax.ShapeDtypeStruct(shape_v, dtype)]
+    if mask_shape is not None:
+        args.append(jax.ShapeDtypeStruct(mask_shape, jnp.float32))
+
+    def call(q, k, v, *mask):
+        return flash_attention(
+            pt.Tensor(q), pt.Tensor(k), pt.Tensor(v),
+            attn_mask=pt.Tensor(mask[0]) if mask else None, causal=causal,
+            force=True).data
+
+    before = monitor.snapshot("flash_attention")
+    jax.eval_shape(call, *args)
+    after = monitor.snapshot("flash_attention")
+    return {key.split(".", 1)[1]: after.get(key, 0) - before.get(key, 0)
+            for key in after}
+
+
+@pytest.mark.parametrize("cell,qk,v,causal,mask,blocks,tiles,masked", [
+    ("joyai_llm_flash.causal_pretrain", (1, 32, 8192, 192),
+     (1, 32, 8192, 128), True, None, (512, 512), 4352, 512),
+    ("nemotron3_nano_30b_a3b.causal_pretrain", (1, 32, 8192, 128),
+     (1, 32, 8192, 128), True, None, (512, 512), 4352, 512),
+    ("bert_base.pretrain_seq512", (16, 12, 512, 64), (16, 12, 512, 64),
+     False, (16, 1, 1, 512), (512, 1024), 192, 0),
+], ids=["joyai", "nemotron", "seq512"])
+def test_counters_at_a_cells_shape(cell, qk, v, causal, mask, blocks, tiles,
+                                   masked):
+    # by hand: 32 heads x sum(i + 1 for i in range(8192 // 512)) = 32 x 136
+    # tiles, one diagonal tile a q-block; seq512 one whole tile a program
+    assert _blocks_that_fit(qk[2], qk[3], v[3], 2, 512, 1024) == blocks
+    # two buffers of the whole side leave no room for such tiles at
+    # 8,192 x 192 / 128 alone
+    assert _single_buffered(qk[2], qk[3], v[3], 2) == cell.startswith("joyai")
+    seen = _traced_counts(qk, v, jnp.bfloat16, causal, mask)
+    assert seen == {"kernel_traced": 1, "tiles": tiles,
+                    "tiles_masked": masked, "native_operands_traced": 1}
+
+
+def test_a_float32_caller_is_not_counted_native():
+    seen = _traced_counts((1, 2, 64, 16), (1, 2, 64, 16), jnp.float32, True)
+    assert seen.get("native_operands_traced", 0) == 0
+    assert seen["kernel_traced"] == 1
+    assert (seen["tiles"], seen["tiles_masked"]) == (2, 2)    # one block
+    # and its kernels' products stay float32 (test (a), ids f32)
+
+
+@pytest.mark.parametrize("bq,bk,causal,sq,want", [
+    (256, 512, True, 8192, (32 * 272, 32 * 32)),   # one diagonal tile each
+    (512, 256, True, 8192, (32 * 272, 32 * 32)),   # two a q-block
+    (16, 16, True, 40, (32 * 6, 32 * 5)),          # 2.5 blocks: tail masked
+    (16, 16, False, 40, (32 * 9, 32 * 5)),
+])
+def test_tile_counts_by_hand(bq, bk, causal, sq, want):
+    assert _tile_counts(32, block_q=bq, block_k=bk, sq=sq, sk=sq,
+                        causal=causal) == want

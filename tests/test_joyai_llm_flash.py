@@ -361,7 +361,12 @@ def test_the_blocks_shrink_only_where_vmem_asks():
     assert fit(4096, 128, 128, 2, 512, 1024) == (512, 1024)
     assert fit(8192, 128, 128, 2, 512, 1024) == (512, 512)     # nemotron's
     assert fit(4096, 192, 128, 2, 512, 1024) == (512, 1024)
-    assert fit(8192, 192, 128, 2, 512, 1024) == (256, 256)     # this model's
+    # this model's: 512 x 512 since PR 32, beside a whole side held in ONE
+    # VMEM buffer (two of them left room for 256 x 256)
+    assert fit(8192, 192, 128, 2, 512, 1024) == (512, 512)
+    single = flash_mod._single_buffered
+    assert single(8192, 192, 128, 2) and not single(8192, 128, 128, 2)
+    assert not single(4096, 192, 128, 2) and not single(512, 64, 64, 2)
 
 
 # -- gated experts ----------------------------------------------------------
