@@ -194,6 +194,26 @@ def phase_kernels(rows, hidden, batch, heads, seq, head_dim,
     run(f"flash_attention[{batch}x{heads}x{seq}x{dq}|{dv},bf16,causal]",
         k_mla, r_mla, (q, k, v, ct), 3, tol_bf16, 3)
 
+    # the block-diffusion structure over two copies of seq / 2 positions
+    # in blocks of 4 (rows [0, seq / 2) the noisy copy), against float32
+    # attention under the dense mask the kernels never build
+    from paddle_tpu.ops.pallas.flash_attention import block_diffusion_mask
+    q, k, v = (jnp.asarray(rng.randn(batch, heads, seq, 2 * head_dim),
+                           jnp.bfloat16) for _ in range(3))
+    ct = jnp.asarray(rng.randn(batch, heads, seq, 2 * head_dim), jnp.float32)
+    dense = jnp.asarray(block_diffusion_mask(seq // 2, 4))
+
+    def k_bd(q, k, v, ct):
+        out = flash_attention(q, k, v, diffusion_block=4, force=True)
+        return (_raw(out).astype(jnp.float32) * ct).sum()
+
+    def r_bd(q, k, v, ct):
+        out = F.scaled_dot_product_attention(q, k, v, attn_mask=dense)
+        return (_raw(out) * ct).sum()
+
+    run(f"flash_attention[{batch}x{heads}x{seq}x{2 * head_dim},bf16,"
+        f"block_diffusion]", k_bd, r_bd, (q, k, v, ct), 3, tol_bf16, 3)
+
     # the Mamba-2 scan over (batch, seq, 2 * heads heads of 64 in `heads`
     # groups, state 128), bf16 products, all seven gradients
     from paddle_tpu.ops.pallas import ssd_scan as ssd

@@ -30,6 +30,9 @@ def __getattr__(name):
     if name in ("JoyAIFlashConfig", "JoyAIFlashForCausalLM"):
         from . import joyai_llm_flash
         return getattr(joyai_llm_flash, name)
+    if name in ("SDARMoEConfig", "SDARMoEForBlockDiffusion"):
+        from . import sdar_moe
+        return getattr(sdar_moe, name)
     if name in ("Transformer",):
         from . import transformer
         return getattr(transformer, name)

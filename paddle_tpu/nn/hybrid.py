@@ -94,48 +94,86 @@ class Mamba2Mixer(Layer):
 class GroupedQueryAttention(Layer):
     """Self-attention with fewer key/value heads than query heads
     (Ainslie et al., arXiv:2305.13245): KV head j serves the query heads
-    ``[j r, (j + 1) r)``, ``r = num_heads / num_kv_heads``. No bias, no
-    position embedding of its own. The K/V heads are repeated to
-    ``num_heads`` in front of the attention op, which is the flash
-    dispatch (``ops.pallas.flash_attention``: the Pallas kernels on a TPU
-    from ``flash_min_seq`` on, else ``F.scaled_dot_product_attention``);
-    the kernels take any ``head_dim`` (a size that is no multiple of 128
-    lanes is a block's whole last dimension); a kernel that reads each
-    K/V head once is future work (PERF.md section 7)."""
+    ``[j r, (j + 1) r)``, ``r = num_heads / num_kv_heads``. No bias. As it
+    stands (``nemotron_h``) it has no position embedding and no norm of
+    its own. ``qk_norm_epsilon`` adds an RMS norm over each query and each
+    key head (``q_norm`` / ``k_norm``, one ``[head_dim]`` scale each,
+    shared by the heads) and ``rope_theta`` a rotary embedding behind it
+    (halves paired, ``F.rotary_embedding(interleaved=False)``; ``forward``
+    takes the rows' ``positions``, None counts from 0): the ``qwen3_moe``
+    / ``sdar_moe`` attention. ``diffusion_block`` puts the block-diffusion
+    structure in the causal mask's place: the rows are a sequence's noisy
+    copy and then its clean one (``ops.pallas.flash_attention``).
+
+    The K/V heads are repeated to ``num_heads`` in front of the attention
+    op, which is the flash dispatch (``ops.pallas.flash_attention``: the
+    Pallas kernels on a TPU from ``flash_min_seq`` on, else
+    ``F.scaled_dot_product_attention``); the kernels take any ``head_dim``
+    (a size that is no multiple of 128 lanes is a block's whole last
+    dimension); a kernel that reads each K/V head once is future work
+    (PERF.md section 7)."""
 
     def __init__(self, hidden_size, num_heads, num_kv_heads, head_dim,
-                 causal=True):
+                 causal=True, qk_norm_epsilon=None, rope_theta=None,
+                 diffusion_block=None):
         super().__init__()
         if num_heads % num_kv_heads:
             raise ValueError(f"GroupedQueryAttention: {num_heads} query "
                              f"heads over {num_kv_heads} key/value heads")
+        if diffusion_block is not None and causal:
+            raise ValueError("GroupedQueryAttention: the block-diffusion "
+                             "structure stands in the causal mask's place; "
+                             "give causal=False with diffusion_block")
         self.num_heads, self.num_kv_heads = num_heads, num_kv_heads
         self.head_dim, self.causal = head_dim, causal
+        self.rope_theta, self.diffusion_block = rope_theta, diffusion_block
         self.q_proj = Linear(hidden_size, num_heads * head_dim,
                              bias_attr=False)
         self.k_proj = Linear(hidden_size, num_kv_heads * head_dim,
                              bias_attr=False)
         self.v_proj = Linear(hidden_size, num_kv_heads * head_dim,
                              bias_attr=False)
+        self.q_norm = self.k_norm = None
+        if qk_norm_epsilon is not None:
+            self.q_norm = RMSNorm(head_dim, qk_norm_epsilon)
+            self.k_norm = RMSNorm(head_dim, qk_norm_epsilon)
         self.o_proj = Linear(num_heads * head_dim, hidden_size,
                              bias_attr=False)
 
-    def _heads(self, t, b, s, count):
-        """[B, S, count * D] -> [B, num_heads, S, D]."""
-        t = t.reshape([b, s, count, self.head_dim]).transpose([0, 2, 1, 3])
+    def _heads(self, t, b, s, count, norm=None, rotate=False,
+               positions=None):
+        """[B, S, count * D] -> [B, num_heads, S, D], through a head norm
+        and the rotation (queries and keys, where the layer has them)."""
+        t = t.reshape([b, s, count, self.head_dim])
+        if norm is not None:
+            t = norm(t)
+        t = t.transpose([0, 2, 1, 3])
+        if rotate:
+            t = F.rotary_embedding(t, positions, theta=self.rope_theta,
+                                   interleaved=False)
         r = self.num_heads // count
         if r == 1:
             return t
         t = t.unsqueeze(2).expand([b, count, r, s, self.head_dim])
         return t.reshape([b, self.num_heads, s, self.head_dim])
 
-    def forward(self, x, force_flash=False):
+    def qkv(self, x, positions=None):
+        """``(q, k, v)`` as the attention op takes them, ``[B, num_heads,
+        S, head_dim]`` each."""
         b, s = x.shape[0], x.shape[1]
-        q = self._heads(self.q_proj(x), b, s, self.num_heads)
-        k = self._heads(self.k_proj(x), b, s, self.num_kv_heads)
-        v = self._heads(self.v_proj(x), b, s, self.num_kv_heads)
+        rotate = self.rope_theta is not None
+        q = self._heads(self.q_proj(x), b, s, self.num_heads, self.q_norm,
+                        rotate, positions)
+        k = self._heads(self.k_proj(x), b, s, self.num_kv_heads, self.k_norm,
+                        rotate, positions)
+        return q, k, self._heads(self.v_proj(x), b, s, self.num_kv_heads)
+
+    def forward(self, x, positions=None, force_flash=False):
+        b, s = x.shape[0], x.shape[1]
+        q, k, v = self.qkv(x, positions)
         from ..ops.pallas import flash_attention
-        ctx = flash_attention(q, k, v, causal=self.causal, force=force_flash)
+        ctx = flash_attention(q, k, v, causal=self.causal, force=force_flash,
+                              diffusion_block=self.diffusion_block)
         ctx = ctx.transpose([0, 2, 1, 3]).reshape(
             [b, s, self.num_heads * self.head_dim])
         return self.o_proj(ctx)

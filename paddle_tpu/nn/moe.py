@@ -129,9 +129,12 @@ class GatedMLP(Layer):
 
 
 class RoutedMoE(Layer):
-    """The local half of an expert-parallel mixture-of-experts layer, as
-    DeepSeek-V3-style models route (sigmoid scores, a selection bias,
-    top-k weights renormalised and scaled, one always-on shared expert).
+    """The local half of an expert-parallel mixture-of-experts layer.
+    The router is DeepSeek-V3's (``scoring="sigmoid"``: sigmoid scores, a
+    selection bias, top-k weights renormalised and scaled) or Qwen3-MoE's
+    (``scoring="softmax"``: a soft-max over all experts, top-k,
+    renormalised over the chosen; no bias buffer); ``d_shared`` adds one
+    always-on shared expert.
     The experts are ``W_down relu(W_up x)^2`` (``nemotron_h``), or with
     ``gated=True`` ``W_down(silu(W_gate x) * W_up x)`` (DeepSeek-V3,
     ``joyai_llm_flash``): ``experts_gate`` beside ``experts_up``, and the
@@ -164,7 +167,7 @@ class RoutedMoE(Layer):
 
     def __init__(self, d_model, d_expert, num_experts, top_k,
                  d_shared=None, experts_held=None, routed_scaling_factor=1.0,
-                 gated=False):
+                 gated=False, scoring="sigmoid"):
         super().__init__()
         import jax.numpy as jnp
         from .layers import Linear
@@ -177,12 +180,16 @@ class RoutedMoE(Layer):
         self.num_experts, self.top_k = num_experts, top_k
         self.experts_held = held
         self.routed_scaling_factor = float(routed_scaling_factor)
+        self.scoring = scoring
         self.router = Linear(d_model, num_experts, bias_attr=False,
                              weight_attr=I.Normal(0.0, 0.02))
-        # the selection bias: moved by a balancing rule outside the
-        # gradient (none here), so a buffer and not a parameter
-        self.register_buffer("e_score_correction_bias", Tensor(
-            jnp.zeros((num_experts,), jnp.float32)))
+        # the sigmoid router's selection bias: moved by a balancing rule
+        # outside the gradient (none here), so a buffer and not a parameter
+        if scoring == "sigmoid":
+            self.register_buffer("e_score_correction_bias", Tensor(
+                jnp.zeros((num_experts,), jnp.float32)))
+        else:
+            self.e_score_correction_bias = None
         self.experts_gate = None
         if gated:
             self.experts_gate = self.create_parameter(
@@ -210,7 +217,8 @@ class RoutedMoE(Layer):
         from ..ops import nn_ops as F
         weights, experts = M.moe_route(
             u, self.router.weight, self.e_score_correction_bias,
-            top_k=self.top_k, scale=self.routed_scaling_factor)
+            top_k=self.top_k, scale=self.routed_scaling_factor,
+            scoring=self.scoring)
         y, seen = M.moe_experts(u, experts, weights, self.experts_up,
                                 self.experts_down,
                                 first_expert=self.experts_held.start,

@@ -160,6 +160,27 @@ def cross_entropy(input, label, soft_label=False, ignore_index=-100,
                           reduction=reduction), name="cross_entropy")
 
 
+def block_diffusion_loss(input, label, weight, name=None):
+    """The masked-diffusion training loss (Sahoo et al., arXiv:2406.07524;
+    by blocks, Arriola et al., arXiv:2503.09573): ``(1 / N) sum_i w_i CE(
+    z_i, x_i)`` over all ``N`` positions of ``input`` (logits ``[..., V]``)
+    against ``label`` ``[...]``, NO shift: the logit at a masked position
+    predicts that position's own token. ``weight`` ``[...]`` is ``1 / t``
+    at a position masked at noise level ``t`` and 0 elsewhere, so an
+    all-zero weight gives 0 and ``weight = 1`` the plain mean cross
+    entropy. float32 whatever the logits are."""
+    def impl(z, y, w):
+        z = z.astype(jnp.float32)
+        picked = jnp.take_along_axis(z, y[..., None].astype(jnp.int32),
+                                     -1)[..., 0]
+        ce = jax.scipy.special.logsumexp(z, axis=-1) - picked
+        return jnp.sum(w.astype(jnp.float32) * ce) / ce.size
+
+    with _pscope("F.block_diffusion_loss"):
+        return apply(impl, (input, label, weight),
+                     name="block_diffusion_loss")
+
+
 def sigmoid_cross_entropy_with_logits(x, label, ignore_index=-100,
                                       normalize=False, name=None):
     """reference: sigmoid_cross_entropy_with_logits_op.cc"""

@@ -3,9 +3,11 @@
 No reference counterpart in Paddle Fluid 1.7. Two ops, each one pure-jax
 impl through ``dispatch.apply``:
 
-* ``moe_route`` — a sigmoid router with a selection bias: top-k of ``s +
-  b`` over ALL experts the model has, weights from ``s`` alone,
-  renormalised over the chosen and scaled; float32 throughout.
+* ``moe_route`` — the router, over ALL experts the model has, float32
+  throughout. Scores ``s`` are a sigmoid of each output (DeepSeek-V3,
+  ``nemotron_h``; with a selection bias: top-k of ``s + b``) or a soft-max
+  over the outputs (``qwen3_moe``, ``sdar_moe``); weights from ``s``
+  alone, renormalised over the chosen and scaled.
 * ``moe_experts`` — the part of the layer's result that the experts HELD
   HERE give (expert parallelism's local half: the layer is told which
   experts it holds, the router still ranges over all of them). Grouped
@@ -46,14 +48,21 @@ MOE_STATS = ("slots_routed_here", "slots_dropped", "expert_load_max", "calls",
 MIN_ROWS = 512
 
 
-def moe_route(x, router_weight, bias=None, top_k=1, scale=1.0, name=None):
+def moe_route(x, router_weight, bias=None, top_k=1, scale=1.0,
+              scoring="sigmoid", name=None):
     """``(weights [..., k] float32, experts [..., k] int32)`` of a
-    sigmoid router: ``s = sigmoid(x W_r)`` in float32 whatever ``x`` is;
-    experts = top-k of ``s + bias`` (``bias`` a buffer: no gradient
+    router: ``s = sigmoid(x W_r)``, or with ``scoring="softmax"`` the
+    soft-max of ``x W_r`` over all the experts, in float32 whatever ``x``
+    is; experts = top-k of ``s + bias`` (``bias`` a buffer: no gradient
     reaches it); ``weights = scale * s_i / (sum over the chosen of s +
     1e-20)``."""
+    if scoring not in ("sigmoid", "softmax"):
+        raise ValueError(f"moe_route: scoring {scoring!r} is neither "
+                         f"'sigmoid' nor 'softmax'")
+    score = jax.nn.sigmoid if scoring == "sigmoid" else jax.nn.softmax
+
     def impl(x, w, *b, top_k, scale):
-        s = jax.nn.sigmoid(jnp.einsum(
+        s = score(jnp.einsum(
             "...d,de->...e", x.astype(jnp.float32), w.astype(jnp.float32),
             precision="highest"))
         ranked = s if not b else s + jax.lax.stop_gradient(

@@ -6,11 +6,35 @@ formulation).
 
 Forward: grid (batch*heads, q-blocks); each program walks k/v-blocks with
 the online-softmax recurrence (running max m, normalizer l, accumulator
-acc) so the S×S score matrix never hits HBM. The additive attention mask
-(key bias [B,1,1,Sk] or full [.,.,Sq,Sk]) is added to the scores inside
-the kernel, and attention-probability dropout is drawn in-kernel from the
+acc) so the S×S score matrix never hits HBM. A mask enters in one of
+three ways: as an ARRAY (an additive key bias [B,1,1,Sk] or a full
+[.,.,Sq,Sk] bias, added to the scores inside the kernel), as the causal
+diagonal, or as the BLOCK-DIFFUSION STRUCTURE (below) — the last two are
+facts of the call that cost no operand and decide which tiles a program
+walks at all. Attention-probability dropout is drawn in-kernel from the
 TPU PRNG, seeded per (bh, q-block, k-block) tile so the backward
 regenerates the identical keep-mask without ever storing it.
+
+Block-diffusion structure (PR 33; ``flash_attention(diffusion_block=B)``,
+``block_diffusion_mask`` is the rule). q, k and v hold two copies of a
+sequence of L positions, the noisy copy's rows in front of the clean
+copy's. A clean row sees the clean blocks up to its own, a noisy row the
+clean blocks before its own and its own noisy block: of the (2L)^2 score
+tiles n (n + 1) + n hold an allowed pair (n = L / tile; 288 of 1,024 a
+head at 512 x 512 and L = 8,192) and 3 n of them are crossed by the
+structure. The same three kernel bodies take it (``bd=`` in their static
+parameters; without it they trace what they traced before; under it the
+calls are named ``flash_bd_fwd``, ``flash_bd_bwd_dq``, ``flash_bd_bwd_dkv``): the side a
+program keeps whole is the CLEAN copy's L rows — what any row sees of the
+noisy copy is its own block, which arrives as one more blocked operand at
+the program's own positions — so 2L rows cost the VMEM of L; the forward
+and dQ grids run over both copies' q-blocks, and the dK/dV grid runs each
+clean k-block twice, once against each copy's rows (the two parts are
+added outside), the noisy rows' program taking the noisy k-block at the
+same positions with it. Positions inside a crossed tile come from iotas
+and a shift by log2 B, as the causal diagonal's. A copy is padded to whole
+tiles with zero rows; L is whole diffusion blocks, so the structure itself
+keeps every true row off the padding.
 
 Backward: two kernels. dQ: grid (bh, q-blocks) loops k-blocks; dK/dV:
 grid (bh, k-blocks) loops q-blocks over the TRANSPOSED score tile
@@ -238,11 +262,95 @@ def _valid(shape, q0, k0, q_axis, *, sq, sk, block_q, block_k, causal):
     return valid
 
 
-def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, mask_ref, keep_ref, o_ref,
-                m_ref, l_ref, *, block_q, block_k, sq, sk, causal, scale,
-                mask_mode, dropout_p, threshold, drop_mode):
+def _bd_rows(g, per_copy):
+    """Block ``g`` of the ``2 * per_copy`` blocks of a two-copy side, as
+    ``(clean, block within the copy)``: the noisy copy's blocks come
+    first."""
+    clean = g // per_copy
+    return clean, g - clean * per_copy
+
+
+def _bd_k_tile_ranges(qi, *, block_q, block_k, bd):
+    """``_k_tile_ranges`` under the block-diffusion structure ``bd =
+    (shift, q-blocks a copy, k-blocks a copy)``, for q-block ``qi`` of the
+    two copies' rows, over the CLEAN copy's k-blocks: a clean row sees the
+    clean blocks up to its own, a noisy row those before its own. (A noisy
+    q-block walks its own noisy positions besides: ``_bd_own_tiles``.)"""
+    block = 1 << bd[0]
+    clean, pj = _bd_rows(qi, bd[1])
+    n_plain = (pj * block_q + clean * block) // block_k
+    n_needed = ((pj + 1) * block_q - (1 - clean) * block
+                + block_k - 1) // block_k
+    return n_plain, n_needed
+
+
+def _bd_q_tile_ranges(xp, g, *, block_q, block_k, bd):
+    """``_q_tile_ranges`` under the block structure, for program ``g`` of
+    the dK/dV kernel: it owns CLEAN k-block ``g mod k-blocks`` and walks the
+    q-blocks of ONE copy, the noisy one for ``g`` under ``k-blocks`` (whose
+    rows see only earlier blocks), then the clean one. ``(q_start,
+    plain_lo, plain_hi)`` in blocks of that copy."""
+    block, n_q = 1 << bd[0], bd[1]
+    clean, j = _bd_rows(g, bd[2])
+    off = 1 - clean
+    q_start = (j * block_k + (1 + off) * block + block_q - 1) // block_q - 1
+    plain_lo = ((j + 1) * block_k - (1 - off) * block
+                + block_q - 1) // block_q
+    q_start = xp.minimum(q_start, n_q)
+    return q_start, xp.minimum(xp.maximum(plain_lo, q_start), n_q), n_q
+
+
+def _bd_own_tiles(own, other):
+    """(tiles, their width along ``other``'s axis) in which a block of
+    ``own`` positions meets the same positions of the noisy copy."""
+    width = min(own, other)
+    return own // width, width
+
+
+def _bd_crossed(own, other, shift):
+    """``_crossed_tiles`` under the block structure: tiles of the clean
+    side that every program masks, where the shapes alone say it."""
+    if (1 << shift) < min(own, other) and (own % other == 0
+                                          or other % own == 0):
+        return max(1, own // other)
+    return None
+
+
+def _bd_valid(shape, q0, k0, q_axis, shift, off):
+    """Which positions of a score tile may attend under the block
+    structure; ``q0`` / ``k0`` are positions within a copy. Clean keys:
+    key block + ``off`` <= query block (``off`` 0 for a clean row, 1 for a
+    noisy one). ``off`` None: the noisy copy's keys, the row's own block
+    alone. Positions from iotas and a shift, as the causal diagonal's."""
+    q_blk = (q0 + jax.lax.broadcasted_iota(jnp.int32, shape, q_axis)) >> shift
+    k_blk = (k0 + jax.lax.broadcasted_iota(jnp.int32, shape,
+                                           1 - q_axis)) >> shift
+    return k_blk == q_blk if off is None else k_blk + off <= q_blk
+
+
+def _bd_tile_counts(bh, length, *, block_q, block_k, shift):
+    """(score tiles walked, tiles that run the masked body, tiles of the
+    two-copy rectangle) of one forward call under the block structure: the
+    kernel's own bounds added up over its grid, as ``_tile_counts``."""
+    lp = _bd_padded(length, block_q, block_k)
+    bd = (shift, lp // block_q, lp // block_k)
+    qi = np.arange(2 * bd[1])
+    n_plain, n_needed = _bd_k_tile_ranges(qi, block_q=block_q,
+                                          block_k=block_k, bd=bd)
+    own = bd[1] * _bd_own_tiles(block_q, block_k)[0]
+    tiles = int(np.sum(n_needed)) + own
+    return (bh * tiles, bh * (tiles - int(np.sum(n_plain))),
+            bh * 4 * bd[1] * bd[2])
+
+
+def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, mask_ref, keep_ref, *rest,
+                block_q, block_k, sq, sk, causal, scale, mask_mode,
+                dropout_p, threshold, drop_mode, bd=None):
     # q_ref: (1, BQ, D); k_ref: (1, SKp, D); v_ref: (1, SKp, DV);
-    # mask_ref: (1, {1, BQ}, SKp); o_ref: (1, BQ, DV)
+    # mask_ref: (1, {1, BQ}, SKp); o_ref: (1, BQ, DV). Under the block
+    # structure k_ref / v_ref are the CLEAN copy's side and ``own`` the
+    # noisy copy's (1, BQ, D | DV) block at this q-block's positions
+    *own, o_ref, m_ref, l_ref = rest
     geom = dict(block_q=block_q, block_k=block_k, sq=sq, sk=sk,
                 causal=causal)
     bh = pl.program_id(0)
@@ -256,19 +364,17 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, mask_ref, keep_ref, o_ref,
     l0 = jnp.zeros((q.shape[0], 1), jnp.float32)
     acc0 = jnp.zeros((q.shape[0], v_ref.shape[2]), jnp.float32)
 
-    def tile(j, carry, masked):
+    def step(k, v, carry, j, cols, valid):
+        """One score tile of the online soft-max; ``valid`` (None: every
+        position) is made behind the product."""
         m, l, acc = carry
-        cols = pl.ds(j * block_k, block_k)
-        k = k_ref[0, cols, :].astype(q.dtype)
-        v = v_ref[0, cols, :].astype(v_dtype)
         s = _dot_nt(q, k)
         if mask_mode in ("key", "full"):
             # rows are already positioned by the BlockSpec: a (1, BK) key
             # bias broadcasts down, a (BQ, BK) full bias adds elementwise
             s = s + mask_ref[0, :, cols].astype(jnp.float32)
-        if masked:
-            valid = _valid(s.shape, qi * block_q, j * block_k, 0, **geom)
-            s = jnp.where(valid, s, _NEG_INF)
+        if valid is not None:
+            s = jnp.where(valid(s.shape), s, _NEG_INF)
             if sk % block_k:
                 # zero padded v rows: p is 0 there, but 0 * NaN-padding
                 # would still poison the accumulator
@@ -277,7 +383,7 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, mask_ref, keep_ref, o_ref,
                 v = jnp.where(row_pos < sk, v, jnp.zeros_like(v))
         m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
         m_safe = m_new
-        if masked or mask_mode is not None:
+        if valid is not None or mask_mode is not None:
             # rows with every key masked so far: keep the exp argument
             # finite. exp(_NEG_INF - m_safe) is 0 without a second select
             m_safe = jnp.where(m_new <= _NEG_INF, 0.0, m_new)
@@ -293,12 +399,49 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, mask_ref, keep_ref, o_ref,
             p = jnp.where(keep, p / (1.0 - dropout_p), 0.0)
         return m_new, l_new, acc * corr + _dot(p.astype(v.dtype), v)
 
-    n_plain, n_needed = _k_tile_ranges(jnp, qi, **geom)
+    if bd is None:
+        def valid(shape, j):
+            return _valid(shape, qi * block_q, j * block_k, 0, **geom)
+
+        n_plain, n_needed = _k_tile_ranges(jnp, qi, **geom)
+        crossed = _crossed_tiles(block_q, block_k, **geom)
+    else:
+        clean, pj = _bd_rows(qi, bd[1])
+
+        def valid(shape, j):
+            return _bd_valid(shape, pj * block_q, j * block_k, 0, bd[0],
+                             1 - clean)
+
+        n_plain, n_needed = _bd_k_tile_ranges(qi, block_q=block_q,
+                                              block_k=block_k, bd=bd)
+        crossed = _bd_crossed(block_q, block_k, bd[0])
+
+    def tile(j, carry, masked):
+        cols = pl.ds(j * block_k, block_k)
+        return step(k_ref[0, cols, :].astype(q.dtype),
+                    v_ref[0, cols, :].astype(v_dtype), carry, j, cols,
+                    (lambda shape: valid(shape, j)) if masked else None)
+
     carry = _walk(0, n_plain, functools.partial(tile, masked=False),
                   (m0, l0, acc0))
-    m, l, acc = _walk_crossed(
+    carry = _walk_crossed(
         n_plain, n_needed, functools.partial(tile, masked=True), carry,
-        _crossed_tiles(block_q, block_k, **geom))
+        crossed)
+    if bd is not None:
+        # a noisy q-block's own positions in the noisy copy
+        n_own, width = _bd_own_tiles(block_q, block_k)
+
+        def own_tile(t, carry):
+            cols = pl.ds(t * width, width)
+            return step(
+                own[0][0, cols, :].astype(q.dtype),
+                own[1][0, cols, :].astype(v_dtype), carry, t, cols,
+                lambda shape: _bd_valid(shape, pj * block_q,
+                                        pj * block_q + t * width, 0, bd[0],
+                                        None))
+
+        carry = _walk(0, (1 - clean) * n_own, own_tile, carry)
+    m, l, acc = carry
     o_ref[0] = (acc / jnp.maximum(l, 1e-20)).astype(o_ref.dtype)
     m_fin = jnp.where(m <= _NEG_INF, 0.0, m)
     m_ref[0] = _stat_row(m_fin)
@@ -306,9 +449,12 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, mask_ref, keep_ref, o_ref,
 
 
 def _bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, mask_ref, keep_ref,
-                   m_ref, linv_ref, delta_ref, do_ref, dq_ref, *, block_q,
+                   m_ref, linv_ref, delta_ref, do_ref, *rest, block_q,
                    block_k, sq, sk, causal, scale, mask_mode, dropout_p,
-                   threshold, drop_mode):
+                   threshold, drop_mode, bd=None):
+    # under the block structure k_ref / v_ref are the clean copy's side and
+    # ``own`` the noisy copy's block at this q-block's positions
+    *own, dq_ref = rest
     geom = dict(block_q=block_q, block_k=block_k, sq=sq, sk=sk,
                 causal=causal)
     q = (q_ref[0].astype(jnp.float32) * scale).astype(
@@ -321,16 +467,12 @@ def _bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, mask_ref, keep_ref,
     qi = pl.program_id(1)
     dq0 = jnp.zeros((q.shape[0], q_ref.shape[2]), jnp.float32)
 
-    def tile(j, dq, masked):
-        cols = pl.ds(j * block_k, block_k)
-        k = k_ref[0, cols, :].astype(q.dtype)
-        v = v_ref[0, cols, :].astype(do.dtype)
+    def step(k, v, dq, j, cols, valid):
         s = _dot_nt(q, k)
         if mask_mode in ("key", "full"):
             s = s + mask_ref[0, :, cols].astype(jnp.float32)
-        if masked:
-            valid = _valid(s.shape, qi * block_q, j * block_k, 0, **geom)
-            s = jnp.where(valid, s, _NEG_INF)
+        if valid is not None:
+            s = jnp.where(valid(s.shape), s, _NEG_INF)
         # p = exp(s − m)/l: same rounding as the forward recurrence even
         # for ~1e9-scale masked scores (see module docstring); 0 at
         # _NEG_INF, whose row's m is finite
@@ -346,18 +488,52 @@ def _bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, mask_ref, keep_ref,
         ds = p * (dp - delta)
         return dq + _dot(ds.astype(k.dtype), k)
 
-    n_plain, n_needed = _k_tile_ranges(jnp, qi, **geom)
+    if bd is None:
+        def valid(shape, j):
+            return _valid(shape, qi * block_q, j * block_k, 0, **geom)
+
+        n_plain, n_needed = _k_tile_ranges(jnp, qi, **geom)
+        crossed = _crossed_tiles(block_q, block_k, **geom)
+    else:
+        clean, pj = _bd_rows(qi, bd[1])
+
+        def valid(shape, j):
+            return _bd_valid(shape, pj * block_q, j * block_k, 0, bd[0],
+                             1 - clean)
+
+        n_plain, n_needed = _bd_k_tile_ranges(qi, block_q=block_q,
+                                              block_k=block_k, bd=bd)
+        crossed = _bd_crossed(block_q, block_k, bd[0])
+
+    def tile(j, dq, masked):
+        cols = pl.ds(j * block_k, block_k)
+        return step(k_ref[0, cols, :].astype(q.dtype),
+                    v_ref[0, cols, :].astype(do.dtype), dq, j, cols,
+                    (lambda shape: valid(shape, j)) if masked else None)
+
     dq = _walk(0, n_plain, functools.partial(tile, masked=False), dq0)
     dq = _walk_crossed(n_plain, n_needed,
-                       functools.partial(tile, masked=True), dq,
-                       _crossed_tiles(block_q, block_k, **geom))
+                       functools.partial(tile, masked=True), dq, crossed)
+    if bd is not None:
+        n_own, width = _bd_own_tiles(block_q, block_k)
+
+        def own_tile(t, dq):
+            cols = pl.ds(t * width, width)
+            return step(
+                own[0][0, cols, :].astype(q.dtype),
+                own[1][0, cols, :].astype(do.dtype), dq, t, cols,
+                lambda shape: _bd_valid(shape, pj * block_q,
+                                        pj * block_q + t * width, 0, bd[0],
+                                        None))
+
+        dq = _walk(0, (1 - clean) * n_own, own_tile, dq)
     dq_ref[0] = (dq * scale).astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, mask_ref, keep_ref,
-                    m_ref, linv_ref, delta_ref, do_ref, dk_ref, dv_ref, *,
+                    m_ref, linv_ref, delta_ref, do_ref, *rest,
                     block_q, block_k, sq, sk, causal, scale, mask_mode,
-                    dropout_p, threshold, drop_mode):
+                    dropout_p, threshold, drop_mode, bd=None):
     # this program owns ONE k-block (grid (bh, k-blocks)) and loops
     # q-blocks. q_ref: (1, SQp, D); do_ref: (1, SQp, DV); k_ref: (1, BK, D);
     # v_ref: (1, BK, DV); mask_ref: (1, {1, SQp}, BK); m/linv/delta:
@@ -366,6 +542,15 @@ def _bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, mask_ref, keep_ref,
     # (1, BQ) row statistics broadcast down its sublanes as they are read
     # (no relayout), and p^T, ds^T are already the left operands of
     # dv = p^T dO and dk = ds^T Q (no transpose of a score tile).
+    # Under the block structure (grid (bh, 2 x k-blocks a copy)) q_ref,
+    # do_ref and the statistics are ONE copy's rows, k_ref / v_ref the
+    # clean copy's k-block and ``own`` the noisy copy's at the same
+    # positions; the results are the clean block's part from that copy's
+    # rows and the noisy block's gradient (zero from the clean rows).
+    if bd is None:
+        dk_ref, dv_ref = rest
+    else:
+        kn_ref, vn_ref, dk_ref, dv_ref, dkn_ref, dvn_ref = rest
     geom = dict(block_q=block_q, block_k=block_k, sq=sq, sk=sk,
                 causal=causal)
     bh = pl.program_id(0)
@@ -375,7 +560,7 @@ def _bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, mask_ref, keep_ref,
     if mask_mode == "key":      # (1, BK) key bias -> (BK, 1), once
         kbias = _stat_col(mask_ref[0].astype(jnp.float32))
 
-    def tile(qi, carry, masked):
+    def step(k, v, qi, carry, valid):
         dk, dv = carry
         rows = pl.ds(qi * block_q, block_q)
         # the forward's rounding of the scaled q, so that s^T is its s
@@ -391,9 +576,8 @@ def _bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, mask_ref, keep_ref,
             st = st + kbias
         elif mask_mode == "full":
             st = st + mask_ref[0, rows, :].astype(jnp.float32).T
-        if masked:
-            valid = _valid(st.shape, qi * block_q, j * block_k, 1, **geom)
-            st = jnp.where(valid, st, _NEG_INF)
+        if valid is not None:
+            st = jnp.where(valid(st.shape), st, _NEG_INF)
         pt = jnp.exp(st - mrow) * linv
         dpt = _dot_nt(v, do)
         pdt = pt
@@ -414,16 +598,53 @@ def _bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, mask_ref, keep_ref,
         dk = dk + _dot(dst.astype(q.dtype), q)
         return dk, dv
 
-    q_start, plain_lo, plain_hi = _q_tile_ranges(jnp, j, **geom)
+    def zeros():
+        return (jnp.zeros(k.shape, jnp.float32),
+                jnp.zeros(v.shape, jnp.float32))
+
+    if bd is None:
+        def valid(shape, qi):
+            return _valid(shape, qi * block_q, j * block_k, 1, **geom)
+
+        q_start, plain_lo, plain_hi = _q_tile_ranges(jnp, j, **geom)
+        count = _crossed_tiles(block_k, block_q, **geom)
+    else:
+        clean, pj = _bd_rows(j, bd[2])
+
+        def valid(shape, qi):
+            return _bd_valid(shape, qi * block_q, pj * block_k, 1, bd[0],
+                             1 - clean)
+
+        q_start, plain_lo, plain_hi = _bd_q_tile_ranges(
+            jnp, j, block_q=block_q, block_k=block_k, bd=bd)
+        count = _bd_crossed(block_k, block_q, bd[0])
+
+    def tile(qi, carry, masked):
+        return step(k, v, qi, carry,
+                    (lambda shape: valid(shape, qi)) if masked else None)
+
     plain, crossed = (functools.partial(tile, masked=m)
                       for m in (False, True))
-    carry = (jnp.zeros(k.shape, jnp.float32), jnp.zeros(v.shape, jnp.float32))
-    carry = _walk_crossed(q_start, plain_lo, crossed, carry,
-                          _crossed_tiles(block_k, block_q, **geom))
+    carry = _walk_crossed(q_start, plain_lo, crossed, zeros(), count)
     carry = _walk(plain_lo, plain_hi, plain, carry)
-    if sq % block_q:        # the one q-block with rows past sq
-        carry = _walk(jnp.maximum(plain_hi, q_start), plain_hi + 1, crossed,
-                      carry)
+    if bd is None:
+        if sq % block_q:        # the one q-block with rows past sq
+            carry = _walk(jnp.maximum(plain_hi, q_start), plain_hi + 1,
+                          crossed, carry)
+    else:
+        # the noisy copy's k-block at these positions: its own rows alone
+        kn = kn_ref[0].astype(k.dtype)
+        vn = vn_ref[0].astype(v.dtype)
+        first = (pj * block_k) // block_q
+        dkn, dvn = _walk(
+            first, first + (1 - clean) * max(1, block_k // block_q),
+            lambda qi, c: step(
+                kn, vn, qi, c,
+                lambda shape: _bd_valid(shape, qi * block_q, pj * block_k,
+                                        1, bd[0], None)),
+            zeros())
+        dkn_ref[0] = dkn.astype(dkn_ref.dtype)
+        dvn_ref[0] = dvn.astype(dvn_ref.dtype)
     dk, dv = carry
     dk_ref[0] = dk.astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
@@ -479,14 +700,14 @@ def _clamped_blocks(block_q, block_k, sq, sk):
     return min(block_q, max(sq, 8)), min(block_k, sk)
 
 
-def _whole_side(shape, single):
+def _whole_side(shape, single, index=lambda i, j: (i, 0, 0)):
     """BlockSpec of an operand a program keeps whole: one (batch, head)'s
-    other side, which changes only with the grid's first axis. ``single``:
-    one buffer in VMEM and no prefetch of the next head's while this one's
-    last block runs (``_single_buffered``)."""
+    other side, which changes only with the grid's first axis (under the
+    block structure one COPY's side, ``index``: it changes once more a
+    head). ``single``: one buffer in VMEM and no prefetch of the next
+    head's while this one's last block runs (``_single_buffered``)."""
     mode = {"pipeline_mode": pl.Buffered(1)} if single else {}
-    return pl.BlockSpec(shape, lambda i, j: (i, 0, 0),
-                        memory_space=pltpu.VMEM, **mode)
+    return pl.BlockSpec(shape, index, memory_space=pltpu.VMEM, **mode)
 
 
 def _pad_axis(x, axis, new):
@@ -731,10 +952,210 @@ def _bwd(mask_mode, causal, scale, block_q, block_k, dropout_p, res, g):
 _flash.defvjp(_fwd, _bwd)
 
 
+def block_diffusion_mask(length, block):
+    """bool ``[2 length, 2 length]``, True where row r may attend to row s
+    of a sequence laid out as its noisy copy (rows ``[0, length)``) in
+    front of its clean copy: with position ``p = r mod length`` and block
+    ``b = p // block``, a clean key of an EARLIER block, or a key of the
+    row's own copy in its own block (Arriola et al., arXiv:2503.09573,
+    section 3: block-diagonal, offset block-causal, block-causal). The
+    kernels never build it: the portable path and the tests do."""
+    at = np.arange(2 * length)
+    clean, blk = at >= length, (at % length) // block
+    return ((clean[None, :] & (blk[None, :] < blk[:, None]))
+            | ((clean[None, :] == clean[:, None])
+               & (blk[None, :] == blk[:, None])))
+
+
+def _bd_blocks(block_q, block_k, length, shift):
+    """The blocks a block-structured call runs with: no larger than a
+    copy, whole diffusion blocks, one a multiple of the other."""
+    block = 1 << shift
+    bq, bk = (max(b // block * block, block)
+              for b in _clamped_blocks(block_q, block_k, length, length))
+    if bq % bk and bk % bq:
+        bk = bq
+    return bq, bk
+
+
+def _bd_padded(length, block_q, block_k):
+    """A copy's length in whole blocks of both sizes."""
+    big = max(block_q, block_k)
+    return -(-length // big) * big
+
+
+def _bd_sides(x, lp):
+    """(B, H, 2 L, D) -> (B H, 2 Lp, D): each copy padded with zero rows
+    to ``lp``. No position past L needs a mask: L is whole diffusion
+    blocks, so the structure keeps every true row off the padding's
+    blocks, and a padded row's q and dO are zero."""
+    b, h, s2, d = x.shape
+    x = _pad_axis(x.reshape(b * h, 2, s2 // 2, d), 2, lp)
+    return x.reshape(b * h, 2 * lp, d)
+
+
+def _bd_unpad(x, b, h, length):
+    """(B H, 2 Lp, D) -> (B, H, 2 L, D)."""
+    bh, s2, d = x.shape
+    return x.reshape(bh, 2, s2 // 2, d)[:, :, :length].reshape(
+        b, h, 2 * length, d)
+
+
+class _BdPlan:
+    """What the three block-structured calls share: the blocks, a copy's
+    padded length ``lp``, ``bd`` for the kernels, the block specs of the
+    two-copy ``(B H, 2 Lp, width)`` arrays, and the unused seed / mask /
+    dropout operands with their specs."""
+
+    def __init__(self, q, v, shift, scale, block_q, block_k):
+        d, length = q.shape[3], q.shape[2] // 2
+        self.bq, self.bk = _bd_blocks(block_q, block_k, length, shift)
+        self.lp = _bd_padded(length, self.bq, self.bk)
+        self.n_q, self.n_k = self.lp // self.bq, self.lp // self.bk
+        self.bd = (shift, self.n_q, self.n_k)
+        self.scale = scale if scale is not None else 1.0 / np.sqrt(d)
+        self.single = _single_buffered(length, d, v.shape[3],
+                                       q.dtype.itemsize)
+        self.unused = (jnp.zeros((2,), jnp.int32),
+                       jnp.zeros((1, 1, 1), jnp.float32))
+        self.seed_spec = pl.BlockSpec(memory_space=pltpu.SMEM)
+        self.zero_spec = pl.BlockSpec((1, 1, 1), lambda i, j: (0, 0, 0),
+                                      memory_space=pltpu.VMEM)
+        self.stat_q = pl.BlockSpec((1, 1, self.bq), lambda i, j: (i, 0, j),
+                                   memory_space=pltpu.VMEM)
+
+    def body(self, kernel):
+        return functools.partial(
+            kernel, block_q=self.bq, block_k=self.bk, sq=self.lp, sk=self.lp,
+            causal=False, scale=self.scale, mask_mode=None, dropout_p=0.0,
+            threshold=0, drop_mode="prng", bd=self.bd)
+
+    def block(self, rows, width, index=lambda i, j: (i, j, 0)):
+        return pl.BlockSpec((1, rows, width), index,
+                            memory_space=pltpu.VMEM)
+
+    def own(self, width):
+        """The noisy copy's block at a q-block's positions; a clean
+        q-block keeps the last noisy one (no copy)."""
+        last = self.n_q - 1
+        return self.block(self.bq, width,
+                          lambda i, j: (i, jnp.minimum(j, last), 0))
+
+    def clean_side(self, width):
+        return _whole_side((1, self.lp, width), self.single,
+                           lambda i, j: (i, 1, 0))
+
+
+def _bd_fwd_res(q, k, v, shift, scale, block_q, block_k):
+    from . import interpret_mode
+    b, h, _, d = q.shape
+    dvh = v.shape[3]
+    p = _BdPlan(q, v, shift, scale, block_q, block_k)
+    q3, k3, v3 = (_bd_sides(x, p.lp) for x in (q, k, v))
+    out, mrow, lrow = pl.pallas_call(
+        p.body(_fwd_kernel), grid=(b * h, 2 * p.n_q),
+        in_specs=[p.seed_spec, p.block(p.bq, d), p.clean_side(d),
+                  p.clean_side(dvh), p.zero_spec, p.zero_spec, p.own(d),
+                  p.own(dvh)],
+        out_specs=[p.block(p.bq, dvh), p.stat_q, p.stat_q],
+        out_shape=[
+            jax.ShapeDtypeStruct((b * h, 2 * p.lp, dvh), q.dtype),
+            jax.ShapeDtypeStruct((b * h, 1, 2 * p.lp), jnp.float32),
+            jax.ShapeDtypeStruct((b * h, 1, 2 * p.lp), jnp.float32),
+        ],
+        interpret=interpret_mode(),
+        name="flash_bd_fwd",
+    )(p.unused[0], q3, k3, v3, p.unused[1], p.unused[1], k3, v3)
+    return _bd_unpad(out, b, h, q.shape[2] // 2), mrow, lrow
+
+
+def _bd_bwd(q, k, v, out, mrow, lrow, g, shift, scale, block_q, block_k):
+    from . import interpret_mode
+    b, h, s2, d = q.shape
+    dvh, length = v.shape[3], s2 // 2
+    p = _BdPlan(q, v, shift, scale, block_q, block_k)
+    lp, n_k = p.lp, p.n_k
+    seed, zero = p.unused
+    q3, k3, v3, do3, o3 = (_bd_sides(x, lp) for x in (q, k, v, g, out))
+    delta = jnp.sum(do3.astype(jnp.float32) * o3.astype(jnp.float32),
+                    axis=-1)[:, None, :]
+    stats = [mrow, 1.0 / jnp.maximum(lrow, 1e-20), delta]   # (BH, 1, 2 Lp)
+    interp = interpret_mode()
+
+    dq = pl.pallas_call(
+        p.body(_bwd_dq_kernel), grid=(b * h, 2 * p.n_q),
+        in_specs=[p.seed_spec, p.block(p.bq, d), p.clean_side(d),
+                  p.clean_side(dvh), p.zero_spec, p.zero_spec, p.stat_q,
+                  p.stat_q, p.stat_q, p.block(p.bq, dvh), p.own(d),
+                  p.own(dvh)],
+        out_specs=p.block(p.bq, d),
+        out_shape=jax.ShapeDtypeStruct((b * h, 2 * lp, d), q.dtype),
+        interpret=interp,
+        name="flash_bd_bwd_dq",
+    )(seed, q3, k3, v3, zero, zero, *stats, do3, k3, v3)
+
+    # program g of the dK/dV kernel: clean k-block g mod n_k against the
+    # rows of copy g // n_k (the noisy copy first), and for the noisy rows
+    # the noisy k-block at the same positions
+    def copy_side(width):
+        return _whole_side((1, lp, width), p.single,
+                           lambda i, j: (i, j // n_k, 0))
+
+    stat_all = _whole_side((1, 1, lp), p.single,
+                           lambda i, j: (i, 0, j // n_k))
+
+    def clean_block(width):
+        return p.block(p.bk, width, lambda i, j: (i, n_k + j % n_k, 0))
+
+    def noisy_block(width):
+        return p.block(p.bk, width, lambda i, j: (i, j % n_k, 0))
+
+    parts = pl.pallas_call(
+        p.body(_bwd_dkv_kernel), grid=(b * h, 2 * n_k),
+        in_specs=[p.seed_spec, copy_side(d), clean_block(d),
+                  clean_block(dvh), p.zero_spec, p.zero_spec, stat_all,
+                  stat_all, stat_all, copy_side(dvh), noisy_block(d),
+                  noisy_block(dvh)],
+        out_specs=[p.block(p.bk, d), p.block(p.bk, dvh)] * 2,
+        out_shape=[jax.ShapeDtypeStruct((b * h, 2 * lp, d), k.dtype),
+                   jax.ShapeDtypeStruct((b * h, 2 * lp, dvh), v.dtype)] * 2,
+        interpret=interp,
+        name="flash_bd_bwd_dkv",
+    )(seed, q3, k3, v3, zero, zero, *stats, do3, k3, v3)
+
+    def both(clean_parts, noisy):
+        """[noisy copy's gradient ; the clean copy's, its two parts (from
+        the noisy and from the clean rows) added in float32]."""
+        two = clean_parts.reshape(b * h, 2, lp, -1).astype(jnp.float32)
+        clean = (two[:, 0] + two[:, 1]).astype(noisy.dtype)
+        return jnp.concatenate([noisy[:, :lp], clean], 1)
+
+    dk, dv = both(parts[0], parts[2]), both(parts[1], parts[3])
+    return tuple(_bd_unpad(x, b, h, length) for x in (dq, dk, dv))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash_bd(q, k, v, shift, scale, block_q, block_k):
+    return _bd_fwd_res(q, k, v, shift, scale, block_q, block_k)[0]
+
+
+def _bd_vjp_fwd(q, k, v, shift, scale, block_q, block_k):
+    out, mrow, lrow = _bd_fwd_res(q, k, v, shift, scale, block_q, block_k)
+    return out, (q, k, v, out, mrow, lrow)
+
+
+def _bd_vjp_bwd(shift, scale, block_q, block_k, res, g):
+    return _bd_bwd(*res, g, shift, scale, block_q, block_k)
+
+
+_flash_bd.defvjp(_bd_vjp_fwd, _bd_vjp_bwd)
+
+
 # Each kernel keeps the whole other side of one (batch, head) in VMEM (the
 # forward and dQ kernels K and V, the dK/dV kernel Q, dO and three rows of
-# statistics), beside its own blocks and a few float32 (BQ, BK) score
-# tiles, in the 16 MiB a kernel may use. What a tile costs beyond its
+# statistics; under the block-diffusion structure ONE copy's side, so
+# ``seq`` below is a copy's length), beside its own blocks and a few
+# float32 (BQ, BK) score tiles, in the 16 MiB a kernel may use. What a tile costs beyond its
 # products is paid once an inner iteration (the fill and drain of the MXU
 # and of the cross-lane reductions; about ten operations on (BQ, 1)
 # columns), so the largest tiles that fit are the fastest: alone on a v5e,
@@ -756,6 +1177,13 @@ _flash.defvjp(_fwd, _bwd)
 #   512 fits all three kernels, alone and inside the step. At 4 MiB and
 #   under one buffer only costs (23.5 -> 24.2 ms at 128 / 128; +12 % on
 #   the backward at BERT's seq 512, where every program has a new side).
+# * two copies of 8,192 x 128 under the block structure: a copy's side is
+#   the 4 MiB of the causal 8k call and takes its answer, 512 x 512 with
+#   two buffers (the noisy copy's block and the dK/dV kernel's second pair
+#   of results add 0.6 MiB); 512 x 1,024 asks 20.97 MiB and 1,024 x 512
+#   16.64. Alone on a v5e, forward / forward + backward ms (PERF.md section
+#   6, PR 33): 512 x 512 10.52 / 41.63, 256 x 512 10.92 / 44.80, 512 x 256
+#   16.03 / 50.69.
 _WHOLE_SIDE_BYTES = 4 * 1024 * 1024
 
 
@@ -780,7 +1208,8 @@ def _single_buffered(seq, d, dv, itemsize):
 
 def flash_attention(q, k, v, attn_mask=None, causal=False, scale=None,
                     block_q=512, block_k=1024, dropout_p=0.0,
-                    training=False, force=False, name=None):
+                    training=False, force=False, diffusion_block=None,
+                    name=None):
     """Framework op: flash attention over q, k (B, H, S, D) and v (B, H,
     S, DV); the result is (B, H, Sq, DV). The kernels take any head sizes:
     ``DV`` may differ from ``D`` (multi-head latent attention: 192 and
@@ -791,11 +1220,24 @@ def flash_attention(q, k, v, attn_mask=None, causal=False, scale=None,
     (non-broadcastable to (B,H,Sq,Sk)) fall back to plain sdpa with
     identical semantics. Off-TPU the op also falls back to sdpa (the
     interpret-mode kernel is emulator-speed) unless force=True (kernel
-    correctness tests). ``monitor`` counters ``flash_attention.
-    kernel_traced`` / ``flash_attention.xla_traced`` count the call sites
-    that traced each path; per kernel call site ``flash_attention.tiles``
-    and ``flash_attention.tiles_masked`` add the forward kernel's score
-    tiles and those of them that run the masked body, and
+    correctness tests).
+
+    ``diffusion_block`` (a power of two) gives the block-diffusion
+    structure in place of a mask: q, k and v hold TWO copies of a sequence
+    of ``S / 2`` positions, the noisy one in rows ``[0, S / 2)`` and the
+    clean one behind it, and a row attends as :func:`block_diffusion_mask`
+    says. The structure is a fact of the call, not an array: the kernels
+    hold the clean copy's side, walk only the tiles that hold an allowed
+    pair and mask only those the structure crosses; the portable path
+    builds the dense mask. No ``attn_mask``, ``causal`` or dropout beside
+    it.
+
+    ``monitor`` counters ``flash_attention.kernel_traced`` /
+    ``flash_attention.xla_traced`` count the call sites that traced each
+    path; per kernel call site ``flash_attention.tiles``, ``flash_attention.
+    tiles_masked`` and ``flash_attention.tiles_skipped`` add the forward
+    kernel's score tiles, those of them that run the masked body and the
+    tiles of the whole rectangle it does not walk, and
     ``flash_attention.native_operands_traced`` counts the call sites whose
     products take bfloat16 operands."""
     from ...dispatch import apply
@@ -805,10 +1247,20 @@ def flash_attention(q, k, v, attn_mask=None, causal=False, scale=None,
 
     b, h, sq, d = q.shape
     sk = k.shape[2]
-    block_q, block_k = _blocks_that_fit(max(sq, sk), d, v.shape[3],
-                                        q.dtype.itemsize, block_q, block_k)
     p_drop = float(dropout_p) if training else 0.0
     has_mask = attn_mask is not None
+    held = max(sq, sk)          # the side a program keeps whole
+    if diffusion_block is not None:
+        block = int(diffusion_block)
+        held = sq // 2
+        if has_mask or causal or p_drop or sq != sk or sq % 2 \
+                or block < 1 or block & (block - 1) or held % block:
+            raise ValueError(
+                f"flash_attention: diffusion_block={diffusion_block} takes "
+                f"two copies of whole blocks of a power of two along S "
+                f"(q {sq}, k {sk} rows) and no mask, causal or dropout")
+    block_q, block_k = _blocks_that_fit(held, d, v.shape[3],
+                                        q.dtype.itemsize, block_q, block_k)
     mode = _mask_mode(attn_mask.shape if has_mask else None, b, h, sq, sk)
     kernel = mode != "fallback" and (force or enabled(
         "flash_attention", seq_len=max(sq, sk)))
@@ -816,20 +1268,33 @@ def flash_attention(q, k, v, attn_mask=None, causal=False, scale=None,
                     else "flash_attention.xla_traced").inc()
     if not kernel:
         from ..nn_ops import scaled_dot_product_attention as sdpa
+        if diffusion_block is not None:
+            attn_mask = jnp.asarray(block_diffusion_mask(held, block))
         return sdpa(q, k, v, attn_mask=attn_mask, is_causal=causal,
                     scale=scale, dropout_p=p_drop, training=training)
-    # how often the kernels' two mechanisms engage, per call site: the
-    # forward kernel's score tiles and those of them that run the masked
-    # body; call sites whose products take bfloat16 operands
-    bq, bk = _clamped_blocks(block_q, block_k, sq, sk)
-    tiles, masked = _tile_counts(b * h, block_q=bq, block_k=bk, sq=sq, sk=sk,
-                                 causal=causal)
+    # how often the kernels' mechanisms engage, per call site: the forward
+    # kernel's score tiles, those of them that run the masked body, the
+    # tiles of the rectangle it leaves out; call sites whose products take
+    # bfloat16 operands
+    if diffusion_block is None:
+        bq, bk = _clamped_blocks(block_q, block_k, sq, sk)
+        tiles, masked = _tile_counts(b * h, block_q=bq, block_k=bk, sq=sq,
+                                     sk=sk, causal=causal)
+        whole = b * h * -(-sq // bq) * -(-sk // bk)
+    else:
+        shift = block.bit_length() - 1
+        bq, bk = _bd_blocks(block_q, block_k, held, shift)
+        tiles, masked, whole = _bd_tile_counts(
+            b * h, held, block_q=bq, block_k=bk, shift=shift)
     monitor.counter("flash_attention.tiles").inc(tiles)
     monitor.counter("flash_attention.tiles_masked").inc(masked)
+    monitor.counter("flash_attention.tiles_skipped").inc(whole - tiles)
     if q.dtype == k.dtype == v.dtype == jnp.bfloat16:
         monitor.counter("flash_attention.native_operands_traced").inc()
 
     def impl(q, k, v, *rest):
+        if diffusion_block is not None:
+            return _flash_bd(q, k, v, shift, scale, block_q, block_k)
         m = _canon_mask(rest[0]) if has_mask else None
         if p_drop > 0.0:
             raw = jnp.ravel(rest[-1])[:2]

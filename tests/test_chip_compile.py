@@ -268,6 +268,30 @@ def _kernel_eqns(jaxpr):
                     yield from _kernel_eqns(sub)
 
 
+# 2 x 8,192 x 128 under the block-diffusion structure, 32 heads: the
+# sdar_30b_a3b_chat cell's attention after its K/V heads are repeated. The
+# kernels hold ONE copy's side (4 MiB, the causal 8k call's) beside the
+# noisy copy's block at the program's own positions, so the same rule gives
+# the same 512 x 512; nothing of 16,384 x 16,384 exists, and the row
+# statistics stay one float32 a row
+def test_flash_block_diffusion_at_the_cells_size(one_chip, compiled_kernels):
+    from paddle_tpu.ops.pallas.flash_attention import (_blocks_that_fit,
+                                                       _flash_bd)
+    block_q, block_k = _blocks_that_fit(8192, 128, 128, 2, 512, 1024)
+    assert (block_q, block_k) == (512, 512)
+
+    def f(q, k, v):
+        return _flash_bd(q, k, v, 2, None, block_q, block_k)
+
+    qkv = ((1, 32, 16384, 128), jnp.bfloat16)
+    text = _compiled_text(_grad_sum(f, argnums=(0, 1, 2)), one_chip, qkv,
+                          qkv, qkv, names=("flash_bd_fwd", "flash_bd_bwd_dq",
+                                           "flash_bd_bwd_dkv"))
+    assert text.count("tpu_custom_call") == 3
+    assert not re.search(r"\[(\d+,)*16384,(\d+,)*16384[,\]]", text)
+    assert "f32[32,1,16384]" in text
+
+
 def test_flash_kernels_widen_no_tile_of_k_or_v(compiled_kernels):
     """What Mosaic is handed at the joyai cell's call (8,192 x 192 | 128,
     512 x 512 tiles): every product takes bfloat16 operands with a float32
@@ -585,7 +609,9 @@ KERNEL_NAMES = {
     "batch_norm.py": ["batch_norm_stats", "batch_norm_apply",
                       "batch_norm_bwd_reduce", "batch_norm_bwd_dx"],
     "causal_conv1d.py": ["conv1d_fwd", "conv1d_bwd"],
-    "flash_attention.py": ["flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"],
+    "flash_attention.py": ["flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
+                           "flash_bd_fwd", "flash_bd_bwd_dq",
+                           "flash_bd_bwd_dkv"],
     "gated_rms_norm.py": ["gated_norm_fwd", "gated_norm_bwd"],
     "layer_norm.py": ["layer_norm_fwd", "layer_norm_bwd"],
     "softmax_xent.py": ["softmax_xent_fwd", "softmax_xent_bwd"],
@@ -627,7 +653,7 @@ def test_no_pallas_call_site_is_left_out_and_no_name_is_used_twice():
     found = {f: names for f, names in found.items() if names}
     assert found == KERNEL_NAMES
     every = [n for names in found.values() for n in names]
-    assert len(every) == len(set(every)) == 17
+    assert len(every) == len(set(every)) == 20
 
 
 def test_every_registered_kernel_has_a_module_with_a_call_site():

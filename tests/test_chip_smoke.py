@@ -37,10 +37,12 @@ def test_phase_kernels_tiny_interpret(cs, capsys):
     cs.phase_kernels(rows=64, hidden=128, batch=2, heads=2, seq=128,
                      head_dim=32)
     out = _lines(capsys, "kernels")
-    # layer norm f32+bf16, flash x3 and at latent attention's head sizes,
-    # the scan, the convolution, the gated norm
-    assert len(out) == 9
+    # layer norm f32+bf16, flash x3, at latent attention's head sizes and
+    # under the block-diffusion structure, the scan, the convolution, the
+    # gated norm
+    assert len(out) == 10
     assert any("x96|64,bf16,causal" in l for l in out)
+    assert any("x64,bf16,block_diffusion" in l for l in out)
     assert any("padmask" in l for l in out)
     assert all("tpu_custom_calls=0" in l for l in out)   # interpreted
 
