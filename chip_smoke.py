@@ -101,10 +101,11 @@ def phase_kernels(rows, hidden, batch, heads, seq, head_dim,
 
     shipped = sorted(k for k, on in P._AUTO_ON.items() if on)
     if shipped != ["causal_conv1d", "flash_attention", "gated_rms_norm",
-                   "layer_norm", "ssd_scan"]:
+                   "layer_norm", "moe_scatter_add", "ssd_scan"]:
         raise AssertionError(f"_AUTO_ON ships {shipped}; this phase covers "
                              f"causal_conv1d, flash_attention, "
-                             f"gated_rms_norm, layer_norm and ssd_scan")
+                             f"gated_rms_norm, layer_norm, moe_scatter_add "
+                             f"and ssd_scan")
     on_chip = pt.device.is_tpu_backend()
     if on_chip and P.interpret_mode():
         raise AssertionError("interpret mode reachable on a TPU backend")
@@ -276,6 +277,34 @@ def phase_kernels(rows, hidden, batch, heads, seq, head_dim,
         lambda *a: (_rms_norm(*a[:3], epsilon=1e-5, num_groups=heads,
                               gated=True, scaled=True) * a[3]).sum(),
         norm_args, 3, tol_bf16, 2)
+
+    # routed experts over (rows, hidden) bf16, 4 held of 16, top-2, gated:
+    # the combine through the in-place scatter-add kernel, forward and dx
+    from paddle_tpu.ops import moe
+    width, held, k = hidden // 4, 4, 2
+    if not P.moe_scatter_add_mod.supported(
+            hidden, moe._ladder(rows, moe.MIN_ROWS)):
+        raise AssertionError("the scatter-add kernel would not take this "
+                             "shape")
+    moe_args = (
+        jnp.asarray(rng.randn(1, rows, hidden), jnp.bfloat16),
+        jnp.asarray(rng.uniform(0.1, 1.0, (1, rows, k)), jnp.float32),
+        *(jnp.asarray(0.05 * rng.randn(*shape), jnp.float32)
+          for shape in ((held, hidden, width), (held, width, hidden),
+                        (held, hidden, width))),
+        jnp.asarray(rng.randn(1, rows, hidden), jnp.float32),
+        jnp.asarray(np.argsort(rng.rand(1, rows, 16))[..., :k], jnp.int32))
+
+    def experts(kernel, dot_dtype):
+        def loss(x, weights, up, down, gate, ct, chosen):
+            y, _ = moe._routed(x, chosen, weights, up, down, gate, first=0,
+                               dot_dtype=dot_dtype, kernel=kernel)
+            return (y.astype(jnp.float32) * ct).sum()
+        return loss
+
+    run(f"moe_scatter_add[{rows}x{hidden},{held}x{width}gated,bf16]",
+        experts(True, jnp.bfloat16), experts(False, jnp.float32), moe_args,
+        5, tol_bf16, 2)
 
 
 # ---------------------------------------------------------------------------

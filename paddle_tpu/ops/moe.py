@@ -28,6 +28,7 @@ impl through ``dispatch.apply``:
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -68,7 +69,12 @@ def moe_route(x, router_weight, bias=None, top_k=1, scale=1.0,
         ranked = s if not b else s + jax.lax.stop_gradient(
             b[0].astype(jnp.float32))
         _, experts = jax.lax.top_k(ranked, top_k)
-        picked = jnp.take_along_axis(s, experts, -1)
+        # s at the chosen, as a select over the expert axis: the values of
+        # take_along_axis to the bit, and a backward pass that is a dense
+        # broadcast where the gather's scatters one element at a time
+        picked = jnp.sum(jnp.where(
+            experts[..., None] == jnp.arange(s.shape[-1]),
+            s[..., None, :], 0.0), -1)
         weights = scale * picked / (jnp.sum(picked, -1, keepdims=True)
                                     + 1e-20)
         return weights, experts.astype(jnp.int32)
@@ -104,12 +110,31 @@ def _on_rung(rung, ladder, run, *carry):
                       *carry)
 
 
-def _grouped_rows(rows, gate, ups, w_down, order, rung, ladder, dot_dtype):
+def _accumulator(rows, kernel):
+    """The scan's float32 sum over the experts, and the function that adds
+    ``out`` to its rows ``at``: XLA's scatter-add into ``[tokens, d]``, or
+    the in-place kernel over ``[tokens, 1, d]`` (``ops/pallas/
+    moe_scatter_add.py``)."""
+    tokens, d = rows.shape
+    if not kernel:
+        return (jnp.zeros((tokens, d), jnp.float32),
+                lambda acc, at, out: acc.at[at].add(out,
+                                                    unique_indices=True))
+    from . import pallas
+    add = functools.partial(pallas.moe_scatter_add_mod.scatter_add,
+                            interpret=pallas.interpret_mode())
+    return jnp.zeros((tokens, 1, d), jnp.float32), add
+
+
+def _grouped_rows(rows, gate, ups, w_down, order, rung, ladder, dot_dtype,
+                  kernel):
     """``y[t] = sum_e gate[e, t] W_down[e] h_e(rows[t])`` over the first
     ``ladder[rung[e]]`` tokens of ``order[e]``; ``gate`` is 0 for every
     token after an expert's own. ``ups`` is ``(w_up,)`` for ``h = relu(W_up
     x)^2`` and ``(w_gate, w_up)`` for the gated ``h = silu(W_gate x) *
     (W_up x)``. float32 [tokens, d]."""
+    y, add_rows = _accumulator(rows, kernel)
+
     def expert(y, xs):
         ups, down, ids, g, r = xs
 
@@ -125,26 +150,28 @@ def _grouped_rows(rows, gate, ups, w_down, order, rung, ladder, dot_dtype):
                 h = jax.nn.silu(a) * u * g[at][:, None]
             out = _dot(h.astype(dot_dtype), down.astype(dot_dtype),
                        ((1,), (0,)))
-            return y.at[at].add(out, unique_indices=True)
+            return add_rows(y, at, out)
 
         return _on_rung(r, ladder, run, y), None
 
-    y = jnp.zeros(rows.shape, jnp.float32)
-    return lax.scan(expert, y, (ups, w_down, order, gate, rung))[0]
+    y = lax.scan(expert, y, (ups, w_down, order, gate, rung))[0]
+    return y.reshape(rows.shape)
 
 
-_grouped = jax.custom_vjp(_grouped_rows, nondiff_argnums=(6, 7))
+_grouped = jax.custom_vjp(_grouped_rows, nondiff_argnums=(6, 7, 8))
 
 
-def _grouped_fwd(rows, gate, ups, w_down, order, rung, ladder, dot_dtype):
+def _grouped_fwd(rows, gate, ups, w_down, order, rung, ladder, dot_dtype,
+                 kernel):
     y = _grouped_rows(rows, gate, ups, w_down, order, rung, ladder,
-                      dot_dtype)
+                      dot_dtype, kernel)
     return y, (rows, gate, ups, w_down, order, rung)
 
 
-def _grouped_bwd(ladder, dot_dtype, saved, dy):
+def _grouped_bwd(ladder, dot_dtype, kernel, saved, dy):
     rows, gate, ups, w_down, order, rung = saved
     f32 = jnp.float32
+    dx, add_rows = _accumulator(rows, kernel)
 
     def expert(dx, xs):
         ups, down, ids, g, r = xs
@@ -162,8 +189,7 @@ def _grouped_bwd(ladder, dot_dtype, saved, dy):
                     jnp.sum(d_h * h, -1), unique_indices=True)
                 d_pre = (d_h * (2.0 * ga) * act).astype(dot_dtype)
                 d_up = _dot(x, d_pre, ((0,), (0,)))
-                dx = dx.at[at].add(_dot(d_pre, upc, ((1,), (1,))),
-                                   unique_indices=True)
+                dx = add_rows(dx, at, _dot(d_pre, upc, ((1,), (1,))))
                 return dx, (d_up,), d_down, d_gate
             gatec, upc = (w.astype(dot_dtype) for w in ups)
             downc = down.astype(dot_dtype)
@@ -179,9 +205,8 @@ def _grouped_bwd(ladder, dot_dtype, saved, dy):
             d_h = d_h * ga
             d_a = (d_h * u * (sig + act * (1.0 - sig))).astype(dot_dtype)
             d_u = (d_h * act).astype(dot_dtype)
-            dx = dx.at[at].add(_dot(d_a, gatec, ((1,), (1,)))
-                               + _dot(d_u, upc, ((1,), (1,))),
-                               unique_indices=True)
+            dx = add_rows(dx, at, _dot(d_a, gatec, ((1,), (1,)))
+                          + _dot(d_u, upc, ((1,), (1,))))
             return (dx, (_dot(x, d_a, ((0,), (0,))),
                          _dot(x, d_u, ((0,), (0,)))), d_down, d_gate)
 
@@ -189,8 +214,8 @@ def _grouped_bwd(ladder, dot_dtype, saved, dy):
         return dx, (d_ups, d_down, d_gate)
 
     dx, (d_ups, d_down, d_gate) = lax.scan(
-        expert, jnp.zeros(rows.shape, f32), (ups, w_down, order, gate, rung))
-    return (dx.astype(rows.dtype), d_gate,
+        expert, dx, (ups, w_down, order, gate, rung))
+    return (dx.reshape(rows.shape).astype(rows.dtype), d_gate,
             tuple(d.astype(w.dtype) for d, w in zip(d_ups, ups)),
             d_down.astype(w_down.dtype), None, None)
 
@@ -199,7 +224,7 @@ _grouped.defvjp(_grouped_fwd, _grouped_bwd)
 
 
 def _routed(x, experts, weights, w_up, w_down, w_gate=None, *, first,
-            dot_dtype):
+            dot_dtype, kernel=False):
     f32 = jnp.float32
     lead, d = x.shape[:-1], x.shape[-1]
     rows = x.reshape(-1, d)
@@ -219,7 +244,7 @@ def _routed(x, experts, weights, w_up, w_down, w_gate=None, *, first,
     rung = jnp.minimum(rung, len(ladder) - 1).astype(jnp.int32)
     ups = (w_up,) if w_gate is None else (w_gate, w_up)
     y = _grouped(rows.astype(dot_dtype), gate, ups, w_down, order, rung,
-                 ladder, dot_dtype)
+                 ladder, dot_dtype, kernel)
     computed = jnp.asarray(ladder, jnp.int32)[rung]
     stats = jnp.stack([jnp.sum(sizes),
                        jnp.sum(jnp.maximum(sizes - computed, 0)),
@@ -243,13 +268,29 @@ def moe_experts(x, experts, weights, w_up, w_down, first_expert=0,
     hold (0: the last rung holds every token); the fullest expert's rows;
     1; and the rows the products ran over, padding included (the ladder
     starts at :data:`MIN_ROWS`). Under ``amp.auto_cast`` the products
-    take the compute dtype's operands and accumulate in float32."""
-    from .. import amp
+    take the compute dtype's operands and accumulate in float32.
+
+    On one TPU an expert's rows are added to the sum in place, by the
+    kernel of ``ops/pallas/moe_scatter_add.py``; on the CPU, under a mesh
+    and at a width that is no whole 128-lane tile, by XLA's scatter-add.
+    The counters ``moe_experts.kernel_traced`` / ``moe_experts.xla_traced``
+    say which a call site traced."""
+    from .. import amp, monitor
+    from . import pallas
     dot_dtype = amp.compute_dtype() if amp.is_enabled() else None
+    # read off the call, as ssd_scan: the kernel where its tiles fit every
+    # rung of this call's ladder and the registry has it on
+    tokens = math.prod(x.shape[:-1])
+    kernel = (pallas.enabled("moe_scatter_add")
+              and pallas.moe_scatter_add_mod.supported(
+                  int(x.shape[-1]), _ladder(tokens, MIN_ROWS)))
+    monitor.counter("moe_experts.kernel_traced" if kernel
+                    else "moe_experts.xla_traced").inc()
 
     def impl(x, experts, weights, w_up, w_down, *gate, first):
         return _routed(x, experts, weights, w_up, w_down, *gate, first=first,
-                       dot_dtype=dot_dtype or jnp.result_type(x))
+                       dot_dtype=dot_dtype or jnp.result_type(x),
+                       kernel=kernel)
 
     args = (x, experts, weights, w_up, w_down)
     with _pscope("F.moe_experts"):

@@ -31,7 +31,8 @@ def on_tpu():
 # Per-kernel default overrides: None = auto.
 _overrides = {}
 _KERNELS = ("layer_norm", "flash_attention", "softmax_xent", "batch_norm",
-            "ssd_scan", "causal_conv1d", "gated_rms_norm")
+            "ssd_scan", "causal_conv1d", "gated_rms_norm",
+            "moe_scatter_add")
 
 # Auto defaults from one builder-run v5e ablation (2026-07-31, superseded
 # toolchain, not reproduced — docs/performance.md carries the table):
@@ -65,9 +66,20 @@ _KERNELS = ("layer_norm", "flash_attention", "softmax_xent", "batch_norm",
 # of two shared-seed pairs). Each op looks at the call as ssd_scan does
 # (causal_conv1d.supported, gated_rms_norm.supported; an F.rms_norm
 # without a gate never asks).
+# moe_scatter_add: on, measured on the same v5e (PERF.md section 6, PR
+# 38). Alone at the sdar cell's size (16 held gated experts of width 768
+# over 16,384 x 2,048 bfloat16 rows) F.moe_experts takes 4.6 ms forward
+# and 11.0 forward + backward where XLA's scatter-add, which copies the
+# float32 accumulator whole once an expert, took 7.6 and 17.2; in
+# sdar_30b_a3b_chat.block_diffusion_8k the expert layers fell from 120.4
+# to 74.8 ms of a step and the step from 562.0 to 516.2 ms. F.moe_experts
+# looks at the call (moe_scatter_add.supported: d whole 128-lane tiles, a
+# row tile for every rung); the kernel is a module-level jax.jit, so a
+# step lowers it once a rung, not once a call site.
 _AUTO_ON = {"layer_norm": True, "flash_attention": True,
             "softmax_xent": False, "batch_norm": False, "ssd_scan": True,
-            "causal_conv1d": True, "gated_rms_norm": True}
+            "causal_conv1d": True, "gated_rms_norm": True,
+            "moe_scatter_add": True}
 
 
 # flash is an O(S^2)-score win: below some sequence length the XLA sdpa
@@ -116,7 +128,8 @@ def configure(flash_min_seq=_UNSET, **kernels):
     """configure(layer_norm=False, softmax_xent=None, ...) — override the
     auto default for named kernels ('layer_norm', 'flash_attention',
     'softmax_xent', 'batch_norm', 'ssd_scan', 'causal_conv1d',
-    'gated_rms_norm'); any other name raises ValueError. None restores auto.
+    'gated_rms_norm', 'moe_scatter_add'); any other name raises
+    ValueError. None restores auto.
     flash_min_seq=N routes sequences shorter than N to XLA sdpa even
     with the flash kernel enabled (N=0 disables the gate);
     flash_min_seq=None restores the measured default crossover,
@@ -161,6 +174,7 @@ from . import batch_norm as batch_norm_mod
 from . import ssd_scan as ssd_scan_mod
 from . import causal_conv1d as causal_conv1d_mod
 from . import gated_rms_norm as gated_rms_norm_mod
+from . import moe_scatter_add as moe_scatter_add_mod
 
 from .layer_norm import layer_norm
 from .softmax_xent import softmax_cross_entropy

@@ -352,6 +352,37 @@ def test_grouped_experts_fwd_bwd_at_the_cells_size(one_chip):
     assert "tpu_custom_call" not in text
 
 
+@pytest.mark.parametrize("tokens,d,f,held,k,gated", [
+    (8192, 2688, 1856, 8, 6, False), (16384, 2048, 768, 16, 8, True)],
+    ids=["nemotron", "sdar"])
+def test_grouped_experts_combine_in_place_at_the_cells_sizes(
+        one_chip, compiled_kernels, tokens, d, f, held, k, gated):
+    """The same with the scatter-add kernel (PR 38): one call a rung in
+    each conditional, the float32 accumulator ``[tokens, 1, d]`` laid out
+    row by row, and no branch that copies it whole."""
+    from paddle_tpu.ops import moe
+
+    def loss(x, weights, experts, *ws):
+        y, _ = moe._routed(x, experts, weights, *ws, first=0,
+                           dot_dtype=jnp.bfloat16, kernel=True)
+        return jnp.sum(jnp.square(y.astype(jnp.float32)))
+
+    n = 2 + gated
+    text = _compiled_text(
+        jax.grad(loss, argnums=(0, 1) + tuple(range(3, 3 + n))), one_chip,
+        ((1, tokens, d), jnp.bfloat16), ((1, tokens, k), jnp.float32),
+        ((1, tokens, k), jnp.int32), ((held, d, f), jnp.float32),
+        ((held, f, d), jnp.float32), *[((held, d, f), jnp.float32)] * gated,
+        names=("moe_scatter_add",))
+    rungs = len(moe._ladder(tokens, moe.MIN_ROWS))
+    assert text.count(" conditional(") == 2
+    assert text.count('custom_call_target="tpu_custom_call"') == 2 * rungs
+    assert f"f32[{tokens},1,{d}]{{2,1,0:T(1,128)}}" in text
+    whole = re.findall(r"= f32\[%d,(?:1,)?%d\]\S* (?:copy|fusion)\("
+                       % (tokens, d), text)
+    assert len(whole) <= 4, whole     # zeros and the way out, not a branch
+
+
 # -- the nemotron cell's Mamba-2 scan: a kernel pair ------------------------
 
 def test_ssd_scan_kernels_keep_chunk_sized_arrays_in_vmem(one_chip,
@@ -614,6 +645,7 @@ KERNEL_NAMES = {
                            "flash_bd_bwd_dkv"],
     "gated_rms_norm.py": ["gated_norm_fwd", "gated_norm_bwd"],
     "layer_norm.py": ["layer_norm_fwd", "layer_norm_bwd"],
+    "moe_scatter_add.py": ["moe_scatter_add"],
     "softmax_xent.py": ["softmax_xent_fwd", "softmax_xent_bwd"],
     "ssd_scan.py": ["ssd_fwd", "ssd_bwd"],
 }
@@ -653,7 +685,7 @@ def test_no_pallas_call_site_is_left_out_and_no_name_is_used_twice():
     found = {f: names for f, names in found.items() if names}
     assert found == KERNEL_NAMES
     every = [n for names in found.values() for n in names]
-    assert len(every) == len(set(every)) == 20
+    assert len(every) == len(set(every)) == 21
 
 
 def test_every_registered_kernel_has_a_module_with_a_call_site():
