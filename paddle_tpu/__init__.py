@@ -10,6 +10,9 @@ input pipeline.
 Top-level API mirrors the reference's `paddle` / `paddle.fluid` surface:
 Tensor, nn.Layer, optimizers, static Program/Executor, fleet, io.
 """
+from time import perf_counter as _perf_counter
+_t_import = _perf_counter()
+
 __version__ = "0.1.0"
 
 from .tensor import (Tensor, Parameter, to_tensor, set_default_dtype,
@@ -143,3 +146,7 @@ from . import incubate  # noqa: E402
 
 from . import framework  # noqa: E402
 from . import imperative  # noqa: E402
+
+# what `import paddle_tpu` cost this process (the package's own imports
+# and jax's, where this import was the first to ask for it)
+monitor.gauge("runtime.import_s").set(_perf_counter() - _t_import)

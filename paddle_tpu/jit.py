@@ -201,8 +201,8 @@ class StaticFunction:
     def __call__(self, *args, **kwargs):
         # jit.<fn> is the parent of the step path's three spans: collect
         # (everything before the executable is called), execute (the
-        # call), writeback (state and outputs); a compile sits between
-        # the first two under its own spans
+        # call), writeback (state and outputs); a first call's trace,
+        # lowering and compile sit between them under jit.aot_capture
         with _monitor.trace.span(f"jit.{getattr(self, '__name__', 'fn')}"):
             return self._call(args, kwargs)
 
@@ -287,15 +287,15 @@ class StaticFunction:
             span = max((len(sh.device_set) for sh in (
                 getattr(a, "sharding", None) for a in state_vals + arrays)
                 if sh is not None), default=1)
-            with _monitor.trace.span(f"jit.compile.{fn_label}"):
-                self._cache[key] = self._make_entry(
-                    treedef, arr_idx, statics, state_names, span)
+            self._cache[key] = self._make_entry(
+                treedef, arr_idx, statics, state_names, span)
         entry = self._cache[key]
 
         if is_new and _monitor.enabled():
             # AOT the fresh entry (the compile the first call pays
-            # anyway) so monitor.xla records its measured flops/bytes;
-            # any failure keeps the original jitted callable
+            # anyway) so monitor.xla records its measured flops/bytes and,
+            # under xla.trace / xla.lower / xla.backend_compile, where the
+            # first call's time went; any failure keeps the jitted callable
             import time as _time
             _t0_compile = _time.perf_counter()
             with _monitor.trace.span("jit.aot_capture", fn=fn_label):

@@ -136,7 +136,16 @@ Span tracing & XLA-measured cost (PR 4's additions):
   compiled executables as ``xla.flops.<label>`` /
   ``xla.bytes_accessed.<label>`` / ``xla.peak_memory.<label>`` gauges
   plus ``xla_cost`` JSONL records; feeds the measured-MFU columns in
-  StepMonitor.
+  StepMonitor. A compiled step's first call runs under the spans
+  ``xla.trace`` / ``xla.lower`` / ``xla.backend_compile``, whose
+  durations, the cache's verdict and the step's Pallas instances stand
+  in the same record (``monitor.xla.get(label)``)
+* ``xla.programs.{cache_hits,cache_misses,backend_s,cache_retrieval_s,
+  lower_s}`` / ``monitor.xla.programs()`` — every program the process
+  compiled or loaded while the monitor was on (one ``jax.monitoring``
+  listener, registered by ``enable()`` and taken out by ``disable()``)
+* ``runtime.import_s`` (gauge) — what ``import paddle_tpu`` cost this
+  process
 
 Memory-observability series (docs/observability.md "Memory
 attribution & budget"; ``paddle_tpu.monitor.memory``):
@@ -262,6 +271,7 @@ def enable(path=None, time_dispatch=None, max_bytes=None,
             _sink = JsonlSink(fp, max_bytes=max_bytes)
     _enabled = True
     trace.note_monitor(True)
+    xla.listen(True)
 
     telemetry_target = telemetry_dir or os.environ.get(
         "PADDLE_TPU_TELEMETRY_DIR")
@@ -302,6 +312,7 @@ def disable(flush_counters=True):
     fleet.stop_server()
     _enabled = False
     trace.note_monitor(False)
+    xla.listen(False)
     if _sink is not None:
         _sink.close()
         _sink = None

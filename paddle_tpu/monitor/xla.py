@@ -17,28 +17,51 @@ compiler's own count doesn't).
 
 Capture is free-riding, not double-compiling: :func:`aot_capture`
 replaces a ``jax.jit`` callable with its AOT-compiled form
-(``.lower(*args).compile()`` — the one compile the first call would
-have paid anyway), records the analysis, and falls back to the
+(``.trace(*args).lower().compile()`` — the one compile the first call
+would have paid anyway), records the analysis, and falls back to the
 original callable on ANY failure, so instrumentation can never break a
 step. ``Executor.run``/``warmup`` and ``jit.to_static`` call it on
 their cache-miss paths when the monitor is enabled.
+
+Where a first call's time goes: the three stages run under the spans
+``xla.trace`` (the step function under JAX's tracer), ``xla.lower``
+(jaxpr -> StableHLO, every Pallas instance to Mosaic) and
+``xla.backend_compile`` (the cache key, then XLA's compile or the
+persistent cache's load), and their durations stand in the executable's
+record (``trace_s``, ``lower_s``, ``backend_s``) beside the cache's
+verdict (``cache_hit``, ``cache_retrieval_s``) and the Pallas instances
+the step holds (``pallas_instances``, ``pallas_traces``). While the
+monitor is on, one ``jax.monitoring`` listener also keeps every program
+the process compiled or loaded (:func:`programs`, counters
+``xla.programs.*``): it fires only when JAX traces, lowers, compiles or
+loads a program, never in a steady step.
 """
 from __future__ import annotations
 
+import collections
 import threading
+import time
 
 __all__ = [
     "analyze", "capture", "aot_capture", "get", "flops",
     "bytes_accessed", "peak_memory", "labels", "last", "hlo_text",
-    "executable", "measured_mfu", "reset",
+    "executable", "measured_mfu", "reset", "programs", "count_pallas",
 ]
 
 MAX_ENTRIES = 64
+MAX_PROGRAMS = 256
+
+_CLOCK = time.perf_counter
 
 _lock = threading.Lock()
 _entries = {}       # label -> analysis dict
 _execs = {}         # label -> the Compiled object (for HLO dumps)
 _order = []         # labels, oldest first (insertion/refresh order)
+_programs = collections.deque(maxlen=MAX_PROGRAMS)  # newest last
+_listening = False
+# the cache's verdict on the program this thread is compiling: JAX says
+# hit or miss before it reports the backend compile's duration
+_pending = threading.local()
 
 
 def analyze(compiled):
@@ -84,13 +107,16 @@ def analyze(compiled):
     return info
 
 
-def capture(label, compiled):
+def capture(label, compiled, **stages):
     """Analyze + store under ``label`` (newest entry becomes
     :func:`last`), set the ``xla.*`` gauges and emit one ``xla_cost``
-    JSONL record when the monitor is enabled. Returns the analysis dict
-    (may be empty on exotic backends)."""
+    JSONL record when the monitor is enabled. ``stages`` are what
+    :func:`aot_capture` saw of the first call (``trace_s`` ...), kept in
+    the same record. Returns the analysis dict (may hold the stages
+    alone on exotic backends)."""
     label = str(label)
     info = analyze(compiled)
+    info.update(stages)
     with _lock:
         if label in _order:
             _order.remove(label)
@@ -112,24 +138,175 @@ def capture(label, compiled):
     return info
 
 
+def _stage(name, label, call):
+    """One stage of a first call under its span ``xla.<name>``: (what
+    ``call`` returned, its seconds). A stage that raises says so on the
+    span's args."""
+    from . import trace
+    span = trace.span(f"xla.{name}", label=label)
+    with span:
+        t0 = _CLOCK()
+        try:
+            return call(), _CLOCK() - t0
+        except Exception:
+            if getattr(span, "args", None) is not None:
+                span.args["failed"] = name
+            raise
+
+
 def aot_capture(fn, label, args):
     """AOT-compile ``fn`` at ``args`` (a tuple of the exact call
-    arguments — lowering does NOT execute them), capture the analysis,
-    and return the Compiled callable; an already-compiled object is
-    captured in place. Any failure returns ``fn`` untouched — the
-    caller keeps its working jitted entry."""
+    arguments — lowering does NOT execute them) in three timed stages,
+    capture the analysis with them, and return the Compiled callable; an
+    already-compiled object is captured in place. Any failure returns
+    ``fn`` untouched — the caller keeps its working jitted entry — and
+    leaves one ``xla_capture_failed`` record that names the stage."""
+    stage = "capture"
     try:
         if hasattr(fn, "cost_analysis"):       # already AOT-compiled
             capture(label, fn)
             return fn
-        compiled = fn.lower(*args).compile()
-        capture(label, compiled)
+        stage = "trace"
+        traced, trace_s = _stage(stage, label, lambda: fn.trace(*args))
+        instances, traces = count_pallas(traced.jaxpr)
+        stage = "lower"
+        lowered, lower_s = _stage(stage, label, traced.lower)
+        stage = "backend_compile"
+        _pending.program = None
+        compiled, backend_s = _stage(stage, label, lowered.compile)
+        # the listener's record of that compile, made on this thread
+        # inside lowered.compile(); there is none while the monitor is off
+        program = getattr(_pending, "program", None) or {}
+        stage = "capture"
+        from . import registry
+        capture(label, compiled, trace_s=trace_s, lower_s=lower_s,
+                backend_s=backend_s, pallas_instances=instances,
+                pallas_traces=traces,
+                cache_hit=program.get("cache_hit"),
+                cache_retrieval_s=program.get("cache_retrieval_s"),
+                at_step_calls=_step_calls(registry()))
         return compiled
-    except Exception:
-        from . import counter, enabled
+    except Exception as e:
+        from . import counter, emit, enabled
         if enabled():
             counter("xla.capture_failed").inc()
+            emit(kind="xla_capture_failed", label=str(label), stage=stage,
+                 error=repr(e))
         return fn
+
+
+def count_pallas(jaxpr):
+    """(instances, traces) of a step's jaxpr: the ``pallas_call``
+    equations that will each be lowered to Mosaic once, and the distinct
+    kernel bodies among them (each traced once). The walk enters every
+    sub-jaxpr an equation holds (cond branches, scan and while bodies,
+    ``checkpoint``, custom-vjp) and a jaxpr that several equations share
+    through an inner ``jax.jit`` once, as JAX lowers it once a module; a
+    kernel's own body is not entered."""
+    instances, bodies, shared = 0, set(), set()
+    todo = [getattr(jaxpr, "jaxpr", jaxpr)]
+    while todo:
+        for eqn in todo.pop().eqns:
+            name = eqn.primitive.name
+            if name == "pallas_call":
+                instances += 1
+                bodies.add(id(eqn.params.get("jaxpr")))
+                continue
+            for value in eqn.params.values():
+                for sub in value if isinstance(value, (tuple, list)) \
+                        else (value,):
+                    inner = getattr(sub, "jaxpr", sub)
+                    if not hasattr(inner, "eqns"):
+                        continue
+                    if name == "jit":
+                        # JAX's key for the one lowering a module
+                        key = (eqn.params.get("name"), id(sub))
+                        if key in shared:
+                            continue
+                        shared.add(key)
+                    todo.append(inner)
+    return instances, len(bodies)
+
+
+# ---------------------------------------------------------------------------
+# every program the process compiled or loaded (jax.monitoring)
+
+_LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_BACKEND_EVENT = "/jax/core/compile/backend_compile_duration"
+_RETRIEVAL_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+_MISS_EVENT = "/jax/compilation_cache/cache_misses"
+
+
+def _step_calls(registry):
+    """How many compiled-step calls the process has made."""
+    return int(registry.value("jit.compile", 0)) + \
+        int(registry.value("jit.cache_hit", 0))
+
+
+def _on_event(event, **kwargs):
+    if event == _HIT_EVENT or event == _MISS_EVENT:
+        from . import counter
+        _pending.cache_hit = event == _HIT_EVENT
+        counter("xla.programs.cache_hits" if _pending.cache_hit
+                else "xla.programs.cache_misses").inc()
+
+
+def _on_duration(event, duration, **kwargs):
+    # JAX reports every traced function here, thousands a step: the
+    # three events kept are told from the rest before anything else
+    if event == _LOWER_EVENT:
+        from . import counter
+        counter("xla.programs.lower_s").inc(duration)
+    elif event == _RETRIEVAL_EVENT:
+        from . import counter
+        counter("xla.programs.cache_retrieval_s").inc(duration)
+        _pending.retrieval_s = duration
+    elif event == _BACKEND_EVENT:
+        from . import counter, registry
+        counter("xla.programs.backend_s").inc(duration)
+        record = {"fun_name": kwargs.get("fun_name"),
+                  "backend_s": duration,
+                  "cache_hit": getattr(_pending, "cache_hit", None),
+                  "cache_retrieval_s": getattr(_pending, "retrieval_s",
+                                               None),
+                  "at_step_calls": _step_calls(registry())}
+        _pending.cache_hit = _pending.retrieval_s = None
+        _pending.program = record
+        _programs.append(record)
+
+
+def listen(on):
+    """``monitor.enable()`` / ``monitor.disable()`` register and
+    unregister the one ``jax.monitoring`` listener pair here; a process
+    that never enabled the monitor registered nothing."""
+    global _listening
+    if bool(on) == _listening:
+        return
+    from jax import monitoring
+    if on:
+        monitoring.register_event_listener(_on_event)
+        monitoring.register_event_duration_secs_listener(_on_duration)
+    else:
+        for unregister, callback in (
+                (monitoring.unregister_event_listener, _on_event),
+                (monitoring.unregister_event_duration_listener,
+                 _on_duration)):
+            try:
+                unregister(callback)
+            except (AssertionError, ValueError):
+                pass    # someone cleared JAX's lists: nothing to take out
+    _listening = bool(on)
+
+
+def programs():
+    """One record a backend compile the process made while the monitor
+    was on, oldest first, the newest ``MAX_PROGRAMS``: ``fun_name``,
+    ``backend_s`` (XLA's compile, or the cache key and the load),
+    ``cache_hit`` (None where no persistent cache is configured),
+    ``cache_retrieval_s`` and ``at_step_calls``, the compiled-step calls
+    (``jit.compile`` + ``jit.cache_hit``) the process had made by then."""
+    return [dict(r) for r in list(_programs)]
 
 
 def get(label=None):
@@ -221,3 +398,4 @@ def reset():
         _entries.clear()
         _execs.clear()
         _order.clear()
+    _programs.clear()
