@@ -87,10 +87,10 @@ _AUTO_ON = {"layer_norm": True, "flash_attention": True,
 # crossover (512; 0 = flash whenever enabled) was set on a superseded
 # toolchain and has not been re-derived: no cell runs both paths at one
 # length. What the benchmark measures on the v5e today (PERF.md §5-6,
-# PR 26): at seq 512 (bert_base.pretrain_seq512, 16 x 12 heads x 512 x 64)
-# the three flash kernels take 8.98 ms of a 66.3 ms step, 30.6 % of their
+# PR 40): at seq 512 (bert_base.pretrain_seq512, 16 x 12 heads x 512 x 64)
+# the two flash kernels take 6.64 ms of a 64.0 ms step, 41.4 % of their
 # roofline; at seq 128 the gate sends attention to sdpa (attention core
-# 4.3 ms of 56.3). Whether sdpa would beat 8.98 ms at 512, or flash 4.3 ms
+# 4.3 ms of 56.3). Whether sdpa would beat 6.64 ms at 512, or flash 4.3 ms
 # at 128, is unmeasured.
 _FLASH_MIN_SEQ_DEFAULT = 512
 _flash_min_seq = _FLASH_MIN_SEQ_DEFAULT

@@ -22,31 +22,41 @@ copy's. A clean row sees the clean blocks up to its own, a noisy row the
 clean blocks before its own and its own noisy block: of the (2L)^2 score
 tiles n (n + 1) + n hold an allowed pair (n = L / tile; 288 of 1,024 a
 head at 512 x 512 and L = 8,192) and 3 n of them are crossed by the
-structure. The same three kernel bodies take it (``bd=`` in their static
+structure. The same two kernel bodies take it (``bd=`` in their static
 parameters; without it they trace what they traced before; under it the
-calls are named ``flash_bd_fwd``, ``flash_bd_bwd_dq``, ``flash_bd_bwd_dkv``): the side a
-program keeps whole is the CLEAN copy's L rows — what any row sees of the
-noisy copy is its own block, which arrives as one more blocked operand at
-the program's own positions — so 2L rows cost the VMEM of L; the forward
-and dQ grids run over both copies' q-blocks, and the dK/dV grid runs each
-clean k-block twice, once against each copy's rows (the two parts are
-added outside), the noisy rows' program taking the noisy k-block at the
-same positions with it. Positions inside a crossed tile come from iotas
-and a shift by log2 B, as the causal diagonal's. A copy is padded to whole
-tiles with zero rows; L is whole diffusion blocks, so the structure itself
-keeps every true row off the padding.
+calls are named ``flash_bd_fwd`` and ``flash_bd_bwd``): the side a
+program keeps whole is ONE copy's L rows — the forward keeps the clean
+copy's keys, and what any row sees of the noisy copy is its own block,
+which arrives as one more blocked operand at the program's own positions —
+so 2L rows cost the VMEM of L; the forward grid runs over both copies'
+q-blocks, and the backward grid runs each clean k-block twice, once
+against each copy's rows (the two parts of dK and dV are added outside;
+dQ and its accumulator follow the copy), the noisy rows' program taking
+the noisy k-block at the same positions with it. Positions inside a
+crossed tile come from iotas and a shift by log2 B, as the causal
+diagonal's. A copy is padded to whole tiles with zero rows; L is whole
+diffusion blocks, so the structure itself keeps every true row off the
+padding.
 
-Backward: two kernels. dQ: grid (bh, q-blocks) loops k-blocks; dK/dV:
-grid (bh, k-blocks) loops q-blocks over the TRANSPOSED score tile
-s^T = K Q^T, accumulating dv = pd^T @ dO and dk = ds^T @ Q with no
-transpose of a tile. Both recompute p = exp(s - m) / l from the saved PER-ROW
-(max m, normalizer l) — deliberately NOT the folded lse = m + log l: with
-a finite large-negative additive mask (the -1e9 convention) s and m are
-~1e9-scale where f32 ulp is 64, so s − m reproduces the forward's (and
-sdpa's) rounding exactly while s − (m + log l) would silently lose the
-entire log-normalizer. delta = rowsum(dO∘O) is one cheap XLA reduction
-outside the kernels (the identity Σ_k p_k·dp_k = rowsum(dO∘O) holds under
-dropout too).
+Backward: ONE kernel (PR 40; two until then, which made every score tile
+twice: seven products a tile for the five the gradients need). Grid
+(bh, k-blocks), the second axis sequential: a program owns a k-block and
+loops q-blocks over the TRANSPOSED score tile s^T = K Q^T, accumulating
+dv = pd^T @ dO and dk = ds^T @ Q in its carry, and adds the tile's part of
+dQ, transposed too, dq^T[:, rows] += K^T @ ds^T, into a float32 (D, Sq)
+accumulator that stays in VMEM from a head's first k-block (which zeroes
+it) to its last (which transposes it once, scales, rounds and stores dq):
+q-block by q-block the parts are added in ascending order of k-blocks, as
+a loop over k-blocks would add them. No transpose of a score tile
+anywhere; K^T is made once a program. The call's VMEM limit is computed
+from its shapes (``_bwd_params``). It recomputes p = exp(s - m) / l from
+the saved PER-ROW (max m, normalizer l) — deliberately NOT the folded
+lse = m + log l: with a finite large-negative additive mask (the -1e9
+convention) s and m are ~1e9-scale where f32 ulp is 64, so s − m
+reproduces the forward's (and sdpa's) rounding exactly while
+s − (m + log l) would silently lose the entire log-normalizer.
+delta = rowsum(dO∘O) is one cheap XLA reduction outside the kernels (the
+identity Σ_k p_k·dp_k = rowsum(dO∘O) holds under dropout too).
 
 Inner loops (PR 32). A tile loaded from q, k, v or dO enters the MXU in
 the dtype it was read in (bfloat16 as bfloat16, float32 as float32: read
@@ -69,18 +79,16 @@ largest tiles that fit, and holds a whole side too large to keep twice
 Row statistics (m, l, and the backward's 1/l and delta) cross HBM as ONE
 f32 a (batch·head, row): (BH, 1, S) arrays with the sequence on the lane
 axis — as a forward result, as the residual saved for the backward, and as
-an operand of both backward kernels. Inside a kernel a statistic is a
-(BQ, 1) column (it broadcasts along a score tile's keys); the forward
-relays its two carries to (1, BQ) rows once a q-block before the store
-(_stat_row) and the dQ kernel relays the three rows it reads back to
-columns (_stat_col): in-VMEM relayouts, no HBM traffic. The dK/dV kernel
-needs none: a (1, BQ) row broadcasts down the sublanes of its transposed
-tile as it is. There is no lane-replicated
-(…, 128) copy and no (…, 1) custom-call operand (whose tiled layout pads
-the 1 to 128 lanes in HBM) on any path: at BERT-base's seq-512 shapes
-those cost 50 MB an array — 650 MB a layer against 190 MB of q/k/v/o/dO
-traffic, and 5.4 ms of a 74.8 ms step in the XLA ops that made and sliced
-them (PERF.md §6, PR 26).
+an operand of the backward kernel. Inside the forward a statistic is a
+(BQ, 1) column (it broadcasts along a score tile's keys), relayed to a
+(1, BQ) row once a q-block before the store (_stat_row): an in-VMEM
+relayout, no HBM traffic. The backward kernel needs none: a (1, BQ) row
+broadcasts down the sublanes of its transposed tile as it is. There is no
+lane-replicated (…, 128) copy and no (…, 1) custom-call operand (whose
+tiled layout pads the 1 to 128 lanes in HBM) on any path: at BERT-base's
+seq-512 shapes those cost 50 MB an array — 650 MB a layer against 190 MB
+of q/k/v/o/dO traffic, and 5.4 ms of a 74.8 ms step in the XLA ops that
+made and sliced them (PERF.md §6, PR 26).
 """
 from __future__ import annotations
 
@@ -98,9 +106,7 @@ _NEG_INF = -1e30
 # A row statistic changes between column (BQ, 1) and row (1, BQ) through
 # one lane tile in VMEM: replicate, transpose, keep one. Of the forms Mosaic
 # lowers (reshape, expand_dims, .T, this), this one leaves the column in the
-# layout the score tiles broadcast from cheaply: a reshape is cheaper to
-# make but costs the dQ kernel 11-14 % at seq 2048, where a program walks
-# several k-blocks (PERF.md §6, PR 26).
+# layout the score tiles broadcast from cheaply (PERF.md §6, PR 26).
 _TILE = 128
 
 
@@ -111,16 +117,15 @@ def _stat_row(col):
 
 
 def _stat_col(row):
-    """(1, BQ) lane-major row, as read from HBM -> the (BQ, 1) column that
-    broadcasts along a score tile's keys (also the key bias of the dK/dV
-    kernel's transposed tile)."""
+    """(1, BK) lane-major row, as read from HBM -> a (BK, 1) column: the key
+    bias of the backward kernel's transposed tile."""
     return jnp.broadcast_to(row, (_TILE, row.shape[1])).T[:, :1]
 
 
 def _dropout_keep(seed_ref, bh, qi, j, shape, threshold):
     """Regeneratable dropout keep-mask for one (BQ, BK) score tile, drawn
-    from the TPU PRNG seeded per tile (so fwd and both bwd kernels
-    regenerate the identical mask without storing it)."""
+    from the TPU PRNG seeded per tile (so the forward and the backward
+    kernel regenerate the identical mask without storing it)."""
     # libtpu's tpu.prng_set_seed_32 takes at most TWO seed words, so fold
     # the (bh, qi, j) tile coordinates into one mixed word via a
     # murmur-style absorb (xor word, odd-constant multiply, logical
@@ -170,7 +175,7 @@ def _dot_nt(a, b):
 
 
 def _k_tile_ranges(xp, qi, *, block_q, block_k, sq, sk, causal):
-    """The k-blocks q-block ``qi`` of the forward and dQ kernels walks, as
+    """The k-blocks q-block ``qi`` of the forward kernel walks, as
     ``(n_plain, n_needed)``: every position of tiles ``[0, n_plain)`` is
     valid, tiles ``[n_plain, n_needed)`` are crossed by the diagonal or by
     a true length, the rest lies wholly above the diagonal. Integer
@@ -187,7 +192,7 @@ def _k_tile_ranges(xp, qi, *, block_q, block_k, sq, sk, causal):
 
 
 def _q_tile_ranges(xp, j, *, block_q, block_k, sq, sk, causal):
-    """The q-blocks k-block ``j`` of the dK/dV kernel walks, as ``(q_start,
+    """The q-blocks k-block ``j`` of the backward kernel walks, as ``(q_start,
     plain_lo, plain_hi)``: tiles ``[q_start, plain_lo)`` are crossed by the
     diagonal (two of them where ``block_q < block_k``), every position of
     ``[plain_lo, plain_hi)`` is valid, and ``[plain_hi, cdiv(sq, block_q))``
@@ -286,7 +291,7 @@ def _bd_k_tile_ranges(qi, *, block_q, block_k, bd):
 
 def _bd_q_tile_ranges(xp, g, *, block_q, block_k, bd):
     """``_q_tile_ranges`` under the block structure, for program ``g`` of
-    the dK/dV kernel: it owns CLEAN k-block ``g mod k-blocks`` and walks the
+    the backward kernel: it owns CLEAN k-block ``g mod k-blocks`` and walks the
     q-blocks of ONE copy, the noisy one for ``g`` under ``k-blocks`` (whose
     rows see only earlier blocks), then the clean one. ``(q_start,
     plain_lo, plain_hi)`` in blocks of that copy."""
@@ -448,121 +453,52 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, mask_ref, keep_ref, *rest,
     l_ref[0] = _stat_row(l)
 
 
-def _bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, mask_ref, keep_ref,
-                   m_ref, linv_ref, delta_ref, do_ref, *rest, block_q,
-                   block_k, sq, sk, causal, scale, mask_mode, dropout_p,
-                   threshold, drop_mode, bd=None):
-    # under the block structure k_ref / v_ref are the clean copy's side and
-    # ``own`` the noisy copy's block at this q-block's positions
-    *own, dq_ref = rest
-    geom = dict(block_q=block_q, block_k=block_k, sq=sq, sk=sk,
-                causal=causal)
-    q = (q_ref[0].astype(jnp.float32) * scale).astype(
-        _operand_dtype(q_ref, k_ref))
-    do = do_ref[0].astype(_operand_dtype(v_ref, do_ref))
-    mrow = _stat_col(m_ref[0])       # (1, BQ) -> (BQ, 1)
-    linv = _stat_col(linv_ref[0])
-    delta = _stat_col(delta_ref[0])
-    bh = pl.program_id(0)
-    qi = pl.program_id(1)
-    dq0 = jnp.zeros((q.shape[0], q_ref.shape[2]), jnp.float32)
-
-    def step(k, v, dq, j, cols, valid):
-        s = _dot_nt(q, k)
-        if mask_mode in ("key", "full"):
-            s = s + mask_ref[0, :, cols].astype(jnp.float32)
-        if valid is not None:
-            s = jnp.where(valid(s.shape), s, _NEG_INF)
-        # p = exp(s − m)/l: same rounding as the forward recurrence even
-        # for ~1e9-scale masked scores (see module docstring); 0 at
-        # _NEG_INF, whose row's m is finite
-        p = jnp.exp(s - mrow) * linv
-        dp = _dot_nt(do, v)
-        if dropout_p > 0.0:
-            if drop_mode == "prng":
-                keep = _dropout_keep(seed_ref, bh, qi, j, p.shape,
-                                     threshold)
-            else:
-                keep = keep_ref[0, :, cols] > 0.5
-            dp = jnp.where(keep, dp / (1.0 - dropout_p), 0.0)
-        ds = p * (dp - delta)
-        return dq + _dot(ds.astype(k.dtype), k)
-
-    if bd is None:
-        def valid(shape, j):
-            return _valid(shape, qi * block_q, j * block_k, 0, **geom)
-
-        n_plain, n_needed = _k_tile_ranges(jnp, qi, **geom)
-        crossed = _crossed_tiles(block_q, block_k, **geom)
-    else:
-        clean, pj = _bd_rows(qi, bd[1])
-
-        def valid(shape, j):
-            return _bd_valid(shape, pj * block_q, j * block_k, 0, bd[0],
-                             1 - clean)
-
-        n_plain, n_needed = _bd_k_tile_ranges(qi, block_q=block_q,
-                                              block_k=block_k, bd=bd)
-        crossed = _bd_crossed(block_q, block_k, bd[0])
-
-    def tile(j, dq, masked):
-        cols = pl.ds(j * block_k, block_k)
-        return step(k_ref[0, cols, :].astype(q.dtype),
-                    v_ref[0, cols, :].astype(do.dtype), dq, j, cols,
-                    (lambda shape: valid(shape, j)) if masked else None)
-
-    dq = _walk(0, n_plain, functools.partial(tile, masked=False), dq0)
-    dq = _walk_crossed(n_plain, n_needed,
-                       functools.partial(tile, masked=True), dq, crossed)
-    if bd is not None:
-        n_own, width = _bd_own_tiles(block_q, block_k)
-
-        def own_tile(t, dq):
-            cols = pl.ds(t * width, width)
-            return step(
-                own[0][0, cols, :].astype(q.dtype),
-                own[1][0, cols, :].astype(do.dtype), dq, t, cols,
-                lambda shape: _bd_valid(shape, pj * block_q,
-                                        pj * block_q + t * width, 0, bd[0],
-                                        None))
-
-        dq = _walk(0, (1 - clean) * n_own, own_tile, dq)
-    dq_ref[0] = (dq * scale).astype(dq_ref.dtype)
-
-
-def _bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, mask_ref, keep_ref,
-                    m_ref, linv_ref, delta_ref, do_ref, *rest,
-                    block_q, block_k, sq, sk, causal, scale, mask_mode,
-                    dropout_p, threshold, drop_mode, bd=None):
+def _bwd_kernel(seed_ref, q_ref, k_ref, v_ref, mask_ref, keep_ref,
+                m_ref, linv_ref, delta_ref, do_ref, *rest,
+                block_q, block_k, sq, sk, causal, scale, mask_mode,
+                dropout_p, threshold, drop_mode, bd=None):
     # this program owns ONE k-block (grid (bh, k-blocks)) and loops
     # q-blocks. q_ref: (1, SQp, D); do_ref: (1, SQp, DV); k_ref: (1, BK, D);
     # v_ref: (1, BK, DV); mask_ref: (1, {1, SQp}, BK); m/linv/delta:
-    # (1, 1, SQp).
+    # (1, 1, SQp); dq_ref: (1, SQp, D); dqt_ref: (D, SQp) float32 scratch.
     # The score tile is computed TRANSPOSED, s^T = K Q^T (BK, BQ): the
     # (1, BQ) row statistics broadcast down its sublanes as they are read
-    # (no relayout), and p^T, ds^T are already the left operands of
-    # dv = p^T dO and dk = ds^T Q (no transpose of a score tile).
+    # (no relayout), p^T, ds^T are already the left operands of
+    # dv = p^T dO and dk = ds^T Q, and ds^T is the right operand of
+    # dq^T = K^T ds^T (no transpose of a score tile). dq^T of the whole q
+    # side stays in ``dqt_ref`` while the grid's second axis walks the
+    # head's k-blocks in ascending order: the first zeroes it, every one
+    # adds its tiles' parts, the last stores it as dq.
     # Under the block structure (grid (bh, 2 x k-blocks a copy)) q_ref,
-    # do_ref and the statistics are ONE copy's rows, k_ref / v_ref the
-    # clean copy's k-block and ``own`` the noisy copy's at the same
-    # positions; the results are the clean block's part from that copy's
-    # rows and the noisy block's gradient (zero from the clean rows).
+    # do_ref, the statistics, dq_ref and dqt_ref are ONE copy's rows,
+    # k_ref / v_ref the clean copy's k-block and ``kn_ref`` / ``vn_ref`` the
+    # noisy copy's at the same positions; dk and dv are the clean block's
+    # part from that copy's rows and the noisy block's gradient (zero from
+    # the clean rows).
     if bd is None:
-        dk_ref, dv_ref = rest
+        dq_ref, dk_ref, dv_ref, dqt_ref, kt_ref = rest
     else:
-        kn_ref, vn_ref, dk_ref, dv_ref, dkn_ref, dvn_ref = rest
+        (kn_ref, vn_ref, dq_ref, dk_ref, dv_ref, dkn_ref, dvn_ref,
+         dqt_ref, kt_ref) = rest
     geom = dict(block_q=block_q, block_k=block_k, sq=sq, sk=sk,
                 causal=causal)
     bh = pl.program_id(0)
     j = pl.program_id(1)
+    # this k-block within its head (its copy): the accumulator's life
+    pj, n_k = (j, pl.num_programs(1)) if bd is None else (
+        _bd_rows(j, bd[2])[1], bd[2])
     k = k_ref[0].astype(_operand_dtype(q_ref, k_ref))
     v = v_ref[0].astype(_operand_dtype(v_ref, do_ref))
     if mask_mode == "key":      # (1, BK) key bias -> (BK, 1), once
         kbias = _stat_col(mask_ref[0].astype(jnp.float32))
 
+    @pl.when(pj == 0)
+    def _():
+        dqt_ref[...] = jnp.zeros(dqt_ref.shape, jnp.float32)
+
     def step(k, v, qi, carry, valid):
         dk, dv = carry
-        rows = pl.ds(qi * block_q, block_q)
+        rows = pl.ds(pl.multiple_of(qi * block_q, block_q), block_q)
         # the forward's rounding of the scaled q, so that s^T is its s
         # (k scaled once a program, or the float32 tile scaled, is no
         # faster and another rounding: PERF.md section 6, PR 32)
@@ -578,6 +514,9 @@ def _bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, mask_ref, keep_ref,
             st = st + mask_ref[0, rows, :].astype(jnp.float32).T
         if valid is not None:
             st = jnp.where(valid(st.shape), st, _NEG_INF)
+        # p = exp(s - m)/l: same rounding as the forward recurrence even
+        # for ~1e9-scale masked scores (see module docstring); 0 at
+        # _NEG_INF, whose row's m is finite
         pt = jnp.exp(st - mrow) * linv
         dpt = _dot_nt(v, do)
         pdt = pt
@@ -593,9 +532,11 @@ def _bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, mask_ref, keep_ref,
             pdt = jnp.where(keep, pt / (1.0 - dropout_p), 0.0)
             dpt = jnp.where(keep, dpt / (1.0 - dropout_p), 0.0)
         dv = dv + _dot(pdt.astype(do.dtype), do)
-        dst = pt * (dpt - delta)
-        # q above is pre-scaled, so ds^T @ (q·scale) is already dk
-        dk = dk + _dot(dst.astype(q.dtype), q)
+        dst = (pt * (dpt - delta)).astype(q.dtype)
+        # q above is pre-scaled, so ds^T @ (q·scale) is already dk; dq is
+        # scaled at its store
+        dk = dk + _dot(dst, q)
+        dqt_ref[:, rows] += _dot(kt_ref[...], dst)
         return dk, dv
 
     def zeros():
@@ -609,7 +550,7 @@ def _bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, mask_ref, keep_ref,
         q_start, plain_lo, plain_hi = _q_tile_ranges(jnp, j, **geom)
         count = _crossed_tiles(block_k, block_q, **geom)
     else:
-        clean, pj = _bd_rows(j, bd[2])
+        clean = _bd_rows(j, bd[2])[0]
 
         def valid(shape, qi):
             return _bd_valid(shape, qi * block_q, pj * block_k, 1, bd[0],
@@ -618,6 +559,20 @@ def _bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, mask_ref, keep_ref,
         q_start, plain_lo, plain_hi = _bd_q_tile_ranges(
             jnp, j, block_q=block_q, block_k=block_k, bd=bd)
         count = _bd_crossed(block_k, block_q, bd[0])
+
+    def transposed(ref):
+        """A k-block as (D, BK), the left operand of every dq^T part of
+        its walk, made once and kept in VMEM beside the accumulator. It
+        comes off the MXU like everything else here: I K^T contracts both
+        operands' own last dimension and is K^T exactly, with no
+        transposition for Mosaic to lower at a head size of 64 or 192."""
+        d = ref.shape[2]
+        eye = (jax.lax.broadcasted_iota(jnp.int32, (d, d), 0)
+               == jax.lax.broadcasted_iota(jnp.int32, (d, d), 1))
+        kt_ref[...] = _dot_nt(eye.astype(k.dtype),
+                              ref[0].astype(k.dtype)).astype(kt_ref.dtype)
+
+    transposed(k_ref)
 
     def tile(qi, carry, masked):
         return step(k, v, qi, carry,
@@ -634,6 +589,7 @@ def _bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, mask_ref, keep_ref,
     else:
         # the noisy copy's k-block at these positions: its own rows alone
         kn = kn_ref[0].astype(k.dtype)
+        transposed(kn_ref)
         vn = vn_ref[0].astype(v.dtype)
         first = (pj * block_k) // block_q
         dkn, dvn = _walk(
@@ -648,6 +604,15 @@ def _bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, mask_ref, keep_ref,
     dk, dv = carry
     dk_ref[0] = dk.astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
+
+    @pl.when(pj == n_k - 1)
+    def _():
+        def store(qi, _):
+            rows = pl.ds(pl.multiple_of(qi * block_q, block_q), block_q)
+            dq_ref[0, rows, :] = (dqt_ref[:, rows].T * scale).astype(
+                dq_ref.dtype)
+
+        jax.lax.fori_loop(0, dq_ref.shape[1] // block_q, store, None)
 
 
 def _mask_mode(mask_shape, b, h, sq, sk):
@@ -820,8 +785,6 @@ def _flash_bwd(q, k, v, mask, mask_mode, seed, out, mrow, lrow, g, causal,
     linv = 1.0 / jnp.maximum(lrow, 1e-20)
     # (BH, 1, SQp), as the forward wrote them: no lane replication
     stats = [_pad_axis(x, 2, sq_pad) for x in (mrow, linv, delta)]
-    stat_q = pl.BlockSpec((1, 1, bq), lambda i, j: (i, 0, j),
-                          memory_space=pltpu.VMEM)
     stat_all = _whole_side((1, 1, sq_pad), single)
 
     if mask_mode in ("key", "full"):
@@ -835,92 +798,65 @@ def _flash_bwd(q, k, v, mask, mask_mode, seed, out, mrow, lrow, g, causal,
     drop_mode = "mask" if (interp and dropout_p > 0.0) else "prng"
     if drop_mode == "mask":
         keep3 = _host_keep_mask(seed2, b * h, sq_pad, sk_pad, dropout_p)
-        kspec_q = pl.BlockSpec((1, bq, sk_pad), lambda i, j: (i, j, 0),
-                               memory_space=pltpu.VMEM)
-        kspec_kv = pl.BlockSpec((1, sq_pad, bk), lambda i, j: (i, 0, j),
-                                memory_space=pltpu.VMEM)
+        kspec = pl.BlockSpec((1, sq_pad, bk), lambda i, j: (i, 0, j),
+                             memory_space=pltpu.VMEM)
     else:
         keep3 = jnp.zeros((1, 1, 1), jnp.float32)
-        kspec_q = pl.BlockSpec((1, 1, 1), lambda i, j: (0, 0, 0),
-                               memory_space=pltpu.VMEM)
-        kspec_kv = kspec_q
-    if mask_mode == "full":
-        mspec_q = pl.BlockSpec((1, bq, sk_pad),
-                               lambda i, j: (bh_to_g(i), j, 0),
-                               memory_space=pltpu.VMEM)
-    else:
-        mspec_q = pl.BlockSpec((1, 1, sk_pad),
-                               lambda i, j: (bh_to_g(i), 0, 0),
-                               memory_space=pltpu.VMEM)
+        kspec = pl.BlockSpec((1, 1, 1), lambda i, j: (0, 0, 0),
+                             memory_space=pltpu.VMEM)
 
-    dq = pl.pallas_call(
-        functools.partial(
-            _bwd_dq_kernel, block_q=bq, block_k=bk, sq=sq, sk=sk,
-            causal=causal, scale=s, mask_mode=mask_mode,
-            dropout_p=dropout_p, threshold=threshold, drop_mode=drop_mode),
-        grid=(b * h, pl.cdiv(sq, bq)),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, bq, d), lambda i, j: (i, j, 0),
-                         memory_space=pltpu.VMEM),
-            _whole_side((1, sk_pad, d), single),
-            _whole_side((1, sk_pad, dvh), single),
-            mspec_q,
-            kspec_q,
-            stat_q,
-            stat_q,
-            stat_q,
-            pl.BlockSpec((1, bq, dvh), lambda i, j: (i, j, 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec((1, bq, d), lambda i, j: (i, j, 0),
-                               memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
-        interpret=interp,
-        name="flash_bwd_dq",
-    )(seed2, q3, k3, v3, m3, keep3, *stats, do3)
-
-    # dK/dV pass needs whole-Q operands padded to the block multiple
+    # the whole-Q operands padded to the block multiple
     q3p = _pad_axis(q3, 1, sq_pad)
     do3p = _pad_axis(do3, 1, sq_pad)
 
-    dk, dv = pl.pallas_call(
+    def k_block(width):
+        return pl.BlockSpec((1, bk, width), lambda i, j: (i, j, 0),
+                            memory_space=pltpu.VMEM)
+
+    dq, dk, dv = pl.pallas_call(
         functools.partial(
-            _bwd_dkv_kernel, block_q=bq, block_k=bk, sq=sq, sk=sk,
+            _bwd_kernel, block_q=bq, block_k=bk, sq=sq, sk=sk,
             causal=causal, scale=s, mask_mode=mask_mode,
             dropout_p=dropout_p, threshold=threshold, drop_mode=drop_mode),
         grid=(b * h, pl.cdiv(sk, bk)),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
             _whole_side((1, sq_pad, d), single),
-            pl.BlockSpec((1, bk, d), lambda i, j: (i, j, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bk, dvh), lambda i, j: (i, j, 0),
-                         memory_space=pltpu.VMEM),
+            k_block(d),
+            k_block(dvh),
             pl.BlockSpec((1, msq_blk, bk), lambda i, j: (bh_to_g(i), 0, j),
                          memory_space=pltpu.VMEM),
-            kspec_kv,
+            kspec,
             stat_all,
             stat_all,
             stat_all,
             _whole_side((1, sq_pad, dvh), single),
         ],
+        # dq's block changes with the head alone: it is written once, by
+        # the head's last k-block, from the accumulator
         out_specs=[
-            pl.BlockSpec((1, bk, d), lambda i, j: (i, j, 0),
+            pl.BlockSpec((1, sq_pad, d), lambda i, j: (i, 0, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bk, dvh), lambda i, j: (i, j, 0),
-                         memory_space=pltpu.VMEM),
+            k_block(d),
+            k_block(dvh),
         ],
         out_shape=[
+            jax.ShapeDtypeStruct((b * h, sq_pad, d), q.dtype),
             jax.ShapeDtypeStruct((b * h, sk_pad, d), k.dtype),
             jax.ShapeDtypeStruct((b * h, sk_pad, dvh), v.dtype),
         ],
+        scratch_shapes=[pltpu.VMEM((d, sq_pad), jnp.float32),
+                        pltpu.VMEM((d, bk), k.dtype)],
+        compiler_params=_bwd_params(
+            sq_pad, d, dvh, q.dtype.itemsize, bq, bk, single,
+            extra=(msq_blk + (sq_pad if drop_mode == "mask" else 0)) * bk),
         interpret=interp,
-        name="flash_bwd_dkv",
+        name="flash_bwd",
     )(seed2, q3p, k3, v3, m3, keep3, *stats, do3p)
+    dq = dq[:, :sq].reshape(b, h, sq, d)
     dk = dk[:, :sk].reshape(b, h, sk, d)
     dv = dv[:, :sk].reshape(b, h, sk, dvh)
-    return dq.reshape(b, h, sq, d), dk, dv
+    return dq, dk, dv
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 6, 7, 8, 9, 10))
@@ -938,8 +874,20 @@ def _fwd(q, k, v, mask, mask_mode, seed, causal, scale, block_q, block_k,
     return out, (q, k, v, mask, seed, out, mrow, lrow)
 
 
+def _count_backward(tiles):
+    """One call site's backward is being traced: the one kernel that makes
+    each of ``tiles`` score tiles once and takes five products from it."""
+    from ... import monitor
+    monitor.counter("flash_attention.backward_fused_traced").inc()
+    monitor.counter("flash_attention.backward_products").inc(5 * tiles)
+
+
 def _bwd(mask_mode, causal, scale, block_q, block_k, dropout_p, res, g):
     q, k, v, mask, seed, out, mrow, lrow = res
+    bq, bk = _clamped_blocks(block_q, block_k, q.shape[2], k.shape[2])
+    _count_backward(_tile_counts(q.shape[0] * q.shape[1], block_q=bq,
+                                 block_k=bk, sq=q.shape[2], sk=k.shape[2],
+                                 causal=causal)[0])
     dq, dk, dv = _flash_bwd(q, k, v, mask, mask_mode, seed, out, mrow,
                             lrow, g, causal, scale, block_q, block_k,
                             dropout_p)
@@ -1082,21 +1030,9 @@ def _bd_bwd(q, k, v, out, mrow, lrow, g, shift, scale, block_q, block_k):
     stats = [mrow, 1.0 / jnp.maximum(lrow, 1e-20), delta]   # (BH, 1, 2 Lp)
     interp = interpret_mode()
 
-    dq = pl.pallas_call(
-        p.body(_bwd_dq_kernel), grid=(b * h, 2 * p.n_q),
-        in_specs=[p.seed_spec, p.block(p.bq, d), p.clean_side(d),
-                  p.clean_side(dvh), p.zero_spec, p.zero_spec, p.stat_q,
-                  p.stat_q, p.stat_q, p.block(p.bq, dvh), p.own(d),
-                  p.own(dvh)],
-        out_specs=p.block(p.bq, d),
-        out_shape=jax.ShapeDtypeStruct((b * h, 2 * lp, d), q.dtype),
-        interpret=interp,
-        name="flash_bd_bwd_dq",
-    )(seed, q3, k3, v3, zero, zero, *stats, do3, k3, v3)
-
-    # program g of the dK/dV kernel: clean k-block g mod n_k against the
-    # rows of copy g // n_k (the noisy copy first), and for the noisy rows
-    # the noisy k-block at the same positions
+    # program g: clean k-block g mod n_k against the rows of copy g // n_k
+    # (the noisy copy first), and for the noisy rows the noisy k-block at
+    # the same positions; dq's block and its accumulator follow the copy
     def copy_side(width):
         return _whole_side((1, lp, width), p.single,
                            lambda i, j: (i, j // n_k, 0))
@@ -1110,17 +1046,23 @@ def _bd_bwd(q, k, v, out, mrow, lrow, g, shift, scale, block_q, block_k):
     def noisy_block(width):
         return p.block(p.bk, width, lambda i, j: (i, j % n_k, 0))
 
-    parts = pl.pallas_call(
-        p.body(_bwd_dkv_kernel), grid=(b * h, 2 * n_k),
+    dq, *parts = pl.pallas_call(
+        p.body(_bwd_kernel), grid=(b * h, 2 * n_k),
         in_specs=[p.seed_spec, copy_side(d), clean_block(d),
                   clean_block(dvh), p.zero_spec, p.zero_spec, stat_all,
                   stat_all, stat_all, copy_side(dvh), noisy_block(d),
                   noisy_block(dvh)],
-        out_specs=[p.block(p.bk, d), p.block(p.bk, dvh)] * 2,
-        out_shape=[jax.ShapeDtypeStruct((b * h, 2 * lp, d), k.dtype),
-                   jax.ShapeDtypeStruct((b * h, 2 * lp, dvh), v.dtype)] * 2,
+        out_specs=[p.block(lp, d, lambda i, j: (i, j // n_k, 0))]
+        + [p.block(p.bk, d), p.block(p.bk, dvh)] * 2,
+        out_shape=[jax.ShapeDtypeStruct((b * h, 2 * lp, d), q.dtype)]
+        + [jax.ShapeDtypeStruct((b * h, 2 * lp, d), k.dtype),
+           jax.ShapeDtypeStruct((b * h, 2 * lp, dvh), v.dtype)] * 2,
+        scratch_shapes=[pltpu.VMEM((d, lp), jnp.float32),
+                        pltpu.VMEM((d, p.bk), k.dtype)],
+        compiler_params=_bwd_params(lp, d, dvh, q.dtype.itemsize, p.bq,
+                                    p.bk, p.single, blocks=2),
         interpret=interp,
-        name="flash_bd_bwd_dkv",
+        name="flash_bd_bwd",
     )(seed, q3, k3, v3, zero, zero, *stats, do3, k3, v3)
 
     def both(clean_parts, noisy):
@@ -1145,6 +1087,11 @@ def _bd_vjp_fwd(q, k, v, shift, scale, block_q, block_k):
 
 
 def _bd_vjp_bwd(shift, scale, block_q, block_k, res, g):
+    q = res[0]
+    length = q.shape[2] // 2
+    bq, bk = _bd_blocks(block_q, block_k, length, shift)
+    _count_backward(_bd_tile_counts(q.shape[0] * q.shape[1], length,
+                                    block_q=bq, block_k=bk, shift=shift)[0])
     return _bd_bwd(*res, g, shift, scale, block_q, block_k)
 
 
@@ -1152,39 +1099,44 @@ _flash_bd.defvjp(_bd_vjp_fwd, _bd_vjp_bwd)
 
 
 # Each kernel keeps the whole other side of one (batch, head) in VMEM (the
-# forward and dQ kernels K and V, the dK/dV kernel Q, dO and three rows of
-# statistics; under the block-diffusion structure ONE copy's side, so
-# ``seq`` below is a copy's length), beside its own blocks and a few
-# float32 (BQ, BK) score tiles, in the 16 MiB a kernel may use. What a tile costs beyond its
+# forward K and V; the backward Q, dO and three rows of statistics, and
+# beside them dq's block and dq's float32 accumulator; under the
+# block-diffusion structure ONE copy's side, so ``seq`` below is a copy's
+# length), beside its own blocks and a few float32 (BQ, BK) score tiles. The
+# forward lives in the 16 MiB a kernel may use unasked; the backward asks
+# for what its shapes need (``_bwd_params``: 30.5 MiB allowed and 16.7 taken
+# at 8,192 x 128, 34.3 and 23.9 at 8,192 x 192 | 128, 31.5 and 22.1 under
+# the block structure, 38.5 and 28.7 at 16,384 x 128, the default at BERT's
+# seq 512; a v5e core has 128). What a tile costs beyond its
 # products is paid once an inner iteration (the fill and drain of the MXU
 # and of the cross-lane reductions; about ten operations on (BQ, 1)
 # columns), so the largest tiles that fit are the fastest: alone on a v5e,
-# 32 heads x 8,192 causal, forward + (forward + backward) ms at 192 / 128:
-# 256 x 256 47.1, 256 x 512 37.3, 512 x 512 35.1; at 128 / 128: 256 x 256
-# 36.0, 256 x 512 24.3, 512 x 512 22.4 (PERF.md section 7, row 29).
+# 32 heads x 8,192 causal, forward / backward ms at 192 | 128: 256 x 256
+# 11.76 / 16.99, 256 x 512 7.45 / 16.78, 512 x 512 7.18 / 15.37; at
+# 128 | 128: 256 x 256 9.22 / 10.65, 256 x 512 4.99 / 10.00, 512 x 512
+# 4.74 / 9.16 (PERF.md section 7, row 29).
 # Compiled for a v5e at 8,192 positions in bfloat16:
 # * up to 4,096 positions at these head sizes (under 4 MiB a side) the
 #   blocks stay as asked (512 x 1,024), the whole side double-buffered.
-# * head size 128 for q/k and v (4 MiB a side): with BK = 1,024 the dK/dV
-#   kernel asks for 18.4 MiB; with BK = 512 it fits, double-buffered.
+# * head size 128 for q/k and v (4 MiB a side): BK = 512, double-buffered.
+#   (Under its own limit the backward takes BK = 1,024 too and is no faster,
+#   9.43 ms; the forward is, 4.42: larger tiles are another change.)
 # * head size 192 for q/k, 128 for v (multi-head latent attention; 5 MiB a
 #   side as counted here, 6 in VMEM, where 192 lanes take two tiles of
-#   128): double-buffered, nothing above 256 x 256 fits inside the
-#   joyai_llm_flash step (256 x 512: the dK/dV kernel asks 16.02 MiB).
-#   The whole side changes once in 16 to 32 programs, so it is held in ONE
-#   buffer there: the next head's 5 MiB are fetched when the last block of
-#   this one is done (0.3 ms a forward call of 7.2, measured), and 512 x
-#   512 fits all three kernels, alone and inside the step. At 4 MiB and
-#   under one buffer only costs (23.5 -> 24.2 ms at 128 / 128; +12 % on
-#   the backward at BERT's seq 512, where every program has a new side).
+#   128): double-buffered, nothing above 256 x 256 fits the forward inside
+#   the joyai_llm_flash step. The whole side changes once in 16 to 32
+#   programs, so it is held in ONE buffer there: the next head's 5 MiB are
+#   fetched when the last block of this one is done (0.3 ms a forward call
+#   of 7.2, measured), and 512 x 512 fits, alone and inside the step. At
+#   4 MiB and under one buffer only costs (23.5 -> 24.2 ms at 128 | 128;
+#   +12 % on the backward at BERT's seq 512, where every program has a new
+#   side).
 # * two copies of 8,192 x 128 under the block structure: a copy's side is
 #   the 4 MiB of the causal 8k call and takes its answer, 512 x 512 with
-#   two buffers (the noisy copy's block and the dK/dV kernel's second pair
-#   of results add 0.6 MiB); 512 x 1,024 asks 20.97 MiB and 1,024 x 512
-#   16.64. Alone on a v5e, forward / forward + backward ms (PERF.md section
-#   6, PR 33): 512 x 512 10.52 / 41.63, 256 x 512 10.92 / 44.80, 512 x 256
-#   16.03 / 50.69.
+#   two buffers. Alone on a v5e, forward / backward ms: 512 x 512 10.48 /
+#   22.82, 256 x 512 10.88 / 24.62, 512 x 256 16.00 / 23.91.
 _WHOLE_SIDE_BYTES = 4 * 1024 * 1024
+_DEFAULT_VMEM_BYTES = 16 * 1024 * 1024      # what a kernel may use unasked
 
 
 def _side_bytes(seq, d, dv, itemsize):
@@ -1204,6 +1156,32 @@ def _single_buffered(seq, d, dv, itemsize):
     """Whether the kernels hold the whole other side in one VMEM buffer:
     where two of them would leave no room for 512 x 512 tiles."""
     return _side_bytes(seq, d, dv, itemsize) > _WHOLE_SIDE_BYTES
+
+
+def _lanes(width):
+    """A last dimension as VMEM holds it: whole 128-lane tiles."""
+    return -(-width // _TILE) * _TILE
+
+
+def _bwd_params(seq, d, dv, itemsize, block_q, block_k, single, blocks=1,
+                extra=0):
+    """Compiler parameters of the backward call: the grid's second axis
+    carries dq's accumulator from one k-block of a head to the next, and
+    the VMEM it may use is what its shapes need, not the default 16 MiB
+    (a v5e core has 128): the whole q side of ``seq`` rows (q, dO, three
+    statistic rows of 8 sublanes; one buffer or two), dq's block, its
+    float32 accumulator, ``blocks`` sets of the k-block's operands and
+    results, ``extra`` float32 of mask and keep-mask a block, and the
+    float32 (BK, BQ) tiles a step holds."""
+    side = seq * ((_lanes(d) + _lanes(dv)) * itemsize + 3 * 8 * 4)
+    dq = 2 * seq * _lanes(d) * itemsize + -(-d // 8) * 8 * seq * 4
+    own = 2 * 2 * blocks * block_k * (_lanes(d) + _lanes(dv)) * itemsize
+    tiles = 8 * block_q * block_k * 4
+    need = ((1 if single else 2) * side + dq + own + 2 * extra * 4 + tiles
+            + 4 * 1024 * 1024)
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "arbitrary"),
+        vmem_limit_bytes=max(int(need), _DEFAULT_VMEM_BYTES))
 
 
 def flash_attention(q, k, v, attn_mask=None, causal=False, scale=None,
@@ -1237,9 +1215,12 @@ def flash_attention(q, k, v, attn_mask=None, causal=False, scale=None,
     path; per kernel call site ``flash_attention.tiles``, ``flash_attention.
     tiles_masked`` and ``flash_attention.tiles_skipped`` add the forward
     kernel's score tiles, those of them that run the masked body and the
-    tiles of the whole rectangle it does not walk, and
+    tiles of the whole rectangle it does not walk,
     ``flash_attention.native_operands_traced`` counts the call sites whose
-    products take bfloat16 operands."""
+    products take bfloat16 operands, and where a call site's backward is
+    traced ``flash_attention.backward_fused_traced`` counts it and
+    ``flash_attention.backward_products`` adds the five products of each
+    of its tiles (the one backward kernel walks the forward's tiles)."""
     from ...dispatch import apply
     from ... import monitor
     from ... import random as prandom

@@ -1,19 +1,22 @@
-"""Flash-attention block sweep on the chip, at the geometries of the three
+"""Flash-attention block sweep on the chip, at the geometries of the four
 benchmark cells that run the kernels (q/k and v head sizes apart):
 
     joyai     1 x 32 x 8192 x 192 | 128, causal
     nemotron  1 x 32 x 8192 x 128 | 128, causal
     seq512    16 x 12 x 512 x 64 | 64, key bias, no causal mask
+    sdar      1 x 32 x (2 x 8192) x 128 | 128, block-diffusion structure,
+              diffusion blocks of 4
 
-For each (block_q, block_k): forward ms and forward + backward ms (host
+For each (block_q, block_k): forward ms, backward ms (the backward call
+alone, on the forward's saved results) and forward + backward ms (host
 clock around ``block_until_ready``, the kernels alone: no projections), the
 forward's tiles and how many of them run the masked body. With
 ``--parent DIR`` (a checkout of the parent commit, e.g. ``git archive`` into
-the git-ignored ``_archive_check/``) every row is timed on the parent's
+the git-ignored ``.benchmark_work/``) every row is timed on the parent's
 kernels too, in the same process, and at the blocks the rule gives the
 parent's and the change's o, dq, dk, dv are compared bit for bit.
 
-    chiprun -- python -u scripts/tune_flash.py --parent _archive_check/parent
+    chiprun -- python -u scripts/tune_flash.py --parent .benchmark_work/parent
 
 The table goes into PERF.md section 7, row 29.
 """
@@ -30,15 +33,18 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 import numpy as np
 
 GEOMETRIES = {
-    # name: (batch, heads, seq, d, dv, causal, key_bias, block pairs)
-    "joyai": (1, 32, 8192, 192, 128, True, False,
+    # name: (batch, heads, seq, d, dv, causal, key_bias, diffusion block,
+    # block pairs); under a diffusion block ``seq`` is two copies' rows
+    "joyai": (1, 32, 8192, 192, 128, True, False, None,
               [(256, 256), (128, 512), (256, 512), (512, 512), (256, 1024),
                (512, 1024)]),
-    "nemotron": (1, 32, 8192, 128, 128, True, False,
+    "nemotron": (1, 32, 8192, 128, 128, True, False, None,
                  [(256, 256), (256, 512), (512, 512), (512, 1024),
                   (1024, 512), (1024, 1024)]),
-    "seq512": (16, 12, 512, 64, 64, False, True,
+    "seq512": (16, 12, 512, 64, 64, False, True, None,
                [(512, 512), (256, 512), (512, 256), (256, 256)]),
+    "sdar": (1, 32, 16384, 128, 128, False, False, 4,
+             [(256, 512), (512, 256), (512, 512)]),
 }
 
 
@@ -59,7 +65,7 @@ def load_kernels(parent):
 
 def make_inputs(geometry, seed=0):
     import jax.numpy as jnp
-    b, h, s, d, dv, causal, key_bias, _ = geometry
+    b, h, s, d, dv, causal, key_bias, block, _ = geometry
     rng = np.random.RandomState(seed)
     q, k = (jnp.asarray(rng.randn(b, h, s, d), jnp.bfloat16)
             for _ in range(2))
@@ -73,22 +79,54 @@ def make_inputs(geometry, seed=0):
 
 
 def functions(module, geometry, bias, block_q, block_k):
-    """(forward, forward + backward) of ``module``'s kernels, jitted."""
+    """(forward, backward on the forward's saved results, forward +
+    backward) of ``module``'s kernels, jitted. ``backward(q, k, v, ct)``
+    runs the forward once, outside the clock."""
     import jax
     import jax.numpy as jnp
-    b, h, s, d, dv, causal, key_bias, _ = geometry
+    b, h, s, d, dv, causal, key_bias, block, _ = geometry
     mode = "key" if key_bias else None
     seed = jnp.zeros((2,), jnp.int32)
 
-    def fwd(q, k, v):
-        return module._flash(q, k, v, bias, mode, seed, causal, None,
-                             block_q, block_k, 0.0)
+    if block:
+        shift = block.bit_length() - 1
+
+        def fwd(q, k, v):
+            return module._flash_bd(q, k, v, shift, None, block_q, block_k)
+
+        def saved(q, k, v):
+            return module._bd_fwd_res(q, k, v, shift, None, block_q, block_k)
+
+        def bwd(q, k, v, ct, out, mrow, lrow):
+            return module._bd_bwd(q, k, v, out, mrow, lrow, ct, shift, None,
+                                  block_q, block_k)
+    else:
+        def fwd(q, k, v):
+            return module._flash(q, k, v, bias, mode, seed, causal, None,
+                                 block_q, block_k, 0.0)
+
+        def saved(q, k, v):
+            return module._flash_fwd_res(q, k, v, bias, mode, seed, causal,
+                                         None, block_q, block_k, 0.0)
+
+        def bwd(q, k, v, ct, out, mrow, lrow):
+            return module._flash_bwd(q, k, v, bias, mode, seed, out, mrow,
+                                     lrow, ct, causal, None, block_q,
+                                     block_k, 0.0)
 
     def both(q, k, v, ct):
         out, vjp = jax.vjp(fwd, q, k, v)
         return (out,) + vjp(ct)
 
-    return jax.jit(fwd), jax.jit(both)
+    saved, bwd = jax.jit(saved), jax.jit(bwd)
+    residuals = {}
+
+    def backward(q, k, v, ct):
+        if not residuals:
+            residuals["r"] = jax.block_until_ready(saved(q, k, v))
+        return bwd(q, k, v, ct, *residuals["r"])
+
+    return jax.jit(fwd), backward, jax.jit(both)
 
 
 def timed(fn, args, steps):
@@ -120,7 +158,7 @@ def ulps(a, b):
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--parent", default=None)
-    ap.add_argument("--cells", default="joyai,nemotron,seq512")
+    ap.add_argument("--cells", default="joyai,nemotron,seq512,sdar")
     ap.add_argument("--steps", type=int, default=20)
     args = ap.parse_args()
 
@@ -133,31 +171,42 @@ def main():
 
     for cell in args.cells.split(","):
         geometry = GEOMETRIES[cell]
-        b, h, s, d, dv, causal, key_bias, pairs = geometry
+        b, h, s, d, dv, causal, key_bias, block, pairs = geometry
         q, k, v, ct, bias = make_inputs(geometry)
-        rule = {name: m._blocks_that_fit(s, d, dv, 2, 512, 1024)
+        held = s // 2 if block else s
+        rule = {name: m._blocks_that_fit(held, d, dv, 2, 512, 1024)
                 for name, m in sides}
         print(f"{cell}: {b} x {h} x {s} x {d} | {dv} "
               f"causal={causal} key_bias={key_bias} rule={rule}", flush=True)
         for bq, bk in pairs:
-            bq_, bk_ = change._clamped_blocks(bq, bk, s, s)
-            tiles, masked = change._tile_counts(
-                b * h, block_q=bq_, block_k=bk_, sq=s, sk=s, causal=causal)
+            if block:
+                shift = block.bit_length() - 1
+                bq_, bk_ = change._bd_blocks(bq, bk, held, shift)
+                tiles, masked, _ = change._bd_tile_counts(
+                    b * h, held, block_q=bq_, block_k=bk_, shift=shift)
+            else:
+                bq_, bk_ = change._clamped_blocks(bq, bk, s, s)
+                tiles, masked = change._tile_counts(
+                    b * h, block_q=bq_, block_k=bk_, sq=s, sk=s,
+                    causal=causal)
             row = f"  {bq:4d} x {bk:4d}  tiles {tiles:6d} masked {masked:5d}"
             for name, module in sides:
                 try:
-                    f, fb = functions(module, geometry, bias, bq, bk)
+                    f, back, fb = functions(module, geometry, bias, bq, bk)
                     ms = timed(f, (q, k, v), args.steps)
+                    ms_back = timed(back, (q, k, v, ct), args.steps)
                     ms_both = timed(fb, (q, k, v, ct), args.steps)
-                    row += f"  {name} fwd {ms:7.3f} fwd+bwd {ms_both:7.3f}"
+                    row += (f"  {name} fwd {ms:7.3f} bwd {ms_back:7.3f} "
+                            f"fwd+bwd {ms_both:7.3f}")
                 except Exception as e:     # noqa: BLE001 - VMEM, mostly
                     row += f"  {name} FAIL {type(e).__name__}: " \
                            f"{str(e).splitlines()[0][:120]}"
             print(row, flush=True)
         if args.parent:
             # the same inputs through both sides, each at its rule's blocks
-            outs = {name: functions(m, geometry, bias, *rule[name])[1](
-                q, k, v, ct) for name, m in sides}
+            outs = {name: functions(m, geometry, bias, *rule[name])[2](
+                q, k, v, ct)
+                    for name, m in sides}
             for label, a, c in zip(("o", "dq", "dk", "dv"), outs["parent"],
                                    outs["change"]):
                 share, worst, rel = ulps(a, c)

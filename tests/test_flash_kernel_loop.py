@@ -3,9 +3,11 @@ loaded tiles reach the MXU in the dtype they were read in; only the score
 tiles the diagonal or a true length crosses run the masked body, as
 straight-line code behind the plain loop where the shapes say how many
 they are; a whole side too large to hold twice is held in one buffer.
-Read off the kernels' jaxprs, checked against float32 attention in
-interpret mode, and counted at the dispatch. CPU only: counts and values,
-no time."""
+Two kernels a call since PR 40: the one backward kernel makes a score tile
+once and takes dV, dK and dQ from it, five products a tile, dQ through a
+float32 accumulator of the whole q side. Read off the kernels' jaxprs,
+checked against float32 attention in interpret mode, and counted at the
+dispatch. CPU only: counts and values, no time."""
 import itertools
 
 import numpy as np
@@ -17,15 +19,18 @@ import jax.numpy as jnp
 import paddle_tpu as pt
 from paddle_tpu import monitor
 from paddle_tpu.ops.pallas import flash_attention
-from paddle_tpu.ops.pallas.flash_attention import (_blocks_that_fit,
+from paddle_tpu.ops.pallas.flash_attention import (_bd_tile_counts,
+                                                   _blocks_that_fit,
                                                    _canon_mask,
                                                    _crossed_tiles, _flash,
+                                                   _flash_bd,
                                                    _host_keep_mask,
                                                    _mask_mode,
                                                    _single_buffered,
-                                                   _tile_counts)
+                                                   _tile_counts,
+                                                   block_diffusion_mask)
 
-KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
+KERNELS = ("flash_fwd", "flash_bwd")
 B, H, BQ, BK, D, DV = 1, 2, 16, 32, 24, 16
 
 
@@ -41,7 +46,7 @@ def _eqns(jaxpr):
 
 
 def _kernel_jaxprs(dtype, causal=True, seq=64, mask_shape=None):
-    """name -> jaxpr of each of the three kernels at (1, 2, seq, 24 | 16),
+    """name -> jaxpr of each of the two kernels at (1, 2, seq, 24 | 16),
     16 x 32 blocks."""
     mode = _mask_mode(mask_shape, B, H, seq, seq)
 
@@ -63,10 +68,21 @@ def _kernel_jaxprs(dtype, causal=True, seq=64, mask_shape=None):
 
 def _loops(kernel):
     """The bodies of a kernel's tile loops (``fori_loop`` is a ``while``
-    under traced bounds and a ``scan`` under static ones)."""
+    under traced bounds and a ``scan`` under static ones): the loops of the
+    kernel's own level. The backward's store of dQ, q-block by q-block, is
+    under the ``cond`` of a head's last k-block and not among them."""
     return [e.params["body_jaxpr" if e.primitive.name == "while"
                      else "jaxpr"].jaxpr for e in kernel.eqns
             if e.primitive.name in ("while", "scan")]
+
+
+def _eqns_of(eqn):
+    """Every equation under one equation's nested jaxprs."""
+    for v in eqn.params.values():
+        for sub in (v if isinstance(v, (list, tuple)) else (v,)):
+            sub = getattr(sub, "jaxpr", sub)
+            if hasattr(sub, "eqns"):
+                yield from _eqns(sub)
 
 
 def _names(jaxpr):
@@ -77,10 +93,12 @@ def _names(jaxpr):
 
 # tile bodies a causal 64 x 64 call at 16 x 32 holds: the plain loop's, and
 # the crossed tiles behind it (one a q-block; two q-blocks a k-block)
-BODIES = {"flash_fwd": 2, "flash_bwd_dq": 2, "flash_bwd_dkv": 3}
-# forward: q k^T, p v; dQ: q k^T, dO v^T, ds k; dK/dV: k q^T, v dO^T,
-# p^T dO, ds^T q
-PRODUCTS = {"flash_fwd": 2, "flash_bwd_dq": 3, "flash_bwd_dkv": 4}
+BODIES = {"flash_fwd": 2, "flash_bwd": 3}
+# forward: q k^T, p v; backward: k q^T, v dO^T, p^T dO, ds^T q, k^T ds^T
+PRODUCTS = {"flash_fwd": 2, "flash_bwd": 5}
+# ... and once a program, outside every tile, the backward makes K^T as the
+# product I K^T
+ONCE = {"flash_fwd": 0, "flash_bwd": 1}
 
 
 @pytest.mark.parametrize("name", KERNELS)
@@ -89,7 +107,7 @@ PRODUCTS = {"flash_fwd": 2, "flash_bwd_dq": 3, "flash_bwd_dkv": 4}
 def test_products_take_their_operands_as_they_were_read(dtype, name):
     kernel = _kernel_jaxprs(dtype)[name]
     dots = [e for e in _eqns(kernel) if e.primitive.name == "dot_general"]
-    assert len(dots) == BODIES[name] * PRODUCTS[name]
+    assert len(dots) == BODIES[name] * PRODUCTS[name] + ONCE[name]
     for e in dots:
         assert [v.aval.dtype for v in e.invars] == [dtype, dtype], e
         assert e.outvars[0].aval.dtype == jnp.float32
@@ -99,14 +117,20 @@ def test_products_take_their_operands_as_they_were_read(dtype, name):
                and e.invars[0].aval.dtype == jnp.bfloat16
                and e.outvars[0].aval.dtype == jnp.float32]
     # bfloat16 in: no K or V tile is widened anywhere; q is, to be scaled in
-    # float32 and rounded back: once a q-block in the forward and dQ
-    # kernels, once a tile in the dK/dV kernel, which walks q-blocks
+    # float32 and rounded back: once a q-block in the forward, once a tile
+    # in the backward kernel, which walks q-blocks
     assert widened == ([] if dtype == jnp.float32 else [(BQ, D)] * (
-        BODIES[name] if name == "flash_bwd_dkv" else 1)), widened
+        BODIES[name] if name == "flash_bwd" else 1)), widened
     (body,) = _loops(kernel)
     # no copy of a tile is transposed: the products contract over the
-    # operands' own last dimensions
+    # operands' own last dimensions, or take an operand as it stands (K^T,
+    # made once a program, against ds^T)
     assert "transpose" not in _names(body)
+    for e in _eqns(body):
+        if e.primitive.name == "dot_general":
+            (lhs, rhs), batch = e.params["dimension_numbers"]
+            assert (tuple(lhs), tuple(rhs)) in (((1,), (1,)), ((1,), (0,)))
+            assert batch == ((), ())
     # statistics and accumulators are carried in float32
     carried = {v.aval.dtype for v in body.outvars if v.aval.shape}
     assert carried == {jnp.dtype(jnp.float32)}, carried
@@ -117,7 +141,35 @@ def test_products_take_their_operands_as_they_were_read(dtype, name):
                 and e.outvars[0].aval.dtype == jnp.bfloat16
                 and e.invars[0].aval.shape in ((BQ, BK), (BK, BQ))]
     assert len(narrowed) == (0 if dtype == jnp.float32 else
-                             2 if name == "flash_bwd_dkv" else 1)
+                             2 if name == "flash_bwd" else 1)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+def test_dq_is_accumulated_in_float32_and_transposed_once_a_head(dtype):
+    """The backward kernel's scratch: dq^T of the whole q side, (D, Sq)
+    float32 whatever the call's dtype, and one K^T block in the operands'
+    dtype. A tile body adds to one (D, BQ) float32 window of it; the one
+    transposition of the kernel is at the store, a q-block at a time,
+    under the ``cond`` of the head's last k-block (the first zeroes)."""
+    seq = 64
+    kernel = _kernel_jaxprs(dtype)["flash_bwd"]
+    acc, kt = kernel.invars[-2:]
+    assert (acc.aval.shape, acc.aval.dtype) == ((D, seq), jnp.float32)
+    assert (kt.aval.shape, kt.aval.dtype) == ((D, BK), dtype)
+    (body,) = _loops(kernel)
+    writes = [e for e in _eqns(body)
+              if e.primitive.name in ("swap", "addupdate")
+              and e.invars[0].aval.shape == (D, seq)]
+    assert len(writes) == 1
+    assert writes[0].invars[1].aval.shape == (D, BQ)
+    assert writes[0].invars[1].aval.dtype == jnp.float32
+    conds = [e for e in kernel.eqns if e.primitive.name == "cond"]
+    assert len(conds) == 2
+    transposes = [e for e in _eqns(kernel) if e.primitive.name == "transpose"]
+    assert [e.invars[0].aval.shape for e in transposes] == [(D, BQ)]
+    assert transposes[0].invars[0].aval.dtype == jnp.float32
+    assert "transpose" in [e.primitive.name for e in _eqns_of(conds[1])]
 
 
 # -- (b) a plain loop, and the crossed tiles behind it -----------------------
@@ -134,8 +186,8 @@ def test_a_causal_kernel_masks_its_crossed_tiles_outside_the_loop(name):
     (plain,) = _loops(kernel)
     assert not _POSITIONS & set(_names(plain))
     outside = [e.primitive.name for e in kernel.eqns]
-    # two iotas a crossed tile
-    assert outside.count("iota") == 2 * (BODIES[name] - 1)
+    # two iotas a crossed tile (and the identity of the backward's I K^T)
+    assert outside.count("iota") == 2 * (BODIES[name] - 1 + ONCE[name])
 
 
 @pytest.mark.parametrize("own,other,want", [
@@ -155,7 +207,7 @@ def test_a_true_length_inside_a_block_keeps_the_masked_loop(name):
     to program, so they are a loop of their own."""
     loops = _loops(_kernel_jaxprs(jnp.bfloat16, seq=80)[name])
     with_positions = ["iota" in _names(body) for body in loops]
-    assert with_positions == ([True, False] if name == "flash_bwd_dkv"
+    assert with_positions == ([True, False] if name == "flash_bwd"
                               else [False, True])
     plain = loops[with_positions.index(False)]
     assert not _POSITIONS & set(_names(plain))
@@ -168,7 +220,10 @@ def test_aligned_lengths_without_a_diagonal_run_the_plain_loop_alone(name):
     kernel = _kernel_jaxprs(jnp.bfloat16, causal=False,
                             mask_shape=(B, 1, 1, 64))[name]
     assert len(_loops(kernel)) == 1
-    assert "iota" not in _names(kernel)
+    # ... but the (D, D) identity the backward makes K^T with
+    iotas = [e.outvars[0].aval.shape for e in _eqns(kernel)
+             if e.primitive.name == "iota"]
+    assert iotas == [(D, D)] * 2 * ONCE[name]
 
 
 @pytest.mark.parametrize("name", KERNELS)
@@ -177,8 +232,8 @@ def test_a_partial_block_is_masked_and_the_whole_ones_are_not(name):
     and every tile of the last q-block run the masked body."""
     loops = _loops(_kernel_jaxprs(jnp.float32, causal=False, seq=40)[name])
     with_positions = ["iota" in _names(body) for body in loops]
-    # dK/dV: the k-blocks' partial one, the plain tiles, the q tail
-    assert with_positions == ([True, False, True] if name == "flash_bwd_dkv"
+    # backward: the k-blocks' partial one, the plain tiles, the q tail
+    assert with_positions == ([True, False, True] if name == "flash_bwd"
                               else [False, True])
 
 
@@ -205,12 +260,13 @@ def _reference(q, k, v, ct, bias, causal, keep, p_drop):
     return (out,) + vjp(ct)
 
 
-def _bias(kind, seq, rng):
+def _bias(kind, seq, rng, sk=None):
+    sk = seq if sk is None else sk
     if kind == "none":
         return None
     if kind == "key":
-        return np.where(rng.rand(B, 1, 1, seq) < 0.3, -1e9, 0.0).astype("f4")
-    m = (rng.randn(1, 1, seq, seq) * 2).astype("f4")
+        return np.where(rng.rand(B, 1, 1, sk) < 0.3, -1e9, 0.0).astype("f4")
+    m = (rng.randn(1, 1, seq, sk) * 2).astype("f4")
     if kind == "masked_rows":
         m[0, 0, 3, :] = -1e9            # a row with every key masked
         m[0, 0, seq - 1, :seq - 2] = -1e9   # and the last row, nearly
@@ -225,7 +281,7 @@ CASES = list(itertools.product(
     ((16, 16), (24, 16)),                   # head sizes d, dv
     ("none", "key", "full", "masked_rows"),
     (0.0, 0.1)))                            # dropout
-# BQ > BK: two crossed tiles a q-block in the forward and dQ kernels
+# BQ > BK: two crossed tiles a q-block in the forward kernel
 CASES += list(itertools.product((True,), ("aligned", "2.5 blocks"),
                                 ((32, 16),), ((24, 16),), ("none", "key"),
                                 (0.0,)))
@@ -238,17 +294,40 @@ CASES += list(itertools.product((True,), ("aligned", "2.5 blocks"),
          for c in CASES])
 def test_bf16_kernels_against_float32_attention(causal, lengths, blocks,
                                                 heads, mask, p_drop):
+    seq = 2 * blocks[1] if lengths == "aligned" else 5 * blocks[1] // 2
+    _against_float32_attention(
+        causal, seq, seq, blocks, heads, mask, p_drop,
+        len(str((causal, lengths, blocks, heads, mask, p_drop))))
+
+
+# sq != sk (the diagonal starts at the first row and the first key): a q
+# side of 2.5 blocks against 4.5 k-blocks and the other way round, so a
+# k-block's walk ends, or starts, where the other side's rows do; every
+# k-block of a head adds its part to the one dq accumulator
+CROSS = list(itertools.product(
+    (False, True), ((40, 72), (72, 40)), ("none", "key", "full"),
+    (0.0, 0.1)))
+
+
+@pytest.mark.parametrize(
+    "causal,lengths,mask,p_drop", CROSS,
+    ids=["-".join(("causal" if c[0] else "bidir", "q%d.k%d" % c[1], c[2],
+                   "drop%g" % c[3])) for c in CROSS])
+def test_bf16_kernels_against_float32_attention_at_sq_not_sk(causal, lengths,
+                                                             mask, p_drop):
+    _against_float32_attention(causal, *lengths, (16, 16), (24, 16), mask,
+                               p_drop, len(str((causal, mask, p_drop))))
+
+
+def _against_float32_attention(causal, seq, sk, blocks, heads, mask, p_drop,
+                               salt):
     (bq, bk), (d, dv) = blocks, heads
-    seq = 2 * bk if lengths == "aligned" else 5 * bk // 2
-    rng = np.random.RandomState(len(str((causal, lengths, blocks, heads,
-                                         mask, p_drop))) + seq + d)
+    rng = np.random.RandomState(salt + seq + d)
     # bfloat16 inputs; the reference reads the same rounded numbers
-    q, k = (jnp.asarray(rng.randn(B, H, seq, d), jnp.bfloat16)
-            for _ in range(2))
-    v, ct = (jnp.asarray(rng.randn(B, H, seq, dv), jnp.bfloat16)
-             for _ in range(2))
-    bias = _bias(mask, seq, rng)
-    mode = _mask_mode(None if bias is None else bias.shape, B, H, seq, seq)
+    q, k, v, ct = (jnp.asarray(rng.randn(B, H, n, w), jnp.bfloat16)
+                   for n, w in ((seq, d), (sk, d), (sk, dv), (seq, dv)))
+    bias = _bias(mask, seq, rng, sk)
+    mode = _mask_mode(None if bias is None else bias.shape, B, H, seq, sk)
     assert mode == {"none": None, "key": "key"}.get(mask, "full")
     seed = jnp.asarray([7, 11], jnp.int32)
     canon = None if bias is None else _canon_mask(jnp.asarray(bias))
@@ -262,8 +341,8 @@ def test_bf16_kernels_against_float32_attention(causal, lengths, blocks,
     keep = None
     if p_drop:
         pad = lambda n, blk: -(-n // blk) * blk
-        keep = _host_keep_mask(seed, B * H, pad(seq, bq), pad(seq, bk),
-                               p_drop)[:, :seq, :seq]
+        keep = _host_keep_mask(seed, B * H, pad(seq, bq), pad(sk, bk),
+                               p_drop)[:, :seq, :sk]
     f32 = lambda a: jnp.asarray(a, jnp.float32)
     want = _reference(f32(q), f32(k), f32(v), f32(ct), bias, causal, keep,
                       p_drop)
@@ -272,6 +351,36 @@ def test_bf16_kernels_against_float32_attention(causal, lengths, blocks,
         a, w = np.asarray(f32(a)), np.asarray(w)
         assert np.isfinite(a).all(), name
         # bfloat16 products and a bfloat16 result: 2^-8 of the array's size
+        np.testing.assert_allclose(a, w, atol=0.03 * max(1.0, np.abs(w).max()),
+                                   err_msg=name)
+        assert np.linalg.norm(a - w) <= 0.012 * np.linalg.norm(w), name
+
+
+@pytest.mark.parametrize("length,block,bq,bk", [
+    (64, 4, 16, 16), (40, 4, 16, 16), (64, 4, 16, 32), (64, 4, 32, 16),
+    (64, 16, 16, 16)], ids=lambda n: str(n))
+def test_bf16_kernels_under_the_block_structure(length, block, bq, bk):
+    """Two copies of ``length`` rows: the backward runs every clean k-block
+    twice, and each copy's dq has the accumulator for half the grid."""
+    rng = np.random.RandomState(length + block + bq + 2 * bk)
+    q, k = (jnp.asarray(rng.randn(B, H, 2 * length, D), jnp.bfloat16)
+            for _ in range(2))
+    v, ct = (jnp.asarray(rng.randn(B, H, 2 * length, DV), jnp.bfloat16)
+             for _ in range(2))
+
+    def f(q, k, v):
+        return _flash_bd(q, k, v, block.bit_length() - 1, None, bq, bk)
+
+    out, vjp = jax.vjp(f, q, k, v)
+    f32 = lambda a: jnp.asarray(a, jnp.float32)
+    allowed = np.where(block_diffusion_mask(length, block), 0.0,
+                       -np.inf).astype("f4")[None, None]
+    want = _reference(f32(q), f32(k), f32(v), f32(ct), allowed, False, None,
+                      0.0)
+    for name, a, w in zip(("o", "dq", "dk", "dv"), (out,) + vjp(ct), want):
+        assert a.dtype == jnp.bfloat16
+        a, w = np.asarray(f32(a)), np.asarray(w)
+        assert np.isfinite(a).all(), name
         np.testing.assert_allclose(a, w, atol=0.03 * max(1.0, np.abs(w).max()),
                                    err_msg=name)
         assert np.linalg.norm(a - w) <= 0.012 * np.linalg.norm(w), name
@@ -343,3 +452,48 @@ def test_a_float32_caller_is_not_counted_native():
 def test_tile_counts_by_hand(bq, bk, causal, sq, want):
     assert _tile_counts(32, block_q=bq, block_k=bk, sq=sq, sk=sq,
                         causal=causal) == want
+
+
+# -- (e) the backward's counters at the four flash cells' shapes --------------
+
+@pytest.mark.parametrize("qk,v,kw,mask,tiles", [
+    ((1, 32, 8192, 192), (1, 32, 8192, 128), dict(causal=True), None, 4352),
+    ((1, 32, 8192, 128), (1, 32, 8192, 128), dict(causal=True), None, 4352),
+    ((16, 12, 512, 64), (16, 12, 512, 64), {}, (16, 1, 1, 512), 192),
+    # 32 heads x (16 x 17 + 16) tiles of 512 x 512 over two copies of 8,192
+    ((1, 32, 16384, 128), (1, 32, 16384, 128), dict(diffusion_block=4), None,
+     9216),
+], ids=["joyai", "nemotron", "seq512", "sdar"])
+def test_backward_counters_at_a_cells_shape(qk, v, kw, mask, tiles):
+    """A call site whose backward is traced counts one fused backward and
+    five products a score tile of its forward's walk; a forward alone
+    counts neither."""
+    args = [jax.ShapeDtypeStruct(qk, jnp.bfloat16)] * 2 + [
+        jax.ShapeDtypeStruct(v, jnp.bfloat16)]
+    if mask is not None:
+        args.append(jax.ShapeDtypeStruct(mask, jnp.float32))
+
+    def call(q, k, v, *m):
+        return flash_attention(
+            pt.Tensor(q), pt.Tensor(k), pt.Tensor(v),
+            attn_mask=pt.Tensor(m[0]) if m else None, force=True,
+            **kw).data.astype(jnp.float32).sum()
+
+    def gained(fn):
+        before = monitor.snapshot("flash_attention")
+        jax.eval_shape(fn, *args)
+        after = monitor.snapshot("flash_attention")
+        return {key.split(".", 1)[1]: after[key] - before.get(key, 0)
+                for key in after}
+
+    forward = gained(call)
+    assert forward["kernel_traced"] == 1 and forward["tiles"] == tiles
+    assert not forward.get("backward_fused_traced")
+    assert not forward.get("backward_products")
+    both = gained(jax.grad(call, argnums=(0, 1, 2)))
+    assert both["kernel_traced"] == 1 and both["tiles"] == tiles
+    assert both["backward_fused_traced"] == 1
+    assert both["backward_products"] == 5 * tiles
+    if "diffusion_block" in kw:
+        assert tiles == _bd_tile_counts(32, 8192, block_q=512, block_k=512,
+                                        shift=2)[0]

@@ -151,8 +151,8 @@ def test_flash_attention_key_mask_fused():
 
 def test_flash_attention_key_mask_grads():
     """Backward through the fused key-mask path (the default BERT path)
-    matches sdpa — guards the (1,BK) broadcast branches in both backward
-    kernels."""
+    matches sdpa — guards the (1,BK) key-bias broadcast in the backward
+    kernel."""
     b, h, s, d = 2, 2, 24, 8
     rng = np.random.RandomState(9)
     qn = rng.randn(b, h, s, d).astype("f4")
@@ -245,8 +245,8 @@ def _masked_row_case(kind, b, h, s, rng):
 
 @pytest.mark.parametrize("kind", ["key", "full", "causal"])
 def test_flash_row_statistics_one_value_a_row(kind):
-    """The soft-max row statistics cross from the forward to the two
-    backward kernels as one value a (batch*head, row), sequence on the
+    """The soft-max row statistics cross from the forward to the
+    backward kernel as one value a (batch*head, row), sequence on the
     lane axis. Several q-blocks AND k-blocks, seq not a multiple of the
     block, a fully masked row: where a relaid statistic could land on
     the wrong row, output, statistics and dQ/dK/dV must still be the
