@@ -216,6 +216,23 @@ def phase_kernels(rows, hidden, batch, heads, seq, head_dim,
     run(f"flash_attention[{batch}x{heads}x{seq}x{2 * head_dim},bf16,"
         f"block_diffusion]", k_bd, r_bd, (q, k, v, ct), 3, tol_bf16, 2)
 
+    # a sliding window of a quarter of the sequence beside the causal
+    # diagonal, against float32 attention under the dense window mask
+    from paddle_tpu.ops.pallas.flash_attention import sliding_window_mask
+    band = jnp.asarray(sliding_window_mask(seq, seq // 4))
+
+    def k_win(q, k, v, ct):
+        out = flash_attention(q, k, v, causal=True, window=seq // 4,
+                              force=True)
+        return (_raw(out).astype(jnp.float32) * ct).sum()
+
+    def r_win(q, k, v, ct):
+        out = F.scaled_dot_product_attention(q, k, v, attn_mask=band)
+        return (_raw(out) * ct).sum()
+
+    run(f"flash_attention[{batch}x{heads}x{seq}x{2 * head_dim},bf16,"
+        f"window{seq // 4}]", k_win, r_win, (q, k, v, ct), 3, tol_bf16, 2)
+
     # the Mamba-2 scan over (batch, seq, 2 * heads heads of 64 in `heads`
     # groups, state 128), bf16 products, all seven gradients
     from paddle_tpu.ops.pallas import ssd_scan as ssd

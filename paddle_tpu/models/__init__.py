@@ -33,6 +33,9 @@ def __getattr__(name):
     if name in ("SDARMoEConfig", "SDARMoEForBlockDiffusion"):
         from . import sdar_moe
         return getattr(sdar_moe, name)
+    if name in ("SmallThinkerConfig", "SmallThinkerForCausalLM"):
+        from . import smallthinker
+        return getattr(smallthinker, name)
     if name in ("Transformer",):
         from . import transformer
         return getattr(transformer, name)

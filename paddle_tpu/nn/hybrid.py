@@ -104,6 +104,10 @@ class GroupedQueryAttention(Layer):
     / ``sdar_moe`` attention. ``diffusion_block`` puts the block-diffusion
     structure in the causal mask's place: the rows are a sequence's noisy
     copy and then its clean one (``ops.pallas.flash_attention``).
+    ``window`` beside ``causal`` is a sliding window: a row sees itself
+    and the ``window - 1`` positions before it (``smallthinker``'s window
+    layers; its global layers are this class with neither ``window`` nor
+    ``rope_theta``).
 
     The K/V heads are repeated to ``num_heads`` in front of the attention
     op, which is the flash dispatch (``ops.pallas.flash_attention``: the
@@ -115,7 +119,7 @@ class GroupedQueryAttention(Layer):
 
     def __init__(self, hidden_size, num_heads, num_kv_heads, head_dim,
                  causal=True, qk_norm_epsilon=None, rope_theta=None,
-                 diffusion_block=None):
+                 diffusion_block=None, window=None):
         super().__init__()
         if num_heads % num_kv_heads:
             raise ValueError(f"GroupedQueryAttention: {num_heads} query "
@@ -127,6 +131,7 @@ class GroupedQueryAttention(Layer):
         self.num_heads, self.num_kv_heads = num_heads, num_kv_heads
         self.head_dim, self.causal = head_dim, causal
         self.rope_theta, self.diffusion_block = rope_theta, diffusion_block
+        self.window = window
         self.q_proj = Linear(hidden_size, num_heads * head_dim,
                              bias_attr=False)
         self.k_proj = Linear(hidden_size, num_kv_heads * head_dim,
@@ -173,7 +178,8 @@ class GroupedQueryAttention(Layer):
         q, k, v = self.qkv(x, positions)
         from ..ops.pallas import flash_attention
         ctx = flash_attention(q, k, v, causal=self.causal, force=force_flash,
-                              diffusion_block=self.diffusion_block)
+                              diffusion_block=self.diffusion_block,
+                              window=self.window)
         ctx = ctx.transpose([0, 2, 1, 3]).reshape(
             [b, s, self.num_heads * self.head_dim])
         return self.o_proj(ctx)

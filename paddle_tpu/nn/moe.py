@@ -139,7 +139,11 @@ class RoutedMoE(Layer):
     ``gated=True`` ``W_down(silu(W_gate x) * W_up x)`` (DeepSeek-V3,
     ``joyai_llm_flash``): ``experts_gate`` beside ``experts_up``, and the
     shared expert a :class:`GatedMLP` named ``shared_experts`` in place of
-    ``shared_up`` / ``shared_down``. The layer is TOLD which experts
+    ``shared_up`` / ``shared_down``; ``activation="relu"`` gates with
+    ``relu(W_gate x)`` instead (``smallthinker``). ``forward(u,
+    router_input=t)`` routes by ``t`` and feeds the experts ``u`` (a
+    router placed ahead of attention reads the block's input, the experts
+    what attention left). The layer is TOLD which experts
     it holds (``experts_held``, a range over the model's ``num_experts``),
     routes over all of them, and returns what its own experts give for
     the tokens routed to them, plus the shared expert:
@@ -167,7 +171,7 @@ class RoutedMoE(Layer):
 
     def __init__(self, d_model, d_expert, num_experts, top_k,
                  d_shared=None, experts_held=None, routed_scaling_factor=1.0,
-                 gated=False, scoring="sigmoid"):
+                 gated=False, scoring="sigmoid", activation="silu"):
         super().__init__()
         import jax.numpy as jnp
         from .layers import Linear
@@ -181,6 +185,10 @@ class RoutedMoE(Layer):
         self.experts_held = held
         self.routed_scaling_factor = float(routed_scaling_factor)
         self.scoring = scoring
+        if activation != "silu" and (not gated or d_shared):
+            raise ValueError(f"RoutedMoE: activation {activation!r} is the "
+                             f"gate of gated experts with no shared expert")
+        self.activation = activation
         self.router = Linear(d_model, num_experts, bias_attr=False,
                              weight_attr=I.Normal(0.0, 0.02))
         # the sigmoid router's selection bias: moved by a balancing rule
@@ -212,17 +220,19 @@ class RoutedMoE(Layer):
                                             jnp.int32)), owner=self),
             persistable=False)
 
-    def forward(self, u):
+    def forward(self, u, router_input=None):
         from ..ops import moe as M
         from ..ops import nn_ops as F
         weights, experts = M.moe_route(
-            u, self.router.weight, self.e_score_correction_bias,
+            u if router_input is None else router_input,
+            self.router.weight, self.e_score_correction_bias,
             top_k=self.top_k, scale=self.routed_scaling_factor,
             scoring=self.scoring)
         y, seen = M.moe_experts(u, experts, weights, self.experts_up,
                                 self.experts_down,
                                 first_expert=self.experts_held.start,
-                                w_gate=self.experts_gate)
+                                w_gate=self.experts_gate,
+                                activation=self.activation)
         self.stats.data = self.stats.data + seen.data
         if self.shared_experts is not None:
             y = y + self.shared_experts(u)

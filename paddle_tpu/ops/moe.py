@@ -17,7 +17,8 @@ impl through ``dispatch.apply``:
   its rows (``_ladder``: from ``MIN_ROWS`` up by doubling to the number
   of tokens, which is the most one expert can draw), gathers that
   many rows, runs its products on them (two, ``W_down relu(W_up x)^2``;
-  three with a gate, ``W_down (silu(W_gate x) * W_up x)``) and adds the
+  three with a gate, ``W_down (silu(W_gate x) * W_up x)``, or with
+  ``activation="relu"`` ``W_down (relu(W_gate x) * W_up x)``) and adds the
   result back to its tokens. Dropless by construction: the ladder's last rung holds
   every token, so no imbalance can overflow it. The backward pass is
   written by hand (``jax.custom_vjp``) over the same rows and makes the
@@ -127,12 +128,13 @@ def _accumulator(rows, kernel):
 
 
 def _grouped_rows(rows, gate, ups, w_down, order, rung, ladder, dot_dtype,
-                  kernel):
+                  kernel, relu_gate=False):
     """``y[t] = sum_e gate[e, t] W_down[e] h_e(rows[t])`` over the first
     ``ladder[rung[e]]`` tokens of ``order[e]``; ``gate`` is 0 for every
     token after an expert's own. ``ups`` is ``(w_up,)`` for ``h = relu(W_up
     x)^2`` and ``(w_gate, w_up)`` for the gated ``h = silu(W_gate x) *
-    (W_up x)``. float32 [tokens, d]."""
+    (W_up x)`` (``relu_gate``: ``relu(W_gate x) * (W_up x)``). float32
+    [tokens, d]."""
     y, add_rows = _accumulator(rows, kernel)
 
     def expert(y, xs):
@@ -147,7 +149,8 @@ def _grouped_rows(rows, gate, ups, w_down, order, rung, ladder, dot_dtype,
                 x = rows[at]
                 a, u = (_dot(x, w.astype(dot_dtype), ((1,), (0,)))
                         for w in ups)
-                h = jax.nn.silu(a) * u * g[at][:, None]
+                act = jax.nn.relu(a) if relu_gate else jax.nn.silu(a)
+                h = act * u * g[at][:, None]
             out = _dot(h.astype(dot_dtype), down.astype(dot_dtype),
                        ((1,), (0,)))
             return add_rows(y, at, out)
@@ -158,17 +161,17 @@ def _grouped_rows(rows, gate, ups, w_down, order, rung, ladder, dot_dtype,
     return y.reshape(rows.shape)
 
 
-_grouped = jax.custom_vjp(_grouped_rows, nondiff_argnums=(6, 7, 8))
+_grouped = jax.custom_vjp(_grouped_rows, nondiff_argnums=(6, 7, 8, 9))
 
 
 def _grouped_fwd(rows, gate, ups, w_down, order, rung, ladder, dot_dtype,
-                 kernel):
+                 kernel, relu_gate=False):
     y = _grouped_rows(rows, gate, ups, w_down, order, rung, ladder,
-                      dot_dtype, kernel)
+                      dot_dtype, kernel, relu_gate)
     return y, (rows, gate, ups, w_down, order, rung)
 
 
-def _grouped_bwd(ladder, dot_dtype, kernel, saved, dy):
+def _grouped_bwd(ladder, dot_dtype, kernel, relu_gate, saved, dy):
     rows, gate, ups, w_down, order, rung = saved
     f32 = jnp.float32
     dx, add_rows = _accumulator(rows, kernel)
@@ -195,15 +198,19 @@ def _grouped_bwd(ladder, dot_dtype, kernel, saved, dy):
             downc = down.astype(dot_dtype)
             a = _dot(x, gatec, ((1,), (0,)))
             u = _dot(x, upc, ((1,), (0,)))
-            sig = jax.nn.sigmoid(a)
-            act = a * sig                                       # silu(a)
+            if relu_gate:
+                act = jax.nn.relu(a)
+            else:
+                sig = jax.nn.sigmoid(a)
+                act = a * sig                                   # silu(a)
             h = act * u
             d_h = _dot(dyr, downc, ((1,), (1,)))                # [cap, f]
             d_down = _dot((h * ga).astype(dot_dtype), dyr, ((0,), (0,)))
             d_gate = jnp.zeros(g.shape, f32).at[at].set(
                 jnp.sum(d_h * h, -1), unique_indices=True)
             d_h = d_h * ga
-            d_a = (d_h * u * (sig + act * (1.0 - sig))).astype(dot_dtype)
+            d_a = (d_h * u * ((a > 0).astype(f32) if relu_gate
+                              else sig + act * (1.0 - sig))).astype(dot_dtype)
             d_u = (d_h * act).astype(dot_dtype)
             dx = add_rows(dx, at, _dot(d_a, gatec, ((1,), (1,)))
                           + _dot(d_u, upc, ((1,), (1,))))
@@ -224,7 +231,7 @@ _grouped.defvjp(_grouped_fwd, _grouped_bwd)
 
 
 def _routed(x, experts, weights, w_up, w_down, w_gate=None, *, first,
-            dot_dtype, kernel=False):
+            dot_dtype, kernel=False, relu_gate=False):
     f32 = jnp.float32
     lead, d = x.shape[:-1], x.shape[-1]
     rows = x.reshape(-1, d)
@@ -244,7 +251,7 @@ def _routed(x, experts, weights, w_up, w_down, w_gate=None, *, first,
     rung = jnp.minimum(rung, len(ladder) - 1).astype(jnp.int32)
     ups = (w_up,) if w_gate is None else (w_gate, w_up)
     y = _grouped(rows.astype(dot_dtype), gate, ups, w_down, order, rung,
-                 ladder, dot_dtype, kernel)
+                 ladder, dot_dtype, kernel, relu_gate)
     computed = jnp.asarray(ladder, jnp.int32)[rung]
     stats = jnp.stack([jnp.sum(sizes),
                        jnp.sum(jnp.maximum(sizes - computed, 0)),
@@ -254,11 +261,12 @@ def _routed(x, experts, weights, w_up, w_down, w_gate=None, *, first,
 
 
 def moe_experts(x, experts, weights, w_up, w_down, first_expert=0,
-                w_gate=None, name=None):
+                w_gate=None, activation="silu", name=None):
     """``(y, stats)``: ``y[t] = sum over t's chosen experts i that are
     held here of weights[t, i] * W_down[i] h_i(x[t])``, with ``h_i(x) =
     relu(W_up[i] x)^2``, or, given ``w_gate``, the gated ``silu(W_gate[i]
-    x) * (W_up[i] x)``.
+    x) * (W_up[i] x)`` (``activation="relu"``: ``relu(W_gate[i] x) *
+    (W_up[i] x)``).
 
     ``experts`` / ``weights`` [..., k] from :func:`moe_route`, over all the
     model's experts; ``w_up`` (and ``w_gate``) [held, d, f] and ``w_down``
@@ -277,6 +285,10 @@ def moe_experts(x, experts, weights, w_up, w_down, first_expert=0,
     say which a call site traced."""
     from .. import amp, monitor
     from . import pallas
+    if activation not in ("silu", "relu") or (
+            activation == "relu" and w_gate is None):
+        raise ValueError(f"moe_experts: activation {activation!r} is "
+                         f"neither 'silu' nor, beside w_gate, 'relu'")
     dot_dtype = amp.compute_dtype() if amp.is_enabled() else None
     # read off the call, as ssd_scan: the kernel where its tiles fit every
     # rung of this call's ladder and the registry has it on
@@ -290,7 +302,7 @@ def moe_experts(x, experts, weights, w_up, w_down, first_expert=0,
     def impl(x, experts, weights, w_up, w_down, *gate, first):
         return _routed(x, experts, weights, w_up, w_down, *gate, first=first,
                        dot_dtype=dot_dtype or jnp.result_type(x),
-                       kernel=kernel)
+                       kernel=kernel, relu_gate=activation == "relu")
 
     args = (x, experts, weights, w_up, w_down)
     with _pscope("F.moe_experts"):

@@ -7,13 +7,28 @@ formulation).
 Forward: grid (batch*heads, q-blocks); each program walks k/v-blocks with
 the online-softmax recurrence (running max m, normalizer l, accumulator
 acc) so the S×S score matrix never hits HBM. A mask enters in one of
-three ways: as an ARRAY (an additive key bias [B,1,1,Sk] or a full
+four ways: as an ARRAY (an additive key bias [B,1,1,Sk] or a full
 [.,.,Sq,Sk] bias, added to the scores inside the kernel), as the causal
-diagonal, or as the BLOCK-DIFFUSION STRUCTURE (below) — the last two are
-facts of the call that cost no operand and decide which tiles a program
-walks at all. Attention-probability dropout is drawn in-kernel from the
+diagonal, as a SLIDING WINDOW beside it (below), or as the BLOCK-DIFFUSION
+STRUCTURE (below) — the last three are facts of the call that cost no
+operand and decide which tiles a program walks at all. Attention-probability dropout is drawn in-kernel from the
 TPU PRNG, seeded per (bh, q-block, k-block) tile so the backward
 regenerates the identical keep-mask without ever storing it.
+
+Sliding window (PR 41; ``flash_attention(causal=True, window=W)``,
+``sliding_window_mask`` is the rule: row i sees keys ``(i - W, i]``). The
+same two kernel bodies take it (``window=`` in their static parameters;
+without it they trace what they traced before; under it the calls are
+named ``flash_win_fwd`` and ``flash_win_bwd``). A forward program walks
+the k-tiles from the one that holds the first key its first row sees to
+the diagonal's: 9 of them at most at 512 x 512 under a window of 4,096
+(252 of 1,024 a head at 16,384 positions), the first crossed by the
+window's lower edge and the last by the diagonal; a backward program walks
+its k-block's q-tiles from the diagonal down to the last row that sees its
+last key, and dq's accumulator takes parts from at most 9 k-blocks a
+q-block. Positions inside a crossed tile come from iotas, as the
+diagonal's. The side a program keeps whole is still the whole side (the
+block rule at the end of this file says what that costs).
 
 Block-diffusion structure (PR 33; ``flash_attention(diffusion_block=B)``,
 ``block_diffusion_mask`` is the rule). q, k and v hold two copies of a
@@ -174,13 +189,16 @@ def _dot_nt(a, b):
                                preferred_element_type=jnp.float32)
 
 
-def _k_tile_ranges(xp, qi, *, block_q, block_k, sq, sk, causal):
+def _k_tile_ranges(xp, qi, *, block_q, block_k, sq, sk, causal, window=None):
     """The k-blocks q-block ``qi`` of the forward kernel walks, as
-    ``(n_plain, n_needed)``: every position of tiles ``[0, n_plain)`` is
-    valid, tiles ``[n_plain, n_needed)`` are crossed by the diagonal or by
-    a true length, the rest lies wholly above the diagonal. Integer
-    arithmetic; ``xp`` is ``jnp`` inside a kernel (``qi`` a program id) and
-    ``np`` on the host (``qi`` every q-block at once, ``_tile_counts``)."""
+    ``(first, plain_lo, plain_hi, n_needed)``: tiles ``[first, plain_lo)``
+    are crossed by the window's lower edge (none without a window: both
+    are 0), every position of tiles ``[plain_lo, plain_hi)`` is valid,
+    tiles ``[plain_hi, n_needed)`` are crossed by the diagonal or by a true
+    length, the rest lies wholly above the diagonal or below the window.
+    Integer arithmetic; ``xp`` is ``jnp`` inside a kernel (``qi`` a program
+    id) and ``np`` on the host (``qi`` every q-block at once,
+    ``_tile_counts``)."""
     n_needed, n_plain = -(-sk // block_k), sk // block_k
     if causal:
         n_needed = xp.minimum(
@@ -188,15 +206,28 @@ def _k_tile_ranges(xp, qi, *, block_q, block_k, sq, sk, causal):
         n_plain = xp.minimum(n_plain, (qi * block_q + 1) // block_k)
     if sq % block_q:        # the last q-block holds rows past sq
         n_plain = xp.where((qi + 1) * block_q <= sq, n_plain, 0)
-    return n_plain, n_needed
+    if window is None:
+        return 0, 0, n_plain, n_needed
+    # row i sees keys (i - window, i]: the first tile that holds one of the
+    # block's first row, the first whose every key the block's last row sees
+    first = xp.minimum(xp.maximum(qi * block_q - window + 1, 0) // block_k,
+                       n_needed)
+    whole = xp.maximum((qi + 1) * block_q - window + block_k - 1,
+                       0) // block_k
+    plain_lo = xp.minimum(xp.maximum(whole, first), n_needed)
+    plain_hi = xp.minimum(xp.maximum(n_plain, plain_lo), n_needed)
+    return first, plain_lo, plain_hi, n_needed
 
 
-def _q_tile_ranges(xp, j, *, block_q, block_k, sq, sk, causal):
+def _q_tile_ranges(xp, j, *, block_q, block_k, sq, sk, causal, window=None):
     """The q-blocks k-block ``j`` of the backward kernel walks, as ``(q_start,
-    plain_lo, plain_hi)``: tiles ``[q_start, plain_lo)`` are crossed by the
-    diagonal (two of them where ``block_q < block_k``), every position of
-    ``[plain_lo, plain_hi)`` is valid, and ``[plain_hi, cdiv(sq, block_q))``
-    is the one tile with rows past ``sq``, if there is one."""
+    plain_lo, plain_hi, q_end)``: tiles ``[q_start, plain_lo)`` are crossed
+    by the diagonal (two of them where ``block_q < block_k``), every
+    position of ``[plain_lo, plain_hi)`` is valid, and ``[plain_hi, cdiv(sq,
+    block_q))`` is the one tile with rows past ``sq``, if there is one.
+    ``q_end`` is None without a ``window``; under one, tiles ``[plain_hi,
+    q_end)`` are crossed by the window's lower edge (or hold rows past
+    ``sq``), and no row from ``q_end`` on sees a key of the block."""
     q_start, plain_lo, plain_hi = 0, 0, sq // block_q
     if causal:
         q_start = (j * block_k) // block_q
@@ -204,18 +235,29 @@ def _q_tile_ranges(xp, j, *, block_q, block_k, sq, sk, causal):
             plain_hi, ((j + 1) * block_k + block_q - 2) // block_q)
     if sk % block_k:        # the last k-block holds keys past sk
         plain_lo = xp.where((j + 1) * block_k <= sk, plain_lo, plain_hi)
-    return q_start, plain_lo, plain_hi
+    if window is None:
+        return q_start, plain_lo, plain_hi, None
+    # the block's last key is seen up to row (j + 1) block_k + window - 2;
+    # its first key by every row of a tile that ends by j block_k + window
+    q_end = xp.minimum(-(-sq // block_q),
+                       ((j + 1) * block_k + window - 2) // block_q + 1)
+    plain_lo = xp.minimum(plain_lo, q_end)
+    plain_hi = xp.minimum(xp.maximum(
+        xp.minimum(plain_hi, (j * block_k + window) // block_q), plain_lo),
+        q_end)
+    return q_start, plain_lo, plain_hi, q_end
 
 
-def _tile_counts(bh, *, block_q, block_k, sq, sk, causal):
+def _tile_counts(bh, *, block_q, block_k, sq, sk, causal, window=None):
     """(score tiles, tiles that run the masked body) of one forward call:
     the kernel's own bounds, added up over its grid on the host."""
     qi = np.arange(-(-sq // block_q))
-    n_plain, n_needed = _k_tile_ranges(np, qi, block_q=block_q,
-                                       block_k=block_k, sq=sq, sk=sk,
-                                       causal=causal)
-    tiles = int(np.sum(np.broadcast_to(n_needed, qi.shape)))
-    plain = int(np.sum(np.broadcast_to(n_plain, qi.shape)))
+    first, plain_lo, plain_hi, n_needed = (
+        np.broadcast_to(n, qi.shape) for n in _k_tile_ranges(
+            np, qi, block_q=block_q, block_k=block_k, sq=sq, sk=sk,
+            causal=causal, window=window))
+    tiles = int(np.sum(n_needed - first))
+    plain = int(np.sum(plain_hi - plain_lo))
     return bh * tiles, bh * (tiles - plain)
 
 
@@ -227,14 +269,17 @@ def _walk(lo, hi, body, carry):
     return jax.lax.fori_loop(lo, hi, body, carry)
 
 
-def _crossed_tiles(own, other, *, block_q, block_k, sq, sk, causal):
+def _crossed_tiles(own, other, *, block_q, block_k, sq, sk, causal,
+                   window=None):
     """How many tiles the diagonal crosses in every program of a kernel
     whose programs own blocks of ``own`` positions and walk blocks of
     ``other``, where the shapes alone say it: a square causal call of
     whole blocks, one block size a multiple of the other. None where the
-    count differs from program to program (a true length inside a block)."""
+    count differs from program to program (a true length inside a block;
+    a window so narrow that its lower edge reaches the diagonal's tiles)."""
     if (causal and sq == sk and sq % block_q == 0 and sk % block_k == 0
-            and (own % other == 0 or other % own == 0)):
+            and (own % other == 0 or other % own == 0)
+            and (window is None or window >= block_q + block_k)):
         return max(1, own // other)
     return None
 
@@ -252,16 +297,20 @@ def _walk_crossed(lo, hi, body, carry, count):
     return carry
 
 
-def _valid(shape, q0, k0, q_axis, *, sq, sk, block_q, block_k, causal):
+def _valid(shape, q0, k0, q_axis, *, sq, sk, block_q, block_k, causal,
+           window=None):
     """Which positions of a score tile that starts at query ``q0`` and key
     ``k0`` (queries along ``q_axis``) may attend. A length that is a
-    multiple of its block has no position past it: a fact of the shapes."""
+    multiple of its block has no position past it: a fact of the shapes.
+    Under a ``window`` a row sees itself and the ``window - 1`` positions
+    before it."""
     q_pos = q0 + jax.lax.broadcasted_iota(jnp.int32, shape, q_axis)
     k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis)
     valid = None
     for term in ((q_pos < sq) if sq % block_q else None,
                  (k_pos < sk) if sk % block_k else None,
-                 (q_pos >= k_pos) if causal else None):
+                 (q_pos >= k_pos) if causal else None,
+                 (q_pos - k_pos < window) if window is not None else None):
         if term is not None:
             valid = term if valid is None else valid & term
     return valid
@@ -350,14 +399,14 @@ def _bd_tile_counts(bh, length, *, block_q, block_k, shift):
 
 def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, mask_ref, keep_ref, *rest,
                 block_q, block_k, sq, sk, causal, scale, mask_mode,
-                dropout_p, threshold, drop_mode, bd=None):
+                dropout_p, threshold, drop_mode, bd=None, window=None):
     # q_ref: (1, BQ, D); k_ref: (1, SKp, D); v_ref: (1, SKp, DV);
     # mask_ref: (1, {1, BQ}, SKp); o_ref: (1, BQ, DV). Under the block
     # structure k_ref / v_ref are the CLEAN copy's side and ``own`` the
     # noisy copy's (1, BQ, D | DV) block at this q-block's positions
     *own, o_ref, m_ref, l_ref = rest
     geom = dict(block_q=block_q, block_k=block_k, sq=sq, sk=sk,
-                causal=causal)
+                causal=causal, window=window)
     bh = pl.program_id(0)
     qi = pl.program_id(1)
     # scaled in float32 once a q-block, then rounded to what the MXU takes
@@ -408,9 +457,10 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, mask_ref, keep_ref, *rest,
         def valid(shape, j):
             return _valid(shape, qi * block_q, j * block_k, 0, **geom)
 
-        n_plain, n_needed = _k_tile_ranges(jnp, qi, **geom)
+        first, plain_lo, n_plain, n_needed = _k_tile_ranges(jnp, qi, **geom)
         crossed = _crossed_tiles(block_q, block_k, **geom)
     else:
+        first = plain_lo = 0
         clean, pj = _bd_rows(qi, bd[1])
 
         def valid(shape, j):
@@ -427,8 +477,11 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, mask_ref, keep_ref, *rest,
                     v_ref[0, cols, :].astype(v_dtype), carry, j, cols,
                     (lambda shape: valid(shape, j)) if masked else None)
 
-    carry = _walk(0, n_plain, functools.partial(tile, masked=False),
+    # under a window, first the tiles its lower edge crosses
+    carry = _walk(first, plain_lo, functools.partial(tile, masked=True),
                   (m0, l0, acc0))
+    carry = _walk(plain_lo, n_plain, functools.partial(tile, masked=False),
+                  carry)
     carry = _walk_crossed(
         n_plain, n_needed, functools.partial(tile, masked=True), carry,
         crossed)
@@ -456,7 +509,7 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, mask_ref, keep_ref, *rest,
 def _bwd_kernel(seed_ref, q_ref, k_ref, v_ref, mask_ref, keep_ref,
                 m_ref, linv_ref, delta_ref, do_ref, *rest,
                 block_q, block_k, sq, sk, causal, scale, mask_mode,
-                dropout_p, threshold, drop_mode, bd=None):
+                dropout_p, threshold, drop_mode, bd=None, window=None):
     # this program owns ONE k-block (grid (bh, k-blocks)) and loops
     # q-blocks. q_ref: (1, SQp, D); do_ref: (1, SQp, DV); k_ref: (1, BK, D);
     # v_ref: (1, BK, DV); mask_ref: (1, {1, SQp}, BK); m/linv/delta:
@@ -481,7 +534,7 @@ def _bwd_kernel(seed_ref, q_ref, k_ref, v_ref, mask_ref, keep_ref,
         (kn_ref, vn_ref, dq_ref, dk_ref, dv_ref, dkn_ref, dvn_ref,
          dqt_ref, kt_ref) = rest
     geom = dict(block_q=block_q, block_k=block_k, sq=sq, sk=sk,
-                causal=causal)
+                causal=causal, window=window)
     bh = pl.program_id(0)
     j = pl.program_id(1)
     # this k-block within its head (its copy): the accumulator's life
@@ -547,7 +600,7 @@ def _bwd_kernel(seed_ref, q_ref, k_ref, v_ref, mask_ref, keep_ref,
         def valid(shape, qi):
             return _valid(shape, qi * block_q, j * block_k, 1, **geom)
 
-        q_start, plain_lo, plain_hi = _q_tile_ranges(jnp, j, **geom)
+        q_start, plain_lo, plain_hi, q_end = _q_tile_ranges(jnp, j, **geom)
         count = _crossed_tiles(block_k, block_q, **geom)
     else:
         clean = _bd_rows(j, bd[2])[0]
@@ -582,7 +635,10 @@ def _bwd_kernel(seed_ref, q_ref, k_ref, v_ref, mask_ref, keep_ref,
                       for m in (False, True))
     carry = _walk_crossed(q_start, plain_lo, crossed, zeros(), count)
     carry = _walk(plain_lo, plain_hi, plain, carry)
-    if bd is None:
+    if window is not None:
+        # the tiles the window's lower edge crosses, and rows past sq
+        carry = _walk(plain_hi, q_end, crossed, carry)
+    elif bd is None:
         if sq % block_q:        # the one q-block with rows past sq
             carry = _walk(jnp.maximum(plain_hi, q_start), plain_hi + 1,
                           crossed, carry)
@@ -684,7 +740,7 @@ def _pad_axis(x, axis, new):
 
 
 def _flash_fwd_res(q, k, v, mask, mask_mode, seed, causal, scale, block_q,
-                   block_k, dropout_p):
+                   block_k, dropout_p, window=None, interpret=None):
     from . import interpret_mode
     b, h, sq, d = q.shape
     sk, dvh = k.shape[2], v.shape[3]
@@ -716,7 +772,7 @@ def _flash_fwd_res(q, k, v, mask, mask_mode, seed, causal, scale, block_q,
         mspec = pl.BlockSpec((1, 1, sk_pad), lambda i, j: (0, 0, 0),
                              memory_space=pltpu.VMEM)
     seed2 = jnp.asarray(seed, jnp.int32).reshape(2)
-    interp = interpret_mode()
+    interp = interpret_mode() if interpret is None else interpret
     drop_mode = "mask" if (interp and dropout_p > 0.0) else "prng"
     if drop_mode == "mask":
         keep3 = _host_keep_mask(seed2, b * h, sq_pad, sk_pad, dropout_p)
@@ -730,11 +786,7 @@ def _flash_fwd_res(q, k, v, mask, mask_mode, seed, causal, scale, block_q,
     # row statistics leave as (BH, 1, SQ): one value a row, seq on lanes
     stat_spec = pl.BlockSpec((1, 1, bq), lambda i, j: (i, 0, j),
                              memory_space=pltpu.VMEM)
-    out, mrow, lrow = pl.pallas_call(
-        functools.partial(
-            _fwd_kernel, block_q=bq, block_k=bk, sq=sq, sk=sk,
-            causal=causal, scale=s, mask_mode=mask_mode,
-            dropout_p=dropout_p, threshold=threshold, drop_mode=drop_mode),
+    call = dict(
         grid=(b * h, pl.cdiv(sq, bq)),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -756,14 +808,23 @@ def _flash_fwd_res(q, k, v, mask, mask_mode, seed, causal, scale, block_q,
             jax.ShapeDtypeStruct((b * h, 1, sq), jnp.float32),
             jax.ShapeDtypeStruct((b * h, 1, sq), jnp.float32),
         ],
-        interpret=interp,
-        name="flash_fwd",
-    )(seed2, q3, k3, v3, m3, keep3)
+        interpret=interp)
+    kernel = functools.partial(
+        _fwd_kernel, block_q=bq, block_k=bk, sq=sq, sk=sk, causal=causal,
+        scale=s, mask_mode=mask_mode, dropout_p=dropout_p,
+        threshold=threshold, drop_mode=drop_mode, window=window)
+    # one kernel body, and a name a call form: a trace tells them apart
+    if window is None:
+        run = pl.pallas_call(kernel, name="flash_fwd", **call)
+    else:
+        run = pl.pallas_call(kernel, name="flash_win_fwd", **call)
+    out, mrow, lrow = run(seed2, q3, k3, v3, m3, keep3)
     return out.reshape(b, h, sq, dvh), mrow, lrow
 
 
 def _flash_bwd(q, k, v, mask, mask_mode, seed, out, mrow, lrow, g, causal,
-               scale, block_q, block_k, dropout_p):
+               scale, block_q, block_k, dropout_p, window=None,
+               interpret=None):
     from . import interpret_mode
     b, h, sq, d = q.shape
     sk, dvh = k.shape[2], v.shape[3]
@@ -794,7 +855,7 @@ def _flash_bwd(q, k, v, mask, mask_mode, seed, out, mrow, lrow, g, causal,
         bh_to_g = lambda i: 0
     seed2 = jnp.asarray(seed, jnp.int32).reshape(2)
     msq_blk = 1 if mask_mode != "full" else sq_pad
-    interp = interpret_mode()
+    interp = interpret_mode() if interpret is None else interpret
     drop_mode = "mask" if (interp and dropout_p > 0.0) else "prng"
     if drop_mode == "mask":
         keep3 = _host_keep_mask(seed2, b * h, sq_pad, sk_pad, dropout_p)
@@ -813,11 +874,7 @@ def _flash_bwd(q, k, v, mask, mask_mode, seed, out, mrow, lrow, g, causal,
         return pl.BlockSpec((1, bk, width), lambda i, j: (i, j, 0),
                             memory_space=pltpu.VMEM)
 
-    dq, dk, dv = pl.pallas_call(
-        functools.partial(
-            _bwd_kernel, block_q=bq, block_k=bk, sq=sq, sk=sk,
-            causal=causal, scale=s, mask_mode=mask_mode,
-            dropout_p=dropout_p, threshold=threshold, drop_mode=drop_mode),
+    call = dict(
         grid=(b * h, pl.cdiv(sk, bk)),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -850,9 +907,16 @@ def _flash_bwd(q, k, v, mask, mask_mode, seed, out, mrow, lrow, g, causal,
         compiler_params=_bwd_params(
             sq_pad, d, dvh, q.dtype.itemsize, bq, bk, single,
             extra=(msq_blk + (sq_pad if drop_mode == "mask" else 0)) * bk),
-        interpret=interp,
-        name="flash_bwd",
-    )(seed2, q3p, k3, v3, m3, keep3, *stats, do3p)
+        interpret=interp)
+    kernel = functools.partial(
+        _bwd_kernel, block_q=bq, block_k=bk, sq=sq, sk=sk, causal=causal,
+        scale=s, mask_mode=mask_mode, dropout_p=dropout_p,
+        threshold=threshold, drop_mode=drop_mode, window=window)
+    if window is None:
+        run = pl.pallas_call(kernel, name="flash_bwd", **call)
+    else:
+        run = pl.pallas_call(kernel, name="flash_win_bwd", **call)
+    dq, dk, dv = run(seed2, q3p, k3, v3, m3, keep3, *stats, do3p)
     dq = dq[:, :sq].reshape(b, h, sq, d)
     dk = dk[:, :sk].reshape(b, h, sk, d)
     dv = dv[:, :sk].reshape(b, h, sk, dvh)
@@ -898,6 +962,72 @@ def _bwd(mask_mode, causal, scale, block_q, block_k, dropout_p, res, g):
 
 
 _flash.defvjp(_fwd, _bwd)
+
+
+_NO_SEED = np.zeros((2,), np.int32)
+
+
+# The windowed calls sit behind module-level ``jax.jit``s: a model has one
+# call site a window layer, each traced three times where its block is
+# recomputed (forward, recomputed forward, backward), and every
+# ``pl.pallas_call`` instance is lowered to Mosaic in Python in every
+# process's set-up. JAX lowers an inner jit once a module for equal shapes,
+# so the eighteen instances of six window layers become three (the
+# forward's, its copy that ``jax.checkpoint`` re-stages, the backward's:
+# PERF.md section 6, PR 38; ``interpret`` is an argument because the cached
+# trace outlives a change of the mode).
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7))
+def _win_fwd(q, k, v, window, scale, block_q, block_k, interpret):
+    return _flash_fwd_res(q, k, v, None, None, _NO_SEED, True, scale,
+                          block_q, block_k, 0.0, window=window,
+                          interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnums=(7, 8, 9, 10, 11))
+def _win_bwd(q, k, v, out, mrow, lrow, g, window, scale, block_q, block_k,
+             interpret):
+    return _flash_bwd(q, k, v, None, None, _NO_SEED, out, mrow, lrow, g,
+                      True, scale, block_q, block_k, 0.0, window=window,
+                      interpret=interpret)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash_win(q, k, v, window, scale, block_q, block_k):
+    """Causal attention in which a row sees itself and the ``window - 1``
+    positions before it: the kernels of ``_flash`` with one more fact of
+    the call, named ``flash_win_fwd`` / ``flash_win_bwd``."""
+    return _win_vjp_fwd(q, k, v, window, scale, block_q, block_k)[0]
+
+
+def _win_vjp_fwd(q, k, v, window, scale, block_q, block_k):
+    from . import interpret_mode
+    out, mrow, lrow = _win_fwd(q, k, v, window, scale, block_q, block_k,
+                               interpret_mode())
+    return out, (q, k, v, out, mrow, lrow)
+
+
+def _win_vjp_bwd(window, scale, block_q, block_k, res, g):
+    from . import interpret_mode
+    q, k = res[:2]
+    bq, bk = _clamped_blocks(block_q, block_k, q.shape[2], k.shape[2])
+    _count_backward(_tile_counts(q.shape[0] * q.shape[1], block_q=bq,
+                                 block_k=bk, sq=q.shape[2], sk=k.shape[2],
+                                 causal=True, window=window)[0])
+    return _win_bwd(*res, g, window, scale, block_q, block_k,
+                    interpret_mode())
+
+
+_flash_win.defvjp(_win_vjp_fwd, _win_vjp_bwd)
+
+
+def sliding_window_mask(length, window):
+    """bool ``[length, length]``, True where row i may attend to key j:
+    ``j <= i`` and ``i - j < window`` (a row sees itself and the ``window
+    - 1`` positions before it). The kernels never build it: the portable
+    path and the tests do."""
+    at = np.arange(length, dtype=np.int32)
+    gap = at[:, None] - at[None, :]
+    return (gap >= 0) & (gap < window)
 
 
 def block_diffusion_mask(length, block):
@@ -1106,8 +1236,9 @@ _flash_bd.defvjp(_bd_vjp_fwd, _bd_vjp_bwd)
 # forward lives in the 16 MiB a kernel may use unasked; the backward asks
 # for what its shapes need (``_bwd_params``: 30.5 MiB allowed and 16.7 taken
 # at 8,192 x 128, 34.3 and 23.9 at 8,192 x 192 | 128, 31.5 and 22.1 under
-# the block structure, 38.5 and 28.7 at 16,384 x 128, the default at BERT's
-# seq 512; a v5e core has 128). What a tile costs beyond its
+# the block structure, 38.5 and 28.7 at 16,384 x 128 (30.3 taken under a
+# window, whose forward takes 12.0 of the default 16), the default at
+# BERT's seq 512; a v5e core has 128). What a tile costs beyond its
 # products is paid once an inner iteration (the fill and drain of the MXU
 # and of the cross-lane reductions; about ten operations on (BQ, 1)
 # columns), so the largest tiles that fit are the fastest: alone on a v5e,
@@ -1135,6 +1266,17 @@ _flash_bd.defvjp(_bd_vjp_fwd, _bd_vjp_bwd)
 #   the 4 MiB of the causal 8k call and takes its answer, 512 x 512 with
 #   two buffers. Alone on a v5e, forward / backward ms: 512 x 512 10.48 /
 #   22.82, 256 x 512 10.88 / 24.62, 512 x 256 16.00 / 23.91.
+# * 16,384 x 128 (8 MiB a side, one buffer), causal and under a sliding
+#   window of 4,096 (28 heads; smallthinker's two kinds of layer): 512 x 512
+#   in both. Alone on a v5e, forward / backward ms: causal 512 x 512 16.12 /
+#   30.40, 256 x 512 16.57 / 33.33; window 512 x 512 8.39 / 15.57, 256 x 512
+#   8.68 / 16.91, 512 x 256 12.92 / 16.23, 256 x 256 15.22 / 17.41. A
+#   windowed program reads W + BQ rows of the side it holds whole, and the
+#   whole side stays: over its q-blocks a head reads every row of it, so a
+#   moving span would save only the exposed part of the one-buffer fetch
+#   (8.4 MB a head, 0.29 ms of a forward call's 8.39 at the most) for manual
+#   DMA of overlapping row ranges; a windowed call takes 0.52 of the causal
+#   call's time for 0.477 of its tiles (PERF.md section 7, row 29).
 _WHOLE_SIDE_BYTES = 4 * 1024 * 1024
 _DEFAULT_VMEM_BYTES = 16 * 1024 * 1024      # what a kernel may use unasked
 
@@ -1187,7 +1329,7 @@ def _bwd_params(seq, d, dv, itemsize, block_q, block_k, single, blocks=1,
 def flash_attention(q, k, v, attn_mask=None, causal=False, scale=None,
                     block_q=512, block_k=1024, dropout_p=0.0,
                     training=False, force=False, diffusion_block=None,
-                    name=None):
+                    window=None, name=None):
     """Framework op: flash attention over q, k (B, H, S, D) and v (B, H,
     S, DV); the result is (B, H, Sq, DV). The kernels take any head sizes:
     ``DV`` may differ from ``D`` (multi-head latent attention: 192 and
@@ -1210,12 +1352,22 @@ def flash_attention(q, k, v, attn_mask=None, causal=False, scale=None,
     builds the dense mask. No ``attn_mask``, ``causal`` or dropout beside
     it.
 
+    ``window`` beside ``causal=True`` is a sliding window
+    (:func:`sliding_window_mask`): a row sees itself and the ``window - 1``
+    positions before it. A fact of the call too: the kernels (named
+    ``flash_win_fwd`` / ``flash_win_bwd``) walk the tiles between the
+    window's lower edge and the diagonal and mask those either crosses; a
+    window that holds the whole sequence is a causal call. No
+    ``attn_mask``, dropout or ``diffusion_block`` beside it.
+
     ``monitor`` counters ``flash_attention.kernel_traced`` /
     ``flash_attention.xla_traced`` count the call sites that traced each
     path; per kernel call site ``flash_attention.tiles``, ``flash_attention.
     tiles_masked`` and ``flash_attention.tiles_skipped`` add the forward
     kernel's score tiles, those of them that run the masked body and the
-    tiles of the whole rectangle it does not walk,
+    tiles of the whole rectangle it does not walk
+    (``flash_attention.window_tiles`` / ``.window_tiles_skipped``: the
+    windowed call sites' part of the first and the last),
     ``flash_attention.native_operands_traced`` counts the call sites whose
     products take bfloat16 operands, and where a call site's backward is
     traced ``flash_attention.backward_fused_traced`` counts it and
@@ -1240,6 +1392,14 @@ def flash_attention(q, k, v, attn_mask=None, causal=False, scale=None,
                 f"flash_attention: diffusion_block={diffusion_block} takes "
                 f"two copies of whole blocks of a power of two along S "
                 f"(q {sq}, k {sk} rows) and no mask, causal or dropout")
+    if window is not None:
+        if has_mask or not causal or p_drop or sq != sk \
+                or diffusion_block is not None or int(window) < 1:
+            raise ValueError(
+                f"flash_attention: window={window} takes causal=True over "
+                f"one sequence (q {sq}, k {sk} rows) and no mask, dropout "
+                f"or diffusion_block")
+        window = int(window) if window < sq else None
     block_q, block_k = _blocks_that_fit(held, d, v.shape[3],
                                         q.dtype.itemsize, block_q, block_k)
     mode = _mask_mode(attn_mask.shape if has_mask else None, b, h, sq, sk)
@@ -1251,6 +1411,8 @@ def flash_attention(q, k, v, attn_mask=None, causal=False, scale=None,
         from ..nn_ops import scaled_dot_product_attention as sdpa
         if diffusion_block is not None:
             attn_mask = jnp.asarray(block_diffusion_mask(held, block))
+        if window is not None:
+            attn_mask = jnp.asarray(sliding_window_mask(sq, window))
         return sdpa(q, k, v, attn_mask=attn_mask, is_causal=causal,
                     scale=scale, dropout_p=p_drop, training=training)
     # how often the kernels' mechanisms engage, per call site: the forward
@@ -1260,7 +1422,7 @@ def flash_attention(q, k, v, attn_mask=None, causal=False, scale=None,
     if diffusion_block is None:
         bq, bk = _clamped_blocks(block_q, block_k, sq, sk)
         tiles, masked = _tile_counts(b * h, block_q=bq, block_k=bk, sq=sq,
-                                     sk=sk, causal=causal)
+                                     sk=sk, causal=causal, window=window)
         whole = b * h * -(-sq // bq) * -(-sk // bk)
     else:
         shift = block.bit_length() - 1
@@ -1270,12 +1432,18 @@ def flash_attention(q, k, v, attn_mask=None, causal=False, scale=None,
     monitor.counter("flash_attention.tiles").inc(tiles)
     monitor.counter("flash_attention.tiles_masked").inc(masked)
     monitor.counter("flash_attention.tiles_skipped").inc(whole - tiles)
+    if window is not None:      # the windowed call sites' share of the two
+        monitor.counter("flash_attention.window_tiles").inc(tiles)
+        monitor.counter("flash_attention.window_tiles_skipped").inc(
+            whole - tiles)
     if q.dtype == k.dtype == v.dtype == jnp.bfloat16:
         monitor.counter("flash_attention.native_operands_traced").inc()
 
     def impl(q, k, v, *rest):
         if diffusion_block is not None:
             return _flash_bd(q, k, v, shift, scale, block_q, block_k)
+        if window is not None:
+            return _flash_win(q, k, v, window, scale, block_q, block_k)
         m = _canon_mask(rest[0]) if has_mask else None
         if p_drop > 0.0:
             raw = jnp.ravel(rest[-1])[:2]

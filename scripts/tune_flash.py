@@ -1,4 +1,4 @@
-"""Flash-attention block sweep on the chip, at the geometries of the four
+"""Flash-attention block sweep on the chip, at the geometries of the five
 benchmark cells that run the kernels (q/k and v head sizes apart):
 
     joyai     1 x 32 x 8192 x 192 | 128, causal
@@ -6,6 +6,10 @@ benchmark cells that run the kernels (q/k and v head sizes apart):
     seq512    16 x 12 x 512 x 64 | 64, key bias, no causal mask
     sdar      1 x 32 x (2 x 8192) x 128 | 128, block-diffusion structure,
               diffusion blocks of 4
+    st_global 1 x 28 x 16384 x 128 | 128, causal (smallthinker's global
+              layers)
+    st_window the same under a sliding window of 4,096 (its window layers;
+              the parent has no such call and is left out)
 
 For each (block_q, block_k): forward ms, backward ms (the backward call
 alone, on the forward's saved results) and forward + backward ms (host
@@ -45,7 +49,12 @@ GEOMETRIES = {
                [(512, 512), (256, 512), (512, 256), (256, 256)]),
     "sdar": (1, 32, 16384, 128, 128, False, False, 4,
              [(256, 512), (512, 256), (512, 512)]),
+    "st_global": (1, 28, 16384, 128, 128, True, False, None,
+                  [(512, 512), (256, 512)]),
+    "st_window": (1, 28, 16384, 128, 128, True, False, None,
+                  [(512, 512), (256, 512), (512, 256), (256, 256)]),
 }
+WINDOWS = {"st_window": 4096}       # geometry -> flash_attention(window=)
 
 
 def load_kernels(parent):
@@ -78,7 +87,7 @@ def make_inputs(geometry, seed=0):
     return q, k, v, ct, bias
 
 
-def functions(module, geometry, bias, block_q, block_k):
+def functions(module, geometry, bias, block_q, block_k, window=None):
     """(forward, backward on the forward's saved results, forward +
     backward) of ``module``'s kernels, jitted. ``backward(q, k, v, ct)``
     runs the forward once, outside the clock."""
@@ -100,6 +109,17 @@ def functions(module, geometry, bias, block_q, block_k):
         def bwd(q, k, v, ct, out, mrow, lrow):
             return module._bd_bwd(q, k, v, out, mrow, lrow, ct, shift, None,
                                   block_q, block_k)
+    elif window:
+        def fwd(q, k, v):
+            return module._flash_win(q, k, v, window, None, block_q, block_k)
+
+        def saved(q, k, v):
+            return module._win_fwd(q, k, v, window, None, block_q, block_k,
+                                   False)
+
+        def bwd(q, k, v, ct, out, mrow, lrow):
+            return module._win_bwd(q, k, v, out, mrow, lrow, ct, window,
+                                   None, block_q, block_k, False)
     else:
         def fwd(q, k, v):
             return module._flash(q, k, v, bias, mode, seed, causal, None,
@@ -158,26 +178,29 @@ def ulps(a, b):
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--parent", default=None)
-    ap.add_argument("--cells", default="joyai,nemotron,seq512,sdar")
+    ap.add_argument("--cells", default="joyai,nemotron,seq512,sdar,"
+                                       "st_global,st_window")
     ap.add_argument("--steps", type=int, default=20)
     args = ap.parse_args()
 
     import jax
     print("device", jax.devices()[0].device_kind, flush=True)
     change = load_kernels(None)
-    sides = [("change", change)]
+    all_sides = [("change", change)]
     if args.parent:
-        sides.insert(0, ("parent", load_kernels(args.parent)))
+        all_sides.insert(0, ("parent", load_kernels(args.parent)))
 
     for cell in args.cells.split(","):
-        geometry = GEOMETRIES[cell]
+        geometry, window = GEOMETRIES[cell], WINDOWS.get(cell)
+        sides = [side for side in all_sides
+                 if window is None or hasattr(side[1], "_flash_win")]
         b, h, s, d, dv, causal, key_bias, block, pairs = geometry
         q, k, v, ct, bias = make_inputs(geometry)
         held = s // 2 if block else s
         rule = {name: m._blocks_that_fit(held, d, dv, 2, 512, 1024)
                 for name, m in sides}
-        print(f"{cell}: {b} x {h} x {s} x {d} | {dv} "
-              f"causal={causal} key_bias={key_bias} rule={rule}", flush=True)
+        print(f"{cell}: {b} x {h} x {s} x {d} | {dv} causal={causal} "
+              f"key_bias={key_bias} window={window} rule={rule}", flush=True)
         for bq, bk in pairs:
             if block:
                 shift = block.bit_length() - 1
@@ -188,11 +211,12 @@ def main():
                 bq_, bk_ = change._clamped_blocks(bq, bk, s, s)
                 tiles, masked = change._tile_counts(
                     b * h, block_q=bq_, block_k=bk_, sq=s, sk=s,
-                    causal=causal)
+                    causal=causal, window=window)
             row = f"  {bq:4d} x {bk:4d}  tiles {tiles:6d} masked {masked:5d}"
             for name, module in sides:
                 try:
-                    f, back, fb = functions(module, geometry, bias, bq, bk)
+                    f, back, fb = functions(module, geometry, bias, bq, bk,
+                                            window)
                     ms = timed(f, (q, k, v), args.steps)
                     ms_back = timed(back, (q, k, v, ct), args.steps)
                     ms_both = timed(fb, (q, k, v, ct), args.steps)
@@ -202,7 +226,7 @@ def main():
                     row += f"  {name} FAIL {type(e).__name__}: " \
                            f"{str(e).splitlines()[0][:120]}"
             print(row, flush=True)
-        if args.parent:
+        if len(sides) == 2:
             # the same inputs through both sides, each at its rule's blocks
             outs = {name: functions(m, geometry, bias, *rule[name])[2](
                 q, k, v, ct)

@@ -307,6 +307,38 @@ def _vmem_of_kernels(text):
     return out
 
 
+# 16,384 x 128 under a sliding window of 4,096, 28 heads: the
+# smallthinker_21b_a3b cell's window layers after their K/V heads are
+# repeated. The kernels of the causal call with one more fact: the same
+# blocks (512 x 512, the whole side in one buffer), the forward inside the
+# 16 MiB a kernel may use unasked, the backward inside the limit its shapes
+# give; no mask operand, nothing of 16,384 x 16,384
+def test_flash_window_at_the_cells_size(one_chip, compiled_kernels):
+    import importlib
+    fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
+    block_q, block_k = fa._blocks_that_fit(16384, 128, 128, 2, 512, 1024)
+    assert (block_q, block_k) == (512, 512)
+
+    def f(q, k, v):
+        return fa._flash_win(q, k, v, 4096, None, block_q, block_k)
+
+    qkv = ((1, 28, 16384, 128), jnp.bfloat16)
+    text = _compiled_text(_grad_sum(f, argnums=(0, 1, 2)), one_chip, qkv,
+                          qkv, qkv, names=("flash_win_fwd", "flash_win_bwd"))
+    assert text.count("tpu_custom_call") == 2
+    assert not re.search(r"\[(\d+,)*16384,(\d+,)*16384[,\]]", text)
+    assert "f32[28,1,16384]" in text
+    vmem = _vmem_of_kernels(text)
+    mib = 2 ** 20
+    allowed, took = vmem["flash_win_fwd"]
+    assert took <= allowed == 16 * mib
+    allowed, took = vmem["flash_win_bwd"]
+    want = fa._bwd_params(16384, 128, 128, 2, 512, 512, True,
+                          extra=512).vmem_limit_bytes
+    assert want <= allowed < want + mib
+    assert 16 * mib < took <= allowed < 48 * mib
+
+
 # the one backward kernel at the four flash cells' shapes, and at 16,384 x
 # 128: beside the whole q side (q, dO, statistics) it holds dq's block and
 # dq's float32 accumulator, which the default 16 MiB do not hold with 512 x
@@ -707,8 +739,8 @@ KERNEL_NAMES = {
     "batch_norm.py": ["batch_norm_stats", "batch_norm_apply",
                       "batch_norm_bwd_reduce", "batch_norm_bwd_dx"],
     "causal_conv1d.py": ["conv1d_fwd", "conv1d_bwd"],
-    "flash_attention.py": ["flash_fwd", "flash_bwd", "flash_bd_fwd",
-                           "flash_bd_bwd"],
+    "flash_attention.py": ["flash_fwd", "flash_win_fwd", "flash_bwd",
+                           "flash_win_bwd", "flash_bd_fwd", "flash_bd_bwd"],
     "gated_rms_norm.py": ["gated_norm_fwd", "gated_norm_bwd"],
     "layer_norm.py": ["layer_norm_fwd", "layer_norm_bwd"],
     "moe_scatter_add.py": ["moe_scatter_add"],
@@ -751,7 +783,7 @@ def test_no_pallas_call_site_is_left_out_and_no_name_is_used_twice():
     found = {f: names for f, names in found.items() if names}
     assert found == KERNEL_NAMES
     every = [n for names in found.values() for n in names]
-    assert len(every) == len(set(every)) == 19
+    assert len(every) == len(set(every)) == 21
 
 
 def test_every_registered_kernel_has_a_module_with_a_call_site():

@@ -38,9 +38,10 @@ def test_phase_kernels_tiny_interpret(cs, capsys):
                      head_dim=32)
     out = _lines(capsys, "kernels")
     # layer norm f32+bf16, flash x3, at latent attention's head sizes and
-    # under the block-diffusion structure, the scan, the convolution, the
-    # gated norm, the experts' scatter-add
-    assert len(out) == 11
+    # under the block-diffusion structure and a sliding window, the scan,
+    # the convolution, the gated norm, the experts' scatter-add
+    assert len(out) == 12
+    assert any("x64,bf16,window32" in l for l in out)
     assert any("moe_scatter_add[64x128" in l for l in out)
     assert any("x96|64,bf16,causal" in l for l in out)
     assert any("x64,bf16,block_diffusion" in l for l in out)
