@@ -489,15 +489,22 @@ def recompute(layer_or_fn, *args, policy=None, **kwargs):
     Usage: ``out = jit.recompute(block, x)`` — activations inside `block`
     are recomputed during backward, trading FLOPs for HBM.
 
-    ``policy=`` names what the checkpoint may keep: ``"full"`` (default —
-    save only the inputs), or ``"dots"`` (checkpoint_dots: matmul
-    outputs stay, the elementwise tail recomputes).
+    ``policy=`` names what the checkpoint may keep. Unnamed (the
+    default, ``memory_plan.KERNEL_RESULTS``): the inputs and a kernel's
+    saved result — the flash kernels' o and statistic rows, so the
+    backward replays the block without its forward flash kernel, for
+    heads x rows x d_v of HBM a call; a block that holds no such call
+    keeps its inputs alone. ``"full"``: only the inputs, for whoever
+    needs those bytes. ``"dots"`` (checkpoint_dots): matmul outputs stay,
+    the elementwise tail recomputes.
     """
     from .dispatch import apply
     from .nn.layer import bind_state, _remat_suspended
     from . import autograd as _ag
-    from .memory_plan import checkpoint_policy
+    from .memory_plan import checkpoint_policy, KERNEL_RESULTS
+    policy = KERNEL_RESULTS if policy is None else policy
     ckpt_policy = checkpoint_policy(policy)
+    _monitor.counter(f"recompute.placed.{policy}").inc()
 
     if isinstance(layer_or_fn, Layer):
         from .nn.moe import MoEFFN

@@ -11,6 +11,11 @@ an auto-picker that turns the predicted-peak model into decisions:
   ``"full"`` (save only the inputs) — or MeshPlan-style per-layer
   regex rules ``((pattern, policy), ...)``, first match wins. Exact:
   the backward replays the identical ops, losses are bit-identical.
+  Between ``"full"`` and ``"dots"`` stands what ``jit.recompute(layer,
+  x)`` means when no policy is named, ``KERNEL_RESULTS``: the block's
+  activations are made again, a kernel's saved result (the flash
+  kernels' o and statistic rows, which they leave under names) is not.
+  ``remat=`` and the layer hook always name ``"full"`` or ``"dots"``.
 * **Optimizer-state host offload** (``offload``): pages the flat
   ``ParamArena`` Adam moments to host RAM after each apply and
   prefetches them back during the next step's forward/backward on a
@@ -40,6 +45,7 @@ record the decision in the monitor ledger exactly like
 from __future__ import annotations
 
 import contextlib
+import functools
 import os
 import re
 import threading
@@ -47,6 +53,7 @@ import time
 
 __all__ = [
     "MemoryPolicy", "resolve", "policy_key", "checkpoint_policy",
+    "KERNEL_RESULTS",
     "remat_scope", "current_remat", "policy_for_layer",
     "install_layer_hook",
     "host_mem_limit", "host_headroom_bytes", "host_link_bandwidth",
@@ -56,6 +63,9 @@ __all__ = [
 ]
 
 _REMAT_NAMES = ("none", "dots", "full")
+
+# jit.recompute's policy when none is named (not a ``remat=`` name)
+KERNEL_RESULTS = "kernel_results"
 
 
 def _canon_remat(pol):
@@ -164,11 +174,25 @@ def policy_key(pol):
     return ",".join(parts)
 
 
+@functools.cache
+def _kernel_results_policy():
+    # ONE function object a process: JAX caches a checkpoint's partial
+    # evaluation of an inner ``jax.jit`` by the policy's identity, and a
+    # policy made anew for every block gives every block a jaxpr (and a
+    # Mosaic lowering) of its own of a kernel behind a module-level jit
+    import jax
+    from ..ops.pallas.flash_attention import RESULT_NAMES
+    return jax.checkpoint_policies.save_only_these_names(*RESULT_NAMES)
+
+
 def checkpoint_policy(name):
     """Map a remat policy name onto ``jax.checkpoint``'s ``policy=``:
     ``"full"`` → None (save nothing but the inputs), ``"dots"`` →
     ``jax.checkpoint_policies.checkpoint_dots`` (save matmul outputs,
-    recompute the elementwise tail). Callers only reach here when a
+    recompute the elementwise tail), ``KERNEL_RESULTS`` →
+    ``save_only_these_names`` over the names the flash kernels give
+    their results (a block without such a call saves what ``"full"``
+    saves). Callers only reach here when a
     checkpoint is actually being placed — ``"none"`` means *no*
     ``jax.checkpoint`` at all, which is not this function's job."""
     if name in (None, "none", "full"):
@@ -176,6 +200,8 @@ def checkpoint_policy(name):
     if name == "dots":
         import jax
         return jax.checkpoint_policies.checkpoint_dots
+    if name == KERNEL_RESULTS:
+        return _kernel_results_policy()
     raise ValueError(f"unknown remat policy {name!r}")
 
 

@@ -104,6 +104,14 @@ tiled layout pads the 1 to 128 lanes in HBM) on any path: at BERT-base's
 seq-512 shapes those cost 50 MB an array — 650 MB a layer against 190 MB
 of q/k/v/o/dO traffic, and 5.4 ms of a 74.8 ms step in the XLA ops that
 made and sliced them (PERF.md §6, PR 26).
+
+Saved results (PR 42). What a call's backward needs of its forward, o and
+the two statistic rows, leaves the three vjp-forward rules under the names
+``RESULT_NAMES`` (``jax.ad_checkpoint.checkpoint_name``: an identity
+outside a ``jax.checkpoint``). A checkpoint whose policy keeps those names
+(``jit.recompute``'s default, ``memory_plan.checkpoint_policy``) replays
+its block without the forward kernel: the block's activations are made
+again, the kernel's result is not.
 """
 from __future__ import annotations
 
@@ -923,6 +931,22 @@ def _flash_bwd(q, k, v, mask, mask_mode, seed, out, mrow, lrow, g, causal,
     return dq, dk, dv
 
 
+# (o, the two statistic rows): the names a checkpoint policy may keep.
+RESULT_NAMES = ("flash_out", "flash_stats")
+
+
+def _named_results(out, mrow, lrow):
+    """A vjp-forward's results under ``RESULT_NAMES``. The rule returns the
+    named ``out`` to its caller AND saves it for the backward, so a policy
+    that keeps the name keeps the one value both read."""
+    from jax.ad_checkpoint import checkpoint_name
+    from ... import monitor
+    monitor.counter("flash_attention.results_named").inc()
+    o_name, stats_name = RESULT_NAMES
+    return (checkpoint_name(out, o_name), checkpoint_name(mrow, stats_name),
+            checkpoint_name(lrow, stats_name))
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 6, 7, 8, 9, 10))
 def _flash(q, k, v, mask, mask_mode, seed, causal, scale, block_q, block_k,
            dropout_p):
@@ -933,8 +957,9 @@ def _flash(q, k, v, mask, mask_mode, seed, causal, scale, block_q, block_k,
 
 def _fwd(q, k, v, mask, mask_mode, seed, causal, scale, block_q, block_k,
          dropout_p):
-    out, mrow, lrow = _flash_fwd_res(q, k, v, mask, mask_mode, seed, causal,
-                                     scale, block_q, block_k, dropout_p)
+    out, mrow, lrow = _named_results(*_flash_fwd_res(
+        q, k, v, mask, mask_mode, seed, causal, scale, block_q, block_k,
+        dropout_p))
     return out, (q, k, v, mask, seed, out, mrow, lrow)
 
 
@@ -968,14 +993,13 @@ _NO_SEED = np.zeros((2,), np.int32)
 
 
 # The windowed calls sit behind module-level ``jax.jit``s: a model has one
-# call site a window layer, each traced three times where its block is
-# recomputed (forward, recomputed forward, backward), and every
-# ``pl.pallas_call`` instance is lowered to Mosaic in Python in every
-# process's set-up. JAX lowers an inner jit once a module for equal shapes,
-# so the eighteen instances of six window layers become three (the
-# forward's, its copy that ``jax.checkpoint`` re-stages, the backward's:
-# PERF.md section 6, PR 38; ``interpret`` is an argument because the cached
-# trace outlives a change of the mode).
+# call site a window layer, each traced twice where its block is recomputed
+# (forward and backward; three times under ``policy="full"``, whose replay
+# runs the forward again), and every ``pl.pallas_call`` instance is lowered
+# to Mosaic in Python in every process's set-up. JAX lowers an inner jit
+# once a module for equal shapes, so the twelve instances of six window
+# layers become two (PERF.md section 6, PR 38; ``interpret`` is an argument
+# because the cached trace outlives a change of the mode).
 @functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7))
 def _win_fwd(q, k, v, window, scale, block_q, block_k, interpret):
     return _flash_fwd_res(q, k, v, None, None, _NO_SEED, True, scale,
@@ -996,13 +1020,15 @@ def _flash_win(q, k, v, window, scale, block_q, block_k):
     """Causal attention in which a row sees itself and the ``window - 1``
     positions before it: the kernels of ``_flash`` with one more fact of
     the call, named ``flash_win_fwd`` / ``flash_win_bwd``."""
-    return _win_vjp_fwd(q, k, v, window, scale, block_q, block_k)[0]
+    from . import interpret_mode
+    return _win_fwd(q, k, v, window, scale, block_q, block_k,
+                    interpret_mode())[0]
 
 
 def _win_vjp_fwd(q, k, v, window, scale, block_q, block_k):
     from . import interpret_mode
-    out, mrow, lrow = _win_fwd(q, k, v, window, scale, block_q, block_k,
-                               interpret_mode())
+    out, mrow, lrow = _named_results(*_win_fwd(
+        q, k, v, window, scale, block_q, block_k, interpret_mode()))
     return out, (q, k, v, out, mrow, lrow)
 
 
@@ -1212,7 +1238,8 @@ def _flash_bd(q, k, v, shift, scale, block_q, block_k):
 
 
 def _bd_vjp_fwd(q, k, v, shift, scale, block_q, block_k):
-    out, mrow, lrow = _bd_fwd_res(q, k, v, shift, scale, block_q, block_k)
+    out, mrow, lrow = _named_results(*_bd_fwd_res(
+        q, k, v, shift, scale, block_q, block_k))
     return out, (q, k, v, out, mrow, lrow)
 
 

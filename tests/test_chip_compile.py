@@ -426,6 +426,93 @@ def test_flash_kernels_widen_no_tile_of_k_or_v(compiled_kernels):
                                           else 1), (name, widened)
 
 
+# -- what a recomputed block keeps, in XLA's account of a cell's step --------
+
+def _cell_step_memory(monkeypatch, one_chip, cell, layers, policy):
+    """XLA's memory analysis of a benchmark cell's training step, cut to
+    ``layers`` blocks, compiled for the described chip with the cell's own
+    family file, widths and sequence; ``policy`` is what every
+    ``jit.recompute`` of the step is given (None: none named). Sizes, no
+    time: nothing runs."""
+    import importlib
+    import json
+    import paddle_tpu as pt
+    from paddle_tpu import jit
+    from benchmark.families import trainer
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    config, traffic = cell.split(".")
+    with open(os.path.join(root, "benchmark", "configs",
+                           config + ".json")) as f:
+        cfg = json.load(f)
+    with open(os.path.join(root, "benchmark", "traffic",
+                           traffic + ".json")) as f:
+        traffic = dict(json.load(f), chips=1)
+    cfg["num_hidden_layers"] = layers
+    if "sliding_window_layout" in cfg:
+        cfg["sliding_window_layout"] = cfg["sliding_window_layout"][:layers]
+
+    class Compiled(Exception):
+        pass
+
+    make = jit.StaticFunction._make_entry
+
+    def make_entry(self, *args, **kwargs):
+        entry = make(self, *args, **kwargs)
+        jitted = entry["jitted"]
+
+        def compile_only(state, arrays):
+            shapes = jax.tree_util.tree_map(
+                lambda v: jax.ShapeDtypeStruct(v.shape, v.dtype,
+                                               sharding=one_chip),
+                (state, arrays))
+            raise Compiled(jitted.lower(*shapes).compile().memory_analysis())
+        entry["jitted"] = compile_only
+        return entry
+
+    monkeypatch.setattr(jit.StaticFunction, "_make_entry", make_entry)
+    # the seed's weights are not needed to compile
+    monkeypatch.setattr(trainer.Trainer, "load", lambda self, weights: None)
+    if policy is not None:
+        recompute = jit.recompute
+        monkeypatch.setattr(jit, "recompute", lambda *a, **kw: recompute(
+            *a, policy=policy, **kw))
+    family = importlib.import_module("benchmark.families." + cfg["family"])
+    step = family.build(cfg, traffic, None).step
+    ids = np.zeros((traffic["batch_per_chip"], traffic["seq_len"]), np.int32)
+    with pytest.raises(Compiled) as e:
+        step(pt.to_tensor(ids))
+    return e.value.args[0]
+
+
+@pytest.mark.parametrize("cell,layers,attentions,heads,rows,dv", [
+    ("smallthinker_21b_a3b.causal_pretrain_16k", 2, 2, 28, 16384, 128),
+    # the dense layer and the MTP module: two recomputed blocks
+    ("joyai_llm_flash.causal_pretrain", 1, 2, 32, 8192, 128),
+], ids=["smallthinker", "joyai"])
+def test_kept_flash_results_in_the_steps_temporaries(
+        one_chip, compiled_kernels, monkeypatch, cell, layers, attentions,
+        heads, rows, dv):
+    """Two recomputed blocks of the two cells nearest the chip's 16 GB, at
+    their widths and sequence lengths: what the default policy of
+    ``jit.recompute`` keeps, a call's o in bfloat16 and two float32 rows,
+    is what XLA's temporaries grow by against ``"full"`` (to 10 %; at PR 42
+    241.5 MB for 242.2 and 137.9 for 138.4; 0.959 GB for smallthinker's
+    eight layers). With expert layers between the attentions XLA's
+    scheduler, not the residuals, sets joyai's peak (two layers and the
+    module: 33.5 MB more for 207.6 kept; the whole step: 0.43 GB LESS), so
+    the whole steps are compiled by hand before a chip is asked
+    (PERF.md section 6, PR 42) and this holds the mechanism's bytes."""
+    with monkeypatch.context() as patch:
+        kept = _cell_step_memory(patch, one_chip, cell, layers, None)
+    with monkeypatch.context() as patch:
+        full = _cell_step_memory(patch, one_chip, cell, layers, "full")
+    results = attentions * (heads * rows * dv * 2 + 2 * heads * rows * 4)
+    more = kept.temp_size_in_bytes - full.temp_size_in_bytes
+    assert kept.argument_size_in_bytes == full.argument_size_in_bytes
+    assert abs(more - results) <= 0.1 * results, (more, results)
+
+
 # -- the nemotron cell's routed experts: plain XLA, chosen on the device ----
 
 def test_grouped_experts_fwd_bwd_at_the_cells_size(one_chip):

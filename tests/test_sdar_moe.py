@@ -305,11 +305,15 @@ def test_the_dispatch_counts_the_path_and_the_tiles_and_refuses_a_mix():
 # text, recomputed at PR 40, whose one backward kernel is meant to reach
 # all of them (ea9585c's were 552400deb2309687, 1ce92ea17d73c217 and
 # 0d43c90354e8ae13). A change to the kernels that is meant to reach those
-# cells recomputes them; the block structure is not.
+# cells recomputes them; the block structure is not. Retaken at PR 42,
+# which names the vjp-forward's three results: e256fce's texts
+# (0bef64586abe9150, 398076cef3b873b6, 8d25934ee86c1597) with three ``name``
+# equations more and the later variables' letters moved by them, nothing
+# else (compared line by line with the letters taken out).
 PARENT_JAXPRS = {
-    "seq512": "0bef64586abe9150",
-    "nemotron": "398076cef3b873b6",
-    "joyai": "8d25934ee86c1597",
+    "seq512": "a2968dc5da9e054d",
+    "nemotron": "edb2f8e1d4cb1eae",
+    "joyai": "60540556dedf1c9b",
 }
 
 
@@ -343,6 +347,7 @@ def test_a_call_without_the_structure_traces_the_parents_kernels(form):
 
     text = str(jax.make_jaxpr(value_and_grads)(*args))
     assert text.count("pallas_call") == 2
+    assert text.count("name[name=flash_") == 3
     assert hashlib.sha256(text.encode()).hexdigest()[:16] == \
         PARENT_JAXPRS[form]
 
