@@ -101,11 +101,12 @@ def phase_kernels(rows, hidden, batch, heads, seq, head_dim,
 
     shipped = sorted(k for k, on in P._AUTO_ON.items() if on)
     if shipped != ["causal_conv1d", "flash_attention", "gated_rms_norm",
-                   "layer_norm", "moe_scatter_add", "ssd_scan"]:
+                   "gated_short_conv", "layer_norm", "moe_scatter_add",
+                   "ssd_scan"]:
         raise AssertionError(f"_AUTO_ON ships {shipped}; this phase covers "
                              f"causal_conv1d, flash_attention, "
-                             f"gated_rms_norm, layer_norm, moe_scatter_add "
-                             f"and ssd_scan")
+                             f"gated_rms_norm, gated_short_conv, layer_norm, "
+                             f"moe_scatter_add and ssd_scan")
     on_chip = pt.device.is_tpu_backend()
     if on_chip and P.interpret_mode():
         raise AssertionError("interpret mode reachable on a TPU backend")
@@ -283,6 +284,21 @@ def phase_kernels(rows, hidden, batch, heads, seq, head_dim,
                     .astype(jnp.float32) * a[3]).sum(),
         lambda *a: (_conv1d(*a[:3], activation="silu") * a[3]).sum(),
         conv_args, 3, tol_bf16, 2)
+    # the lfm2 family's double-gated short convolution over [b | c | u]
+    # (3 x inner channels, 3 taps), bf16 in and out: the file's second pair
+    from paddle_tpu.ops.ssm import _gated_conv
+    if not conv.gated_supported((batch, seq, 3 * inner), 3):
+        raise AssertionError("the gated short convolution's kernels would "
+                             "not take this shape")
+    gated_args = (
+        jnp.asarray(rng.randn(batch, seq, 3 * inner), jnp.bfloat16),
+        jnp.asarray(rng.uniform(-0.5, 0.5, (inner, 3)), jnp.float32),
+        jnp.asarray(rng.randn(batch, seq, inner), jnp.float32))
+    run(f"gated_short_conv[{batch}x{seq}x3x{inner},3taps,bf16]",
+        lambda *a: (conv.gated_short_conv(*a[:2]).astype(jnp.float32)
+                    * a[2]).sum(),
+        lambda *a: (_gated_conv(*a[:2]) * a[2]).sum(),
+        gated_args, 2, tol_bf16, 2)
     norm_args = (
         jnp.asarray(rng.randn(batch, seq, inner), jnp.bfloat16),
         jnp.asarray(rng.randn(batch, seq, inner), jnp.bfloat16),

@@ -36,6 +36,9 @@ def __getattr__(name):
     if name in ("SmallThinkerConfig", "SmallThinkerForCausalLM"):
         from . import smallthinker
         return getattr(smallthinker, name)
+    if name in ("Lfm2MoeConfig", "Lfm2MoeForCausalLM"):
+        from . import lfm2
+        return getattr(lfm2, name)
     if name in ("Transformer",):
         from . import transformer
         return getattr(transformer, name)

@@ -37,8 +37,8 @@ from ..ops import nn_ops as conv  # reference exports its conv module
 from .layers import Upsample as UpSample  # noqa: F401 (2.0-alpha name)
 from .layers import HSigmoid  # noqa: F401
 from .moe import MoEFFN, RoutedMoE, GatedMLP, moe_aux_loss  # noqa: F401
-from .hybrid import (Mamba2Mixer, GroupedQueryAttention,  # noqa: F401
-                     MultiHeadLatentAttention)
+from .hybrid import (Mamba2Mixer, GatedShortConv,  # noqa: F401
+                     GroupedQueryAttention, MultiHeadLatentAttention)
 from ..fluid.dygraph import RowConv  # noqa: F401
 
 # paddle.nn 1.x functional tails (reference: python/paddle/nn/

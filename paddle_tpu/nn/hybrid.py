@@ -1,6 +1,6 @@
 """Sequence mixers of hybrid and latent-attention language models: a
-Mamba-2 state-space mixer, grouped-query attention and multi-head latent
-attention.
+Mamba-2 state-space mixer, a gated short convolution, grouped-query
+attention and multi-head latent attention.
 
 No reference counterpart in Paddle Fluid 1.7. All map ``[B, S, hidden]``
 to ``[B, S, hidden]`` with no bias, no dropout and no cache: the training
@@ -19,7 +19,7 @@ from ..ops import manip
 from ..ops import nn_ops as F
 from ..ops import ssm as S
 
-__all__ = ["Mamba2Mixer", "GroupedQueryAttention",
+__all__ = ["Mamba2Mixer", "GatedShortConv", "GroupedQueryAttention",
            "MultiHeadLatentAttention"]
 
 
@@ -89,6 +89,27 @@ class Mamba2Mixer(Layer):
                        chunk_size=self.chunk_size)
         y = self.norm(y.reshape([b, s, self.inner]), gate=z)
         return self.out_proj(y)
+
+
+class GatedShortConv(Layer):
+    """The gated short convolution of the ``lfm2`` family (the ``lfm2`` /
+    ``lfm2_moe`` model codes' ``ShortConv``): ``[b | c | x] = u W_in``, a
+    causal depthwise convolution of ``taps`` taps over ``b * x`` with no
+    bias and no activation, the gate ``c`` on its result, then ``W_out``
+    (``F.gated_short_conv`` between the two projections). The inner width
+    is the hidden size; no bias anywhere."""
+
+    def __init__(self, hidden_size, taps=3):
+        super().__init__()
+        self.in_proj = Linear(hidden_size, 3 * hidden_size, bias_attr=False)
+        tap = 1.0 / math.sqrt(taps)
+        self.conv_weight = self.create_parameter(
+            (hidden_size, taps), default_initializer=I.Uniform(-tap, tap))
+        self.out_proj = Linear(hidden_size, hidden_size, bias_attr=False)
+
+    def forward(self, u):
+        return self.out_proj(S.gated_short_conv(self.in_proj(u),
+                                                self.conv_weight))
 
 
 class GroupedQueryAttention(Layer):

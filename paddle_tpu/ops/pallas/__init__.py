@@ -32,7 +32,7 @@ def on_tpu():
 _overrides = {}
 _KERNELS = ("layer_norm", "flash_attention", "softmax_xent", "batch_norm",
             "ssd_scan", "causal_conv1d", "gated_rms_norm",
-            "moe_scatter_add")
+            "moe_scatter_add", "gated_short_conv")
 
 # Auto defaults from one builder-run v5e ablation (2026-07-31, superseded
 # toolchain, not reproduced — docs/performance.md carries the table):
@@ -79,7 +79,7 @@ _KERNELS = ("layer_norm", "flash_attention", "softmax_xent", "batch_norm",
 _AUTO_ON = {"layer_norm": True, "flash_attention": True,
             "softmax_xent": False, "batch_norm": False, "ssd_scan": True,
             "causal_conv1d": True, "gated_rms_norm": True,
-            "moe_scatter_add": True}
+            "moe_scatter_add": True, "gated_short_conv": True}
 
 
 # flash is an O(S^2)-score win: below some sequence length the XLA sdpa
@@ -128,7 +128,8 @@ def configure(flash_min_seq=_UNSET, **kernels):
     """configure(layer_norm=False, softmax_xent=None, ...) — override the
     auto default for named kernels ('layer_norm', 'flash_attention',
     'softmax_xent', 'batch_norm', 'ssd_scan', 'causal_conv1d',
-    'gated_rms_norm', 'moe_scatter_add'); any other name raises
+    'gated_rms_norm', 'moe_scatter_add', 'gated_short_conv'); any other
+    name raises
     ValueError. None restores auto.
     flash_min_seq=N routes sequences shorter than N to XLA sdpa even
     with the flash kernel enabled (N=0 disables the gate);

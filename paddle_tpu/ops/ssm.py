@@ -1,4 +1,5 @@
-"""paddle_tpu.ops.ssm — state-space sequence ops (Mamba-2).
+"""paddle_tpu.ops.ssm — state-space and short-convolution sequence ops
+(Mamba-2; the ``lfm2`` family's gated short convolution).
 
 No reference counterpart in Paddle Fluid 1.7 (its recurrences are the
 LSTM/GRU ops of ops/sequence.py and nn/rnn.py); these are the ops of a
@@ -8,7 +9,15 @@ convolution, and the selective state-space recurrence in its chunked
 sequence, no step-by-step loop, so the MXU does the work and the backward
 pass is the products' own.
 
-Both go through ``dispatch.apply`` (tape autograd, ``jit.to_static``,
+``F.gated_short_conv`` is here too, the ``lfm2`` family's token mixer
+between its two projections: ``y = c * conv(b * u)`` over the one array
+``[b | c | u]``. ``_gated_conv`` below is its plain XLA form; on one TPU
+it is the second kernel pair of ``ops/pallas/causal_conv1d.py``, which
+reads the three thirds in place and writes their gradient as one array
+(PERF.md section 6, PR 43); counters ``gated_short_conv.kernel_traced`` /
+``gated_short_conv.xla_traced``.
+
+All go through ``dispatch.apply`` (tape autograd, ``jit.to_static``,
 ``jit.recompute``), and each has two forms of one algorithm, chosen by
 what the call shows. The convolution: ``_conv1d`` below, plain XLA, and
 on one TPU, where rows and channels fit their tiles, the kernel pair of
@@ -31,7 +40,7 @@ import jax.numpy as jnp
 from ..dispatch import apply
 from .nn_ops import _pscope
 
-__all__ = ["causal_conv1d", "ssd_scan"]
+__all__ = ["causal_conv1d", "gated_short_conv", "ssd_scan"]
 
 
 def _conv1d(x, w, *b, activation):
@@ -73,6 +82,43 @@ def causal_conv1d(x, weight, bias=None, activation=None, name=None):
         return apply(pallas.causal_conv1d_mod.causal_conv1d if kernel
                      else _conv1d, args, dict(activation=activation),
                      name="causal_conv1d")
+
+
+def _gated_conv(bcx, w):
+    """The portable path of ``gated_short_conv`` and the kernels' oracle:
+    both gates and the ``K`` shifted products in float32, rounded once."""
+    s, taps = bcx.shape[1], w.shape[1]
+    b, c, u = jnp.split(bcx.astype(jnp.float32), 3, axis=-1)
+    v = jnp.pad(b * u, [(0, 0), (taps - 1, 0), (0, 0)])
+    wf = w.astype(jnp.float32)
+    z = sum(v[:, j:j + s] * wf[:, j] for j in range(taps))
+    return (c * z).astype(bcx.dtype)
+
+
+def gated_short_conv(bcx, weight, name=None):
+    """The double-gated short convolution of the ``lfm2`` family: with
+    ``[b | c | u] = bcx`` (thirds of the last axis, in this order), ``y[t]
+    = c[t] * sum_j weight[:, j] * (b * u)[t - (K - 1) + j]``; positions
+    before a sequence's start read zero, every sequence of the batch its
+    own. ``bcx`` [B, S, 3 C]; ``weight`` [C, K] (tap K - 1 multiplies the
+    current position, as ``causal_conv1d`` has it); no bias, no
+    activation. Returns [B, S, C] in ``bcx``'s dtype."""
+    if bcx.shape[-1] != 3 * weight.shape[0]:
+        raise ValueError(f"gated_short_conv: bcx {tuple(bcx.shape)} is not "
+                         f"three times the {weight.shape[0]} channels of "
+                         f"weight {tuple(weight.shape)}")
+    from .. import monitor
+    from . import pallas
+    # read off the call, as causal_conv1d above
+    kernel = (pallas.enabled("gated_short_conv")
+              and pallas.causal_conv1d_mod.gated_supported(
+                  tuple(bcx.shape), int(weight.shape[1])))
+    monitor.counter("gated_short_conv.kernel_traced" if kernel
+                    else "gated_short_conv.xla_traced").inc()
+    with _pscope("F.gated_short_conv"):
+        return apply(pallas.causal_conv1d_mod.gated_short_conv if kernel
+                     else _gated_conv, (bcx, weight),
+                     name="gated_short_conv")
 
 
 def _chunk_heads(t, k, chunk, g, r):

@@ -3,6 +3,8 @@
     python3 scripts/cell_faults.py --workload smallthinker_21b_a3b.causal_pretrain_16k \
         --seed 3000041701 --faults router_behind_attention,window_ignored \
         [--out chiprun_out/faults.json]
+    python3 scripts/cell_faults.py --workload lfm2_8b_a1b.causal_pretrain_2x8k \
+        --seed 3000043701 --faults gates_left_out,sequences_run_on
 
 For each named fault: the program with that fault planted, driven through
 the steps `correct` checks at the cell's own size (``benchmark/control.py``'s
@@ -44,7 +46,39 @@ def window_ignored():
     return lambda: setattr(hybrid.GroupedQueryAttention, "__init__", init)
 
 
-FAULTS = {f.__name__: f for f in (router_behind_attention, window_ignored)}
+def gates_left_out():
+    """The short convolution without its two gates: ``y = conv(u)`` where
+    the layer has ``c * conv(b * u)``."""
+    from paddle_tpu.nn import hybrid
+    ssm = hybrid.S
+
+    class Ungated:
+        def __getattr__(self, name):
+            return getattr(ssm, name)
+
+        @staticmethod
+        def gated_short_conv(bcx, weight):
+            return ssm.causal_conv1d(bcx[:, :, 2 * weight.shape[0]:], weight)
+    hybrid.S = Ungated()
+    return lambda: setattr(hybrid, "S", ssm)
+
+
+def sequences_run_on():
+    """The batch taken as one long sequence by the short convolution: the
+    first ``taps - 1`` rows of every sequence but the first read the tail
+    of the sequence before."""
+    from paddle_tpu.nn import hybrid
+    forward = hybrid.GatedShortConv.forward
+
+    def patched(self, u):
+        b, s, d = u.shape
+        return forward(self, u.reshape([1, b * s, d])).reshape([b, s, d])
+    hybrid.GatedShortConv.forward = patched
+    return lambda: setattr(hybrid.GatedShortConv, "forward", forward)
+
+
+FAULTS = {f.__name__: f for f in (router_behind_attention, window_ignored,
+                                  gates_left_out, sequences_run_on)}
 
 
 def main(argv=None):

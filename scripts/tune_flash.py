@@ -1,4 +1,4 @@
-"""Flash-attention block sweep on the chip, at the geometries of the five
+"""Flash-attention block sweep on the chip, at the geometries of the six
 benchmark cells that run the kernels (q/k and v head sizes apart):
 
     joyai     1 x 32 x 8192 x 192 | 128, causal
@@ -10,6 +10,8 @@ benchmark cells that run the kernels (q/k and v head sizes apart):
               layers)
     st_window the same under a sliding window of 4,096 (its window layers;
               the parent has no such call and is left out)
+    lfm2      2 x 32 x 8192 x 64 | 64, causal (lfm2_8b_a1b's attention
+              layer: two sequences a step, head size 64)
 
 For each (block_q, block_k): forward ms, backward ms (the backward call
 alone, on the forward's saved results) and forward + backward ms (host
@@ -53,6 +55,9 @@ GEOMETRIES = {
                   [(512, 512), (256, 512)]),
     "st_window": (1, 28, 16384, 128, 128, True, False, None,
                   [(512, 512), (256, 512), (512, 256), (256, 256)]),
+    "lfm2": (2, 32, 8192, 64, 64, True, False, None,
+             [(512, 1024), (512, 512), (256, 1024), (1024, 1024),
+              (1024, 512), (256, 512)]),
 }
 WINDOWS = {"st_window": 4096}       # geometry -> flash_attention(window=)
 
@@ -179,7 +184,7 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--parent", default=None)
     ap.add_argument("--cells", default="joyai,nemotron,seq512,sdar,"
-                                       "st_global,st_window")
+                                       "st_global,st_window,lfm2")
     ap.add_argument("--steps", type=int, default=20)
     args = ap.parse_args()
 

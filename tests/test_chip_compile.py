@@ -339,8 +339,9 @@ def test_flash_window_at_the_cells_size(one_chip, compiled_kernels):
     assert 16 * mib < took <= allowed < 48 * mib
 
 
-# the one backward kernel at the four flash cells' shapes, and at 16,384 x
-# 128: beside the whole q side (q, dO, statistics) it holds dq's block and
+# the one backward kernel at the four flash cells' shapes, at 16,384 x 128
+# and at the lfm2 cell's 2 x 32 x 8,192 x 64 (two buffers a side, 512 x
+# 1,024 tiles): beside the whole q side (q, dO, statistics) it holds dq's block and
 # dq's float32 accumulator, which the default 16 MiB do not hold with 512 x
 # 512 tiles; its limit is computed from the call's shapes
 @pytest.mark.parametrize("shape,kw,blocks", [
@@ -349,7 +350,8 @@ def test_flash_window_at_the_cells_size(one_chip, compiled_kernels):
     ((1, 32, 16384, 128, 128), dict(shift=2), (512, 512)),
     ((16, 12, 512, 64, 64), dict(mask=(16, 1, 1, 512)), (512, 1024)),
     ((1, 32, 16384, 128, 128), dict(causal=True), (512, 512)),
-], ids=["joyai", "nemotron", "sdar", "seq512", "16k"])
+    ((2, 32, 8192, 64, 64), dict(causal=True), (512, 1024)),
+], ids=["joyai", "nemotron", "sdar", "seq512", "16k", "lfm2"])
 def test_flash_backward_fits_the_vmem_limit_its_shapes_give(
         one_chip, compiled_kernels, shape, kw, blocks):
     import importlib
@@ -511,6 +513,34 @@ def test_kept_flash_results_in_the_steps_temporaries(
     more = kept.temp_size_in_bytes - full.temp_size_in_bytes
     assert kept.argument_size_in_bytes == full.argument_size_in_bytes
     assert abs(more - results) <= 0.1 * results, (more, results)
+
+
+def test_the_lfm2_cells_step_holds_its_kernels_and_fits(one_chip,
+                                                         compiled_kernels,
+                                                         monkeypatch):
+    """The first two blocks of ``lfm2_8b_a1b.causal_pretrain_2x8k`` (a conv
+    layer over the dense feed-forward, the attention layer over experts)
+    at the cell's widths and 2 x 8,192 tokens, through the cell's own
+    family file: the step compiles for the chip with both kernel routes
+    taken (counts and sizes, no time: nothing runs)."""
+    from paddle_tpu import monitor
+    before = {p: monitor.snapshot(p)
+              for p in ("gated_short_conv", "flash_attention")}
+    seen = _cell_step_memory(monkeypatch, one_chip,
+                             "lfm2_8b_a1b.causal_pretrain_2x8k", 2, None)
+
+    def gained(prefix, name):
+        return monitor.snapshot(prefix).get(f"{prefix}.{name}", 0) \
+            - before[prefix].get(f"{prefix}.{name}", 0)
+
+    assert gained("gated_short_conv", "kernel_traced") == 1
+    assert gained("gated_short_conv", "xla_traced") == 0
+    assert gained("flash_attention", "kernel_traced") == 1
+    # 193 M parameters at 12 bytes (weights and two moments; the gradient
+    # is a temporary), and temporaries that leave the chip room
+    params = 60_827_648 + 98_635_904 + 33_554_432 + 2_048
+    assert abs(seen.argument_size_in_bytes - 12 * params) < 2 ** 20
+    assert seen.temp_size_in_bytes < 6 * 10 ** 9
 
 
 # -- the nemotron cell's routed experts: plain XLA, chosen on the device ----
@@ -691,6 +721,41 @@ def test_conv1d_kernels_compile_where_supported(one_chip, compiled_kernels,
         assert count == 1            # the forward's result is not needed
 
 
+# the lfm2_8b_a1b cell's gated short convolution, 2 x 8,192 x 3 x 2,048 in
+# bfloat16 with 3 taps, and two small shapes: the forward reads the three
+# thirds of bcx in place and the backward writes d(bcx) whole, so the
+# compiled program around the two kernels holds no slice of a third, no
+# concatenation, and nothing of rows x channels in float32
+@pytest.mark.parametrize("b,s,c,taps", [(2, 8192, 2048, 3), (1, 384, 128, 3),
+                                        (2, 256, 384, 4)],
+                         ids=["2x8192x2048-K3", "384x128-K3", "2x256x384-K4"])
+def test_gated_conv_kernels_compile_where_supported(one_chip,
+                                                    compiled_kernels, b, s, c,
+                                                    taps):
+    from paddle_tpu.ops.pallas import causal_conv1d as K
+    assert K.gated_supported((b, s, 3 * c), taps)
+
+    def both(bcx, w, dy):
+        y, vjp = jax.vjp(K.gated_short_conv, bcx, w)
+        return y, vjp(dy)
+
+    text = _compiled_text(both, one_chip, ((b, s, 3 * c), jnp.bfloat16),
+                          ((c, taps), jnp.float32), ((b, s, c), jnp.bfloat16),
+                          names=("gated_conv_fwd", "gated_conv_bwd"))
+    assert text.count("tpu_custom_call") == 2
+    entry = text[text.index("\nENTRY "):]
+    assert not [shape for shape in _shapes(entry, "f32")
+                if shape[-2:] in ((s, c), (s, 3 * c))]
+    assert " concatenate(" not in entry and " slice(" not in entry
+    assert (b, s, 3 * c) in _shapes(entry, "bf16")
+    # inside what a kernel may use unasked (no limit of its own is given)
+    took = [int(re.search(_SCOPED % "used_scoped_memory_configs",
+                          line).group(1))
+            for line in text.splitlines()
+            if "tpu_custom_call" in line and " custom-call(" in line]
+    assert len(took) == 2 and 0 < max(took) <= 16 * 2 ** 20
+
+
 @pytest.mark.parametrize("b,s,d,groups", [(2, 1024, 1024, 8), (1, 384, 2048, 1),
                                           (1, 128, 256, 1)],
                          ids=["2x1024x1024-G8", "384x2048-G1", "128x256-G1"])
@@ -825,7 +890,8 @@ def test_to_static_step_on_a_mesh_traces_without_kernels(compiled_kernels):
 KERNEL_NAMES = {
     "batch_norm.py": ["batch_norm_stats", "batch_norm_apply",
                       "batch_norm_bwd_reduce", "batch_norm_bwd_dx"],
-    "causal_conv1d.py": ["conv1d_fwd", "conv1d_bwd"],
+    "causal_conv1d.py": ["conv1d_fwd", "conv1d_bwd", "gated_conv_fwd",
+                         "gated_conv_bwd"],
     "flash_attention.py": ["flash_fwd", "flash_win_fwd", "flash_bwd",
                            "flash_win_bwd", "flash_bd_fwd", "flash_bd_bwd"],
     "gated_rms_norm.py": ["gated_norm_fwd", "gated_norm_bwd"],
@@ -870,13 +936,26 @@ def test_no_pallas_call_site_is_left_out_and_no_name_is_used_twice():
     found = {f: names for f, names in found.items() if names}
     assert found == KERNEL_NAMES
     every = [n for names in found.values() for n in names]
-    assert len(every) == len(set(every)) == 21
+    assert len(every) == len(set(every)) == 23
+
+
+# a registered name switches the kernels of the file of its name; where two
+# pairs share a file, those whose names start with its prefix
+KERNEL_SWITCHES = {"causal_conv1d": ("causal_conv1d.py", "conv1d_"),
+                   "gated_short_conv": ("causal_conv1d.py", "gated_conv_")}
 
 
 def test_every_registered_kernel_has_a_module_with_a_call_site():
     """``_KERNELS`` against the files the test above has just walked: a
-    registered name is a module with named ``pl.pallas_call``s and a
-    module with call sites is registered, so a name cannot outlive its
-    kernel, nor a kernel ship without its switch."""
-    assert {f[:-len(".py")] for f in KERNEL_NAMES} \
-        == set(P._KERNELS) == set(P._AUTO_ON)
+    registered name switches at least one named ``pl.pallas_call`` and
+    every call site is under exactly one name, so a name cannot outlive
+    its kernel, nor a kernel ship without its switch."""
+    switched = {}
+    for name in P._KERNELS:
+        filename, prefix = KERNEL_SWITCHES.get(name, (name + ".py", ""))
+        switched[name] = [k for k in KERNEL_NAMES.get(filename, [])
+                          if k.startswith(prefix)]
+    assert all(switched.values()), switched
+    assert sorted(k for names in switched.values() for k in names) \
+        == sorted(k for names in KERNEL_NAMES.values() for k in names)
+    assert set(P._KERNELS) == set(P._AUTO_ON)
