@@ -101,12 +101,12 @@ def phase_kernels(rows, hidden, batch, heads, seq, head_dim,
 
     shipped = sorted(k for k, on in P._AUTO_ON.items() if on)
     if shipped != ["causal_conv1d", "flash_attention", "gated_rms_norm",
-                   "gated_short_conv", "layer_norm", "moe_scatter_add",
-                   "ssd_scan"]:
+                   "gated_short_conv", "layer_norm", "moe_grouped",
+                   "moe_scatter_add", "ssd_scan"]:
         raise AssertionError(f"_AUTO_ON ships {shipped}; this phase covers "
                              f"causal_conv1d, flash_attention, "
                              f"gated_rms_norm, gated_short_conv, layer_norm, "
-                             f"moe_scatter_add and ssd_scan")
+                             f"moe_grouped, moe_scatter_add and ssd_scan")
     on_chip = pt.device.is_tpu_backend()
     if on_chip and P.interpret_mode():
         raise AssertionError("interpret mode reachable on a TPU backend")
@@ -339,6 +339,27 @@ def phase_kernels(rows, hidden, batch, heads, seq, head_dim,
     run(f"moe_scatter_add[{rows}x{hidden},{held}x{width}gated,bf16]",
         experts(True, jnp.bfloat16), experts(False, jnp.float32), moe_args,
         5, tol_bf16, 2)
+
+    # the same layer through the grouped products (one sort, rows padded
+    # to the row tile, five kernels and the scatter-add), experts whole
+    # 128-lane tiles wide; off the chip at a tile the interpreter can walk
+    wide = -(-width // 128) * 128
+    tile, chunk = (moe.ROW_TILE, moe.CHUNK_ROWS) if on_chip else (16, 64)
+    if not P.moe_grouped_mod.supported(hidden, wide, tile):
+        raise AssertionError("the grouped kernels would not take this shape")
+    grouped_args = moe_args[:2] + tuple(
+        jnp.asarray(0.05 * rng.randn(*shape), jnp.float32)
+        for shape in ((held, hidden, wide), (held, wide, hidden),
+                      (held, hidden, wide))) + moe_args[5:]
+
+    def grouped(x, weights, up, down, gate, ct, chosen):
+        y, _ = moe._tiles(x[0].astype(jnp.bfloat16), weights[0], (gate, up),
+                          down, chosen[0], 0, tile, chunk, jnp.bfloat16,
+                          False)
+        return (y * ct[0]).sum()
+
+    run(f"moe_grouped[{rows}x{hidden},{held}x{wide}gated,bf16]",
+        grouped, experts(False, jnp.float32), grouped_args, 5, tol_bf16, 9)
 
 
 # ---------------------------------------------------------------------------

@@ -10,21 +10,44 @@ impl through ``dispatch.apply``:
   alone, renormalised over the chosen and scaled.
 * ``moe_experts`` — the part of the layer's result that the experts HELD
   HERE give (expert parallelism's local half: the layer is told which
-  experts it holds, the router still ranges over all of them). Grouped
-  products whose cost follows the rows routed: the tokens of each held
-  expert are brought to the front of a list (a stable sort), the expert
-  picks, inside the step, the smallest capacity of a ladder that holds
-  its rows (``_ladder``: from ``MIN_ROWS`` up by doubling to the number
-  of tokens, which is the most one expert can draw), gathers that
-  many rows, runs its products on them (two, ``W_down relu(W_up x)^2``;
-  three with a gate, ``W_down (silu(W_gate x) * W_up x)``, or with
-  ``activation="relu"`` ``W_down (relu(W_gate x) * W_up x)``) and adds the
-  result back to its tokens. Dropless by construction: the ladder's last rung holds
-  every token, so no imbalance can overflow it. The backward pass is
-  written by hand (``jax.custom_vjp``) over the same rows and makes the
-  hidden activations again, so nothing of a rung's size is kept between
-  the passes. What the absent experts would add is left out; no code
-  stands in for their chips or for the exchange.
+  experts it holds, the router still ranges over all of them): two
+  products an expert, ``W_down relu(W_up x)^2``; three with a gate,
+  ``W_down (silu(W_gate x) * W_up x)``, or with ``activation="relu"``
+  ``W_down (relu(W_gate x) * W_up x)``. Dropless by construction, on either
+  of two paths whose cost follows the rows routed:
+
+  **Grouped** (one TPU, ``d`` and the experts' width whole 128-lane tiles;
+  ``_routed_tiles``). The ``tokens x k`` (token, slot) pairs are sorted by
+  held expert ONCE a layer (``_layout``: one stable multi-operand sort,
+  the slots of absent experts last), each expert's rows padded to a row
+  tile of ``ROW_TILE`` rows and not to a capacity. Then ONE grouped
+  product a matrix and pass over the sorted rows - the kernels of
+  ``ops/pallas/moe_grouped.py``, whose grids follow the group sizes by a
+  scalar-prefetched table (which expert's weight block a row tile reads;
+  row tiles past the live rows are skipped) and which cast the float32
+  matrices block by block in VMEM, with no pass over them in HBM -
+  between one gather of the rows (and of ``dy``) and one in-place
+  scatter-add (``ops/pallas/moe_scatter_add.py``). The layout's
+  static bound is ``tokens x min(k, held)`` rows, since every token may
+  choose only experts held here; a tenth to a third of that is live, so
+  gather, products and scatter run in rounds of ``CHUNK_ROWS`` sorted rows,
+  a loop whose trip count follows the live rows: nothing is dropped, and a
+  layer holds one round's rows in HBM whatever the bound.
+
+  **The ladder** (``_routed``: the CPU, under a mesh, a width that is no
+  whole tile). The tokens of each held expert are brought to the front of
+  a list (a stable sort an expert), the expert picks, inside the step, the
+  smallest capacity of a ladder that holds its rows (``_ladder``: from
+  ``MIN_ROWS`` up by doubling to the number of tokens, which is the most
+  one expert can draw), gathers that many rows, runs its products on them
+  and adds the result back to its tokens; the ladder's last rung holds
+  every token, so no imbalance can overflow it.
+
+  On both the backward pass is written by hand (``jax.custom_vjp``) over
+  the same rows and makes the hidden activations again, so nothing of a
+  round's or a rung's size is kept between the passes. What the absent
+  experts would add is left out; no code stands in for their chips or for
+  the exchange.
 """
 from __future__ import annotations
 
@@ -44,9 +67,10 @@ __all__ = ["moe_route", "moe_experts", "MOE_STATS"]
 MOE_STATS = ("slots_routed_here", "slots_dropped", "expert_load_max", "calls",
              "rows_computed")
 
-# Under about 500 rows an expert's products on a v5e wait for its two
-# weight matrices, not for its rows (2 flops a weight byte and row against
-# the chip's 240 flops a byte), so smaller rungs would cost the same.
+# The ladder's first rung. Under about 500 rows an expert's products on a
+# v5e wait for its two weight matrices, not for its rows (2 flops a weight
+# byte and row against the chip's 240 flops a byte), so smaller rungs would
+# cost the same. (The grouped path has no rungs: ROW_TILE below.)
 MIN_ROWS = 512
 
 
@@ -230,6 +254,191 @@ def _grouped_bwd(ladder, dot_dtype, kernel, relu_gate, saved, dy):
 _grouped.defvjp(_grouped_fwd, _grouped_bwd)
 
 
+# -- the grouped path: rows sorted by expert once, one product a matrix ----
+
+# Rows an expert's group is padded to in the sorted layout: what the
+# products run over is each group rounded up to this, not to a rung. The
+# kernels keep an expert's weight block in VMEM while its row tiles go by
+# (ops/pallas/moe_grouped.py), so a small tile costs no weight traffic.
+ROW_TILE = 256
+# Sorted rows that one round of gather, products and scatter holds. The
+# layout's static bound is tokens x min(k, held) rows (dropless: every
+# token may choose only experts held here) where a tenth to a third of
+# that is live, so the rounds are a loop whose count follows the live
+# rows, and what a layer keeps in HBM is one round's rows whatever the
+# bound.
+CHUNK_ROWS = 8192
+
+
+def _layout(experts, weights, first, held, tile, chunk):
+    """The sorted layout of a call: the (token, slot) pairs whose expert
+    is held here, expert by expert in token order, each expert's rows
+    padded to whole tiles of ``tile`` rows - by ONE stable sort, of the
+    ``tokens x k`` slots together with ``tile`` rows of padding an expert:
+    the padding an expert needs sorts behind its rows, the rest and the
+    slots of absent experts behind everything. The router's weights and
+    the slots' own numbers ride along, so nothing is gathered or scattered
+    one element at a time.
+
+    ``(token, gate, group, live, sizes, slot)``: for each row of the
+    layout its token (a row of padding: ``tokens + its place in its
+    tile``, so that a tile never names a row twice) and its router weight
+    (padding: 0), int32 / float32 [rows], ``rows`` the static bound
+    ``tokens x min(k, held)`` + padding in whole chunks; for each row tile
+    the expert it belongs to, int32 [rows / tile]; the tiles that hold
+    rows; the rows of each expert, int32 [held]; and every sorted entry's
+    slot (padding: numbers past the slots), which sorts a gradient by row
+    back into ``weights``' order."""
+    tokens, k = experts.shape
+    slots, pads = tokens * k, held * tile
+    local = experts.reshape(slots) - first
+    here = (local >= 0) & (local < held)
+    key = jnp.where(here, local, held)
+    sizes = jnp.sum(key[None] == jnp.arange(held)[:, None], 1,
+                    dtype=jnp.int32)
+    padded = -(-sizes // tile) * tile
+    bound = tokens * min(k, held) + held * (tile - 1)
+    rows = -(-bound // chunk) * chunk
+    dead = max(rows - slots - pads, 0)
+    of_pad = jnp.repeat(jnp.arange(held, dtype=jnp.int32), tile)
+    needed = jnp.tile(jnp.arange(tile, dtype=jnp.int32), held) \
+        < jnp.repeat(padded - sizes, tile)
+    last = jnp.full((dead,), 2 * held, jnp.int32)
+    keys, slot, gate = lax.sort((
+        jnp.concatenate([2 * key, jnp.where(needed, 2 * of_pad + 1,
+                                            2 * held), last]),
+        jnp.arange(slots + pads + dead, dtype=jnp.int32),
+        jnp.concatenate([jnp.where(here, weights.reshape(slots).astype(
+            jnp.float32), 0.0), jnp.zeros((pads + dead,), jnp.float32)])),
+        num_keys=1, is_stable=True)
+    keys, at = keys[:rows], jnp.arange(rows, dtype=jnp.int32)
+    token = jnp.where((keys % 2 == 0) & (keys < 2 * held),
+                      slot[:rows] // k, tokens + at % tile)
+    group = jnp.minimum(keys[::tile] // 2, held - 1)
+    return token, gate[:rows], group, jnp.sum(padded) // tile, sizes, slot
+
+
+def _rounds(token, gate, group, live, tile, chunk, body, carry):
+    """``body(carry, token, gate, group, live)`` over the chunks of the
+    layout that hold rows, each with its slice of the layout's tables."""
+    per = chunk // tile
+
+    def one(c, carry):
+        return body(carry,
+                    lax.dynamic_slice(token, (c * chunk,), (chunk,)),
+                    lax.dynamic_slice(gate, (c * chunk,), (chunk,)),
+                    lax.dynamic_slice(group, (c * per,), (per,)),
+                    jnp.clip(live - c * per, 0, per).reshape(1), c)
+
+    return lax.fori_loop(0, -(-live // per), one, carry)
+
+
+def _sum_rows(tokens, tile, d):
+    """The float32 sum over a call's rows, ``[tokens + tile, 1, d]`` (the
+    last ``tile`` rows take the padding), and ``add(acc, token, rows)``."""
+    from . import pallas
+    add = functools.partial(
+        pallas.moe_scatter_add_mod.scatter_add,
+        tile=pallas.moe_scatter_add_mod.row_tile(tile, d),
+        interpret=pallas.interpret_mode())
+    return jnp.zeros((tokens + tile, 1, d), jnp.float32), add
+
+
+def _tiles_rows(rows, weights, ups, w_down, experts, first, tile, chunk,
+                dot_dtype, relu_gate):
+    return _tiles_fwd(rows, weights, ups, w_down, experts, first, tile,
+                      chunk, dot_dtype, relu_gate)[0]
+
+
+_tiles = jax.custom_vjp(_tiles_rows, nondiff_argnums=(5, 6, 7, 8, 9))
+
+
+def _tiles_fwd(rows, weights, ups, w_down, experts, first, tile, chunk,
+               dot_dtype, relu_gate):
+    """``(y, stats)``: ``y[t] = sum over the layout's rows r of token t of
+    gate[r] W_down[g(r)] h_{g(r)}(rows[t])``, float32 [tokens, d]."""
+    from . import pallas
+    G, interpret = pallas.moe_grouped_mod, pallas.interpret_mode()
+    tokens, d = rows.shape
+    token, gate, group, live, sizes, slot = _layout(
+        experts, weights, first, w_down.shape[0], tile, chunk)
+    y, add_rows = _sum_rows(tokens, tile, d)
+
+    def body(y, token, gate, group, live, _):
+        xs = rows[jnp.minimum(token, tokens - 1)]
+        # (the kernels cast the float32 matrices block by block in VMEM:
+        # no pass over them in HBM, where the ladder makes one an expert)
+        h = G.hidden(xs, gate[:, None], ups, group, live, tile=tile,
+                     relu_gate=relu_gate, interpret=interpret)
+        out = G.gmm((h,), (w_down,), group, live, tile=tile,
+                    transpose_rhs=False, interpret=interpret)
+        return add_rows(y, token, out)
+
+    y = _rounds(token, gate, group, live, tile, chunk, body, y)
+    stats = jnp.stack([jnp.sum(sizes), jnp.zeros((), jnp.int32),
+                       jnp.max(sizes), jnp.ones((), jnp.int32),
+                       live * tile])
+    return (y[:tokens].reshape(tokens, d), stats), (
+        rows, weights, ups, w_down, token, gate, group, live, slot)
+
+
+def _tiles_bwd(first, tile, chunk, dot_dtype, relu_gate, saved, cotangent):
+    from . import pallas
+    G, interpret = pallas.moe_grouped_mod, pallas.interpret_mode()
+    rows, weights, ups, w_down, token, gate, group, live, slot = saved
+    tokens, d = rows.shape
+    f32 = jnp.float32
+    dy = cotangent[0].astype(dot_dtype)
+    dx, add_rows = _sum_rows(tokens, tile, d)
+    kw = dict(tile=tile, interpret=interpret)
+
+    def body(carry, token, gate, group, live, c):
+        dx, d_gate, d_ups, d_down = carry
+        at = jnp.minimum(token, tokens - 1)
+        xs, dyr = rows[at], dy[at]
+        cotangents, hg, dg = G.hidden_bwd(
+            xs, dyr, gate[:, None], ups, w_down, group, live,
+            relu_gate=relu_gate, **kw)
+        d_down = G.tgmm(hg, dyr, d_down, group, live, **kw)
+        d_ups = tuple(G.tgmm(xs, ct, acc, group, live, **kw)
+                      for ct, acc in zip(cotangents, d_ups))
+        dx = add_rows(dx, token, G.gmm(cotangents, ups, group, live,
+                                       transpose_rhs=True, **kw))
+        # a tile past the live ones was never written
+        dg = jnp.where(jnp.arange(chunk) < live * tile,
+                       jnp.sum(dg, (0, 2)), 0.0)
+        return (dx, lax.dynamic_update_slice(d_gate, dg, (c * chunk,)),
+                d_ups, d_down)
+
+    dx, d_gate, d_ups, d_down = _rounds(
+        token, gate, group, live, tile, chunk, body,
+        (dx, jnp.zeros(slot.shape, f32),
+         tuple(jnp.zeros(w.shape, f32) for w in ups),
+         jnp.zeros(w_down.shape, f32)))
+    # by row -> by slot: the sort's inverse is a sort by the slots' numbers;
+    # the padding's numbers lie past the slots and fall off the end
+    d_weights = lax.sort((slot, d_gate), num_keys=1)[1][:weights.size]
+    return (dx[:tokens].reshape(tokens, d).astype(rows.dtype),
+            d_weights.reshape(weights.shape).astype(weights.dtype),
+            tuple(g.astype(w.dtype) for g, w in zip(d_ups, ups)),
+            d_down.astype(w_down.dtype), None)
+
+
+_tiles.defvjp(_tiles_fwd, _tiles_bwd)
+
+
+def _routed_tiles(x, experts, weights, w_up, w_down, w_gate=None, *, first,
+                  dot_dtype, relu_gate=False):
+    lead, d = x.shape[:-1], x.shape[-1]
+    rows = x.reshape(-1, d)
+    tokens, k = rows.shape[0], experts.shape[-1]
+    ups = (w_up,) if w_gate is None else (w_gate, w_up)
+    y, stats = _tiles(rows.astype(dot_dtype), weights.reshape(tokens, k),
+                      ups, w_down, experts.reshape(tokens, k), first,
+                      ROW_TILE, CHUNK_ROWS, dot_dtype, relu_gate)
+    return y.reshape(*lead, d).astype(x.dtype), stats
+
+
 def _routed(x, experts, weights, w_up, w_down, w_gate=None, *, first,
             dot_dtype, kernel=False, relu_gate=False):
     f32 = jnp.float32
@@ -272,17 +481,24 @@ def moe_experts(x, experts, weights, w_up, w_down, first_expert=0,
     model's experts; ``w_up`` (and ``w_gate``) [held, d, f] and ``w_down``
     [held, f, d] are the experts ``first_expert .. first_expert + held``.
     ``stats`` is
-    int32[5], :data:`MOE_STATS`: slots routed here; slots a rung did not
-    hold (0: the last rung holds every token); the fullest expert's rows;
-    1; and the rows the products ran over, padding included (the ladder
-    starts at :data:`MIN_ROWS`). Under ``amp.auto_cast`` the products
-    take the compute dtype's operands and accumulate in float32.
+    int32[5], :data:`MOE_STATS`: slots routed here; slots dropped (0 on
+    either path: the layout's bound, or the ladder's last rung, holds
+    every token); the fullest expert's rows; 1; and the rows the products
+    ran over, padding included - each expert's rows rounded up to
+    :data:`ROW_TILE` on the grouped path, to its rung (from
+    :data:`MIN_ROWS` up by doubling) on the ladder. Under
+    ``amp.auto_cast`` the products take the compute dtype's operands and
+    accumulate in float32.
 
-    On one TPU an expert's rows are added to the sum in place, by the
-    kernel of ``ops/pallas/moe_scatter_add.py``; on the CPU, under a mesh
-    and at a width that is no whole 128-lane tile, by XLA's scatter-add.
-    The counters ``moe_experts.kernel_traced`` / ``moe_experts.xla_traced``
-    say which a call site traced."""
+    On one TPU, where ``d`` and the experts' width are whole 128-lane
+    tiles, the call takes the grouped path (module docstring): one sort a
+    layer, one grouped product a matrix and pass
+    (``ops/pallas/moe_grouped.py``). Otherwise the ladder, an expert's rows
+    added to the sum in place by the kernel of
+    ``ops/pallas/moe_scatter_add.py`` on one TPU and by XLA's scatter-add
+    on the CPU, under a mesh and at a ``d`` that is no whole tile. The
+    counters ``moe_experts.grouped_traced`` / ``moe_experts.kernel_traced``
+    / ``moe_experts.xla_traced`` say which a call site traced."""
     from .. import amp, monitor
     from . import pallas
     if activation not in ("silu", "relu") or (
@@ -290,19 +506,30 @@ def moe_experts(x, experts, weights, w_up, w_down, first_expert=0,
         raise ValueError(f"moe_experts: activation {activation!r} is "
                          f"neither 'silu' nor, beside w_gate, 'relu'")
     dot_dtype = amp.compute_dtype() if amp.is_enabled() else None
-    # read off the call, as ssd_scan: the kernel where its tiles fit every
-    # rung of this call's ladder and the registry has it on
-    tokens = math.prod(x.shape[:-1])
-    kernel = (pallas.enabled("moe_scatter_add")
-              and pallas.moe_scatter_add_mod.supported(
-                  int(x.shape[-1]), _ladder(tokens, MIN_ROWS)))
-    monitor.counter("moe_experts.kernel_traced" if kernel
+    # read off the call, as ssd_scan: the grouped kernels where their
+    # blocks fit its widths, else the ladder with the scatter-add kernel
+    # where its tiles fit every rung - each where the registry has it on
+    tokens, d = math.prod(x.shape[:-1]), int(x.shape[-1])
+    scatter = pallas.moe_scatter_add_mod
+    grouped = (pallas.enabled("moe_grouped")
+               and pallas.moe_grouped_mod.supported(
+                   d, int(w_up.shape[2]), ROW_TILE)
+               and scatter.row_tile(ROW_TILE, d) is not None)
+    kernel = not grouped and (
+        pallas.enabled("moe_scatter_add")
+        and scatter.supported(d, _ladder(tokens, MIN_ROWS)))
+    monitor.counter("moe_experts.grouped_traced" if grouped
+                    else "moe_experts.kernel_traced" if kernel
                     else "moe_experts.xla_traced").inc()
 
     def impl(x, experts, weights, w_up, w_down, *gate, first):
-        return _routed(x, experts, weights, w_up, w_down, *gate, first=first,
-                       dot_dtype=dot_dtype or jnp.result_type(x),
-                       kernel=kernel, relu_gate=activation == "relu")
+        kw = dict(first=first, dot_dtype=dot_dtype or jnp.result_type(x),
+                  relu_gate=activation == "relu")
+        if grouped:
+            return _routed_tiles(x, experts, weights, w_up, w_down, *gate,
+                                 **kw)
+        return _routed(x, experts, weights, w_up, w_down, *gate,
+                       kernel=kernel, **kw)
 
     args = (x, experts, weights, w_up, w_down)
     with _pscope("F.moe_experts"):

@@ -32,7 +32,7 @@ def on_tpu():
 _overrides = {}
 _KERNELS = ("layer_norm", "flash_attention", "softmax_xent", "batch_norm",
             "ssd_scan", "causal_conv1d", "gated_rms_norm",
-            "moe_scatter_add", "gated_short_conv")
+            "moe_scatter_add", "gated_short_conv", "moe_grouped")
 
 # Auto defaults from one builder-run v5e ablation (2026-07-31, superseded
 # toolchain, not reproduced — docs/performance.md carries the table):
@@ -76,10 +76,26 @@ _KERNELS = ("layer_norm", "flash_attention", "softmax_xent", "batch_norm",
 # looks at the call (moe_scatter_add.supported: d whole 128-lane tiles, a
 # row tile for every rung); the kernel is a module-level jax.jit, so a
 # step lowers it once a rung, not once a call site.
+# moe_grouped: on, measured on the same v5e (PERF.md section 6, PR 44).
+# F.moe_experts alone, a forward call + a forward-and-backward call, the
+# per-expert ladder against the grouped path (one sort a layer, one
+# grouped product a matrix and pass, a row tile of 256, the float32
+# matrices cast block by block in VMEM): 32.4 -> 24.1 ms at the lfm2
+# cell's shape (8 held experts 1,792 wide over 2 x 8,192 x 2,048 rows,
+# ~20 k live), 15.1 -> 13.8 smallthinker, 15.7 -> 12.8 sdar, 10.0 -> 7.2
+# joyai; the products alone 11.7 ms at lfm2's shape where jax's megablox
+# takes 15.4 at its best tiling and lax.ragged_dot 19.7
+# (scripts/tune_moe.py). In the cells: lfm2's step 292.6 -> 265.5 ms,
+# joyai's 394.2 -> 380.6, sdar's 424.1 -> 410.8, smallthinker's 631.4 ->
+# 616.1. F.moe_experts looks at the call (moe_grouped.supported: d and the
+# experts' width whole 128-lane tiles - nemotron's 1,856 is not, and keeps
+# the ladder); every kernel is a module-level jax.jit, one lowering a
+# distinct shape.
 _AUTO_ON = {"layer_norm": True, "flash_attention": True,
             "softmax_xent": False, "batch_norm": False, "ssd_scan": True,
             "causal_conv1d": True, "gated_rms_norm": True,
-            "moe_scatter_add": True, "gated_short_conv": True}
+            "moe_scatter_add": True, "gated_short_conv": True,
+            "moe_grouped": True}
 
 
 # flash is an O(S^2)-score win: below some sequence length the XLA sdpa
@@ -128,7 +144,8 @@ def configure(flash_min_seq=_UNSET, **kernels):
     """configure(layer_norm=False, softmax_xent=None, ...) — override the
     auto default for named kernels ('layer_norm', 'flash_attention',
     'softmax_xent', 'batch_norm', 'ssd_scan', 'causal_conv1d',
-    'gated_rms_norm', 'moe_scatter_add', 'gated_short_conv'); any other
+    'gated_rms_norm', 'moe_scatter_add', 'gated_short_conv',
+    'moe_grouped'); any other
     name raises
     ValueError. None restores auto.
     flash_min_seq=N routes sequences shorter than N to XLA sdpa even
@@ -176,6 +193,7 @@ from . import ssd_scan as ssd_scan_mod
 from . import causal_conv1d as causal_conv1d_mod
 from . import gated_rms_norm as gated_rms_norm_mod
 from . import moe_scatter_add as moe_scatter_add_mod
+from . import moe_grouped as moe_grouped_mod
 
 from .layer_norm import layer_norm
 from .softmax_xent import softmax_cross_entropy

@@ -10,6 +10,11 @@ whole through the switch, once an expert and pass (134 MB at 16,384 x
 takes a tile of ``rows`` through VMEM, fetches the accumulator's rows
 ``at[i]`` (scalar prefetch) one DMA a row, adds, and writes them back.
 
+Since PR 44 the grouped path of ``ops/moe.py`` ends a round of sorted rows
+with the same call: one scatter of a round's rows where the ladder makes
+one an expert, at a program tile that divides the layout's row tile, so a
+program's rows are one expert's (``scatter_add(..., tile=)``).
+
 The accumulator is ``[tokens, 1, d]``: on a TPU that array lies row by
 row in HBM (tiles of 1 x 128), so one row is one slice of it; as
 ``[tokens, d]`` eight rows share a tile and no single row can be
@@ -40,7 +45,9 @@ _TILE_BYTES = 2 << 20       # of float32 rows a program holds, at most
 
 def row_tile(cap, d):
     """Rows of ``rows`` a program takes: whole sublanes of eight that
-    divide ``cap``, or all of a small ``cap``."""
+    divide ``cap``, or all of a small ``cap``. (Given a row tile of the
+    grouped layout as ``cap``, a tile that divides it: no program then
+    holds rows of two experts, so none holds a token twice.)"""
     fit = _TILE_BYTES // (4 * d)
     return next((t for t in _ROW_TILES if cap % t == 0 and t <= fit),
                 cap if cap <= min(fit, _ROW_TILES[0]) else None)
@@ -84,13 +91,14 @@ def _kernel(at_ref, rows_ref, _, acc_ref, buf, sem, *, tile):
     each(lambda r: store(r).wait())
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def scatter_add(acc, at, rows, *, interpret):
+@functools.partial(jax.jit, static_argnames=("interpret", "tile"))
+def scatter_add(acc, at, rows, *, interpret, tile=None):
     """``acc`` float32 [tokens, 1, d] with ``rows[i]`` ([cap, d], any
-    float dtype) added to row ``at[i]`` (int32 [cap], no token twice), in
-    place."""
+    float dtype) added to row ``at[i]`` (int32 [cap], no token twice
+    among the ``tile`` rows of a program: ``row_tile(cap, d)`` of them
+    unless told), in place."""
     cap, d = rows.shape
-    tile = row_tile(cap, d)
+    tile = tile or row_tile(cap, d)
     return pl.pallas_call(
         functools.partial(_kernel, tile=tile),
         grid_spec=pltpu.PrefetchScalarGridSpec(

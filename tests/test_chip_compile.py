@@ -599,6 +599,80 @@ def test_grouped_experts_combine_in_place_at_the_cells_sizes(
     assert len(whole) <= 4, whole     # zeros and the way out, not a branch
 
 
+# -- the grouped path (PR 44): one product a matrix over sorted rows ---------
+
+GROUPED_CELLS = {   # tokens, d, f, held, k, gated, relu gate
+    "lfm2": (16384, 2048, 1792, 8, 4, True, False),
+    "smallthinker": (16384, 2560, 768, 8, 6, True, True),
+    "sdar": (16384, 2048, 768, 16, 8, True, False),
+    "joyai": (8192, 2048, 768, 16, 8, True, False),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(GROUPED_CELLS))
+def test_grouped_experts_fwd_bwd_at_the_cells_sizes(one_chip,
+                                                    compiled_kernels, cell):
+    """``F.moe_experts``' grouped path, forward and hand-written backward,
+    at the four cells it runs in: the five kernels of
+    ``ops/pallas/moe_grouped.py`` and the scatter-add, each within the VMEM
+    its shapes ask for; no conditional an expert (one loop over rounds of
+    rows forward, one backward); nothing at the layout's static bound but
+    its int32 / float32 tables - what is held of the rows is one round."""
+    from paddle_tpu.ops import moe
+    tokens, d, f, held, k, gated, relu_gate = GROUPED_CELLS[cell]
+
+    def loss(x, weights, experts, *ws):
+        y, _ = moe._routed_tiles(x, experts, weights, *ws, first=0,
+                                 dot_dtype=jnp.bfloat16, relu_gate=relu_gate)
+        return jnp.sum(jnp.square(y.astype(jnp.float32)))
+
+    text = _compiled_text(
+        jax.grad(loss, argnums=(0, 1) + tuple(range(3, 5 + gated))),
+        one_chip, ((1, tokens, d), jnp.bfloat16),
+        ((1, tokens, k), jnp.float32), ((1, tokens, k), jnp.int32),
+        ((held, d, f), jnp.float32), ((held, f, d), jnp.float32),
+        *[((held, d, f), jnp.float32)] * gated,
+        names=("moe_hidden", "moe_gmm", "moe_hidden_bwd", "moe_tgmm",
+               "moe_scatter_add"))
+    # forward: hidden, gmm, scatter-add; backward: hidden_bwd, tgmm x 3,
+    # gmm, scatter-add
+    assert text.count('custom_call_target="tpu_custom_call"') == 9
+    assert " conditional(" not in text
+    assert len(re.findall(r" while\(", text)) == 2
+    # XLA may keep arrays of its own in VMEM under a kernel's share: the
+    # share starts at an offset, and what was used counts from 0
+    share = (r'"scoped_memory_configs":\[\{"memory_space":"1",'
+             r'"offset":"(\d+)","size":"(\d+)"')
+    mib, seen = 2 ** 20, set()
+    for line in text.splitlines():
+        if "tpu_custom_call" in line and " custom-call(" in line:
+            name = re.search(r"[/(](\w+)\)*/pallas_call", line).group(1)
+            offset, allowed = map(int, re.search(share, line).groups())
+            took = int(re.search(_SCOPED % "used_scoped_memory_configs",
+                                 line).group(1)) - offset
+            # (hidden_bwd holds three float32 weight blocks twice, their
+            # casts and two row tiles: 68-80 MiB of the chip's 128)
+            assert 0 < took <= allowed <= 96 * mib, (name, allowed, took)
+            seen.add(name)
+    assert len(seen) == 5
+    bound = tokens * min(k, held)
+    assert moe.CHUNK_ROWS < bound
+    assert re.search(r"bf16\[%d,%d\]" % (moe.CHUNK_ROWS, d), text)
+    assert not re.search(r"(bf16|f32)\[(\d+,)?%d,%d\]" % (
+        -(-(bound + held * (moe.ROW_TILE - 1)) // moe.CHUNK_ROWS)
+        * moe.CHUNK_ROWS, d), text)
+
+
+def test_the_nemotron_cells_width_keeps_the_ladder():
+    """1,856 is no whole 128-lane tile (14.5): padding the matrices and
+    their gradients costs that cell more than its rungs' padding does
+    (PERF.md section 6, PR 44), and 384 rows an expert wait for their
+    weights either way."""
+    assert not P.moe_grouped_mod.supported(2688, 1856, 256)
+    assert all(P.moe_grouped_mod.supported(d, f, 256)
+               for _, d, f, *_ in GROUPED_CELLS.values())
+
+
 # -- the nemotron cell's Mamba-2 scan: a kernel pair ------------------------
 
 def test_ssd_scan_kernels_keep_chunk_sized_arrays_in_vmem(one_chip,
@@ -896,6 +970,8 @@ KERNEL_NAMES = {
                            "flash_win_bwd", "flash_bd_fwd", "flash_bd_bwd"],
     "gated_rms_norm.py": ["gated_norm_fwd", "gated_norm_bwd"],
     "layer_norm.py": ["layer_norm_fwd", "layer_norm_bwd"],
+    "moe_grouped.py": ["moe_hidden", "moe_gmm", "moe_hidden_bwd",
+                       "moe_tgmm"],
     "moe_scatter_add.py": ["moe_scatter_add"],
     "softmax_xent.py": ["softmax_xent_fwd", "softmax_xent_bwd"],
     "ssd_scan.py": ["ssd_fwd", "ssd_bwd"],
@@ -936,7 +1012,7 @@ def test_no_pallas_call_site_is_left_out_and_no_name_is_used_twice():
     found = {f: names for f, names in found.items() if names}
     assert found == KERNEL_NAMES
     every = [n for names in found.values() for n in names]
-    assert len(every) == len(set(every)) == 23
+    assert len(every) == len(set(every)) == 27
 
 
 # a registered name switches the kernels of the file of its name; where two

@@ -183,11 +183,11 @@ class _Stack(nn.Layer):
     behind a module-level ``jax.jit`` (the experts' row scatter-add) under
     ``jit.recompute`` and the ``lax.switch`` over its rungs."""
 
-    def __init__(self, d):
+    def __init__(self, d, wide=32):
         super().__init__()
         self.norm_in, self.norm_out = nn.LayerNorm(d), nn.LayerNorm(d)
         self.blocks = nn.LayerList([
-            nn.RoutedMoE(d, 32, 8, 2, experts_held=range(4), gated=True,
+            nn.RoutedMoE(d, wide, 8, 2, experts_held=range(4), gated=True,
                          scoring="softmax") for _ in range(2)])
 
     def forward(self, u):
@@ -197,8 +197,17 @@ class _Stack(nn.Layer):
         return self.norm_out(u)
 
 
+@pytest.fixture(params=["ladder", "grouped"])
+def experts_path(request):
+    """``F.moe_experts``' two kernel paths: the per-expert ladder with the
+    scatter-add in its branches, and (PR 44) the grouped products."""
+    P.configure(moe_grouped=request.param == "grouped")
+    yield request.param
+    P.configure(moe_grouped=None)
+
+
 def test_pallas_instances_are_the_custom_calls_of_the_lowered_step(
-        monkeypatch):
+        monkeypatch, experts_path):
     """Lowered for a TPU from this process (``lowering_platforms``: Mosaic
     is lowered in Python, no chip and no TPU library are asked for)."""
     class Lowered(Exception):
@@ -224,7 +233,7 @@ def test_pallas_instances_are_the_custom_calls_of_the_lowered_step(
     monkeypatch.setattr(P, "interpret_mode", lambda: False)
     monkeypatch.setattr(moe_ops, "MIN_ROWS", 16)
     pt.seed(0)
-    model = _Stack(128)
+    model = _Stack(128, 128 if experts_path == "grouped" else 32)
     o = opt.AdamW(learning_rate=1e-3, parameters=model.parameters())
 
     def step(u):
@@ -238,6 +247,15 @@ def test_pallas_instances_are_the_custom_calls_of_the_lowered_step(
         jit.to_static(step, models=[model], optimizers=[o])(
             pt.to_tensor(np.ones((1, 64, 128), np.float32)))
     (instances, traces), text = e.value.args
+    if experts_path == "grouped":
+        # the layer norms' 4; hidden, the two forms of gmm, hidden_bwd, one
+        # tgmm (the three gradients are one shape here) and the
+        # scatter-add, which the forward's re-staged jaxpr holds once more:
+        # one instance a shape, whatever the layers, rounds and call sites
+        assert instances == text.count("tpu_custom_call") == 4 + 7
+        assert traces == 4 + 6
+        assert text.count("call @tgmm") == 2 * 3
+        return
     assert instances == text.count("tpu_custom_call") == 10
     # 2 layer norms x (forward + backward), each traced at its own site;
     # the scatter-add's 3 rungs once for the forwards and once for the

@@ -222,10 +222,10 @@ def test_counters_say_which_path_a_call_site_traced(kernel_on):
 # -- the guard on the set-up ------------------------------------------------
 
 class _Stack(nn.Layer):
-    def __init__(self, layers, d):
+    def __init__(self, layers, d, wide=32):
         super().__init__()
         self.blocks = nn.LayerList([
-            nn.RoutedMoE(d, 32, 8, 2, experts_held=range(4), gated=True,
+            nn.RoutedMoE(d, wide, 8, 2, experts_held=range(4), gated=True,
                          scoring="softmax") for _ in range(layers)])
 
     def forward(self, u):
@@ -237,9 +237,11 @@ class _Stack(nn.Layer):
 _MAKE_ENTRY = jit.StaticFunction._make_entry
 
 
-def _lowered_step(monkeypatch, layers, d=128):
+def _lowered_step(monkeypatch, layers, d=128, grouped=False):
     """The StableHLO of a training step over ``layers`` recomputed expert
-    layers, lowered for a TPU from this process (nothing is compiled)."""
+    layers, lowered for a TPU from this process (nothing is compiled):
+    through the per-expert ladder, or (``grouped``, what a TPU runs since
+    PR 44) through the grouped products."""
     class Lowered(Exception):
         pass
 
@@ -258,9 +260,10 @@ def _lowered_step(monkeypatch, layers, d=128):
 
     monkeypatch.setattr(jit.StaticFunction, "_make_entry", make_entry)
     monkeypatch.setattr(P, "interpret_mode", lambda: False)
+    monkeypatch.setitem(P._overrides, "moe_grouped", grouped)
     monkeypatch.setattr(moe_ops, "MIN_ROWS", 16)
     pt.seed(0)
-    model = _Stack(layers, d)
+    model = _Stack(layers, d, 128 if grouped else 32)
     o = opt.AdamW(learning_rate=1e-3, parameters=model.parameters())
 
     def step(u):
@@ -313,3 +316,49 @@ def test_at_a_width_the_tiles_do_not_fit_the_step_holds_no_custom_call(
     text = _lowered_step(monkeypatch, 2, d=96)
     assert "tpu_custom_call" not in text and "scatter_add" not in text
     assert int(reg.value("moe_experts.xla_traced", 0)) - before == 2
+
+
+# -- the same guard on the grouped path (PR 44) ------------------------------
+
+GROUPED_KERNELS = ("hidden", "gmm", "hidden_bwd", "tgmm", "scatter_add")
+
+
+def test_grouped_kernel_instances_do_not_grow_with_layers_or_call_sites(
+        monkeypatch):
+    """A layer calls ``hidden`` and the down ``gmm`` at two sites (forward,
+    recomputed forward), ``hidden_bwd``, ``tgmm`` three times and the
+    ``dx`` ``gmm`` in the backward, the scatter-add at three. The module
+    holds each kernel once a distinct shape (the two ``gmm`` forms; the
+    scatter-add once more for the forward's re-staged jaxpr) - and no more
+    with three layers than with two, nor with the call sites."""
+    monitor.enable()
+    reg = monitor.registry()
+    counts = {}
+    for layers in (1, 2, 3):
+        before = int(reg.value("moe_experts.grouped_traced", 0))
+        text = _lowered_step(monkeypatch, layers, grouped=True)
+        assert int(reg.value("moe_experts.grouped_traced", 0)) - before \
+            == layers
+        counts[layers] = (text.count("tpu_custom_call"), sorted(
+            re.sub(r"_\d+$", "", name) for name in re.findall(
+                r"func\.func private @(\w+)", text)
+            if re.sub(r"_\d+$", "", name) in GROUPED_KERNELS))
+    assert counts[2] == counts[3] == (7, [
+        "gmm", "gmm", "hidden", "hidden_bwd", "scatter_add", "scatter_add",
+        "tgmm"]), counts
+    assert counts[1][0] <= 7
+    # called, not inlined, from every layer
+    assert text.count("call @hidden(") >= 3
+    assert text.count("call @tgmm(") >= 3 * 3
+
+
+def test_a_grouped_step_holds_no_switch_and_the_ladders_one_an_expert_scan(
+        monkeypatch):
+    """``lax.switch`` lowers to ``stablehlo.case``: the ladder's step has
+    one in each scan over the experts (forward, recomputed forward,
+    backward), the grouped step none - its loop is over rounds of rows."""
+    ladder = _lowered_step(monkeypatch, 2)
+    grouped = _lowered_step(monkeypatch, 2, grouped=True)
+    assert ladder.count("stablehlo.case") >= 3
+    assert "stablehlo.case" not in grouped
+    assert "stablehlo.while" in grouped
