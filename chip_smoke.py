@@ -102,11 +102,12 @@ def phase_kernels(rows, hidden, batch, heads, seq, head_dim,
     shipped = sorted(k for k, on in P._AUTO_ON.items() if on)
     if shipped != ["causal_conv1d", "flash_attention", "gated_rms_norm",
                    "gated_short_conv", "layer_norm", "moe_grouped",
-                   "moe_scatter_add", "ssd_scan"]:
+                   "moe_scatter_add", "qk_heads", "ssd_scan"]:
         raise AssertionError(f"_AUTO_ON ships {shipped}; this phase covers "
                              f"causal_conv1d, flash_attention, "
                              f"gated_rms_norm, gated_short_conv, layer_norm, "
-                             f"moe_grouped, moe_scatter_add and ssd_scan")
+                             f"moe_grouped, moe_scatter_add, qk_heads and "
+                             f"ssd_scan")
     on_chip = pt.device.is_tpu_backend()
     if on_chip and P.interpret_mode():
         raise AssertionError("interpret mode reachable on a TPU backend")
@@ -311,6 +312,28 @@ def phase_kernels(rows, hidden, batch, heads, seq, head_dim,
         lambda *a: (_rms_norm(*a[:3], epsilon=1e-5, num_groups=heads,
                               gated=True, scaled=True) * a[3]).sum(),
         norm_args, 3, tol_bf16, 2)
+
+    # a projection's result on its way to the flash kernels: (batch, seq,
+    # heads x 128) bf16 through the head norm and the rotation at given
+    # positions (both halves of the rows at one position, as block
+    # diffusion sends them) into (batch, heads, seq, 128)
+    from paddle_tpu.ops.nn_ops import _qk_heads, _rotary_frequencies
+    from paddle_tpu.ops.pallas import qk_heads as qkh
+    if not qkh.supported((batch, seq, heads * 128), heads, (seq,)):
+        raise AssertionError("the head kernels would not take this shape")
+    head_attrs = dict(heads=heads, epsilon=1e-6, normed=True, positioned=True,
+                      freq=tuple(_rotary_frequencies(128, 1e6,
+                                                     "smoke").tolist()))
+    head_args = (
+        jnp.asarray(rng.randn(batch, seq, heads * 128), jnp.bfloat16),
+        jnp.asarray(1.0 + 0.1 * rng.randn(128), jnp.float32),
+        jnp.asarray(rng.randn(batch, heads, seq, 128), jnp.float32),
+        jnp.asarray(np.tile(np.arange(seq // 2), 2), jnp.int32))
+    run(f"qk_heads[{batch}x{seq}x{heads}x128,norm+rotary,bf16]",
+        lambda *a: (qkh.qk_heads(*a[:2], a[3], **head_attrs)
+                    .astype(jnp.float32) * a[2]).sum(),
+        lambda *a: (_qk_heads(*a[:2], a[3], **head_attrs) * a[2]).sum(),
+        head_args, 2, tol_bf16, 2)
 
     # routed experts over (rows, hidden) bf16, 4 held of 16, top-2, gated:
     # the combine through the in-place scatter-add kernel, forward and dx

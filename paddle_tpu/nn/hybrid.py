@@ -118,25 +118,23 @@ class GroupedQueryAttention(Layer):
     ``[j r, (j + 1) r)``, ``r = num_heads / num_kv_heads``. No bias. As it
     stands (``nemotron_h``) it has no position embedding and no norm of
     its own. ``qk_norm_epsilon`` adds an RMS norm over each query and each
-    key head (``q_norm`` / ``k_norm``, one ``[head_dim]`` scale each,
-    shared by the heads) and ``rope_theta`` a rotary embedding behind it
-    (halves paired, ``F.rotary_embedding(interleaved=False)``; ``forward``
+    key head (``q_norm`` / ``k_norm``, one ``[head_dim]`` scale each) and
+    ``rope_theta`` a rotary embedding behind it (halves paired; ``forward``
     takes the rows' ``positions``, None counts from 0): the ``qwen3_moe``
     / ``sdar_moe`` attention. ``diffusion_block`` puts the block-diffusion
     structure in the causal mask's place: the rows are a sequence's noisy
     copy and then its clean one (``ops.pallas.flash_attention``).
     ``window`` beside ``causal`` is a sliding window: a row sees itself
     and the ``window - 1`` positions before it (``smallthinker``'s window
-    layers; its global layers are this class with neither ``window`` nor
-    ``rope_theta``).
+    layers; its global layers have neither ``window`` nor ``rope_theta``).
 
-    The K/V heads are repeated to ``num_heads`` in front of the attention
-    op, which is the flash dispatch (``ops.pallas.flash_attention``: the
-    Pallas kernels on a TPU from ``flash_min_seq`` on, else
-    ``F.scaled_dot_product_attention``); the kernels take any ``head_dim``
-    (a size that is no multiple of 128 lanes is a block's whole last
-    dimension); a kernel that reads each K/V head once is future work
-    (PERF.md section 7)."""
+    ``_heads`` takes a projection to ``[B, heads, S, head_dim]``: queries
+    and keys of a layer with a norm or a rotation through ``F.qk_heads``
+    (one pass; a kernel pair on one TPU at whole 128-lane heads), values
+    and a layer with neither by a reshape and a transpose. K/V heads are
+    then repeated to ``num_heads`` for the flash dispatch
+    (``ops.pallas.flash_attention``; any ``head_dim``); a kernel that
+    reads each K/V head once is future work (PERF.md section 7)."""
 
     def __init__(self, hidden_size, num_heads, num_kv_heads, head_dim,
                  causal=True, qk_norm_epsilon=None, rope_theta=None,
@@ -169,14 +167,16 @@ class GroupedQueryAttention(Layer):
     def _heads(self, t, b, s, count, norm=None, rotate=False,
                positions=None):
         """[B, S, count * D] -> [B, num_heads, S, D], through a head norm
-        and the rotation (queries and keys, where the layer has them)."""
-        t = t.reshape([b, s, count, self.head_dim])
-        if norm is not None:
-            t = norm(t)
-        t = t.transpose([0, 2, 1, 3])
-        if rotate:
-            t = F.rotary_embedding(t, positions, theta=self.rope_theta,
-                                   interleaved=False)
+        and the rotation (queries and keys, where the layer has them:
+        ``F.qk_heads``)."""
+        if norm is None and not rotate:
+            t = t.reshape([b, s, count, self.head_dim]).transpose(
+                [0, 2, 1, 3])
+        else:
+            weight, epsilon = (None, 0.0) if norm is None \
+                else (norm.weight, norm._epsilon)
+            t = F.qk_heads(t, count, weight, epsilon,
+                           *((positions, self.rope_theta) if rotate else ()))
         r = self.num_heads // count
         if r == 1:
             return t

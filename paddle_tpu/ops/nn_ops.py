@@ -780,29 +780,106 @@ def rotary_embedding(x, positions=None, theta=10000.0, interleaved=True,
     same for any layout q and k share, and depends on the two positions'
     difference only. Angles and the rotation in float32 whatever ``x`` is
     (the frequencies are made in float64 on the host); the result has
-    ``x``'s dtype."""
-    d = int(x.shape[-1])
-    if d % 2:
-        raise ValueError(f"rotary_embedding: the last axis has to be even, "
-                         f"got {d}")
-    freq = np.asarray(float(theta) ** (-np.arange(0, d, 2, dtype=np.float64)
-                                       / d), np.float32)
+    ``x``'s dtype. Whole heads on their way from a projection to the
+    attention op take ``qk_heads`` below, which rotates in the same pass
+    that norms and transposes them."""
+    freq = _rotary_frequencies(x.shape[-1], theta, "rotary_embedding")
 
     def impl(x, *pos, interleaved):
-        p = pos[0].astype(jnp.float32) if pos \
-            else jnp.arange(x.shape[-2], dtype=jnp.float32)
-        angle = p[..., None] * freq
-        cos, sin = jnp.cos(angle), jnp.sin(angle)
-        xf = x.astype(jnp.float32)
-        a, b = (xf[..., 0::2], xf[..., 1::2]) if interleaved \
-            else (xf[..., :d // 2], xf[..., d // 2:])
-        return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
-                               -1).astype(x.dtype)
+        return _rotate(x, pos[0] if pos else None, freq, interleaved)
 
     args = (x,) if positions is None else (x, positions)
     with _pscope("F.rotary_embedding"):
         return apply(impl, args, dict(interleaved=bool(interleaved)),
                      name="rotary_embedding")
+
+
+def _rotary_frequencies(d, theta, op):
+    d = int(d)
+    if d % 2:
+        raise ValueError(f"{op}: the last axis has to be even, got {d}")
+    return np.asarray(float(theta) ** (-np.arange(0, d, 2, dtype=np.float64)
+                                       / d), np.float32)
+
+
+def _cos_sin(positions, s, freq):
+    """Cosine and sine ``[..., S, D / 2]`` of the rotation's angles,
+    float32; ``positions`` None counts ``s`` rows from 0."""
+    p = jnp.arange(s, dtype=jnp.float32) if positions is None \
+        else positions.astype(jnp.float32)
+    angle = p[..., None] * jnp.asarray(freq, jnp.float32)
+    return jnp.cos(angle), jnp.sin(angle)
+
+
+def _rotate(x, positions, freq, interleaved):
+    """``rotary_embedding`` on arrays: ``x`` [..., S, D], ``positions``
+    None or what broadcasts against ``x``'s leading axes and ``S``."""
+    d = x.shape[-1]
+    cos, sin = _cos_sin(positions, x.shape[-2], freq)
+    xf = x.astype(jnp.float32)
+    a, b = (xf[..., 0::2], xf[..., 1::2]) if interleaved \
+        else (xf[..., :d // 2], xf[..., d // 2:])
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
+                           -1).astype(x.dtype)
+
+
+def _qk_heads(x, *rest, heads, epsilon, freq, normed, positioned):
+    """The portable path of ``qk_heads`` (``rest``: the norm's weight where
+    ``normed``, then the positions where ``positioned``) and the kernels'
+    oracle: ``_rms_norm`` over each head, the transpose, ``_rotate``."""
+    rest = list(rest)
+    b, s, hd = x.shape
+    t = x.reshape(b, s, heads, hd // heads)
+    if normed:
+        t = _rms_norm(t, rest.pop(0), epsilon=epsilon, num_groups=1,
+                      gated=False, scaled=True)
+    t = jnp.transpose(t, (0, 2, 1, 3))
+    if freq is not None:
+        t = _rotate(t, rest.pop(0) if positioned else None, freq, False)
+    return t
+
+
+def qk_heads(x, num_heads, weight=None, epsilon=1e-6, positions=None,
+             theta=None, name=None):
+    """A projection's result ``x`` [B, S, num_heads * D] as the attention
+    op takes its heads, [B, num_heads, S, D]: an RMS norm over each head
+    where ``weight`` [D] is given (``rms_norm``'s numbers, ``epsilon``), a
+    rotary embedding where ``theta`` is (``rotary_embedding(interleaved=
+    False)``'s: pairs ``(x[j], x[j + D/2])``, ``positions`` [S] or None
+    for 0, 1, ...), the norm first. Float32 inside, rounded to ``x``'s
+    dtype after the norm and after the rotation, as the two ops round.
+
+    On one TPU, where a head is whole 128-lane tiles and the rows whole
+    row tiles, the kernel pair of ``ops/pallas/qk_heads.py``: one read and
+    one write of the array forward, the rotation a lane roll, the
+    transpose the index maps' (PERF.md section 6, PR 45). Else the
+    composition of the three ops, ``_qk_heads`` above. Counters
+    ``qk_heads.kernel_traced`` / ``qk_heads.xla_traced``."""
+    if x.ndim != 3 or x.shape[-1] % num_heads:
+        raise ValueError(f"qk_heads: x {tuple(x.shape)} is not [B, S, "
+                         f"{num_heads} heads * D]")
+    d = x.shape[-1] // num_heads
+    freq = None if theta is None else tuple(
+        _rotary_frequencies(d, theta, "qk_heads").tolist())
+    if freq is None and positions is not None:
+        raise ValueError("qk_heads: positions without theta rotate nothing")
+    from .. import monitor
+    from . import pallas
+    # read off the call, as rms_norm's gated form above
+    kernel = (pallas.enabled("qk_heads")
+              and pallas.qk_heads_mod.supported(
+                  tuple(x.shape), int(num_heads),
+                  None if positions is None else tuple(positions.shape))
+              and (weight is None or tuple(weight.shape) == (d,)))
+    monitor.counter("qk_heads.kernel_traced" if kernel
+                    else "qk_heads.xla_traced").inc()
+    args = (x,) + (() if weight is None else (weight,)) \
+        + (() if positions is None else (positions,))
+    attrs = dict(heads=int(num_heads), epsilon=float(epsilon), freq=freq,
+                 normed=weight is not None, positioned=positions is not None)
+    with _pscope("F.qk_heads"):
+        return apply(pallas.qk_heads_mod.qk_heads if kernel else _qk_heads,
+                     args, attrs, name="qk_heads")
 
 
 def interpolate(x, size=None, scale_factor=None, mode="nearest",

@@ -32,7 +32,8 @@ def on_tpu():
 _overrides = {}
 _KERNELS = ("layer_norm", "flash_attention", "softmax_xent", "batch_norm",
             "ssd_scan", "causal_conv1d", "gated_rms_norm",
-            "moe_scatter_add", "gated_short_conv", "moe_grouped")
+            "moe_scatter_add", "gated_short_conv", "moe_grouped",
+            "qk_heads")
 
 # Auto defaults from one builder-run v5e ablation (2026-07-31, superseded
 # toolchain, not reproduced — docs/performance.md carries the table):
@@ -91,11 +92,30 @@ _KERNELS = ("layer_norm", "flash_attention", "softmax_xent", "batch_norm",
 # experts' width whole 128-lane tiles - nemotron's 1,856 is not, and keeps
 # the ladder); every kernel is a module-level jax.jit, one lowering a
 # distinct shape.
+# qk_heads: on, measured on the same v5e (PERF.md section 6, PR 45).
+# F.qk_heads takes a projection's result [B, S, H D] to the flash kernels'
+# [B, H, S, D] through the head norm and the half-split rotation in one
+# pass each way. In sdar_30b_a3b_chat.block_diffusion_8k (16,384 rows x
+# 32 + 4 heads of 128, norm + rotation, five layers) qk_heads_fwd reads
+# 0.46 ms a layer's q and k (80 % of the HBM peak at one read and one write
+# of 151 MB) and qk_heads_bwd 0.73 (76 % at three passes), 8.2 ms a step
+# with the replays, where the chain of XLA fusions behind F.rms_norm,
+# transpose and F.rotary_embedding took 45.1 ms on the q side alone (its
+# slices, concatenations and pads at half a vreg's width are passes of
+# their own, in float32); the attention layers fell from 294.1 to 256.6
+# ms of a step and the step from 410.9 to 372.4. In
+# smallthinker_21b_a3b.causal_pretrain_16k (28 + 4 heads, rotation alone,
+# six window layers) 0.38 and 0.61 ms (85 % and 81 %), the window layers
+# 286.8 -> 264.4 ms, the step 616.4 -> 595.1. The op looks at the call
+# (qk_heads.supported: a head whole 128-lane tiles, rows whole row tiles,
+# positions one a row; lfm2's heads of 64 keep the composition); both
+# kernels are module-level jax.jits, one lowering a distinct shape and
+# staging.
 _AUTO_ON = {"layer_norm": True, "flash_attention": True,
             "softmax_xent": False, "batch_norm": False, "ssd_scan": True,
             "causal_conv1d": True, "gated_rms_norm": True,
             "moe_scatter_add": True, "gated_short_conv": True,
-            "moe_grouped": True}
+            "moe_grouped": True, "qk_heads": True}
 
 
 # flash is an O(S^2)-score win: below some sequence length the XLA sdpa
@@ -145,7 +165,7 @@ def configure(flash_min_seq=_UNSET, **kernels):
     auto default for named kernels ('layer_norm', 'flash_attention',
     'softmax_xent', 'batch_norm', 'ssd_scan', 'causal_conv1d',
     'gated_rms_norm', 'moe_scatter_add', 'gated_short_conv',
-    'moe_grouped'); any other
+    'moe_grouped', 'qk_heads'); any other
     name raises
     ValueError. None restores auto.
     flash_min_seq=N routes sequences shorter than N to XLA sdpa even
@@ -194,6 +214,7 @@ from . import causal_conv1d as causal_conv1d_mod
 from . import gated_rms_norm as gated_rms_norm_mod
 from . import moe_scatter_add as moe_scatter_add_mod
 from . import moe_grouped as moe_grouped_mod
+from . import qk_heads as qk_heads_mod
 
 from .layer_norm import layer_norm
 from .softmax_xent import softmax_cross_entropy

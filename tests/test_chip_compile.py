@@ -830,6 +830,49 @@ def test_gated_conv_kernels_compile_where_supported(one_chip,
     assert len(took) == 2 and 0 < max(took) <= 16 * 2 ** 20
 
 
+# the two long grouped-query cells' projections on their way to the flash
+# kernels (PR 45): 1 x 16,384 rows x 32 / 28 heads of 128 in bfloat16, sdar
+# with head norm and rotation at given positions, smallthinker with the
+# rotation alone, and a head of two lane tiles. The compiled program holds
+# the two kernels and, outside them, nothing of the array's size: no
+# transpose, no float32 copy, no half-width slice, concatenation or pad
+@pytest.mark.parametrize("s,heads,d,normed,positioned", [
+    (16384, 32, 128, True, True), (16384, 28, 128, False, False),
+    (512, 2, 256, True, False)], ids=["sdar-q", "smallthinker-q", "d256"])
+def test_qk_heads_kernels_compile_where_supported(one_chip, compiled_kernels,
+                                                  s, heads, d, normed,
+                                                  positioned):
+    from paddle_tpu.ops import nn_ops
+    from paddle_tpu.ops.pallas import qk_heads as K
+    assert K.supported((1, s, heads * d), heads, (s,) if positioned else None)
+    attrs = dict(heads=heads, epsilon=1e-6, normed=normed,
+                 positioned=positioned, freq=tuple(
+                     nn_ops._rotary_frequencies(d, 1e6, "test").tolist()))
+
+    def both(g, x, *rest):
+        y, vjp = jax.vjp(lambda *a: K.qk_heads(*a, **attrs), x, *rest)
+        return y, vjp(g)[:1 + normed]
+
+    text = _compiled_text(
+        both, one_chip, ((1, heads, s, d), jnp.bfloat16),
+        ((1, s, heads * d), jnp.bfloat16),
+        *([((d,), jnp.float32)] * normed + [((s,), jnp.int32)] * positioned),
+        names=("qk_heads_fwd", "qk_heads_bwd"))
+    assert text.count("tpu_custom_call") == 2
+    entry = text[text.index("\nENTRY "):]
+    assert not [shape for shape in _shapes(entry, "f32")
+                if shape[-2:] in ((s, heads * d), (heads, d))
+                or shape[-3:] == (heads, s, d)]
+    assert " transpose(" not in entry and " pad(" not in entry
+    assert not [shape for shape in _shapes(entry, "bf16")
+                if shape[-1] == d // 2]
+    took = [int(re.search(_SCOPED % "used_scoped_memory_configs",
+                          line).group(1))
+            for line in text.splitlines()
+            if "tpu_custom_call" in line and " custom-call(" in line]
+    assert len(took) == 2 and 0 < max(took) <= 16 * 2 ** 20
+
+
 @pytest.mark.parametrize("b,s,d,groups", [(2, 1024, 1024, 8), (1, 384, 2048, 1),
                                           (1, 128, 256, 1)],
                          ids=["2x1024x1024-G8", "384x2048-G1", "128x256-G1"])
@@ -973,6 +1016,7 @@ KERNEL_NAMES = {
     "moe_grouped.py": ["moe_hidden", "moe_gmm", "moe_hidden_bwd",
                        "moe_tgmm"],
     "moe_scatter_add.py": ["moe_scatter_add"],
+    "qk_heads.py": ["qk_heads_fwd", "qk_heads_bwd"],
     "softmax_xent.py": ["softmax_xent_fwd", "softmax_xent_bwd"],
     "ssd_scan.py": ["ssd_fwd", "ssd_bwd"],
 }
@@ -1012,7 +1056,7 @@ def test_no_pallas_call_site_is_left_out_and_no_name_is_used_twice():
     found = {f: names for f, names in found.items() if names}
     assert found == KERNEL_NAMES
     every = [n for names in found.values() for n in names]
-    assert len(every) == len(set(every)) == 27
+    assert len(every) == len(set(every)) == 29
 
 
 # a registered name switches the kernels of the file of its name; where two

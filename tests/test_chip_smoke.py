@@ -40,8 +40,10 @@ def test_phase_kernels_tiny_interpret(cs, capsys):
     # layer norm f32+bf16, flash x3, at latent attention's head sizes and
     # under the block-diffusion structure and a sliding window, the scan,
     # the convolution, the gated short convolution, the gated norm, the
-    # experts' scatter-add, the experts' grouped products
-    assert len(out) == 14
+    # projection-to-heads pair, the experts' scatter-add, the experts'
+    # grouped products
+    assert len(out) == 15
+    assert any("qk_heads[2x128x2x128,norm+rotary" in l for l in out)
     assert any("moe_grouped[64x128,4x128gated" in l for l in out)
     assert any("gated_short_conv[2x128x3x" in l for l in out)
     assert any("x64,bf16,window32" in l for l in out)
