@@ -4,11 +4,19 @@ CPU-only machines). Nothing runs; what the chip's compiler would refuse
 — a misaligned slice, too much VMEM — fails here at no chip time.
 
 The topology is described inside a module-scoped fixture and every
-compile happens in the test's own process: only one process may load
-the TPU library, so nothing here may run at import or collection time.
-Code that asks ``jax.default_backend()`` sees the CPU in this process;
-the ``compiled_kernels`` fixture steers ``pallas.interpret_mode`` to the
-TPU answer for the duration of one test.
+compile happens in the test's own process, so nothing here runs at import
+or collection time. Several processes can each describe the topology at
+once (``ALLOW_MULTIPLE_LIBTPU_LOAD=1``, as in the driver's tier-1 command),
+so nothing binds these cases to one process. They are ONE file all the
+same, and so one worker's under ``--dist loadfile``: the chip's compiler
+is multi-threaded, and files of such cases that start together take the
+cores from each other and from every test beside them (CHANGES.md, PR 46,
+has the measurement). A kernel's compile cases go here. ``topo``
+skips where no topology can be described, and a skip is a lost count:
+under the driver's command every case has to PASS. Code that asks
+``jax.default_backend()`` sees the CPU in this process; the
+``compiled_kernels`` fixture steers ``pallas.interpret_mode`` to the TPU
+answer for the duration of one test.
 """
 import os
 import re

@@ -5,7 +5,9 @@ embedding, multi-head latent attention through the flash kernels at two
 head sizes, the gated routed experts and the chip's share of them, the
 multi-token-prediction module, and the model trained through
 ``jit.to_static`` + ``amp.auto_cast`` + ``AdamW`` + ``loss.backward()``.
+The contract with the reference is tests/family_contract.py's.
 """
+import functools
 import os
 import sys
 
@@ -20,45 +22,34 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
 import paddle_tpu as pt                                         # noqa: E402
-from paddle_tpu import amp, jit, monitor, nn                    # noqa: E402
-from paddle_tpu import optimizer as opt                         # noqa: E402
+from paddle_tpu import monitor, nn                              # noqa: E402
 from paddle_tpu.models.joyai_llm_flash import (                 # noqa: E402
     JoyAIFlashConfig, JoyAIFlashForCausalLM, MultiTokenPredictor)
 from paddle_tpu.nn import functional as F                       # noqa: E402
 from paddle_tpu.ops import moe as moe_ops                       # noqa: E402
 from paddle_tpu.ops.pallas import flash_attention_mod as flash_mod  # noqa: E402,E501
 from benchmark.reference import joyai_llm_flash as R            # noqa: E402
+from family_contract import (Family, Reference,                 # noqa: E402
+                             check_expert_shares_add_up,
+                             check_matches_reference,
+                             check_trains_through_to_static, ids,
+                             plain as _plain, rel as _rel)
 
-HYPER = dict(learning_rate=1e-3, beta1=0.9, beta2=0.95, epsilon=1e-8,
-             weight_decay=0.1)
-
-
-def _plain(spec, a, b):
-    return jnp.einsum(spec, a, b)
-
-
-def _model(seed=5, **kw):
-    """(model holding the reference's seeded weights, cfg dict, weights)."""
-    config = JoyAIFlashConfig.tiny(**kw)
-    cfg = dict(vars(config))
-    model = JoyAIFlashForCausalLM(config)
-    weights = R.init_weights(cfg, seed)
-    params = dict(model.named_parameters())
-    assert set(params) == set(weights)
-    for name, p in params.items():
-        assert tuple(p.shape) == tuple(weights[name].shape), name
-        p.set_value(weights[name])
-    return model, cfg, weights
+_ids = functools.partial(ids, seq=21)
 
 
-def _ids(rows=2, seq=21, vocab=256, seed=0):
-    return np.random.default_rng(seed).integers(
-        0, vocab, (rows, seq)).astype(np.int32)
+# two logits, the main model's and the MTP module's, and a loss of both
+FAMILY = Family(
+    R, JoyAIFlashForCausalLM, JoyAIFlashConfig.tiny,
+    batch=lambda seed: (_ids(seed=seed),),
+    loss=lambda model, outputs, batch: model.loss(outputs[0], outputs[1],
+                                                  batch[0]),
+    outputs=("logits", "mtp_logits"))
 
 
-def _rel(got, ref):
-    return np.abs(np.asarray(got) - np.asarray(ref)).max() \
-        / (np.abs(np.asarray(ref)).max() + 1e-12)
+@pytest.fixture(scope="module")
+def reference():
+    return Reference(FAMILY)
 
 
 # -- the model against the reference ---------------------------------------
@@ -66,65 +57,41 @@ def _rel(got, ref):
 @pytest.mark.parametrize("recompute", [False, True],
                          ids=["plain", "recompute"])
 def test_model_matches_the_reference_on_both_logits_losses_and_every_gradient(
-        recompute):
-    model, cfg, weights = _model(recompute=recompute)
-    ids = _ids()
-    logits, mtp_logits = model(pt.to_tensor(ids))
-    want, want_mtp = R.forward(cfg, weights, jnp.asarray(ids))
-    np.testing.assert_allclose(logits.numpy(), want, atol=2e-6)
-    np.testing.assert_allclose(mtp_logits.numpy(), want_mtp, atol=2e-6)
+        reference, recompute):
+    seen = check_matches_reference(reference, recompute)
+    model, cfg, weights = seen.model, seen.cfg, seen.weights
+    batch = tuple(jnp.asarray(a) for a in seen.batch)
+    logits, _ = seen.outputs
 
-    main, mtp = R.loss_terms(cfg, weights, (jnp.asarray(ids),))
-    t = pt.to_tensor(ids)
-    only_main = model.loss(logits, None, t)
+    main, mtp = reference.once(
+        "loss_terms", lambda: R.loss_terms(cfg, weights, batch))
+    only_main = model.loss(logits, None, pt.to_tensor(seen.batch[0]))
     assert abs(float(only_main.numpy()) - float(main)) < 1e-5
-    loss = model.loss(logits, mtp_logits, t)
-    assert abs(float(loss.numpy()) - float(main + 0.3 * mtp)) < 1e-5
+    assert abs(float(seen.loss.numpy()) - float(main + 0.3 * mtp)) < 1e-5
     # the module's term by itself: what the two-term loss adds, over 0.3
-    assert abs((float(loss.numpy()) - float(only_main.numpy())) / 0.3
+    assert abs((float(seen.loss.numpy()) - float(only_main.numpy())) / 0.3
                - float(mtp)) < 1e-4
 
-    loss.backward()
-    want_grad = jax.grad(
-        lambda q: R.loss_fn(cfg, q, (jnp.asarray(ids),)))(weights)
     assert len(R.compared_leaves(cfg)) >= 40
-    for name, p in model.named_parameters():
-        assert _rel(p._grad, want_grad[name]) < 2e-5, name
     # embedding and head are the main model's: the module's pass reaches
     # them, so their gradient differs from the main term's alone
-    main_grad = jax.grad(
-        lambda q: R.loss_terms(cfg, q, (jnp.asarray(ids),))[0])(weights)
+    main_grad = reference.once("main_grad", lambda: jax.jit(jax.grad(
+        lambda q: R.loss_terms(cfg, q, batch)[0]))(weights))
     for name in ("embed_tokens.weight", "lm_head.weight"):
-        assert _rel(main_grad[name], want_grad[name]) > 1e-3, name
+        assert _rel(main_grad[name], seen.want_grad[name]) > 1e-3, name
 
 
-def test_model_trains_through_to_static_amp_and_adamw_like_the_reference():
-    model, cfg, _ = _model(recompute=True)
-    o = opt.AdamW(parameters=model.parameters(), **HYPER)
-
-    def step(ids):
-        with amp.auto_cast(dtype="bfloat16"):
-            logits, mtp_logits = model(ids)
-        loss = model.loss(logits.astype("float32"),
-                          mtp_logits.astype("float32"), ids)
-        loss.backward()
-        o.step()
-        o.clear_grad()
-        return loss
-
-    compiled = jit.to_static(step, models=[model], optimizers=[o])
-    batches = [(_ids(seed=s),) for s in range(3)]
-    got = [float(compiled(pt.to_tensor(b[0])).numpy()) for b in batches]
-    want = R.train(cfg, HYPER, 5, batches)["loss"]
+def test_model_trains_through_to_static_amp_and_adamw_like_the_reference(
+        reference):
     # bf16 products against float32: the losses agree to bf16's rounding
-    np.testing.assert_allclose(got, want, rtol=2e-3)
+    got, _ = check_trains_through_to_static(reference, rtol=2e-3)
     assert got[2] < got[0]
 
 
-def test_the_module_cannot_see_the_id_it_is_fed_last():
+def test_the_module_cannot_see_the_id_it_is_fed_last(reference):
     """The last position is fed ``id_0`` for ``id_S``; attention is causal,
     so the module's logits before it do not move with that id."""
-    model, _, _ = _model()
+    model, _, _ = reference.model()
     a = _ids()
     b = a.copy()
     b[:, 0] = (b[:, 0] + 7) % 256          # moves every main logit ...
@@ -411,26 +378,13 @@ def test_the_shares_of_a_gated_expert_layer_add_up_to_the_uncut_layer():
     cfg = dict(vars(JoyAIFlashConfig.tiny()))
     weights = _gated_weights(cfg)
     u = jax.random.normal(jax.random.key(7), (2, 13, cfg["hidden_size"]))
-    whole = dict(cfg, n_routed_experts=16, first_expert_held=0)
-    want = R._moe(whole, weights, u.reshape(-1, 64), _plain).reshape(u.shape)
-
-    t = pt.to_tensor(np.asarray(u))
-    shared = _gated_layer(cfg, weights, 0, 4).shared_experts(t).numpy()
-    total = np.zeros_like(shared)
-    routed = 0
-    for first in (0, 4, 8, 12):
-        layer = _gated_layer(cfg, weights, first, 4)
-        mine = layer(t).numpy()
-        total += mine - shared
-        # the reference given the same share agrees with the program's
-        part = dict(cfg, n_routed_experts=4, first_expert_held=first)
-        held = dict(weights, **{k: weights[k][first:first + 4] for k in (
-            "experts_gate", "experts_up", "experts_down")})
-        np.testing.assert_allclose(
-            mine, R._moe(part, held, u.reshape(-1, 64),
-                         _plain).reshape(u.shape), atol=2e-4)
-        routed += int(layer.stats.numpy()[0])
-    np.testing.assert_allclose(total + shared, want, atol=5e-4)
+    # each share against the reference given the same share, and their sum
+    routed = check_expert_shares_add_up(
+        R, weights, lambda first, n: _gated_layer(cfg, weights, first, n),
+        lambda first, n: dict(cfg, n_routed_experts=n,
+                              first_expert_held=first),
+        u, experts=16, held=4, part_atol=2e-4, sum_atol=5e-4,
+        shared=lambda layer, t: layer.shared_experts(t))
     assert routed == 2 * 13 * cfg["num_experts_per_tok"]   # every slot, once
 
 

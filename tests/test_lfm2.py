@@ -5,7 +5,9 @@ beside a grouped-query attention layer in one stack, the double-gated
 convolution as one op with an XLA route and a kernel route, a dense layer
 in front of sigmoid-routed gated experts and the chip's share of them, the
 tied head, and the model trained through ``jit.to_static`` +
-``amp.auto_cast`` + ``AdamW`` + ``loss.backward()``.
+``amp.auto_cast`` + ``AdamW`` + ``loss.backward()``. The contract with
+the reference is tests/family_contract.py's; the convolution op's two
+routes are in tests/test_gated_short_conv.py.
 """
 import json
 import os
@@ -22,47 +24,24 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
 import paddle_tpu as pt                                         # noqa: E402
-from paddle_tpu import amp, jit, monitor, nn                    # noqa: E402
-from paddle_tpu import optimizer as opt                         # noqa: E402
+from paddle_tpu import nn                                       # noqa: E402
 from paddle_tpu.models.lfm2 import (                            # noqa: E402
     Lfm2MoeConfig, Lfm2MoeForCausalLM)
-from paddle_tpu.nn import functional as F                       # noqa: E402
-from paddle_tpu.ops import pallas as P                          # noqa: E402
-from paddle_tpu.ops.pallas import causal_conv1d as CK           # noqa: E402
-from paddle_tpu.ops.ssm import _gated_conv                      # noqa: E402
 from benchmark.reference import lfm2_moe as R                   # noqa: E402
+from family_contract import (Family, Reference,                 # noqa: E402
+                             check_adamw_step, check_expert_shares_add_up,
+                             check_matches_reference,
+                             check_trains_through_to_static, ids as _ids,
+                             plain as _plain, rel as _rel, routed_share)
 
-HYPER = dict(learning_rate=1e-3, beta1=0.9, beta2=0.95, epsilon=1e-8,
-             weight_decay=0.1)
-F32, BF16 = jnp.float32, jnp.bfloat16
-
-
-def _plain(spec, a, b):
-    return jnp.einsum(spec, a, b)
-
-
-def _model(seed=5, **kw):
-    """(model holding the reference's seeded weights, cfg dict, weights)."""
-    config = Lfm2MoeConfig.tiny(**kw)
-    cfg = dict(vars(config), first_layer=0)     # layer_types is cut already
-    model = Lfm2MoeForCausalLM(config)
-    weights = R.init_weights(cfg, seed)
-    params = dict(model.named_parameters())
-    assert set(params) == set(weights)
-    for name, p in params.items():
-        assert tuple(p.shape) == tuple(weights[name].shape), name
-        p.set_value(weights[name])
-    return model, cfg, weights
+# layer_types is cut already: the reference reads it from layer 0 on
+FAMILY = Family(R, Lfm2MoeForCausalLM, Lfm2MoeConfig.tiny,
+                cfg_extra=dict(first_layer=0))
 
 
-def _ids(rows=2, seq=24, vocab=256, seed=0):
-    return np.random.default_rng(seed).integers(
-        0, vocab, (rows, seq)).astype(np.int32)
-
-
-def _rel(got, ref):
-    got, ref = (np.asarray(t, np.float32) for t in (got, ref))
-    return np.abs(got - ref).max() / (np.abs(ref).max() + 1e-12)
+@pytest.fixture(scope="module")
+def reference():
+    return Reference(FAMILY)
 
 
 # -- the model against the reference ---------------------------------------
@@ -70,82 +49,35 @@ def _rel(got, ref):
 @pytest.mark.parametrize("recompute", [False, True],
                          ids=["plain", "recompute"])
 def test_model_matches_the_reference_on_logits_loss_and_every_gradient(
-        recompute):
+        reference, recompute):
     """Five layers as the configuration cuts them: a conv layer over the
     dense feed-forward, an attention layer and three conv layers over
     experts; two sequences."""
-    model, cfg, weights = _model(recompute=recompute)
-    assert R.layer_kinds(cfg) == [
+    seen = check_matches_reference(reference, recompute)
+    assert R.layer_kinds(seen.cfg) == [
         ("conv", True), ("full_attention", False), ("conv", False),
         ("conv", False), ("conv", False)]
-    ids = _ids()
-    logits = model(pt.to_tensor(ids))
-    assert tuple(logits.shape) == (2, 24, 256)
-    want = R.forward(cfg, weights, jnp.asarray(ids))
-    np.testing.assert_allclose(logits.numpy(), want, atol=2e-6)
-    batch = (jnp.asarray(ids),)
-    loss = model.loss(logits, pt.to_tensor(ids))
-    assert abs(float(loss.numpy()) - float(R.loss_fn(cfg, weights, batch))) \
-        < 1e-5
-    loss.backward()
-    want_grad = jax.grad(lambda q: R.loss_fn(cfg, q, batch))(weights)
+    assert tuple(seen.outputs[0].shape) == (2, 24, 256)
     # embedding, 4 x 3 of the conv operators, 4 of attention, 3 of the
     # dense layer, 4 x 4 of the expert layers
-    assert len(R.compared_leaves(cfg)) == 1 + 12 + 4 + 3 + 16
-    for name, p in model.named_parameters():
-        assert _rel(p._grad, want_grad[name]) < 2e-5, name
+    assert len(R.compared_leaves(seen.cfg)) == 1 + 12 + 4 + 3 + 16
 
 
-def test_parameters_after_one_adamw_step_are_the_references():
-    """float32 through ``jit.to_static``: the loss, and every parameter's
-    change after one AdamW step, leaf by leaf."""
-    model, cfg, weights = _model(recompute=True)
-    o = opt.AdamW(parameters=model.parameters(), **HYPER)
-
-    def step(ids):
-        loss = model.loss(model(ids), ids)
-        loss.backward()
-        o.step()
-        o.clear_grad()
-        return loss
-
-    batch = (_ids(seed=3),)
-    got = float(jit.to_static(step, models=[model], optimizers=[o])(
-        pt.to_tensor(batch[0])).numpy())
-    want = R.train(cfg, HYPER, 5, [batch])
-    assert abs(got - want["loss"][0]) < 1e-5
-    for name, p in model.named_parameters():
-        moved = float(jnp.sqrt(jnp.sum(jnp.square(p.data - weights[name]))))
-        assert abs(moved - want["delta_norm"][name]) \
-            <= 1e-4 * want["delta_norm"][name] + 1e-9, name
+def test_parameters_after_one_adamw_step_are_the_references(reference):
+    check_adamw_step(reference)
 
 
-def test_model_trains_through_to_static_amp_and_adamw_like_the_reference():
-    model, cfg, _ = _model(recompute=True)
-    o = opt.AdamW(parameters=model.parameters(), **HYPER)
-
-    def step(ids):
-        with amp.auto_cast(dtype="bfloat16"):
-            logits = model(ids)
-        loss = model.loss(logits.astype("float32"), ids)
-        loss.backward()
-        o.step()
-        o.clear_grad()
-        return loss
-
-    compiled = jit.to_static(step, models=[model], optimizers=[o])
-    batches = [(_ids(seed=s),) for s in range(3)]
-    got = [float(compiled(pt.to_tensor(b[0])).numpy()) for b in batches]
-    want = R.train(cfg, HYPER, 5, batches)["loss"]
+def test_model_trains_through_to_static_amp_and_adamw_like_the_reference(
+        reference):
     # bf16 products against float32: the losses agree to bf16's rounding
-    np.testing.assert_allclose(got, want, rtol=3e-3)
+    check_trains_through_to_static(reference, rtol=3e-3)
 
 
-def test_the_tied_leafs_gradient_is_the_lookups_plus_the_heads():
+def test_the_tied_leafs_gradient_is_the_lookups_plus_the_heads(reference):
     """One leaf, read twice: its gradient is the sum of what the look-up
     alone and the head alone would give it (the other use held
     constant)."""
-    model, cfg, weights = _model()
+    model, cfg, weights = reference.model()
     assert not any("lm_head" in n for n, _ in model.named_parameters())
     ids = _ids()
     model.loss(model(pt.to_tensor(ids)), pt.to_tensor(ids)).backward()
@@ -166,14 +98,14 @@ def test_the_tied_leafs_gradient_is_the_lookups_plus_the_heads():
         return total / (2 * 23)
 
     e = weights["embed_tokens.weight"]
-    d_look_up, d_head = jax.grad(loss, (0, 1))(e, e)
+    d_look_up, d_head = jax.jit(jax.grad(loss, (0, 1)))(e, e)
     assert float(jnp.abs(d_look_up).max()) > 0 \
         and float(jnp.abs(d_head).max()) > 0
     assert _rel(got, d_look_up + d_head) < 2e-5
     assert _rel(got, d_head) > 1e-2 and _rel(got, d_look_up) > 1e-2
 
 
-def test_config_reads_the_list_from_first_layer_on_and_the_share():
+def test_config_reads_the_list_from_first_layer_on_and_the_share(reference):
     c = Lfm2MoeConfig()
     assert (c.num_hidden_layers, c.hidden_size, c.num_dense_layers) \
         == (24, 2048, 2)
@@ -197,7 +129,7 @@ def test_config_reads_the_list_from_first_layer_on_and_the_share():
         Lfm2MoeConfig(layer_types=["conv", "window"], num_hidden_layers=2)
     with pytest.raises(ValueError, match="source's forms"):
         Lfm2MoeConfig(conv_bias=True)
-    model, _, _ = _model()
+    model, _, _ = reference.model()
     kinds = [type(getattr(b, "conv", None) or b.self_attn).__name__
              for b in model.layers]
     assert kinds == ["GatedShortConv", "GroupedQueryAttention"] \
@@ -214,180 +146,7 @@ def test_config_reads_the_list_from_first_layer_on_and_the_share():
     assert moe.shared_experts is None and moe.experts_gate is not None
 
 
-# -- the gated short convolution: one op, two routes --------------------------
-
-@pytest.fixture()
-def kernels_forced():
-    P.configure(gated_short_conv=True)
-    try:
-        yield
-    finally:
-        P.configure(gated_short_conv=None)
-
-
-def _traced():
-    seen = monitor.snapshot("gated_short_conv")
-    return (int(seen.get("gated_short_conv.kernel_traced", 0)),
-            int(seen.get("gated_short_conv.xla_traced", 0)))
-
-
-def _conv_inputs(batch, seq, channels, taps, dtype, seed=0):
-    k = jax.random.split(jax.random.key(seed), 5)
-    tap = 1.0 / np.sqrt(taps)
-    b, c, u = (jax.random.normal(k[i], (batch, seq, channels)).astype(dtype)
-               for i in range(3))
-    w = jax.random.uniform(k[3], (channels, taps), F32, -tap, tap)
-    return [b, c, u, w], jax.random.normal(k[4], (batch, seq, channels))
-
-
-def _composition(b, c, u, w):
-    """What the op fuses, from the ops the repo had."""
-    return c * F.causal_conv1d(b * u, w)
-
-
-def _op(b, c, u, w):
-    from paddle_tpu.ops import manip
-    return F.gated_short_conv(manip.concat([b, c, u], axis=-1), w)
-
-
-def _through_the_tape(fn, arrays, probe):
-    leaves = [pt.Tensor(a, stop_gradient=False) for a in arrays]
-    out = fn(*leaves)
-    (out.astype("float32") * pt.Tensor(probe.astype(F32))).sum().backward()
-    return out, [t._grad for t in leaves]
-
-
-# rows: 256 is two tiles of 128 with B = 2, 384 three; 1024 two of 512;
-# channels: 384 is three lane tiles of 128, so a third starts at lane 384
-CONV_CASES = [(2, 256, 128, 3), (2, 384, 384, 3), (1, 1024, 128, 3),
-              (2, 256, 256, 4), (1, 128, 128, 2)]
-
-
-@pytest.mark.parametrize("route", ["xla", "kernels"])
-@pytest.mark.parametrize("dtype", [F32, BF16], ids=["f32", "bf16"])
-@pytest.mark.parametrize("batch,seq,channels,taps", CONV_CASES)
-def test_gated_short_conv_is_the_composition_forward_and_four_gradients(
-        request, route, dtype, batch, seq, channels, taps):
-    if route == "kernels":
-        request.getfixturevalue("kernels_forced")
-    arrays, probe = _conv_inputs(batch, seq, channels, taps, dtype)
-    assert CK.gated_supported((batch, seq, 3 * channels), taps)
-    before = _traced()
-    out, grads = _through_the_tape(_op, arrays, probe)
-    after = _traced()
-    assert (after[0] - before[0], after[1] - before[1]) \
-        == ((1, 0) if route == "kernels" else (0, 1))
-    assert out.dtype == dtype and tuple(out.shape) == (batch, seq, channels)
-    # the oracle: the composition on float32 copies of the same values
-    f32 = [a.astype(F32) for a in arrays]
-    want, want_grads = _through_the_tape(_composition, f32, probe)
-    names = ("y", "b", "c", "u", "weight")
-    got_all, want_all = [out.data] + grads, [want.data] + want_grads
-    if dtype == F32:
-        for name, g, w in zip(names, got_all, want_all):
-            assert _rel(g, w) < 2e-6, name
-        return
-    # bfloat16: one rounding on the way out (half a unit in the last of
-    # eight places, of the largest value at the most) where the composition
-    # rounds at every stage: as near the oracle as that, or as the
-    # composition at the call's own dtype is, and no further
-    port, port_grads = _through_the_tape(_composition, arrays, probe)
-    for name, g, w, p in zip(names, got_all, want_all,
-                             [port.data] + port_grads):
-        assert g.dtype == p.dtype, name
-        assert _rel(g, w) <= max(1.1 * _rel(p, w), 2.0 ** -8) + 1e-4, name
-
-
-@pytest.mark.parametrize("route", ["xla", "kernels"])
-def test_a_sequence_starts_from_zeros_whatever_stands_before_it(request,
-                                                                route):
-    """B = 2, two row tiles of 128 a sequence: row 0 of sequence 1 (and
-    every row of it) is unchanged when sequence 0 changes, forward and
-    backward; inside a sequence a row tile's first rows read the tile
-    before."""
-    if route == "kernels":
-        request.getfixturevalue("kernels_forced")
-    (b, c, u, w), probe = _conv_inputs(2, 256, 128, 3, F32, seed=7)
-    bcx = jnp.concatenate([b, c, u], -1)
-
-    def run(bcx):
-        t = pt.Tensor(bcx, stop_gradient=False)
-        y = F.gated_short_conv(t, pt.Tensor(w))
-        (y * pt.Tensor(probe)).sum().backward()
-        return np.asarray(y.data), np.asarray(t._grad)
-
-    y, g = run(bcx)
-    other = bcx.at[0].set(jax.random.normal(jax.random.key(9), bcx.shape[1:]))
-    y2, g2 = run(other)
-    np.testing.assert_array_equal(y[1], y2[1])
-    np.testing.assert_array_equal(g[1], g2[1])
-    assert np.abs(y[0] - y2[0]).max() > 0.1
-    # row 0 of a sequence sees zeros before it: y[0] = c[0] k_{K-1} (b u)[0]
-    np.testing.assert_allclose(
-        y[1, 0], np.asarray(c[1, 0] * w[:, 2] * b[1, 0] * u[1, 0]),
-        rtol=1e-6, atol=1e-7)
-    # the tile boundary at row 128: moving row 127 moves rows 127..129
-    moved = bcx.at[1, 127].add(1.0)
-    delta = np.abs(run(moved)[0][1] - y[1]).max(-1)
-    assert delta[:127].max() == 0 and delta[127:130].min() > 0 \
-        and delta[130:].max() == 0
-    # and a cotangent at row 128 reaches rows 126..128 of d(b) and d(u)
-    spike = jnp.zeros_like(probe).at[1, 128].set(1.0)
-    t = pt.Tensor(bcx, stop_gradient=False)
-    (F.gated_short_conv(t, pt.Tensor(w)) * pt.Tensor(spike)).sum().backward()
-    db = np.abs(np.asarray(t._grad)[1, :, :128]).max(-1)
-    assert db[:126].max() == 0 and db[126:129].min() > 0 \
-        and db[129:].max() == 0
-    assert np.abs(np.asarray(t._grad)[0]).max() == 0
-
-
-def test_the_kernel_route_is_two_kernels_and_no_copy_of_a_third():
-    """Forward and backward are one ``pallas_call`` each, behind
-    module-level jits; nothing of rows x channels is sliced out of ``bcx``
-    or concatenated into its gradient, and nothing is float32 at a
-    kernel's boundary but the taps' gradient."""
-    bcx = jax.ShapeDtypeStruct((2, 256, 768), BF16)
-    w = jax.ShapeDtypeStruct((256, 3), F32)
-    dy = jax.ShapeDtypeStruct((2, 256, 256), BF16)
-
-    def both(a, b, ct):
-        y, vjp = jax.vjp(CK.gated_short_conv, a, b)
-        return y, vjp(ct)
-
-    text = str(jax.make_jaxpr(both)(bcx, w, dy))
-    assert text.count("pallas_call") == 2
-    assert "name=gated_conv_fwd" in text and "name=gated_conv_bwd" in text
-    assert "concatenate" not in text and "slice" not in text.replace(
-        "dynamic_slice", "")
-    assert "f32[2,256,768]" not in text and "f32[2,256,256]" not in text
-    assert "bf16[2,256,768]" in text and "f32[2,3,256]" in text
-
-
-def test_which_calls_take_the_kernels_is_read_off_the_call(monkeypatch):
-    assert P.enabled("gated_short_conv") is False         # a CPU
-    monkeypatch.setattr(P, "interpret_mode", lambda: False)
-    assert P.enabled("gated_short_conv") is True
-    monkeypatch.undo()
-    assert CK.gated_supported((2, 8192, 6144), 3)          # the cell's
-    assert not CK.gated_supported((2, 8192, 6145), 3)
-    assert not CK.gated_supported((2, 8192, 3 * 64), 3)    # no lane tile
-    assert not CK.gated_supported((2, 100, 384), 3)        # no row tile
-    assert not CK.gated_supported((8192, 6144), 3)
-    P.configure(gated_short_conv=True)
-    try:
-        (b, c, u, w), _ = _conv_inputs(1, 100, 128, 3, F32)
-        before = _traced()
-        got = _op(*(pt.Tensor(a) for a in (b, c, u, w)))
-        assert _traced() == (before[0], before[1] + 1)      # portable path
-        np.testing.assert_allclose(
-            got.numpy(), _gated_conv(jnp.concatenate([b, c, u], -1), w),
-            atol=1e-6)
-    finally:
-        P.configure(gated_short_conv=None)
-    with pytest.raises(ValueError, match="three times"):
-        F.gated_short_conv(pt.Tensor(jnp.zeros((1, 128, 256))),
-                           pt.Tensor(jnp.zeros((128, 3))))
-
+# -- the gated short convolution as a layer --------------------------------
 
 def test_the_layer_is_the_references_operator():
     layer = nn.GatedShortConv(64, taps=3)
@@ -413,8 +172,8 @@ def test_the_layer_is_the_references_operator():
 
 # -- attention at the configuration's form, the router, the share -----------
 
-def test_the_attention_layer_is_the_references(monkeypatch):
-    model, cfg, weights = _model()
+def test_the_attention_layer_is_the_references(reference):
+    model, cfg, weights = reference.model()
     layer = model.layers[1].self_attn
     w = R._under(weights, "layers.1.self_attn.")
     x = np.asarray(jax.random.normal(jax.random.key(13), (1, 40, 64)))
@@ -455,32 +214,18 @@ def test_the_shares_of_the_experts_add_up_to_the_uncut_layer():
     for i, (_, p) in enumerate(whole.named_parameters()):
         p.set_value(0.2 * jax.random.normal(jax.random.fold_in(key, i + 1),
                                             tuple(p.shape)))
-    mt = pt.to_tensor(np.asarray(m))
-    want = whole(mt).numpy()
     w = {k: p.data for k, p in whole.named_parameters()}
-    cfg = dict(num_experts=32, num_experts_published=32,
-               num_experts_per_tok=4, routed_scaling_factor=1.0)
-    flat = m.reshape(48, 64)
-    ref_whole = R._moe(cfg, w, flat, _plain)
-    np.testing.assert_allclose(want.reshape(48, 64), ref_whole, atol=2e-6)
-    total, ref_total = 0.0, 0.0
-    for first in range(0, 32, 8):
-        share = nn.RoutedMoE(64, 32, 32, 4,
-                             experts_held=range(first, first + 8), **kind)
-        share.router.weight.set_value(w["router.weight"])
-        held = {k: w[k][first:first + 8]
-                for k in ("experts_gate", "experts_up", "experts_down")}
-        for k, v in held.items():
-            getattr(share, k).set_value(v)
-        part = share(mt).numpy()
-        assert np.abs(part).max() > 0
-        total = total + part
-        ref_total = ref_total + R._moe(
-            dict(cfg, num_experts=8, first_expert_held=first),
-            dict(held, **{"router.weight": w["router.weight"]}), flat,
-            _plain)
-    np.testing.assert_allclose(total, want, atol=3e-6)
-    np.testing.assert_allclose(ref_total, ref_whole, atol=3e-6)
+
+    def layer(first, n):
+        return whole if n == 32 else routed_share(
+            lambda held: nn.RoutedMoE(64, 32, 32, 4, experts_held=held,
+                                      **kind), w, first, n)
+
+    check_expert_shares_add_up(
+        R, w, layer, lambda first, n: dict(
+            num_experts=n, num_experts_published=32, num_experts_per_tok=4,
+            routed_scaling_factor=1.0, first_expert_held=first),
+        m, experts=32, held=8)
 
 
 # -- the configuration file against the catalog's row ------------------------
@@ -538,7 +283,7 @@ def test_the_configuration_files_widths_are_the_catalog_rows():
 
 # -- the experts' ladder is the op's own, as in every other cell -------------
 
-def test_the_expert_layers_pad_on_the_ops_own_ladder(monkeypatch):
+def test_the_expert_layers_pad_on_the_ops_own_ladder(reference, monkeypatch):
     """Nothing of the model or its configuration moves a rung of
     ``F.moe_experts``' ladder of capacities: the cell's even share, 2 x
     8,192 x 4 / 32 = 2,048 rows an expert, IS one of its rungs (an expert
@@ -557,7 +302,7 @@ def test_the_expert_layers_pad_on_the_ops_own_ladder(monkeypatch):
     assert share in moe_ops._ladder(tokens, moe_ops.MIN_ROWS)
 
     monkeypatch.setattr(moe_ops, "MIN_ROWS", 4)
-    model, _, _ = _model()
+    model, _, _ = reference.model()
     ids = _ids(rows=2, seq=24)
     model(pt.to_tensor(ids))
     rungs = moe_ops._ladder(ids.size, 4)

@@ -7,6 +7,7 @@ that say which path a call site traced; and the guard on the set-up: the
 kernel is lowered once a module for a rung, however many layers and call
 sites the step has.
 """
+import functools
 import os
 import re
 import sys
@@ -162,6 +163,18 @@ def _experts_inputs(gated, dtype, crowded, tokens=80, d=128, f=32, held=4,
     return (x, experts.astype(jnp.int32), weights, *ws)
 
 
+@functools.lru_cache(maxsize=None)
+def _routed_step(gated, dtype, kernel):
+    """Loss, ``(y, stats)`` and every gradient of ``_routed`` as one jitted
+    program, traced at its first call (under the caller's ``MIN_ROWS``)."""
+    def loss(x, e, w, *ws):
+        y, stats = moe_ops._routed(x, e, w, *ws, first=0, dot_dtype=dtype,
+                                   kernel=kernel)
+        return jnp.sum(jnp.square(y.astype(jnp.float32))), (y, stats)
+    return jax.jit(jax.value_and_grad(
+        loss, argnums=(0,) + tuple(range(2, 5 + gated)), has_aux=True))
+
+
 @pytest.mark.parametrize("crowded", [False, True], ids=["thin", "crowded"])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["f32", "bf16"])
@@ -174,12 +187,9 @@ def test_experts_through_the_kernel_equal_the_indexed_add(monkeypatch, gated,
     a = _experts_inputs(gated, dtype, crowded)
 
     def run(kernel):
-        def loss(x, e, w, *ws):
-            y, stats = moe_ops._routed(x, e, w, *ws, first=0,
-                                       dot_dtype=dtype, kernel=kernel)
-            return jnp.sum(jnp.square(y.astype(jnp.float32))), (y, stats)
-        return jax.jit(jax.value_and_grad(
-            loss, argnums=(0,) + tuple(range(2, len(a))), has_aux=True))(*a)
+        # thin and crowded differ in what the router chose, not in the
+        # program: the two of a (gated, dtype) share one compiled step
+        return _routed_step(gated, dtype, kernel)(*a)
 
     ((_, (y, stats)), grads), ((_, (y0, stats0)), grads0) = run(True), \
         run(False)

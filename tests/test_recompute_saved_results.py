@@ -185,8 +185,9 @@ PARENT_STABLEHLO_SEQ512 = "3a18b0f395c710b4"
 def _program_text(text):
     """The lowered text without what moves with a source line or with the
     number of equations traced: the kernels' serialized bodies (they carry
-    the line numbers of flash_attention.py; digests in test_sdar_moe.py hold
-    their jaxprs) and the counter behind a private function's name."""
+    the line numbers of flash_attention.py; digests in
+    test_flash_block_diffusion.py hold their jaxprs) and the counter behind
+    a private function's name."""
     text = re.sub(r'\\22body\\22: \\22[^\\]*\\22', "", text)
     return re.sub(r"@(\w+?)_\d+\b", r"@\1", text)
 

@@ -4,7 +4,7 @@ checkpointing and auto-resume — every fault class driven end-to-end."""
 import os
 import pickle
 import signal
-import time
+import threading
 
 import numpy as np
 import pytest
@@ -461,13 +461,20 @@ def test_executor_train_from_dataset_resumes(tmp_path):
 # -- watchdog ---------------------------------------------------------------
 
 def test_watchdog_flags_slow_step(jsonl):
-    wd = Watchdog(min_deadline=0.05, poll=0.01).start()
+    """Counts, not clocks: the fast step ends at once under a deadline no
+    scheduler's hiccup reaches; the hung one waits (bounded) until the
+    watcher has flagged it and written its dump."""
+    flagged = threading.Event()
+    wd = Watchdog(min_deadline=60.0, poll=0.01,
+                  on_stall=lambda *a: flagged.set()).start()
     try:
         with wd.step(0):
-            time.sleep(0.02)  # fast: no stall
+            pass  # fast: no stall
         assert wd.stall_count == 0
+        wd.min_deadline = 0.05
         with wd.step(1):
-            time.sleep(0.2)  # hung
+            assert flagged.wait(60.0)  # hung until flagged
+            assert wd.stall_count == 1
     finally:
         wd.stop()
     assert wd.stall_count == 1

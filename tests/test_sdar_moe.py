@@ -5,6 +5,8 @@ flash kernels that take it as structure, grouped-query attention with head
 norms and rotary positions, the soft-max router and the chip's share of the
 experts, the masked-position loss, and the model trained through
 ``jit.to_static`` + ``amp.auto_cast`` + ``AdamW`` + ``loss.backward()``.
+The contract with the reference is tests/family_contract.py's; the mask and
+the flash kernels under it are in tests/test_flash_block_diffusion.py.
 """
 import hashlib
 import os
@@ -21,36 +23,17 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
 import paddle_tpu as pt                                         # noqa: E402
-from paddle_tpu import amp, jit, monitor, nn, ops               # noqa: E402
-from paddle_tpu import optimizer as opt                         # noqa: E402
+from paddle_tpu import monitor, nn, ops                         # noqa: E402
 from paddle_tpu.models.sdar_moe import (                        # noqa: E402
     SDARMoEConfig, SDARMoEForBlockDiffusion)
 from paddle_tpu.nn import functional as F                       # noqa: E402
 from paddle_tpu.ops import moe as moe_ops                       # noqa: E402
-from paddle_tpu.ops.pallas import flash_attention               # noqa: E402
-from paddle_tpu.ops.pallas import flash_attention_mod as flash_mod  # noqa: E402,E501
 from benchmark.reference import sdar_moe as R                   # noqa: E402
-
-HYPER = dict(learning_rate=1e-3, beta1=0.9, beta2=0.95, epsilon=1e-8,
-             weight_decay=0.1)
-
-
-def _plain(spec, a, b):
-    return jnp.einsum(spec, a, b)
-
-
-def _model(seed=5, **kw):
-    """(model holding the reference's seeded weights, cfg dict, weights)."""
-    config = SDARMoEConfig.tiny(**kw)
-    cfg = dict(vars(config))
-    model = SDARMoEForBlockDiffusion(config)
-    weights = R.init_weights(cfg, seed)
-    params = dict(model.named_parameters())
-    assert set(params) == set(weights)
-    for name, p in params.items():
-        assert tuple(p.shape) == tuple(weights[name].shape), name
-        p.set_value(weights[name])
-    return model, cfg, weights
+from family_contract import (Family, Reference,                 # noqa: E402
+                             check_expert_shares_add_up,
+                             check_matches_reference,
+                             check_trains_through_to_static,
+                             plain as _plain, routed_share)
 
 
 def _batch(rows=2, seq=24, block=4, vocab=256, seed=0):
@@ -64,9 +47,19 @@ def _batch(rows=2, seq=24, block=4, vocab=256, seed=0):
             np.where(masked, 1.0 / t, 0.0).astype(np.float32))
 
 
-def _rel(got, ref):
-    return np.abs(np.asarray(got) - np.asarray(ref)).max() \
-        / (np.abs(np.asarray(ref)).max() + 1e-12)
+# the forward reads the noisy copy, then the clean one; the loss the clean
+# ids and the weights
+FAMILY = Family(
+    R, SDARMoEForBlockDiffusion, SDARMoEConfig.tiny,
+    batch=lambda seed: _batch(seed=seed),
+    inputs=lambda batch: (batch[1], batch[0]),
+    loss=lambda model, outputs, batch: model.loss(outputs[0], batch[0],
+                                                  batch[2]))
+
+
+@pytest.fixture(scope="module")
+def reference():
+    return Reference(FAMILY)
 
 
 # -- the model against the reference ---------------------------------------
@@ -74,46 +67,18 @@ def _rel(got, ref):
 @pytest.mark.parametrize("recompute", [False, True],
                          ids=["plain", "recompute"])
 def test_model_matches_the_reference_on_logits_loss_and_every_gradient(
-        recompute):
-    model, cfg, weights = _model(recompute=recompute)
-    clean, noisy, w = _batch()
-    logits = model(pt.to_tensor(noisy), pt.to_tensor(clean))
-    assert tuple(logits.shape) == (2, 24, 256)   # the noisy copy's rows
-    want = R.forward(cfg, weights, jnp.asarray(noisy), jnp.asarray(clean))
-    np.testing.assert_allclose(logits.numpy(), want, atol=2e-6)
-    batch = tuple(jnp.asarray(a) for a in (clean, noisy, w))
-    loss = model.loss(logits, pt.to_tensor(clean), pt.to_tensor(w))
-    assert abs(float(loss.numpy()) - float(R.loss_fn(cfg, weights, batch))) \
-        < 1e-5
-    loss.backward()
-    want_grad = jax.grad(lambda q: R.loss_fn(cfg, q, batch))(weights)
-    assert len(R.compared_leaves(cfg)) == 2 + 2 * 8
-    for name, p in model.named_parameters():
-        assert _rel(p._grad, want_grad[name]) < 2e-5, name
+        reference, recompute):
+    seen = check_matches_reference(reference, recompute)
+    # the noisy copy's rows
+    assert tuple(seen.outputs[0].shape) == (2, 24, 256)
+    assert len(R.compared_leaves(seen.cfg)) == 2 + 2 * 8
 
 
-def test_model_trains_through_to_static_amp_and_adamw_like_the_reference():
-    model, cfg, _ = _model(recompute=True)
-    monitor.device_counters.reset()
-    model = _model(recompute=True)[0]       # registers after the reset
-    o = opt.AdamW(parameters=model.parameters(), **HYPER)
-
-    def step(clean, noisy, w):
-        with amp.auto_cast(dtype="bfloat16"):
-            logits = model(noisy, clean)
-        loss = model.loss(logits.astype("float32"), clean, w)
-        loss.backward()
-        o.step()
-        o.clear_grad()
-        return loss
-
-    compiled = jit.to_static(step, models=[model], optimizers=[o])
-    batches = [_batch(seed=s) for s in range(3)]
-    got = [float(compiled(*(pt.to_tensor(a) for a in b)).numpy())
-           for b in batches]
-    want = R.train(cfg, HYPER, 5, batches)["loss"]
+def test_model_trains_through_to_static_amp_and_adamw_like_the_reference(
+        reference):
+    monitor.device_counters.reset()     # the model registers after it
     # bf16 products against float32: the losses agree to bf16's rounding
-    np.testing.assert_allclose(got, want, rtol=3e-3)
+    _, batches = check_trains_through_to_static(reference, rtol=3e-3)
     # the step counted its own masked positions, on the device
     seen = monitor.device_counters.read("diffusion.")
     assert seen == {"diffusion.masked_rows":
@@ -121,11 +86,12 @@ def test_model_trains_through_to_static_amp_and_adamw_like_the_reference():
                     "diffusion.steps": 3}
 
 
-def test_the_clean_copy_gives_keys_and_no_logits_and_no_row_sees_ahead():
+def test_the_clean_copy_gives_keys_and_no_logits_and_no_row_sees_ahead(
+        reference):
     """A clean token of a LATER block moves no logit of an earlier block;
     a clean token of an earlier block moves the later blocks' logits; a
     noisy token moves its own block's logits alone."""
-    model, _, _ = _model()
+    model, _, _ = reference.model()
     clean, noisy, _ = _batch(rows=1)
 
     def logits(noisy, clean):
@@ -167,204 +133,6 @@ def test_config_checks_the_share_the_block_and_the_names():
     assert not any("shared" in n or "bias" in n for n in names)
     assert tuple(names["layers.0.self_attn.q_norm.weight"].shape) == (16,)
     assert pt.models.SDARMoEConfig is SDARMoEConfig
-
-
-# -- the mask ----------------------------------------------------------------
-
-def test_the_mask_is_the_written_rule_on_every_pair():
-    """L = 16, B = 4: every (r, s) of the 32 x 32 pairs against the rule
-    as ISSUE 33 writes it, and the reference's own."""
-    length, block = 16, 4
-    got = flash_mod.block_diffusion_mask(length, block)
-    assert got.shape == (32, 32) and got.dtype == np.bool_
-    for r in range(32):
-        for s in range(32):
-            c_r, c_s = r // length, s // length
-            b_r, b_s = (r % length) // block, (s % length) // block
-            want = (c_s == 1 and b_s < b_r) or (c_s == c_r and b_s == b_r)
-            assert got[r, s] == want, (r, s)
-    at = jnp.arange(32)
-    np.testing.assert_array_equal(got, R.allowed(at, at, length, block))
-    # a clean row: block-causal over the clean copy, nothing of the noisy
-    assert got[16 + 5].tolist() == [False] * 16 + [True] * 8 + [False] * 8
-    # a noisy row: the clean blocks before its own, its own noisy block
-    assert got[5].tolist() == [False] * 4 + [True] * 4 + [False] * 8 \
-        + [True] * 4 + [False] * 12
-    assert int(got.sum()) == length * length + length * block
-
-
-# -- the kernels under the structure ----------------------------------------
-
-def _dense(q, k, v, length, block):
-    mask = jnp.asarray(flash_mod.block_diffusion_mask(length, block))
-    s = jnp.einsum("bhqd,bhkd->bhqk", q, k, precision="highest") \
-        / np.sqrt(q.shape[-1])
-    p = jax.nn.softmax(jnp.where(mask, s, -jnp.inf), -1)
-    return jnp.einsum("bhqk,bhkd->bhqd", p, v, precision="highest")
-
-
-KERNEL_CASES = [      # (L, B, block_q, block_k)
-    (32, 4, 16, 16),       # L a multiple of the tile
-    (40, 4, 16, 16),       # ... and not: each copy is padded to 48
-    (64, 32, 32, 32),      # a diffusion block is a tile
-    (96, 32, 32, 64),      # block_q < block_k, padded to 128
-    (48, 4, 16, 8),        # block_q > block_k
-    (48, 4, 8, 16),
-    (64, 4, 512, 1024),    # the defaults: one tile a copy
-]
-
-
-@pytest.mark.parametrize("length,block,block_q,block_k", KERNEL_CASES)
-def test_kernels_under_the_structure_match_dense_masked_attention(
-        length, block, block_q, block_k):
-    """Interpret mode, float32: forward and all three gradients, q/k 24
-    wide and v 16."""
-    key = jax.random.key(length * 7 + block)
-    q, k, v, ct = (jax.random.normal(jax.random.fold_in(key, i),
-                                     (1, 2, 2 * length, d))
-                   for i, d in enumerate((24, 24, 16, 16)))
-    shift = block.bit_length() - 1
-
-    def kernels(q, k, v):
-        return flash_mod._flash_bd(q, k, v, shift, None, block_q, block_k)
-
-    np.testing.assert_allclose(kernels(q, k, v),
-                               _dense(q, k, v, length, block), atol=2e-6)
-    got = jax.grad(lambda *a: jnp.sum(kernels(*a) * ct), (0, 1, 2))(q, k, v)
-    want = jax.grad(lambda *a: jnp.sum(_dense(*a, length, block) * ct),
-                    (0, 1, 2))(q, k, v)
-    for name, a, b in zip("qkv", got, want):
-        np.testing.assert_allclose(a, b, atol=5e-6, err_msg=f"d{name}")
-
-
-@pytest.mark.parametrize("length,block,block_q,block_k", KERNEL_CASES)
-def test_tile_counts_are_a_brute_force_count_of_the_tiles_that_hold_a_pair(
-        length, block, block_q, block_k):
-    shift = block.bit_length() - 1
-    bq, bk = flash_mod._bd_blocks(block_q, block_k, length, shift)
-    lp = flash_mod._bd_padded(length, bq, bk)
-    tiles, masked, whole = flash_mod._bd_tile_counts(
-        3, length, block_q=bq, block_k=bk, shift=shift)
-    # the layout the kernels walk: each copy padded to whole tiles
-    dense = flash_mod.block_diffusion_mask(lp, block)
-    by_tile = dense.reshape(2 * lp // bq, bq, 2 * lp // bk, bk)
-    holds = by_tile.any((1, 3))
-    assert whole == 3 * holds.size
-    assert tiles == 3 * int(holds.sum())
-    # over the clean copy's keys a tile that holds a pair and is not all
-    # pairs runs the masked body; a noisy block's own tiles always do
-    clean_keys = by_tile[:, :, lp // bk:]
-    crossed = int((clean_keys.any((1, 3)) & ~clean_keys.all((1, 3))).sum())
-    own = (lp // bq) * max(1, bq // bk)
-    assert masked == 3 * (crossed + own)
-
-
-def test_tile_counts_at_the_cells_shape_are_the_issues():
-    """32 heads x 2 x 8,192 rows at 512 x 512: n (n + 1) + n of 4 n^2 tiles
-    a head, 3 n of them masked, n = 16."""
-    bq, bk = flash_mod._blocks_that_fit(8192, 128, 128, 2, 512, 1024)
-    assert flash_mod._bd_blocks(bq, bk, 8192, 2) == (512, 512)
-    assert not flash_mod._single_buffered(8192, 128, 128, 2)
-    tiles, masked, whole = flash_mod._bd_tile_counts(
-        32, 8192, block_q=512, block_k=512, shift=2)
-    assert (tiles, masked, whole) == (32 * 288, 32 * 48, 32 * 1024)
-    assert abs(100 * tiles / whole - 28.125) < 1e-9
-
-
-def test_the_dispatch_counts_the_path_and_the_tiles_and_refuses_a_mix():
-    q = pt.to_tensor(np.asarray(jax.random.normal(jax.random.key(3),
-                                                  (1, 2, 64, 16))))
-    before = monitor.snapshot("flash_attention")
-    got = flash_attention(q, q, q, diffusion_block=4, force=True,
-                          block_q=16, block_k=16)
-    plain = flash_attention(q, q, q, diffusion_block=4)      # sdpa, dense
-    np.testing.assert_allclose(got.numpy(), plain.numpy(), atol=2e-6)
-    after = monitor.snapshot("flash_attention")
-
-    def gained(name):
-        return after.get("flash_attention." + name, 0) \
-            - before.get("flash_attention." + name, 0)
-
-    assert gained("kernel_traced") == 1 and gained("xla_traced") == 1
-    # two heads, two blocks of 16 a copy: 2 x 3 + 2 of 16 tiles a head
-    assert gained("tiles") == 2 * 8 and gained("tiles_masked") == 2 * 6
-    assert gained("tiles_skipped") == 2 * 8
-    for kw in (dict(causal=True), dict(attn_mask=q), dict(diffusion_block=3),
-               dict(diffusion_block=64)):
-        with pytest.raises(ValueError, match="diffusion_block"):
-            flash_attention(q, q, q, **{"diffusion_block": 4, **kw})
-    # a causal call counts what it leaves out too
-    before = after
-    flash_attention(q, q, q, causal=True, force=True, block_q=16, block_k=16)
-    after = monitor.snapshot("flash_attention")
-    assert gained("tiles") == 2 * 10 and gained("tiles_skipped") == 2 * 6
-
-
-# the three call forms the benchmark's other cells trace, lowered here as
-# value-and-gradients of the kernels' custom_vjp: sha256 of the jaxpr's
-# text, recomputed at PR 40, whose one backward kernel is meant to reach
-# all of them (ea9585c's were 552400deb2309687, 1ce92ea17d73c217 and
-# 0d43c90354e8ae13). A change to the kernels that is meant to reach those
-# cells recomputes them; the block structure is not. Retaken at PR 42,
-# which names the vjp-forward's three results: e256fce's texts
-# (0bef64586abe9150, 398076cef3b873b6, 8d25934ee86c1597) with three ``name``
-# equations more and the later variables' letters moved by them, nothing
-# else (compared line by line with the letters taken out).
-PARENT_JAXPRS = {
-    "seq512": "a2968dc5da9e054d",
-    "nemotron": "edb2f8e1d4cb1eae",
-    "joyai": "60540556dedf1c9b",
-}
-
-
-def _call_forms():
-    S, bf = jax.ShapeDtypeStruct, jnp.bfloat16
-    return {
-        "seq512": ((S((16, 12, 512, 64), bf),) * 3
-                   + (S((16, 1, 1, 512), jnp.float32),), False),
-        "nemotron": ((S((1, 32, 8192, 128), bf),) * 3, True),
-        "joyai": ((S((1, 32, 8192, 192), bf),) * 2
-                  + (S((1, 32, 8192, 128), bf),), True),
-    }
-
-
-@pytest.mark.parametrize("form", sorted(PARENT_JAXPRS))
-def test_a_call_without_the_structure_traces_the_parents_kernels(form):
-    args, causal = _call_forms()[form]
-
-    def value_and_grads(q, k, v, *mask):
-        bq, bk = flash_mod._blocks_that_fit(q.shape[2], q.shape[3],
-                                            v.shape[3], 2, 512, 1024)
-        mode = flash_mod._mask_mode(mask[0].shape if mask else None,
-                                    *q.shape[:3], k.shape[2])
-        m = flash_mod._canon_mask(mask[0]) if mask else None
-
-        def loss(q, k, v):
-            return jnp.sum(flash_mod._flash(
-                q, k, v, m, mode, jnp.zeros((2,), jnp.int32), causal, None,
-                bq, bk, 0.0).astype(jnp.float32))
-        return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
-
-    text = str(jax.make_jaxpr(value_and_grads)(*args))
-    assert text.count("pallas_call") == 2
-    assert text.count("name[name=flash_") == 3
-    assert hashlib.sha256(text.encode()).hexdigest()[:16] == \
-        PARENT_JAXPRS[form]
-
-
-def test_the_structured_call_holds_no_array_of_both_copies_squared():
-    """Nothing of 2L x 2L, and no mask operand: every array of the traced
-    program has at most one axis of 2 L (or L) rows."""
-    import re
-    S = jax.ShapeDtypeStruct((1, 2, 2048, 16), jnp.bfloat16)
-    text = str(jax.make_jaxpr(jax.grad(
-        lambda q, k, v: jnp.sum(flash_mod._flash_bd(
-            q, k, v, 2, None, 256, 256).astype(jnp.float32)),
-        argnums=(0, 1, 2)))(S, S, S))
-    assert text.count("pallas_call") == 2
-    for shape in re.findall(r"\w+\[([\d,]+)\]", text):
-        dims = [int(d) for d in shape.split(",")]
-        assert sum(d >= 1024 for d in dims) <= 1, shape
 
 
 # -- grouped-query attention with head norms and positions -------------------
@@ -475,28 +243,19 @@ def test_the_shares_of_the_experts_add_up_to_the_uncut_layer():
     for i, (_, p) in enumerate(whole.named_parameters()):
         p.set_value(0.2 * jax.random.normal(jax.random.fold_in(key, i + 1),
                                             tuple(p.shape)))
-    want = whole(pt.to_tensor(np.asarray(u))).numpy()
     w = {k: p.data for k, p in whole.named_parameters()}
-    cfg = dict(num_experts=16, num_experts_published=16,
-               num_experts_per_tok=3)
-    ref_whole = R._moe(cfg, w, u.reshape(48, 64), _plain)
-    np.testing.assert_allclose(want.reshape(48, 64), ref_whole, atol=2e-6)
-    total, ref_total = 0.0, 0.0
-    for first in range(0, 16, 4):
-        share = nn.RoutedMoE(64, 32, 16, 3, gated=True, scoring="softmax",
-                             experts_held=range(first, first + 4))
-        share.router.weight.set_value(w["router.weight"])
-        held = {k: w[k][first:first + 4]
-                for k in ("experts_gate", "experts_up", "experts_down")}
-        for k, v in held.items():
-            getattr(share, k).set_value(v)
-        total = total + share(pt.to_tensor(np.asarray(u))).numpy()
-        ref_total = ref_total + R._moe(
-            dict(cfg, num_experts=4, first_expert_held=first),
-            dict(held, **{"router.weight": w["router.weight"]}),
-            u.reshape(48, 64), _plain)
-    np.testing.assert_allclose(total, want, atol=3e-6)
-    np.testing.assert_allclose(ref_total, ref_whole, atol=3e-6)
+
+    def layer(first, n):
+        return whole if n == 16 else routed_share(
+            lambda held: nn.RoutedMoE(64, 32, 16, 3, gated=True,
+                                      scoring="softmax", experts_held=held),
+            w, first, n)
+
+    check_expert_shares_add_up(
+        R, w, layer, lambda first, n: dict(
+            num_experts=n, num_experts_published=16, num_experts_per_tok=3,
+            first_expert_held=first),
+        u, experts=16, held=4)
 
 
 # -- the loss -----------------------------------------------------------------

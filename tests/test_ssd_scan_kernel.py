@@ -96,7 +96,7 @@ def test_kernels_equal_the_recurrence_forward_and_all_seven_gradients(
 
     want = _step_by_step(*(t[k] for k in ORDER))
     # float32 throughout, or bfloat16 operands of products that accumulate
-    # in float32 (the portable path's own distance, tests/test_nemotron_h)
+    # in float32 (the portable path's own distance, tests/test_ssm_ops)
     tol = 0.02 if autocast else 2e-5
     assert np.abs(y.numpy() - want).max() < tol * np.abs(want).max()
 
@@ -159,7 +159,7 @@ def test_decays_sums_and_accumulation_stay_float32_under_bf16_products():
     """Forward and backward with bfloat16 products: every ``exp``, every
     sum and every product's result is float32; bfloat16 appears only as a
     product's operand. (``test_scan_keeps_decays_in_float32_under_autocast``
-    of tests/test_nemotron_h.py, for the kernels.)"""
+    of tests/test_ssm_ops.py, for the kernels.)"""
     t = _inputs(1, 2 * CHUNK, 1, 8, 64)
     args = [t[k] for k in ORDER]
     f = lambda *a: K.ssd_scan(*a, chunk=CHUNK,            # noqa: E731
