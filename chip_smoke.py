@@ -100,14 +100,15 @@ def phase_kernels(rows, hidden, batch, heads, seq, head_dim,
     from paddle_tpu.ops.pallas.layer_norm import layer_norm as pallas_ln
 
     shipped = sorted(k for k, on in P._AUTO_ON.items() if on)
-    if shipped != ["causal_conv1d", "flash_attention", "gated_rms_norm",
-                   "gated_short_conv", "layer_norm", "moe_grouped",
-                   "moe_scatter_add", "qk_heads", "ssd_scan"]:
+    if shipped != ["causal_conv1d", "dsa_kl", "dsa_select",
+                   "flash_attention", "gated_rms_norm", "gated_short_conv",
+                   "layer_norm", "moe_grouped", "moe_scatter_add",
+                   "qk_heads", "ssd_scan"]:
         raise AssertionError(f"_AUTO_ON ships {shipped}; this phase covers "
-                             f"causal_conv1d, flash_attention, "
-                             f"gated_rms_norm, gated_short_conv, layer_norm, "
-                             f"moe_grouped, moe_scatter_add, qk_heads and "
-                             f"ssd_scan")
+                             f"causal_conv1d, dsa_kl, dsa_select, "
+                             f"flash_attention, gated_rms_norm, "
+                             f"gated_short_conv, layer_norm, moe_grouped, "
+                             f"moe_scatter_add, qk_heads and ssd_scan")
     on_chip = pt.device.is_tpu_backend()
     if on_chip and P.interpret_mode():
         raise AssertionError("interpret mode reachable on a TPU backend")
@@ -383,6 +384,52 @@ def phase_kernels(rows, hidden, batch, heads, seq, head_dim,
 
     run(f"moe_grouped[{rows}x{hidden},{held}x{wide}gated,bf16]",
         grouped, experts(False, jnp.float32), grouped_args, 5, tol_bf16, 9)
+
+    # a learned sparse attention over (2, heads, seq, 128) bf16 with an
+    # indexer of 4 heads of 64 that keeps a quarter of the keys a row: the
+    # selection kernel against its definition (a sort), then attention
+    # under that ONE selection and the indexer's loss, the three kernels
+    # against the definition routes
+    from paddle_tpu.ops import sparse_attention as sa
+    from paddle_tpu.ops.pallas import dsa
+    from paddle_tpu.ops.pallas.flash_attention import _flash_sel
+    hi, di, top_k = 4, 64, seq // 4
+    tiles = dict(rows=dsa.SELECT_ROWS, chunk=dsa.SELECT_CHUNK) \
+        if on_chip else dict(rows=32, chunk=128)
+    if not (dsa.select_supported((2, hi, seq, di), **tiles)
+            and dsa.kl_supported((2, heads, seq, 128), (2, hi, seq, di),
+                                 min(dsa.KL_BLOCK, seq))):
+        raise AssertionError("the indexer's kernels would not take this "
+                             "shape")
+    index_args = (jnp.asarray(rng.randn(2, hi, seq, di), jnp.bfloat16),
+                  jnp.asarray(rng.randn(2, seq, di), jnp.bfloat16),
+                  jnp.asarray(rng.randn(2, seq, hi) / 16.0, jnp.float32))
+    selected, lse, _, pairs = jax.jit(
+        lambda *a: dsa.select(*a, top_k=top_k, **tiles))(*index_args)
+    want = jax.jit(lambda *a: sa._select(*a, top_k=top_k))(*index_args)
+    apart = int(jnp.sum(selected != want[0]))
+    say("kernels", kernel=f"dsa_select[2x{seq},{hi}x{di},top{top_k}]",
+        selected_pairs=int(jnp.sum(pairs)), differ_from_a_sort=apart)
+    if apart > 2e-3 * int(jnp.sum(pairs)):
+        raise AssertionError(f"dsa_select: {apart} pairs apart from the "
+                             f"definition's selection")
+    sparse_args = tuple(
+        jnp.asarray(rng.randn(2, heads, seq, 128), jnp.bfloat16)
+        for _ in range(3)) + index_args + (
+        jnp.asarray(rng.randn(2, heads, seq, 128), jnp.float32),
+        selected, lse)
+
+    def sparse(kernel):
+        def loss(q, k, v, qi, ki, w, ct, selected, lse):
+            o, m, l = _flash_sel(q, k, v, selected, None, 512, 512) \
+                if kernel else sa.selected_attention(q, k, v, selected)
+            return (o.astype(jnp.float32) * ct).sum() \
+                + 100.0 * sa._indexer_loss(q, k, m, l, selected, qi, ki, w,
+                                           lse, None, kernel)
+        return loss
+
+    run(f"sparse_attention[2x{heads}x{seq}x128,{hi}x{di},top{top_k},bf16]",
+        sparse(True), sparse(False), sparse_args, 6, tol_bf16, 3)
 
 
 # ---------------------------------------------------------------------------

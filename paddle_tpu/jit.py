@@ -561,22 +561,28 @@ def recompute(layer_or_fn, *args, policy=None, **kwargs):
                                             for n in written)
             finally:
                 prandom._global_key.data = saved
-            out = out.data if isinstance(out, Tensor) else out
+            # a layer that returns several tensors (a block's stream and
+            # a loss of its own) hands each out as a checkpoint output
+            n_out[:] = [len(out)] if isinstance(out, (tuple, list)) else []
+            outs = tuple(o.data if isinstance(o, Tensor) else o
+                         for o in (out if n_out else (out,)))
             auxs = tuple(l.aux_loss.data for l in moe_subs)
             extra = auxs + new_buffers
-            return (out,) + extra if extra else out
+            return outs + extra if extra or n_out else outs[0]
 
+        n_out = []
         ckpt = jax.checkpoint(impl, policy=ckpt_policy)
         tensors = (prandom.next_key_graph(),) + live_args + tuple(
             holder_map[n] for n in names)
         res = apply(ckpt, tensors, name="recompute")
         if not isinstance(res, tuple):
             return res
-        for l, a in zip(moe_subs, res[1:]):
+        first = n_out[0] if n_out else 1
+        for l, a in zip(moe_subs, res[first:]):
             l.aux_loss = a
-        for n, t in zip(written, res[1 + len(moe_subs):]):
+        for n, t in zip(written, res[first + len(moe_subs):]):
             holder_map[n].data = t.data
-        return res[0]
+        return res[:first] if n_out else res[0]
 
     fn = layer_or_fn
     # same None-slot contract as the Layer branch: record positions of

@@ -14,7 +14,9 @@ an auto-picker that turns the predicted-peak model into decisions:
   Between ``"full"`` and ``"dots"`` stands what ``jit.recompute(layer,
   x)`` means when no policy is named, ``KERNEL_RESULTS``: the block's
   activations are made again, a kernel's saved result (the flash
-  kernels' o and statistic rows, which they leave under names) is not.
+  kernels' o and statistic rows, and the loss and three gradients of
+  ``F.dsa_indexer_loss``'s one pass, which they leave under names) is
+  not.
   ``remat=`` and the layer hook always name ``"full"`` or ``"dots"``.
 * **Optimizer-state host offload** (``offload``): pages the flat
   ``ParamArena`` Adam moments to host RAM after each apply and
@@ -182,7 +184,9 @@ def _kernel_results_policy():
     # Mosaic lowering) of its own of a kernel behind a module-level jit
     import jax
     from ..ops.pallas.flash_attention import RESULT_NAMES
-    return jax.checkpoint_policies.save_only_these_names(*RESULT_NAMES)
+    from ..ops.sparse_attention import RESULT_NAMES as INDEXER_LOSS_NAMES
+    return jax.checkpoint_policies.save_only_these_names(
+        *RESULT_NAMES, *INDEXER_LOSS_NAMES)
 
 
 def checkpoint_policy(name):

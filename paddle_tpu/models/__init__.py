@@ -36,6 +36,9 @@ def __getattr__(name):
     if name in ("SmallThinkerConfig", "SmallThinkerForCausalLM"):
         from . import smallthinker
         return getattr(smallthinker, name)
+    if name in ("KeyeVL2TextConfig", "KeyeVL2ForCausalLM"):
+        from . import keye_vl
+        return getattr(keye_vl, name)
     if name in ("Lfm2MoeConfig", "Lfm2MoeForCausalLM"):
         from . import lfm2
         return getattr(lfm2, name)

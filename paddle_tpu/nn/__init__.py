@@ -38,7 +38,8 @@ from .layers import Upsample as UpSample  # noqa: F401 (2.0-alpha name)
 from .layers import HSigmoid  # noqa: F401
 from .moe import MoEFFN, RoutedMoE, GatedMLP, moe_aux_loss  # noqa: F401
 from .hybrid import (Mamba2Mixer, GatedShortConv,  # noqa: F401
-                     GroupedQueryAttention, MultiHeadLatentAttention)
+                     GroupedQueryAttention, MultiHeadLatentAttention,
+                     SparseGroupedQueryAttention)
 from ..fluid.dygraph import RowConv  # noqa: F401
 
 # paddle.nn 1.x functional tails (reference: python/paddle/nn/

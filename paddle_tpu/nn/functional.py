@@ -7,6 +7,7 @@ from ..ops.nn_ops import *  # noqa: F401,F403
 from ..ops.loss import *  # noqa: F401,F403
 from ..ops.ssm import *  # noqa: F401,F403
 from ..ops.moe import *  # noqa: F401,F403
+from ..ops.sparse_attention import *  # noqa: F401,F403
 from ..ops.manip import one_hot, pad  # noqa: F401
 
 # --- paddle.nn.functional 1.x surface (reference: python/paddle/nn/

@@ -20,7 +20,7 @@ from ..ops import nn_ops as F
 from ..ops import ssm as S
 
 __all__ = ["Mamba2Mixer", "GatedShortConv", "GroupedQueryAttention",
-           "MultiHeadLatentAttention"]
+           "SparseGroupedQueryAttention", "MultiHeadLatentAttention"]
 
 
 class Mamba2Mixer(Layer):
@@ -138,7 +138,7 @@ class GroupedQueryAttention(Layer):
 
     def __init__(self, hidden_size, num_heads, num_kv_heads, head_dim,
                  causal=True, qk_norm_epsilon=None, rope_theta=None,
-                 diffusion_block=None, window=None):
+                 diffusion_block=None, window=None, rope_sections=None):
         super().__init__()
         if num_heads % num_kv_heads:
             raise ValueError(f"GroupedQueryAttention: {num_heads} query "
@@ -150,7 +150,7 @@ class GroupedQueryAttention(Layer):
         self.num_heads, self.num_kv_heads = num_heads, num_kv_heads
         self.head_dim, self.causal = head_dim, causal
         self.rope_theta, self.diffusion_block = rope_theta, diffusion_block
-        self.window = window
+        self.window, self.rope_sections = window, rope_sections
         self.q_proj = Linear(hidden_size, num_heads * head_dim,
                              bias_attr=False)
         self.k_proj = Linear(hidden_size, num_kv_heads * head_dim,
@@ -176,7 +176,8 @@ class GroupedQueryAttention(Layer):
             weight, epsilon = (None, 0.0) if norm is None \
                 else (norm.weight, norm._epsilon)
             t = F.qk_heads(t, count, weight, epsilon,
-                           *((positions, self.rope_theta) if rotate else ()))
+                           *((positions, self.rope_theta) if rotate else ()),
+                           sections=self.rope_sections if rotate else None)
         r = self.num_heads // count
         if r == 1:
             return t
@@ -204,6 +205,98 @@ class GroupedQueryAttention(Layer):
         ctx = ctx.transpose([0, 2, 1, 3]).reshape(
             [b, s, self.num_heads * self.head_dim])
         return self.o_proj(ctx)
+
+
+class SparseGroupedQueryAttention(GroupedQueryAttention):
+    """Grouped-query attention over a LEARNED SELECTION of keys (DeepSeek
+    sparse attention, DeepSeek-V3.2-Exp's report section 2, in front of
+    grouped-query attention as the ``KeyeVL2`` language model has it): a
+    small indexer scores every causal key of every row, a row's
+    ``top_k`` best are the keys its heads read, and the indexer is trained
+    by a loss of its own, the KL divergence from the attention
+    probabilities its selection produced to the soft-max of its scores.
+
+    On ``n = stop_gradient(x)``: ``qI = n W_qI`` as ``indexer_heads``
+    heads of ``indexer_head_dim``, ``kI = LayerNorm(n W_kI)`` (one key
+    head), both rotated by the FIRST axis of the positions over the whole
+    head (halves paired); ``w = n W_w / sqrt(indexer_heads *
+    indexer_head_dim)``; ``I(t, s) = sum_j w[t, j] relu(qI[t, j] . kI[s])``.
+    ``F.dsa_select`` makes the selection, ``flash_attention(selected=)``
+    attends under it, ``F.dsa_indexer_loss`` gives the layer's loss.
+    ``forward(x, positions)`` returns ``(y, loss)``: the loss reaches
+    ``indexer_q``, ``indexer_k``, ``indexer_k_norm`` and ``indexer_w``
+    alone, and nothing else reaches them (the selection has no gradient).
+
+    ``stats`` (a buffer, int32[2]) adds up, inside a compiled step, the
+    selected and the causal (row, key) pairs of every call, in units of
+    ``max(1, S // 16)`` pairs so that int32 holds a run's total:
+    ``monitor.device_counters.read()`` gives ``dsa.pairs_selected`` and
+    ``dsa.pairs_causal``."""
+
+    COUNTERS = ("dsa.pairs_selected", "dsa.pairs_causal")
+
+    def __init__(self, hidden_size, num_heads, num_kv_heads, head_dim,
+                 indexer_heads, indexer_head_dim, top_k,
+                 qk_norm_epsilon=None, rope_theta=10000.0,
+                 rope_sections=None, indexer_epsilon=1e-6):
+        super().__init__(hidden_size, num_heads, num_kv_heads, head_dim,
+                         causal=True, qk_norm_epsilon=qk_norm_epsilon,
+                         rope_theta=rope_theta, rope_sections=rope_sections)
+        import jax.numpy as jnp
+        from .layers import LayerNorm
+        from .. import monitor
+        from ..tensor import Tensor
+        self.indexer_heads, self.top_k = indexer_heads, int(top_k)
+        self.indexer_q = Linear(hidden_size, indexer_heads * indexer_head_dim,
+                                bias_attr=False)
+        self.indexer_k = Linear(hidden_size, indexer_head_dim,
+                                bias_attr=False)
+        self.indexer_k_norm = LayerNorm(indexer_head_dim, indexer_epsilon,
+                                        use_pallas=False)
+        self.indexer_w = Linear(hidden_size, indexer_heads, bias_attr=False)
+        self._w_scale = 1.0 / math.sqrt(indexer_heads * indexer_head_dim)
+        self.register_buffer("stats", monitor.device_counters.register(
+            self.COUNTERS, Tensor(jnp.zeros((2,), jnp.int32)), owner=self),
+            persistable=False)
+
+    def indexer(self, x, positions):
+        """``(qI [B, Hi, S, Di], kI [B, S, Di], w [B, S, Hi] float32)``
+        of the layer's input, which gets no gradient from them."""
+        import jax
+        from ..dispatch import apply
+        n = apply(jax.lax.stop_gradient, (x,), nondiff=True,
+                  name="stop_gradient")
+        at = None if positions is None else (
+            positions if self.rope_sections is None else positions[0])
+        qi = F.qk_heads(self.indexer_q(n), self.indexer_heads, None, 0.0,
+                        at, self.rope_theta)
+        ki = F.rotary_embedding(self.indexer_k_norm(self.indexer_k(n)), at,
+                                theta=self.rope_theta, interleaved=False)
+        w = self.indexer_w(n).astype("float32") * self._w_scale
+        return qi, ki, w
+
+    def forward(self, x, positions=None, force_flash=False):
+        import jax.numpy as jnp
+        from ..dispatch import apply
+        from ..ops.pallas import flash_attention
+        from ..ops.sparse_attention import dsa_indexer_loss, dsa_select
+        b, s = x.shape[0], x.shape[1]
+        q, k, v = self.qkv(x, positions)
+        qi, ki, w = self.indexer(x, positions)
+        selected, lse, _, pairs = dsa_select(qi, ki, w, self.top_k)
+        unit = max(1, s // 16)
+        causal = b * (s * (s + 1) // 2 // unit)
+        self.stats.data = self.stats.data + apply(
+            lambda n: jnp.stack([jnp.sum(n // unit),
+                                 jnp.full((), causal, n.dtype)]),
+            (pairs,), nondiff=True, name="dsa_counters").data
+        ctx, m, l = flash_attention(q, k, v, causal=True, selected=selected,
+                                    force=force_flash)
+        loss = dsa_indexer_loss(q.detach(), k.detach(), m, l, selected, qi,
+                                ki, w, lse)
+        ctx = ctx.transpose([0, 2, 1, 3]).reshape(
+            [b, s, self.num_heads * self.head_dim])
+        return self.o_proj(ctx), loss
 
 
 class MultiHeadLatentAttention(Layer):

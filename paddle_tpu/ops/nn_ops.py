@@ -765,12 +765,17 @@ def scaled_dot_product_attention(q, k, v, attn_mask=None, dropout_p=0.0,
 
 
 def rotary_embedding(x, positions=None, theta=10000.0, interleaved=True,
-                     name=None):
+                     sections=None, name=None):
     """Rotary position embedding (Su et al., arXiv:2104.09864) of ``x``
     ``[..., S, D]``, the sequence on the axis before the last and ``D``
     even: pair ``j < D / 2`` at position ``p`` turns by ``p * theta ** (-2 j
     / D)``. ``positions`` ``[S]`` (or anything that broadcasts against
-    ``x``'s leading axes and ``S``); None counts from 0.
+    ``x``'s leading axes and ``S``); None counts from 0. With ``sections``
+    (whole numbers that add up to ``D / 2``) the positions have one row
+    an AXIS, ``[len(sections), S]``, and the pairs are cut into contiguous
+    chunks of those sizes, chunk ``a`` turning by row ``a``'s position
+    (Qwen2-VL's ``mrope_section``: temporal, height, width); rows equal to
+    each other give the one-axis result bit for bit.
 
     ``interleaved=True`` pairs the neighbours ``(x[2j], x[2j+1])`` and
     returns the halves apart, as the DeepSeek-V3 family's code
@@ -784,9 +789,12 @@ def rotary_embedding(x, positions=None, theta=10000.0, interleaved=True,
     attention op take ``qk_heads`` below, which rotates in the same pass
     that norms and transposes them."""
     freq = _rotary_frequencies(x.shape[-1], theta, "rotary_embedding")
+    sections = _rotary_sections(sections, positions, x.shape[-1],
+                                "rotary_embedding")
 
     def impl(x, *pos, interleaved):
-        return _rotate(x, pos[0] if pos else None, freq, interleaved)
+        return _rotate(x, pos[0] if pos else None, freq, interleaved,
+                       sections)
 
     args = (x,) if positions is None else (x, positions)
     with _pscope("F.rotary_embedding"):
@@ -802,20 +810,42 @@ def _rotary_frequencies(d, theta, op):
                                        / d), np.float32)
 
 
-def _cos_sin(positions, s, freq):
+def _rotary_sections(sections, positions, d, op):
+    """``sections`` as a tuple of whole numbers, checked against the
+    positions' rows and the pairs of a head of ``d``; None stays None."""
+    if sections is None:
+        return None
+    sections = tuple(int(n) for n in sections)
+    shape = None if positions is None else tuple(positions.shape)
+    if shape is None or len(shape) != 2 or shape[0] != len(sections) \
+            or sum(sections) != d // 2 or min(sections) < 1:
+        raise ValueError(
+            f"{op}: sections {sections} take positions [{len(sections)}, S] "
+            f"and add up to the {d // 2} pairs of a head; positions "
+            f"{shape}")
+    return sections
+
+
+def _cos_sin(positions, s, freq, sections=None):
     """Cosine and sine ``[..., S, D / 2]`` of the rotation's angles,
-    float32; ``positions`` None counts ``s`` rows from 0."""
+    float32; ``positions`` None counts ``s`` rows from 0. Under
+    ``sections`` the positions are ``[axes, S]`` and pair ``j`` reads the
+    row of the chunk it lies in."""
     p = jnp.arange(s, dtype=jnp.float32) if positions is None \
         else positions.astype(jnp.float32)
-    angle = p[..., None] * jnp.asarray(freq, jnp.float32)
+    if sections is None:
+        p = p[..., None]
+    else:
+        p = p[np.repeat(np.arange(len(sections)), sections)].T
+    angle = p * jnp.asarray(freq, jnp.float32)
     return jnp.cos(angle), jnp.sin(angle)
 
 
-def _rotate(x, positions, freq, interleaved):
+def _rotate(x, positions, freq, interleaved, sections=None):
     """``rotary_embedding`` on arrays: ``x`` [..., S, D], ``positions``
     None or what broadcasts against ``x``'s leading axes and ``S``."""
     d = x.shape[-1]
-    cos, sin = _cos_sin(positions, x.shape[-2], freq)
+    cos, sin = _cos_sin(positions, x.shape[-2], freq, sections)
     xf = x.astype(jnp.float32)
     a, b = (xf[..., 0::2], xf[..., 1::2]) if interleaved \
         else (xf[..., :d // 2], xf[..., d // 2:])
@@ -823,7 +853,8 @@ def _rotate(x, positions, freq, interleaved):
                            -1).astype(x.dtype)
 
 
-def _qk_heads(x, *rest, heads, epsilon, freq, normed, positioned):
+def _qk_heads(x, *rest, heads, epsilon, freq, normed, positioned,
+              sections=None):
     """The portable path of ``qk_heads`` (``rest``: the norm's weight where
     ``normed``, then the positions where ``positioned``) and the kernels'
     oracle: ``_rms_norm`` over each head, the transpose, ``_rotate``."""
@@ -835,18 +866,20 @@ def _qk_heads(x, *rest, heads, epsilon, freq, normed, positioned):
                       gated=False, scaled=True)
     t = jnp.transpose(t, (0, 2, 1, 3))
     if freq is not None:
-        t = _rotate(t, rest.pop(0) if positioned else None, freq, False)
+        t = _rotate(t, rest.pop(0) if positioned else None, freq, False,
+                    sections)
     return t
 
 
 def qk_heads(x, num_heads, weight=None, epsilon=1e-6, positions=None,
-             theta=None, name=None):
+             theta=None, sections=None, name=None):
     """A projection's result ``x`` [B, S, num_heads * D] as the attention
     op takes its heads, [B, num_heads, S, D]: an RMS norm over each head
     where ``weight`` [D] is given (``rms_norm``'s numbers, ``epsilon``), a
     rotary embedding where ``theta`` is (``rotary_embedding(interleaved=
     False)``'s: pairs ``(x[j], x[j + D/2])``, ``positions`` [S] or None
-    for 0, 1, ...), the norm first. Float32 inside, rounded to ``x``'s
+    for 0, 1, ...; ``[axes, S]`` with ``sections``, as
+    ``rotary_embedding`` reads them), the norm first. Float32 inside, rounded to ``x``'s
     dtype after the norm and after the rotation, as the two ops round.
 
     On one TPU, where a head is whole 128-lane tiles and the rows whole
@@ -863,13 +896,15 @@ def qk_heads(x, num_heads, weight=None, epsilon=1e-6, positions=None,
         _rotary_frequencies(d, theta, "qk_heads").tolist())
     if freq is None and positions is not None:
         raise ValueError("qk_heads: positions without theta rotate nothing")
+    sections = _rotary_sections(sections, positions, d, "qk_heads")
     from .. import monitor
     from . import pallas
     # read off the call, as rms_norm's gated form above
     kernel = (pallas.enabled("qk_heads")
               and pallas.qk_heads_mod.supported(
                   tuple(x.shape), int(num_heads),
-                  None if positions is None else tuple(positions.shape))
+                  None if positions is None else tuple(positions.shape),
+                  sections)
               and (weight is None or tuple(weight.shape) == (d,)))
     monitor.counter("qk_heads.kernel_traced" if kernel
                     else "qk_heads.xla_traced").inc()
@@ -877,6 +912,8 @@ def qk_heads(x, num_heads, weight=None, epsilon=1e-6, positions=None,
         + (() if positions is None else (positions,))
     attrs = dict(heads=int(num_heads), epsilon=float(epsilon), freq=freq,
                  normed=weight is not None, positioned=positions is not None)
+    if sections is not None:    # a call without them traces what it traced
+        attrs["sections"] = sections
     with _pscope("F.qk_heads"):
         return apply(pallas.qk_heads_mod.qk_heads if kernel else _qk_heads,
                      args, attrs, name="qk_heads")

@@ -7,11 +7,14 @@ formulation).
 Forward: grid (batch*heads, q-blocks); each program walks k/v-blocks with
 the online-softmax recurrence (running max m, normalizer l, accumulator
 acc) so the S×S score matrix never hits HBM. A mask enters in one of
-four ways: as an ARRAY (an additive key bias [B,1,1,Sk] or a full
+five ways: as an ARRAY (an additive key bias [B,1,1,Sk] or a full
 [.,.,Sq,Sk] bias, added to the scores inside the kernel), as the causal
-diagonal, as a SLIDING WINDOW beside it (below), or as the BLOCK-DIFFUSION
-STRUCTURE (below) — the last three are facts of the call that cost no
-operand and decide which tiles a program walks at all. Attention-probability dropout is drawn in-kernel from the
+diagonal, as a SLIDING WINDOW beside it (below), as the BLOCK-DIFFUSION
+STRUCTURE (below) — these three are facts of the call that cost no
+operand and decide which tiles a program walks at all — or as a
+data-dependent SELECTION shared by a sequence's heads (below): one narrow
+operand, tested in both kernel bodies beside the diagonal.
+Attention-probability dropout is drawn in-kernel from the
 TPU PRNG, seeded per (bh, q-block, k-block) tile so the backward
 regenerates the identical keep-mask without ever storing it.
 
@@ -29,6 +32,23 @@ last key, and dq's accumulator takes parts from at most 9 k-blocks a
 q-block. Positions inside a crossed tile come from iotas, as the
 diagonal's. The side a program keeps whole is still the whole side (the
 block rule at the end of this file says what that costs).
+
+Selection (PR 47; ``flash_attention(causal=True, selected=...)``): an int8
+array ``[B, 1, Sq, Sk]``, row t of sequence b reads the keys it marks
+(``F.dsa_select`` makes one from a learned indexer's scores: the ``top_k``
+best causal keys a row). The additive path would carry it as a float32
+``[1, 1, 16384, 16384]`` bias, 1.07 GB a layer read once a head; as int8
+it is 268 MB, and the same two kernel bodies take it (``selected=`` in
+their static parameters; without it they trace what they traced before;
+under it the calls are named ``flash_sel_fwd`` and ``flash_sel_bwd``): the
+forward gets a q-block's rows of it as one more blocked operand (BQ x Sk
+bytes a program, the same block for every head of a sequence), the
+backward a k-block's rows of its TRANSPOSE (made once a backward call by
+XLA), and every score tile is masked by its int8 tile, the tiles the
+diagonal crosses by both. Every causal tile is walked: a token-level
+selection empties no tile. The forward asks for the VMEM its shapes need
+(``_sel_fwd_vmem``); the call also returns its two statistic rows, which
+the indexer's loss reads.
 
 Block-diffusion structure (PR 33; ``flash_attention(diffusion_block=B)``,
 ``block_diffusion_mask`` is the rule). q, k and v hold two copies of a
@@ -324,6 +344,15 @@ def _valid(shape, q0, k0, q_axis, *, sq, sk, block_q, block_k, causal,
     return valid
 
 
+def _and_selected(check, tile):
+    """``check`` (None: every position) and a selection's int8 tile, as
+    the ``valid`` a kernel's step takes."""
+    def both(shape):
+        keep = tile.astype(jnp.float32) > 0.5
+        return keep if check is None else keep & check(shape)
+    return both
+
+
 def _bd_rows(g, per_copy):
     """Block ``g`` of the ``2 * per_copy`` blocks of a two-copy side, as
     ``(clean, block within the copy)``: the noisy copy's blocks come
@@ -407,11 +436,13 @@ def _bd_tile_counts(bh, length, *, block_q, block_k, shift):
 
 def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, mask_ref, keep_ref, *rest,
                 block_q, block_k, sq, sk, causal, scale, mask_mode,
-                dropout_p, threshold, drop_mode, bd=None, window=None):
+                dropout_p, threshold, drop_mode, bd=None, window=None,
+                selected=False):
     # q_ref: (1, BQ, D); k_ref: (1, SKp, D); v_ref: (1, SKp, DV);
     # mask_ref: (1, {1, BQ}, SKp); o_ref: (1, BQ, DV). Under the block
     # structure k_ref / v_ref are the CLEAN copy's side and ``own`` the
-    # noisy copy's (1, BQ, D | DV) block at this q-block's positions
+    # noisy copy's (1, BQ, D | DV) block at this q-block's positions;
+    # under a selection ``own`` is its (1, 1, BQ, SKp) int8 rows
     *own, o_ref, m_ref, l_ref = rest
     geom = dict(block_q=block_q, block_k=block_k, sq=sq, sk=sk,
                 causal=causal, window=window)
@@ -481,9 +512,12 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, mask_ref, keep_ref, *rest,
 
     def tile(j, carry, masked):
         cols = pl.ds(j * block_k, block_k)
+        check = (lambda shape: valid(shape, j)) if masked else None
+        if selected:    # every tile reads its part of the selection
+            check = _and_selected(check, own[0][0, 0, :, cols])
         return step(k_ref[0, cols, :].astype(q.dtype),
                     v_ref[0, cols, :].astype(v_dtype), carry, j, cols,
-                    (lambda shape: valid(shape, j)) if masked else None)
+                    check)
 
     # under a window, first the tiles its lower edge crosses
     carry = _walk(first, plain_lo, functools.partial(tile, masked=True),
@@ -517,7 +551,8 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, mask_ref, keep_ref, *rest,
 def _bwd_kernel(seed_ref, q_ref, k_ref, v_ref, mask_ref, keep_ref,
                 m_ref, linv_ref, delta_ref, do_ref, *rest,
                 block_q, block_k, sq, sk, causal, scale, mask_mode,
-                dropout_p, threshold, drop_mode, bd=None, window=None):
+                dropout_p, threshold, drop_mode, bd=None, window=None,
+                selected=False):
     # this program owns ONE k-block (grid (bh, k-blocks)) and loops
     # q-blocks. q_ref: (1, SQp, D); do_ref: (1, SQp, DV); k_ref: (1, BK, D);
     # v_ref: (1, BK, DV); mask_ref: (1, {1, SQp}, BK); m/linv/delta:
@@ -536,7 +571,9 @@ def _bwd_kernel(seed_ref, q_ref, k_ref, v_ref, mask_ref, keep_ref,
     # noisy copy's at the same positions; dk and dv are the clean block's
     # part from that copy's rows and the noisy block's gradient (zero from
     # the clean rows).
-    if bd is None:
+    if selected:    # the selection TRANSPOSED, this k-block's (1, 1, BK, SQp)
+        sel_ref, dq_ref, dk_ref, dv_ref, dqt_ref, kt_ref = rest
+    elif bd is None:
         dq_ref, dk_ref, dv_ref, dqt_ref, kt_ref = rest
     else:
         (kn_ref, vn_ref, dq_ref, dk_ref, dv_ref, dkn_ref, dvn_ref,
@@ -636,8 +673,11 @@ def _bwd_kernel(seed_ref, q_ref, k_ref, v_ref, mask_ref, keep_ref,
     transposed(k_ref)
 
     def tile(qi, carry, masked):
-        return step(k, v, qi, carry,
-                    (lambda shape: valid(shape, qi)) if masked else None)
+        check = (lambda shape: valid(shape, qi)) if masked else None
+        if selected:
+            rows = pl.ds(pl.multiple_of(qi * block_q, block_q), block_q)
+            check = _and_selected(check, sel_ref[0, 0, :, rows])
+        return step(k, v, qi, carry, check)
 
     plain, crossed = (functools.partial(tile, masked=m)
                       for m in (False, True))
@@ -748,7 +788,8 @@ def _pad_axis(x, axis, new):
 
 
 def _flash_fwd_res(q, k, v, mask, mask_mode, seed, causal, scale, block_q,
-                   block_k, dropout_p, window=None, interpret=None):
+                   block_k, dropout_p, window=None, interpret=None,
+                   selected=None):
     from . import interpret_mode
     b, h, sq, d = q.shape
     sk, dvh = k.shape[2], v.shape[3]
@@ -822,17 +863,30 @@ def _flash_fwd_res(q, k, v, mask, mask_mode, seed, causal, scale, block_q,
         scale=s, mask_mode=mask_mode, dropout_p=dropout_p,
         threshold=threshold, drop_mode=drop_mode, window=window)
     # one kernel body, and a name a call form: a trace tells them apart
-    if window is None:
+    operands = [seed2, q3, k3, v3, m3, keep3]
+    if selected is not None:
+        # a q-block's rows of the selection, every key: one int8 block a
+        # program, shared by the heads of a sequence
+        call["in_specs"].append(pl.BlockSpec(
+            (1, 1, bq, sk_pad), lambda i, j: (i // h, 0, j, 0),
+            memory_space=pltpu.VMEM))
+        call["compiler_params"] = pltpu.CompilerParams(
+            vmem_limit_bytes=_sel_fwd_vmem(sk_pad, d, dvh, q.dtype.itemsize,
+                                           bq, bk, single))
+        operands.append(_pad_axis(_pad_axis(selected, 2, sq_pad), 3, sk_pad))
+        run = pl.pallas_call(functools.partial(kernel, selected=True),
+                             name="flash_sel_fwd", **call)
+    elif window is None:
         run = pl.pallas_call(kernel, name="flash_fwd", **call)
     else:
         run = pl.pallas_call(kernel, name="flash_win_fwd", **call)
-    out, mrow, lrow = run(seed2, q3, k3, v3, m3, keep3)
+    out, mrow, lrow = run(*operands)
     return out.reshape(b, h, sq, dvh), mrow, lrow
 
 
 def _flash_bwd(q, k, v, mask, mask_mode, seed, out, mrow, lrow, g, causal,
                scale, block_q, block_k, dropout_p, window=None,
-               interpret=None):
+               interpret=None, selected_t=None):
     from . import interpret_mode
     b, h, sq, d = q.shape
     sk, dvh = k.shape[2], v.shape[3]
@@ -914,17 +968,28 @@ def _flash_bwd(q, k, v, mask, mask_mode, seed, out, mrow, lrow, g, causal,
                         pltpu.VMEM((d, bk), k.dtype)],
         compiler_params=_bwd_params(
             sq_pad, d, dvh, q.dtype.itemsize, bq, bk, single,
-            extra=(msq_blk + (sq_pad if drop_mode == "mask" else 0)) * bk),
+            extra=(msq_blk + (sq_pad if drop_mode == "mask" else 0)
+                   + (0 if selected_t is None else sq_pad // 4)) * bk),
         interpret=interp)
     kernel = functools.partial(
         _bwd_kernel, block_q=bq, block_k=bk, sq=sq, sk=sk, causal=causal,
         scale=s, mask_mode=mask_mode, dropout_p=dropout_p,
         threshold=threshold, drop_mode=drop_mode, window=window)
-    if window is None:
+    operands = [seed2, q3p, k3, v3, m3, keep3, *stats, do3p]
+    if selected_t is not None:
+        # the selection transposed: this k-block's keys, every row
+        call["in_specs"].append(pl.BlockSpec(
+            (1, 1, bk, sq_pad), lambda i, j: (i // h, 0, j, 0),
+            memory_space=pltpu.VMEM))
+        operands.append(_pad_axis(_pad_axis(selected_t, 2, sk_pad), 3,
+                                  sq_pad))
+        run = pl.pallas_call(functools.partial(kernel, selected=True),
+                             name="flash_sel_bwd", **call)
+    elif window is None:
         run = pl.pallas_call(kernel, name="flash_bwd", **call)
     else:
         run = pl.pallas_call(kernel, name="flash_win_bwd", **call)
-    dq, dk, dv = run(seed2, q3p, k3, v3, m3, keep3, *stats, do3p)
+    dq, dk, dv = run(*operands)
     dq = dq[:, :sq].reshape(b, h, sq, d)
     dk = dk[:, :sk].reshape(b, h, sk, d)
     dv = dv[:, :sk].reshape(b, h, sk, dvh)
@@ -1044,6 +1109,72 @@ def _win_vjp_bwd(window, scale, block_q, block_k, res, g):
 
 
 _flash_win.defvjp(_win_vjp_fwd, _win_vjp_bwd)
+
+
+# Under a SELECTION (PR 47): causal attention in which row t reads the keys
+# ``selected[b, 0, t, :]`` marks (int8, one array a sequence, shared by its
+# heads; ``F.dsa_select`` makes it). The two kernel bodies with one more
+# static parameter and one more operand, named ``flash_sel_fwd`` /
+# ``flash_sel_bwd`` and behind module-level ``jax.jit``s as the windowed
+# calls: the forward reads a q-block's rows of the selection (BQ x S bytes a
+# program), the backward a k-block's rows of its TRANSPOSE (made once a
+# backward call by XLA, S x S bytes each way), one tile beside each score
+# tile. Every causal tile is walked: a token-level selection empties none.
+# The call returns its statistics too: the indexer's loss reads them.
+@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7))
+def _sel_fwd(q, k, v, selected, scale, block_q, block_k, interpret):
+    return _flash_fwd_res(q, k, v, None, None, _NO_SEED, True, scale,
+                          block_q, block_k, 0.0, interpret=interpret,
+                          selected=selected)
+
+
+@functools.partial(jax.jit, static_argnums=(8, 9, 10, 11))
+def _sel_bwd(q, k, v, selected, out, mrow, lrow, g, scale, block_q, block_k,
+             interpret):
+    return _flash_bwd(q, k, v, None, None, _NO_SEED, out, mrow, lrow, g,
+                      True, scale, block_q, block_k, 0.0,
+                      interpret=interpret,
+                      selected_t=jnp.swapaxes(selected, 2, 3))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _flash_sel(q, k, v, selected, scale, block_q, block_k):
+    """``(o, m, l)`` of causal attention over the selected keys."""
+    from . import interpret_mode
+    return _sel_fwd(q, k, v, selected, scale, block_q, block_k,
+                    interpret_mode())
+
+
+def _sel_vjp_fwd(q, k, v, selected, scale, block_q, block_k):
+    from . import interpret_mode
+    out, mrow, lrow = _named_results(*_sel_fwd(
+        q, k, v, selected, scale, block_q, block_k, interpret_mode()))
+    return (out, mrow, lrow), (q, k, v, selected, out, mrow, lrow)
+
+
+def _sel_vjp_bwd(scale, block_q, block_k, res, g):
+    from . import interpret_mode
+    q, k, v, selected = res[:4]
+    bq, bk = _clamped_blocks(block_q, block_k, q.shape[2], k.shape[2])
+    _count_backward(_tile_counts(q.shape[0] * q.shape[1], block_q=bq,
+                                 block_k=bk, sq=q.shape[2], sk=k.shape[2],
+                                 causal=True)[0])
+    grads = _sel_bwd(*res, g[0], scale, block_q, block_k, interpret_mode())
+    return (*grads, np.zeros(selected.shape, jax.dtypes.float0))
+
+
+_flash_sel.defvjp(_sel_vjp_fwd, _sel_vjp_bwd)
+
+
+def _sel_fwd_vmem(sk, d, dv, itemsize, block_q, block_k, single):
+    """The VMEM a forward call under a selection may use: the whole k / v
+    side (one buffer or two), two buffers of a q-block's int8 rows of the
+    selection, its own blocks and a few float32 score tiles."""
+    side = sk * (_lanes(d) + _lanes(dv)) * itemsize
+    need = ((1 if single else 2) * side + 2 * block_q * sk
+            + 4 * block_q * (_lanes(d) + _lanes(dv)) * itemsize
+            + 8 * block_q * block_k * 4 + 4 * 1024 * 1024)
+    return max(int(need), _DEFAULT_VMEM_BYTES)
 
 
 def sliding_window_mask(length, window):
@@ -1356,7 +1487,7 @@ def _bwd_params(seq, d, dv, itemsize, block_q, block_k, single, blocks=1,
 def flash_attention(q, k, v, attn_mask=None, causal=False, scale=None,
                     block_q=512, block_k=1024, dropout_p=0.0,
                     training=False, force=False, diffusion_block=None,
-                    window=None, name=None):
+                    window=None, selected=None, name=None):
     """Framework op: flash attention over q, k (B, H, S, D) and v (B, H,
     S, DV); the result is (B, H, Sq, DV). The kernels take any head sizes:
     ``DV`` may differ from ``D`` (multi-head latent attention: 192 and
@@ -1386,6 +1517,22 @@ def flash_attention(q, k, v, attn_mask=None, causal=False, scale=None,
     window's lower edge and the diagonal and mask those either crosses; a
     window that holds the whole sequence is a causal call. No
     ``attn_mask``, dropout or ``diffusion_block`` beside it.
+
+    ``selected`` beside ``causal=True`` is a data-dependent SELECTION, an
+    int8 (or bool) array ``[B, 1, S, S]`` shared by a sequence's heads:
+    row t reads the keys ``selected[b, 0, t, :]`` marks, and of those the
+    ones at or before it (``F.dsa_select`` makes one, a learned sparse
+    attention's ``top_k`` keys a row). An operand, unlike the three facts
+    above: the kernels (named ``flash_sel_fwd`` / ``flash_sel_bwd``) read
+    one int8 tile beside each score tile and walk every causal tile. The
+    result of such a call is ``(o, m, l)``: beside ``o`` the soft-max
+    statistics ``[B * H, 1, S]`` float32 as the kernels keep them, a row's
+    largest selected (scaled) score and the sum of ``exp(score - m)``,
+    without a gradient (``F.dsa_indexer_loss`` reads them). No
+    ``attn_mask``, dropout, ``window`` or ``diffusion_block`` beside it.
+    Counters ``flash_attention.selected_tiles_walked`` (the forward
+    kernel's own bounds added up, as ``flash_attention.tiles``) and
+    ``.selected_tiles_causal`` (the tiles that hold a causal pair).
 
     ``monitor`` counters ``flash_attention.kernel_traced`` /
     ``flash_attention.xla_traced`` count the call sites that traced each
@@ -1427,6 +1574,14 @@ def flash_attention(q, k, v, attn_mask=None, causal=False, scale=None,
                 f"one sequence (q {sq}, k {sk} rows) and no mask, dropout "
                 f"or diffusion_block")
         window = int(window) if window < sq else None
+    if selected is not None:
+        if has_mask or not causal or p_drop or sq != sk \
+                or diffusion_block is not None or window is not None \
+                or tuple(selected.shape) != (b, 1, sq, sk):
+            raise ValueError(
+                f"flash_attention: selected {tuple(selected.shape)} takes "
+                f"causal=True over one sequence ([{b}, 1, {sq}, {sk}]) and "
+                f"no mask, dropout, window or diffusion_block")
     block_q, block_k = _blocks_that_fit(held, d, v.shape[3],
                                         q.dtype.itemsize, block_q, block_k)
     mode = _mask_mode(attn_mask.shape if has_mask else None, b, h, sq, sk)
@@ -1434,6 +1589,9 @@ def flash_attention(q, k, v, attn_mask=None, causal=False, scale=None,
         "flash_attention", seq_len=max(sq, sk)))
     monitor.counter("flash_attention.kernel_traced" if kernel
                     else "flash_attention.xla_traced").inc()
+    if selected is not None:
+        return _selected_call(q, k, v, selected, scale, block_q, block_k,
+                              kernel)
     if not kernel:
         from ..nn_ops import scaled_dot_product_attention as sdpa
         if diffusion_block is not None:
@@ -1486,3 +1644,35 @@ def flash_attention(q, k, v, attn_mask=None, causal=False, scale=None,
     if p_drop > 0.0:
         args = args + (prandom.next_key_graph(),)
     return apply(impl, args, name="pallas_flash_attention")
+
+
+def _selected_call(q, k, v, selected, scale, block_q, block_k, kernel):
+    """``flash_attention(selected=...)`` behind its checks, ``(o, m, l)``:
+    the kernels, or the definition route of ``ops/sparse_attention.py``."""
+    from ...dispatch import apply
+    from ... import monitor
+    from ..sparse_attention import selected_attention
+    if kernel:
+        b, h, sq, _ = q.shape
+        bq, bk = _clamped_blocks(block_q, block_k, sq, sq)
+        walked, _ = _tile_counts(b * h, block_q=bq, block_k=bk, sq=sq, sk=sq,
+                                 causal=True)
+        # the tiles that hold a pair at or below the diagonal, counted
+        # apart from the kernel's bounds: a token-level selection empties
+        # none of them, a kernel that skipped some would walk fewer
+        causal = b * h * sum(-(-min(i * bq + bq, sq) // bk)
+                             for i in range(-(-sq // bq)))
+        monitor.counter("flash_attention.selected_tiles_walked").inc(walked)
+        monitor.counter("flash_attention.selected_tiles_causal").inc(causal)
+
+        def impl(q, k, v, selected):
+            o, m, l = _flash_sel(q, k, v, selected.astype(jnp.int8), scale,
+                                 block_q, block_k)
+            return o, jax.lax.stop_gradient(m), jax.lax.stop_gradient(l)
+    else:
+        def impl(q, k, v, selected):
+            return selected_attention(q, k, v, selected, scale)
+
+    o, m, l = apply(impl, (q, k, v, selected),
+                    name="pallas_flash_attention")
+    return o, m.detach(), l.detach()

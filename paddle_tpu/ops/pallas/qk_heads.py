@@ -59,23 +59,27 @@ def _tiles(s, heads, d, tiles=None):
     return None
 
 
-def supported(x_shape, heads, positions_shape=None):
+def supported(x_shape, heads, positions_shape=None, sections=None):
     """Whether the kernels' tiles fit ``x`` [B, S, heads * D]: a head a
     whole number of 128-lane tiles, whole row tiles (S a multiple of 128),
-    positions (where given) one a row."""
+    positions (where given) one a row, or one a row and axis under
+    ``sections``."""
     if len(x_shape) != 3 or heads < 1 or x_shape[2] % heads:
         return False
     s, d = x_shape[1], x_shape[2] // heads
-    if positions_shape is not None and tuple(positions_shape) != (s,):
+    rows = (s,) if sections is None else (len(sections), s)
+    if positions_shape is not None and tuple(positions_shape) != rows:
         return False
     return d % 128 == 0 and _tiles(s, heads, d) is not None
 
 
-def tables(positions, s, freq):
+def tables(positions, s, freq, sections=None):
     """The rotation's two float32 tables ``[S, D]``: ``[cos | cos]`` and
-    ``[-sin | sin]`` of ``ops/nn_ops.py: _rotate``'s angles."""
+    ``[-sin | sin]`` of ``ops/nn_ops.py: _rotate``'s angles; under
+    ``sections`` each pair's column from its chunk's row of the
+    positions."""
     from ..nn_ops import _cos_sin
-    cos, sin = _cos_sin(positions, s, freq)
+    cos, sin = _cos_sin(positions, s, freq, sections)
     return (jnp.concatenate([cos, cos], -1),
             jnp.concatenate([-sin, sin], -1))
 
@@ -194,27 +198,28 @@ def _backward(g, x, w, cos, sin, *, epsilon, interpret, tiles=None):
             jnp.sum(got[1], (0, 1)) if normed else None)
 
 
-def _operands(x, w, positions, freq):
+def _operands(x, w, positions, freq, sections=None):
     cos, sin = (None, None) if freq is None \
-        else tables(positions, x.shape[1], freq)
+        else tables(positions, x.shape[1], freq, sections)
     return (None if w is None else w.astype(_F32)[None]), cos, sin
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _heads(x, w, positions, heads, epsilon, freq):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _heads(x, w, positions, heads, epsilon, freq, sections=None):
     from . import interpret_mode
-    return _forward(x, *_operands(x, w, positions, freq), heads=heads,
-                    epsilon=epsilon, interpret=interpret_mode())
+    return _forward(x, *_operands(x, w, positions, freq, sections),
+                    heads=heads, epsilon=epsilon, interpret=interpret_mode())
 
 
-def _heads_fwd(x, w, positions, heads, epsilon, freq):
-    return _heads(x, w, positions, heads, epsilon, freq), (x, w, positions)
+def _heads_fwd(x, w, positions, heads, epsilon, freq, sections):
+    return (_heads(x, w, positions, heads, epsilon, freq, sections),
+            (x, w, positions))
 
 
-def _heads_bwd(heads, epsilon, freq, res, g):
+def _heads_bwd(heads, epsilon, freq, sections, res, g):
     from . import interpret_mode
     x, w, positions = res
-    dx, dw = _backward(g, x, *_operands(x, w, positions, freq),
+    dx, dw = _backward(g, x, *_operands(x, w, positions, freq, sections),
                        epsilon=epsilon, interpret=interpret_mode())
     at = None if positions is None \
         else np.zeros(positions.shape, jax.dtypes.float0)
@@ -224,7 +229,8 @@ def _heads_bwd(heads, epsilon, freq, res, g):
 _heads.defvjp(_heads_fwd, _heads_bwd)
 
 
-def qk_heads(x, *rest, heads, epsilon, freq, normed, positioned):
+def qk_heads(x, *rest, heads, epsilon, freq, normed, positioned,
+             sections=None):
     """``ops/nn_ops.py: _qk_heads`` through the kernels; same arguments
     (``rest``: the norm's weight where ``normed``, then the positions
     where ``positioned``), same result. The shapes have to be
@@ -232,4 +238,4 @@ def qk_heads(x, *rest, heads, epsilon, freq, normed, positioned):
     rest = list(rest)
     w = rest.pop(0) if normed else None
     positions = rest.pop(0) if positioned else None
-    return _heads(x, w, positions, heads, float(epsilon), freq)
+    return _heads(x, w, positions, heads, float(epsilon), freq, sections)

@@ -5,6 +5,9 @@
         [--out chiprun_out/faults.json]
     python3 scripts/cell_faults.py --workload lfm2_8b_a1b.causal_pretrain_2x8k \
         --seed 3000043701 --faults gates_left_out,sequences_run_on
+    python3 scripts/cell_faults.py --workload keye_vl2_30b_a3b.sparse_causal_16k \
+        --seed 3000004751 \
+        --faults selection_left_out,indexer_loss_left_out,position_rows_collapsed
 
 For each named fault: the program with that fault planted, driven through
 the steps `correct` checks at the cell's own size (``benchmark/control.py``'s
@@ -77,8 +80,40 @@ def sequences_run_on():
     return lambda: setattr(hybrid.GatedShortConv, "forward", forward)
 
 
-FAULTS = {f.__name__: f for f in (router_behind_attention, window_ignored,
-                                  gates_left_out, sequences_run_on)}
+def selection_left_out():
+    """Dense causal attention: every causal key kept."""
+    from paddle_tpu.ops import sparse_attention
+    select = sparse_attention.dsa_select
+    sparse_attention.dsa_select = lambda qi, ki, w, top_k: select(
+        qi, ki, w, int(qi.shape[2]))
+    return lambda: setattr(sparse_attention, "dsa_select", select)
+
+
+def indexer_loss_left_out():
+    """``L_I`` left out of the step's loss: the indexers get no gradient."""
+    from paddle_tpu.models.keye_vl import KeyeVL2ForCausalLM
+    loss = KeyeVL2ForCausalLM.loss
+    KeyeVL2ForCausalLM.loss = \
+        lambda self, logits, ids, weights, indexer_loss: loss(
+            self, logits, ids, weights, indexer_loss * 0.0)
+    return lambda: setattr(KeyeVL2ForCausalLM, "loss", loss)
+
+
+def position_rows_collapsed():
+    """Height and width ids replaced by the temporal one: one-axis
+    positions over the image spans."""
+    import paddle_tpu as pt
+    from paddle_tpu.models.keye_vl import KeyeVL2ForCausalLM
+    forward = KeyeVL2ForCausalLM.forward
+    KeyeVL2ForCausalLM.forward = lambda self, ids, at: forward(
+        self, ids, pt.ops.manip.stack([at[0], at[0], at[0]]))
+    return lambda: setattr(KeyeVL2ForCausalLM, "forward", forward)
+
+
+FAULTS = {f.__name__: f for f in (
+    router_behind_attention, window_ignored, gates_left_out,
+    sequences_run_on, selection_left_out, indexer_loss_left_out,
+    position_rows_collapsed)}
 
 
 def main(argv=None):

@@ -347,6 +347,68 @@ def test_flash_window_at_the_cells_size(one_chip, compiled_kernels):
     assert 16 * mib < took <= allowed < 48 * mib
 
 
+# 16,384 x 128 under a learned selection, 32 heads: the keye_vl2_30b_a3b
+# cell's attention after its K/V heads are repeated. The causal call's two
+# kernel bodies with one more operand, the int8 selection [1, 1, S, S] (a
+# q-block's rows forward, a k-block's rows of its transpose backward): the
+# same blocks, each call inside the VMEM limit its shapes give, and the
+# only S x S array of the program is the int8 one
+def test_flash_under_a_selection_at_the_cells_size(one_chip,
+                                                    compiled_kernels):
+    import importlib
+    fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
+    block_q, block_k = fa._blocks_that_fit(16384, 128, 128, 2, 512, 1024)
+
+    def f(q, k, v, selected):
+        return fa._flash_sel(q, k, v, selected, None, block_q, block_k)[0]
+
+    qkv = ((1, 32, 16384, 128), jnp.bfloat16)
+    text = _compiled_text(
+        _grad_sum(f, argnums=(0, 1, 2)), one_chip, qkv, qkv, qkv,
+        ((1, 1, 16384, 16384), jnp.int8),
+        names=("flash_sel_fwd", "flash_sel_bwd"))
+    assert text.count("tpu_custom_call") == 2
+    assert not re.search(r"(f32|bf16)\[(\d+,)*16384,(\d+,)*16384[,\]]", text)
+    assert "s8[1,1,16384,16384]" in text and "f32[32,1,16384]" in text
+    vmem = _vmem_of_kernels(text)
+    mib = 2 ** 20
+    allowed, took = vmem["flash_sel_fwd"]
+    assert allowed == fa._sel_fwd_vmem(16384, 128, 128, 2, 512, 512, True)
+    assert 16 * mib < took <= allowed < 48 * mib
+    allowed, took = vmem["flash_sel_bwd"]
+    assert 16 * mib < took <= allowed < 64 * mib
+
+
+# the indexer's two kernels at the cell's size (16 heads of 64 over 16,384
+# rows): the selection holds a block of 128 rows' scores in VMEM and writes
+# int8; the loss's pass reads the selection transposed and leaves four
+# float32 results; neither makes a float S x S array
+def test_the_indexers_kernels_at_the_cells_size(one_chip, compiled_kernels):
+    from paddle_tpu.ops.pallas import dsa
+    s = 16384
+    qi, ki, w = (((1, 16, s, 64), jnp.bfloat16), ((1, s, 64), jnp.bfloat16),
+                 ((1, s, 16), jnp.float32))
+    assert dsa.select_supported(qi[0])
+    text = _compiled_text(lambda *a: dsa.select(*a, top_k=2048), one_chip,
+                          qi, ki, w, names=("dsa_select",))
+    assert text.count("tpu_custom_call") == 1
+    assert "s8[1,1,16384,16384]" in text
+    assert not re.search(r"(f32|bf16)\[(\d+,)*16384,(\d+,)*16384[,\]]", text)
+    allowed, took = _vmem_of_kernels(text)["dsa_select"]
+    assert 8 * 2 ** 20 < took <= allowed < 64 * 2 ** 20
+    qk = ((1, 32, s, 128), jnp.bfloat16)
+    stat = ((32, 1, s), jnp.float32)
+    assert dsa.kl_supported(qk[0], qi[0])
+    text = _compiled_text(
+        lambda *a: dsa.kl_and_grads(*a, None), one_chip, qk, qk, stat, stat,
+        ((1, 1, s, s), jnp.int8), qi, ki, w, ((1, s), jnp.float32),
+        names=("dsa_kl",))
+    assert text.count("tpu_custom_call") == 1
+    assert not re.search(r"(f32|bf16)\[(\d+,)*16384,(\d+,)*16384[,\]]", text)
+    allowed, took = _vmem_of_kernels(text)["dsa_kl"]
+    assert took <= allowed < 96 * 2 ** 20
+
+
 # the one backward kernel at the four flash cells' shapes, at 16,384 x 128
 # and at the lfm2 cell's 2 x 32 x 8,192 x 64 (two buffers a side, 512 x
 # 1,024 tiles): beside the whole q side (q, dO, statistics) it holds dq's block and
@@ -1017,8 +1079,10 @@ KERNEL_NAMES = {
                       "batch_norm_bwd_reduce", "batch_norm_bwd_dx"],
     "causal_conv1d.py": ["conv1d_fwd", "conv1d_bwd", "gated_conv_fwd",
                          "gated_conv_bwd"],
-    "flash_attention.py": ["flash_fwd", "flash_win_fwd", "flash_bwd",
-                           "flash_win_bwd", "flash_bd_fwd", "flash_bd_bwd"],
+    "dsa.py": ["dsa_select", "dsa_kl"],
+    "flash_attention.py": ["flash_sel_fwd", "flash_fwd", "flash_win_fwd",
+                           "flash_sel_bwd", "flash_bwd", "flash_win_bwd",
+                           "flash_bd_fwd", "flash_bd_bwd"],
     "gated_rms_norm.py": ["gated_norm_fwd", "gated_norm_bwd"],
     "layer_norm.py": ["layer_norm_fwd", "layer_norm_bwd"],
     "moe_grouped.py": ["moe_hidden", "moe_gmm", "moe_hidden_bwd",
@@ -1064,13 +1128,15 @@ def test_no_pallas_call_site_is_left_out_and_no_name_is_used_twice():
     found = {f: names for f, names in found.items() if names}
     assert found == KERNEL_NAMES
     every = [n for names in found.values() for n in names]
-    assert len(every) == len(set(every)) == 29
+    assert len(every) == len(set(every)) == 33
 
 
 # a registered name switches the kernels of the file of its name; where two
 # pairs share a file, those whose names start with its prefix
 KERNEL_SWITCHES = {"causal_conv1d": ("causal_conv1d.py", "conv1d_"),
-                   "gated_short_conv": ("causal_conv1d.py", "gated_conv_")}
+                   "gated_short_conv": ("causal_conv1d.py", "gated_conv_"),
+                   "dsa_select": ("dsa.py", "dsa_select"),
+                   "dsa_kl": ("dsa.py", "dsa_kl")}
 
 
 def test_every_registered_kernel_has_a_module_with_a_call_site():

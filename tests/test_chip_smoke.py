@@ -41,8 +41,12 @@ def test_phase_kernels_tiny_interpret(cs, capsys):
     # under the block-diffusion structure and a sliding window, the scan,
     # the convolution, the gated short convolution, the gated norm, the
     # projection-to-heads pair, the experts' scatter-add, the experts'
-    # grouped products
-    assert len(out) == 15
+    # grouped products, a learned selection against a sort and the two
+    # kernels under it
+    assert len(out) == 17
+    assert any("dsa_select[2x128,4x64,top32]" in l
+               and "differ_from_a_sort=0" in l for l in out)
+    assert any("sparse_attention[2x2x128x128,4x64,top32" in l for l in out)
     assert any("qk_heads[2x128x2x128,norm+rotary" in l for l in out)
     assert any("moe_grouped[64x128,4x128gated" in l for l in out)
     assert any("gated_short_conv[2x128x3x" in l for l in out)
@@ -51,7 +55,8 @@ def test_phase_kernels_tiny_interpret(cs, capsys):
     assert any("x96|64,bf16,causal" in l for l in out)
     assert any("x64,bf16,block_diffusion" in l for l in out)
     assert any("padmask" in l for l in out)
-    assert all("tpu_custom_calls=0" in l for l in out)   # interpreted
+    assert all("tpu_custom_calls=0" in l for l in out
+               if "dsa_select" not in l)                # interpreted
 
 
 def test_phase_kernels_fails_past_tolerance(cs):
