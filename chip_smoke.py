@@ -102,13 +102,14 @@ def phase_kernels(rows, hidden, batch, heads, seq, head_dim,
     shipped = sorted(k for k, on in P._AUTO_ON.items() if on)
     if shipped != ["causal_conv1d", "dsa_kl", "dsa_select",
                    "flash_attention", "gated_rms_norm", "gated_short_conv",
-                   "layer_norm", "moe_grouped", "moe_scatter_add",
-                   "qk_heads", "ssd_scan"]:
+                   "layer_norm", "mla_heads", "moe_grouped",
+                   "moe_scatter_add", "qk_heads", "ssd_scan"]:
         raise AssertionError(f"_AUTO_ON ships {shipped}; this phase covers "
                              f"causal_conv1d, dsa_kl, dsa_select, "
                              f"flash_attention, gated_rms_norm, "
-                             f"gated_short_conv, layer_norm, moe_grouped, "
-                             f"moe_scatter_add, qk_heads and ssd_scan")
+                             f"gated_short_conv, layer_norm, mla_heads, "
+                             f"moe_grouped, moe_scatter_add, qk_heads and "
+                             f"ssd_scan")
     on_chip = pt.device.is_tpu_backend()
     if on_chip and P.interpret_mode():
         raise AssertionError("interpret mode reachable on a TPU backend")
@@ -335,6 +336,33 @@ def phase_kernels(rows, hidden, batch, heads, seq, head_dim,
                     .astype(jnp.float32) * a[2]).sum(),
         lambda *a: (_qk_heads(*a[:2], a[3], **head_attrs) * a[2]).sum(),
         head_args, 2, tol_bf16, 2)
+
+    # a latent attention's three projected arrays (batch, seq, heads x
+    # (128 + 64)), (batch, seq, heads x (128 + 128)) and the one rotary
+    # key head (batch, seq, 64) bf16 into q, k (batch, heads, seq, 192)
+    # and v (batch, heads, seq, 128): interleaved rotation, the key head
+    # behind every head, K split from V
+    from paddle_tpu.ops.nn_ops import _mla_heads
+    from paddle_tpu.ops.pallas import mla_heads as mlh
+    widths = (heads * 192, heads * 256, 64)
+    if not mlh.supported(*((batch, seq, n) for n in widths), heads, 128, 128,
+                         [jnp.bfloat16] * 3):
+        raise AssertionError("the latent heads' kernels would not take this "
+                             "shape")
+    latent_attrs = dict(heads=heads, nope=128, v=128, freq=tuple(
+        _rotary_frequencies(64, 1e4, "smoke").tolist()))
+    latent_args = tuple(
+        jnp.asarray(rng.randn(batch, seq, n), jnp.bfloat16) for n in widths
+    ) + tuple(jnp.asarray(rng.randn(batch, heads, seq, d), jnp.float32)
+              for d in (192, 192, 128))
+
+    def latent_loss(fn):
+        return lambda *a: sum((y.astype(jnp.float32) * ct).sum() for y, ct
+                              in zip(fn(*a[:3], **latent_attrs), a[3:]))
+
+    run(f"mla_heads[{batch}x{seq}x{heads}x(128+64|128+128),bf16]",
+        latent_loss(mlh.mla_heads), latent_loss(_mla_heads), latent_args, 3,
+        tol_bf16, 2)
 
     # routed experts over (rows, hidden) bf16, 4 held of 16, top-2, gated:
     # the combine through the in-place scatter-add kernel, forward and dx

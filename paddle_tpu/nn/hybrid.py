@@ -15,7 +15,6 @@ import numpy as np
 from .layer import Layer
 from .layers import Linear, RMSNorm
 from .. import initializer as I
-from ..ops import manip
 from ..ops import nn_ops as F
 from ..ops import ssm as S
 
@@ -315,9 +314,16 @@ class MultiHeadLatentAttention(Layer):
     with ``R`` = ``F.rotary_embedding`` (interleaved pairs) and ``d_qk =
     qk_nope_head_dim + qk_rope_head_dim``. Queries and keys are ``d_qk``
     wide and values ``v_head_dim``: the flash dispatch
-    (``ops.pallas.flash_attention``) takes the two sizes as they are. The
-    rotary key head is repeated to ``num_heads`` in front of it (a kernel
-    that reads it once is future work, PERF.md section 7). No bias.
+    (``ops.pallas.flash_attention``) takes the two sizes as they are.
+    ``F.mla_heads`` assembles its three operands from ``q_b_proj``'s and
+    ``kv_b_proj``'s results and the rotary key head: on one TPU one kernel
+    each way (the rotation, the key head behind every head's ``k_nope``,
+    the split of K from V and the move to ``[B, heads, S, d]`` in one
+    pass), else the chain of transposes, slices, rotations, a broadcast
+    and concatenations that stood here. K is still stored ``d_qk`` wide
+    with the rotary key head in every head (flash kernels that take
+    ``k_nope`` and ``k_rope`` apart are future work, PERF.md section 7).
+    No bias.
     Parameter names are the source's (``q_a_proj``, ``q_a_layernorm``,
     ``q_b_proj``, ``kv_a_proj_with_mqa``, ``kv_a_layernorm``,
     ``kv_b_proj``, ``o_proj``). The compressed cache and the absorbed
@@ -346,23 +352,14 @@ class MultiHeadLatentAttention(Layer):
 
     def qkv(self, x):
         """``(q, k, v)`` as the attention op takes them: ``[B, heads, S,
-        d_qk]`` twice and ``[B, heads, S, v_head_dim]``."""
-        b, s, h = x.shape[0], x.shape[1], self.num_heads
-        nope, rope = self.qk_nope_head_dim, self.qk_rope_head_dim
+        d_qk]`` twice and ``[B, heads, S, v_head_dim]`` (``F.mla_heads``)."""
         q = self.q_b_proj(self.q_a_layernorm(self.q_a_proj(x)))
-        q = q.reshape([b, s, h, nope + rope]).transpose([0, 2, 1, 3])
         ckv = self.kv_a_proj_with_mqa(x)
         kv = self.kv_b_proj(self.kv_a_layernorm(
             ckv[:, :, :self.kv_lora_rank]))
-        kv = kv.reshape([b, s, h, nope + self.v_head_dim]).transpose(
-            [0, 2, 1, 3])
-        q_rope = F.rotary_embedding(q[:, :, :, nope:], theta=self.rope_theta)
-        k_rope = F.rotary_embedding(ckv[:, :, self.kv_lora_rank:],
-                                    theta=self.rope_theta)
-        k_rope = k_rope.unsqueeze(1).expand([b, h, s, rope])
-        q = manip.concat([q[:, :, :, :nope], q_rope], axis=-1)
-        k = manip.concat([kv[:, :, :, :nope], k_rope], axis=-1)
-        return q, k, kv[:, :, :, nope:]
+        return F.mla_heads(q, kv, ckv[:, :, self.kv_lora_rank:],
+                           self.num_heads, self.qk_nope_head_dim,
+                           self.v_head_dim, theta=self.rope_theta)
 
     def forward(self, x, force_flash=False):
         b, s = x.shape[0], x.shape[1]

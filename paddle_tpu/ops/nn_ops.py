@@ -919,6 +919,70 @@ def qk_heads(x, num_heads, weight=None, epsilon=1e-6, positions=None,
                      args, attrs, name="qk_heads")
 
 
+def _mla_heads(q, kv, k_rope, *, heads, nope, v, freq):
+    """The portable path of ``mla_heads`` and the kernels' oracle: what
+    ``MultiHeadLatentAttention.qkv`` wrote down before the op was there."""
+    b, s, rope = k_rope.shape
+    q = jnp.transpose(q.reshape(b, s, heads, nope + rope), (0, 2, 1, 3))
+    kv = jnp.transpose(kv.reshape(b, s, heads, nope + v), (0, 2, 1, 3))
+    q_rope = _rotate(q[:, :, :, nope:], None, freq, True)
+    k_rope = _rotate(k_rope, None, freq, True)
+    k_rope = jnp.broadcast_to(k_rope[:, None], (b, heads, s, rope))
+    q = jnp.concatenate([q[:, :, :, :nope], q_rope], -1)
+    k = jnp.concatenate([kv[:, :, :, :nope], k_rope], -1)
+    return q, k, kv[:, :, :, nope:]
+
+
+def mla_heads(q, kv, k_rope, num_heads, qk_nope_head_dim, v_head_dim,
+              theta=10000.0, name=None):
+    """A latent attention's three projected arrays as the attention op
+    takes its heads (DeepSeek-V2, arXiv:2405.04434 section 2.1): ``q`` [B,
+    S, num_heads * (nope + rope)], a head's lanes without positions then
+    its rotary lanes; ``kv`` [B, S, num_heads * (nope + v)], a head's
+    ``k_nope`` then its value; ``k_rope`` [B, S, rope], the ONE rotary key
+    head. Returns ``(q, k, v)``: [B, num_heads, S, nope + rope] twice and
+    [B, num_heads, S, v]. The rotary lanes are
+    ``rotary_embedding(interleaved=True)``'s at positions 0, 1, ...
+    (neighbouring pairs, the halves returned apart; float32 inside, one
+    rounding), every other lane is moved as it is, and the rotated key
+    head stands behind every head's ``k_nope``.
+
+    On one TPU, where the rotary part is 64 lanes, ``nope`` and ``v`` whole
+    128-lane tiles, the heads even and the rows whole row tiles, the kernel
+    pair of ``ops/pallas/mla_heads.py``: one read of each input and one
+    write of each result each way, the key head's gradient summed over the
+    heads in VMEM (PERF.md section 6, PR 48). Else the composition,
+    ``_mla_heads`` above. Counters ``mla_heads.kernel_traced`` /
+    ``mla_heads.xla_traced``."""
+    heads, nope, v = int(num_heads), int(qk_nope_head_dim), int(v_head_dim)
+    if q.ndim != 3 or k_rope.ndim != 3 or heads < 1:
+        raise ValueError(f"mla_heads: q {tuple(q.shape)} and k_rope "
+                         f"{tuple(k_rope.shape)} are not [B, S, width]")
+    b, s, rope = k_rope.shape
+    if tuple(q.shape) != (b, s, heads * (nope + rope)) \
+            or tuple(kv.shape) != (b, s, heads * (nope + v)):
+        raise ValueError(
+            f"mla_heads: {heads} heads of {nope} + {rope} and {nope} + {v} "
+            f"lanes over k_rope's [{b}, {s}] rows are q [{b}, {s}, "
+            f"{heads * (nope + rope)}] and kv [{b}, {s}, "
+            f"{heads * (nope + v)}]; got {tuple(q.shape)} and "
+            f"{tuple(kv.shape)}")
+    freq = tuple(_rotary_frequencies(rope, theta, "mla_heads").tolist())
+    from .. import monitor
+    from . import pallas
+    kernel = (pallas.enabled("mla_heads")     # read off the call
+              and pallas.mla_heads_mod.supported(
+                  tuple(q.shape), tuple(kv.shape), tuple(k_rope.shape),
+                  heads, nope, v, [t.dtype for t in (q, kv, k_rope)]))
+    monitor.counter("mla_heads.kernel_traced" if kernel
+                    else "mla_heads.xla_traced").inc()
+    with _pscope("F.mla_heads"):
+        return apply(pallas.mla_heads_mod.mla_heads if kernel
+                     else _mla_heads, (q, kv, k_rope),
+                     dict(heads=heads, nope=nope, v=v, freq=freq), n_out=3,
+                     name="mla_heads")
+
+
 def interpolate(x, size=None, scale_factor=None, mode="nearest",
                 align_corners=False, data_format="NCHW", name=None):
     """reference: interpolate_op.cc (nearest/bilinear)."""

@@ -33,7 +33,7 @@ _overrides = {}
 _KERNELS = ("layer_norm", "flash_attention", "softmax_xent", "batch_norm",
             "ssd_scan", "causal_conv1d", "gated_rms_norm",
             "moe_scatter_add", "gated_short_conv", "moe_grouped",
-            "qk_heads", "dsa_select", "dsa_kl")
+            "qk_heads", "dsa_select", "dsa_kl", "mla_heads")
 
 # Auto defaults from one builder-run v5e ablation (2026-07-31, superseded
 # toolchain, not reproduced — docs/performance.md carries the table):
@@ -111,12 +111,30 @@ _KERNELS = ("layer_norm", "flash_attention", "softmax_xent", "batch_norm",
 # positions one a row; lfm2's heads of 64 keep the composition); both
 # kernels are module-level jax.jits, one lowering a distinct shape and
 # staging.
+# mla_heads: on, measured on the same v5e (PERF.md section 6, PR 48).
+# F.mla_heads takes a latent attention's q_b_proj and kv_b_proj results and
+# the one rotary key head to the flash call's q, K [B, H, S, 192] and V
+# [B, H, S, 128] in one pass each way: the interleaved rotation of each
+# head's 64 rotary lanes (de-interleaved by a product with a 0/1 matrix in
+# VMEM), the key head behind every head's k_nope, K split from V, the
+# transposes in the index maps, the key head's gradient summed over the
+# heads in VMEM. In joyai_llm_flash.causal_pretrain (8,192 rows x 32 heads
+# of 128 + 64 | 128 + 128, six latent attentions, every block recomputed)
+# mla_heads_fwd reads 0.80-0.89 ms a call and mla_heads_bwd 0.85 (504 MB a
+# call, 570 as HBM stores the 192-wide heads in 256 lanes: 83 % of the HBM
+# peak), 15.2 ms a step in eighteen calls, where the chain of XLA
+# transposes, slices, rotations, the key head's broadcast and the
+# concatenations took 57.5; the latent attentions fell from 242.6 to 200.0
+# ms of a step and the step from 381.2 to 339.5 (tokens/s +12.2 %). The op
+# looks at the call (mla_heads.supported: a rotary part of 64 lanes, nope
+# and v whole 128-lane tiles, heads in pairs, rows whole row tiles); both
+# kernels are module-level jax.jits.
 _AUTO_ON = {"layer_norm": True, "flash_attention": True,
             "softmax_xent": False, "batch_norm": False, "ssd_scan": True,
             "causal_conv1d": True, "gated_rms_norm": True,
             "moe_scatter_add": True, "gated_short_conv": True,
             "moe_grouped": True, "qk_heads": True, "dsa_select": True,
-            "dsa_kl": True}
+            "dsa_kl": True, "mla_heads": True}
 
 
 # flash is an O(S^2)-score win: below some sequence length the XLA sdpa
@@ -166,8 +184,8 @@ def configure(flash_min_seq=_UNSET, **kernels):
     auto default for named kernels ('layer_norm', 'flash_attention',
     'softmax_xent', 'batch_norm', 'ssd_scan', 'causal_conv1d',
     'gated_rms_norm', 'moe_scatter_add', 'gated_short_conv',
-    'moe_grouped', 'qk_heads', 'dsa_select', 'dsa_kl'); any other
-    name raises
+    'moe_grouped', 'qk_heads', 'dsa_select', 'dsa_kl', 'mla_heads'); any
+    other name raises
     ValueError. None restores auto.
     flash_min_seq=N routes sequences shorter than N to XLA sdpa even
     with the flash kernel enabled (N=0 disables the gate);
@@ -217,6 +235,7 @@ from . import moe_scatter_add as moe_scatter_add_mod
 from . import moe_grouped as moe_grouped_mod
 from . import qk_heads as qk_heads_mod
 from . import dsa as dsa_mod
+from . import mla_heads as mla_heads_mod
 
 from .layer_norm import layer_norm
 from .softmax_xent import softmax_cross_entropy

@@ -15,7 +15,7 @@ it (base name and result types), its operand types, its phase and regions.
 Instructions with one name, one signature and one set of regions are
 added up. The table goes to ``chiprun_out/ops/<tag>.<cell>.txt`` and its
 first ``--top`` lines to standard output, with the kernel counters
-(``monitor.snapshot`` of ``qk_heads``, ``flash_attention``,
+(``monitor.snapshot`` of ``qk_heads``, ``mla_heads``, ``flash_attention``,
 ``moe_experts``) behind them. **Read no set-up number from such a run**: under
 ``runpy`` a step traces and lowers 1.7 x as slowly (PERF.md section 6, PR 41).
 """
@@ -27,7 +27,7 @@ import sys
 
 HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _INSTANCE = re.compile(r"_\d+(?=/|$)")
-COUNTERS = ("qk_heads", "flash_attention", "moe_experts")
+COUNTERS = ("qk_heads", "mla_heads", "flash_attention", "moe_experts")
 
 
 def table(root, cell, top):

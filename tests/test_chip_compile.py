@@ -943,6 +943,51 @@ def test_qk_heads_kernels_compile_where_supported(one_chip, compiled_kernels,
     assert len(took) == 2 and 0 < max(took) <= 16 * 2 ** 20
 
 
+# a latent attention's projections to the flash kernels' heads
+# (ops/pallas/mla_heads.py) at the joyai cell's size, and in float32 at two
+# lane tiles a width (the gathers' products at the highest precision): the
+# two kernels and, outside them, nothing of an array's size but the bitcasts
+@pytest.mark.parametrize("s,heads,nope,v,dtype", [
+    (8192, 32, 128, 128, jnp.bfloat16), (512, 2, 256, 256, jnp.float32)],
+    ids=["joyai", "f32-256"])
+def test_mla_heads_kernels_compile_where_supported(one_chip, compiled_kernels,
+                                                   s, heads, nope, v, dtype):
+    from paddle_tpu.ops import nn_ops
+    from paddle_tpu.ops.pallas import mla_heads as K
+    ins = [(1, s, heads * (nope + 64)), (1, s, heads * (nope + v)),
+           (1, s, 64)]
+    outs = [(1, heads, s, nope + 64), (1, heads, s, nope + 64),
+            (1, heads, s, v)]
+    assert K.supported(*ins, heads, nope, v, [dtype] * 3)
+    attrs = dict(heads=heads, nope=nope, v=v, freq=tuple(
+        nn_ops._rotary_frequencies(64, 1e4, "test").tolist()))
+
+    def both(gs, *xs):
+        y, vjp = jax.vjp(lambda *a: K.mla_heads(*a, **attrs), *xs)
+        return y, vjp(tuple(gs))
+
+    text = _compiled_text(
+        both, one_chip, tuple((shape, dtype) for shape in outs),
+        *((shape, dtype) for shape in ins),
+        names=("mla_heads_fwd", "mla_heads_bwd"))
+    assert text.count("tpu_custom_call") == 2
+    entry = text[text.index("\nENTRY "):]
+    assert " transpose(" not in entry and " concatenate(" not in entry
+    name = "bf16" if dtype == jnp.bfloat16 else "f32"
+    # fusions that write an array of a result's size would be the chain's
+    assert not [line for line in entry.splitlines() if " fusion(" in line
+                and any(shape[-2:] == (s, d) for d in (nope + 64, v)
+                        for shape in _shapes(line.split(" fusion(")[0],
+                                             name))]
+    took = [int(re.search(_SCOPED % "used_scoped_memory_configs",
+                          line).group(1))
+            for line in text.splitlines()
+            if "tpu_custom_call" in line and " custom-call(" in line]
+    limit = K._block_bytes(K._rows(s, nope, v, jnp.dtype(dtype).itemsize),
+                           nope, v, jnp.dtype(dtype).itemsize) + 8 * 2 ** 20
+    assert len(took) == 2 and 0 < max(took) <= limit
+
+
 @pytest.mark.parametrize("b,s,d,groups", [(2, 1024, 1024, 8), (1, 384, 2048, 1),
                                           (1, 128, 256, 1)],
                          ids=["2x1024x1024-G8", "384x2048-G1", "128x256-G1"])
@@ -1085,6 +1130,7 @@ KERNEL_NAMES = {
                            "flash_bd_fwd", "flash_bd_bwd"],
     "gated_rms_norm.py": ["gated_norm_fwd", "gated_norm_bwd"],
     "layer_norm.py": ["layer_norm_fwd", "layer_norm_bwd"],
+    "mla_heads.py": ["mla_heads_fwd", "mla_heads_bwd"],
     "moe_grouped.py": ["moe_hidden", "moe_gmm", "moe_hidden_bwd",
                        "moe_tgmm"],
     "moe_scatter_add.py": ["moe_scatter_add"],
@@ -1128,7 +1174,7 @@ def test_no_pallas_call_site_is_left_out_and_no_name_is_used_twice():
     found = {f: names for f, names in found.items() if names}
     assert found == KERNEL_NAMES
     every = [n for names in found.values() for n in names]
-    assert len(every) == len(set(every)) == 33
+    assert len(every) == len(set(every)) == 35
 
 
 # a registered name switches the kernels of the file of its name; where two

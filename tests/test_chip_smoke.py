@@ -40,14 +40,15 @@ def test_phase_kernels_tiny_interpret(cs, capsys):
     # layer norm f32+bf16, flash x3, at latent attention's head sizes and
     # under the block-diffusion structure and a sliding window, the scan,
     # the convolution, the gated short convolution, the gated norm, the
-    # projection-to-heads pair, the experts' scatter-add, the experts'
-    # grouped products, a learned selection against a sort and the two
-    # kernels under it
-    assert len(out) == 17
+    # projection-to-heads pair, the latent heads' pair, the experts'
+    # scatter-add, the experts' grouped products, a learned selection
+    # against a sort and the two kernels under it
+    assert len(out) == 18
     assert any("dsa_select[2x128,4x64,top32]" in l
                and "differ_from_a_sort=0" in l for l in out)
     assert any("sparse_attention[2x2x128x128,4x64,top32" in l for l in out)
     assert any("qk_heads[2x128x2x128,norm+rotary" in l for l in out)
+    assert any("mla_heads[2x128x2x(128+64|128+128)" in l for l in out)
     assert any("moe_grouped[64x128,4x128gated" in l for l in out)
     assert any("gated_short_conv[2x128x3x" in l for l in out)
     assert any("x64,bf16,window32" in l for l in out)
