@@ -415,15 +415,18 @@ def phase_kernels(rows, hidden, batch, heads, seq, head_dim,
 
     # a learned sparse attention over (2, heads, seq, 128) bf16 with an
     # indexer of 4 heads of 64 that keeps a quarter of the keys a row: the
-    # selection kernel against its definition (a sort), then attention
-    # under that ONE selection and the indexer's loss, the three kernels
-    # against the definition routes
+    # selection kernel against its definition (a sort; both leave the
+    # selection as packed bits), then attention under that ONE selection,
+    # unpacked, and the indexer's loss, the three kernels against the
+    # definition routes. The sequence is the least the selection kernel
+    # takes: whole chunks of keys in each bit of a packed element
     from paddle_tpu.ops import sparse_attention as sa
     from paddle_tpu.ops.pallas import dsa
     from paddle_tpu.ops.pallas.flash_attention import _flash_sel
-    hi, di, top_k = 4, 64, seq // 4
     tiles = dict(rows=dsa.SELECT_ROWS, chunk=dsa.SELECT_CHUNK) \
         if on_chip else dict(rows=32, chunk=128)
+    seq = max(seq, sa.PACK * tiles["chunk"])
+    hi, di, top_k = 4, 64, seq // 4
     if not (dsa.select_supported((2, hi, seq, di), **tiles)
             and dsa.kl_supported((2, heads, seq, 128), (2, hi, seq, di),
                                  min(dsa.KL_BLOCK, seq))):
@@ -432,9 +435,13 @@ def phase_kernels(rows, hidden, batch, heads, seq, head_dim,
     index_args = (jnp.asarray(rng.randn(2, hi, seq, di), jnp.bfloat16),
                   jnp.asarray(rng.randn(2, seq, di), jnp.bfloat16),
                   jnp.asarray(rng.randn(2, seq, hi) / 16.0, jnp.float32))
-    selected, lse, _, pairs = jax.jit(
-        lambda *a: dsa.select(*a, top_k=top_k, **tiles))(*index_args)
-    want = jax.jit(lambda *a: sa._select(*a, top_k=top_k))(*index_args)
+
+    def select(route, **tiles):
+        bits, *rest = route(*index_args, top_k=top_k, **tiles)
+        return (sa._unpack(bits, seq), *rest)
+
+    selected, lse, _, pairs = jax.jit(lambda: select(dsa.select, **tiles))()
+    want = jax.jit(lambda: select(sa._select))()
     apart = int(jnp.sum(selected != want[0]))
     say("kernels", kernel=f"dsa_select[2x{seq},{hi}x{di},top{top_k}]",
         selected_pairs=int(jnp.sum(pairs)), differ_from_a_sort=apart)

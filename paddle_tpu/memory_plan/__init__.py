@@ -14,9 +14,9 @@ an auto-picker that turns the predicted-peak model into decisions:
   Between ``"full"`` and ``"dots"`` stands what ``jit.recompute(layer,
   x)`` means when no policy is named, ``KERNEL_RESULTS``: the block's
   activations are made again, a kernel's saved result (the flash
-  kernels' o and statistic rows, and the loss and three gradients of
-  ``F.dsa_indexer_loss``'s one pass, which they leave under names) is
-  not.
+  kernels' o and statistic rows, the loss and three gradients of
+  ``F.dsa_indexer_loss``'s one pass and the packed bits of
+  ``F.dsa_select``'s selection, which they leave under names) is not.
   ``remat=`` and the layer hook always name ``"full"`` or ``"dots"``.
 * **Optimizer-state host offload** (``offload``): pages the flat
   ``ParamArena`` Adam moments to host RAM after each apply and
@@ -184,9 +184,9 @@ def _kernel_results_policy():
     # Mosaic lowering) of its own of a kernel behind a module-level jit
     import jax
     from ..ops.pallas.flash_attention import RESULT_NAMES
-    from ..ops.sparse_attention import RESULT_NAMES as INDEXER_LOSS_NAMES
+    from ..ops import sparse_attention as sa    # the loss's pass, the bits
     return jax.checkpoint_policies.save_only_these_names(
-        *RESULT_NAMES, *INDEXER_LOSS_NAMES)
+        *RESULT_NAMES, *sa.RESULT_NAMES, *sa.SELECTION_NAMES)
 
 
 def checkpoint_policy(name):
@@ -194,9 +194,9 @@ def checkpoint_policy(name):
     ``"full"`` → None (save nothing but the inputs), ``"dots"`` →
     ``jax.checkpoint_policies.checkpoint_dots`` (save matmul outputs,
     recompute the elementwise tail), ``KERNEL_RESULTS`` →
-    ``save_only_these_names`` over the names the flash kernels give
-    their results (a block without such a call saves what ``"full"``
-    saves). Callers only reach here when a
+    ``save_only_these_names`` over the names the flash kernels, the
+    indexer's loss and the selection give their results (a block without
+    such a call saves what ``"full"`` saves). Callers only reach here when a
     checkpoint is actually being placed — ``"none"`` means *no*
     ``jax.checkpoint`` at all, which is not this function's job."""
     if name in (None, "none", "full"):

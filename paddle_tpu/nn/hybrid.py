@@ -220,12 +220,12 @@ class SparseGroupedQueryAttention(GroupedQueryAttention):
     head), both rotated by the FIRST axis of the positions over the whole
     head (halves paired); ``w = n W_w / sqrt(indexer_heads *
     indexer_head_dim)``; ``I(t, s) = sum_j w[t, j] relu(qI[t, j] . kI[s])``.
-    ``F.dsa_select`` makes the selection, ``flash_attention(selected=)``
-    attends under it, ``F.dsa_indexer_loss`` gives the layer's loss.
-    ``forward(x, positions)`` returns ``(y, loss)``: the loss reaches
-    ``indexer_q``, ``indexer_k``, ``indexer_k_norm`` and ``indexer_w``
-    alone, and nothing else reaches them (the selection has no gradient).
-
+    ``F.dsa_select`` makes the selection (once a layer: it leaves it as
+    packed bits under a name, which a recomputed block's replay unpacks),
+    ``flash_attention(selected=)`` attends under it, ``F.dsa_indexer_loss``
+    gives the layer's loss. ``forward(x, positions)`` returns ``(y, loss)``:
+    the loss reaches the four ``indexer_*`` layers alone, and nothing else
+    reaches them (the selection has no gradient).
     ``stats`` (a buffer, int32[2]) adds up, inside a compiled step, the
     selected and the causal (row, key) pairs of every call, in units of
     ``max(1, S // 16)`` pairs so that int32 holds a run's total:

@@ -8,9 +8,14 @@ flipped where the sign is set, order as the numbers do). It makes the
 scores chunk of keys by chunk (sixteen 64-deep products a chunk, a ReLU and
 a weight each), finds each row's ``top_k``-th largest EXACTLY by building
 the threshold's 32 bits from the top, one counting pass over the held
-scores a bit, and writes the selection as int8 beside the threshold, the
-selected scores' log-sum-exp and their number. The ``[S, S]`` scores never
-reach HBM. Only the chunks up to the block's diagonal are made or counted.
+scores a bit, and writes the selection as PACKED BITS (``ops/
+sparse_attention.py: _pack``: int8 ``[B, 1, S, S / 8]``, bit ``b`` of
+element ``[t, c]`` is key ``b * S / 8 + c``, so an element is made of eight
+whole chunks of the held scores, a shift and an or each; 256 KiB a program
+where the int8 selection was 2 MiB) beside the threshold, the selected
+scores' log-sum-exp and their number. The ``[S, S]`` scores never reach
+HBM. Only the chunks up to the block's diagonal are made, counted or
+packed; the bits of the others are 0.
 
 Both calls are behind module-level ``jax.jit``s (one lowering a distinct
 shape however many layers and replays call them, PERF.md section 6, PR 38).
@@ -50,19 +55,23 @@ def _unordered(key):
 
 def select_supported(qi_shape, rows=SELECT_ROWS, chunk=SELECT_CHUNK):
     """Whether the select kernel's tiles fit ``qi`` [B, Hi, S, Di]: whole
-    row blocks and key chunks."""
+    row blocks, and whole key chunks in each of the ``PACK`` column chunks
+    that share a packed element."""
+    from ..sparse_attention import PACK
     s = qi_shape[2]
-    return s % rows == 0 and s % chunk == 0 and chunk % rows == 0 \
+    return s % rows == 0 and s % (PACK * chunk) == 0 and chunk % rows == 0 \
         and chunk % _LANES == 0
 
 
 def _select_kernel(qi_ref, ki_ref, w_ref, sel_ref, tau_ref, lse_ref, cnt_ref,
-                   key_ref, *, rows, chunk, heads, top_k):
+                   key_ref, bits_ref, *, rows, chunk, heads, top_k):
     # qi_ref (1, Hi, R, Di); ki_ref (1, S, Di); w_ref (1, R, Hi);
-    # sel_ref (1, 1, R, S) int8; tau / lse / cnt (1, 1, R);
-    # key_ref (R, S) int32 scratch: the block's scores as ordered integers
+    # sel_ref (1, 1, R, S / PACK) int8, the packed selection; tau / lse /
+    # cnt (1, 1, R); key_ref (R, S) int32 scratch: the block's scores as
+    # ordered integers; bits_ref (R, S / PACK) int32 scratch: the packed
+    # elements while they are or-ed together
     i = pl.program_id(1)
-    total = sel_ref.shape[3] // chunk
+    wide = sel_ref.shape[3] // chunk                # chunks a packed row
     live = ((i + 1) * rows + chunk - 1) // chunk    # chunks up to the diagonal
     row_at = i * rows + jax.lax.broadcasted_iota(_I32, (rows, 1), 0)
 
@@ -117,22 +126,24 @@ def _select_kernel(qi_ref, ki_ref, w_ref, sel_ref, tau_ref, lse_ref, cnt_ref,
     # a row with no more than top_k causal keys keeps them all
     tau = jnp.where(row_at + 1 <= top_k, _INT_MIN, tau)
 
+    # chunk c is bit c // wide of the packed columns of chunk c % wide; a
+    # chunk past ``live`` is never made and leaves its bit 0
+    bits_ref[...] = jnp.zeros(bits_ref.shape, _I32)
+
     def write(c, carry):
         total_exp, n = carry
         key = key_ref[:, cols(c)]
         keep = (key >= tau) & causal(c)
-        sel_ref[0, 0, :, cols(c)] = keep.astype(jnp.int8)
+        at = cols(c % wide)
+        bits_ref[:, at] = bits_ref[:, at] | jnp.where(
+            keep, jnp.int32(1) << (c // wide), 0)
         e = jnp.where(keep, jnp.exp(_unordered(key) - top), 0.0)
         return (total_exp + jnp.sum(e, axis=1, keepdims=True),
                 n + jnp.sum(jnp.where(keep, 1.0, 0.0), axis=1, keepdims=True))
 
     total_exp, n = jax.lax.fori_loop(
         0, live, write, (jnp.zeros((rows, 1), _F32),) * 2)
-
-    def blank(c, _):
-        sel_ref[0, 0, :, cols(c)] = jnp.zeros((rows, chunk), jnp.int8)
-
-    jax.lax.fori_loop(live, total, blank, None)
+    sel_ref[0, 0] = bits_ref[...].astype(jnp.int8)  # bit 7 is int8's sign
     tau_ref[0] = _stat_row(jnp.where(tau == _INT_MIN, -jnp.inf,
                                      _unordered(tau)))
     lse_ref[0] = _stat_row(top + jnp.log(total_exp))
@@ -142,7 +153,9 @@ def _select_kernel(qi_ref, ki_ref, w_ref, sel_ref, tau_ref, lse_ref, cnt_ref,
 @functools.partial(jax.jit, static_argnames=("top_k", "rows", "chunk",
                                              "interpret"))
 def _select_call(qi, ki, w, *, top_k, rows, chunk, interpret):
+    from ..sparse_attention import PACK
     b, heads, s, d = qi.shape
+    width = s // PACK
     row = pl.BlockSpec((1, 1, rows), lambda n, i: (n, 0, i),
                        memory_space=pltpu.VMEM)
     stat = jax.ShapeDtypeStruct((b, 1, s), _F32)
@@ -159,18 +172,21 @@ def _select_call(qi, ki, w, *, top_k, rows, chunk, interpret):
                          memory_space=pltpu.VMEM),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, rows, s), lambda n, i: (n, 0, i, 0),
+            pl.BlockSpec((1, 1, rows, width), lambda n, i: (n, 0, i, 0),
                          memory_space=pltpu.VMEM),
             row, row, row,
         ],
-        out_shape=[jax.ShapeDtypeStruct((b, 1, s, s), jnp.int8),
+        out_shape=[jax.ShapeDtypeStruct((b, 1, s, width), jnp.int8),
                    stat, stat, stat],
-        scratch_shapes=[pltpu.VMEM((rows, s), _I32)],
+        scratch_shapes=[pltpu.VMEM((rows, s), _I32),
+                        pltpu.VMEM((rows, width), _I32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
+            # the held scores, the packed elements as int32 and two
+            # buffers of them as int8, kI's two buffers (64 of 128 lanes)
             vmem_limit_bytes=int(
-                max(16 * 2 ** 20, rows * s * (4 + 2 * 1) + 4 * s * 128 * 2
-                    + 16 * 2 ** 20))),
+                max(16 * 2 ** 20, rows * s * 4 + rows * width * (4 + 2 * 1)
+                    + 4 * s * 128 * 2 + 16 * 2 ** 20))),
         interpret=interpret,
         name="dsa_select",
     )(qi, ki, w)
@@ -180,11 +196,10 @@ def select(qi, ki, w, *, top_k, rows=SELECT_ROWS, chunk=SELECT_CHUNK):
     """``ops/sparse_attention.py: _select`` through the kernel; same
     arguments, same results. The shapes have to be ``select_supported``."""
     from . import interpret_mode
-    from ..sparse_attention import _select_results
-    sel, tau, lse, n = _select_call(qi, ki, w, top_k=int(top_k), rows=rows,
-                                    chunk=chunk, interpret=interpret_mode())
+    bits, tau, lse, n = _select_call(qi, ki, w, top_k=int(top_k), rows=rows,
+                                     chunk=chunk, interpret=interpret_mode())
     count = jnp.sum(n[:, 0].astype(jnp.int32), axis=-1)
-    return _select_results(sel, lse[:, 0], tau[:, 0], count)
+    return bits, lse[:, 0], tau[:, 0], count
 
 
 KL_BLOCK = 512          # rows and keys a tile of the loss's pass

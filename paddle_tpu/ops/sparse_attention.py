@@ -13,7 +13,16 @@ No reference counterpart in Paddle Fluid 1.7. Three ops, one layer's worth:
     array ``[B, 1, S, S]`` that ``flash_attention(selected=...)`` reads.
     Written as a threshold so that ties keep every key at ``tau``. Also
     ``lse[t] = logsumexp`` of the selected scores, for the loss below. No
-    gradient: a selection has none.
+    gradient: a selection has none. Both routes make the selection as
+    PACKED BITS, one a (row, key) pair (``_pack``: int8 ``[B, 1, S, S / 8]``,
+    bit ``b`` of element ``[t, c]`` is key ``b * S / 8 + c``, so packing and
+    unpacking move whole column chunks and nothing is laid out anew), and
+    the op leaves that array under the name ``SELECTION_NAMES[0]`` and
+    unpacks the int8 array from it. A recomputed block whose policy keeps
+    the name (``jit.recompute``'s default) replays from the kept bits, 1/8
+    of the int8 array, and makes no second selection: the backward of the
+    flash call is the one reader the replay has, ``lse`` feeds a pass that
+    is itself kept by name, the count a forward-only counter.
 
 ``flash_attention(q, k, v, causal=True, selected=...)``
     (``ops/pallas/flash_attention.py``) attention over the selected keys;
@@ -36,7 +45,8 @@ Each op has the definition route below (plain ``jax.numpy`` in row blocks;
 a CPU, a mesh, shapes the tiles do not fit) and, on one TPU, the kernels of
 ``ops/pallas/dsa.py``; counters ``dsa.select.kernel_traced`` /
 ``.xla_traced`` and ``dsa.kl.kernel_traced`` / ``.xla_traced`` say which a
-call site traced. Layouts are the kernels': ``qi`` ``[B, Hi, S, Di]``,
+call site traced, ``dsa.select.results_named`` that a selection's bits got
+their name. Layouts are the kernels': ``qi`` ``[B, Hi, S, Di]``,
 ``ki`` ``[B, S, Di]``, ``w`` ``[B, S, Hi]``.
 """
 from __future__ import annotations
@@ -56,6 +66,9 @@ _F32 = jnp.float32
 # what a recomputed block keeps of the indexer's loss: the loss and the
 # three gradients its one pass made
 RESULT_NAMES = ("dsa_kl_results",)
+# and of the selection: its packed bits
+SELECTION_NAMES = ("dsa_selection_bits",)
+PACK = 8                # keys an element of the packed selection
 _ROW_BLOCK = 512        # rows a block of the definition routes
 
 
@@ -82,6 +95,34 @@ def _causal(r0, rows, keys):
     return jnp.arange(keys)[None, :] <= at
 
 
+def packed_width(s):
+    """Elements a row of the packed selection over ``s`` keys."""
+    return -(-s // PACK)
+
+
+def _pack(sel):
+    """bool ``[..., R, S]`` -> int8 ``[..., R, packed_width(S)]``: bit ``b``
+    of element ``[r, c]`` is key ``b * width + c`` of row ``r`` (keys past
+    ``S`` read 0). Keys a width apart share an element, so the packed
+    array is ``PACK`` column chunks shifted and or-ed, whole."""
+    s = sel.shape[-1]
+    width = packed_width(s)
+    sel = jnp.pad(sel, [(0, 0)] * (sel.ndim - 1) + [(0, PACK * width - s)])
+    packed = jnp.zeros((*sel.shape[:-1], width), jnp.int8)
+    for b in range(PACK):
+        chunk = sel[..., b * width:(b + 1) * width].astype(jnp.int8)
+        packed = packed | (chunk << b)
+    return packed
+
+
+def _unpack(packed, s):
+    """``_pack``'s inverse, as the int8 ``[..., R, s]`` the flash kernels
+    and the loss read: ``PACK`` shifted-and-masked copies of the packed
+    array side by side along the key axis."""
+    sel = jnp.concatenate([(packed >> b) & 1 for b in range(PACK)], axis=-1)
+    return sel[..., :s]
+
+
 def _select_block(qi, ki, w, r0, top_k):
     """(selected bool [R, S], tau [R], lse [R]) of one block of rows."""
     rows, s = qi.shape[1], ki.shape[0]
@@ -98,7 +139,9 @@ def _select_block(qi, ki, w, r0, top_k):
 
 
 def _select(qi, ki, w, *, top_k):
-    """The definition route of ``dsa_select``: a sort a block of rows."""
+    """The definition route of ``dsa_select``: a sort a block of rows.
+    ``(bits`` int8 [B, 1, S, packed_width(S)] (``_pack``), ``lse`` [B, S],
+    ``tau`` [B, S], ``pairs`` int32 [B]``)``."""
     b, _, s, _ = qi.shape
     block = _row_block(s)
 
@@ -108,21 +151,16 @@ def _select(qi, ki, w, *, top_k):
         def rows(at):
             cut = lambda t, axis: jax.lax.dynamic_slice_in_dim(
                 t, at, block, axis)
-            return _select_block(cut(qi, 1), ki, cut(w, 0), at, top_k)
+            sel, tau, lse = _select_block(cut(qi, 1), ki, cut(w, 0), at,
+                                          top_k)
+            return _pack(sel), tau, lse, jnp.sum(sel, dtype=jnp.int32)
 
-        sel, tau, lse = jax.lax.map(rows, jnp.arange(0, s, block))
-        return sel.reshape(s, s), tau.reshape(s), lse.reshape(s)
+        bits, tau, lse, count = jax.lax.map(rows, jnp.arange(0, s, block))
+        return (bits.reshape(s, -1), tau.reshape(s), lse.reshape(s),
+                jnp.sum(count))
 
-    sel, tau, lse = jax.lax.map(sequence, (qi, ki, w))
-    return _select_results(sel.astype(jnp.int8)[:, None], lse, tau)
-
-
-def _select_results(sel, lse, tau, count=None):
-    """What ``dsa_select`` returns, none of it differentiable: the
-    selection, ``lse``, ``tau`` and the selected pairs a sequence."""
-    if count is None:
-        count = jnp.sum(sel, axis=(1, 2, 3), dtype=jnp.int32)
-    return tuple(jax.lax.stop_gradient(t) for t in (sel, lse, tau, count))
+    bits, tau, lse, count = jax.lax.map(sequence, (qi, ki, w))
+    return bits[:, None], lse, tau, count
 
 
 def dsa_select(qi, ki, w, top_k, name=None):
@@ -137,6 +175,7 @@ def dsa_select(qi, ki, w, top_k, name=None):
             f"dsa_select: qi {tuple(qi.shape)} [B, Hi, S, Di], ki "
             f"{tuple(ki.shape)} [B, S, Di], w {tuple(w.shape)} [B, S, Hi], "
             f"top_k {top_k}")
+    from jax.ad_checkpoint import checkpoint_name
     from .. import monitor
     from . import pallas
     kernel = pallas.enabled("dsa_select") and pallas.dsa_mod.select_supported(
@@ -148,8 +187,14 @@ def dsa_select(qi, ki, w, top_k, name=None):
     def impl(*operands, top_k):
         # inside a recomputed block JAX differentiates the block whole: no
         # tangent may reach the kernel
-        return route(*(jax.lax.stop_gradient(t) for t in operands),
-                     top_k=top_k)
+        bits, lse, tau, pairs = route(
+            *(jax.lax.stop_gradient(t) for t in operands), top_k=top_k)
+        # the bits under their name, the int8 array from the NAMED value:
+        # a checkpoint that keeps the name replays the unpacking alone
+        monitor.counter("dsa.select.results_named").inc()
+        bits = checkpoint_name(bits, SELECTION_NAMES[0])
+        return tuple(jax.lax.stop_gradient(t) for t in (
+            _unpack(bits, operands[0].shape[2]), lse, tau, pairs))
 
     with _pscope("F.dsa_select"):
         return apply(impl, (qi, ki, w), dict(top_k=int(top_k)),

@@ -8,7 +8,11 @@ row; bfloat16), each kernel against what it is compared with:
 
 * ``F.dsa_select``: the kernel ``dsa_select`` (``ops/pallas/dsa.py``)
   against the definition route (a sort a block of rows), and the kernel
-  at other rows a program / keys a chunk;
+  at other rows a program / keys a chunk; the kernel's first result is the
+  PACKED selection, so the int8 array's unpacking is timed beside it, as
+  the forward has it (row-major) and as a recomputed block's replay has it
+  (transposed for ``flash_sel_bwd``), against the transpose of the int8
+  array that the replay made before;
 * attention under the selection: ``flash_sel_fwd`` / ``flash_sel_bwd``
   against the dense causal call's ``flash_fwd`` / ``flash_bwd`` at the
   same 32 heads (the masked walk's price over the dense walk), forward
@@ -92,7 +96,7 @@ def main(argv=None):
     dtype, iters = jnp.bfloat16, args.iters
     peaks = None
     if args.rehearse:
-        s, h, d, hi, di, top_k, dtype, iters = 256, 4, 128, 4, 8, 32, \
+        s, h, d, hi, di, top_k, dtype, iters = 1024, 4, 128, 4, 8, 32, \
             jnp.float32, 0
         cfg = dict(cfg, num_attention_heads=h, num_key_value_heads=h,
                    head_dim=d, sa_config=dict(
@@ -121,9 +125,18 @@ def main(argv=None):
     # -- the selection
     select = jax.jit(lambda *a: dsa.select(*a, top_k=top_k, rows=rows,
                                            chunk=chunk))
-    ms, (sel, lse, _, pairs) = timed(select, (qi, ki, w), iters)
+    ms, (bits, lse, _, pairs) = timed(select, (qi, ki, w), iters)
     line(f"dsa_select kernel {rows} x {chunk}", ms,
          keye_vl_costs.select_kernel_costs(cfg, s), peaks)
+    unpack = lambda bits: sa._unpack(bits, s)
+    ms, sel = timed(jax.jit(unpack), (bits,), iters)
+    line("  its bits unpacked to int8 [S, S]", ms)
+    ms, _ = timed(jax.jit(lambda bits: jnp.swapaxes(unpack(bits), 2, 3)),
+                  (bits,), iters)
+    line("  its bits unpacked to the transposed int8 [S, S]", ms)
+    ms, _ = timed(jax.jit(lambda sel: jnp.swapaxes(sel, 2, 3)), (sel,),
+                  iters)
+    line("  the int8 [S, S] transposed", ms)
     say(f"  selected {int(pairs[0])} of {s * (s + 1) // 2} causal pairs "
         f"({200.0 * int(pairs[0]) / (s * (s + 1)):.2f} %)")
     for r in [int(x) for x in args.select_rows.split(",") if x]:
@@ -138,7 +151,7 @@ def main(argv=None):
                         (qi, ki, w), iters)
         line("dsa_select definition route (a sort a row block)", ms)
         say(f"  its selection differs in "
-            f"{int(jnp.sum(out[0] != sel))} pairs")
+            f"{int(jnp.sum(unpack(out[0]) != sel))} pairs")
 
     # -- attention under it, against the dense causal call
     block_q, block_k = fa._blocks_that_fit(s, d, d, q.dtype.itemsize, 512,

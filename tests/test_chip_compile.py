@@ -381,8 +381,11 @@ def test_flash_under_a_selection_at_the_cells_size(one_chip,
 
 # the indexer's two kernels at the cell's size (16 heads of 64 over 16,384
 # rows): the selection holds a block of 128 rows' scores in VMEM and writes
-# int8; the loss's pass reads the selection transposed and leaves four
-# float32 results; neither makes a float S x S array
+# the selection as packed bits, 256 KiB a program (its VMEM allowance
+# follows that block: the held scores, the packed elements as int32 and
+# two int8 buffers of them), and nothing S x S; the loss's pass reads the
+# selection transposed and leaves four float32 results; neither makes a
+# float S x S array
 def test_the_indexers_kernels_at_the_cells_size(one_chip, compiled_kernels):
     from paddle_tpu.ops.pallas import dsa
     s = 16384
@@ -392,10 +395,13 @@ def test_the_indexers_kernels_at_the_cells_size(one_chip, compiled_kernels):
     text = _compiled_text(lambda *a: dsa.select(*a, top_k=2048), one_chip,
                           qi, ki, w, names=("dsa_select",))
     assert text.count("tpu_custom_call") == 1
-    assert "s8[1,1,16384,16384]" in text
-    assert not re.search(r"(f32|bf16)\[(\d+,)*16384,(\d+,)*16384[,\]]", text)
+    assert "s8[1,1,16384,2048]" in text
+    assert not re.search(r"\[(\d+,)*16384,(\d+,)*16384[,\]]", text)
     allowed, took = _vmem_of_kernels(text)["dsa_select"]
-    assert 8 * 2 ** 20 < took <= allowed < 64 * 2 ** 20
+    mib = 2 ** 20
+    held, packed = 128 * s * 4, 128 * (s // 8) * (4 + 2 * 1)
+    assert allowed == held + packed + 4 * s * 128 * 2 + 16 * mib
+    assert held + packed < took <= allowed < 48 * mib
     qk = ((1, 32, s, 128), jnp.bfloat16)
     stat = ((32, 1, s), jnp.float32)
     assert dsa.kl_supported(qk[0], qi[0])

@@ -44,9 +44,11 @@ def test_phase_kernels_tiny_interpret(cs, capsys):
     # scatter-add, the experts' grouped products, a learned selection
     # against a sort and the two kernels under it
     assert len(out) == 18
-    assert any("dsa_select[2x128,4x64,top32]" in l
+    # (at 1,024 positions: the least the selection kernel packs at chunks
+    # of 128 keys)
+    assert any("dsa_select[2x1024,4x64,top256]" in l
                and "differ_from_a_sort=0" in l for l in out)
-    assert any("sparse_attention[2x2x128x128,4x64,top32" in l for l in out)
+    assert any("sparse_attention[2x2x1024x128,4x64,top256" in l for l in out)
     assert any("qk_heads[2x128x2x128,norm+rotary" in l for l in out)
     assert any("mla_heads[2x128x2x(128+64|128+128)" in l for l in out)
     assert any("moe_grouped[64x128,4x128gated" in l for l in out)

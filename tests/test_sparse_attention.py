@@ -53,21 +53,71 @@ def _dense_scores(qi, ki, w):
 
 # -- the selection -------------------------------------------------------------
 
-@pytest.mark.parametrize("top_k", [16, 300], ids=["k16", "k_past_s"])
+def _plain_pack(sel):
+    """``sa._pack`` by its definition, element by element in numpy: bit b
+    of element [r, c] is key b * width + c."""
+    s = sel.shape[-1]
+    width = sa.packed_width(s)
+    padded = np.zeros((*sel.shape[:-1], sa.PACK * width), np.uint8)
+    padded[..., :s] = sel
+    bits = padded.reshape(*sel.shape[:-1], sa.PACK, width)
+    weights = (1 << np.arange(sa.PACK, dtype=np.uint8))[:, None]
+    return (bits * weights).sum(-2).astype(np.uint8).view(np.int8)
+
+
+# S 1,024 is the least the kernel takes at chunks of 128 keys (eight whole
+# chunks share a packed element); 24 and 100 (no whole element) are the
+# definition route's alone
+@pytest.mark.parametrize("s", [1024, 24, 100])
+@pytest.mark.parametrize("case", ["random", "ties", "short_rows"])
+def test_pack_and_unpack_are_each_others_inverse(case, s):
+    top_k = 8
+    if case == "random":
+        sel = np.random.default_rng(s).random((2, 1, s, s)) < 0.3
+    else:
+        _, _, _, qi, ki, w = _operands(b=1, s=s)
+        if case == "ties":      # three equal keys: rows with more than top_k
+            ki = ki.at[:, 7].set(ki[:, 3]).at[:, 11].set(ki[:, 3])
+            top_k = 4
+        else:                   # the first rows keep every causal key
+            top_k = s // 2
+        sel = np.asarray(sa._unpack(sa._select(qi, ki, w, top_k=top_k)[0],
+                                    s)) != 0
+        kept = sel[0, 0].sum(-1)
+        if case == "ties":
+            assert kept.max() > top_k
+        else:
+            assert (kept[:top_k] == np.arange(1, top_k + 1)).all()
+    assert dsa.select_supported((1, 4, s, 8), 32, 128) == (s == 1024)
+    packed = sa._pack(jnp.asarray(sel))
+    assert packed.dtype == jnp.int8 and packed.shape == (
+        *sel.shape[:-1], sa.packed_width(s))
+    assert np.array_equal(np.asarray(packed), _plain_pack(sel))
+    back = sa._unpack(packed, s)
+    assert back.dtype == jnp.int8 and np.array_equal(np.asarray(back), sel)
+
+
+@pytest.mark.parametrize("top_k", [16, 1100], ids=["k16", "k_past_s"])
 def test_select_kernel_and_definition_route_give_one_selection(top_k):
-    _, _, _, qi, ki, w = _operands(s=256)
+    _, _, _, qi, ki, w = _operands(s=1024)
     want = sa._select(qi, ki, w, top_k=top_k)
     got = dsa.select(qi, ki, w, top_k=top_k, rows=32, chunk=128)
-    for name, a, b in zip(("selected", "lse", "tau", "pairs"), got, want):
+    # the same PACKED array from both routes, and the op's int8 selection
+    assert got[0].dtype == jnp.int8 and got[0].shape == (2, 1, 1024, 128)
+    for name, a, b in zip(("bits", "lse", "tau", "pairs"), got, want):
         a, b = np.asarray(a), np.asarray(b)
-        if name in ("selected", "pairs"):
+        if name in ("bits", "pairs"):
             assert np.array_equal(a, b), name
         else:
             assert np.array_equal(np.isfinite(a), np.isfinite(b)), name
             np.testing.assert_allclose(np.where(np.isfinite(a), a, 0.0),
                                        np.where(np.isfinite(b), b, 0.0),
                                        atol=3e-6, err_msg=name)
-    sel = np.asarray(got[0])[:, 0] != 0
+    selected = F.dsa_select(*(pt.to_tensor(np.asarray(t))
+                              for t in (qi, ki, w)), top_k)[0].numpy()
+    assert selected.dtype == np.int8 and np.array_equal(
+        selected, np.asarray(sa._unpack(got[0], 1024)))
+    sel = selected[:, 0] != 0
     scores = np.asarray(_dense_scores(qi, ki, w))
     s = sel.shape[-1]
     assert not np.triu(sel, 1).any()            # never a key ahead
@@ -95,14 +145,15 @@ def test_tied_scores_keep_every_key_at_the_threshold(route):
     """Keys 3, 7 and 11 are one vector: their scores are equal in every
     row. Where they are the threshold the row keeps all three, so it holds
     more than ``top_k`` keys; ``-0.0`` and ``0.0`` are one score."""
-    _, _, _, qi, ki, w = _operands(b=1, s=128)
+    s = 128 if route == "xla" else 1024
+    _, _, _, qi, ki, w = _operands(b=1, s=s)
     ki = ki.at[:, 7].set(ki[:, 3]).at[:, 11].set(ki[:, 3])
     w = jnp.abs(w).at[:, :, 0].multiply(-1.0)       # a head that gives -0.0
     top_k = 4
     select = sa._select if route == "xla" else functools.partial(
         dsa.select, rows=32, chunk=128)
-    sel, _, tau, pairs = select(qi, ki, w, top_k=top_k)
-    sel = np.asarray(sel)[0, 0] != 0
+    bits, _, tau, pairs = select(qi, ki, w, top_k=top_k)
+    sel = np.asarray(sa._unpack(bits, s))[0, 0] != 0
     assert not np.triu(sel, 1).any()
     # the three are kept together or not at all, in every row that sees them
     assert (sel[11:, 3] == sel[11:, 7]).all()
@@ -125,8 +176,9 @@ def test_select_through_the_op_has_no_gradient_and_counts_its_route():
         (1, 1, 32, 32)
     assert all(t.stop_gradient for t in (selected, lse, tau, pairs))
     after = monitor.snapshot("dsa.select")
-    assert after.get("dsa.select.xla_traced", 0) \
-        == before.get("dsa.select.xla_traced", 0) + 1
+    # one route and one name a traced call
+    for counter in ("dsa.select.xla_traced", "dsa.select.results_named"):
+        assert after.get(counter, 0) == before.get(counter, 0) + 1, counter
     with pytest.raises(ValueError, match="dsa_select"):
         F.dsa_select(tensors[0], tensors[1], tensors[1], 8)
 
@@ -134,8 +186,11 @@ def test_select_through_the_op_has_no_gradient_and_counts_its_route():
 # -- attention under a selection ------------------------------------------------
 
 def _selection(top_k=24):
+    """``(selected int8 [B, 1, S, S], lse, tau, pairs)`` as the op gives
+    them."""
     _, _, _, qi, ki, w = _operands()
-    return sa._select(qi, ki, w, top_k=top_k)
+    bits, *rest = sa._select(qi, ki, w, top_k=top_k)
+    return (sa._unpack(bits, qi.shape[2]), *rest)
 
 
 def _sdpa(q, k, v, sel):
@@ -257,6 +312,45 @@ def test_indexer_loss_through_the_op_keeps_its_pass_under_a_checkpoint():
     assert count(kept) > 0
     text = lambda f: str(jax.make_jaxpr(jax.grad(f, (0, 1, 2)))(qi, ki, w))
     assert text(whole).count(" exp ") > text(kept).count(" exp ")
+
+
+def test_a_recomputed_block_keeps_the_selections_bits_and_selects_once():
+    """A block that selects, attends and takes the indexer's loss, as
+    ``jit.recompute`` runs one (the ops under ``no_grad``, JAX
+    differentiating the block whole): under its policy the gradient program
+    bears the selection's name and holds ONE selection (one sort on this
+    route), the replay unpacking the kept bits; a checkpoint with no policy
+    selects again."""
+    from paddle_tpu.memory_plan import checkpoint_policy, KERNEL_RESULTS
+    operands = _operands()
+    T = pt.Tensor
+
+    def block(q, k, v, qi, ki, w):
+        with pt.no_grad():
+            selected, lse, _, _ = F.dsa_select(T(qi), T(ki), T(w), 24)
+            o, m, l = flash_attention(T(q), T(k), T(v), causal=True,
+                                      selected=selected)
+            loss = F.dsa_indexer_loss(T(q), T(k), m, l, selected, T(qi),
+                                      T(ki), T(w), lse)
+        return jnp.sum(o.data) + loss.data
+
+    def program(f):
+        return str(jax.make_jaxpr(jax.grad(f, tuple(range(6))))(*operands))
+
+    keeping = jax.checkpoint(block, policy=checkpoint_policy(KERNEL_RESULTS))
+    before = monitor.snapshot("dsa.select").get("dsa.select.results_named",
+                                                 0)
+    kept = program(keeping)
+    # traced once: the replay is the forward's jaxpr, not a second trace
+    assert monitor.snapshot("dsa.select")["dsa.select.results_named"] \
+        == before + 1
+    whole = program(jax.checkpoint(block))
+    assert sa.SELECTION_NAMES[0] in kept
+    assert (kept.count(" sort["), whole.count(" sort[")) == (1, 2)
+    want = jax.grad(block, tuple(range(6)))(*operands)
+    got = jax.grad(keeping, tuple(range(6)))(*operands)
+    for name, a, b in zip(("q", "k", "v", "qi", "ki", "w"), got, want):
+        np.testing.assert_allclose(a, b, atol=1e-6, err_msg=name)
 
 
 # -- the layer -------------------------------------------------------------------
