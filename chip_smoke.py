@@ -103,13 +103,14 @@ def phase_kernels(rows, hidden, batch, heads, seq, head_dim,
     if shipped != ["causal_conv1d", "dsa_kl", "dsa_select",
                    "flash_attention", "gated_rms_norm", "gated_short_conv",
                    "layer_norm", "mla_heads", "moe_grouped",
-                   "moe_scatter_add", "qk_heads", "ssd_scan"]:
+                   "moe_scatter_add", "qk_heads", "selective_scan",
+                   "ssd_scan"]:
         raise AssertionError(f"_AUTO_ON ships {shipped}; this phase covers "
                              f"causal_conv1d, dsa_kl, dsa_select, "
                              f"flash_attention, gated_rms_norm, "
                              f"gated_short_conv, layer_norm, mla_heads, "
-                             f"moe_grouped, moe_scatter_add, qk_heads and "
-                             f"ssd_scan")
+                             f"moe_grouped, moe_scatter_add, qk_heads, "
+                             f"selective_scan and ssd_scan")
     on_chip = pt.device.is_tpu_backend()
     if on_chip and P.interpret_mode():
         raise AssertionError("interpret mode reachable on a TPU backend")
@@ -263,6 +264,30 @@ def phase_kernels(rows, hidden, batch, heads, seq, head_dim,
 
     run(f"ssd_scan[{batch}x{seq}x{h}x{p},state{n},bf16]", k_scan, r_scan,
         scan_args, 7, tol_bf16, 2)
+
+    # Mamba-1's selective scan over (batch, seq, 1,024 channels, state 16):
+    # a transition a (channel, state) pair, the positions walked on the
+    # vector unit, all six gradients
+    from paddle_tpu.ops.pallas import selective_scan as sscan
+    from paddle_tpu.ops.ssm import _selective_scan
+    wide, n1 = sscan.CHANNELS, 16
+    if not sscan.supported((batch, seq, wide), n1):
+        raise AssertionError("the selective scan's kernels would not take "
+                             "this shape")
+    own = np.random.RandomState(1)      # the checks below keep their draws
+    sel_args = (
+        jnp.asarray(own.randn(batch, seq, wide), jnp.bfloat16),
+        jnp.asarray(np.exp(own.uniform(np.log(1e-3), np.log(1e-1),
+                                       (batch, seq, wide))), jnp.float32),
+        jnp.asarray(-own.uniform(1.0, 16.0, (wide, n1)), jnp.float32),
+        jnp.asarray(own.randn(batch, seq, n1), jnp.bfloat16),
+        jnp.asarray(own.randn(batch, seq, n1), jnp.bfloat16),
+        jnp.asarray(own.randn(wide), jnp.float32),
+        jnp.asarray(own.randn(batch, seq, wide), jnp.float32))
+    run(f"selective_scan[{batch}x{seq}x{wide},state{n1},bf16]",
+        lambda *a: (sscan.selective_scan(*a[:6]) * a[6]).sum(),
+        lambda *a: (_selective_scan(*a[:6], chunk=sscan.CHUNK) * a[6]).sum(),
+        sel_args, 6, tol_bf16, 2)
 
     # the same mixer's convolution (x | B | C: h * p + 2 * heads * n
     # channels, 4 taps, SiLU) and its gated norm (h * p lanes in `heads`

@@ -42,6 +42,9 @@ def __getattr__(name):
     if name in ("Lfm2MoeConfig", "Lfm2MoeForCausalLM"):
         from . import lfm2
         return getattr(lfm2, name)
+    if name in ("Phi4FlashConfig", "Phi4FlashForCausalLM"):
+        from . import phi4_flash
+        return getattr(phi4_flash, name)
     if name in ("Transformer",):
         from . import transformer
         return getattr(transformer, name)

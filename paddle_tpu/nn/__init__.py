@@ -39,7 +39,8 @@ from .layers import HSigmoid  # noqa: F401
 from .moe import MoEFFN, RoutedMoE, GatedMLP, moe_aux_loss  # noqa: F401
 from .hybrid import (Mamba2Mixer, GatedShortConv,  # noqa: F401
                      GroupedQueryAttention, MultiHeadLatentAttention,
-                     SparseGroupedQueryAttention)
+                     SparseGroupedQueryAttention, MambaMixer,
+                     DifferentialAttention, GatedMemoryUnit)
 from ..fluid.dygraph import RowConv  # noqa: F401
 
 # paddle.nn 1.x functional tails (reference: python/paddle/nn/

@@ -1,10 +1,10 @@
-"""Sequence mixers of hybrid and latent-attention language models: a
-Mamba-2 state-space mixer, a gated short convolution, grouped-query
-attention and multi-head latent attention.
+"""Sequence mixers of hybrid and latent-attention language models: Mamba-1
+and Mamba-2 mixers, a gated short convolution, grouped-query, sparse,
+differential and multi-head latent attention, a gated memory unit.
 
 No reference counterpart in Paddle Fluid 1.7. All map ``[B, S, hidden]``
-to ``[B, S, hidden]`` with no bias, no dropout and no cache: the training
-path. (A state cache for serving is future work, PERF.md section 7.)
+to ``[B, S, hidden]``, no dropout, no cache: the training path; NO BIAS but
+in the phi4flash layers at the end of the file (PERF.md section 7: serving).
 """
 from __future__ import annotations
 
@@ -369,3 +369,168 @@ class MultiHeadLatentAttention(Layer):
         ctx = ctx.transpose([0, 2, 1, 3]).reshape(
             [b, s, self.num_heads * self.v_head_dim])
         return self.o_proj(ctx)
+
+
+# -- the phi4flash layers (decoder-hybrid-decoder, arXiv:2507.06607) --------
+# At the end of the file on purpose: the flash call sites above keep their
+# line numbers (a Mosaic kernel's cache key carries its caller's lines).
+
+__all__ += ["MambaMixer", "DifferentialAttention", "GatedMemoryUnit"]
+
+
+class MambaMixer(Layer):
+    """Mamba-1 mixer (Gu & Dao, arXiv:2312.00752) as the ``mamba`` /
+    ``phi4flash`` model codes lay it out: ``[x | z] = u W_in``; a causal
+    depthwise convolution of ``conv_kernel`` taps with bias and SiLU over
+    ``x``; ``[dt_r | B | C] = x W_x`` (``dt_rank + 2 state_size`` wide);
+    the step sizes ``softplus(dt_r W_dt + b_dt)``, one a (token, channel);
+    ``A = -exp(A_log)`` ``[inner, state_size]``, a transition for every
+    (channel, state) pair; the selective recurrence (``F.selective_scan``);
+    the gate ``silu(z)``; ``W_out``. Biases: the convolution's and
+    ``dt_proj``'s, no other. ``forward(u, return_memory=True)`` also
+    returns the scan's result BEFORE the gate, ``[B, S, inner]``: what a
+    decoder-hybrid-decoder's gated memory units read."""
+
+    def __init__(self, hidden_size, inner, state_size=16, conv_kernel=4,
+                 dt_rank=None, time_step_min=0.001, time_step_max=0.1,
+                 time_step_floor=1e-4):
+        super().__init__()
+        self.inner, self.state_size = inner, state_size
+        self.dt_rank = dt_rank or -(-hidden_size // 16)
+        self.in_proj = Linear(hidden_size, 2 * inner, bias_attr=False)
+        tap = 1.0 / math.sqrt(conv_kernel)
+        self.conv_weight = self.create_parameter(
+            (inner, conv_kernel), default_initializer=I.Uniform(-tap, tap))
+        self.conv_bias = self.create_parameter(
+            (inner,), default_initializer=I.Uniform(-tap, tap))
+        self.x_proj = Linear(inner, self.dt_rank + 2 * state_size,
+                             bias_attr=False)
+        self.dt_proj = Linear(self.dt_rank, inner)
+        # the step sizes start log-uniform in [min, max] (softplus^-1 of
+        # them in dt_proj's bias), A_log = log(1..N) in every channel, D = 1
+        rng = np.random.default_rng(0)
+        dt = np.maximum(np.exp(rng.uniform(math.log(time_step_min),
+                                           math.log(time_step_max), inner)),
+                        time_step_floor)
+        self.dt_proj.bias.set_value(
+            (dt + np.log(-np.expm1(-dt))).astype("float32"))
+        self.A_log = self.create_parameter(
+            (inner, state_size), default_initializer=I.Assign(np.tile(np.log(
+                np.arange(1, state_size + 1, dtype="float32")), (inner, 1))))
+        self.D = self.create_parameter(
+            (inner,), default_initializer=I.Constant(1.0))
+        self.out_proj = Linear(inner, hidden_size, bias_attr=False)
+
+    def forward(self, u, return_memory=False):
+        n, r = self.state_size, self.dt_rank
+        xz = self.in_proj(u)
+        x = S.causal_conv1d(xz[:, :, :self.inner], self.conv_weight,
+                            self.conv_bias, activation="silu")
+        dbc = self.x_proj(x)
+        gated, y = S.selective_scan(
+            x, F.linear(dbc[:, :, :r], self.dt_proj.weight), self.A_log,
+            dbc[:, :, r:r + n], dbc[:, :, r + n:], self.D,
+            dt_bias=self.dt_proj.bias, z=xz[:, :, self.inner:])
+        out = self.out_proj(gated)
+        return (out, y) if return_memory else out
+
+
+class DifferentialAttention(GroupedQueryAttention):
+    """Differential attention (Ye et al., arXiv:2410.05258) over
+    grouped-query heads, as the ``phi4flash`` model code has it: the
+    heads pair up as ``(2j, 2j + 1)``, query pair ``j`` reads key/value
+    pair ``j // r`` (``r = num_heads / num_kv_heads``), and a pair gives
+
+        A1 = softmax(mask(q_2j k_2g^T / sqrt(D))),  A2 = ... q_2j+1 k_2g+1^T
+        o_j = (1 - lambda_init) RMSNorm_2D((A1 - lambda A2) [v_2g | v_2g+1])
+
+    with ``lambda = exp(lq1 . lk1) - exp(lq2 . lk2) + lambda_init`` (four
+    learned ``[D]`` vectors a layer), ``lambda_init = 0.8 - 0.6 exp(-0.3
+    depth)`` by the layer's SOURCE index ``depth``, and ONE learned scale
+    ``[2 D]`` a layer in the pair norm (``subln``). One flash call of
+    ``num_heads`` heads at ``D`` | ``2 D`` computes both maps: the pairing
+    is in which K head and which V pair a query head is given; ``lambda``,
+    the subtraction (its two terms nearly cancel) and the pair norm are
+    float32 (``F.differential_heads``). ``window`` beside the causal mask
+    is a sliding window. Every projection has a BIAS. ``cross=True`` makes
+    queries only: ``forward(x, kv=(k, v))`` then attends over another
+    layer's keys and values, as ``key_value(x)`` of that layer gives them
+    (``[B, num_kv_heads, S, D]`` and ``[B, num_kv_heads / 2, S, 2 D]``);
+    ``forward(x, return_kv=True)`` returns them beside the result."""
+
+    def __init__(self, hidden_size, num_heads, num_kv_heads, head_dim, depth,
+                 window=None, cross=False, epsilon=1e-5):
+        Layer.__init__(self)
+        if num_heads % num_kv_heads or num_kv_heads % 2:
+            raise ValueError(f"DifferentialAttention: {num_heads} query "
+                             f"heads over {num_kv_heads} key/value heads "
+                             f"do not pair up")
+        self.num_heads, self.num_kv_heads = num_heads, num_kv_heads
+        self.head_dim, self.window, self.cross = head_dim, window, cross
+        self.lambda_init = 0.8 - 0.6 * math.exp(-0.3 * depth)
+        self.q_proj = Linear(hidden_size, num_heads * head_dim)
+        if not cross:
+            self.k_proj = Linear(hidden_size, num_kv_heads * head_dim)
+            self.v_proj = Linear(hidden_size, num_kv_heads * head_dim)
+        for name in ("lambda_q1", "lambda_k1", "lambda_q2", "lambda_k2"):
+            setattr(self, name, self.create_parameter(
+                (head_dim,), default_initializer=I.Normal(0.0, 0.1)))
+        self.subln = RMSNorm(2 * head_dim, epsilon)
+        self.o_proj = Linear(num_heads * head_dim, hidden_size)
+
+    def key_value(self, x):
+        """``(k [B, num_kv_heads, S, D], v [B, num_kv_heads / 2, S, 2 D])``
+        of the layer's input: each key head once, the value heads of a
+        pair side by side."""
+        b, s = x.shape[0], x.shape[1]
+        k = self.k_proj(x).reshape(
+            [b, s, self.num_kv_heads, self.head_dim]).transpose([0, 2, 1, 3])
+        v = self.v_proj(x).reshape(
+            [b, s, self.num_kv_heads // 2, 2 * self.head_dim]).transpose(
+                [0, 2, 1, 3])
+        return k, v
+
+    def _served(self, k, v):
+        """K and V as the flash dispatch takes them, a head a query head:
+        query head ``2j + i`` reads key head ``2 (j // r) + i`` and value
+        pair ``j // r``."""
+        b, _, s, d = k.shape
+        pairs, r = self.num_kv_heads // 2, self.num_heads // self.num_kv_heads
+        k = k.reshape([b, pairs, 1, 2, s, d]).expand([b, pairs, r, 2, s, d])
+        v = v.unsqueeze(2).expand([b, pairs, 2 * r, s, 2 * d])
+        return (k.reshape([b, self.num_heads, s, d]),
+                v.reshape([b, self.num_heads, s, 2 * d]))
+
+    def forward(self, x, kv=None, return_kv=False, force_flash=False):
+        from ..ops.pallas import flash_attention
+        b, s = x.shape[0], x.shape[1]
+        if (kv is None) == self.cross:
+            raise ValueError("DifferentialAttention: a cross layer takes "
+                             "kv=(k, v), a self layer makes its own")
+        q = self._heads(self.q_proj(x), b, s, self.num_heads)
+        kv = self.key_value(x) if kv is None else kv
+        ctx = flash_attention(q, *self._served(*kv), causal=True,
+                              window=self.window, force=force_flash,
+                              scale=1.0 / math.sqrt(self.head_dim))
+        out = self.o_proj(F.differential_heads(
+            ctx, self.lambda_q1, self.lambda_k1, self.lambda_q2,
+            self.lambda_k2, self.subln.weight, self.lambda_init,
+            self.subln._epsilon))
+        return (out,) + tuple(kv) if return_kv else out
+
+
+class GatedMemoryUnit(Layer):
+    """The gated memory unit of a decoder-hybrid-decoder (SambaY,
+    arXiv:2507.06607 section 2): ``W_2 (silu(W_1 u) * M)`` with ``M``
+    ``[B, S, memory_size]`` ANOTHER layer's scan result, taken before its
+    gate (``MambaMixer.forward(return_memory=True)``): an element-wise
+    gate on a memory that was made once, in place of a token mixer. No
+    bias."""
+
+    def __init__(self, hidden_size, memory_size):
+        super().__init__()
+        self.in_proj = Linear(hidden_size, memory_size, bias_attr=False)
+        self.out_proj = Linear(memory_size, hidden_size, bias_attr=False)
+
+    def forward(self, u, memory):
+        return self.out_proj(F.silu(self.in_proj(u)) * memory)

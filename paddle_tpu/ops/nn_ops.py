@@ -983,6 +983,43 @@ def mla_heads(q, kv, k_rope, num_heads, qk_nope_head_dim, v_head_dim,
                      name="mla_heads")
 
 
+def _differential_heads(ctx, lq1, lk1, lq2, lk2, weight, *, lambda_init,
+                        epsilon):
+    f32 = jnp.float32
+    b, h, s, w = ctx.shape
+    lam = (jnp.exp(jnp.sum(lq1.astype(f32) * lk1.astype(f32)))
+           - jnp.exp(jnp.sum(lq2.astype(f32) * lk2.astype(f32)))
+           + lambda_init)
+    pair = ctx.astype(f32).reshape(b, h // 2, 2, s, w)
+    diff = pair[:, :, 0] - lam * pair[:, :, 1]
+    diff = diff * jax.lax.rsqrt(
+        jnp.mean(jnp.square(diff), -1, keepdims=True) + epsilon)
+    out = (1.0 - lambda_init) * diff * weight.astype(f32)
+    return jnp.moveaxis(out, 1, 2).reshape(b, s, h // 2 * w).astype(ctx.dtype)
+
+
+def differential_heads(ctx, lambda_q1, lambda_k1, lambda_q2, lambda_k2,
+                       weight, lambda_init, epsilon=1e-5, name=None):
+    """What differential attention (Ye et al., arXiv:2410.05258) does
+    behind its two soft-max maps. ``ctx`` [B, H, S, W] holds ``A1 V`` in
+    the even heads and ``A2 V`` in the odd ones (one attention call over
+    paired heads); pair ``j`` gives ``(1 - lambda_init) * RMSNorm_W(ctx[2j]
+    - lambda * ctx[2j + 1]) * weight`` with ``lambda = exp(lambda_q1 .
+    lambda_k1) - exp(lambda_q2 . lambda_k2) + lambda_init`` and ONE scale
+    ``weight`` [W] for all pairs. Returns [B, S, H / 2 * W], the pairs
+    side by side, in ``ctx``'s dtype; ``lambda``, the subtraction (whose
+    two terms nearly cancel) and the norm are float32 whatever it is."""
+    if ctx.ndim != 4 or ctx.shape[1] % 2:
+        raise ValueError(f"differential_heads: ctx {tuple(ctx.shape)} is "
+                         f"not [B, an even number of heads, S, W]")
+    with _pscope("F.differential_heads"):
+        return apply(_differential_heads,
+                     (ctx, lambda_q1, lambda_k1, lambda_q2, lambda_k2, weight),
+                     dict(lambda_init=float(lambda_init),
+                          epsilon=float(epsilon)),
+                     name="differential_heads")
+
+
 def interpolate(x, size=None, scale_factor=None, mode="nearest",
                 align_corners=False, data_format="NCHW", name=None):
     """reference: interpolate_op.cc (nearest/bilinear)."""

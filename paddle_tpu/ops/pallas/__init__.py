@@ -33,7 +33,8 @@ _overrides = {}
 _KERNELS = ("layer_norm", "flash_attention", "softmax_xent", "batch_norm",
             "ssd_scan", "causal_conv1d", "gated_rms_norm",
             "moe_scatter_add", "gated_short_conv", "moe_grouped",
-            "qk_heads", "dsa_select", "dsa_kl", "mla_heads")
+            "qk_heads", "dsa_select", "dsa_kl", "mla_heads",
+            "selective_scan")
 
 # Auto defaults from one builder-run v5e ablation (2026-07-31, superseded
 # toolchain, not reproduced — docs/performance.md carries the table):
@@ -134,7 +135,7 @@ _AUTO_ON = {"layer_norm": True, "flash_attention": True,
             "causal_conv1d": True, "gated_rms_norm": True,
             "moe_scatter_add": True, "gated_short_conv": True,
             "moe_grouped": True, "qk_heads": True, "dsa_select": True,
-            "dsa_kl": True, "mla_heads": True}
+            "dsa_kl": True, "mla_heads": True, "selective_scan": True}
 
 
 # flash is an O(S^2)-score win: below some sequence length the XLA sdpa
@@ -184,7 +185,8 @@ def configure(flash_min_seq=_UNSET, **kernels):
     auto default for named kernels ('layer_norm', 'flash_attention',
     'softmax_xent', 'batch_norm', 'ssd_scan', 'causal_conv1d',
     'gated_rms_norm', 'moe_scatter_add', 'gated_short_conv',
-    'moe_grouped', 'qk_heads', 'dsa_select', 'dsa_kl', 'mla_heads'); any
+    'moe_grouped', 'qk_heads', 'dsa_select', 'dsa_kl', 'mla_heads',
+    'selective_scan'); any
     other name raises
     ValueError. None restores auto.
     flash_min_seq=N routes sequences shorter than N to XLA sdpa even
@@ -236,6 +238,7 @@ from . import moe_grouped as moe_grouped_mod
 from . import qk_heads as qk_heads_mod
 from . import dsa as dsa_mod
 from . import mla_heads as mla_heads_mod
+from . import selective_scan as selective_scan_mod
 
 from .layer_norm import layer_norm
 from .softmax_xent import softmax_cross_entropy

@@ -1541,7 +1541,9 @@ def flash_attention(q, k, v, attn_mask=None, causal=False, scale=None,
     kernel's score tiles, those of them that run the masked body and the
     tiles of the whole rectangle it does not walk
     (``flash_attention.window_tiles`` / ``.window_tiles_skipped``: the
-    windowed call sites' part of the first and the last),
+    windowed call sites' part of the first and the last;
+    ``.window_tiles_needed``: the least tiles of the call's size that could
+    hold the pairs its window allows),
     ``flash_attention.native_operands_traced`` counts the call sites whose
     products take bfloat16 operands, and where a call site's backward is
     traced ``flash_attention.backward_fused_traced`` counts it and
@@ -1582,8 +1584,8 @@ def flash_attention(q, k, v, attn_mask=None, causal=False, scale=None,
                 f"flash_attention: selected {tuple(selected.shape)} takes "
                 f"causal=True over one sequence ([{b}, 1, {sq}, {sk}]) and "
                 f"no mask, dropout, window or diffusion_block")
-    block_q, block_k = _blocks_that_fit(held, d, v.shape[3],
-                                        q.dtype.itemsize, block_q, block_k)
+    block_q, block_k = _window_blocks(window, *_blocks_that_fit(
+        held, d, v.shape[3], q.dtype.itemsize, block_q, block_k))
     mode = _mask_mode(attn_mask.shape if has_mask else None, b, h, sq, sk)
     kernel = mode != "fallback" and (force or enabled(
         "flash_attention", seq_len=max(sq, sk)))
@@ -1621,6 +1623,10 @@ def flash_attention(q, k, v, attn_mask=None, causal=False, scale=None,
         monitor.counter("flash_attention.window_tiles").inc(tiles)
         monitor.counter("flash_attention.window_tiles_skipped").inc(
             whole - tiles)
+        # the least tiles of this size that could hold the allowed pairs
+        allowed = window * (window + 1) // 2 + (sq - window) * window
+        monitor.counter("flash_attention.window_tiles_needed").inc(
+            b * h * -(-allowed // (bq * bk)))
     if q.dtype == k.dtype == v.dtype == jnp.bfloat16:
         monitor.counter("flash_attention.native_operands_traced").inc()
 
@@ -1676,3 +1682,18 @@ def _selected_call(q, k, v, selected, scale, block_q, block_k, kernel):
     o, m, l = apply(impl, (q, k, v, selected),
                     name="pallas_flash_attention")
     return o, m.detach(), l.detach()
+
+
+def _window_blocks(window, block_q, block_k):
+    """The block rule's clause for a window narrower than a k-block (at the
+    end of the file: the call sites above keep their lines). A q-block's
+    rows see ``window + block_q - 1`` keys, in whole k-blocks: under a
+    window of 512 at 512 x 1,024 a head's sixteen programs walk 23 tiles
+    of 1,024 keys, three times the pairs the window allows; with the
+    k-block cut to the window's width (a power of two, a lane tile at
+    least) they walk 31 of 512, twice the pairs. A window as wide as a
+    k-block or wider keeps the blocks it had (smallthinker's 4,096 at 512
+    x 512)."""
+    if window is not None and window < block_k:
+        block_k = max(_TILE, 1 << (int(window) - 1).bit_length())
+    return block_q, block_k

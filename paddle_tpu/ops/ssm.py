@@ -1,5 +1,6 @@
 """paddle_tpu.ops.ssm — state-space and short-convolution sequence ops
-(Mamba-2; the ``lfm2`` family's gated short convolution).
+(Mamba-2; Mamba-1's selective scan; the ``lfm2`` family's gated short
+convolution).
 
 No reference counterpart in Paddle Fluid 1.7 (its recurrences are the
 LSTM/GRU ops of ops/sequence.py and nn/rnn.py); these are the ops of a
@@ -16,6 +17,16 @@ it is the second kernel pair of ``ops/pallas/causal_conv1d.py``, which
 reads the three thirds in place and writes their gradient as one array
 (PERF.md section 6, PR 43); counters ``gated_short_conv.kernel_traced`` /
 ``gated_short_conv.xla_traced``.
+
+``F.selective_scan`` is Mamba-1's recurrence (Gu & Dao, arXiv:2312.00752):
+a transition ``A`` [D, N] that differs for every (channel, state) pair and a
+step size for every (token, channel), so the decay between two positions
+differs pair by pair and the chunked matrix form above does not exist.
+``_selective_scan`` below walks the positions (a ``lax.scan``, chunks of it
+recomputed in the backward pass so that a chunk's states are all that is
+held); on one TPU the kernel pair of ``ops/pallas/selective_scan.py`` does
+the same walk on the vector unit with the state in registers; counters
+``selective_scan.kernel_traced`` / ``selective_scan.xla_traced``.
 
 All go through ``dispatch.apply`` (tape autograd, ``jit.to_static``,
 ``jit.recompute``), and each has two forms of one algorithm, chosen by
@@ -40,7 +51,8 @@ import jax.numpy as jnp
 from ..dispatch import apply
 from .nn_ops import _pscope
 
-__all__ = ["causal_conv1d", "gated_short_conv", "ssd_scan"]
+__all__ = ["causal_conv1d", "gated_short_conv", "ssd_scan",
+           "selective_scan"]
 
 
 def _conv1d(x, w, *b, activation):
@@ -223,3 +235,78 @@ def ssd_scan(x, dt, A_log, B, C, D, dt_bias, chunk_size=128, name=None):
     with _pscope("F.ssd_scan"):
         return apply(impl, (x, dt, A_log, B, C, D, dt_bias),
                      dict(chunk=int(chunk_size)), name="ssd_scan")
+
+
+def _selective_scan(x, dt, a, b, c, d_skip, *, chunk):
+    """The portable path of ``selective_scan`` and the kernels' oracle:
+    ``H_t = exp(dt_t A) * H_{t-1} + (dt_t x_t) B_t^T``, ``y_t = H_t C_t + D
+    x_t`` position by position in float32, a chunk's walk recomputed in
+    the backward pass (what is kept is the state that enters each chunk).
+    ``dt`` holds the step sizes (softplus taken), ``a`` is negative."""
+    f32 = jnp.float32
+    bsz, s, d = x.shape
+    pad = -s % chunk
+    xf, dt, b, c = (t.astype(f32) for t in (x, dt, b, c))
+    if pad:     # dt = 0 there: the state stands still, nothing reads them
+        xf, dt, b, c = (jnp.pad(t, [(0, 0), (0, pad), (0, 0)])
+                        for t in (xf, dt, b, c))
+    a, d_skip = a.astype(f32), d_skip.astype(f32)
+
+    def position(h, at):
+        x_t, dt_t, b_t, c_t = at                    # [B, D] x2, [B, N] x2
+        h = jnp.exp(dt_t[..., None] * a) * h \
+            + (dt_t * x_t)[..., None] * b_t[:, None, :]
+        return h, jnp.sum(h * c_t[:, None, :], -1) + d_skip * x_t
+
+    @jax.checkpoint
+    def chunk_of(h, rows):
+        return jax.lax.scan(position, h, rows)
+
+    rows = tuple(jnp.moveaxis(t, 1, 0).reshape(
+        (s + pad) // chunk, chunk, bsz, t.shape[-1]) for t in (xf, dt, b, c))
+    _, y = jax.lax.scan(chunk_of, jnp.zeros((bsz, d, a.shape[1]), f32), rows)
+    return jnp.moveaxis(y.reshape(s + pad, bsz, d), 0, 1)[:, :s]
+
+
+def selective_scan(x, dt, A_log, B, C, D, dt_bias=None, z=None,
+                   chunk_size=None, force=False, name=None):
+    """Mamba-1's selective state-space recurrence, position by position.
+
+    ``x`` [B, S, D] (channels); ``dt`` [B, S, D], the raw step sizes
+    (``softplus(dt + dt_bias)`` is taken here, in float32); ``A_log`` [D,
+    N] (``A = -exp(A_log)``: a transition for every (channel, state)
+    pair); ``B``, ``C`` [B, S, N], shared by the channels; ``D``,
+    ``dt_bias`` [D]. Returns ``y`` [B, S, D] in ``x``'s dtype, or with a
+    gate ``z`` [B, S, D] the pair ``(y * silu(z), y)``: the gated result
+    and the UN-GATED one, which a decoder-hybrid-decoder hands on as its
+    memory. Any sequence length. State, step sizes, exponentials and the
+    gate are float32 whatever ``x`` is. ``force`` takes the kernels off a
+    TPU too (interpret mode: the kernel tests)."""
+    from .. import monitor
+    from . import pallas
+    mod = pallas.selective_scan_mod
+    chunk = int(chunk_size or mod.CHUNK)
+    kernel = (force or pallas.enabled("selective_scan")) and mod.supported(
+        tuple(x.shape), int(A_log.shape[1]), chunk)
+    monitor.counter("selective_scan.kernel_traced" if kernel
+                    else "selective_scan.xla_traced").inc()
+    scan = mod.selective_scan if kernel else _selective_scan
+    extras = {k: t for k, t in (("dt_bias", dt_bias), ("z", z))
+              if t is not None}
+
+    def impl(x, dt, a_log, b, c, d_skip, *rest):
+        f32 = jnp.float32
+        given = dict(zip(extras, rest))
+        dt = dt.astype(f32)
+        if "dt_bias" in given:
+            dt = dt + given["dt_bias"].astype(f32)
+        y = scan(x, jax.nn.softplus(dt), -jnp.exp(a_log.astype(f32)), b, c,
+                 d_skip, chunk=chunk)
+        if "z" not in given:
+            return y.astype(x.dtype)
+        return ((y * jax.nn.silu(given["z"].astype(f32))).astype(x.dtype),
+                y.astype(x.dtype))
+
+    with _pscope("F.selective_scan"):
+        return apply(impl, (x, dt, A_log, B, C, D, *extras.values()),
+                     name="selective_scan")

@@ -8,6 +8,9 @@
     python3 scripts/cell_faults.py --workload keye_vl2_30b_a3b.sparse_causal_16k \
         --seed 3000004751 \
         --faults selection_left_out,indexer_loss_left_out,position_rows_collapsed
+    python3 scripts/cell_faults.py --workload phi4_mini_flash.causal_pretrain \
+        --seed 3000050701 \
+        --faults lambda_taken_as_zero,diff_window_ignored,memory_behind_the_gate,cross_reads_its_own_stream,bfloat16_scan_state
 
 For each named fault: the program with that fault planted, driven through
 the steps `correct` checks at the cell's own size (``benchmark/control.py``'s
@@ -110,10 +113,108 @@ def position_rows_collapsed():
     return lambda: setattr(KeyeVL2ForCausalLM, "forward", forward)
 
 
+def lambda_taken_as_zero():
+    """Plain attention in differential attention's place: ``lambda = 0``,
+    so a pair gives ``(1 - lambda_init) RMSNorm(A1 V)`` and the odd heads'
+    map is dropped."""
+    from paddle_tpu.ops import nn_ops
+    combine = nn_ops._differential_heads
+
+    def patched(ctx, lq1, lk1, lq2, lk2, weight, *, lambda_init, epsilon):
+        return _plain_heads(ctx, weight, lambda_init, epsilon)
+    nn_ops._differential_heads = patched
+    return lambda: setattr(nn_ops, "_differential_heads", combine)
+
+
+def _plain_heads(ctx, weight, lambda_init, epsilon):
+    import jax
+    import jax.numpy as jnp
+    b, h, s, w = ctx.shape
+    o = ctx.astype(jnp.float32).reshape(b, h // 2, 2, s, w)[:, :, 0]
+    o = o * jax.lax.rsqrt(jnp.mean(jnp.square(o), -1, keepdims=True)
+                          + epsilon)
+    out = (1.0 - lambda_init) * o * weight.astype(jnp.float32)
+    return jnp.moveaxis(out, 1, 2).reshape(b, s, h // 2 * w).astype(ctx.dtype)
+
+
+def diff_window_ignored():
+    """Every windowed differential attention layer attends causally over
+    the whole sequence."""
+    from paddle_tpu.nn import hybrid
+    init = hybrid.DifferentialAttention.__init__
+
+    def patched(self, *args, **kwargs):
+        init(self, *args, **kwargs)
+        self.window = None
+    hybrid.DifferentialAttention.__init__ = patched
+    return lambda: setattr(hybrid.DifferentialAttention, "__init__", init)
+
+
+def memory_behind_the_gate():
+    """The memory tapped BEHIND the Mamba layer's gate: the memory units
+    read ``y * silu(z)`` where the model hands on ``y``."""
+    from paddle_tpu.nn import hybrid
+    ssm = hybrid.S
+
+    class Gated:
+        def __getattr__(self, name):
+            return getattr(ssm, name)
+
+        @staticmethod
+        def selective_scan(*args, **kwargs):
+            gated, _ = ssm.selective_scan(*args, **kwargs)
+            return gated, gated
+    hybrid.S = Gated()
+    return lambda: setattr(hybrid, "S", ssm)
+
+
+def cross_reads_its_own_stream():
+    """A cross attention layer reading keys and values of ITS OWN block's
+    input: the giver's two projections and norm applied to the stream that
+    enters the reader, where the model reads what layer N/2 + 1 made from
+    its own."""
+    from paddle_tpu.models.phi4_flash import Phi4FlashForCausalLM
+    handed_to = Phi4FlashForCausalLM.handed_to
+
+    def patched(self, block, h, handed):
+        if block.kind != "cross_attention":
+            return handed_to(self, block, h, handed)
+        giver = next(b for b in self.layers if b.kind == "full_attention")
+        return giver.mixer.key_value(giver.input_layernorm(h))
+    Phi4FlashForCausalLM.handed_to = patched
+    return lambda: setattr(Phi4FlashForCausalLM, "handed_to", handed_to)
+
+
+def bfloat16_scan_state():
+    """Mamba-1's state rounded to bfloat16 after every position (the
+    reference's own time-step recurrence with ``state_dtype``, in the
+    portable path's place; the kernels refused)."""
+    import jax
+    import jax.numpy as jnp
+    from benchmark.reference import phi4_flash as reference
+    from paddle_tpu.ops import pallas, ssm
+    scan, supported = ssm._selective_scan, pallas.selective_scan_mod.supported
+
+    def patched(x, dt, a, b, c, d_skip, *, chunk):
+        f32 = jnp.float32
+        return jax.vmap(lambda x, dt, b, c: reference.selective_scan(
+            x, dt, a.astype(f32), b, c, d_skip.astype(f32), jnp.bfloat16))(
+                *(t.astype(f32) for t in (x, dt, b, c)))
+    ssm._selective_scan = patched
+    pallas.selective_scan_mod.supported = lambda *a, **k: False
+
+    def undo():
+        ssm._selective_scan = scan
+        pallas.selective_scan_mod.supported = supported
+    return undo
+
+
 FAULTS = {f.__name__: f for f in (
     router_behind_attention, window_ignored, gates_left_out,
     sequences_run_on, selection_left_out, indexer_loss_left_out,
-    position_rows_collapsed)}
+    position_rows_collapsed, lambda_taken_as_zero, diff_window_ignored,
+    memory_behind_the_gate, cross_reads_its_own_stream,
+    bfloat16_scan_state)}
 
 
 def main(argv=None):

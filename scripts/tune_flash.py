@@ -1,4 +1,4 @@
-"""Flash-attention block sweep on the chip, at the geometries of the six
+"""Flash-attention block sweep on the chip, at the geometries of the seven
 benchmark cells that run the kernels (q/k and v head sizes apart):
 
     joyai     1 x 32 x 8192 x 192 | 128, causal
@@ -12,6 +12,11 @@ benchmark cells that run the kernels (q/k and v head sizes apart):
               the parent has no such call and is left out)
     lfm2      2 x 32 x 8192 x 64 | 64, causal (lfm2_8b_a1b's attention
               layer: two sequences a step, head size 64)
+    phi4_causal 1 x 40 x 8192 x 64 | 128, causal (phi4_mini_flash's full and
+                cross attention: differential attention's paired heads)
+    phi4_window the same under a sliding window of 512 (its window layer: a
+                window narrower than the default k-block)
+    (``--cells phi4`` is both)
 
 For each (block_q, block_k): forward ms, backward ms (the backward call
 alone, on the forward's saved results) and forward + backward ms (host
@@ -58,8 +63,16 @@ GEOMETRIES = {
     "lfm2": (2, 32, 8192, 64, 64, True, False, None,
              [(512, 1024), (512, 512), (256, 1024), (1024, 1024),
               (1024, 512), (256, 512)]),
+    "phi4_causal": (1, 40, 8192, 64, 128, True, False, None,
+                    [(512, 1024), (512, 512), (1024, 1024), (256, 1024),
+                     (1024, 512)]),
+    "phi4_window": (1, 40, 8192, 64, 128, True, False, None,
+                    [(512, 1024), (512, 512), (256, 512), (512, 256),
+                     (256, 256), (1024, 512)]),
 }
-WINDOWS = {"st_window": 4096}       # geometry -> flash_attention(window=)
+# geometry -> flash_attention(window=)
+WINDOWS = {"st_window": 4096, "phi4_window": 512}
+ALIASES = {"phi4": ["phi4_causal", "phi4_window"]}
 
 
 def load_kernels(parent):
@@ -195,7 +208,8 @@ def main():
     if args.parent:
         all_sides.insert(0, ("parent", load_kernels(args.parent)))
 
-    for cell in args.cells.split(","):
+    for cell in [c for name in args.cells.split(",")
+                 for c in ALIASES.get(name, [name])]:
         geometry, window = GEOMETRIES[cell], WINDOWS.get(cell)
         sides = [side for side in all_sides
                  if window is None or hasattr(side[1], "_flash_win")]
@@ -204,6 +218,14 @@ def main():
         held = s // 2 if block else s
         rule = {name: m._blocks_that_fit(held, d, dv, 2, 512, 1024)
                 for name, m in sides}
+        if hasattr(change, "_window_blocks"):   # the window's clause
+            rule["change"] = change._window_blocks(window, *rule["change"])
+        vmem = change._bwd_params(
+            held, d, dv, 2, *rule["change"],
+            change._single_buffered(held, d, dv, 2)).vmem_limit_bytes
+        print(f"{cell}: a side {change._side_bytes(held, d, dv, 2) / 2**20:.1f}"
+              f" MiB, the backward call may use {vmem / 2**20:.1f} MiB of "
+              f"VMEM at the rule's blocks", flush=True)
         print(f"{cell}: {b} x {h} x {s} x {d} | {dv} causal={causal} "
               f"key_bias={key_bias} window={window} rule={rule}", flush=True)
         for bq, bk in pairs:

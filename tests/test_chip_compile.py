@@ -506,10 +506,12 @@ def test_flash_kernels_widen_no_tile_of_k_or_v(compiled_kernels):
 
 # -- what a recomputed block keeps, in XLA's account of a cell's step --------
 
-def _cell_step_memory(monkeypatch, one_chip, cell, layers, policy):
+def _cell_step_memory(monkeypatch, one_chip, cell, layers, policy,
+                      first_layer=None):
     """XLA's memory analysis of a benchmark cell's training step, cut to
-    ``layers`` blocks, compiled for the described chip with the cell's own
-    family file, widths and sequence; ``policy`` is what every
+    ``layers`` blocks (from source layer ``first_layer`` on, where given),
+    compiled for the described chip with the cell's own family file,
+    widths and sequence; ``policy`` is what every
     ``jit.recompute`` of the step is given (None: none named). Sizes, no
     time: nothing runs."""
     import importlib
@@ -527,6 +529,8 @@ def _cell_step_memory(monkeypatch, one_chip, cell, layers, policy):
                            traffic + ".json")) as f:
         traffic = dict(json.load(f), chips=1)
     cfg["num_hidden_layers"] = layers
+    if first_layer is not None:
+        cfg["first_layer"] = first_layer
     if "sliding_window_layout" in cfg:
         cfg["sliding_window_layout"] = cfg["sliding_window_layout"][:layers]
 
@@ -1141,6 +1145,7 @@ KERNEL_NAMES = {
                        "moe_tgmm"],
     "moe_scatter_add.py": ["moe_scatter_add"],
     "qk_heads.py": ["qk_heads_fwd", "qk_heads_bwd"],
+    "selective_scan.py": ["selective_scan_fwd", "selective_scan_bwd"],
     "softmax_xent.py": ["softmax_xent_fwd", "softmax_xent_bwd"],
     "ssd_scan.py": ["ssd_fwd", "ssd_bwd"],
 }
@@ -1180,7 +1185,7 @@ def test_no_pallas_call_site_is_left_out_and_no_name_is_used_twice():
     found = {f: names for f, names in found.items() if names}
     assert found == KERNEL_NAMES
     every = [n for names in found.values() for n in names]
-    assert len(every) == len(set(every)) == 35
+    assert len(every) == len(set(every)) == 37
 
 
 # a registered name switches the kernels of the file of its name; where two
@@ -1205,3 +1210,90 @@ def test_every_registered_kernel_has_a_module_with_a_call_site():
     assert sorted(k for names in switched.values() for k in names) \
         == sorted(k for names in KERNEL_NAMES.values() for k in names)
     assert set(P._KERNELS) == set(P._AUTO_ON)
+
+
+# -- the phi4_mini_flash cell: Mamba-1's scan pair, flash at 64 | 128 -------
+
+def test_selective_scan_fwd_bwd_at_the_cells_size(one_chip,
+                                                  compiled_kernels):
+    """1 x 8,192 positions x 5,120 channels, state 16: the forward kernel
+    and the backward kernel (the chunk's states made again in VMEM, the
+    state's gradient in registers), with every gradient asked for."""
+    from paddle_tpu.ops.pallas import selective_scan as K
+    s, d, n = 8192, 5120, 16
+    f = jax.grad(lambda *a: jnp.sum(K.selective_scan(*a)),
+                 argnums=range(6))
+    count = _compile(
+        f, one_chip, ((1, s, d), jnp.bfloat16), ((1, s, d), jnp.float32),
+        ((d, n), jnp.float32), ((1, s, n), jnp.bfloat16),
+        ((1, s, n), jnp.bfloat16), ((d,), jnp.float32),
+        names=("selective_scan_fwd", "selective_scan_bwd"))
+    assert count == 2
+
+
+@pytest.mark.parametrize("window", [None, 512], ids=["causal", "window512"])
+def test_flash_at_64_128_fwd_bwd_at_the_phi4_cells_size(
+        one_chip, compiled_kernels, window):
+    """40 heads x 8,192 positions, queries and keys 64 wide, values 128
+    (differential attention's paired heads), causal and under the window
+    of 512, at the blocks the op's rule gives them: 512 x 1,024 for the
+    causal call (3 MiB a side) and 512 x 512 under the window, whose
+    k-block the rule cuts to the window's width."""
+    from paddle_tpu.ops.pallas import flash_attention_mod as fa
+    bq, bk = fa._window_blocks(window, *fa._blocks_that_fit(
+        8192, 64, 128, 2, 512, 1024))
+    assert (bq, bk) == ((512, 512) if window else (512, 1024))
+
+    def f(q, k, v):
+        if window:
+            return fa._flash_win(q, k, v, window, 0.125, bq, bk)
+        return fa._flash(q, k, v, None, "none", jnp.zeros((2,), jnp.int32),
+                         True, 0.125, bq, bk, 0.0)
+
+    qk, v = ((1, 40, 8192, 64), jnp.bfloat16), ((1, 40, 8192, 128),
+                                                jnp.bfloat16)
+    names = ("flash_win_fwd", "flash_win_bwd") if window \
+        else ("flash_fwd", "flash_bwd")
+    assert _compile(_grad_sum(f, argnums=(0, 1, 2)), one_chip, qk, qk, v,
+                    names=names) == 2
+
+
+def test_the_phi4_cells_step_holds_its_kernels_and_fits(one_chip,
+                                                        compiled_kernels,
+                                                        monkeypatch):
+    """The last four blocks of ``phi4_mini_flash.causal_pretrain`` (source
+    layers 16-19: the Mamba layer that gives the memory, the full attention
+    that gives K and V, the memory unit and the cross attention that read
+    them: both hand-overs across recomputed blocks) at the cell's widths and
+    1 x 8,192 tokens, through the cell's own family file: the step compiles
+    for the chip with the scan, convolution, flash and layer-norm kernels
+    taken (counts and sizes, no time: nothing runs; the window layer's call
+    is the case above, and all six blocks ran on the chip, PERF.md section
+    6, PR 50: four keep this case's compile under a minute)."""
+    from paddle_tpu import monitor
+    prefixes = ("selective_scan", "causal_conv1d", "flash_attention",
+                "recompute")
+    before = {p: monitor.snapshot(p) for p in prefixes}
+    seen = _cell_step_memory(monkeypatch, one_chip,
+                             "phi4_mini_flash.causal_pretrain", 4, None,
+                             first_layer=16)
+
+    def gained(prefix, name):
+        return monitor.snapshot(prefix).get(f"{prefix}.{name}", 0) \
+            - before[prefix].get(f"{prefix}.{name}", 0)
+
+    assert gained("selective_scan", "kernel_traced") == 1
+    assert gained("selective_scan", "xla_traced") == 0
+    assert gained("causal_conv1d", "kernel_traced") == 1
+    assert gained("flash_attention", "kernel_traced") == 2
+    # the memory to the memory unit, K and V to the cross attention
+    assert gained("recompute", "handed_on") == 3
+    assert gained("recompute", "handed_on_bytes") == 2 * 8192 * (
+        5120 + 20 * 64 + 10 * 128)
+    # 538 M parameters at 12 bytes (weights and two moments; the gradient
+    # is a temporary), and temporaries that leave the chip's 16 GiB room
+    params = 41_241_600 + 19_668_864 + 26_214_400 + 13_112_704 \
+        + 4 * 78_653_440 + 25_008 * 2_560 + 5_120
+    assert abs(seen.argument_size_in_bytes - 12 * params) < 2 ** 20
+    assert seen.argument_size_in_bytes + seen.temp_size_in_bytes \
+        < 14 * 10 ** 9

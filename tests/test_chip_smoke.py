@@ -39,11 +39,12 @@ def test_phase_kernels_tiny_interpret(cs, capsys):
     out = _lines(capsys, "kernels")
     # layer norm f32+bf16, flash x3, at latent attention's head sizes and
     # under the block-diffusion structure and a sliding window, the scan,
-    # the convolution, the gated short convolution, the gated norm, the
+    # Mamba-1's selective scan, the convolution, the gated short convolution, the gated norm, the
     # projection-to-heads pair, the latent heads' pair, the experts'
     # scatter-add, the experts' grouped products, a learned selection
     # against a sort and the two kernels under it
-    assert len(out) == 18
+    assert len(out) == 19
+    assert any("selective_scan[2x128x1024,state16" in l for l in out)
     # (at 1,024 positions: the least the selection kernel packs at chunks
     # of 128 keys)
     assert any("dsa_select[2x1024,4x64,top256]" in l

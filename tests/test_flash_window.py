@@ -134,6 +134,44 @@ def test_tile_counts_at_the_cells_shape_are_the_issues():
     assert int((np.arange(16384) + 1).sum()) == 134225920
 
 
+def test_a_window_narrower_than_a_k_block_cuts_the_k_block_to_its_width():
+    """The block rule's clause for the phi4_mini_flash cell's window layer
+    (40 heads x 8,192 rows at 64 | 128 under a window of 512): 3 MiB a side
+    keeps 512 x 1,024, under which a q-block walks two tiles of 1,024 keys
+    for the 1,023 its rows see; the clause cuts the k-block to 512, and the
+    walk halves. A window as wide as the k-block or wider, and no window,
+    keep the blocks they had."""
+    assert flash_mod._blocks_that_fit(8192, 64, 128, 2, 512, 1024) \
+        == (512, 1024)
+    assert flash_mod._window_blocks(512, 512, 1024) == (512, 512)
+    assert flash_mod._window_blocks(300, 512, 1024) == (512, 512)
+    assert flash_mod._window_blocks(100, 512, 1024) == (512, 128)
+    assert flash_mod._window_blocks(48, 512, 1024) == (512, 128)
+    for window, blocks in ((None, (512, 1024)), (4096, (512, 512)),
+                           (1024, (512, 1024)), (512, (256, 512))):
+        assert flash_mod._window_blocks(window, *blocks) == blocks
+    geom = dict(sq=8192, sk=8192, causal=True, window=512)
+    wide, _ = flash_mod._tile_counts(40, block_q=512, block_k=1024, **geom)
+    cut, masked = flash_mod._tile_counts(40, block_q=512, block_k=512, **geom)
+    assert (wide, cut, masked) == (40 * 23, 40 * 31, 40 * 31)
+    allowed = 512 * 513 // 2 + 7680 * 512
+    assert wide * 512 * 1024 / (40 * allowed) > 2.9      # the pairs walked
+    assert 1.9 < cut * 512 * 512 / (40 * allowed) < 2.0
+    # the dispatch applies it, and counts the tiles the window needs
+    q = pt.to_tensor(np.zeros((1, 1, 256, 16), np.float32))
+    before = monitor.snapshot("flash_attention.window")
+    flash_attention(q, q, q, causal=True, window=32, force=True,
+                    block_q=32, block_k=256)
+    after = monitor.snapshot("flash_attention.window")
+    gained = {k: v - before.get(k, 0) for k, v in after.items()}
+    # the k-block of 256 asked for is cut to a lane tile, 128: eight
+    # q-blocks of 32 walk one tile each and the one that starts a k-block
+    # two; the window's 7,696 pairs would fill 1.9 tiles of 32 x 128
+    assert gained["flash_attention.window_tiles"] == 9
+    assert gained["flash_attention.window_tiles_skipped"] == 16 - 9
+    assert gained["flash_attention.window_tiles_needed"] == 2
+
+
 def test_the_dispatch_counts_the_path_and_the_tiles_and_refuses_a_mix():
     q = pt.to_tensor(np.asarray(jax.random.normal(jax.random.key(3),
                                                   (1, 2, 64, 16))))
